@@ -439,20 +439,19 @@ def block_multihead_attention(q, k_pool, v_pool, block_table, pos,
     incubate/nn/functional/block_multihead_attention.py analogue).
     q: [b, t, h, d]; returns [b, t, h*d].
 
-    t == 1 (decode) runs the Pallas paged kernel: pages are DMA'd straight
-    from the pool via scalar-prefetch block indexing, so the full
-    [b, max_len, h, d] cache is never materialized (round-3 VERDICT
-    Missing #3). Prefill (t > 1) and non-tiling head dims use the
-    gather + dense-mask path."""
+    t == 1 (decode) runs the ragged paged kernel at q_len 1: pages are
+    DMA'd straight from the pool via scalar-prefetch block indexing, so
+    the full [b, max_len, h, d] cache is never materialized. Prefill
+    (t > 1) and non-tiling head dims use the gather + dense-mask path."""
     b, t, h, d = q.shape
     scale = scale if scale is not None else 1.0 / np.sqrt(d)
     if t == 1:
-        from paddle_tpu.ops.pallas.paged_attention import (
-            paged_decode_attention, paged_decode_ok)
+        from paddle_tpu.ops.pallas.ragged_paged_attention import (
+            ragged_attention_ok, ragged_paged_attention)
 
-        if paged_decode_ok(d):
-            out = paged_decode_attention(q[:, 0], k_pool, v_pool,
-                                         block_table, pos, scale=scale)
+        if ragged_attention_ok(d, h, k_pool.shape[2]):
+            out = ragged_paged_attention(q, k_pool, v_pool, block_table,
+                                         pos, 1, scale=scale)
             return out.reshape(b, 1, h * d)
         _warn_paged_fallback(d)
     k = paged_gather(k_pool, block_table)

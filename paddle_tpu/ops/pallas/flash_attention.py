@@ -79,10 +79,10 @@ def _tile_scores(q_ref, k_ref, qi, ki, block_q, block_k, causal, scale,
     if mask_ref is not None:
         s = s + mask_ref[0].astype(jnp.float32)
     if kbias_ref is not None:
-        s = s + kbias_ref[0].astype(jnp.float32)[None, :]
+        s = s + kbias_ref[0, 0].astype(jnp.float32)[None, :]
     if qseg_ref is not None:
-        qs = qseg_ref[0]
-        ks = kseg_ref[0]
+        qs = qseg_ref[0, 0]
+        ks = kseg_ref[0, 0]
         s = jnp.where(qs[:, None] == ks[None, :], s, NEG_INF)
     if causal:
         q_start = (seq_k - seq_q) + qi * block_q
@@ -222,23 +222,28 @@ def _extra_inputs_specs(mask, kbias, qseg, kseg, h, block_q, block_k,
                    (lambda bh, ki, qi: (bh // h, qi, ki)))
         inputs.append(mf)
         specs.append(pl.BlockSpec((1, block_q, block_k), idx))
+    # per-key / per-row vectors ride as [b, 1, s] with (1, 1, block)
+    # blocks: a bare (1, block) block over [b, s] breaks the TPU block
+    # rule (second-to-last block dim 8-aligned or the whole dim) for
+    # every batch but 1
     if kbias is not None:
         if order == "qk":
-            kbidx = lambda bh, qi, ki: (bh // h, ki)  # noqa: E731
+            kbidx = lambda bh, qi, ki: (bh // h, 0, ki)  # noqa: E731
         else:
-            kbidx = lambda bh, ki, qi: (bh // h, ki)  # noqa: E731
-        inputs.append(kbias.astype(jnp.float32))
-        specs.append(pl.BlockSpec((1, block_k), kbidx))
+            kbidx = lambda bh, ki, qi: (bh // h, 0, ki)  # noqa: E731
+        inputs.append(kbias.astype(jnp.float32)[:, None])
+        specs.append(pl.BlockSpec((1, 1, block_k), kbidx))
     if qseg is not None:
         if order == "qk":
-            qidx = lambda bh, qi, ki: (bh // h, qi)   # noqa: E731
-            kidx = lambda bh, qi, ki: (bh // h, ki)   # noqa: E731
+            qidx = lambda bh, qi, ki: (bh // h, 0, qi)   # noqa: E731
+            kidx = lambda bh, qi, ki: (bh // h, 0, ki)   # noqa: E731
         else:
-            qidx = lambda bh, ki, qi: (bh // h, qi)   # noqa: E731
-            kidx = lambda bh, ki, qi: (bh // h, ki)   # noqa: E731
-        inputs += [qseg.astype(jnp.int32), kseg.astype(jnp.int32)]
-        specs += [pl.BlockSpec((1, block_q), qidx),
-                  pl.BlockSpec((1, block_k), kidx)]
+            qidx = lambda bh, ki, qi: (bh // h, 0, qi)   # noqa: E731
+            kidx = lambda bh, ki, qi: (bh // h, 0, ki)   # noqa: E731
+        inputs += [qseg.astype(jnp.int32)[:, None],
+                   kseg.astype(jnp.int32)[:, None]]
+        specs += [pl.BlockSpec((1, 1, block_q), qidx),
+                  pl.BlockSpec((1, 1, block_k), kidx)]
     if block_mask is not None:
         # the whole [n_qblocks, n_kblocks] table rides in VMEM (tiny);
         # every grid step indexes it by (qi, ki)

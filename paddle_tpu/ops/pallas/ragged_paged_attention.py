@@ -12,10 +12,12 @@ padded KV history ([B, max_pages*page_size, H, D]) in HBM per step.
 
 Design (the flash-attention online-softmax structure of
 ops/pallas/flash_attention.py crossed with the scalar-prefetch block
-indexing of ops/pallas/paged_attention.py):
+indexing):
 
-  * grid (batch, page): each step folds ONE pool page into one
-    sequence's accumulators; per-sequence block tables, span start
+  * grid (batch, q_tile, page): each step folds ONE pool page into the
+    accumulators of one tile of Q_TILE span rows (a whole span when it
+    is shorter), so VMEM use does not grow with the prefill bucket;
+    per-sequence block tables, span start
     positions, and span lengths ride in SMEM via
     pltpu.PrefetchScalarGridSpec, and the K/V BlockSpec index_map reads
     ``table[b, j]`` to DMA exactly that pool page into VMEM;
@@ -30,7 +32,7 @@ indexing of ops/pallas/paged_attention.py):
     live page and the Pallas pipeline elides the repeated block copy, so
     a short sequence in a long table pays only its own pages' bandwidth;
   * native GQA: q heads are grouped by their KV head OUTSIDE the kernel
-    ([B, T, n_q, d] -> [B, n_kv, n_rep*T, d]), so the in-kernel matmuls
+    ([B, T, n_q, d] -> [B, n_kv, T*n_rep, d]), so the in-kernel matmuls
     batch over n_kv and contract d with no head replication — grouped
     models (n_rep > 1) stop falling back to the gather path;
   * fp32 online softmax with running (m, l, acc) in VMEM scratch across
@@ -60,13 +62,17 @@ except Exception:  # pragma: no cover
     pltpu = None
 
 NEG_INF = -1e30
+# span rows one grid step holds in VMEM; spans that are a multiple of it
+# are tiled, shorter (or odd) spans are one tile
+Q_TILE = 128
 
 
 def _ragged_kernel(table_ref, start_ref, qlen_ref, q_ref, k_ref, v_ref,
                    o_ref, m_ref, l_ref, acc_ref, *, page_size: int,
                    n_rep: int, scale: float,
                    kscale_ref=None, vscale_ref=None):
-    """Grid (b, page): fold one KV page into sequence b's span rows.
+    """Grid (b, q_tile, page): fold one KV page into one tile of
+    sequence b's span rows.
 
     With kscale_ref/vscale_ref (ISSUE 9: int8 pools), the K/V block is
     int8 codes and the per-page-per-head scales ride the SMEM scalar
@@ -75,10 +81,11 @@ def _ragged_kernel(table_ref, start_ref, qlen_ref, q_ref, k_ref, v_ref,
     here inside the page walk, and the online softmax stays fp32 — the
     page walk reads half the bytes, the math above it is unchanged."""
     b = pl.program_id(0)
-    j = pl.program_id(1)
-    n_pages = pl.num_programs(1)
+    i = pl.program_id(1)
+    j = pl.program_id(2)
+    n_pages = pl.num_programs(2)
     n_kv, G, d = q_ref.shape[1], q_ref.shape[2], q_ref.shape[3]
-    T = G // n_rep                     # padded span rows per q head
+    tq = G // n_rep                    # span rows in this tile
 
     @pl.when(j == 0)
     def _init():
@@ -88,12 +95,16 @@ def _ragged_kernel(table_ref, start_ref, qlen_ref, q_ref, k_ref, v_ref,
 
     start = start_ref[b]
     qlen = qlen_ref[b]
-    last_pos = start + qlen - 1        # last visible key position
+    t0 = i * tq                        # span row of this tile's row 0
+    # last key position any live row of this tile sees (causal: rows
+    # past the tile never look further than its own last row)
+    last_pos = start + jnp.minimum(qlen, t0 + tq) - 1
 
-    # early-out: dead spans (qlen == 0) and pages past the span's last
-    # visible key fold nothing in — and their DMA was elided by the
-    # clamped index_map (the revisited block is already VMEM-resident)
-    @pl.when((qlen > 0) & (j * page_size <= last_pos))
+    # early-out: dead spans and tiles (t0 >= qlen) and pages past the
+    # tile's last visible key fold nothing in — and their DMA was
+    # elided by the clamped index_map (the revisited block is already
+    # VMEM-resident)
+    @pl.when((qlen > t0) & (j * page_size <= last_pos))
     def _page():
         q = q_ref[0].astype(jnp.float32)           # [n_kv, G, d]
         k = k_ref[0].astype(jnp.float32)           # [ps, n_kv, d]
@@ -112,10 +123,11 @@ def _ragged_kernel(table_ref, start_ref, qlen_ref, q_ref, k_ref, v_ref,
         s = jax.lax.dot_general(
             q, k, (((2,), (2,)), ((0,), (1,))),
             preferred_element_type=jnp.float32) * scale
-        # grouped row r is (rep, t) flattened; its query position is
-        # start + t with t = r % T, and rows t >= qlen are padding
-        t_idx = jax.lax.broadcasted_iota(
-            jnp.int32, (n_kv, G, page_size), 1) % T
+        # grouped row r is (t, rep) flattened; its query position is
+        # start + t with t = t0 + r // n_rep, and rows t >= qlen are
+        # padding
+        t_idx = t0 + jax.lax.broadcasted_iota(
+            jnp.int32, (n_kv, G, page_size), 1) // n_rep
         k_pos = j * page_size + jax.lax.broadcasted_iota(
             jnp.int32, (n_kv, G, page_size), 2)
         s = jnp.where((k_pos <= start + t_idx) & (t_idx < qlen),
@@ -177,17 +189,21 @@ def ragged_paged_attention(q, k_pool, v_pool, block_table, start_pos, q_len,
         jnp.asarray(start_pos, jnp.int32).reshape(-1), (B,))
     qlen_arr = jnp.broadcast_to(
         jnp.asarray(q_len, jnp.int32).reshape(-1), (B,))
-    G = n_rep * T
+    # span rows per grid tile: the q/out blocks and the (m, l, acc)
+    # scratch scale with it, so a long prefill span walks the pages once
+    # per tile instead of asking for more scoped VMEM than the chip has
+    tq = Q_TILE if T % Q_TILE == 0 else T
+    G = n_rep * tq
     # group q heads by KV head outside the kernel (XLA transpose) so the
-    # kernel body needs no layout shuffles: row r of group g = (rep, t)
-    qg = q.reshape(B, T, n_kv, n_rep, d).transpose(0, 2, 3, 1, 4)
-    qg = qg.reshape(B, n_kv, G, d)
+    # kernel body needs no layout shuffles: row r of group g = (t, rep)
+    qg = q.reshape(B, T, n_kv, n_rep, d).transpose(0, 2, 1, 3, 4)
+    qg = qg.reshape(B, n_kv, T * n_rep, d)
 
-    def kv_map(b, j, t, s, ql, *_):
-        # clamp dead pages (past the span's last visible key) to the last
+    def kv_map(b, i, j, t, s, ql, *_):
+        # clamp dead pages (past the tile's last visible key) to the last
         # live page: the pipeline sees an unchanged block index and
         # elides the DMA (dead slots clamp to the table's first entry)
-        last = jnp.maximum(s[b] + ql[b] - 1, 0)
+        last = jnp.maximum(s[b] + jnp.minimum(ql[b], (i + 1) * tq) - 1, 0)
         jc = jnp.minimum(j, last // page_size)
         return (t[b, jc], 0, 0, 0)
 
@@ -195,14 +211,14 @@ def ragged_paged_attention(q, k_pool, v_pool, block_table, start_pos, q_len,
         # quantized pools prefetch the scale rows alongside the tables:
         # scalars 3/4 are k_scale/v_scale, read per clamped page id
         num_scalar_prefetch=5 if quantized else 3,
-        grid=(B, n_pages),
+        grid=(B, T // tq, n_pages),
         in_specs=[
-            pl.BlockSpec((1, n_kv, G, d), lambda b, j, *_: (b, 0, 0, 0)),
+            pl.BlockSpec((1, n_kv, G, d), lambda b, i, j, *_: (b, 0, i, 0)),
             pl.BlockSpec((1, page_size, n_kv, d), kv_map),
             pl.BlockSpec((1, page_size, n_kv, d), kv_map),
         ],
         out_specs=pl.BlockSpec((1, n_kv, G, d),
-                               lambda b, j, *_: (b, 0, 0, 0)),
+                               lambda b, i, j, *_: (b, 0, i, 0)),
         scratch_shapes=[
             pltpu.VMEM((n_kv, G, 1), jnp.float32),
             pltpu.VMEM((n_kv, G, 1), jnp.float32),
@@ -225,12 +241,12 @@ def ragged_paged_attention(q, k_pool, v_pool, block_table, start_pos, q_len,
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, n_kv, G, d),
+        out_shape=jax.ShapeDtypeStruct((B, n_kv, T * n_rep, d),
                                        jnp.float32 if quantized else q.dtype),
         interpret=interpret,
     )(*scalars, qg, k_pool, v_pool)
     out = out.astype(q.dtype)
-    out = out.reshape(B, n_kv, n_rep, T, d).transpose(0, 3, 1, 2, 4)
+    out = out.reshape(B, n_kv, T, n_rep, d).transpose(0, 2, 1, 3, 4)
     return out.reshape(B, T, n_q, d)
 
 

@@ -195,20 +195,10 @@ def _register_constraint_op():
             # is ambient; a bare PartitionSpec resolves against it (a concrete
             # NamedSharding would mis-type the manual axes). Plain jit has an
             # empty abstract mesh -> use the concrete mesh.
-            get_am = getattr(jax.sharding, "get_abstract_mesh", None)
-            if get_am is not None:
-                if get_am().axis_names:
-                    return jax.lax.with_sharding_constraint(x, spec)
-                return jax.lax.with_sharding_constraint(
-                    x, NamedSharding(mesh, spec))
-            # jax < 0.6 has no abstract-mesh probe: resolve against the
-            # concrete mesh, and drop the hint where it cannot type (a
-            # constraint is an optimization, never semantics)
-            try:
-                return jax.lax.with_sharding_constraint(
-                    x, NamedSharding(mesh, spec))
-            except Exception:
-                return x
+            if jax.sharding.get_abstract_mesh().axis_names:
+                return jax.lax.with_sharding_constraint(x, spec)
+            return jax.lax.with_sharding_constraint(
+                x, NamedSharding(mesh, spec))
         return x
 
     # dynamic=True skips the per-op jit wrapper so the flag is read at the

@@ -275,9 +275,7 @@ def send_in(x, axis: str, dst_offset: int = 1):
     """In-jit: send this rank's block `dst_offset` ranks forward along the
     axis ring; returns what this rank RECEIVES (collective_permute
     semantics — every rank participates)."""
-    from paddle_tpu.parallel.pipeline import axis_size
-
-    n = axis_size(axis)
+    n = lax.axis_size(axis)
     perm = [(i, (i + dst_offset) % n) for i in range(n)]
     return lax.ppermute(x, axis, perm)
 

@@ -27,61 +27,18 @@ import numpy as np
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
-try:
-    from jax import shard_map
-except ImportError:  # older jax
-    from jax.experimental.shard_map import shard_map  # type: ignore
-
-
-def compat_shard_map(f, mesh, in_specs, out_specs, axis_names=None,
-                     check_rep: bool = False):
-    """shard_map across the jax version skew — the ONE spelling every
-    pipeline schedule, ring attention, and the serving TP kernels use.
-
-    Newer jax takes `axis_names` (the manually-mapped axes; the rest
-    stay GSPMD-auto). jax < 0.6 has neither `axis_names` nor a working
-    `auto=` (NotImplementedError on 0.4.x): there the map runs FULLY
-    manual over every mesh axis with check_rep=False — unnamed axes in
-    the in_specs then mean per-device replicated compute, which is the
-    same math, minus the auto-sharding of the untouched axes."""
-    import inspect
-
-    params = inspect.signature(shard_map).parameters
-    kw = {}
-    if axis_names is not None and "axis_names" in params:
-        kw["axis_names"] = frozenset(axis_names)
-    if "check_rep" in params:
-        kw["check_rep"] = check_rep
-    elif "check_vma" in params:
-        kw["check_vma"] = check_rep
-    return shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                     **kw)
-
-
-def axis_size(axis: str):
-    """lax.axis_size across the jax version skew: jax < 0.6 spells it
-    jax.core.axis_frame(name), which returns the size as a plain int
-    inside a shard_map body."""
-    fn = getattr(lax, "axis_size", None)
-    if fn is not None:
-        return fn(axis)
-    from jax import core
-
-    return int(core.axis_frame(axis))
+# every manually-mapped region of the repo (pipeline schedules, ring
+# attention, the serving TP kernels) goes through this one spelling:
+# `axis_names` are the manual axes (the rest stay GSPMD-auto), and the
+# varying-manual-axes check is off
+manual_shard_map = partial(jax.shard_map, check_vma=False)
 
 
 def varying(v, axis: str = "pp"):
     """Mark a value as axis-varying for shard_map's vma type system (no-op
     if already varying). Shared by the pipeline schedules and ring
-    attention — one site to fix when the experimental vma API moves."""
-    try:
-        if axis in jax.typeof(v).vma:
-            return v
-    except Exception:
-        pass
-    if getattr(lax, "pcast", None) is None:
-        # jax < 0.7: no vma type system — nothing to mark (the compat
-        # shard_map runs with replication checking off)
+    attention."""
+    if axis in jax.typeof(v).vma:
         return v
     return lax.pcast(v, (axis,), to="varying")
 
@@ -174,7 +131,7 @@ def pipeline_apply(stage_fn: Callable[[Any, Any], Any], stacked_params,
         (_, outbuf), _ = lax.scan(tick, init, jnp.arange(total_ticks))
         return outbuf
 
-    mapped = compat_shard_map(
+    mapped = manual_shard_map(
         per_device,
         mesh=mesh,
         in_specs=(jax.tree_util.tree_map(lambda _: P("pp"), stacked_params),

@@ -38,10 +38,6 @@ import numpy as np
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
-try:
-    from jax import shard_map
-except ImportError:  # older jax
-    from jax.experimental.shard_map import shard_map  # type: ignore
 
 
 # ----------------------------------------------------------------- simulators
@@ -494,7 +490,7 @@ def schedule_stats(pp: int, m: int, schedule: str = "gpipe", v: int = 1):
 
 
 from paddle_tpu.parallel.pipeline import (  # noqa: E402
-    chain_stages, compat_shard_map, varying as _varying,
+    chain_stages, manual_shard_map, varying as _varying,
 )
 
 
@@ -589,7 +585,7 @@ def pipeline_apply_interleave(stage_fn: Callable[[Any, Any], Any],
         (_, outbuf, _), _ = lax.scan(tick, init, tab)
         return outbuf
 
-    mapped = compat_shard_map(
+    mapped = manual_shard_map(
         per_device, mesh=mesh,
         in_specs=(jax.tree_util.tree_map(lambda _: P("pp"), re), P()),
         out_specs=P("pp"),
@@ -739,7 +735,7 @@ def pipeline_1f1b(stage_fn: Callable[[Any, Any], Any], stacked_params,
         dx = lax.psum(dx_buf * first_mask, "pp")
         return loss, gparams, ghead, dx
 
-    mapped = compat_shard_map(
+    mapped = manual_shard_map(
         per_device, mesh=mesh,
         in_specs=(jax.tree_util.tree_map(lambda _: P("pp"), stacked_params),
                   jax.tree_util.tree_map(lambda _: P(), head_params),
@@ -924,7 +920,7 @@ def pipeline_zbh1(stage_fn: Callable[[Any, Any], Any], stacked_params,
         dx = lax.psum(dx_buf * first_mask, "pp")
         return loss, gparams, ghead, dx
 
-    mapped = compat_shard_map(
+    mapped = manual_shard_map(
         per_device, mesh=mesh,
         in_specs=(jax.tree_util.tree_map(lambda _: P("pp"), stacked_params),
                   jax.tree_util.tree_map(lambda _: P(), head_params),
@@ -1134,7 +1130,7 @@ def pipeline_zbvpp(stage_fn: Callable[[Any, Any], Any], stacked_params,
         dx = lax.psum(dx_buf * first_mask, "pp")
         return loss, gparams, ghead, dx
 
-    mapped = compat_shard_map(
+    mapped = manual_shard_map(
         per_device, mesh=mesh,
         in_specs=(jax.tree_util.tree_map(lambda _: P("pp"), re),
                   jax.tree_util.tree_map(lambda _: P(), head_params),

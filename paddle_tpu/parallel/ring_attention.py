@@ -23,14 +23,12 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
-from paddle_tpu.parallel.pipeline import compat_shard_map
+from paddle_tpu.parallel.pipeline import manual_shard_map
 
 
 def _ring_attention_local(q, k, v, axis: str, causal: bool, scale):
     """Per-device body. q/k/v: [b, s_local, h, d] local shards."""
-    from paddle_tpu.parallel.pipeline import axis_size
-
-    n = axis_size(axis)
+    n = lax.axis_size(axis)
     idx = lax.axis_index(axis)
     b, sq, h, d = q.shape
     scale = scale or (1.0 / math.sqrt(d))
@@ -87,7 +85,7 @@ def ring_attention(q, k, v, mesh: Mesh, axis: str = "sp",
     body = partial(_ring_attention_local, axis=axis, causal=causal,
                    scale=scale)
     spec = P(None, axis, None, None)
-    mapped = compat_shard_map(
+    mapped = manual_shard_map(
         body, mesh=mesh,
         in_specs=(spec, spec, spec),
         out_specs=spec,

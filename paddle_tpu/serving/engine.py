@@ -120,7 +120,10 @@ from paddle_tpu.serving.kv_cache import (
     KVCachePool, OffloadRecord, SCRATCH_PAGE,
 )
 from paddle_tpu.serving.metrics import EngineMetrics
-from paddle_tpu.serving.model_runner import PagedModelRunner, runner_for
+from paddle_tpu.serving.model_runner import (
+    PagedModelRunner, UnrecoverableStepError, require_live_pools,
+    runner_for,
+)
 from paddle_tpu.serving.resilience import QueueFullError, audit_engine
 from paddle_tpu.serving.scheduler import (
     FCFSScheduler, Request, RequestState, SamplingParams,
@@ -1164,7 +1167,10 @@ class ServingEngine:
                 logits, new_pools = self.runner.prefill_chunk(
                     chunk, start, table, self.pool.pools)
                 break
+            except UnrecoverableStepError:
+                raise
             except Exception:
+                require_live_pools(self.pool.pools)
                 if attempt >= self.max_step_retries:
                     self._finish_abnormal(req, "error")
                     return None
@@ -1316,7 +1322,10 @@ class ServingEngine:
                     logits, new_pools = self.runner.ragged_step(
                         tokens, tables, starts, qlens, self.pool.pools)
                 break
+            except UnrecoverableStepError:
+                raise
             except Exception:
+                require_live_pools(self.pool.pools)
                 if attempts < self.max_step_retries:
                     attempts += 1
                     self.metrics.step_retries.inc()
@@ -1647,7 +1656,10 @@ class ServingEngine:
                 packed, new_pools = self.runner.decode_multi_spec(
                     tokens, tables, pos, self.pool.pools, drafts, **kw)
                 break
+            except UnrecoverableStepError:
+                raise
             except Exception:
+                require_live_pools(self.pool.pools)
                 if attempts < self.max_step_retries:
                     attempts += 1
                     self.metrics.step_retries.inc()
@@ -1911,7 +1923,10 @@ class ServingEngine:
                 packed, new_pools = self.runner.decode_multi(
                     tokens, tables, pos, self.pool.pools, s, **ctx)
                 break
+            except UnrecoverableStepError:
+                raise
             except Exception:
+                require_live_pools(self.pool.pools)
                 if attempts < self.max_step_retries:
                     attempts += 1
                     self.metrics.step_retries.inc()
@@ -2031,7 +2046,10 @@ class ServingEngine:
                 logits, new_pools = self.runner.decode(tokens, tables, pos,
                                                        self.pool.pools)
                 break
+            except UnrecoverableStepError:
+                raise
             except Exception:
+                require_live_pools(self.pool.pools)
                 if attempts < self.max_step_retries:
                     attempts += 1
                     self.metrics.step_retries.inc()
@@ -2112,7 +2130,10 @@ class ServingEngine:
                 grid = self._timed_drain(lambda: greedy_grid(inf.result))
             else:
                 drained = self._timed_drain(lambda: _to_host(inf.result))
+        except UnrecoverableStepError:
+            raise
         except Exception:
+            require_live_pools(inf.prev_pools)
             self.metrics.step_retries.inc()
             self._sleep(self.retry_backoff_s)
             self.pool.pools = inf.prev_pools

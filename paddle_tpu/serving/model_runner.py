@@ -143,6 +143,34 @@ SCALE_SUFFIX = "::scale"
 WEIGHT_DTYPES = ("fp32", "int8", "int4", "fp8")
 
 
+class UnrecoverableStepError(RuntimeError):
+    """A step failure that retrying cannot cure. The engine's recovery
+    loops treat every other exception as a transient device fault
+    (retry, then quarantine a request); these pass through them and
+    stop the serve."""
+
+
+class StepCompileError(UnrecoverableStepError):
+    """The first call of a newly built jit-cache entry failed: the step
+    could not be traced or compiled for this backend, so every retry and
+    every other request would fail the same way."""
+
+
+class DonatedPoolError(UnrecoverableStepError):
+    """A failed launch had already been given the KV pools by donation
+    (TPU): their buffers are deleted and no retry can resubmit them."""
+
+
+def require_live_pools(pools) -> None:
+    """Raise DonatedPoolError if any pool buffer has been deleted — the
+    check every retry makes before it resubmits the pools it kept."""
+    if any(a.is_deleted() for a in jax.tree_util.tree_leaves(pools)
+           if isinstance(a, jax.Array)):
+        raise DonatedPoolError(
+            "the failed step was given the KV pools by donation; their "
+            "buffers are deleted and the step cannot be retried")
+
+
 def bucket_len(t: int, minimum: int = 8) -> int:
     """Power-of-2 length bucket — the ONE bucket rule every step path
     shares (prefill, chunked prefill, the fused ragged step): compile
@@ -169,14 +197,14 @@ def _shard_mapped_kernel(kernel, shard_ctx, q_spec, rest_specs=()):
     explicit specs for leading trailing args (ISSUE 9: the per-page
     scale pools shard on their kv-head axis); unlisted trailing args
     ride replicated."""
-    from paddle_tpu.parallel.pipeline import compat_shard_map
+    from paddle_tpu.parallel.pipeline import manual_shard_map
 
     mesh, model_axis = shard_ctx
     pool_spec = P(None, None, model_axis, None)
 
     def run(q, k_pool, v_pool, tables, pos_q, *rest):
         extra = tuple(rest_specs) + (P(),) * (len(rest) - len(rest_specs))
-        return compat_shard_map(
+        return manual_shard_map(
             kernel, mesh=mesh,
             in_specs=(q_spec, pool_spec, pool_spec, P(), P()) + extra,
             out_specs=q_spec,
@@ -202,7 +230,7 @@ def paged_attend(q, k_new, v_new, layer_pools, tables, write_page,
     write_page/write_off: [B, T] int32; pos_q: [B] context position of q
     row 0; q_len: [B] live rows per span (rows past it are padding).
     impl is the statically-resolved attention path ("reference" |
-    "paged_decode" | "ragged" — PagedModelRunner._attn_impl_for), baked
+    "ragged" — PagedModelRunner._attn_impl_for), baked
     per jit entry. shard_ctx = (mesh, model_axis) on a sharded runner
     (ISSUE 7): the kernels then run per-shard via shard_map on each
     shard's kv-head slice; the gather reference path needs no wrapper —
@@ -243,21 +271,6 @@ def paged_attend(q, k_new, v_new, layer_pools, tables, write_page,
         v_pool = v_pool.at[write_page, write_off].set(v_new)
         out_pools = (k_pool, v_pool)
     B, T = q.shape[0], q.shape[1]
-    if impl == "paged_decode":
-        from paddle_tpu.ops.pallas.paged_attention import \
-            paged_decode_attention
-
-        if quantized or str(k_pool.dtype).startswith("float8"):
-            # dispatch never routes int8/fp8 pools here
-            raise ValueError("paged_decode has no int8/fp8-pool path — "
-                             "_attn_impl_for routes quantized pools to "
-                             "the ragged kernel or the gather reference")
-        fn = paged_decode_attention
-        if shard_ctx is not None:
-            fn = _shard_mapped_kernel(fn, shard_ctx,
-                                      P(None, shard_ctx[1], None))
-        out = fn(q[:, 0], k_pool, v_pool, tables, pos_q)
-        return out.reshape(B, 1, -1), out_pools
     if impl == "ragged":
         from paddle_tpu.ops.pallas.ragged_paged_attention import \
             ragged_paged_attention
@@ -312,7 +325,7 @@ class PagedModelRunner:
     head_dim: int
     vocab_size: int
 
-    ATTN_IMPLS = ("auto", "pallas", "ragged", "reference")
+    ATTN_IMPLS = ("auto", "ragged", "reference")
 
     def __init__(self, params: Dict[str, jnp.ndarray], block_size: int,
                  max_model_len: int, attn_impl: str = "auto",
@@ -500,7 +513,7 @@ class PagedModelRunner:
         shard WITH the reduction dim (each shard owns whole groups —
         shard() enforces the alignment) so the grouped epilogue runs
         in-shard BEFORE the reduce; fp8 weights cast in-shard."""
-        from paddle_tpu.parallel.pipeline import compat_shard_map
+        from paddle_tpu.parallel.pipeline import manual_shard_map
 
         axis = self.model_axis
         reduce_fn = self._layout.row_parallel_reduce()
@@ -516,7 +529,7 @@ class PagedModelRunner:
                 part = int4_matmul(x_local, w_local, s_local, g)
                 return reduce_fn(part, axis)
 
-            return compat_shard_map(
+            return manual_shard_map(
                 f4, mesh=self.mesh,
                 in_specs=(x_spec, P(axis, None), P(None, axis)),
                 out_specs=P(), axis_names=frozenset({axis}))(x, w, s)
@@ -525,7 +538,7 @@ class PagedModelRunner:
             part = x_local @ w_local.astype(x_local.dtype)
             return reduce_fn(part, axis)
 
-        out = compat_shard_map(
+        out = manual_shard_map(
             f, mesh=self.mesh, in_specs=(x_spec, P(axis, None)),
             out_specs=P(), axis_names=frozenset({axis}))(x, w)
         if s is not None:
@@ -543,7 +556,7 @@ class PagedModelRunner:
         Explicit shard_map for the same reason as _row_mm: GSPMD would
         insert its own fp32 all-gather. x rides in replicated (the
         column-parallel input contract)."""
-        from paddle_tpu.parallel.pipeline import compat_shard_map
+        from paddle_tpu.parallel.pipeline import manual_shard_map
 
         axis = self.model_axis
         gather_fn = self._layout.column_parallel_gather()
@@ -555,7 +568,7 @@ class PagedModelRunner:
                 part = x_local @ w_local.astype(x_local.dtype)
                 return gather_fn(part, axis)
 
-            return compat_shard_map(
+            return manual_shard_map(
                 f, mesh=self.mesh, in_specs=(P(), w_spec),
                 out_specs=P(), axis_names=frozenset({axis}))(x, w)
         if s.ndim == 2:
@@ -567,7 +580,7 @@ class PagedModelRunner:
                 part = int4_matmul(x_local, w_local, s_local, g)
                 return gather_fn(part, axis)
 
-            return compat_shard_map(
+            return manual_shard_map(
                 f4, mesh=self.mesh, in_specs=(P(), w_spec, P(axis, None)),
                 out_specs=P(), axis_names=frozenset({axis}))(x, w, s)
 
@@ -576,7 +589,7 @@ class PagedModelRunner:
                     ) * s_local.astype(x_local.dtype)
             return gather_fn(part, axis)
 
-        return compat_shard_map(
+        return manual_shard_map(
             f8, mesh=self.mesh, in_specs=(P(), w_spec, P(axis)),
             out_specs=P(), axis_names=frozenset({axis}))(x, w, s)
 
@@ -831,47 +844,27 @@ class PagedModelRunner:
         """Resolve the attention path for one (padded) query-span length.
 
         Static per jit entry — called at trace time, where the span
-        bucket and head layout are known. "auto" prefers the specialized
-        single-token paged-decode kernel for its exact shape, then the
-        ragged kernel (GQA, q_len > 1, mixed spans), then the gather
-        reference; "pallas"/"ragged" force kernels (interpret mode off
-        TPU); "reference" forces the gather oracle. The chosen impl is
-        logged once per bucket so a serve's dispatch is auditable."""
-        from paddle_tpu.ops.pallas.paged_attention import best_paged_impl
+        bucket and head layout are known. "auto" takes the ragged kernel
+        on a TPU (decode is its q_len == 1 case) and the gather
+        reference elsewhere; "ragged" forces the kernel (interpret mode
+        off TPU); "reference" forces the gather oracle. A head layout
+        the kernel cannot tile gives way to the reference with a
+        warning. The chosen impl is logged once per bucket so a serve's
+        dispatch is auditable."""
+        from paddle_tpu.ops.pallas.ragged_paged_attention import \
+            ragged_attention_ok
 
-        if self.attn_impl == "reference":
-            impl = "reference"
-        else:
-            best = best_paged_impl(self.head_dim, self.n_heads,
-                                   self.n_kv_heads, q_len_bucket)
-            if self.attn_impl == "ragged":
-                from paddle_tpu.ops.pallas.ragged_paged_attention import \
-                    ragged_attention_ok
-
-                impl = ("ragged" if ragged_attention_ok(
-                    self.head_dim, self.n_heads, self.n_kv_heads)
-                    else "reference")
-            elif self.attn_impl == "pallas":
-                impl = best or "reference"
-            else:          # auto: kernels on TPU, gather oracle on CPU
-                impl = (best or "reference"
-                        if jax.default_backend() == "tpu" else "reference")
-        if self.kv_dtype in ("int8", "fp8") and impl == "paged_decode":
-            # the single-token paged-decode kernel has no dequant/cast
-            # step; int8 and native-fp8 pools route to the ragged
-            # kernel (which dequantizes in its page walk) or the
-            # gather reference ("mixed" pools store fp32 — they keep
-            # the full dispatch)
-            from paddle_tpu.ops.pallas.ragged_paged_attention import \
-                ragged_attention_ok
-
-            impl = ("ragged" if ragged_attention_ok(
-                self.head_dim, self.n_heads, self.n_kv_heads)
-                else "reference")
+        want_kernel = (self.attn_impl == "ragged"
+                       or (self.attn_impl == "auto"
+                           and jax.default_backend() == "tpu"))
+        impl = ("ragged" if want_kernel and ragged_attention_ok(
+            self.head_dim, self.n_heads, self.n_kv_heads) else "reference")
         key = (q_len_bucket, impl)
         if key not in self._impl_logged:
             self._impl_logged.add(key)
-            logger.info(
+            logger.log(
+                logging.WARNING if want_kernel and impl == "reference"
+                else logging.INFO,
                 "serving attention impl: %s (q_len bucket %d, heads %d/%d, "
                 "head_dim %d, attn_impl=%s)", impl, q_len_bucket,
                 self.n_heads, self.n_kv_heads, self.head_dim, self.attn_impl)
@@ -910,7 +903,7 @@ class PagedModelRunner:
 
         per_page = self._kv_page_bytes()
         gather_pages = len(np.asarray(starts).reshape(-1)) * table_width
-        if impl in ("paged_decode", "ragged"):
+        if impl == "ragged":
             pages = int(attention_page_reads(starts, q_lens,
                                              self.block_size).sum())
         else:
@@ -1310,7 +1303,22 @@ class PagedModelRunner:
         else:
             jitted = jax.jit(fn, donate_argnums=donate,
                              static_argnums=static)
-        self._jit_cache[key] = jitted
+
+        def first_call(*args):
+            # tracing and compilation happen here, not at jax.jit above:
+            # a failure is the program's, not a transient device fault
+            try:
+                out = jitted(*args)
+            except Exception as e:
+                self._jit_cache.pop(key, None)
+                raise StepCompileError(
+                    f"serving step {kind} key={shape_key} failed on its "
+                    f"first call (trace/compile): {e}") from e
+            if key in self._jit_cache:
+                self._jit_cache[key] = jitted
+            return out
+
+        self._jit_cache[key] = first_call
         logger.info("serving jit compile %s key=%s (cache entries: %d)",
                     kind, shape_key, len(self._jit_cache))
         cap = int(os.environ.get("PADDLE_TPU_MAX_JIT_CACHE", "0") or "0")
@@ -1320,7 +1328,7 @@ class PagedModelRunner:
                 logger.warning(
                     "serving jit cache over PADDLE_TPU_MAX_JIT_CACHE=%d; "
                     "evicting %s", cap, evicted)
-        return jitted
+        return first_call
 
     def prefill(self, tokens: List[int], table_row: List[int], pools):
         """Run one sequence's (re-)prefill; returns (last_logits[V], pools)."""

@@ -55,8 +55,6 @@ def roofline_estimate(fn: Callable, *args, spec: Optional[DeviceSpec] = None,
     spec = spec or DeviceSpec.current()
     jitted = fn if hasattr(fn, "lower") else jax.jit(fn)
     cost = jitted.lower(*args, **kwargs).compile().cost_analysis()
-    if isinstance(cost, (list, tuple)):    # older jax returns [dict]
-        cost = cost[0] if cost else {}
     flops = float(cost.get("flops", 0.0))
     bytes_ = float(cost.get("bytes accessed", 0.0))
     t_flops = flops / spec.peak_flops

@@ -1,8 +1,9 @@
 """Test configuration: force an 8-device virtual CPU mesh.
 
-The driver's real-TPU runs use bench.py / __graft_entry__.py; unit tests run
-on the XLA CPU backend with 8 virtual devices (SURVEY.md §4: "strictly better
-than the reference's fake-device story").
+Unit tests run on the XLA CPU backend with 8 virtual devices (SURVEY.md §4:
+"strictly better than the reference's fake-device story"); the chip is
+driven by chip_smoke.py and by the `tpu`-marked tier of test_tpu_hw.py
+(PADDLE_TPU_TESTS=1), which leaves JAX on its default backend.
 """
 
 import os
@@ -11,27 +12,12 @@ import pytest
 
 TPU_MODE = os.environ.get("PADDLE_TPU_TESTS") == "1"
 
-os.environ.setdefault("JAX_NUM_CPU_DEVICES", "8")
-if not TPU_MODE:
-    # jax < 0.5 has no jax_num_cpu_devices config option; the XLA flag is
-    # the portable spelling and must be set before the CPU client exists
-    _flags = os.environ.get("XLA_FLAGS", "")
-    if "xla_force_host_platform_device_count" not in _flags:
-        os.environ["XLA_FLAGS"] = (
-            _flags + " --xla_force_host_platform_device_count=8").strip()
-
 import jax
 
 if not TPU_MODE:
     # must happen before the CPU client is instantiated
-    try:
-        jax.config.update("jax_num_cpu_devices", 8)
-    except AttributeError:  # older jax: XLA_FLAGS fallback above applies
-        pass
-    try:
-        jax.config.update("jax_platforms", "cpu")
-    except Exception:
-        pass
+    jax.config.update("jax_num_cpu_devices", 8)
+    jax.config.update("jax_platforms", "cpu")
 
 import paddle_tpu  # noqa: E402
 

@@ -1,0 +1,130 @@
+"""The kernels of the main path, compiled for a TPU v5e that is described
+and not attached (on-chip-measurement guide, section 2), at GPT-2 124M
+widths: 12 heads x 64, sequence 1024, pages of 16.
+
+Nothing runs, so this says nothing about results or times; it raises what
+the chip's compiler would raise (an unparsable contraction, a block the
+tiling refuses, more scoped VMEM than the chip has), which interpret mode
+cannot show. About two seconds a case."""
+
+import os
+
+import pytest
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")
+
+import jax  # noqa: E402
+import jax.numpy as jnp  # noqa: E402
+from jax.sharding import SingleDeviceSharding  # noqa: E402
+
+N_HEADS, HEAD_DIM, SEQ, BATCH, PAGE = 12, 64, 1024, 8, 16
+NUM_PAGES = 1024
+# chip_smoke.py's serve phase draws prompts of 128..512 tokens, prefilled
+# whole: 512 is its widest prefill bucket
+PREFILL_BUCKET = 512
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    """One device of a described v5e 2x2, persistent compile cache off (a
+    compile for a described device is written to it but cannot be read
+    back without a chip)."""
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no libtpu here: nothing to compile with
+        pytest.skip(f"cannot describe a v5e topology: {e}")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _ragged(dtype, B, T):
+    from paddle_tpu.ops.pallas.ragged_paged_attention import \
+        ragged_paged_attention
+
+    def fn(q, kp, vp, table, start, qlen):
+        return ragged_paged_attention(q, kp, vp, table, start, qlen,
+                                      interpret=False)
+
+    pool = ((NUM_PAGES, PAGE, N_HEADS, HEAD_DIM), dtype)
+    return fn, [((B, T, N_HEADS, HEAD_DIM), dtype), pool, pool,
+                ((B, SEQ // PAGE), jnp.int32), ((B,), jnp.int32),
+                ((B,), jnp.int32)]
+
+
+def _flash(dtype, backward, mode="dense"):
+    """mode: the mask forms chip_smoke's kernel phase validates at batch
+    8 — "padbias" (a [b, 1, 1, sk] key-padding mask, streamed as a per-key
+    bias) and "segments" (packed-sequence ids) ride per-batch-row vectors
+    whose blocks broke the TPU block rule at every batch but 1."""
+    from paddle_tpu.ops.pallas.flash_attention import flash_attention
+
+    def fwd(q, k, v):
+        kw = {}
+        if mode == "padbias":
+            kw["mask"] = jnp.zeros((BATCH, 1, 1, SEQ), jnp.float32)
+        if mode == "segments":
+            kw["segment_ids"] = jnp.zeros((BATCH, SEQ), jnp.int32)
+        return flash_attention(q, k, v, causal=mode != "padbias",
+                               interpret=False, **kw)
+
+    def fwd_bwd(q, k, v):
+        return jax.grad(lambda *a: jnp.sum(fwd(*a).astype(jnp.float32) ** 2),
+                        argnums=(0, 1, 2))(q, k, v)
+
+    return (fwd_bwd if backward else fwd), \
+        [((BATCH, SEQ, N_HEADS, HEAD_DIM), dtype)] * 3
+
+
+def _auto_decode_kernel(monkeypatch):
+    """Whatever attn_impl="auto" resolves to on a TPU for a GPT-2 decode
+    step (q_len bucket 1): the test steers the backend question, the
+    runner answers it."""
+    import paddle_tpu as paddle
+    from paddle_tpu.models.gpt import GPT, GPTConfig
+    from paddle_tpu.serving.model_runner import GPTRunner
+
+    paddle.seed(0)
+    runner = GPTRunner(GPT(GPTConfig(num_layers=1, vocab_size=128)),
+                       block_size=PAGE, max_model_len=SEQ)
+    assert (runner.n_heads, runner.n_kv_heads, runner.head_dim) == (
+        N_HEADS, N_HEADS, HEAD_DIM)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    impl = runner._attn_impl_for(1)
+    assert impl == "ragged", f"auto on a TPU resolved to {impl!r}"
+    return _ragged(jnp.float32, BATCH, 1)
+
+
+CASES = {
+    "ragged-fp32-decode": lambda mp: _ragged(jnp.float32, BATCH, 1),
+    "ragged-bf16-decode": lambda mp: _ragged(jnp.bfloat16, BATCH, 1),
+    "ragged-fp32-prefill": lambda mp: _ragged(jnp.float32, 1,
+                                              PREFILL_BUCKET),
+    "ragged-bf16-prefill": lambda mp: _ragged(jnp.bfloat16, 1,
+                                              PREFILL_BUCKET),
+    "ragged-fp32-longest-prompt": lambda mp: _ragged(jnp.float32, 1, SEQ),
+    "flash-fp32-fwd": lambda mp: _flash(jnp.float32, False),
+    "flash-bf16-fwd": lambda mp: _flash(jnp.bfloat16, False),
+    "flash-fp32-fwd-bwd": lambda mp: _flash(jnp.float32, True),
+    "flash-bf16-fwd-bwd": lambda mp: _flash(jnp.bfloat16, True),
+    "flash-fp32-padbias-fwd-bwd": lambda mp: _flash(jnp.float32, True,
+                                                    "padbias"),
+    "flash-fp32-segments-fwd-bwd": lambda mp: _flash(jnp.float32, True,
+                                                     "segments"),
+    "auto-decode-12x64": _auto_decode_kernel,
+}
+
+
+@pytest.mark.parametrize("case", sorted(CASES))
+def test_kernel_compiles_for_v5e(case, v5e, monkeypatch):
+    fn, shapes = CASES[case](monkeypatch)
+    args = [jax.ShapeDtypeStruct(s, d, sharding=v5e) for s, d in shapes]
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()

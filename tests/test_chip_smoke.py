@@ -1,0 +1,109 @@
+"""chip_smoke.py rehearsed on the CPU: its phases at toy width (the test
+steers the size — the script has no option for it), the four-chip phase on
+four virtual devices, and the contract that without a chip, or with any
+phase failing, no result line is printed."""
+
+import importlib.util
+import os
+
+import pytest
+
+import paddle_tpu as paddle
+from paddle_tpu.models.gpt import GPT, GPTConfig
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+_spec = importlib.util.spec_from_file_location(
+    "chip_smoke", os.path.join(REPO, "chip_smoke.py"))
+chip_smoke = importlib.util.module_from_spec(_spec)
+_spec.loader.exec_module(chip_smoke)
+
+SEQ, BATCH = 128, 2
+
+
+def toy_model(tensor_parallel: bool = False):
+    paddle.seed(chip_smoke.SEED)
+    return GPT(GPTConfig(vocab_size=256, hidden_size=64, num_layers=2,
+                         num_heads=4, max_seq_len=SEQ,
+                         tensor_parallel=tensor_parallel))
+
+
+def _kernels():
+    chip_smoke.phase_kernels(4, 16, SEQ, BATCH, interpret=True)
+
+
+def _train():
+    chip_smoke.phase_train(toy_model(), BATCH, SEQ, kernels_required=False)
+
+
+def _serve():
+    # the ragged kernel in interpret mode: the "never the reference"
+    # check is live on the CPU too
+    chip_smoke.phase_serve(toy_model(), n_requests=3, prompt_range=(8, 24),
+                           max_tokens=6, num_blocks=64, attn_impl="ragged")
+
+
+def _sharded():
+    chip_smoke.phase_sharded(toy_model, 4, SEQ)
+
+
+PHASES = {"kernels": _kernels, "train": _train, "serve": _serve,
+          "sharded_on_four_virtual_devices": _sharded}
+
+
+@pytest.mark.parametrize("phase", sorted(PHASES))
+def test_phase_passes_at_toy_width(phase, capsys):
+    PHASES[phase]()
+    assert f"[{phase.split('_')[0]}" in capsys.readouterr().out
+
+
+def test_main_fails_on_cpu_and_prints_no_result(capsys):
+    with pytest.raises(chip_smoke.SmokeFailure, match="platform"):
+        chip_smoke.main([])
+    assert '"ok"' not in capsys.readouterr().out
+
+
+def test_four_chip_option_needs_four_chips(monkeypatch, capsys):
+    monkeypatch.setattr(chip_smoke, "phase_device",
+                        lambda platform, count: chip_smoke.check(
+                            count == 1, "need 4 device(s)"))
+    with pytest.raises(chip_smoke.SmokeFailure, match="4 device"):
+        chip_smoke.main(["--chips", "4"])
+    assert '"ok"' not in capsys.readouterr().out
+
+
+@pytest.mark.parametrize("failing", ["kernels", "train", "serve"])
+def test_a_failed_phase_stops_the_run(failing, monkeypatch, capsys):
+    """main() with the device phase let through and one phase failing: the
+    failure leaves main, later phases do not run, no result line."""
+    ran = []
+
+    def stub(name):
+        def phase(*a, **kw):
+            ran.append(name)
+            chip_smoke.check(name != failing, f"{name} failed")
+        return phase
+
+    monkeypatch.setattr(chip_smoke, "phase_device", lambda *a: {
+        "platform": "tpu", "kind": "stub", "count": 1})
+    for name in ("kernels", "train", "serve"):
+        monkeypatch.setattr(chip_smoke, f"phase_{name}", stub(name))
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/nonexistent")
+    with pytest.raises(chip_smoke.SmokeFailure, match=f"{failing} failed"):
+        chip_smoke.main([])
+    assert ran[-1] == failing
+    assert '"ok"' not in capsys.readouterr().out
+
+
+def test_train_phase_checks_bite(monkeypatch):
+    """A check inside a real phase fails the phase: with a zero band around
+    ln(vocab) the toy model's first loss is out of it."""
+    monkeypatch.setattr(chip_smoke, "FIRST_LOSS_TOL", 0.0)
+    with pytest.raises(chip_smoke.SmokeFailure, match="first loss"):
+        _train()
+
+
+def test_serve_phase_rejects_the_reference_path():
+    with pytest.raises(chip_smoke.SmokeFailure, match="gave way"):
+        chip_smoke.phase_serve(toy_model(), n_requests=1,
+                               prompt_range=(8, 8), max_tokens=2,
+                               num_blocks=16, attn_impl="reference")

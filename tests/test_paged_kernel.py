@@ -1,4 +1,5 @@
-"""Pallas paged-decode attention kernel vs the gather+dense oracle.
+"""Single-token decode through the ragged paged-attention kernel (its
+q_len == 1 case) vs the gather+dense oracle.
 
 The PagedGPTGenerator greedy-identical tests (test_parallel_generation)
 are the end-to-end oracle; these pin the kernel itself: shuffled block
@@ -11,9 +12,15 @@ import pytest
 from paddle_tpu.models.generation import (
     masked_cache_attention, paged_gather,
 )
-from paddle_tpu.ops.pallas.paged_attention import (
-    paged_decode_attention, paged_decode_ok,
+from paddle_tpu.ops.pallas.ragged_paged_attention import (
+    ragged_attention_ok, ragged_paged_attention,
 )
+
+
+def paged_decode_attention(q, kp, vp, tbl, pos, interpret=True):
+    """[b, h, d] decode queries as one-row ragged spans."""
+    return ragged_paged_attention(q[:, None], kp, vp, tbl, pos, 1,
+                                  interpret=interpret)[:, 0]
 
 rng = np.random.default_rng(3)
 
@@ -63,24 +70,29 @@ def test_shared_pages_across_sequences():
 
 
 def test_tiling_gate():
-    assert paged_decode_ok(64) and paged_decode_ok(8)
-    assert not paged_decode_ok(65)
+    assert ragged_attention_ok(64, 4, 4) and ragged_attention_ok(8, 4, 4)
+    assert not ragged_attention_ok(65, 4, 4)
 
 
 def test_block_mha_routes_to_kernel(monkeypatch):
     """block_multihead_attention must take the kernel path for t=1."""
+    import importlib
+
     import paddle_tpu.models.generation as gen
-    import paddle_tpu.ops.pallas.paged_attention as pa
+
+    # the package re-exports the function under the module's own name
+    pa = importlib.import_module(
+        "paddle_tpu.ops.pallas.ragged_paged_attention")
 
     called = {}
-    orig = pa.paged_decode_attention
+    orig = pa.ragged_paged_attention
 
     def spy(*a, **kw):
         called["yes"] = True
         kw["interpret"] = True
         return orig(*a, **kw)
 
-    monkeypatch.setattr(pa, "paged_decode_attention", spy)
+    monkeypatch.setattr(pa, "ragged_paged_attention", spy)
     q, kp, vp, tbl = _pools()
     out = gen.block_multihead_attention(q[:, None], kp, vp, tbl, 10)
     assert called.get("yes"), "paged kernel not dispatched for t=1"
@@ -92,9 +104,6 @@ def test_dead_pages_do_not_change_output():
     sequence content in a 4x pool (extra dead pages past pos) gives a
     bit-identical result — the dead grid steps fold nothing in and their
     clamped DMA revisits the last live page."""
-    import jax.numpy as jnp
-    from paddle_tpu.ops.pallas.paged_attention import paged_decode_attention
-
     rng = np.random.default_rng(5)
     b, h, d, bs = 2, 4, 64, 8
     pos = jnp.asarray([9, 21], jnp.int32)

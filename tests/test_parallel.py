@@ -99,11 +99,11 @@ def test_in_jit_collectives(mesh2x2x2):
     from paddle_tpu.parallel import collective as C
 
     mesh = dist.current_mesh()
-    from paddle_tpu.parallel.pipeline import compat_shard_map
+    from paddle_tpu.parallel.pipeline import manual_shard_map
 
     x = jnp.arange(8.0).reshape(8, 1)
 
-    f = compat_shard_map(lambda a: C.psum(a, "dp"), mesh=mesh,
+    f = manual_shard_map(lambda a: C.psum(a, "dp"), mesh=mesh,
                          in_specs=P("dp"), out_specs=P(),
                          axis_names=frozenset({"dp"}))
     out = f(x)

@@ -16,9 +16,8 @@ import jax.numpy as jnp
 
 import paddle_tpu as paddle
 from paddle_tpu.models.generation import masked_cache_attention, paged_gather
-from paddle_tpu.ops.pallas.paged_attention import best_paged_impl
 from paddle_tpu.ops.pallas.ragged_paged_attention import (
-    attention_page_reads, ragged_attention_ok, ragged_paged_attention,
+    Q_TILE, attention_page_reads, ragged_attention_ok, ragged_paged_attention,
     ragged_reference,
 )
 
@@ -53,6 +52,23 @@ def test_kernel_vs_reference_sweep(q_len, start_pos, n_rep):
     ref = ragged_reference(q, kp, vp, tbl, starts, qlens)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("n_rep", [1, 2])
+def test_tiled_span_vs_reference(n_rep):
+    """A span of several Q_TILE row tiles: each tile walks only the pages
+    its own last row can see, a tile past q_len is dead (exact zeros),
+    and the result equals the one-tile reference."""
+    T = 2 * Q_TILE
+    q, kp, vp, tbl = _pools(B=2, pages=40, T=T, n_rep=n_rep)
+    starts = jnp.asarray([3, 17], jnp.int32)
+    qlens = jnp.asarray([T, Q_TILE - 5], jnp.int32)   # slot 1: tile 1 dead
+    out = ragged_paged_attention(q, kp, vp, tbl, starts, qlens,
+                                 interpret=True)
+    ref = ragged_reference(q, kp, vp, tbl, starts, qlens)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               rtol=1e-5, atol=1e-5)
+    assert bool((np.asarray(out[1, Q_TILE - 5:]) == 0.0).all())
 
 
 def test_matches_gather_masked_cache_attention():
@@ -149,13 +165,6 @@ def test_dispatch_gate_learns_new_capabilities():
     assert ragged_attention_ok(8, 4, 4)
     assert not ragged_attention_ok(65, 8, 2)       # lane misalignment
     assert not ragged_attention_ok(64, 7, 2)       # uneven grouping
-    # the specialized decode kernel keeps its exact shape; everything
-    # else (GQA, q_len > 1) now resolves to the ragged kernel
-    assert best_paged_impl(64, 8, 8, q_len=1) == "paged_decode"
-    assert best_paged_impl(64, 8, 2, q_len=1) == "ragged"
-    assert best_paged_impl(64, 8, 8, q_len=16) == "ragged"
-    assert best_paged_impl(64, 8, 2, q_len=16) == "ragged"
-    assert best_paged_impl(65, 8, 8, q_len=16) is None
 
 
 def test_runner_resolves_and_logs_impl_once_per_bucket(caplog):
@@ -178,14 +187,9 @@ def test_runner_resolves_and_logs_impl_once_per_bucket(caplog):
     lines = [r for r in caplog.records
              if "serving attention impl" in r.getMessage()]
     assert len(lines) == 2          # once per bucket, not per call
-    # auto on CPU stays on the gather oracle; forced pallas prefers the
-    # specialized decode kernel only for its exact MHA shape
+    # auto on CPU stays on the gather oracle
     auto = LlamaRunner(Llama(cfg), block_size=8, max_model_len=32)
     assert auto._attn_impl_for(1) == "reference"
-    forced = LlamaRunner(Llama(cfg), block_size=8, max_model_len=32,
-                         attn_impl="pallas")
-    assert forced._attn_impl_for(1) == "ragged"      # GQA: not decode-ok
-    assert forced._attn_impl_for(16) == "ragged"
 
 
 # ------------------------------------------------------- serving end-to-end

@@ -269,7 +269,7 @@ def test_engine_pallas_decode_path_matches_reference():
     model = Llama(cfg)
     model.eval()
     r_pallas = LlamaRunner(model, block_size=8, max_model_len=32,
-                           attn_impl="pallas")
+                           attn_impl="ragged")
     r_ref = LlamaRunner(model, block_size=8, max_model_len=32,
                         attn_impl="reference")
     eng = ServingEngine(r_pallas, num_blocks=12, max_batch_size=2,

@@ -34,7 +34,7 @@ from paddle_tpu.ops.pallas.ragged_paged_attention import (
     ragged_paged_attention, ragged_reference,
 )
 from paddle_tpu.parallel.mesh import serving_mesh
-from paddle_tpu.parallel.pipeline import compat_shard_map
+from paddle_tpu.parallel.pipeline import manual_shard_map
 from paddle_tpu.quantization.qcomm import (
     allreduce_bytes, quantized_allreduce_reference, quantized_psum,
 )
@@ -93,7 +93,7 @@ def _psum_shard_map(mesh, fn_reduce, chunk=None):
     def run(parts):
         stacked = jnp.asarray(np.stack(parts))      # [S, ...]
         spec = P(*(("model",) + (None,) * (stacked.ndim - 1)))
-        return compat_shard_map(
+        return manual_shard_map(
             f, mesh=mesh, in_specs=(spec,), out_specs=P(),
             axis_names=frozenset({"model"}))(stacked)
 

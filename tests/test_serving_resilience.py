@@ -240,6 +240,86 @@ def test_transient_prefill_fault_recovers_exactly():
     assert eng.metrics.step_retries.value == 1
 
 
+# ------------------------------- what a retry cannot cure leaves the engine
+
+
+@pytest.mark.parametrize("kind", ["prefill", "decode"])
+def test_first_call_failure_of_a_new_jit_entry_leaves_the_engine(
+        llama_setup, kind):
+    """A step that cannot be traced/compiled is not a transient device
+    fault: the first call of a newly built jit-cache entry raises
+    StepCompileError through the retry loops, instead of every request
+    being answered with "error"."""
+    from paddle_tpu.serving.model_runner import StepCompileError
+
+    runner = llama_setup()
+
+    def refused(*a, **kw):
+        raise NotImplementedError("the compiler refuses this step")
+
+    setattr(runner, f"_{kind}_step", refused)
+    eng = ServingEngine(runner, num_blocks=10, max_batch_size=2,
+                        max_model_len=64, retry_backoff_s=0.0)
+    eng.add_request([3, 1, 4, 1, 5], SamplingParams(max_tokens=4))
+    with pytest.raises(StepCompileError, match="compiler refuses"):
+        eng.run()
+    assert eng.metrics.step_retries.value == 0
+    assert not eng.outputs()           # nobody was told "error"
+    assert not any(k[0] == kind for k in runner._jit_cache)
+
+
+def test_warm_step_fault_is_still_retried(llama_setup):
+    """The same exception from an entry that has already run once is a
+    device fault as before: retried, token-exact."""
+    runner = llama_setup()
+    eng = ServingEngine(runner, num_blocks=10, max_batch_size=2,
+                        max_model_len=64, retry_backoff_s=0.0)
+    sp = SamplingParams(max_tokens=5)
+    rid = eng.add_request([3, 1, 4, 1, 5], sp)
+    eng.step()                          # prefill
+    eng.step()                          # first decode: entry is warm now
+    key = ("decode", 2)
+    warm = runner._jit_cache[key]
+    calls = []
+
+    def flaky(*a):
+        calls.append(1)
+        if len(calls) == 1:
+            raise RuntimeError("transient device fault")
+        return warm(*a)
+
+    runner._jit_cache[key] = flaky
+    outs = eng.run()
+    assert eng.metrics.step_retries.value == 1
+    assert outs[rid].finish_reason == "length"
+    assert outs[rid].output_tokens == naive_generate(
+        runner, [3, 1, 4, 1, 5], sp, max_model_len=64)
+
+
+def test_retry_never_resubmits_donated_pools():
+    """On a TPU the pools are donated to the launch. A fault after the
+    launch took them leaves deleted buffers; the retry must not hand
+    those back to the runner (every attempt would fail and every request
+    would end in "error") — it raises DonatedPoolError."""
+    import jax
+
+    from paddle_tpu.serving.model_runner import DonatedPoolError
+
+    runner = StubPagedRunner(block_size=4, max_model_len=32)
+    eng = ServingEngine(runner, num_blocks=16, max_batch_size=2,
+                        max_model_len=32, retry_backoff_s=0.0)
+    eng.add_request([5, 6, 7], SamplingParams(max_tokens=6))
+    eng.step()
+    eng.step()
+    # the next decode fails on the deleted buffers, as a launch that
+    # faulted after taking them would
+    for a in jax.tree_util.tree_leaves(eng.pool.pools):
+        a.delete()                      # what donation does to them
+    with pytest.raises(DonatedPoolError):
+        eng.run()
+    assert eng.metrics.step_retries.value == 0
+
+
 # ------------------------------------------------------------- NaN guards
 
 

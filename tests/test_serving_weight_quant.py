@@ -34,7 +34,7 @@ from jax.sharding import PartitionSpec as P
 import paddle_tpu as paddle
 from paddle_tpu.models.llama import Llama, LlamaConfig
 from paddle_tpu.parallel.mesh import serving_mesh
-from paddle_tpu.parallel.pipeline import compat_shard_map
+from paddle_tpu.parallel.pipeline import manual_shard_map
 from paddle_tpu.quantization.int4 import (
     INT4_QMAX, int4_dequantize, int4_dequantize_reference, int4_matmul,
     int4_quantize, int4_weight_bytes,
@@ -181,7 +181,7 @@ def _gather_shard_map(mesh, chunk):
     def run(parts):
         stacked = jnp.asarray(np.stack(parts))
         spec = P(*(("model",) + (None,) * (stacked.ndim - 1)))
-        return compat_shard_map(
+        return manual_shard_map(
             f, mesh=mesh, in_specs=(spec,), out_specs=P(),
             axis_names=frozenset({"model"}))(stacked)
 
