@@ -48,6 +48,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.sharding import PartitionSpec as P
 
 try:  # TPU-specific memory spaces (absent on pure-CPU builds)
     from jax.experimental.pallas import tpu as pltpu
@@ -738,5 +739,50 @@ def flash_attention(q, k, v, causal: bool = True, scale=None,
     if not _block_shapes_ok(q, k, block_q, block_k, v=v):
         _log_fallback(q, k, block_q, block_k)
         return _reference(q, k, v, causal, scale, mask, kbias, qseg, kseg)
-    return _flash(q, k, v, mask, kbias, qseg, kseg, block_mask, causal,
-                  scale, block_q, block_k, interpret)
+    statics = (causal, scale, block_q, block_k, interpret)
+    placed = _installed_mesh_axes(b, h)
+    if placed is None:
+        return _flash(q, k, v, mask, kbias, qseg, kseg, block_mask,
+                      *statics)
+    # GSPMD cannot partition a Mosaic kernel ("wrap the call in a
+    # shard_map"): under an installed mesh each device runs the kernel on
+    # its own batch rows ('dp') and heads ('tp'), every mesh axis manual
+    mesh, bax, hax = placed
+    qkv, row = P(bax, None, hax, None), P(bax, None)
+    extras = {n: x for n, x in (("mask", mask), ("kbias", kbias),
+                                ("qseg", qseg), ("kseg", kseg),
+                                ("block_mask", block_mask)) if x is not None}
+    specs = {"kbias": row, "qseg": row, "kseg": row, "block_mask": P()}
+    if mask is not None:
+        specs["mask"] = P(bax, hax if mask.shape[1] == h else None,
+                          None, None)
+
+    def per_shard(q, k, v, ex):
+        return _flash(q, k, v, ex.get("mask"), ex.get("kbias"),
+                      ex.get("qseg"), ex.get("kseg"), ex.get("block_mask"),
+                      *statics)
+
+    return jax.shard_map(
+        per_shard, mesh=mesh,
+        in_specs=(qkv, qkv, qkv, {n: specs[n] for n in extras}),
+        out_specs=qkv, check_vma=False,
+    )(q, k, v, extras)
+
+
+def _installed_mesh_axes(b: int, h: int):
+    """(mesh, batch axis, head axis) when a multi-device mesh is installed
+    (parallel.init_mesh) and the call is not already inside a manual
+    region; None otherwise. An axis is named only where it divides the
+    dimension; unnamed axes compute replicated."""
+    from paddle_tpu.parallel.mesh import current_mesh
+
+    mesh = current_mesh()
+    if (mesh is None or mesh.size == 1
+            or jax.sharding.get_abstract_mesh().manual_axes):
+        return None
+
+    def pick(axis, n):
+        return (axis if axis in mesh.axis_names
+                and n % mesh.shape[axis] == 0 else None)
+
+    return mesh, pick("dp", b), pick("tp", h)

@@ -27,11 +27,14 @@ import numpy as np
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
-# every manually-mapped region of the repo (pipeline schedules, ring
-# attention, the serving TP kernels) goes through this one spelling:
-# `axis_names` are the manual axes (the rest stay GSPMD-auto), and the
-# varying-manual-axes check is off
-manual_shard_map = partial(jax.shard_map, check_vma=False)
+def manual_shard_map(f, **kw):
+    """The one spelling of every manually-mapped region of the repo
+    (pipeline schedules, ring attention, the serving TP kernels):
+    jax.shard_map with the varying-manual-axes check off. `axis_names`
+    are the manual axes (the rest stay GSPMD-auto). Jitted, because JAX
+    0.9 refuses the eager call of a partially-manual map whose out_specs
+    leave an auto axis unnamed, and accepts the same map under jit."""
+    return jax.jit(jax.shard_map(f, check_vma=False, **kw))
 
 
 def varying(v, axis: str = "pp"):

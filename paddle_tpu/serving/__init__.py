@@ -71,9 +71,9 @@ paged-decode kernel (`ops/pallas/paged_attention.py`):
                    to byte-complete UTF-8 boundaries
                    (engine.stream_text(request_id));
   metrics.py       queue depth, TTFT, tokens/s, pool utilization,
-                   preemption counters for bench.py's serving sweep —
-                   plus the failure-side instruments (timeouts, aborts,
-                   step retries, NaN events, shed requests);
+                   preemption counters — plus the failure-side
+                   instruments (timeouts, aborts, step retries, NaN
+                   events, shed requests);
   resilience.py    the fault story (ISSUE 2): FaultInjector (simulated
                    device errors / NaN logits / clock stalls for tests
                    and drills), the invariant auditor (page + slot +
@@ -166,7 +166,7 @@ Entry points: `paddle_tpu.inference.create_serving_engine(model)` /
 `create_serving_router(model, replicas=N)` are the bridges from the
 Predictor world; `tools/serving_smoke.py` is a runnable demo;
 `tools/fault_smoke.py --router N` drills the tier fault classes;
-`bench.py --child serving:...` drives the offered-load sweeps.
+`chip_smoke.py` serves GPT-2 124M on the chip.
 """
 
 from paddle_tpu.serving.detokenize import (  # noqa: F401

@@ -96,6 +96,12 @@ raises for load- or fault-induced conditions:
                   was quarantined, or NaN/Inf logits under nan_policy
                   "abort" (or with no finite entry at all)
 
+One thing does leave step(): an UnrecoverableStepError — a step the
+backend's compiler refuses (StepCompileError, raised by the first call of
+a new jit-cache entry) or KV pools a failed launch had already been given
+by donation (DonatedPoolError). Retrying either fails the same way for
+every request, so the serve stops instead of answering all with "error".
+
 Transient runner failures retry with bounded exponential backoff;
 `snapshot()`/`restore()` serialize all request state for crash-safe
 relaunch (KV rebuilds through the recompute-on-resume path); the

@@ -164,7 +164,7 @@ def aggregate_snapshots(snaps) -> Dict[str, float]:
 
 
 class EngineMetrics:
-    """The engine's instrument panel, snapshot()-able for bench.py.
+    """The engine's instrument panel, read through snapshot().
 
     TTFT is measured from add_request() to the first sampled token of that
     request (admission wait + prefill), the number an offered-load sweep

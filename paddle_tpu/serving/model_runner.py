@@ -193,7 +193,10 @@ def _shard_mapped_kernel(kernel, shard_ctx, q_spec, rest_specs=()):
     SAME page ids over its own kv-head slice, so the kernel body is
     unchanged (GQA's n_rep is shard-invariant because n_heads and
     n_kv_heads divide by tp together). Pallas calls are opaque to GSPMD,
-    hence shard_map instead of a sharding annotation. `rest_specs` give
+    hence shard_map instead of a sharding annotation — over EVERY mesh
+    axis: the chip's compiler refuses a Mosaic kernel while any axis is
+    left to GSPMD, so the data axis is manual too and, being unnamed in
+    the specs, computes replicated. `rest_specs` give
     explicit specs for leading trailing args (ISSUE 9: the per-page
     scale pools shard on their kv-head axis); unlisted trailing args
     ride replicated."""
@@ -208,7 +211,6 @@ def _shard_mapped_kernel(kernel, shard_ctx, q_spec, rest_specs=()):
             kernel, mesh=mesh,
             in_specs=(q_spec, pool_spec, pool_spec, P(), P()) + extra,
             out_specs=q_spec,
-            axis_names=frozenset({model_axis}),
         )(q, k_pool, v_pool, tables, pos_q, *rest)
 
     return run

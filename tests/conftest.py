@@ -12,6 +12,9 @@ import pytest
 
 TPU_MODE = os.environ.get("PADDLE_TPU_TESTS") == "1"
 
+# children spawned by tests inherit the device count through the env
+os.environ.setdefault("JAX_NUM_CPU_DEVICES", "8")
+
 import jax
 
 if not TPU_MODE:

@@ -26,7 +26,7 @@ PREFILL_BUCKET = 512
 
 @pytest.fixture(scope="module")
 def v5e():
-    """One device of a described v5e 2x2, persistent compile cache off (a
+    """The devices of a described v5e 2x2, persistent compile cache off (a
     compile for a described device is written to it but cannot be read
     back without a chip)."""
     from jax.experimental import topologies
@@ -40,7 +40,7 @@ def v5e():
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
-    yield SingleDeviceSharding(topo.devices[0])
+    yield topo.devices
     jax.config.update("jax_enable_compilation_cache", was)
     compilation_cache.reset_cache()
 
@@ -125,6 +125,50 @@ CASES = {
 @pytest.mark.parametrize("case", sorted(CASES))
 def test_kernel_compiles_for_v5e(case, v5e, monkeypatch):
     fn, shapes = CASES[case](monkeypatch)
-    args = [jax.ShapeDtypeStruct(s, d, sharding=v5e) for s, d in shapes]
+    one_chip = SingleDeviceSharding(v5e[0])
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in shapes]
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_flash_compiles_per_shard_under_a_dp2_tp2_mesh(v5e):
+    """GSPMD cannot partition a Mosaic kernel: with a mesh installed
+    (parallel.init_mesh, the README's hybrid-parallel step) flash attention
+    must reach the compiler inside a shard_map, batch over dp, heads over
+    tp — the four-chip path of chip_smoke.py --chips 4."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from paddle_tpu.parallel.mesh import mesh_scope
+
+    fn, shapes = _flash(jnp.float32, True)
+    mesh = Mesh(np.asarray(v5e).reshape(2, 2), ("dp", "tp"))
+    spec = NamedSharding(mesh, P("dp", None, "tp", None))
+    args = [jax.ShapeDtypeStruct(s, d, sharding=spec) for s, d in shapes]
+    with mesh_scope(mesh):
+        compiled = jax.jit(fn).lower(*args).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    # each device's kernels see its own 4 batch rows x 6 heads
+    assert f"f32[{BATCH // 2 * N_HEADS // 2},{SEQ},{HEAD_DIM}]" in text
+
+
+def test_ragged_compiles_per_shard_under_a_model4_mesh(v5e):
+    """The tensor-parallel engine's kernel wrapper on serving_mesh(data=1,
+    model=4): three of GPT-2's twelve heads per chip. Every mesh axis has
+    to be manual — the compiler refuses the kernel while the (size-1)
+    data axis is left to GSPMD."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from paddle_tpu.serving.model_runner import _shard_mapped_kernel
+
+    kernel, shapes = _ragged(jnp.float32, BATCH, 1)
+    mesh = Mesh(np.asarray(v5e).reshape(1, 4), ("data", "model"))
+    heads = P(None, None, "model", None)
+    fn = _shard_mapped_kernel(kernel, (mesh, "model"), heads)
+    specs = [heads] * 3 + [P()] * 3
+    args = [jax.ShapeDtypeStruct(s, d, sharding=NamedSharding(mesh, sp))
+            for (s, d), sp in zip(shapes, specs)]
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()

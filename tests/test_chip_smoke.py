@@ -46,8 +46,15 @@ def _sharded():
     chip_smoke.phase_sharded(toy_model, 4, SEQ)
 
 
+def _sharded_serve():
+    chip_smoke.phase_sharded_serve(toy_model(), n_requests=3,
+                                   prompt_range=(8, 24), max_tokens=6,
+                                   num_blocks=64, attn_impl="ragged")
+
+
 PHASES = {"kernels": _kernels, "train": _train, "serve": _serve,
-          "sharded_on_four_virtual_devices": _sharded}
+          "sharded_on_four_virtual_devices": _sharded,
+          "sharded_serve_on_four_virtual_devices": _sharded_serve}
 
 
 @pytest.mark.parametrize("phase", sorted(PHASES))
@@ -107,3 +114,50 @@ def test_serve_phase_rejects_the_reference_path():
         chip_smoke.phase_serve(toy_model(), n_requests=1,
                                prompt_range=(8, 8), max_tokens=2,
                                num_blocks=16, attn_impl="reference")
+
+
+@pytest.mark.parametrize("tol,passes", [(float("inf"), True), (0.0, False)])
+def test_sharded_serve_judges_a_parting_by_the_logit_gap(
+        tol, passes, monkeypatch):
+    """Where the tensor-parallel stream leaves the one-device stream, the
+    one-device logits of the two tokens decide: inside the tolerance it is
+    a near-tie, outside it the phase fails."""
+    real = chip_smoke._serve
+
+    def serve(phase, *a, **kw):
+        eng, streams = real(phase, *a, **kw)
+        if phase.endswith("tp4"):
+            streams[0][2] = (streams[0][2] + 1) % 256
+        return eng, streams
+
+    monkeypatch.setattr(chip_smoke, "_serve", serve)
+    monkeypatch.setattr(chip_smoke, "NEAR_TIE_TOL", tol)
+    if passes:
+        _sharded_serve()
+    else:
+        with pytest.raises(chip_smoke.SmokeFailure, match="logit gap"):
+            _sharded_serve()
+
+
+@pytest.mark.parametrize("env_dir", ["/somewhere/outside", None])
+def test_compile_cache_is_placed_from_outside(env_dir, monkeypatch):
+    """JAX_COMPILATION_CACHE_DIR set: code sets nothing (JAX reads the
+    variable itself). Unset: `<checkout>/.jax_cache`, never a temporary
+    name — the path is part of the cache key."""
+    import jax
+
+    from paddle_tpu.utils.compile_cache import place_compile_cache
+
+    updates = []
+    monkeypatch.setattr(jax.config, "update",
+                        lambda k, v: updates.append((k, v)))
+    if env_dir is None:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        want = os.path.join(REPO, ".jax_cache")
+        assert place_compile_cache() == want
+        assert place_compile_cache() == want          # the same, always
+        assert updates == [("jax_compilation_cache_dir", want)] * 2
+    else:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", env_dir)
+        assert place_compile_cache() == env_dir
+        assert updates == []

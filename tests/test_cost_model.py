@@ -4,10 +4,29 @@ measured op-latency table (reference auto_parallel/static/cost/)."""
 import jax.numpy as jnp
 import numpy as np
 
+import pytest
+
 from paddle_tpu.utils.cost_model import (
-    CostEstimator, DeviceSpec, OpLatencyTable, comm_cost_ms,
+    DEVICE_SPECS, CostEstimator, DeviceSpec, OpLatencyTable, comm_cost_ms,
     roofline_estimate,
 )
+
+
+def test_device_spec_is_looked_up_by_device_kind(monkeypatch):
+    """Peaks come from one table keyed by device_kind; a kind that is not
+    in it raises instead of borrowing the v5e's numbers."""
+    import jax
+
+    assert DeviceSpec.current() is DEVICE_SPECS["cpu"]
+    v5e = DEVICE_SPECS["TPU v5 lite"]
+    assert (v5e.peak_flops, v5e.hbm_gbps) == (197e12, 819.0)
+
+    class Unknown:
+        device_kind = "TPU v9 hypothetical"
+
+    monkeypatch.setattr(jax, "devices", lambda *a: [Unknown()])
+    with pytest.raises(KeyError, match="TPU v9 hypothetical"):
+        DeviceSpec.current()
 
 
 def test_roofline_matmul_is_compute_or_memory_bound():
@@ -23,7 +42,7 @@ def test_roofline_matmul_is_compute_or_memory_bound():
 
 
 def test_comm_cost_scaling():
-    spec = DeviceSpec()
+    spec = DEVICE_SPECS["TPU v5 lite"]
     mb = 64 * 2 ** 20
     ar8 = comm_cost_ms("allreduce", mb, 8, spec)
     ag8 = comm_cost_ms("allgather", mb, 8, spec)

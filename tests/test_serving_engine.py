@@ -334,33 +334,3 @@ def test_scheduler_fuzz_no_leaks_and_oracle_equivalence():
                 runner, p, sp, max_model_len=max_model_len), \
                 f"trial {trial}: {rid} diverged from the oracle"
     assert total_preemptions > 0, "fuzz never exercised preemption churn"
-
-
-@pytest.mark.slow
-def test_bench_serving_child_cpu():
-    """The bench.py serving sweep runs end-to-end on CPU (ISSUE-1
-    satellite: CPU-runnable offered-load sweep)."""
-    import json
-    import os
-    import subprocess
-    import sys
-    import tempfile
-
-    from _helpers import child_env
-
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    out = tempfile.mktemp(suffix=".json")
-    env = child_env()
-    env["BENCH_CHILD_OUT"] = out
-    env["BENCH_PLATFORM"] = "cpu"
-    p = subprocess.run(
-        [sys.executable, os.path.join(repo, "bench.py"), "--child",
-         "serving:1:32:4:6:8:4:64"], env=env, timeout=420,
-        capture_output=True, text=True)
-    assert p.returncode == 0, p.stderr[-2000:]
-    with open(out) as f:
-        res = json.load(f)
-    assert len(res["sweep"]) == 3
-    for pt in res["sweep"]:
-        assert pt["tokens_per_sec"] > 0
-        assert pt["ttft_s_p99"] >= pt["ttft_s_p50"] >= 0

@@ -633,41 +633,3 @@ def test_fuzz_spill_pagein_200_trials_token_exact_no_leaks():
     assert totals["hidden"] > 0, "prefetch never hid a transfer"
     assert totals["drops"] > 0, "tiny caps never overflowed"
     assert totals["restores"] > 0, "fuzz never killed-and-restored"
-
-
-# ------------------------------------------------------- bench child
-
-
-@pytest.mark.slow
-def test_bench_serving_kv_offload_child_cpu():
-    """bench.py's kv_offload child commits the recompute-vs-pagein
-    resume cost, the sessions uplift, and the copy-bandwidth microbench
-    on CPU (ISSUE-10 tooling satellite)."""
-    import json
-    import os
-    import subprocess
-    import sys
-    import tempfile
-
-    from _helpers import child_env
-
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    out = tempfile.mktemp(suffix=".json")
-    env = child_env()
-    env["BENCH_CHILD_OUT"] = out
-    env["BENCH_PLATFORM"] = "cpu"
-    p = subprocess.run(
-        [sys.executable, os.path.join(repo, "bench.py"), "--child",
-         "serving:1:32:3:6:24:12:64:kv_offload"], env=env, timeout=420,
-        capture_output=True, text=True)
-    assert p.returncode == 0, p.stderr[-2000:]
-    with open(out) as f:
-        res = json.load(f)
-    assert res["workload"] == "kv_offload"
-    assert res["recompute"]["preemptions"] > 0
-    assert res["pagein"]["offload_resumes"] > 0
-    assert res["resume_compute_reduction_x"] >= 3.0
-    assert 0.0 <= res["pagein"]["pagein_hidden_ratio"] <= 1.0
-    assert res["sessions_uplift_x"] >= 1.0
-    assert res["copy_bandwidth"]["spill_gbps"] > 0
-    assert res["copy_bandwidth"]["pagein_gbps"] > 0

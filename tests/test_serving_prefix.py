@@ -386,44 +386,6 @@ def test_llama_chunked_prefix_matches_naive(llama_runner):
     assert eng.pool.allocator.check_no_leaks()
 
 
-# ------------------------------------------------------- bench satellite
-
-
-@pytest.mark.slow
-def test_bench_serving_shared_prefix_child_cpu():
-    """bench.py's serving child in --shared-prefix workload mode reports
-    the prefix-hit rate + prefill-token savings on CPU (ISSUE-3
-    satellite)."""
-    import os
-    import subprocess
-    import sys
-    import tempfile
-
-    from _helpers import child_env
-
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    out = tempfile.mktemp(suffix=".json")
-    env = child_env()
-    env["BENCH_CHILD_OUT"] = out
-    env["BENCH_PLATFORM"] = "cpu"
-    # header (20) must span >= one full page (block_size 16) to be
-    # shareable; prompt 24 leaves a unique 4-token tail per request
-    p = subprocess.run(
-        [sys.executable, os.path.join(repo, "bench.py"), "--child",
-         "serving:1:32:4:6:24:4:64:20"], env=env, timeout=420,
-        capture_output=True, text=True)
-    assert p.returncode == 0, p.stderr[-2000:]
-    with open(out) as f:
-        res = json.load(f)
-    assert res["shared_prefix"] == 20
-    assert len(res["sweep"]) == 3
-    for pt in res["sweep"]:
-        assert pt["tokens_per_sec"] > 0
-        assert pt["prefill_tokens_computed"] + pt["prefix_hit_tokens"] > 0
-    # staggered arrivals admit after the header is cached: hits happen
-    assert any(pt["prefix_hit_tokens"] > 0 for pt in res["sweep"])
-
-
 # ------------------------------------------------------------------ fuzz
 
 

@@ -747,50 +747,6 @@ def test_process_backend_store_handoff_zero_wire_bytes():
         r.shutdown()
 
 
-# ------------------------------------------------------- bench child
-
-
-@pytest.mark.slow       # ~25s subprocess: a second jax process compiling
-def test_bench_serving_shared_kv_child_cpu():
-    """bench.py's shared_kv child commits the private-vs-shared
-    resume-compute reduction on a migrated session workload, the
-    handoff-bytes split, the store hit rate, and int8 exactness
-    (ISSUE-14 tooling satellite)."""
-    import json
-    import subprocess
-    import sys
-    import tempfile
-
-    from _helpers import child_env
-
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    out = tempfile.mktemp(suffix=".json")
-    env = child_env()
-    env["BENCH_CHILD_OUT"] = out
-    env["BENCH_PLATFORM"] = "cpu"
-    p = subprocess.run(
-        [sys.executable, os.path.join(repo, "bench.py"), "--child",
-         "serving:1:32:3:6:24:12:64:shared_kv"], env=env, timeout=420,
-        capture_output=True, text=True)
-    assert p.returncode == 0, p.stderr[-2000:]
-    with open(out) as f:
-        res = json.load(f)
-    assert res["workload"] == "shared_kv"
-    assert res["private"]["token_exact"] and res["shared"]["token_exact"]
-    # THE acceptance bar: migrated sessions resume from the store with
-    # >= 3x less recompute than private per-engine tiers
-    assert res["resume_compute_reduction_x"] >= 3.0
-    # handoff payloads: raw page bytes privately, slot references
-    # (zero payload bytes) through the store
-    assert res["handoff_bytes_private"] > 0
-    assert res["handoff_bytes_shared"] == 0
-    assert res["shared"]["store_hit_pages"] > 0
-    assert res["shared"]["store_dedup_pages"] > 0
-    assert res["int8"]["token_exact"]
-    assert not res["shared"]["pages_leaked"]
-    assert not res["int8"]["pages_leaked"]
-
-
 # ----------------------------------------------------- 200-trial fuzz
 
 

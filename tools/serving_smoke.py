@@ -37,6 +37,10 @@ def main() -> int:
                     help="skip the naive-oracle equivalence check")
     args = ap.parse_args()
 
+    from paddle_tpu.utils.compile_cache import place_compile_cache
+
+    place_compile_cache()
+
     import numpy as np
 
     import paddle_tpu as paddle
