@@ -23,6 +23,7 @@ itself has no option for size.
 from __future__ import annotations
 
 import argparse
+import functools
 import json
 import math
 import os
@@ -137,10 +138,11 @@ def phase_kernels(n_heads: int, head_dim: int, seq: int, batch: int,
             B, pages_per_seq).astype(np.int32))
         starts = jnp.asarray(starts, jnp.int32)
         qlens = jnp.asarray(qlens, jnp.int32)
-        out = ragged_paged_attention(q, kp, vp, table, starts, qlens,
-                                     interpret=interpret)
+        out = jax.jit(functools.partial(
+            ragged_paged_attention, interpret=interpret))(
+                q, kp, vp, table, starts, qlens)
         with jax.default_matmul_precision("highest"):
-            ref = ragged_reference(q, kp, vp, table, starts, qlens)
+            ref = jax.jit(ragged_reference)(q, kp, vp, table, starts, qlens)
         err = float(jnp.max(jnp.abs(out - ref)))
         say("kernels", f"ragged {name} B={B} T={T}: max|err|={err:.3e} "
                        f"(tol {RAGGED_TOL})")
@@ -157,16 +159,10 @@ def _token_batch(vocab: int, batch: int, seq: int):
     return tokens[:, :-1], tokens[:, 1:]
 
 
-def _flash_fallbacks(caught) -> list:
-    return [str(w.message) for w in caught
-            if "O(seq^2) XLA reference" in str(w.message)
-            or "falls back to the O(s^2)" in str(w.message)]
-
-
 def _train_steps(model, batch: int, seq: int, steps: int, phase: str):
-    """`steps` TrainStep calls on one repeated batch. Returns the step
-    object, the losses, and the seconds of each call (the first one
-    compiles), each ended by block_until_ready."""
+    """`steps` TrainStep calls on one repeated batch, each timed up to
+    block_until_ready (the first one compiles). Returns the step object
+    and the losses."""
     import paddle_tpu as paddle
     from paddle_tpu.models.gpt import gpt_loss_fn
 
@@ -202,7 +198,10 @@ def phase_train(model, batch: int, seq: int, steps: int = 5,
     with warnings.catch_warnings(record=True) as caught:
         warnings.simplefilter("always")
         step, losses = _train_steps(model, batch, seq, steps, "train")
-    fell_back = _flash_fallbacks(caught)
+    # flash_attention's and SDPA's give-way warnings
+    fell_back = [str(w.message) for w in caught
+                 if "O(seq^2) XLA reference" in str(w.message)
+                 or "falls back to the O(s^2)" in str(w.message)]
     check(not fell_back, f"flash attention fell back: {fell_back}")
     ln_v = math.log(model.cfg.vocab_size)
     check(abs(losses[0] - ln_v) < FIRST_LOSS_TOL,
@@ -392,7 +391,8 @@ def phase_sharded_serve(model, n_requests: int = 8,
         logits = np.asarray(jax.device_get(logits), np.float32)
         gap = abs(float(logits[a[k]]) - float(logits[b[k]]))
         say("sharded/serve", f"request {n} differs first at index {k}: "
-                             f"tokens {a[k]} vs {b[k]}, logit gap {gap:.3e}")
+                             f"tokens {a[k]} vs {b[k]}, logit gap {gap:.3e} "
+                             f"(logits' std {float(logits.std()):.3e})")
         check(gap < NEAR_TIE_TOL,
               f"request {n}: tensor-parallel stream leaves the one-device "
               f"stream at index {k} on a logit gap of {gap}")

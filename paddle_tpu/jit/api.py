@@ -565,8 +565,14 @@ class TrainStep:
                     specs = [P()] * len(vals)
             vals = tuple(jax.device_put(v, NamedSharding(mesh, s))
                          for v, s in zip(vals, specs))
-        self.params, self.buffers, self.opt_state, loss = self._compiled(
-            self.params, self.buffers, self.opt_state, key, lr, step_i, vals)
+        from paddle_tpu.parallel.mesh import program_mesh_scope
+
+        # the first call traces: kernels GSPMD cannot partition learn here
+        # that this program's operands live on `mesh`
+        with program_mesh_scope(mesh):
+            self.params, self.buffers, self.opt_state, loss = self._compiled(
+                self.params, self.buffers, self.opt_state, key, lr, step_i,
+                vals)
         return Tensor._wrap(loss)
 
     def sync(self):

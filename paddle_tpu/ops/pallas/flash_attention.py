@@ -658,12 +658,12 @@ def validate_against_reference(shapes=DEFAULT_CHECK_SHAPES, interpret=None,
             return _reference(q, k, v, causal, scale, mask=mask,
                               kbias=kbias, qseg=segs, kseg=segs)
 
-        o_f = f_f(q, k, v)
-        o_r = f_r(q, k, v)
-        g_f = jax.grad(lambda *a: jnp.sum(f_f(*a) ** 2),
-                       argnums=(0, 1, 2))(q, k, v)
-        g_r = jax.grad(lambda *a: jnp.sum(f_r(*a) ** 2),
-                       argnums=(0, 1, 2))(q, k, v)
+        def out_and_grads(f, q, k, v):
+            out, vjp = jax.vjp(f, q, k, v)
+            return out, vjp(2.0 * out)      # d sum(o^2) / d (q, k, v)
+
+        o_f, g_f = jax.jit(functools.partial(out_and_grads, f_f))(q, k, v)
+        o_r, g_r = jax.jit(functools.partial(out_and_grads, f_r))(q, k, v)
         err_o = float(jnp.max(jnp.abs(o_f - o_r)))
         err_g = max(float(jnp.max(jnp.abs(x - y)))
                     for x, y in zip(g_f, g_r))
@@ -740,13 +740,14 @@ def flash_attention(q, k, v, causal: bool = True, scale=None,
         _log_fallback(q, k, block_q, block_k)
         return _reference(q, k, v, causal, scale, mask, kbias, qseg, kseg)
     statics = (causal, scale, block_q, block_k, interpret)
-    placed = _installed_mesh_axes(b, h)
+    placed = _program_mesh_axes(b, h)
     if placed is None:
         return _flash(q, k, v, mask, kbias, qseg, kseg, block_mask,
                       *statics)
     # GSPMD cannot partition a Mosaic kernel ("wrap the call in a
-    # shard_map"): under an installed mesh each device runs the kernel on
-    # its own batch rows ('dp') and heads ('tp'), every mesh axis manual
+    # shard_map"): in a program compiled for a mesh each device runs the
+    # kernel on its own batch rows ('dp') and heads ('tp'), every mesh
+    # axis manual
     mesh, bax, hax = placed
     qkv, row = P(bax, None, hax, None), P(bax, None)
     extras = {n: x for n, x in (("mask", mask), ("kbias", kbias),
@@ -769,14 +770,14 @@ def flash_attention(q, k, v, causal: bool = True, scale=None,
     )(q, k, v, extras)
 
 
-def _installed_mesh_axes(b: int, h: int):
-    """(mesh, batch axis, head axis) when a multi-device mesh is installed
-    (parallel.init_mesh) and the call is not already inside a manual
-    region; None otherwise. An axis is named only where it divides the
-    dimension; unnamed axes compute replicated."""
-    from paddle_tpu.parallel.mesh import current_mesh
+def _program_mesh_axes(b: int, h: int):
+    """(mesh, batch axis, head axis) when the program being traced computes
+    on a multi-device mesh (parallel.mesh.program_mesh) and the call is
+    not already inside a manual region; None otherwise. An axis is named
+    only where it divides the dimension; unnamed axes compute replicated."""
+    from paddle_tpu.parallel.mesh import program_mesh
 
-    mesh = current_mesh()
+    mesh = program_mesh()
     if (mesh is None or mesh.size == 1
             or jax.sharding.get_abstract_mesh().manual_axes):
         return None

@@ -73,6 +73,29 @@ def mesh_scope(mesh: Mesh):
         _current_mesh = prev
 
 
+_program_mesh: Optional[Mesh] = None
+
+
+@contextmanager
+def program_mesh_scope(mesh: Optional[Mesh]):
+    """Mark `mesh` as the one the program traced inside the scope computes
+    on. Set by entry points that place their state on a mesh and compile
+    one program for it (jit.TrainStep). current_mesh() only says a mesh
+    is installed — eager ops and single-device programs run under it on
+    one device — so code that must know whether ITS operands live on the
+    mesh (a Pallas kernel GSPMD cannot partition) asks program_mesh()."""
+    global _program_mesh
+    prev, _program_mesh = _program_mesh, mesh
+    try:
+        yield mesh
+    finally:
+        _program_mesh = prev
+
+
+def program_mesh() -> Optional[Mesh]:
+    return _program_mesh
+
+
 def serving_mesh(data: int = 1, model: int = 1,
                  devices: Optional[Sequence] = None,
                  data_axis: str = "data",

@@ -127,8 +127,7 @@ from paddle_tpu.serving.kv_cache import (
 )
 from paddle_tpu.serving.metrics import EngineMetrics
 from paddle_tpu.serving.model_runner import (
-    PagedModelRunner, UnrecoverableStepError, require_live_pools,
-    runner_for,
+    PagedModelRunner, require_retryable, runner_for,
 )
 from paddle_tpu.serving.resilience import QueueFullError, audit_engine
 from paddle_tpu.serving.scheduler import (
@@ -1173,10 +1172,8 @@ class ServingEngine:
                 logits, new_pools = self.runner.prefill_chunk(
                     chunk, start, table, self.pool.pools)
                 break
-            except UnrecoverableStepError:
-                raise
-            except Exception:
-                require_live_pools(self.pool.pools)
+            except Exception as e:
+                require_retryable(e, self.pool.pools)
                 if attempt >= self.max_step_retries:
                     self._finish_abnormal(req, "error")
                     return None
@@ -1328,10 +1325,8 @@ class ServingEngine:
                     logits, new_pools = self.runner.ragged_step(
                         tokens, tables, starts, qlens, self.pool.pools)
                 break
-            except UnrecoverableStepError:
-                raise
-            except Exception:
-                require_live_pools(self.pool.pools)
+            except Exception as e:
+                require_retryable(e, self.pool.pools)
                 if attempts < self.max_step_retries:
                     attempts += 1
                     self.metrics.step_retries.inc()
@@ -1662,10 +1657,8 @@ class ServingEngine:
                 packed, new_pools = self.runner.decode_multi_spec(
                     tokens, tables, pos, self.pool.pools, drafts, **kw)
                 break
-            except UnrecoverableStepError:
-                raise
-            except Exception:
-                require_live_pools(self.pool.pools)
+            except Exception as e:
+                require_retryable(e, self.pool.pools)
                 if attempts < self.max_step_retries:
                     attempts += 1
                     self.metrics.step_retries.inc()
@@ -1929,10 +1922,8 @@ class ServingEngine:
                 packed, new_pools = self.runner.decode_multi(
                     tokens, tables, pos, self.pool.pools, s, **ctx)
                 break
-            except UnrecoverableStepError:
-                raise
-            except Exception:
-                require_live_pools(self.pool.pools)
+            except Exception as e:
+                require_retryable(e, self.pool.pools)
                 if attempts < self.max_step_retries:
                     attempts += 1
                     self.metrics.step_retries.inc()
@@ -2052,10 +2043,8 @@ class ServingEngine:
                 logits, new_pools = self.runner.decode(tokens, tables, pos,
                                                        self.pool.pools)
                 break
-            except UnrecoverableStepError:
-                raise
-            except Exception:
-                require_live_pools(self.pool.pools)
+            except Exception as e:
+                require_retryable(e, self.pool.pools)
                 if attempts < self.max_step_retries:
                     attempts += 1
                     self.metrics.step_retries.inc()
@@ -2136,10 +2125,8 @@ class ServingEngine:
                 grid = self._timed_drain(lambda: greedy_grid(inf.result))
             else:
                 drained = self._timed_drain(lambda: _to_host(inf.result))
-        except UnrecoverableStepError:
-            raise
-        except Exception:
-            require_live_pools(inf.prev_pools)
+        except Exception as e:
+            require_retryable(e, inf.prev_pools)
             self.metrics.step_retries.inc()
             self._sleep(self.retry_backoff_s)
             self.pool.pools = inf.prev_pools

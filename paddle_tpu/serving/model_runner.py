@@ -161,14 +161,17 @@ class DonatedPoolError(UnrecoverableStepError):
     (TPU): their buffers are deleted and no retry can resubmit them."""
 
 
-def require_live_pools(pools) -> None:
-    """Raise DonatedPoolError if any pool buffer has been deleted — the
-    check every retry makes before it resubmits the pools it kept."""
+def require_retryable(exc: Exception, pools) -> None:
+    """The gate every recovery loop passes before it retries a failed
+    step with the `pools` it kept: re-raises an UnrecoverableStepError,
+    and raises DonatedPoolError if any pool buffer has been deleted."""
+    if isinstance(exc, UnrecoverableStepError):
+        raise exc
     if any(a.is_deleted() for a in jax.tree_util.tree_leaves(pools)
            if isinstance(a, jax.Array)):
         raise DonatedPoolError(
             "the failed step was given the KV pools by donation; their "
-            "buffers are deleted and the step cannot be retried")
+            "buffers are deleted and the step cannot be retried") from exc
 
 
 def bucket_len(t: int, minimum: int = 8) -> int:

@@ -317,6 +317,16 @@ def stub_runner_factory(index=0, vocab_size=31, block_size=4,
                            max_model_len=max_model_len)
 
 
+def paged_decode_attention(q, kp, vp, tbl, pos, interpret=True):
+    """[b, h, d] single-token decode queries as one-row spans of the
+    ragged paged-attention kernel (its q_len == 1 case)."""
+    from paddle_tpu.ops.pallas.ragged_paged_attention import \
+        ragged_paged_attention
+
+    return ragged_paged_attention(q[:, None], kp, vp, tbl, pos, 1,
+                                  interpret=interpret)[:, 0]
+
+
 def child_env(repo_on_pythonpath=True, num_cpu_devices=None):
     """Env for spawning CPU-only child processes from tests.
 

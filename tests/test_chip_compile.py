@@ -86,18 +86,17 @@ def _flash(dtype, backward, mode="dense"):
 def _auto_decode_kernel(monkeypatch):
     """Whatever attn_impl="auto" resolves to on a TPU for a GPT-2 decode
     step (q_len bucket 1): the test steers the backend question, the
-    runner answers it."""
-    import paddle_tpu as paddle
-    from paddle_tpu.models.gpt import GPT, GPTConfig
-    from paddle_tpu.serving.model_runner import GPTRunner
+    runner's dispatch answers it (on a stand-in with GPT-2's head layout —
+    building a runner costs seconds and the dispatch reads nothing else)."""
+    import types
 
-    paddle.seed(0)
-    runner = GPTRunner(GPT(GPTConfig(num_layers=1, vocab_size=128)),
-                       block_size=PAGE, max_model_len=SEQ)
-    assert (runner.n_heads, runner.n_kv_heads, runner.head_dim) == (
-        N_HEADS, N_HEADS, HEAD_DIM)
+    from paddle_tpu.serving.model_runner import PagedModelRunner
+
+    gpt2 = types.SimpleNamespace(
+        attn_impl="auto", n_heads=N_HEADS, n_kv_heads=N_HEADS,
+        head_dim=HEAD_DIM, _impl_logged=set())
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    impl = runner._attn_impl_for(1)
+    impl = PagedModelRunner._attn_impl_for(gpt2, 1)
     assert impl == "ragged", f"auto on a TPU resolved to {impl!r}"
     return _ragged(jnp.float32, BATCH, 1)
 
@@ -110,6 +109,11 @@ CASES = {
     "ragged-bf16-prefill": lambda mp: _ragged(jnp.bfloat16, 1,
                                               PREFILL_BUCKET),
     "ragged-fp32-longest-prompt": lambda mp: _ragged(jnp.float32, 1, SEQ),
+    # the smoke's other shapes: its second prefill bucket, and the
+    # batch-1 decode step of the naive_generate oracle
+    "ragged-fp32-prefill-256": lambda mp: _ragged(jnp.float32, 1, 256),
+    "ragged-bf16-prefill-256": lambda mp: _ragged(jnp.bfloat16, 1, 256),
+    "ragged-fp32-decode-b1": lambda mp: _ragged(jnp.float32, 1, 1),
     "flash-fp32-fwd": lambda mp: _flash(jnp.float32, False),
     "flash-bf16-fwd": lambda mp: _flash(jnp.bfloat16, False),
     "flash-fp32-fwd-bwd": lambda mp: _flash(jnp.float32, True),
@@ -132,20 +136,21 @@ def test_kernel_compiles_for_v5e(case, v5e, monkeypatch):
 
 
 def test_flash_compiles_per_shard_under_a_dp2_tp2_mesh(v5e):
-    """GSPMD cannot partition a Mosaic kernel: with a mesh installed
-    (parallel.init_mesh, the README's hybrid-parallel step) flash attention
-    must reach the compiler inside a shard_map, batch over dp, heads over
-    tp — the four-chip path of chip_smoke.py --chips 4."""
+    """GSPMD cannot partition a Mosaic kernel: in a program compiled for
+    a mesh (jit.TrainStep under parallel.init_mesh, the README's
+    hybrid-parallel step) flash attention must reach the compiler inside a
+    shard_map, batch over dp, heads over tp — the four-chip path of
+    chip_smoke.py --chips 4."""
     import numpy as np
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-    from paddle_tpu.parallel.mesh import mesh_scope
+    from paddle_tpu.parallel.mesh import program_mesh_scope
 
     fn, shapes = _flash(jnp.float32, True)
     mesh = Mesh(np.asarray(v5e).reshape(2, 2), ("dp", "tp"))
     spec = NamedSharding(mesh, P("dp", None, "tp", None))
     args = [jax.ShapeDtypeStruct(s, d, sharding=spec) for s, d in shapes]
-    with mesh_scope(mesh):
+    with program_mesh_scope(mesh):
         compiled = jax.jit(fn).lower(*args).compile()
     text = compiled.as_text()
     assert "tpu_custom_call" in text

@@ -28,7 +28,7 @@ def toy_model(tensor_parallel: bool = False):
 
 
 def _kernels():
-    chip_smoke.phase_kernels(4, 16, SEQ, BATCH, interpret=True)
+    chip_smoke.phase_kernels(2, 16, SEQ, 1, interpret=True)
 
 
 def _train():
@@ -38,8 +38,8 @@ def _train():
 def _serve():
     # the ragged kernel in interpret mode: the "never the reference"
     # check is live on the CPU too
-    chip_smoke.phase_serve(toy_model(), n_requests=3, prompt_range=(8, 24),
-                           max_tokens=6, num_blocks=64, attn_impl="ragged")
+    chip_smoke.phase_serve(toy_model(), n_requests=3, prompt_range=(9, 16),
+                           max_tokens=4, num_blocks=64, attn_impl="ragged")
 
 
 def _sharded():
@@ -48,8 +48,30 @@ def _sharded():
 
 def _sharded_serve():
     chip_smoke.phase_sharded_serve(toy_model(), n_requests=3,
-                                   prompt_range=(8, 24), max_tokens=6,
+                                   prompt_range=(9, 16), max_tokens=4,
                                    num_blocks=64, attn_impl="ragged")
+
+
+@pytest.fixture(autouse=True, scope="module")
+def _serve_each_engine_once():
+    """Several tests serve the same toy model, prompts and options (the
+    serve phase, the four-device serve and the two near-tie cases): run
+    each distinct engine once per module and hand later callers its
+    result."""
+    real, done = chip_smoke._serve, {}
+
+    def serve(phase, model, prompts, max_tokens, num_blocks, **kw):
+        key = (repr(prompts), max_tokens, num_blocks,
+               repr(sorted(kw.items())))
+        if key not in done:
+            done[key] = real(phase, model, prompts, max_tokens, num_blocks,
+                             **kw)
+        eng, streams = done[key]
+        return eng, [list(t) for t in streams]
+
+    chip_smoke._serve = serve
+    yield
+    chip_smoke._serve = real
 
 
 PHASES = {"kernels": _kernels, "train": _train, "serve": _serve,
@@ -122,10 +144,11 @@ def test_sharded_serve_judges_a_parting_by_the_logit_gap(
     """Where the tensor-parallel stream leaves the one-device stream, the
     one-device logits of the two tokens decide: inside the tolerance it is
     a near-tie, outside it the phase fails."""
-    real = chip_smoke._serve
+    served = chip_smoke._serve
 
     def serve(phase, *a, **kw):
-        eng, streams = real(phase, *a, **kw)
+        eng, streams = served(phase, *a, **kw)
+        streams = [list(t) for t in streams]
         if phase.endswith("tp4"):
             streams[0][2] = (streams[0][2] + 1) % 256
         return eng, streams

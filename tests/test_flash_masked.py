@@ -341,28 +341,30 @@ def test_unaligned_seq_pads_to_flash_kernel(monkeypatch):
 
 
 @pytest.mark.parametrize("mode", ["causal", "segments", "padmask"])
-def test_flash_under_installed_mesh_matches_single_device(mode):
-    """With a mesh installed the kernel runs per shard inside a shard_map
-    (GSPMD cannot partition a Mosaic kernel on the chip): batch over dp,
-    heads over tp, value and gradients equal to the unsharded call."""
+def test_flash_in_a_mesh_program_matches_single_device(mode):
+    """In a program compiled for a mesh (program_mesh_scope, which
+    jit.TrainStep sets) the kernel runs per shard inside a shard_map — GSPMD
+    cannot partition a Mosaic kernel on the chip: batch over dp, heads
+    over tp, value and gradients equal to the unsharded call. A mesh that
+    is merely installed changes nothing."""
     import jax
     import jax.numpy as jnp
 
     from paddle_tpu import parallel as dist
     from paddle_tpu.ops.pallas.flash_attention import flash_attention
-    from paddle_tpu.parallel.mesh import mesh_scope
+    from paddle_tpu.parallel.mesh import mesh_scope, program_mesh_scope
 
     rng_l = np.random.default_rng(11)
-    q, k, v = (jnp.asarray(rng_l.standard_normal((4, 128, 4, 16)),
+    q, k, v = (jnp.asarray(rng_l.standard_normal((2, 128, 2, 16)),
                            jnp.float32) for _ in range(3))
     kw = {"causal": mode != "padmask"}
     if mode == "segments":
         kw["segment_ids"] = jnp.broadcast_to(
-            (jnp.arange(128) * 4) // 128, (4, 128)).astype(jnp.int32)
+            (jnp.arange(128) * 4) // 128, (2, 128)).astype(jnp.int32)
     if mode == "padmask":
         kw["mask"] = jnp.broadcast_to(jnp.where(
             jnp.arange(128) < 96, 0.0, -1e30)[None, None, None, :],
-            (4, 1, 1, 128)).astype(jnp.float32)
+            (2, 1, 1, 128)).astype(jnp.float32)
 
     def loss(q, k, v):
         return jnp.sum(flash_attention(q, k, v, interpret=True, **kw) ** 2)
@@ -370,10 +372,16 @@ def test_flash_under_installed_mesh_matches_single_device(mode):
     want = jax.value_and_grad(loss, argnums=(0, 1, 2))(q, k, v)
     mesh = dist.mesh.Mesh(np.asarray(jax.devices()[:4]).reshape(2, 2),
                           ("dp", "tp"))
+    # a fresh function object per trace: the scope is read at trace time
+    # and is not part of jit's cache key
     with mesh_scope(mesh):
-        text = jax.jit(loss).lower(q, k, v).as_text()
-        got = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2)))(q, k, v)
-    assert "shard_map" in text or "manual" in text
+        assert "manual_computation" not in jax.jit(
+            lambda *a: loss(*a)).lower(q, k, v).as_text()
+    with program_mesh_scope(mesh):
+        assert "manual_computation" in jax.jit(
+            lambda *a: loss(*a)).lower(q, k, v).as_text()
+        got = jax.jit(jax.value_and_grad(lambda *a: loss(*a),
+                                         argnums=(0, 1, 2)))(q, k, v)
     np.testing.assert_allclose(float(got[0]), float(want[0]), rtol=1e-5)
     for a, b in zip(got[1], want[1]):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-4)

@@ -12,15 +12,8 @@ import pytest
 from paddle_tpu.models.generation import (
     masked_cache_attention, paged_gather,
 )
-from paddle_tpu.ops.pallas.ragged_paged_attention import (
-    ragged_attention_ok, ragged_paged_attention,
-)
-
-
-def paged_decode_attention(q, kp, vp, tbl, pos, interpret=True):
-    """[b, h, d] decode queries as one-row ragged spans."""
-    return ragged_paged_attention(q[:, None], kp, vp, tbl, pos, 1,
-                                  interpret=interpret)[:, 0]
+from _helpers import paged_decode_attention
+from paddle_tpu.ops.pallas.ragged_paged_attention import ragged_attention_ok
 
 rng = np.random.default_rng(3)
 

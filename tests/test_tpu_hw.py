@@ -10,21 +10,13 @@ is the quicker proof; this tier goes wider. Keep each test small.
 """
 
 import json
-import os
 
 import numpy as np
 import pytest
 
+from _helpers import paged_decode_attention
+
 pytestmark = pytest.mark.tpu
-
-
-def paged_decode_attention(q, kp, vp, tbl, pos, interpret=False):
-    """[b, h, d] decode queries as one-row spans of the ragged kernel."""
-    from paddle_tpu.ops.pallas.ragged_paged_attention import \
-        ragged_paged_attention
-
-    return ragged_paged_attention(q[:, None], kp, vp, tbl, pos, 1,
-                                  interpret=interpret)[:, 0]
 
 
 @pytest.fixture(scope="module")
