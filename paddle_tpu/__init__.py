@@ -9,6 +9,10 @@ GSPMD partitioning over device meshes (`paddle_tpu.parallel`).
 
 from __future__ import annotations
 
+# first, so that its `paddle_tpu.import` span (closed at the bottom of this
+# file) covers everything the package loads
+from paddle_tpu import profiler  # noqa: F401
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -296,7 +300,6 @@ from paddle_tpu import incubate  # noqa: E402,F401
 from paddle_tpu import text  # noqa: E402,F401
 from paddle_tpu import inference  # noqa: E402,F401
 from paddle_tpu import metric  # noqa: E402,F401
-from paddle_tpu import profiler  # noqa: E402,F401
 from paddle_tpu import geometric  # noqa: E402,F401
 from paddle_tpu import regularizer  # noqa: E402,F401
 from paddle_tpu import signal  # noqa: E402,F401
@@ -340,3 +343,5 @@ from paddle_tpu import strings  # noqa: F401,E402
 from paddle_tpu.core.selected_rows import (  # noqa: F401,E402
     SelectedRows, get_tensor_from_selected_rows, merge_selected_rows,
 )
+
+profiler.record("paddle_tpu.import", profiler.LOADED_NS)

@@ -18,6 +18,7 @@ from typing import Dict, List, Optional
 import jax
 import numpy as np
 
+from paddle_tpu import profiler as _prof
 from paddle_tpu.core.tensor import Tensor
 
 
@@ -210,22 +211,24 @@ def create_serving_engine(model, dtype=None, **kw):
             f"comm_dtype={comm_dtype!r} needs a tensor-parallel mesh — "
             "the quantized collective replaces the row-parallel "
             "allreduce, which only exists at tp > 1")
-    runner = runner_for(model,
-                        **{k: kw.pop(k) for k in
-                           ("block_size", "max_model_len", "attn_impl",
-                            "kv_dtype", "weight_dtype",
-                            "weight_group_size")
-                           if k in kw})
-    if dtype is not None:
-        runner.params = {
-            k: (v.astype(dtype) if jnp.issubdtype(v.dtype, jnp.floating)
-                else v) for k, v in runner.params.items()}
-    if mesh is not None:
-        # cast first, shard second: the device_put then ships the final
-        # serving dtype, not fp32 weights that get re-cast on device
-        runner.shard(mesh, comm_dtype=comm_dtype)
-    kw.setdefault("num_blocks", 128)
-    return ServingEngine(runner, **kw)
+    # runner, weight casts, sharding and the KV pool: part of set-up
+    with _prof.always_span("engine.build"):
+        runner = runner_for(model,
+                            **{k: kw.pop(k) for k in
+                               ("block_size", "max_model_len", "attn_impl",
+                                "kv_dtype", "weight_dtype",
+                                "weight_group_size")
+                               if k in kw})
+        if dtype is not None:
+            runner.params = {
+                k: (v.astype(dtype) if jnp.issubdtype(v.dtype, jnp.floating)
+                    else v) for k, v in runner.params.items()}
+        if mesh is not None:
+            # cast first, shard second: the device_put then ships the final
+            # serving dtype, not fp32 weights that get re-cast on device
+            runner.shard(mesh, comm_dtype=comm_dtype)
+        kw.setdefault("num_blocks", 128)
+        return ServingEngine(runner, **kw)
 
 
 def create_serving_router(model, *, replicas: int = 2, dtype=None,

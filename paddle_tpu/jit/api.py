@@ -20,6 +20,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from paddle_tpu import profiler as _prof
 from paddle_tpu.core.random import default_generator
 from paddle_tpu.core.tensor import Tensor
 from paddle_tpu.jit.functionalize import functionalize
@@ -430,22 +431,25 @@ class TrainStep:
         self.n_inputs = n_inputs
         self.amp_level = amp_level
         self.amp_dtype = amp_dtype
-        self.func = functionalize(model)
-        # copy into TrainStep-owned buffers: steps donate these to XLA, and
-        # donating the model's own arrays would leave model.state_dict()
-        # pointing at deleted buffers. Model tensors stay valid (but stale
-        # until .sync()).
-        self.params = {k: jnp.copy(v) for k, v in self.func.param_values().items()}
-        self.buffers = {k: jnp.copy(v) for k, v in self.func.buffer_values().items()}
-        self.opt_state = jax.tree_util.tree_map(
-            lambda v: optimizer._init_state(v), self.params,
-            is_leaf=lambda v: not isinstance(v, dict))
         self._step_i = 0
         self._compiled = None
         self._mesh = mesh
         self._in_shardings = in_shardings
-        self._restore_opt_state()
-        self._maybe_shard_state()
+        with _prof.always_span("train.init"):
+            self.func = functionalize(model)
+            # copy into TrainStep-owned buffers: steps donate these to XLA,
+            # and donating the model's own arrays would leave
+            # model.state_dict() pointing at deleted buffers. Model tensors
+            # stay valid (but stale until .sync()).
+            self.params = {k: jnp.copy(v)
+                           for k, v in self.func.param_values().items()}
+            self.buffers = {k: jnp.copy(v)
+                            for k, v in self.func.buffer_values().items()}
+            self.opt_state = jax.tree_util.tree_map(
+                lambda v: optimizer._init_state(v), self.params,
+                is_leaf=lambda v: not isinstance(v, dict))
+            self._restore_opt_state()
+            self._maybe_shard_state()
 
     # ---------------------------------------------------------------- sharding
 
@@ -519,22 +523,43 @@ class TrainStep:
                 loss_v = loss_t._value if isinstance(loss_t, Tensor) else loss_t
                 return loss_v, new_buf
 
-            (loss, new_buffers), grads = jax.value_and_grad(
-                compute_loss, has_aux=True)(params)
-            if clip is not None and hasattr(clip, "functional"):
-                grads = clip.functional(grads)
-            new_params, new_opt_state = optimizer.apply_gradients(
-                params, grads, opt_state, lr, step_i)
+            # scope names on the device: the model's own (embed, block/..,
+            # lm_head) nest under "loss"; jvp/transpose in an operation's
+            # name tell forward from backward
+            with jax.named_scope("loss"):
+                (loss, new_buffers), grads = jax.value_and_grad(
+                    compute_loss, has_aux=True)(params)
+            with jax.named_scope("optimizer"):
+                if clip is not None and hasattr(clip, "functional"):
+                    grads = clip.functional(grads)
+                new_params, new_opt_state = optimizer.apply_gradients(
+                    params, grads, opt_state, lr, step_i)
             return new_params, new_buffers, new_opt_state, loss
 
         self._compiled = jax.jit(step, donate_argnums=(0, 1, 2))
 
     def __call__(self, *batch):
-        if self._compiled is None:
-            self._build()
+        self._step_i += 1
+        # the step's root span; whether its sites record is decided here
+        with _prof.step_span("train.step", self._step_i):
+            with _prof.span("train.stage_inputs"):
+                mesh, args = self._stage_inputs(batch)
+            if self._compiled is None:
+                # _build and the first call: trace, lower, compile (or the
+                # compile cache's load), and that call's dispatch
+                with _prof.always_span("train.compile"):
+                    self._build()
+                    loss = self._dispatch(mesh, args)
+            else:
+                with _prof.span("train.dispatch"):
+                    loss = self._dispatch(mesh, args)
+        return Tensor._wrap(loss)
+
+    def _stage_inputs(self, batch):
+        """Batch, lr, key and step number as device arrays, placed on the
+        mesh when there is one. Returns (mesh, (key, lr, step_i, vals))."""
         vals = tuple(b._value if isinstance(b, Tensor) else jnp.asarray(b)
                      for b in batch)
-        self._step_i += 1
         lr = jnp.asarray(self.optimizer.get_lr(), jnp.float32)
         key = default_generator.next_key()
         step_i = jnp.asarray(self._step_i, jnp.int32)
@@ -565,15 +590,17 @@ class TrainStep:
                     specs = [P()] * len(vals)
             vals = tuple(jax.device_put(v, NamedSharding(mesh, s))
                          for v, s in zip(vals, specs))
+        return mesh, (key, lr, step_i, vals)
+
+    def _dispatch(self, mesh, args):
         from paddle_tpu.parallel.mesh import program_mesh_scope
 
         # the first call traces: kernels GSPMD cannot partition learn here
         # that this program's operands live on `mesh`
         with program_mesh_scope(mesh):
             self.params, self.buffers, self.opt_state, loss = self._compiled(
-                self.params, self.buffers, self.opt_state, key, lr, step_i,
-                vals)
-        return Tensor._wrap(loss)
+                self.params, self.buffers, self.opt_state, *args)
+        return loss
 
     def sync(self):
         """Write compiled-side params/buffers back into the model Tensors and

@@ -27,6 +27,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from paddle_tpu import profiler as _prof
 from paddle_tpu.core.tensor import Tensor
 from paddle_tpu.nn import functional as F
 from paddle_tpu.nn import initializer as I
@@ -130,8 +131,12 @@ class GPTBlock(Layer):
         return x
 
     def forward(self, x):
-        x = x + self.attn(self.ln1(self._sp(x)))
-        x = x + self.mlp(self.ln2(self._sp(x)))
+        # scope names reach the device trace as block/attn, block/mlp
+        with jax.named_scope("block"):
+            with jax.named_scope("attn"):
+                x = x + self.attn(self.ln1(self._sp(x)))
+            with jax.named_scope("mlp"):
+                x = x + self.mlp(self.ln2(self._sp(x)))
         return x
 
 
@@ -139,41 +144,47 @@ class GPT(Layer):
     def __init__(self, cfg: GPTConfig):
         super().__init__()
         self.cfg = cfg
-        if cfg.tensor_parallel:
-            self.wte = VocabParallelEmbedding(cfg.vocab_size, cfg.hidden_size)
-        else:
-            self.wte = Embedding(cfg.vocab_size, cfg.hidden_size,
+        # every parameter is initialised eagerly here: part of set-up
+        with _prof.always_span("model.build", model="GPT"):
+            if cfg.tensor_parallel:
+                self.wte = VocabParallelEmbedding(cfg.vocab_size,
+                                                  cfg.hidden_size)
+            else:
+                self.wte = Embedding(cfg.vocab_size, cfg.hidden_size,
+                                     weight_attr=I.Normal(0.0, 0.02))
+            self.wpe = Embedding(cfg.max_seq_len, cfg.hidden_size,
                                  weight_attr=I.Normal(0.0, 0.02))
-        self.wpe = Embedding(cfg.max_seq_len, cfg.hidden_size,
-                             weight_attr=I.Normal(0.0, 0.02))
-        self.drop = Dropout(cfg.dropout)
-        blocks = []
-        for i in range(cfg.num_layers):
-            use_moe = cfg.moe_every > 0 and (i + 1) % cfg.moe_every == 0
-            blocks.append(GPTBlock(cfg, use_moe=use_moe))
-        self.blocks = LayerList(blocks)
-        self.ln_f = LayerNorm(cfg.hidden_size)
-        if not cfg.tie_embeddings:
-            self.lm_head = Linear(cfg.hidden_size, cfg.vocab_size,
-                                  bias_attr=False)
+            self.drop = Dropout(cfg.dropout)
+            blocks = []
+            for i in range(cfg.num_layers):
+                use_moe = cfg.moe_every > 0 and (i + 1) % cfg.moe_every == 0
+                blocks.append(GPTBlock(cfg, use_moe=use_moe))
+            self.blocks = LayerList(blocks)
+            self.ln_f = LayerNorm(cfg.hidden_size)
+            if not cfg.tie_embeddings:
+                self.lm_head = Linear(cfg.hidden_size, cfg.vocab_size,
+                                      bias_attr=False)
 
     def forward(self, input_ids):
         b, s = input_ids.shape
-        pos = Tensor._wrap(jnp.arange(s))
-        x = self.wte(input_ids) + self.wpe(pos)
-        mesh = current_mesh()
-        if mesh is not None and "dp" in mesh.axis_names:
-            x = sharding_constraint(x, P("dp", None, None))
-        x = self.drop(x)
+        with jax.named_scope("embed"):
+            pos = Tensor._wrap(jnp.arange(s))
+            x = self.wte(input_ids) + self.wpe(pos)
+            mesh = current_mesh()
+            if mesh is not None and "dp" in mesh.axis_names:
+                x = sharding_constraint(x, P("dp", None, None))
+            x = self.drop(x)
         for blk in self.blocks:
             x = blk(x)
-        x = self.ln_f(x)
-        if self.cfg.tie_embeddings:
-            from paddle_tpu.ops.registry import C_OPS
+        with jax.named_scope("final_norm"):
+            x = self.ln_f(x)
+        with jax.named_scope("lm_head"):
+            if self.cfg.tie_embeddings:
+                from paddle_tpu.ops.registry import C_OPS
 
-            logits = C_OPS.matmul(x, self.wte.weight, transpose_y=True)
-        else:
-            logits = self.lm_head(x)
+                logits = C_OPS.matmul(x, self.wte.weight, transpose_y=True)
+            else:
+                logits = self.lm_head(x)
         return logits
 
     def loss(self, logits, labels):

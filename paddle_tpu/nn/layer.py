@@ -12,6 +12,7 @@ from typing import Callable, Dict, Iterator, List, Optional, Tuple
 
 import numpy as np
 
+from paddle_tpu import profiler as _prof
 from paddle_tpu.core import dtype as dtype_mod
 from paddle_tpu.core.tensor import Parameter, Tensor
 
@@ -177,18 +178,20 @@ class Layer:
         return out
 
     def set_state_dict(self, state_dict):
-        own = self.state_dict(include_non_persistable_buffer=True)
-        missing, unexpected = [], []
-        for name, t in own.items():
-            if name in state_dict:
-                src = state_dict[name]
-                v = src._value if isinstance(src, Tensor) else np.asarray(src)
-                t.copy_(Tensor._wrap(v))
-            else:
-                missing.append(name)
-        for name in state_dict:
-            if name not in own:
-                unexpected.append(name)
+        with _prof.always_span("model.set_state_dict"):
+            own = self.state_dict(include_non_persistable_buffer=True)
+            missing, unexpected = [], []
+            for name, t in own.items():
+                if name in state_dict:
+                    src = state_dict[name]
+                    v = (src._value if isinstance(src, Tensor)
+                         else np.asarray(src))
+                    t.copy_(Tensor._wrap(v))
+                else:
+                    missing.append(name)
+            for name in state_dict:
+                if name not in own:
+                    unexpected.append(name)
         return missing, unexpected
 
     load_dict = set_state_dict

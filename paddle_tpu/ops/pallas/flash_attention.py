@@ -297,14 +297,14 @@ def _flash_forward(q, k, v, mask, kbias, qseg, kseg, block_mask,
             out_specs=(o_spec,
                        pl.BlockSpec((1, block_q, LSE_LANES),
                                     lambda bh, qi, ki: (bh, qi, 0))),
-            scratch_shapes=scratch, interpret=interpret,
+            scratch_shapes=scratch, interpret=interpret, name="flash_fwd",
         )(qf, kf, vf, *extra_in)
         return jnp.swapaxes(out.reshape(b, h, sq, d), 1, 2), lse
     out = pl.pallas_call(
         functools.partial(_flash_fwd_kernel, **common),
         out_shape=jax.ShapeDtypeStruct((b * h, sq, d), q.dtype),
         grid=grid, in_specs=in_specs, out_specs=o_spec,
-        scratch_shapes=scratch, interpret=interpret,
+        scratch_shapes=scratch, interpret=interpret, name="flash_fwd",
     )(qf, kf, vf, *extra_in)
     return jnp.swapaxes(out.reshape(b, h, sq, d), 1, 2)
 
@@ -441,7 +441,7 @@ def _flash_backward(q, k, v, o, do, lse, mask, kbias, qseg, kseg,
         out_specs=pl.BlockSpec((1, block_q, d),
                                lambda bh, qi, ki: (bh, qi, 0)),
         scratch_shapes=[_scratch((block_q, d))],
-        interpret=interpret,
+        interpret=interpret, name="flash_bwd_dq",
     )(qf, kf, vf, dof, *extra_in, lse, delta)
 
     # ---- dK/dV: grid (bh, ki, qi) ----------------------------------------
@@ -469,7 +469,7 @@ def _flash_backward(q, k, v, o, do, lse, mask, kbias, qseg, kseg,
             pl.BlockSpec((1, block_k, d), lambda bh, ki, qi: (bh, ki, 0)),
         ),
         scratch_shapes=[_scratch((block_k, d)), _scratch((block_k, d))],
-        interpret=interpret,
+        interpret=interpret, name="flash_bwd_dkv",
     )(qf, kf, vf, dof, *extra_in, lse, delta)
 
     unflat = lambda t, s: jnp.swapaxes(t.reshape(b, h, s, d), 1, 2)

@@ -244,6 +244,7 @@ def ragged_paged_attention(q, k_pool, v_pool, block_table, start_pos, q_len,
         out_shape=jax.ShapeDtypeStruct((B, n_kv, T * n_rep, d),
                                        jnp.float32 if quantized else q.dtype),
         interpret=interpret,
+        name="ragged_paged_attn",
     )(*scalars, qg, k_pool, v_pool)
     out = out.astype(q.dtype)
     out = out.reshape(B, n_kv, T, n_rep, d).transpose(0, 2, 1, 3, 4)

@@ -69,8 +69,9 @@ prefix matching, page-in staging — pure host work) while step N's
 decode/horizon launch is still executing on device, commits N's drained
 buffer through the standard replay, and only then dispatches N+1 —
 jax's async dispatch makes the whole thing a scheduling reorder with
-ONE launch in flight, measured by `planned_ahead_steps` and the
-`device_idle_fraction` proxy. `horizon_sampling=True` widens horizons
+ONE launch in flight, counted by `planned_ahead_steps` and shown by
+the step's spans (`engine.plan` ahead of `engine.drain`) against the
+device trace. `horizon_sampling=True` widens horizons
 to temperature > 0 (per-request seeded key schedules inside the
 decode_multi scan, bit-identical to the per-step streams) and
 `horizon_early_stop=True` adds an on-device per-row done bit
@@ -121,6 +122,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from paddle_tpu import profiler as _prof
 from paddle_tpu.serving.detokenize import StreamDetokenizer
 from paddle_tpu.serving.kv_cache import (
     KVCachePool, OffloadRecord, SCRATCH_PAGE,
@@ -736,6 +738,7 @@ class ServingEngine:
             self._finish_abnormal(self.scheduler.waiting[0], "shed",
                                   counted=True)
         req.arrival_time = self.metrics.clock()
+        req.queued_ns = _prof.stamp()
         self._requests[req.request_id] = req
         self.scheduler.add(req)
         self.metrics.requests_added.inc()
@@ -759,14 +762,10 @@ class ServingEngine:
         return self.scheduler.has_work() or self._inflight is not None
 
     def _timed_drain(self, fn):
-        """Run one blocking device->host drain, charging its wall time
-        to drain_wait_seconds — the 'host blocked on device' share of
-        the step-time split the zero-bubble bench commits."""
-        t0 = self.metrics.clock()
-        try:
+        """Run one blocking device->host drain under an `engine.drain`
+        span: the host waiting for the device."""
+        with _prof.span("engine.drain"):
             return fn()
-        finally:
-            self.metrics.drain_wait_seconds.inc(self.metrics.clock() - t0)
 
     # ------------------------------------------------- failure plumbing
 
@@ -846,7 +845,7 @@ class ServingEngine:
                         step: Optional[int] = None) -> Optional[int]:
         """Single-row spelling of the guarded sampler (the completing-
         chunk call site): same greedy_grid pass, scalar-shaped."""
-        am, fin = greedy_grid(logits_row)
+        am, fin = self._timed_drain(lambda: greedy_grid(logits_row))
         self.metrics.host_syncs.inc()
         if step is None:
             step = len(req.output_tokens)
@@ -951,15 +950,18 @@ class ServingEngine:
         an explicit finish_reason."""
         if not self.has_work():
             return []
-        self.metrics.mark_active()
         self._step_count += 1
-        t0 = self.metrics.clock()
-        events: List[TokenEvent] = []
+        # the step's root span; whether this step's sites record at all
+        # is decided here, once (a profiler session is live or not)
+        with _prof.step_span("engine.step", self._step_count):
+            return self._step()
 
-        # ---- PLAN phase (pure host work; with `pipelined` this runs
-        # while the PREVIOUS step's launch is still executing on device
-        # — jax's async dispatch means nothing below blocks on it)
-
+    def _plan(self):
+        """PLAN phase of a step, under the caller's `engine.plan` span
+        (pure host work; with `pipelined` this runs while the PREVIOUS
+        step's launch is still executing on device — jax's async
+        dispatch means nothing here blocks on it). Returns (admitted,
+        prefill plan)."""
         # 0. deadlines first: an expired request must not win admission
         self._expire_deadlines()
 
@@ -974,6 +976,12 @@ class ServingEngine:
         for req in admitted:
             if req.admit_prefix_tokens:
                 self.metrics.prefix_hit_tokens.inc(req.admit_prefix_tokens)
+            if req.queued_ns is not None:
+                # add_request -> first admission
+                if _prof.recording:
+                    _prof.record("request.queue", req.queued_ns,
+                                 request_id=req.request_id)
+                req.queued_ns = None
         if not self.pipelined:
             # 1b. page-in fence (ISSUE 10): every host-resident page an
             #     admission mapped must be IN the pools before anything
@@ -982,6 +990,19 @@ class ServingEngine:
             #     step), the rest stage now; the scatter itself
             #     dispatches async like every other pool write
             self._fence_pagein(admitted)
+        return admitted, self.scheduler.prefill_plan()
+
+    def _reserve_decode(self) -> None:
+        """Decode-page reservation; pool pressure preempts
+        youngest-first. Planning, wherever in the step it falls."""
+        with _prof.span("engine.plan"):
+            for _ in self.scheduler.reserve_decode():
+                self.metrics.preemptions.inc()
+
+    def _step(self) -> List[TokenEvent]:
+        events: List[TokenEvent] = []
+        with _prof.span("engine.plan"):
+            admitted, plan = self._plan()
 
         # 2-4. compute this step's spans. ragged_batch mode collapses the
         # chunk-then-decode sequencing: when the step has BOTH prefill
@@ -1000,16 +1021,11 @@ class ServingEngine:
         # reproduces — several tokens per engine step when drafts hit.
         # Chunks fuse into the same launch under ragged_batch, otherwise
         # they keep the sequential chunk-then-decode sequencing.
-        plan = self.scheduler.prefill_plan()
-        t_plan = self.metrics.clock() - t0
-        self.metrics.host_plan_seconds.inc(t_plan)
         if self._inflight is not None:
             # the whole planning interval above ran under an in-flight
             # launch — host time the device no longer waits for (the
-            # zero-bubble overlap the planned_ahead_steps counter and
-            # device_idle_fraction gauge measure)
+            # zero-bubble overlap planned_ahead_steps counts)
             self.metrics.planned_ahead_steps.inc()
-            self.metrics.overlapped_plan_seconds.inc(t_plan)
         if self.pipelined:
             # ---- COMMIT phase: drain + replay the previous step's
             # launch (stop/length/NaN handling, page release — all the
@@ -1018,15 +1034,16 @@ class ServingEngine:
             # committed side so a drain-failure rollback to the
             # pre-launch pools can never lose them
             events.extend(self._commit_inflight())
-            self._fence_pagein(admitted)
             # re-slice the prefill plan AFTER the commit: a committed
             # fused ragged launch advanced chunk coverage (planning
             # from the stale slice would recompute — and double-sample
             # — the same chunk), and a commit quarantine can end a
             # planned request. The pre-commit plan's only job was to
-            # measure overlapped host work; identical by construction
-            # when the commit was a plain decode/horizon
-            plan = self.scheduler.prefill_plan()
+            # overlap host work; identical by construction when the
+            # commit was a plain decode/horizon
+            with _prof.span("engine.plan"):
+                self._fence_pagein(admitted)
+                plan = self.scheduler.prefill_plan()
 
         if self.role == "prefill":
             # disaggregated serving (ISSUE 12): every request that
@@ -1048,8 +1065,7 @@ class ServingEngine:
                 # fused verify-in-scan (ISSUE 18): drafts ride the
                 # device-resident horizon — accept/reject on device,
                 # ONE drain per horizon, defers like any horizon
-                for v in self.scheduler.reserve_decode():
-                    self.metrics.preemptions.inc()
+                self._reserve_decode()
                 events.extend(self._decode_spec_with_recovery(
                     defer=self.pipelined))
             else:
@@ -1062,14 +1078,12 @@ class ServingEngine:
                                                                end)
                         if ev is not None:
                             events.append(ev)
-                for v in self.scheduler.reserve_decode():
-                    self.metrics.preemptions.inc()
+                self._reserve_decode()
                 proposals = self._plan_speculation(chunk_tokens)
                 events.extend(self._ragged_step_with_recovery(
                     proposals, include_chunks=fused))
         elif fused:
-            for v in self.scheduler.reserve_decode():
-                self.metrics.preemptions.inc()
+            self._reserve_decode()
             # pipelined + ragged_batch compose (ISSUE 12 satellite):
             # the fused launch defers exactly like a decode launch
             events.extend(self._ragged_step_with_recovery(
@@ -1079,9 +1093,7 @@ class ServingEngine:
                 ev = self._prefill_chunk_with_recovery(req, start, end)
                 if ev is not None:
                     events.append(ev)
-            # decode-page reservation; pool pressure preempts youngest-first
-            for v in self.scheduler.reserve_decode():
-                self.metrics.preemptions.inc()
+            self._reserve_decode()
             # one batched decode step over every decode-phase sequence —
             # or, when the batch qualifies (ISSUE 6: decode_horizon > 1,
             # pure greedy, no chunks in flight), one device-resident
@@ -1140,15 +1152,6 @@ class ServingEngine:
             self._prefetch_pagein()
             self.metrics.host_tier_bytes.set(tier.bytes_used)
             self.metrics.host_tier_pages_used.set(tier.used_count)
-        self.metrics.step_seconds.inc(self.metrics.clock() - t0)
-        tot = self.metrics.step_seconds.value
-        blocked = (self.metrics.drain_wait_seconds.value
-                   + self.metrics.overlapped_plan_seconds.value)
-        # host-derived zero-bubble proxy: loop time during which the
-        # host was neither blocked on a drain nor planning under an
-        # in-flight launch — i.e. time the device plausibly waited
-        self.metrics.device_idle_fraction.set(
-            max(0.0, 1.0 - min(blocked / tot, 1.0)) if tot > 0 else 0.0)
         if self.audit:
             audit_engine(self)
         return events
@@ -1161,11 +1164,18 @@ class ServingEngine:
         quarantined (finish_reason="error"). The chunk that completes the
         context (end == num_context) samples the request's next token and
         flips it into the decode phase."""
-        cow = req.kv.ensure_writable(start, end)
-        if cow:
-            self.metrics.cow_copies.inc(cow)
-        table = self.pool.pad_table(req.kv.pages, self.max_pages_per_seq)
-        chunk = req.context_tokens[start:end]
+        with _prof.span("request.prefill", request_id=req.request_id,
+                        start=start, end=end):
+            return self._prefill_chunk(req, start, end)
+
+    def _prefill_chunk(self, req: Request, start: int,
+                       end: int) -> Optional[TokenEvent]:
+        with _prof.span("engine.build_batch"):
+            cow = req.kv.ensure_writable(start, end)
+            if cow:
+                self.metrics.cow_copies.inc(cow)
+            table = self.pool.pad_table(req.kv.pages, self.max_pages_per_seq)
+            chunk = req.context_tokens[start:end]
         delay = self.retry_backoff_s
         for attempt in range(self.max_step_retries + 1):
             try:
@@ -1181,19 +1191,21 @@ class ServingEngine:
                 self._sleep(delay)
                 delay *= 2
         self.pool.pools = new_pools
-        req.kv.num_tokens = end
-        self.metrics.prefill_tokens.inc(end - start)
-        self.metrics.prefill_chunks.inc()
-        if self.pool.prefix_cache is not None:
-            self.pool.prefix_cache.register_seq(req.kv, req.context_tokens)
-        if end < req.num_context:
-            return None              # intermediate chunk: logits unread
-        tok = self._guarded_sample(logits, req)
-        if tok is None:
-            self._finish_abnormal(req, "error")
-            return None
-        req.phase = "decode"
-        return self._append_token(req, tok)
+        with _prof.span("engine.commit"):
+            req.kv.num_tokens = end
+            self.metrics.prefill_tokens.inc(end - start)
+            self.metrics.prefill_chunks.inc()
+            if self.pool.prefix_cache is not None:
+                self.pool.prefix_cache.register_seq(req.kv,
+                                                    req.context_tokens)
+            if end < req.num_context:
+                return None          # intermediate chunk: logits unread
+            tok = self._guarded_sample(logits, req)
+            if tok is None:
+                self._finish_abnormal(req, "error")
+                return None
+            req.phase = "decode"
+            return self._append_token(req, tok)
 
     def _release_spec_state(self, req: Request) -> None:
         """Drop per-request proposer/adaptive-k state on ANY terminal
@@ -1295,6 +1307,7 @@ class ServingEngine:
                               req.slot))
             if not spans:
                 return []
+            build = _prof.span("engine.build_batch").begin()
             B = self.max_batch_size
             P = self.max_pages_per_seq
             T = bucket_len(max(end - start
@@ -1315,6 +1328,7 @@ class ServingEngine:
                 starts[s] = start
                 qlens[s] = end - start
                 tables[s, :len(req.kv.pages)] = req.kv.pages
+            build.end()
             prev = self.pool.pools
             try:
                 if full:
@@ -1360,6 +1374,11 @@ class ServingEngine:
         already-drained grid); a span member that finished while the
         launch was in flight (pipelined abort/deadline) is skipped —
         its drained logits are discarded, never half-committed."""
+        with _prof.span("engine.commit"):
+            return self._commit_ragged(spans, logits, full, grid)
+
+    def _commit_ragged(self, spans, logits, full, grid
+                       ) -> List[TokenEvent]:
         # vectorized greedy/finite pass over the whole call's logits
         # ([B, V] or [B, T, V]); rows transfer lazily only when needed
         if grid is None:
@@ -1607,6 +1626,7 @@ class ServingEngine:
                      if r in row_k]
             if not batch:
                 return []
+            build = _prof.span("engine.build_batch").begin()
             B = self.max_batch_size
             P = self.max_pages_per_seq
             tokens = np.zeros((B,), np.int32)
@@ -1652,6 +1672,7 @@ class ServingEngine:
             if sampling:
                 kw.update(seeds=seeds, base_steps=base, temps=temps,
                           top_k=top_k, top_p=top_p)
+            build.end()
             prev = self.pool.pools
             try:
                 packed, new_pools = self.runner.decode_multi_spec(
@@ -1701,6 +1722,11 @@ class ServingEngine:
         on the spot — a speculated page never survives its rejection,
         and the auditor's over-provision check pins it. A batch member
         that finished while the launch was in flight is skipped."""
+        with _prof.span("engine.commit"):
+            return self._commit_spec_horizon(batch_slots, drained, drafts)
+
+    def _commit_spec_horizon(self, batch_slots, drained, drafts
+                             ) -> List[TokenEvent]:
         toks, fins, keeps = drained[0], drained[1], drained[2]
         s = toks.shape[1]
         events: List[TokenEvent] = []
@@ -1896,6 +1922,7 @@ class ServingEngine:
             batch = self.scheduler.decode_ready()
             if not batch:
                 return []
+            build = _prof.span("engine.build_batch").begin()
             B = self.max_batch_size
             P = self.max_pages_per_seq
             tokens = np.zeros((B,), np.int32)
@@ -1917,6 +1944,7 @@ class ServingEngine:
                 tables[sl, :len(req.kv.pages)] = req.kv.pages
                 pos[sl] = req.num_context - 1
             ctx = self._horizon_ctx(batch, s)
+            build.end()
             prev = self.pool.pools
             try:
                 packed, new_pools = self.runner.decode_multi(
@@ -1958,6 +1986,11 @@ class ServingEngine:
         while the launch was in flight (pipelined abort/deadline) is
         skipped — its drained tokens are discarded, never
         half-committed."""
+        with _prof.span("engine.commit"):
+            return self._commit_horizon(batch_slots, drained, s)
+
+    def _commit_horizon(self, batch_slots, drained, s: int
+                        ) -> List[TokenEvent]:
         toks, fins = drained[0], drained[1]
         live = drained[2] if drained.shape[0] > 2 else None
         events: List[TokenEvent] = []
@@ -2022,6 +2055,7 @@ class ServingEngine:
             batch = self.scheduler.decode_ready()
             if not batch:
                 return []
+            build = _prof.span("engine.build_batch").begin()
             B = self.max_batch_size
             P = self.max_pages_per_seq
             tokens = np.zeros((B,), np.int32)
@@ -2038,6 +2072,7 @@ class ServingEngine:
                 tokens[s] = req.output_tokens[-1]
                 tables[s, :len(req.kv.pages)] = req.kv.pages
                 pos[s] = req.num_context - 1   # position of the fed token
+            build.end()
             prev = self.pool.pools
             try:
                 logits, new_pools = self.runner.decode(tokens, tables, pos,
@@ -2074,6 +2109,10 @@ class ServingEngine:
         the pipelined commit (which passes the already-drained grid). A
         batch member that finished while the launch was in flight is
         skipped."""
+        with _prof.span("engine.commit"):
+            return self._commit_decode(batch_slots, logits, grid)
+
+    def _commit_decode(self, batch_slots, logits, grid) -> List[TokenEvent]:
         if grid is None:
             grid = self._timed_drain(lambda: greedy_grid(logits))
             self.metrics.host_syncs.inc()

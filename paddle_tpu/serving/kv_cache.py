@@ -77,8 +77,12 @@ from bisect import insort
 from dataclasses import dataclass, field
 from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
+import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec
+
+from paddle_tpu import profiler as _prof
 
 SCRATCH_PAGE = 0
 
@@ -1829,61 +1833,47 @@ class KVCachePool:
         store_dtype = jnp.float8_e4m3fn if kv_dtype == "fp8" else dtype
         shape = (num_blocks, block_size, n_kv_heads, head_dim)
         sshape = (num_blocks, n_kv_heads)     # one scale per page per head
+        # a layer's arrays as (shape, dtype, PartitionSpec on a mesh)
+        pages = PartitionSpec(None, None, model_axis, None)
+        if kv_dtype == "int8":
+            # the scale pool shares the pool's page geometry and shards
+            # along the SAME kv-head axis: each model shard dequantizes
+            # its own head slice with its own scales
+            scales = PartitionSpec(None, model_axis)
+            layer = [(shape, jnp.int8, pages), (shape, jnp.int8, pages),
+                     (sshape, jnp.float32, scales),
+                     (sshape, jnp.float32, scales)]
+        elif kv_dtype == "mixed":
+            # mixed-precision tenants (ISSUE 15): fp32 storage + a
+            # per-page tag plane steering the write path — one plane
+            # per layer tuple so the pools stay a uniform pytree
+            # through every jitted step (the planes are kept identical;
+            # tag_pages updates all of them). The tag plane has no head
+            # axis — replicated per shard
+            layer = [(shape, dtype, pages), (shape, dtype, pages),
+                     ((num_blocks,), bool, PartitionSpec())]
+        else:                          # fp32 or native fp8 pages
+            layer = [(shape, store_dtype, pages),
+                     (shape, store_dtype, pages)]
         if mesh is not None:
-            import jax
-            from jax.sharding import NamedSharding, PartitionSpec
-
             self.tp_size = int(mesh.shape[model_axis])
             if n_kv_heads % self.tp_size:
                 raise ValueError(
                     f"n_kv_heads={n_kv_heads} is not divisible by the "
                     f"model-axis degree {self.tp_size}: the paged pools "
                     "shard in whole kv-heads (GQA rule)")
-            sharding = NamedSharding(
-                mesh, PartitionSpec(None, None, model_axis, None))
-            if kv_dtype == "int8":
-                # the scale pool shares the pool's page geometry and
-                # shards along the SAME kv-head axis: each model shard
-                # dequantizes its own head slice with its own scales
-                s_shard = NamedSharding(mesh, PartitionSpec(None, model_axis))
-                self.pools = [
-                    (jax.device_put(jnp.zeros(shape, jnp.int8), sharding),
-                     jax.device_put(jnp.zeros(shape, jnp.int8), sharding),
-                     jax.device_put(jnp.zeros(sshape, jnp.float32), s_shard),
-                     jax.device_put(jnp.zeros(sshape, jnp.float32), s_shard))
-                    for _ in range(num_layers)]
-            elif kv_dtype == "mixed":
-                # the tag plane has no head axis — replicated per shard
-                rep = NamedSharding(mesh, PartitionSpec())
-                self.pools = [
-                    (jax.device_put(jnp.zeros(shape, dtype), sharding),
-                     jax.device_put(jnp.zeros(shape, dtype), sharding),
-                     jax.device_put(jnp.zeros((num_blocks,), bool), rep))
-                    for _ in range(num_layers)]
-            else:                          # fp32 or native fp8 pages
-                self.pools = [
-                    (jax.device_put(jnp.zeros(shape, store_dtype), sharding),
-                     jax.device_put(jnp.zeros(shape, store_dtype), sharding))
-                    for _ in range(num_layers)]
-        elif kv_dtype == "int8":
-            self.pools = [(jnp.zeros(shape, jnp.int8),
-                           jnp.zeros(shape, jnp.int8),
-                           jnp.zeros(sshape, jnp.float32),
-                           jnp.zeros(sshape, jnp.float32))
-                          for _ in range(num_layers)]
-        elif kv_dtype == "mixed":
-            # mixed-precision tenants (ISSUE 15): fp32 storage + a
-            # per-page tag plane steering the write path — one plane
-            # per layer tuple so the pools stay a uniform pytree
-            # through every jitted step (the planes are kept identical;
-            # tag_pages updates all of them)
-            self.pools = [(jnp.zeros(shape, dtype),
-                           jnp.zeros(shape, dtype),
-                           jnp.zeros((num_blocks,), bool))
-                          for _ in range(num_layers)]
+
+            def zeros(shp, dt, spec):
+                return jax.device_put(jnp.zeros(shp, dt),
+                                      NamedSharding(mesh, spec))
         else:
-            self.pools = [(jnp.zeros(shape, store_dtype),
-                           jnp.zeros(shape, store_dtype))
+            def zeros(shp, dt, spec):
+                return jnp.zeros(shp, dt)
+
+        # the pages themselves: zero fills dispatched here, which finish
+        # on the device behind whatever comes next
+        with _prof.always_span("kv_pool.alloc", num_blocks=num_blocks):
+            self.pools = [tuple(zeros(*a) for a in layer)
                           for _ in range(num_layers)]
 
     # -------------------------------- per-request kv-dtype tags (ISSUE 15)

@@ -3,8 +3,10 @@
 Reference: the reference's serving stack exposes per-predictor profiling
 (paddle/fluid/inference/api/analysis_predictor.cc perf stats) and the
 deployment servers around it report QPS/latency. Here the engine itself
-owns the instruments the bench harness needs: queue depth, time-to-first
--token, tokens/s, KV-pool utilization, preemption count.
+owns the counts an operator needs: queue depth, time-to-first-token,
+KV-pool utilization, preemptions, host syncs. Rates and the split of a
+step's time are measured from outside (bench/) and from the engine's
+spans (paddle_tpu.profiler).
 
 Everything is plain python (host-side) — the engine records around its
 device calls, never inside a traced function. The clock is injectable so
@@ -102,8 +104,7 @@ SUMMABLE_KEYS = (
     "spec_proposed_tokens", "spec_accepted_tokens", "spec_rollback_pages",
     "spec_fused_horizons", "spec_dead_positions",
     "host_syncs", "decode_horizon_steps", "horizon_overshoot_tokens",
-    "planned_ahead_steps", "host_plan_seconds", "overlapped_plan_seconds",
-    "drain_wait_seconds", "step_seconds",
+    "planned_ahead_steps",
     "offload_spill_pages", "pagein_pages", "pagein_hidden_pages",
     "offload_resumes", "offload_recompute_fallbacks", "host_tier_drops",
     "host_tier_bytes",
@@ -113,16 +114,14 @@ SUMMABLE_KEYS = (
     "decode_steps", "queue_depth", "running", "pool_used_pages",
 )
 
-MAX_KEYS = ("queue_depth_peak", "pool_utilization_peak", "busy_seconds")
+MAX_KEYS = ("queue_depth_peak", "pool_utilization_peak")
 
 
 def aggregate_snapshots(snaps) -> Dict[str, float]:
     """Merge several EngineMetrics snapshots into one tier-level view:
-    counters sum, peaks take the max (replicas run concurrently, so
-    busy_seconds is the max too — the tier was busy as long as its
-    busiest replica), and derived ratios are recomputed from the summed
-    counters. Percentile keys are intentionally absent (see
-    SUMMABLE_KEYS)."""
+    counters sum, peaks take the max, and derived ratios are recomputed
+    from the summed counters. Percentile keys are intentionally absent
+    (see SUMMABLE_KEYS)."""
     snaps = list(snaps)
     out: Dict[str, float] = {k: 0.0 for k in SUMMABLE_KEYS}
     for k in MAX_KEYS:
@@ -142,13 +141,6 @@ def aggregate_snapshots(snaps) -> Dict[str, float]:
     out["steps_per_token"] = out["decode_steps"] / toks if toks > 0 else 0.0
     out["host_syncs_per_token"] = out["host_syncs"] / toks if toks > 0 \
         else 0.0
-    st = out["step_seconds"]
-    out["device_idle_fraction"] = (
-        max(0.0, 1.0 - min((out["drain_wait_seconds"]
-                            + out["overlapped_plan_seconds"]) / st, 1.0))
-        if st > 0 else 0.0)
-    out["tokens_per_sec"] = (toks / out["busy_seconds"]
-                             if out["busy_seconds"] > 0 else 0.0)
     # quantized collectives (ISSUE 15): the tier-level comm reduction
     # is recomputed from the SUMMED byte counters, never averaged
     # (per-replica ratios over different traffic cannot be averaged
@@ -166,9 +158,12 @@ def aggregate_snapshots(snaps) -> Dict[str, float]:
 class EngineMetrics:
     """The engine's instrument panel, read through snapshot().
 
-    TTFT is measured from add_request() to the first sampled token of that
-    request (admission wait + prefill), the number an offered-load sweep
-    cares about; decode throughput is finished tokens / engine busy time.
+    Counts, gauges and histograms of what the engine did, counted where
+    it happens. How long each part of a step took is not here: the
+    engine's spans (`paddle_tpu.profiler`) carry that. TTFT is measured
+    from add_request() to the first sampled token of that request
+    (admission wait + prefill), by the injectable clock that also serves
+    arrival times and deadlines.
     """
 
     def __init__(self, clock: Optional[Callable[[], float]] = None):
@@ -220,22 +215,10 @@ class EngineMetrics:
         self.horizon_overshoot_tokens = Counter("horizon_overshoot_tokens")
         # zero-bubble pipelined loop (ISSUE 11): planned_ahead_steps
         # counts steps whose host planning ran while a previous launch
-        # was still in flight on the device; the *_seconds counters
-        # split each step's wall time into host planning (overlapped_
-        # plan_seconds is the subset that had device compute to hide
-        # behind), blocking device->host drain waits, and the rest.
-        # device_idle_fraction is the host-derived proxy the bench
-        # commits: the share of loop wall time during which the host
-        # was neither blocked on the device nor planning under an
-        # in-flight launch — i.e. time the device plausibly idled
-        # waiting for the host (~the whole planning interval on the
-        # unpipelined loop, ~0 pipelined).
+        # was still in flight on the device. Where a step's time goes
+        # (plan, batch build, launch, drain, commit) is read from the
+        # engine's spans against the device trace, not counted here
         self.planned_ahead_steps = Counter("planned_ahead_steps")
-        self.host_plan_seconds = Counter("host_plan_seconds")
-        self.overlapped_plan_seconds = Counter("overlapped_plan_seconds")
-        self.drain_wait_seconds = Counter("drain_wait_seconds")
-        self.step_seconds = Counter("step_seconds")
-        self.device_idle_fraction = Gauge("device_idle_fraction")
         # tiered KV offload (ISSUE 10): offload_spill_pages counts device
         # pages copied to the host tier (preemption spills AND prefix
         # demotions), pagein_pages counts pages restored to device, and
@@ -328,25 +311,6 @@ class EngineMetrics:
         self.batch_occupancy = Histogram("batch_occupancy")
         self.ttft_s = Histogram("ttft_s")
         self.e2e_latency_s = Histogram("e2e_latency_s")
-        self._start_t: Optional[float] = None
-        self._last_t: Optional[float] = None
-
-    def mark_active(self) -> None:
-        """Called once per engine step; bounds the busy window."""
-        t = self.clock()
-        if self._start_t is None:
-            self._start_t = t
-        self._last_t = t
-
-    @property
-    def busy_seconds(self) -> float:
-        if self._start_t is None or self._last_t is None:
-            return 0.0
-        return self._last_t - self._start_t
-
-    def tokens_per_sec(self) -> float:
-        dt = self.busy_seconds
-        return self.tokens_generated.value / dt if dt > 0 else 0.0
 
     def spec_acceptance_rate(self) -> float:
         """Accepted / proposed draft tokens (0.0 when nothing proposed)."""
@@ -416,11 +380,6 @@ class EngineMetrics:
             "decode_horizon_steps": self.decode_horizon_steps.value,
             "horizon_overshoot_tokens": self.horizon_overshoot_tokens.value,
             "planned_ahead_steps": self.planned_ahead_steps.value,
-            "host_plan_seconds": self.host_plan_seconds.value,
-            "overlapped_plan_seconds": self.overlapped_plan_seconds.value,
-            "drain_wait_seconds": self.drain_wait_seconds.value,
-            "step_seconds": self.step_seconds.value,
-            "device_idle_fraction": self.device_idle_fraction.value,
             "offload_spill_pages": self.offload_spill_pages.value,
             "pagein_pages": self.pagein_pages.value,
             "pagein_hidden_pages": self.pagein_hidden_pages.value,
@@ -452,6 +411,4 @@ class EngineMetrics:
             "ttft_s_mean": self.ttft_s.mean,
             "e2e_latency_s_p50": self.e2e_latency_s.percentile(50),
             "e2e_latency_s_p99": self.e2e_latency_s.percentile(99),
-            "tokens_per_sec": self.tokens_per_sec(),
-            "busy_seconds": self.busy_seconds,
         }

@@ -121,6 +121,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 logger = logging.getLogger(__name__)
 
+from paddle_tpu import profiler as _prof
 from paddle_tpu.models.generation import (
     _block_params, _layer_norm, _mlp, masked_cache_attention, paged_gather,
 )
@@ -1313,7 +1314,12 @@ class PagedModelRunner:
             # tracing and compilation happen here, not at jax.jit above:
             # a failure is the program's, not a transient device fault
             try:
-                out = jitted(*args)
+                # trace + lower + compile (or the compile cache's load)
+                # and this call's dispatch: always recorded, a child of
+                # the step that caused it
+                with _prof.always_span("runner.compile", kind=kind,
+                                       key=shape_key):
+                    out = jitted(*args)
             except Exception as e:
                 self._jit_cache.pop(key, None)
                 raise StepCompileError(
@@ -1348,36 +1354,41 @@ class PagedModelRunner:
         callers only sample from the chunk that completes the context.
         Chunk lengths share the power-of-2 prefill buckets, so chunking
         never compiles per odd length."""
-        t = len(tokens)
-        tb = bucket_len(t)
-        padded = np.zeros((1, tb), np.int32)
-        padded[0, :t] = tokens
-        self._account_attn(self._attn_impl_for(tb),
-                           np.asarray([start_pos]), np.asarray([t]),
-                           len(table_row))
-        self._account_comm(tb)
-        fn = self._jitted("prefill", tb)
-        # host operands go to the jitted fn as-is — jit commits them in
-        # one hop; a jnp.asarray(np.asarray(...)) round-trip here used to
-        # stage an extra host copy per call (ISSUE 6 satellite). Sharded
-        # runners stage them in ONE replicated device_put (ISSUE 7)
-        toks, table = self._stage(padded,
-                                  np.asarray(table_row, np.int32)[None])
-        return fn(self.params, toks, table,
-                  np.int32(t), np.int32(start_pos), pools)
+        with _prof.span("runner.launch") as launch:
+            t = len(tokens)
+            tb = bucket_len(t)
+            padded = np.zeros((1, tb), np.int32)
+            padded[0, :t] = tokens
+            self._account_attn(self._attn_impl_for(tb),
+                               np.asarray([start_pos]), np.asarray([t]),
+                               len(table_row))
+            self._account_comm(tb)
+            fn = self._jitted("prefill", tb)
+            launch.set(kind="prefill", key=tb)
+            # host operands go to the jitted fn as-is — jit commits them in
+            # one hop; a jnp.asarray(np.asarray(...)) round-trip here used to
+            # stage an extra host copy per call (ISSUE 6 satellite). Sharded
+            # runners stage them in ONE replicated device_put (ISSUE 7)
+            toks, table = self._stage(padded,
+                                      np.asarray(table_row, np.int32)[None])
+            return fn(self.params, toks, table,
+                      np.int32(t), np.int32(start_pos), pools)
 
     def decode(self, tokens, tables, pos, pools):
         """Batched decode step; tokens [B], tables [B, P], pos [B]."""
-        pos_np = np.asarray(pos, np.int32)
-        self._account_attn(self._attn_impl_for(1), pos_np,
-                           np.ones_like(pos_np),
-                           np.asarray(tables).shape[1])
-        self._account_comm(pos_np.shape[0])
-        fn = self._jitted("decode", np.asarray(tokens).shape[0])
-        toks, tabs, pos_a = self._stage(
-            np.asarray(tokens, np.int32)[:, None],
-            np.asarray(tables, np.int32), pos_np)
-        return fn(self.params, toks, tabs, pos_a, pools)
+        with _prof.span("runner.launch") as launch:
+            pos_np = np.asarray(pos, np.int32)
+            self._account_attn(self._attn_impl_for(1), pos_np,
+                               np.ones_like(pos_np),
+                               np.asarray(tables).shape[1])
+            self._account_comm(pos_np.shape[0])
+            B = np.asarray(tokens).shape[0]
+            fn = self._jitted("decode", B)
+            launch.set(kind="decode", key=B)
+            toks, tabs, pos_a = self._stage(
+                np.asarray(tokens, np.int32)[:, None],
+                np.asarray(tables, np.int32), pos_np)
+            return fn(self.params, toks, tabs, pos_a, pools)
 
     def decode_multi(self, tokens, tables, pos, pools, num_steps: int, *,
                      seeds=None, base_steps=None, temps=None,
@@ -1405,45 +1416,48 @@ class PagedModelRunner:
         KV writes freeze and subsequent steps emit dead tokens flagged
         by a third packed plane. Any extension makes the return shape
         [3, B, num_steps] (tokens, finite, LIVE)."""
-        if num_steps < 1:
-            raise ValueError("decode_multi needs num_steps >= 1")
-        pos_np = np.asarray(pos, np.int32)
-        impl = self._attn_impl_for(1)
-        width = np.asarray(tables).shape[1]
-        for t in range(num_steps):      # inner step t attends at pos + t
-            # host-side byte analytics; early-stopped rows may freeze
-            # earlier, so this upper-bounds the extended horizon's reads
-            self._account_attn(impl, pos_np + t, np.ones_like(pos_np),
-                               width)
-        self._account_comm(pos_np.shape[0], steps=num_steps)
-        B = pos_np.shape[0]
-        sampling = temps is not None
-        extended = sampling or early_stop
-        if not extended:
-            fn = self._jitted("decode_multi", (B, num_steps))
-            toks, tabs, pos_a = self._stage(np.asarray(tokens, np.int32),
-                                            np.asarray(tables, np.int32),
-                                            pos_np)
-            return fn(self.params, toks, tabs, pos_a, pools, num_steps)
-        seeds = np.zeros((B,), np.int32) if seeds is None \
-            else np.asarray(seeds, np.int32)
-        base_steps = np.zeros((B,), np.int32) if base_steps is None \
-            else np.asarray(base_steps, np.int32)
-        temps = np.zeros((B,), np.float32) if temps is None \
-            else np.asarray(temps, np.float32)
-        stop_ids = np.full((B, 1), -1, np.int32) if stop_ids is None \
-            else np.asarray(stop_ids, np.int32)
-        remaining = np.full((B,), num_steps, np.int32) if remaining is None \
-            else np.asarray(remaining, np.int32)
-        fn = self._jitted("decode_multi_x",
-                          (B, num_steps, top_k, top_p, sampling,
-                           bool(early_stop), stop_ids.shape[1]))
-        toks, tabs, pos_a, sd, bs, tp, si, rem = self._stage(
-            np.asarray(tokens, np.int32), np.asarray(tables, np.int32),
-            pos_np, seeds, base_steps, temps, stop_ids, remaining)
-        return fn(self.params, toks, tabs, pos_a, pools, sd, bs, tp, si,
-                  rem, num_steps, top_k, top_p, sampling,
-                  bool(early_stop))
+        with _prof.span("runner.launch") as launch:
+            if num_steps < 1:
+                raise ValueError("decode_multi needs num_steps >= 1")
+            pos_np = np.asarray(pos, np.int32)
+            impl = self._attn_impl_for(1)
+            width = np.asarray(tables).shape[1]
+            for t in range(num_steps):      # inner step t attends at pos + t
+                # host-side byte analytics; early-stopped rows may freeze
+                # earlier, so this upper-bounds the extended horizon's reads
+                self._account_attn(impl, pos_np + t, np.ones_like(pos_np),
+                                   width)
+            self._account_comm(pos_np.shape[0], steps=num_steps)
+            B = pos_np.shape[0]
+            sampling = temps is not None
+            extended = sampling or early_stop
+            if not extended:
+                fn = self._jitted("decode_multi", (B, num_steps))
+                launch.set(kind="decode_multi", key=(B, num_steps))
+                toks, tabs, pos_a = self._stage(np.asarray(tokens, np.int32),
+                                                np.asarray(tables, np.int32),
+                                                pos_np)
+                return fn(self.params, toks, tabs, pos_a, pools, num_steps)
+            seeds = np.zeros((B,), np.int32) if seeds is None \
+                else np.asarray(seeds, np.int32)
+            base_steps = np.zeros((B,), np.int32) if base_steps is None \
+                else np.asarray(base_steps, np.int32)
+            temps = np.zeros((B,), np.float32) if temps is None \
+                else np.asarray(temps, np.float32)
+            stop_ids = np.full((B, 1), -1, np.int32) if stop_ids is None \
+                else np.asarray(stop_ids, np.int32)
+            remaining = np.full((B,), num_steps, np.int32) \
+                if remaining is None else np.asarray(remaining, np.int32)
+            key = (B, num_steps, top_k, top_p, sampling, bool(early_stop),
+                   stop_ids.shape[1])
+            fn = self._jitted("decode_multi_x", key)
+            launch.set(kind="decode_multi_x", key=key)
+            toks, tabs, pos_a, sd, bs, tp, si, rem = self._stage(
+                np.asarray(tokens, np.int32), np.asarray(tables, np.int32),
+                pos_np, seeds, base_steps, temps, stop_ids, remaining)
+            return fn(self.params, toks, tabs, pos_a, pools, sd, bs, tp, si,
+                      rem, num_steps, top_k, top_p, sampling,
+                      bool(early_stop))
 
     def decode_multi_spec(self, tokens, tables, pos, pools, drafts, *,
                           seeds=None, base_steps=None, temps=None,
@@ -1462,37 +1476,39 @@ class PagedModelRunner:
         page range. Seeded sampling mirrors decode_multi's extension
         operands. Returns (packed [3, B, s, K+1] int32, pools): planes
         tokens / finiteness / keep-mask, one host transfer per horizon."""
-        drafts = np.asarray(drafts, np.int32)
-        if drafts.ndim != 3 or drafts.shape[1] < 1:
-            raise ValueError(
-                f"drafts must be [B, num_steps>=1, K], got {drafts.shape}")
-        B, num_steps, K = drafts.shape
-        pos_np = np.asarray(pos, np.int32)
-        width = np.asarray(tables).shape[1]
-        impl = self._attn_impl_for(K + 1)
-        spans = np.full((B,), K + 1, np.int32)
-        for t in range(num_steps):   # upper-bounds the per-step reads
-            self._account_attn(impl, pos_np + t * (K + 1), spans, width)
-        self._account_comm(B * (K + 1), steps=num_steps)
-        sampling = temps is not None
-        seeds = np.zeros((B,), np.int32) if seeds is None \
-            else np.asarray(seeds, np.int32)
-        base_steps = np.zeros((B,), np.int32) if base_steps is None \
-            else np.asarray(base_steps, np.int32)
-        temps = np.zeros((B,), np.float32) if temps is None \
-            else np.asarray(temps, np.float32)
-        stop_ids = np.full((B, 1), -1, np.int32) if stop_ids is None \
-            else np.asarray(stop_ids, np.int32)
-        remaining = np.full((B,), num_steps * (K + 1), np.int32) \
-            if remaining is None else np.asarray(remaining, np.int32)
-        fn = self._jitted("decode_multi_spec",
-                          (B, num_steps, K, top_k, top_p, sampling,
-                           stop_ids.shape[1]))
-        toks, tabs, pos_a, dr, sd, bs, tp, si, rem = self._stage(
-            np.asarray(tokens, np.int32), np.asarray(tables, np.int32),
-            pos_np, drafts, seeds, base_steps, temps, stop_ids, remaining)
-        return fn(self.params, toks, tabs, pos_a, pools, dr, sd, bs, tp,
-                  si, rem, num_steps, top_k, top_p, sampling)
+        with _prof.span("runner.launch") as launch:
+            drafts = np.asarray(drafts, np.int32)
+            if drafts.ndim != 3 or drafts.shape[1] < 1:
+                raise ValueError(
+                    f"drafts must be [B, num_steps>=1, K], got {drafts.shape}")
+            B, num_steps, K = drafts.shape
+            pos_np = np.asarray(pos, np.int32)
+            width = np.asarray(tables).shape[1]
+            impl = self._attn_impl_for(K + 1)
+            spans = np.full((B,), K + 1, np.int32)
+            for t in range(num_steps):   # upper-bounds the per-step reads
+                self._account_attn(impl, pos_np + t * (K + 1), spans, width)
+            self._account_comm(B * (K + 1), steps=num_steps)
+            sampling = temps is not None
+            seeds = np.zeros((B,), np.int32) if seeds is None \
+                else np.asarray(seeds, np.int32)
+            base_steps = np.zeros((B,), np.int32) if base_steps is None \
+                else np.asarray(base_steps, np.int32)
+            temps = np.zeros((B,), np.float32) if temps is None \
+                else np.asarray(temps, np.float32)
+            stop_ids = np.full((B, 1), -1, np.int32) if stop_ids is None \
+                else np.asarray(stop_ids, np.int32)
+            remaining = np.full((B,), num_steps * (K + 1), np.int32) \
+                if remaining is None else np.asarray(remaining, np.int32)
+            key = (B, num_steps, K, top_k, top_p, sampling,
+                   stop_ids.shape[1])
+            fn = self._jitted("decode_multi_spec", key)
+            launch.set(kind="decode_multi_spec", key=key)
+            toks, tabs, pos_a, dr, sd, bs, tp, si, rem = self._stage(
+                np.asarray(tokens, np.int32), np.asarray(tables, np.int32),
+                pos_np, drafts, seeds, base_steps, temps, stop_ids, remaining)
+            return fn(self.params, toks, tabs, pos_a, pools, dr, sd, bs, tp,
+                      si, rem, num_steps, top_k, top_p, sampling)
 
     def ragged_step(self, tokens, tables, start_pos, q_lens, pools,
                     full_logits: bool = False):
@@ -1505,17 +1521,20 @@ class PagedModelRunner:
         [B, V] at each span's last live row, or the full per-position
         [B, T, V] when `full_logits=True` — the speculative verify step
         (ISSUE 5) scores all k+1 span positions from one launch."""
-        tokens = np.asarray(tokens, np.int32)
-        B, T = tokens.shape
-        start_pos = np.asarray(start_pos, np.int32)
-        q_lens = np.asarray(q_lens, np.int32)
-        self._account_attn(self._attn_impl_for(T), start_pos, q_lens,
-                           np.asarray(tables).shape[1])
-        self._account_comm(B * T)
-        fn = self._jitted("ragged_full" if full_logits else "ragged", (B, T))
-        toks, tabs, starts, lens = self._stage(
-            tokens, np.asarray(tables, np.int32), start_pos, q_lens)
-        return fn(self.params, toks, tabs, starts, lens, pools)
+        with _prof.span("runner.launch") as launch:
+            tokens = np.asarray(tokens, np.int32)
+            B, T = tokens.shape
+            start_pos = np.asarray(start_pos, np.int32)
+            q_lens = np.asarray(q_lens, np.int32)
+            self._account_attn(self._attn_impl_for(T), start_pos, q_lens,
+                               np.asarray(tables).shape[1])
+            self._account_comm(B * T)
+            kind = "ragged_full" if full_logits else "ragged"
+            fn = self._jitted(kind, (B, T))
+            launch.set(kind=kind, key=(B, T))
+            toks, tabs, starts, lens = self._stage(
+                tokens, np.asarray(tables, np.int32), start_pos, q_lens)
+            return fn(self.params, toks, tabs, starts, lens, pools)
 
     def _forward(self, params, tokens, positions, write_page, write_off,
                  tables, pos_q, q_lens, pools):
@@ -1703,51 +1722,63 @@ class GPTRunner(PagedModelRunner):
         B, T = tokens.shape
         d = self.head_dim
         impl = self._attn_impl_for(T)
-        x = (jnp.take(params["wte.weight"], tokens, axis=0)
-             + jnp.take(params["wpe.weight"], positions, axis=0))
+        # scope names reach the device trace: embed, block/attn, block/mlp,
+        # final_norm, lm_head (the same as models/gpt.py gives training)
+        with jax.named_scope("embed"):
+            x = (jnp.take(params["wte.weight"], tokens, axis=0)
+                 + jnp.take(params["wpe.weight"], positions, axis=0))
         new_pools = []
         for i in range(cfg.num_layers):
             p = _block_params(params, i)
-            h = _layer_norm(x, p["ln1.weight"], p["ln1.bias"])
-            qkv = (self._mm(p, "attn.qkv.weight", h) + p["attn.qkv.bias"]
-                   ).reshape(B, T, 3, self.n_heads, d)
-            q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
-            q, k, v = self._constrain_heads(q, k, v)
-            out, layer = paged_attend(
-                q, k, v, pools[i], tables, write_page,
-                write_off, pos_q, q_lens, 1, impl,
-                shard_ctx=self._shard_ctx)
-            x = x + (self._mm(p, "attn.out.weight", out)
-                     + p["attn.out.bias"])
-            h = _layer_norm(x, p["ln2.weight"], p["ln2.bias"])
-            fc1 = p.get("mlp.fc1.weight")
-            if fc1 is not None and (
-                    "mlp.fc1.weight" + SCALE_SUFFIX in p
-                    or str(fc1.dtype).startswith("float8")):
-                # dense MLP with quantized weights (scale-carrying int8/
-                # int4 or scale-free fp8 — keyed on both, since fp8 has
-                # no scale entry): same gelu(fc1)+fc2 math, matmuls
-                # through the dequant epilogue (_mlp stays the untouched
-                # fp32 path so the default is bit-identical)
-                hm = jax.nn.gelu(self._mm(p, "mlp.fc1.weight", h)
-                                 + p["mlp.fc1.bias"], approximate=True)
-                x = x + self._mm(p, "mlp.fc2.weight", hm) + p["mlp.fc2.bias"]
-            else:
-                x = x + _mlp(p, h)
+            with jax.named_scope("block/attn"):
+                h = _layer_norm(x, p["ln1.weight"], p["ln1.bias"])
+                qkv = (self._mm(p, "attn.qkv.weight", h)
+                       + p["attn.qkv.bias"]
+                       ).reshape(B, T, 3, self.n_heads, d)
+                q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
+                q, k, v = self._constrain_heads(q, k, v)
+                out, layer = paged_attend(
+                    q, k, v, pools[i], tables, write_page,
+                    write_off, pos_q, q_lens, 1, impl,
+                    shard_ctx=self._shard_ctx)
+                x = x + (self._mm(p, "attn.out.weight", out)
+                         + p["attn.out.bias"])
+            with jax.named_scope("block/mlp"):
+                h = _layer_norm(x, p["ln2.weight"], p["ln2.bias"])
+                fc1 = p.get("mlp.fc1.weight")
+                if fc1 is not None and (
+                        "mlp.fc1.weight" + SCALE_SUFFIX in p
+                        or str(fc1.dtype).startswith("float8")):
+                    # dense MLP with quantized weights (scale-carrying
+                    # int8/int4 or scale-free fp8 — keyed on both, since
+                    # fp8 has no scale entry): same gelu(fc1)+fc2 math,
+                    # matmuls through the dequant epilogue (_mlp stays
+                    # the untouched fp32 path so the default is
+                    # bit-identical)
+                    hm = jax.nn.gelu(self._mm(p, "mlp.fc1.weight", h)
+                                     + p["mlp.fc1.bias"], approximate=True)
+                    x = (x + self._mm(p, "mlp.fc2.weight", hm)
+                         + p["mlp.fc2.bias"])
+                else:
+                    x = x + _mlp(p, h)
             new_pools.append(layer)
-        x = _layer_norm(x, params["ln_f.weight"], params["ln_f.bias"])
-        if "lm_head.weight" in params and (
-                "lm_head.weight" + SCALE_SUFFIX in params
-                or str(params["lm_head.weight"].dtype).startswith("float8")
-                or (self.comm_dtype != "fp32"
-                    and "lm_head.weight" in self._gather_names)):
-            # quantized head, or a head whose gather is routed through
-            # the explicit quantized collective (ISSUE 19)
-            logits = self._mm(params, "lm_head.weight", x)
-        elif "lm_head.weight" in params:
-            logits = jnp.einsum("bth,hv->btv", x, params["lm_head.weight"])
-        else:
-            logits = jnp.einsum("bth,vh->btv", x, params["wte.weight"])
+        with jax.named_scope("final_norm"):
+            x = _layer_norm(x, params["ln_f.weight"], params["ln_f.bias"])
+        with jax.named_scope("lm_head"):
+            if "lm_head.weight" in params and (
+                    "lm_head.weight" + SCALE_SUFFIX in params
+                    or str(params["lm_head.weight"].dtype
+                           ).startswith("float8")
+                    or (self.comm_dtype != "fp32"
+                        and "lm_head.weight" in self._gather_names)):
+                # quantized head, or a head whose gather is routed through
+                # the explicit quantized collective (ISSUE 19)
+                logits = self._mm(params, "lm_head.weight", x)
+            elif "lm_head.weight" in params:
+                logits = jnp.einsum("bth,hv->btv", x,
+                                    params["lm_head.weight"])
+            else:
+                logits = jnp.einsum("bth,vh->btv", x, params["wte.weight"])
         return logits, new_pools
 
 

@@ -157,6 +157,9 @@ class Request:                # requests by object, never by field value
     admission_index: int = -1              # set fresh at every admission
     num_preemptions: int = 0
     arrival_time: float = 0.0
+    # when add_request queued it, on the spans' clock (paddle_tpu.profiler);
+    # the `request.queue` span closes at the first admission and clears it
+    queued_ns: Optional[int] = None
     first_token_time: Optional[float] = None
     finish_time: Optional[float] = None
 

@@ -128,7 +128,12 @@ def test_profiler_host_events(tmp_path):
     from paddle_tpu import profiler as prof_mod
     from paddle_tpu.profiler import Profiler, ProfilerTarget, RecordEvent
 
-    p = Profiler(targets=[ProfilerTarget.CPU])
+    # the session's .xplane.pb goes where the constructor says; the
+    # program's spans are read from the one ring (ISSUE 25)
+    p = Profiler(targets=[ProfilerTarget.CPU],
+                 trace_dir=str(tmp_path / "xplane"))
+    with RecordEvent("before_start"):
+        pass                                 # no session: not recorded
     p.start()
     with RecordEvent("my_region"):
         paddle.matmul(paddle.ones([8, 8]), paddle.ones([8, 8]))
@@ -139,7 +144,10 @@ def test_profiler_host_events(tmp_path):
     with open(path) as f:
         trace = json.load(f)
     names = [e["name"] for e in trace["traceEvents"]]
-    assert "my_region" in names
+    assert names == ["my_region"]
+    assert [s[0] for s in p.spans()] == ["my_region"]
+    assert any(f.endswith(".xplane.pb") for _, _, fs in
+               os.walk(tmp_path / "xplane") for f in fs)
     table = p.summary()
     assert "my_region" in table
 

@@ -139,16 +139,6 @@ def test_histogram_percentiles_exact():
     assert h.count == 5 and h.mean == 5.0
 
 
-def test_metrics_virtual_clock():
-    t = [0.0]
-    m = EngineMetrics(clock=lambda: t[0])
-    m.mark_active()
-    m.tokens_generated.inc(10)
-    t[0] = 2.0
-    m.mark_active()
-    assert m.tokens_per_sec() == 5.0
-
-
 # ---------------------------------------------------------- end-to-end
 
 

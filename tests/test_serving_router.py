@@ -559,14 +559,13 @@ def test_metrics_aggregation():
     snaps[1]["tokens_generated"] = 6.0
     snaps[0]["decode_steps"] = 5.0
     snaps[1]["decode_steps"] = 3.0
-    snaps[0]["busy_seconds"] = 2.0
-    snaps[1]["busy_seconds"] = 4.0
+    snaps[0]["queue_depth_peak"] = 2.0
+    snaps[1]["queue_depth_peak"] = 4.0
     agg = aggregate_snapshots(snaps)
     assert agg["tokens_generated"] == 16.0
     assert agg["decode_steps"] == 8.0
-    assert agg["busy_seconds"] == 4.0         # replicas run concurrently
+    assert agg["queue_depth_peak"] == 4.0     # peaks take the max
     assert agg["steps_per_token"] == 0.5
-    assert agg["tokens_per_sec"] == 4.0
     assert "ttft_s_p99" not in agg            # percentiles don't merge
 
     with make_router(supervise=False) as router:

@@ -85,7 +85,8 @@ def test_profiler_device_trace(tpu_backend, tmp_path):
     from paddle_tpu import profiler
 
     prof = profiler.Profiler(targets=[profiler.ProfilerTarget.CPU],
-                             on_trace_ready=None)
+                             on_trace_ready=None,
+                             trace_dir=str(tmp_path / "xplane"))
     prof.start()
     x = paddle.to_tensor(np.ones((256, 256), "float32"))
     (x @ x).numpy()

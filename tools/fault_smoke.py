@@ -62,7 +62,7 @@ Injected failures now land either at dispatch (retried before the
 launch defers) or surface at the deferred drain (pool rollback + sync
 rerun) — recovery must stay token-exact against the same oracles, and
 the auditor holds with a launch in flight. Records add
-planned_ahead_steps / device_idle_fraction.
+planned_ahead_steps.
 
 ISSUE 7: `--tp N` drills all fault classes on a TENSOR-PARALLEL engine:
 the runner's weights and the paged K/V pools shard over a (data=1,
@@ -462,7 +462,6 @@ def run_class(fault: str, runner, args) -> dict:
         "horizon_overshoot_tokens": m["horizon_overshoot_tokens"],
         "pipelined": getattr(args, "pipelined", False),
         "planned_ahead_steps": m["planned_ahead_steps"],
-        "device_idle_fraction": m["device_idle_fraction"],
         "injected": dict(getattr(target, "injected", {})) or None,
     }
 
