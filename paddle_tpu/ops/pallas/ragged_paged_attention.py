@@ -11,32 +11,39 @@ paged_gather + dense-mask path, materializing every sequence's ENTIRE
 padded KV history ([B, max_pages*page_size, H, D]) in HBM per step.
 
 Design (the flash-attention online-softmax structure of
-ops/pallas/flash_attention.py crossed with the scalar-prefetch block
-indexing):
+ops/pallas/flash_attention.py, with the page walk inside the kernel):
 
-  * grid (batch, q_tile, page): each step folds ONE pool page into the
-    accumulators of one tile of Q_TILE span rows (a whole span when it
-    is shorter), so VMEM use does not grow with the prefill bucket;
-    per-sequence block tables, span start
+  * grid (batch, q_tile): one step per sequence and tile of Q_TILE span
+    rows (a whole span when it is shorter), so VMEM use does not grow
+    with the prefill bucket; per-sequence block tables, span start
     positions, and span lengths ride in SMEM via
-    pltpu.PrefetchScalarGridSpec, and the K/V BlockSpec index_map reads
-    ``table[b, j]`` to DMA exactly that pool page into VMEM;
+    pltpu.PrefetchScalarGridSpec. The K and V pools stay in HBM
+    (memory_space=ANY, no BlockSpec);
+  * the walk: a step computes the tile's last visible page from
+    start_pos and q_len and loops over its LIVE pages only, a block of
+    pages_per_block pages at a time: one async copy per live page
+    (``k_pool.at[table[b, p]]`` -> a slot of a double-buffered VMEM
+    block) while the block before is folded into the accumulators; the
+    first block of the NEXT grid step is started before this one ends.
+    A short sequence in a wide table pays for its own pages and nothing
+    else: no grid step, no copy and no FLOP for a page past its context
+    (attention_page_reads counts exactly the copies);
   * ragged spans: sequence b computes query rows t in [0, q_len[b])
     standing at context positions start_pos[b] + t; rows past q_len are
     hard-masked and produce exact zeros (padded buckets never NaN), so
     one launch serves decode (q_len=1), prefill chunks (q_len=chunk,
-    start_pos=chunk offset), and dead batch slots (q_len=0);
-  * per-sequence early-out: pages wholly past a span's last visible key
-    (j*page_size > start_pos + q_len - 1) run no FLOPs (pl.when) and
-    cost no DMA — the index_map clamps dead page indices to the last
-    live page and the Pallas pipeline elides the repeated block copy, so
-    a short sequence in a long table pays only its own pages' bandwidth;
-  * native GQA: q heads are grouped by their KV head OUTSIDE the kernel
-    ([B, T, n_q, d] -> [B, n_kv, T*n_rep, d]), so the in-kernel matmuls
-    batch over n_kv and contract d with no head replication — grouped
-    models (n_rep > 1) stop falling back to the gather path;
+    start_pos=chunk offset), and dead batch slots (q_len=0: no copy,
+    zeros);
+  * one algorithm, two block layouts chosen from static shapes. Few
+    query rows (decode, speculative verify: T * n_q <= 128): the rows
+    stay (t, q-head) as q has them and the block stays (key, kv-head)
+    rows as the pages hold it, scores are ONE matmul of the two and a
+    row keeps the columns of its own kv-head. Otherwise (prefill
+    tiles): q heads are grouped by their KV head OUTSIDE the kernel
+    ([B, T, n_q, d] -> [B, n_kv, T*n_rep, d]) and the matmuls batch
+    over n_kv and contract d with no head replication;
   * fp32 online softmax with running (m, l, acc) in VMEM scratch across
-    the page walk — the attention matrix never exists in HBM, and fully
+    the walk — the attention matrix never exists in HBM, and fully
     masked rows are guarded to exact zero output.
 
 Layout: q [B, T, n_q_heads, d]; pools [num_pages, page_size, n_kv, d];
@@ -65,90 +72,262 @@ NEG_INF = -1e30
 # span rows one grid step holds in VMEM; spans that are a multiple of it
 # are tiled, shorter (or odd) spans are one tile
 Q_TILE = 128
+# query rows (span rows x q heads) up to which a tile is folded as ONE
+# matmul against the block's (key, kv-head) rows: the MXU's row count,
+# below which a matmul costs what loading its other operand costs
+FLAT_ROWS = 128
+# what one grid step may hold of the chip's 16 MiB of scoped VMEM, by
+# pages_per_block's count; the rest is room for what it does not count
+VMEM_BUDGET = 12 * 2 ** 20
 
 
-def _ragged_kernel(table_ref, start_ref, qlen_ref, q_ref, k_ref, v_ref,
-                   o_ref, m_ref, l_ref, acc_ref, *, page_size: int,
-                   n_rep: int, scale: float,
-                   kscale_ref=None, vscale_ref=None):
-    """Grid (b, q_tile, page): fold one KV page into one tile of
-    sequence b's span rows.
+def _page_copy_heads(n_kv: int, itemsize: int) -> int:
+    """KV heads a pool needs for the chip's compiler to copy it page by
+    page: a [page_size, n_kv, d] slice has to be whole tiles, and below
+    32 bits the sublane tile is 2, 4 or 8 rows (the smallest that holds
+    n_kv), so n_kv is a power of two up to 8 or a multiple of 8."""
+    if itemsize >= 4:
+        return n_kv
+    if n_kv >= 8:
+        return -(-n_kv // 8) * 8
+    return max(2, 1 << (n_kv - 1).bit_length())
 
-    With kscale_ref/vscale_ref (ISSUE 9: int8 pools), the K/V block is
-    int8 codes and the per-page-per-head scales ride the SMEM scalar
-    prefetch ([num_pages, n_kv] fp32, indexed by the SAME clamped page
-    id the BlockSpec index_map DMA'd): the dequantize happens right
-    here inside the page walk, and the online softmax stays fp32 — the
-    page walk reads half the bytes, the math above it is unchanged."""
-    b = pl.program_id(0)
-    i = pl.program_id(1)
-    j = pl.program_id(2)
-    n_pages = pl.num_programs(2)
-    n_kv, G, d = q_ref.shape[1], q_ref.shape[2], q_ref.shape[3]
-    tq = G // n_rep                    # span rows in this tile
 
-    @pl.when(j == 0)
-    def _init():
-        m_ref[:] = jnp.full((n_kv, G, 1), NEG_INF, jnp.float32)
-        l_ref[:] = jnp.zeros((n_kv, G, 1), jnp.float32)
-        acc_ref[:] = jnp.zeros((n_kv, G, d), jnp.float32)
+def _tile_elems(n_kv: int, d: int, itemsize: int) -> int:
+    """Elements the (n_kv, d) minor dims of a VMEM array occupy: the
+    sublane dim pads to the dtype's tile (8 rows of 4 bytes, 16 of 2,
+    32 of 1), the lane dim to 128."""
+    sub = 32 // itemsize
+    return -(-n_kv // sub) * sub * -(-d // 128) * 128
 
-    start = start_ref[b]
-    qlen = qlen_ref[b]
-    t0 = i * tq                        # span row of this tile's row 0
+
+def _flat(T: int, n_q: int) -> bool:
+    """Few query rows (decode, speculative verify, short chunks): the
+    tile is the whole span and its rows stay (t, q-head) as q has them."""
+    return T * n_q <= FLAT_ROWS
+
+
+def _span_tile(T: int, n_rep: int) -> int:
+    """Span rows per grid tile: the q/out blocks, the (m, l, acc)
+    scratch and the score tile scale with tile rows x n_rep, so a long
+    prefill span walks its pages once per tile instead of asking for
+    more scoped VMEM than the chip has, and a grouped model's tile
+    shrinks with its group."""
+    tq = Q_TILE // (1 << (n_rep - 1).bit_length())
+    return tq if tq >= 8 and T % tq == 0 else T
+
+
+def pages_per_block(T: int, n_q: int, q_itemsize: int, page_size: int,
+                    n_kv: int, d: int, kv_itemsize: int) -> int:
+    """Pages one block of the in-kernel walk copies and folds: the
+    largest power of two at which a grid step fits VMEM_BUDGET (whatever
+    the table's width: the same spans fold in the same order under any).
+    Counted from static shapes, with the factors the chip's compiler was
+    seen to allocate (PERF.md, PR 26): K's and V's two buffers each; per
+    page of a block its float32 forms (2 where the block is folded as
+    the pages hold it, 5 with the head-major copies of the batched
+    matmuls) and 4-5 score tiles; per step the q and out blocks twice
+    and three float32 copies of the tile's rows."""
+    n_q = n_q // n_kv * _page_copy_heads(n_kv, kv_itemsize)
+    n_kv, d = _page_copy_heads(n_kv, kv_itemsize), -(-d // 128) * 128
+    page_f32 = page_size * _tile_elems(n_kv, d, 4) * 4
+    per_page = 4 * page_size * _tile_elems(n_kv, d, kv_itemsize) * kv_itemsize
+    if _flat(T, n_q):
+        rows = -(-T * n_q // 8) * 8
+        per_page += 2 * page_f32 + 5 * rows * page_size * n_kv * 4
+    else:
+        rows = n_q * _span_tile(T, n_q // n_kv)
+        per_page += 5 * page_f32 + 4 * rows * page_size * 4
+    fixed = (4 * q_itemsize + 12) * rows * d
+    ppb = 1
+    while fixed + 2 * ppb * per_page <= VMEM_BUDGET:
+        ppb *= 2
+    return ppb
+
+
+def _tile_pages(start, qlen, t0, tq: int, page_size: int, table_width: int):
+    """Pages the walk of one tile (span rows [t0, t0 + tq) of a span
+    standing at `start` with `qlen` live rows) copies: those up to the
+    last key its last live row sees; none for a dead slot or a tile past
+    the span. The kernel's loop bound, and what a test counts."""
+    last_pos = start + jnp.minimum(qlen, t0 + tq) - 1
+    return jnp.where(qlen > t0,
+                     jnp.minimum(last_pos // page_size + 1, table_width), 0)
+
+
+def _ragged_kernel(table_ref, start_ref, qlen_ref, *refs, page_size: int,
+                   n_q: int, tq: int, flat: bool, scale: float,
+                   quantized: bool):
+    """Grid (b, q_tile): one step folds every live page of sequence b
+    into one tile of its span rows, a block of pages at a time.
+
+    The pools stay in HBM. Block n of the tile's live pages is copied
+    page by page (one async copy each, `table[b, p]` -> a slot of the
+    [2, pages_per_block, ...] VMEM buffers) while block n - 1 is folded
+    into (m, l, acc); after a tile's last block the copy in flight is
+    the first block of the NEXT grid step, whose table row is in SMEM
+    already. Pages past the tile's last visible key are never copied.
+
+    With int8 pools the per-page-per-head scales ride the SMEM scalar
+    prefetch ([num_pages, n_kv] fp32, read by the page ids the copies
+    used) and the block is dequantized inside the walk; the online
+    softmax stays fp32.
+
+    flat: the q block is [rows, d], rows (t, q-head); else [n_kv, G, d],
+    G rows (t, rep) of each kv-head's group."""
+    if quantized:
+        kscale_ref, vscale_ref, *refs = refs
+    (q_ref, k_hbm, v_hbm, o_ref, kbuf, vbuf, sem, m_ref, l_ref, acc_ref,
+     slot_ref) = refs
+    b, i = pl.program_id(0), pl.program_id(1)
+    n_seq, n_tiles = pl.num_programs(0), pl.num_programs(1)
+    ppb, n_kv, d = kbuf.shape[1], kbuf.shape[3], kbuf.shape[4]
+    n_rep = n_q // n_kv
+    keys = ppb * page_size             # keys in one block
+    table_width = table_ref.shape[1]
+
+    def tile_pages(b_, i_):
+        return _tile_pages(start_ref[b_], qlen_ref[b_], i_ * tq, tq,
+                           page_size, table_width)
+
+    def copies(b_, block, slot, n_pages, wait: bool = False):
+        """Start (or wait for) one copy per live page of `block` of
+        sequence b_'s table row into buffer `slot`, K and V."""
+        first = block * ppb
+
+        def page(r, carry):
+            pid = 0 if wait else table_ref[b_, first + r]
+            for which, (pool, buf) in enumerate(((k_hbm, kbuf),
+                                                 (v_hbm, vbuf))):
+                cp = pltpu.make_async_copy(pool.at[pid], buf.at[slot, r],
+                                           sem.at[slot, which])
+                cp.wait() if wait else cp.start()
+            return carry
+
+        jax.lax.fori_loop(0, jnp.clip(n_pages - first, 0, ppb), page, 0)
+
+    start, qlen, t0 = start_ref[b], qlen_ref[b], i * tq
     # last key position any live row of this tile sees (causal: rows
     # past the tile never look further than its own last row)
     last_pos = start + jnp.minimum(qlen, t0 + tq) - 1
+    n_pages = tile_pages(b, i)
+    n_blocks = pl.cdiv(n_pages, ppb)
+    # the grid step after this one: its first block is this step's to start
+    wraps = i == n_tiles - 1
+    nb = jnp.minimum(jnp.where(wraps, b + 1, b), n_seq - 1)
+    ni = jnp.where(wraps, 0, i + 1)
+    next_pages = jnp.where(wraps & (b == n_seq - 1), 0, tile_pages(nb, ni))
 
-    # early-out: dead spans and tiles (t0 >= qlen) and pages past the
-    # tile's last visible key fold nothing in — and their DMA was
-    # elided by the clamped index_map (the revisited block is already
-    # VMEM-resident)
-    @pl.when((qlen > t0) & (j * page_size <= last_pos))
-    def _page():
-        q = q_ref[0].astype(jnp.float32)           # [n_kv, G, d]
-        k = k_ref[0].astype(jnp.float32)           # [ps, n_kv, d]
-        v = v_ref[0].astype(jnp.float32)
-        if kscale_ref is not None:
-            # same clamp as the index_map: the page id whose block is
-            # VMEM-resident right now; its scale row dequantizes it
-            jc = jnp.minimum(j, jnp.maximum(last_pos, 0) // page_size)
-            pid = table_ref[b, jc]
-            ks = jnp.stack([kscale_ref[pid, h] for h in range(n_kv)])
-            vs = jnp.stack([vscale_ref[pid, h] for h in range(n_kv)])
-            k = k * ks[None, :, None]
-            v = v * vs[None, :, None]
-        # scores[n_kv, G, ps]: batch the KV-head dim, contract d — each
-        # KV head serves its n_rep grouped query rows with no replication
-        s = jax.lax.dot_general(
-            q, k, (((2,), (2,)), ((0,), (1,))),
-            preferred_element_type=jnp.float32) * scale
-        # grouped row r is (t, rep) flattened; its query position is
-        # start + t with t = t0 + r // n_rep, and rows t >= qlen are
-        # padding
-        t_idx = t0 + jax.lax.broadcasted_iota(
-            jnp.int32, (n_kv, G, page_size), 1) // n_rep
-        k_pos = j * page_size + jax.lax.broadcasted_iota(
-            jnp.int32, (n_kv, G, page_size), 2)
-        s = jnp.where((k_pos <= start + t_idx) & (t_idx < qlen),
-                      s, NEG_INF)
-        m = m_ref[:]
+    @pl.when((b == 0) & (i == 0))
+    def _first_step():
+        slot_ref[0] = 0
+        copies(b, 0, 0, n_pages)
+
+    slot0 = slot_ref[0]                # buffer of this tile's block 0
+    m_ref[...] = jnp.full(m_ref.shape, NEG_INF, jnp.float32)
+    l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
+    acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
+
+    def dequantized(x, scale_ref, first):
+        # the scale rows of the pages the copies read (a slot no copy
+        # filled gets some page's finite scale; its keys are masked)
+        sc = jnp.stack([jnp.stack([
+            scale_ref[table_ref[b, jnp.minimum(first + r, table_width - 1)],
+                      h] for h in range(n_kv)]) for r in range(ppb)])
+        x = x.astype(jnp.float32).reshape(ppb, page_size, n_kv, d)
+        return (x * sc[:, None, :, None]).reshape(keys, n_kv, d)
+
+    def fold(block, slot):
+        key0 = block * keys
+        k = kbuf[slot].reshape(keys, n_kv, d)
+        v = vbuf[slot].reshape(keys, n_kv, d)
+        if quantized:
+            k = dequantized(k, kscale_ref, block * ppb)
+            v = dequantized(v, vscale_ref, block * ppb)
+        # the slots of a partial block that no copy filled hold what was
+        # there before, and 0 * NaN is NaN: V is masked, not only scores
+        v = jnp.where(key0 + jax.lax.broadcasted_iota(
+            jnp.int32, (keys, n_kv, d), 0) <= last_pos, v, jnp.zeros_like(v))
+        if v.dtype != jnp.bfloat16:
+            v = v.astype(jnp.float32)
+        if flat:
+            # scores[rows, keys * n_kv]: every query row against every
+            # (key, kv-head) row of the block as the pages hold them, one
+            # matmul; a row keeps the columns of its own kv-head
+            q = q_ref[0]                               # row (t, q-head)
+            k = k.reshape(keys * n_kv, d)
+            v = v.reshape(keys * n_kv, d)
+            if k.dtype != q.dtype:
+                q, k = q.astype(jnp.float32), k.astype(jnp.float32)
+            s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                    preferred_element_type=jnp.float32)
+            row = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+            col = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+            t_idx = row // n_q                         # one tile: t0 is 0
+            k_pos = key0 + col // n_kv
+            own = col % n_kv == row % n_q // n_rep
+        else:
+            # scores[n_kv, G, keys]: batch the KV-head dim, contract d —
+            # each KV head serves its n_rep grouped query rows with no
+            # replication
+            q = q_ref[0].astype(jnp.float32)           # [n_kv, G, d]
+            s = jax.lax.dot_general(
+                q, k.astype(jnp.float32), (((2,), (2,)), ((0,), (1,))),
+                preferred_element_type=jnp.float32)
+            # grouped row r is (t, rep) flattened; its query position is
+            # start + t with t = t0 + r // n_rep
+            t_idx = t0 + jax.lax.broadcasted_iota(
+                jnp.int32, s.shape, 1) // n_rep
+            k_pos = key0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
+            own = True
+        # rows t >= qlen are padding
+        s = jnp.where(own & (k_pos <= start + t_idx) & (t_idx < qlen),
+                      s * scale, NEG_INF)
+        m = m_ref[...]
         new_m = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
         # masked-row guard: where every key so far is hard-masked, new_m
         # is still NEG_INF and exp(s - new_m) would be 1 — force 0 so the
         # row's l stays 0 and its output is exactly zero
         p = jnp.where(s <= NEG_INF * 0.5, 0.0, jnp.exp(s - new_m))
         corr = jnp.exp(m - new_m)
-        m_ref[:] = new_m
-        l_ref[:] = l_ref[:] * corr + jnp.sum(p, axis=-1, keepdims=True)
-        acc_ref[:] = acc_ref[:] * corr + jax.lax.dot_general(
-            p, v, (((2,), (0,)), ((0,), (1,))),
-            preferred_element_type=jnp.float32)    # [n_kv, G, d]
+        m_ref[...] = new_m
+        l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=-1, keepdims=True)
+        if flat:
+            # p stays float32: against bf16 pages it goes through the
+            # MXU as three bf16 terms that sum to it, not rounded to one
+            terms = [p]
+            if v.dtype == jnp.bfloat16:
+                lo = p - p.astype(v.dtype).astype(jnp.float32)
+                terms = [p, lo, lo - lo.astype(v.dtype).astype(jnp.float32)]
+            pv = sum(jax.lax.dot_general(
+                t.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32) for t in terms)
+        else:
+            pv = jax.lax.dot_general(
+                p, v.astype(jnp.float32), (((2,), (0,)), ((0,), (1,))),
+                preferred_element_type=jnp.float32)    # [n_kv, G, d]
+        acc_ref[...] = acc_ref[...] * corr + pv
 
-    @pl.when(j == n_pages - 1)
-    def _finish():
-        o_ref[0] = (acc_ref[:] / jnp.maximum(l_ref[:], 1e-30)
-                    ).astype(o_ref.dtype)
+    def walk(block, carry):
+        slot = (slot0 + block) % 2
+        # in flight while this block is folded: the tile's next block,
+        # or after its last the first block of the next grid step
+        more = block + 1 < n_blocks
+        copies(jnp.where(more, b, nb), jnp.where(more, block + 1, 0),
+               1 - slot, jnp.where(more, n_pages, next_pages))
+        copies(b, block, slot, n_pages, wait=True)
+        fold(block, slot)
+        return carry
+
+    jax.lax.fori_loop(0, n_blocks, walk, 0)
+
+    @pl.when(n_blocks == 0)
+    def _dead_step():                  # nothing to fold: hand on the start
+        copies(nb, 0, slot0, next_pages)
+
+    slot_ref[0] = (slot0 + n_blocks) % 2
+    o_ref[0] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
+                ).astype(o_ref.dtype)
 
 
 def ragged_paged_attention(q, k_pool, v_pool, block_table, start_pos, q_len,
@@ -167,87 +346,117 @@ def ragged_paged_attention(q, k_pool, v_pool, block_table, start_pos, q_len,
     Quantized pools (ISSUE 9): pass int8 code pools plus
     k_scale/v_scale [num_pages, n_kv_heads] fp32 (one scale per page
     per kv-head). The scales ride the SMEM scalar prefetch next to the
-    block tables and each page tile is dequantized inside the page walk
+    block tables and each block of pages is dequantized inside the walk
     — HBM traffic is the int8 bytes + the scale rows, while the online
     softmax stays fp32.
+
+    A head layout whose pages are not whole tiles (head_dim not a
+    multiple of 128; see _page_copy_heads) is padded to one HERE, pools
+    included: a copy of both pools per call. Such a model's pools
+    should be allocated padded (ROADMAP S1).
     """
-    B, T, n_q, d = q.shape
-    page_size = k_pool.shape[1]
-    n_kv = k_pool.shape[2]
+    n_q, n_kv = q.shape[2], k_pool.shape[2]
     if n_q % n_kv:
         raise ValueError(f"n_q_heads={n_q} not a multiple of "
                          f"n_kv_heads={n_kv}")
     if (k_scale is None) != (v_scale is None):
         raise ValueError("pass both k_scale and v_scale or neither")
-    quantized = k_scale is not None
-    n_rep = n_q // n_kv
-    n_pages = block_table.shape[1]
-    scale = scale if scale is not None else 1.0 / np.sqrt(d)
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
+    return _ragged_call(
+        q, k_pool, v_pool, block_table, start_pos, q_len, k_scale, v_scale,
+        scale=float(scale if scale is not None else 1.0 / np.sqrt(q.shape[3])),
+        interpret=bool(interpret))
+
+
+# jitted here, not only by the caller: a model's layers call it with the
+# same shapes, and a jitted callee is traced and lowered once per
+# program, not once per layer (24 kernels of some 20 k characters of
+# MLIR each in a GPT-3 1.3B step, which set-up pays for warm or cold)
+@functools.partial(jax.jit, static_argnames=("scale", "interpret"))
+def _ragged_call(q, k_pool, v_pool, block_table, start_pos, q_len, k_scale,
+                 v_scale, *, scale: float, interpret: bool):
+    B, T, n_q, d = q.shape
+    page_size, n_kv = k_pool.shape[1], k_pool.shape[2]
+    quantized = k_scale is not None
+    n_rep = n_q // n_kv
+    pad_kv = _page_copy_heads(n_kv, k_pool.dtype.itemsize) - n_kv
+    pad_d = -d % 128
+    if pad_kv or pad_d:
+        # zero heads and zero lanes: the scores of the real heads are
+        # unchanged, the added ones come out zero and are cut off
+        q = jnp.pad(q, ((0, 0), (0, 0), (0, pad_kv * n_rep), (0, pad_d)))
+        pool_pad = ((0, 0), (0, 0), (0, pad_kv), (0, pad_d))
+        k_pool, v_pool = jnp.pad(k_pool, pool_pad), jnp.pad(v_pool, pool_pad)
+        if quantized:
+            k_scale, v_scale = (jnp.pad(x, ((0, 0), (0, pad_kv)))
+                                for x in (k_scale, v_scale))
+        out = _ragged_call(q, k_pool, v_pool, block_table, start_pos, q_len,
+                           k_scale, v_scale, scale=scale, interpret=interpret)
+        return out[:, :, :n_q, :d]
     start_arr = jnp.broadcast_to(
         jnp.asarray(start_pos, jnp.int32).reshape(-1), (B,))
     qlen_arr = jnp.broadcast_to(
         jnp.asarray(q_len, jnp.int32).reshape(-1), (B,))
-    # span rows per grid tile: the q/out blocks and the (m, l, acc)
-    # scratch scale with it, so a long prefill span walks the pages once
-    # per tile instead of asking for more scoped VMEM than the chip has
-    tq = Q_TILE if T % Q_TILE == 0 else T
-    G = n_rep * tq
-    # group q heads by KV head outside the kernel (XLA transpose) so the
-    # kernel body needs no layout shuffles: row r of group g = (t, rep)
-    qg = q.reshape(B, T, n_kv, n_rep, d).transpose(0, 2, 1, 3, 4)
-    qg = qg.reshape(B, n_kv, T * n_rep, d)
-
-    def kv_map(b, i, j, t, s, ql, *_):
-        # clamp dead pages (past the tile's last visible key) to the last
-        # live page: the pipeline sees an unchanged block index and
-        # elides the DMA (dead slots clamp to the table's first entry)
-        last = jnp.maximum(s[b] + jnp.minimum(ql[b], (i + 1) * tq) - 1, 0)
-        jc = jnp.minimum(j, last // page_size)
-        return (t[b, jc], 0, 0, 0)
-
+    ppb = pages_per_block(T, n_q, q.dtype.itemsize, page_size, n_kv, d,
+                          k_pool.dtype.itemsize)
+    flat = _flat(T, n_q)
+    if flat:
+        # rows stay (t, q-head) as q has them: one tile, no transposes
+        tq, rows = T, T * n_q
+        qg = q.reshape(B, rows, d)
+        q_spec = pl.BlockSpec((1, rows, d), lambda b, i, *_: (b, 0, 0))
+        stats, acc = (rows, 1), (rows, d)
+    else:
+        tq = _span_tile(T, n_rep)
+        G = n_rep * tq
+        # group q heads by KV head outside the kernel (XLA transpose) so
+        # the kernel body needs no layout shuffles: row r of group g =
+        # (t, rep)
+        qg = q.reshape(B, T, n_kv, n_rep, d).transpose(0, 2, 1, 3, 4)
+        qg = qg.reshape(B, n_kv, T * n_rep, d)
+        q_spec = pl.BlockSpec((1, n_kv, G, d), lambda b, i, *_: (b, 0, i, 0))
+        stats, acc = (n_kv, G, 1), (n_kv, G, d)
+    kv_buf = pltpu.VMEM((2, ppb, page_size, n_kv, d), k_pool.dtype)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         # quantized pools prefetch the scale rows alongside the tables:
-        # scalars 3/4 are k_scale/v_scale, read per clamped page id
+        # scalars 3/4 are k_scale/v_scale, read per page id
         num_scalar_prefetch=5 if quantized else 3,
-        grid=(B, T // tq, n_pages),
-        in_specs=[
-            pl.BlockSpec((1, n_kv, G, d), lambda b, i, j, *_: (b, 0, i, 0)),
-            pl.BlockSpec((1, page_size, n_kv, d), kv_map),
-            pl.BlockSpec((1, page_size, n_kv, d), kv_map),
-        ],
-        out_specs=pl.BlockSpec((1, n_kv, G, d),
-                               lambda b, i, j, *_: (b, 0, i, 0)),
+        grid=(B, T // tq),
+        in_specs=[q_spec,
+                  pl.BlockSpec(memory_space=pl.ANY),
+                  pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=q_spec,
         scratch_shapes=[
-            pltpu.VMEM((n_kv, G, 1), jnp.float32),
-            pltpu.VMEM((n_kv, G, 1), jnp.float32),
-            pltpu.VMEM((n_kv, G, d), jnp.float32),
+            kv_buf, kv_buf,
+            pltpu.SemaphoreType.DMA((2, 2)),       # [slot, K or V]
+            pltpu.VMEM(stats, jnp.float32),
+            pltpu.VMEM(stats, jnp.float32),
+            pltpu.VMEM(acc, jnp.float32),
+            pltpu.SMEM((1,), jnp.int32),           # slot of the next block 0
         ],
     )
+    scalars = (block_table.astype(jnp.int32), start_arr, qlen_arr)
     if quantized:
-        def kernel(table_ref, start_ref, qlen_ref, ks_ref, vs_ref, *rest):
-            _ragged_kernel(table_ref, start_ref, qlen_ref, *rest,
-                           page_size=page_size, n_rep=n_rep, scale=scale,
-                           kscale_ref=ks_ref, vscale_ref=vs_ref)
-
-        scalars = (block_table.astype(jnp.int32), start_arr, qlen_arr,
-                   jnp.asarray(k_scale, jnp.float32),
-                   jnp.asarray(v_scale, jnp.float32))
-    else:
-        kernel = functools.partial(_ragged_kernel, page_size=page_size,
-                                   n_rep=n_rep, scale=scale)
-        scalars = (block_table.astype(jnp.int32), start_arr, qlen_arr)
+        scalars += (jnp.asarray(k_scale, jnp.float32),
+                    jnp.asarray(v_scale, jnp.float32))
     out = pl.pallas_call(
-        kernel,
+        functools.partial(_ragged_kernel, page_size=page_size, n_q=n_q,
+                          tq=tq, flat=flat, scale=scale,
+                          quantized=quantized),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, n_kv, T * n_rep, d),
+        out_shape=jax.ShapeDtypeStruct(qg.shape,
                                        jnp.float32 if quantized else q.dtype),
+        # a step starts the next step's first copies: the grid is a
+        # sequence, in order
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret,
         name="ragged_paged_attn",
     )(*scalars, qg, k_pool, v_pool)
     out = out.astype(q.dtype)
-    out = out.reshape(B, n_kv, T, n_rep, d).transpose(0, 2, 1, 3, 4)
+    if not flat:
+        out = out.reshape(B, n_kv, T, n_rep, d).transpose(0, 2, 1, 3, 4)
     return out.reshape(B, T, n_q, d)
 
 
@@ -308,9 +517,10 @@ def ragged_reference(q, k_pool, v_pool, block_table, start_pos, q_len,
 
 def attention_page_reads(start_pos, q_len, page_size: int):
     """Pages a ragged-kernel launch actually reads, per sequence: the
-    clamped index_map DMAs pages [0, last_visible_page] and nothing for
-    dead slots. Host-side analytics for the instrumented-pool counter —
-    the CPU-countable half of the kernel's bandwidth claim."""
+    walk copies pages [0, last_visible_page] and nothing for dead slots
+    (a span of several tiles walks them once per tile; the count is the
+    distinct pages). Host-side analytics for the instrumented-pool
+    counter — the CPU-countable half of the kernel's bandwidth claim."""
     start = np.asarray(start_pos, np.int64).reshape(-1)
     qlen = np.asarray(q_len, np.int64).reshape(-1)
     last = np.maximum(start + qlen - 1, 0)

@@ -894,7 +894,7 @@ class PagedModelRunner:
 
     def _account_attn(self, impl: str, starts, q_lens, table_width: int):
         """Bump the instrumented-pool counters for one step call: the
-        kernels read only each span's live pages (clamped index_map);
+        kernels read only each span's live pages (the in-kernel walk);
         the gather path reads every table entry of every slot. Counted
         host-side from the same operands the device call gets, so the
         bandwidth claim is verifiable without TPU access. On a sharded

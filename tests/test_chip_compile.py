@@ -1,6 +1,8 @@
 """The kernels of the main path, compiled for a TPU v5e that is described
 and not attached (on-chip-measurement guide, section 2), at GPT-2 124M
-widths: 12 heads x 64, sequence 1024, pages of 16.
+widths (12 heads x 64, sequence 1024, pages of 16) and, for the ragged
+kernel, at the decode cell's: GPT-3 1.3B's 16 x 128 in bf16 over 3136
+pages with a table 128 wide, and a grouped 32/8 x 128.
 
 Nothing runs, so this says nothing about results or times; it raises what
 the chip's compiler would raise (an unparsable contraction, a block the
@@ -45,7 +47,8 @@ def v5e():
     compilation_cache.reset_cache()
 
 
-def _ragged(dtype, B, T):
+def _ragged(dtype, B, T, n_q=N_HEADS, n_kv=N_HEADS, d=HEAD_DIM,
+            pages=NUM_PAGES, table=SEQ // PAGE):
     from paddle_tpu.ops.pallas.ragged_paged_attention import \
         ragged_paged_attention
 
@@ -53,10 +56,17 @@ def _ragged(dtype, B, T):
         return ragged_paged_attention(q, kp, vp, table, start, qlen,
                                       interpret=False)
 
-    pool = ((NUM_PAGES, PAGE, N_HEADS, HEAD_DIM), dtype)
-    return fn, [((B, T, N_HEADS, HEAD_DIM), dtype), pool, pool,
-                ((B, SEQ // PAGE), jnp.int32), ((B,), jnp.int32),
+    pool = ((pages, PAGE, n_kv, d), dtype)
+    return fn, [((B, T, n_q, d), dtype), pool, pool,
+                ((B, table), jnp.int32), ((B,), jnp.int32),
                 ((B,), jnp.int32)]
+
+
+def _ragged_1p3b(B, T, n_q=16, n_kv=16):
+    """gpt3-1.3b.decode's kernel: bf16 pages of 16 x 128, the pool and
+    the table the bench builds (3136 pages, 2048 / 16 entries a row)."""
+    return _ragged(jnp.bfloat16, B, T, n_q=n_q, n_kv=n_kv, d=128,
+                   pages=3136, table=128)
 
 
 def _flash(dtype, backward, mode="dense"):
@@ -114,6 +124,14 @@ CASES = {
     "ragged-fp32-prefill-256": lambda mp: _ragged(jnp.float32, 1, 256),
     "ragged-bf16-prefill-256": lambda mp: _ragged(jnp.bfloat16, 1, 256),
     "ragged-fp32-decode-b1": lambda mp: _ragged(jnp.float32, 1, 1),
+    # the decode cell: 32 slots a step, its three prefill buckets' ends
+    "ragged-1p3b-decode-b32": lambda mp: _ragged_1p3b(32, 1),
+    "ragged-1p3b-prefill-1024": lambda mp: _ragged_1p3b(1, 1024),
+    "ragged-1p3b-prefill-256": lambda mp: _ragged_1p3b(1, 256),
+    # grouped heads at 32/8 x 128 (ROADMAP R3/R4's shape)
+    "ragged-gqa-32-8x128-decode-b32": lambda mp: _ragged_1p3b(32, 1, 32, 8),
+    "ragged-gqa-32-8x128-prefill-256": lambda mp: _ragged_1p3b(1, 256, 32,
+                                                               8),
     "flash-fp32-fwd": lambda mp: _flash(jnp.float32, False),
     "flash-bf16-fwd": lambda mp: _flash(jnp.bfloat16, False),
     "flash-fp32-fwd-bwd": lambda mp: _flash(jnp.float32, True),

@@ -93,10 +93,10 @@ def test_block_mha_routes_to_kernel(monkeypatch):
 
 
 def test_dead_pages_do_not_change_output():
-    """Pool-size invariance of the clamped-index_map kernel: the same
+    """Pool-size invariance of the in-kernel page walk: the same
     sequence content in a 4x pool (extra dead pages past pos) gives a
-    bit-identical result — the dead grid steps fold nothing in and their
-    clamped DMA revisits the last live page."""
+    bit-identical result — the walk stops at the last live page under
+    any table width."""
     rng = np.random.default_rng(5)
     b, h, d, bs = 2, 4, 64, 8
     pos = jnp.asarray([9, 21], jnp.int32)

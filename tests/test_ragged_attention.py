@@ -16,10 +16,15 @@ import jax.numpy as jnp
 
 import paddle_tpu as paddle
 from paddle_tpu.models.generation import masked_cache_attention, paged_gather
+import importlib
+
 from paddle_tpu.ops.pallas.ragged_paged_attention import (
-    Q_TILE, attention_page_reads, ragged_attention_ok, ragged_paged_attention,
-    ragged_reference,
+    Q_TILE, attention_page_reads, pages_per_block, ragged_attention_ok,
+    ragged_paged_attention, ragged_reference,
 )
+
+# the module, not the function of the same name the package re-exports
+rpa = importlib.import_module("paddle_tpu.ops.pallas.ragged_paged_attention")
 
 rng = np.random.default_rng(7)
 
@@ -129,9 +134,9 @@ def test_padded_bucket_rows_are_zero_and_live_rows_invariant():
 
 
 def test_dead_pages_cost_nothing_and_change_nothing():
-    """Page-count invariance of the clamped index_map: the same span
-    content with 3x more (dead) table pages is bit-identical, and the
-    instrumented page-read count says the dead pages were never read."""
+    """Page-count invariance of the walk: the same span content with 3x
+    more (dead) table pages is bit-identical, and the instrumented
+    page-read count says the dead pages were never read."""
     B, n_kv, d, ps = 2, 2, 16, 8
     starts = np.asarray([9, 21], np.int32)
     qlens = np.asarray([4, 1], np.int32)
@@ -156,6 +161,145 @@ def test_dead_pages_cost_nothing_and_change_nothing():
                                   np.asarray(run(3 * n_live)))
     reads = attention_page_reads(starts, qlens, ps)
     np.testing.assert_array_equal(reads, [2, 3])   # live pages only
+
+
+# ------------------------------------------------- the in-kernel page walk
+
+PS, N_KV, N_REP, D = 8, 2, 2, 16
+
+
+def _block_pages(T, int8=False):
+    """pages_per_block of the walk for this file's toy layout and a span
+    bucket of T rows."""
+    return pages_per_block(T, N_KV * N_REP, 4, PS, N_KV, D, 1 if int8 else 4)
+
+
+def _walk_cases(int8=False):
+    """name -> (T, contexts after the span, q_lens): spans whose keys end
+    around the walk's block boundary."""
+    dec, pre = _block_pages(1, int8) * PS, _block_pages(16, int8) * PS
+    return {
+        # decode rows: the last key is the block's last but one, its
+        # last, and the first of the next block
+        "one-key-short-of-a-block": (1, [dec - 1, dec - 1, 5], [1, 1, 1]),
+        "exactly-one-block": (1, [dec, 2 * dec, 3], [1, 1, 1]),
+        "one-block-and-a-key": (1, [dec + 1, 2 * dec + 1, dec], [1, 1, 1]),
+        "table-wider-by-blocks": (1, [PS + 3, 2, 1], [1, 1, 1]),
+        "dead-slot-between-live": (1, [dec + 9, 77, 3 * dec - 2], [1, 0, 1]),
+        "whole-batch-dead": (1, [dec, 9, 40], [0, 0, 0]),
+        # a prefill chunk whose last page is partial, across the boundary
+        "chunk-partial-last-page": (16, [pre + 5, pre - 3, 21], [16, 11, 16]),
+        # a span wide enough for the head-major tile (not the flat one)
+        "tile-partial-last-page": (
+            64, [_block_pages(64, int8) * PS + 13, 64, 70], [64, 64, 3]),
+    }
+
+
+def _poisoned_walk_inputs(T, ctx, qlens, int8):
+    """Pools whose every page OUTSIDE a sequence's live range (the pages
+    up to its last visible key) is poison: NaN in float pools, NaN in the
+    scale rows of int8 pools. The reference reads the same pools with
+    the poison replaced by zeros."""
+    B = len(ctx)
+    width = 4 * _block_pages(T, int8)
+    assert max(ctx) <= width * PS
+    nb = 1 + B * width
+    prng = np.random.default_rng(29)
+    tbl = prng.permutation(np.arange(1, nb)).reshape(B, width).astype(np.int32)
+    starts = np.asarray(ctx, np.int32) - np.asarray(qlens, np.int32)
+    live = np.zeros(nb, bool)
+    for b, n in enumerate(attention_page_reads(starts, qlens, PS)):
+        live[tbl[b, :n]] = True
+    q = jnp.asarray(prng.standard_normal((B, T, N_KV * N_REP, D)), jnp.float32)
+    kv = prng.standard_normal((2, nb, PS, N_KV, D)).astype(np.float32)
+
+    def poisoned(x, poison):            # rows of dead pages <- poison
+        return jnp.asarray(np.where(
+            live.reshape((1, nb) + (1,) * (x.ndim - 2)), x, poison))
+
+    if int8:
+        pools = ref_pools = [jnp.asarray(np.clip(np.round(x * 40), -127, 127),
+                                         jnp.int8) for x in kv]
+        sc = prng.uniform(0.01, 0.03, (2, nb, N_KV)).astype(np.float32)
+        k_sc, v_sc = poisoned(sc, np.nan)
+        kw = dict(k_scale=k_sc, v_scale=v_sc)
+        # a NaN scale would poison the reference's gather of the whole
+        # table: it reads scales zeroed outside the live range instead
+        k_sc, v_sc = poisoned(sc, 0.0)
+        ref_kw = dict(k_scale=k_sc, v_scale=v_sc)
+    else:
+        pools, ref_pools = list(poisoned(kv, np.nan)), list(poisoned(kv, 0.0))
+        kw = ref_kw = {}
+    args = (jnp.asarray(tbl), jnp.asarray(starts),
+            jnp.asarray(qlens, jnp.int32))
+    return q, pools, ref_pools, args, kw, ref_kw
+
+
+@pytest.mark.parametrize("int8", [False, True], ids=["float", "int8"])
+@pytest.mark.parametrize("case", sorted(_walk_cases()))
+def test_walk_crosses_block_boundaries_and_reads_no_dead_page(case, int8):
+    T, ctx, qlens = _walk_cases(int8)[case]
+    q, pools, ref_pools, args, kw, ref_kw = _poisoned_walk_inputs(
+        T, ctx, qlens, int8)
+    out = np.asarray(ragged_paged_attention(q, *pools, *args,
+                                            interpret=True, **kw))
+    ref = np.asarray(ragged_reference(q, *ref_pools, *args, **ref_kw))
+    assert np.isfinite(out).all(), "a dead page reached the result"
+    np.testing.assert_allclose(out, ref, rtol=1e-5, atol=1e-5)
+    for b, n in enumerate(qlens):
+        assert (out[b, n:] == 0.0).all()
+
+
+def test_page_reads_count_the_copies_the_kernel_starts(monkeypatch):
+    """attention_page_reads' contract: for a mixed batch (decode rows, a
+    prefill chunk, a dead slot, a span across a block boundary) its count
+    is the number of page copies the kernel starts, K and V each —
+    counted where they are started, in interpret mode."""
+    import jax
+
+    started = []
+    real = rpa.pltpu.make_async_copy
+
+    class Counted:
+        def __init__(self, *a):
+            self.cp = real(*a)
+
+        def start(self):
+            jax.debug.callback(lambda: started.append(1))
+            self.cp.start()
+
+        def wait(self):
+            self.cp.wait()
+
+    monkeypatch.setattr(rpa.pltpu, "make_async_copy",
+                        lambda *a: Counted(*a))
+    jax.clear_caches()          # the kernel is traced anew, with the count
+    T = 16
+    block_keys = _block_pages(T) * PS
+    ctx = [block_keys + 5, 16, 40, 3 * PS]
+    qlens = [1, 16, 0, 9]
+    q, pools, _, args, _, _ = _poisoned_walk_inputs(T, ctx, qlens, False)
+    jax.block_until_ready(
+        ragged_paged_attention(q, *pools, *args, interpret=True))
+    jax.effects_barrier()
+    reads = attention_page_reads(np.asarray(args[1]), qlens, PS)
+    assert reads.tolist() == [block_keys // PS + 1, 2, 0, 3]
+    assert len(started) == 2 * int(reads.sum())
+    jax.clear_caches()          # and no later test meets the counted one
+
+
+def test_page_reads_of_a_tiled_span_are_its_last_tiles():
+    """A span of several tiles walks its pages once per tile; the count
+    stays the distinct pages (the last live tile's walk), and the
+    kernel's loop bound says so tile by tile."""
+    n_rep, T, start, qlen = 1, 2 * Q_TILE, 3, Q_TILE + 9
+    tq = rpa._span_tile(T, n_rep)
+    assert tq == Q_TILE
+    per_tile = [int(rpa._tile_pages(start, qlen, t0, tq, PS, 1 << 20))
+                for t0 in range(0, T, tq)]
+    assert per_tile == [(start + Q_TILE - 1) // PS + 1,
+                        (start + qlen - 1) // PS + 1]
+    assert attention_page_reads([start], [qlen], PS).tolist() == [per_tile[-1]]
 
 
 # -------------------------------------------------------- dispatch gate

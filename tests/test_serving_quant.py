@@ -154,8 +154,8 @@ def test_int8_kernel_bucket_invariance():
 
 
 def test_int8_kernel_page_count_invariance():
-    """3x more (dead) table pages change nothing: the clamped index_map
-    + per-page scale lookup only ever touch live pages."""
+    """3x more (dead) table pages change nothing: the walk and its
+    per-page scale lookup only ever touch live pages."""
     q, kp, vp, ks, vs, tbl = _int8_pools(pages=4)
     starts = jnp.asarray([9, 21], jnp.int32)
     qlens = jnp.asarray([4, 1], jnp.int32)

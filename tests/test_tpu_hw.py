@@ -274,7 +274,7 @@ def test_bf16_matmul_throughput_probe(tpu_backend):
 
 
 def test_paged_decode_dead_pages_on_hw(tpu_backend):
-    """Clamped index_map: dead pages past pos must not change the output
+    """The in-kernel walk: dead pages past pos must not change the output
     on real hardware."""
     import jax.numpy as jnp
 
