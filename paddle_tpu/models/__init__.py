@@ -1,4 +1,7 @@
 """Flagship model zoo (TPU-native)."""
+from paddle_tpu.models.deepseek_v3 import (  # noqa: F401
+    DeepseekV3Config, DeepseekV3ForCausalLM,
+)
 from paddle_tpu.models.gpt import (  # noqa: F401
     GPT, GPTBlock, GPTConfig, build_pipeline_train_step, gpt_loss_fn,
 )

@@ -1,0 +1,224 @@
+"""Multi-query attention over LATENT pages: the decode kernel of the
+absorbed form of latent attention.
+
+A latent layer's cache holds ONE array per page, `[num_pages, page_size,
+lanes]`: per token a compressed key/value vector whose first `v_lanes`
+lanes are also the value (the rest, a rotary part, enter the scores only).
+Every query head of a sequence attends the SAME keys, so a decode step is
+a multi-query problem: the `n_q` heads of one token are the rows of one
+matmul against each block of the sequence's pages,
+
+    s = q [n_q, lanes] . block [keys, lanes]^T     o += p . block[:, :v_lanes]
+
+with the online softmax of ops/pallas/ragged_paged_attention.py around it.
+Built as that kernel's walk is (PR 26): grid (batch,), the pool stays in
+HBM, a step loops over the LIVE pages of its sequence only, a block of
+`pages_per_block` pages at a time, one async copy per page into a
+double-buffered VMEM block while the block before is folded; after a
+sequence's last block the copy in flight is the first block of the NEXT
+sequence. The block tables ride SMEM by scalar prefetch.
+
+The chip's compiler copies a page only where its minor dims are whole
+tiles, so `lanes` must be a multiple of 128: a pool whose vector is 576
+wide is ALLOCATED 640 wide (serving/kv_cache.py takes the layout from the
+runner); nothing is padded per call.
+
+q: [B, n_q, lanes] (lanes past the vector's width zero); pool as above;
+block_table [B, pages_per_seq] int32; pos [B] int32, the context position
+of the query: it sees keys at positions <= pos. Returns [B, n_q, v_lanes].
+"""
+
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+
+try:  # pragma: no cover - absent on pure-CPU builds
+    from jax.experimental.pallas import tpu as pltpu
+except Exception:  # pragma: no cover
+    pltpu = None
+
+NEG_INF = -1e30
+# what one grid step may hold of the chip's 16 MiB of scoped VMEM
+VMEM_BUDGET = 10 * 2 ** 20
+
+
+def pages_per_block(n_q: int, page_size: int, lanes: int, v_lanes: int,
+                    itemsize: int) -> int:
+    """Pages one block of the walk copies and folds: the largest power of
+    two, up to 64, at which the two buffers, the block's float32 form, its
+    value slice and four score tiles fit VMEM_BUDGET."""
+    per_page = page_size * (2 * lanes * itemsize + lanes * 4
+                            + v_lanes * itemsize + 4 * n_q * 4)
+    fixed = n_q * (2 * lanes * itemsize + 3 * v_lanes * 4)
+    ppb = 1
+    while ppb < 64 and fixed + 2 * ppb * per_page <= VMEM_BUDGET:
+        ppb *= 2
+    return ppb
+
+
+def _kernel(table_ref, pos_ref, q_ref, pool_hbm, o_ref, buf, sem, m_ref,
+            l_ref, acc_ref, slot_ref, *, page_size: int, v_lanes: int,
+            scale: float):
+    b, n_seq = pl.program_id(0), pl.num_programs(0)
+    ppb = buf.shape[1]
+    keys = ppb * page_size
+    table_width = table_ref.shape[1]
+
+    def seq_pages(b_):
+        return jnp.minimum(pos_ref[b_] // page_size + 1, table_width)
+
+    def copies(b_, block, slot, n_pages, wait: bool = False):
+        """Start (or wait for) one copy per live page of `block` of
+        sequence b_'s table row into buffer `slot`."""
+        first = block * ppb
+
+        def page(r, carry):
+            pid = 0 if wait else table_ref[b_, first + r]
+            cp = pltpu.make_async_copy(pool_hbm.at[pid], buf.at[slot, r],
+                                       sem.at[slot])
+            cp.wait() if wait else cp.start()
+            return carry
+
+        jax.lax.fori_loop(0, jnp.clip(n_pages - first, 0, ppb), page, 0)
+
+    last_pos = pos_ref[b]
+    n_pages = seq_pages(b)
+    n_blocks = pl.cdiv(n_pages, ppb)
+    nb = jnp.minimum(b + 1, n_seq - 1)
+    next_pages = jnp.where(b == n_seq - 1, 0, seq_pages(nb))
+
+    @pl.when(b == 0)
+    def _first_step():
+        slot_ref[0] = 0
+        copies(b, 0, 0, n_pages)
+
+    slot0 = slot_ref[0]                # buffer of this sequence's block 0
+    m_ref[...] = jnp.full(m_ref.shape, NEG_INF, jnp.float32)
+    l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
+    acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
+    q = q_ref[0]                                           # [n_q, lanes]
+
+    def fold(block, slot, masked: bool):
+        """Fold one block into (m, l, acc). Only a sequence's LAST block
+        can hold keys past its context (and slots no copy filled): the
+        others skip the masks, which cost as much as the matmuls."""
+        key0 = block * keys
+        kv = buf[slot].reshape(keys, buf.shape[3])
+        s = jax.lax.dot_general(q, kv, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32) * scale
+        v = kv[:, :v_lanes]
+        if masked:
+            live = key0 + jax.lax.broadcasted_iota(
+                jnp.int32, s.shape, 1) <= last_pos
+            s = jnp.where(live, s, NEG_INF)
+            # the slots of a partial block that no copy filled hold what
+            # was there before, and 0 * NaN is NaN: the values are masked
+            v = jnp.where(key0 + jax.lax.broadcasted_iota(
+                jnp.int32, v.shape, 0) <= last_pos, v, jnp.zeros_like(v))
+        m = m_ref[...]
+        new_m = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+        p = jnp.exp(s - new_m)
+        if masked:
+            p = jnp.where(live, p, 0.0)
+        corr = jnp.exp(m - new_m)
+        m_ref[...] = new_m
+        l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=-1, keepdims=True)
+        acc_ref[...] = acc_ref[...] * corr + jax.lax.dot_general(
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+
+    def walk(block, carry):
+        slot = (slot0 + block) % 2
+        # in flight while this block is folded: the sequence's next block,
+        # or after its last the first block of the next sequence
+        more = block + 1 < n_blocks
+        copies(jnp.where(more, b, nb), jnp.where(more, block + 1, 0),
+               1 - slot, jnp.where(more, n_pages, next_pages))
+        copies(b, block, slot, n_pages, wait=True)
+
+        @pl.when(more)
+        def _full_block():
+            fold(block, slot, masked=False)
+
+        @pl.when(jnp.logical_not(more))
+        def _last_block():
+            fold(block, slot, masked=True)
+
+        return carry
+
+    jax.lax.fori_loop(0, n_blocks, walk, 0)
+    slot_ref[0] = (slot0 + n_blocks) % 2
+    o_ref[0] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
+                ).astype(o_ref.dtype)
+
+
+def latent_paged_attention(q, pool, block_table, pos, *, v_lanes: int,
+                           scale: float, interpret: bool | None = None):
+    """Decode attention of B sequences over latent pages (see the head).
+    Every sequence reads at least its first page (a dead slot's table is
+    all scratch and its position 0)."""
+    if pool.shape[2] % 128:
+        raise ValueError(
+            f"latent pages of {pool.shape[2]} lanes: the chip copies whole "
+            "tiles, allocate the pool in multiples of 128")
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    return _call(q, pool, block_table, pos, v_lanes=int(v_lanes),
+                 scale=float(scale), interpret=bool(interpret))
+
+
+# jitted here as the ragged kernel's wrapper is: a model's layers call it
+# with the same shapes, and a jitted callee is lowered once per program
+@functools.partial(jax.jit, static_argnames=("v_lanes", "scale",
+                                             "interpret"))
+def _call(q, pool, block_table, pos, *, v_lanes: int, scale: float,
+          interpret: bool):
+    B, n_q, lanes = q.shape
+    page_size = pool.shape[1]
+    ppb = pages_per_block(n_q, page_size, lanes, v_lanes,
+                          pool.dtype.itemsize)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(B,),
+        in_specs=[pl.BlockSpec((1, n_q, lanes), lambda b, *_: (b, 0, 0)),
+                  pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec((1, n_q, v_lanes), lambda b, *_: (b, 0, 0)),
+        scratch_shapes=[
+            pltpu.VMEM((2, ppb, page_size, lanes), pool.dtype),
+            pltpu.SemaphoreType.DMA((2,)),
+            pltpu.VMEM((n_q, 1), jnp.float32),
+            pltpu.VMEM((n_q, 1), jnp.float32),
+            pltpu.VMEM((n_q, v_lanes), jnp.float32),
+            pltpu.SMEM((1,), jnp.int32),           # slot of the next block 0
+        ],
+    )
+    return pl.pallas_call(
+        functools.partial(_kernel, page_size=page_size, v_lanes=v_lanes,
+                          scale=scale),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, n_q, v_lanes), q.dtype),
+        # a step starts the next step's first copies: the grid is a
+        # sequence, in order
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+        name="latent_paged_attn",
+    )(block_table.astype(jnp.int32), jnp.asarray(pos, jnp.int32).reshape(-1),
+      q, pool)
+
+
+def latent_reference(q, pool, block_table, pos, *, v_lanes: int,
+                     scale: float):
+    """Gather + dense-mask oracle of the kernel: O(B * table width) HBM."""
+    B, n_q, lanes = q.shape
+    lat = pool[block_table].reshape(B, -1, lanes).astype(jnp.float32)
+    s = jnp.einsum("bhc,blc->bhl", q.astype(jnp.float32), lat) * scale
+    k_pos = jnp.arange(lat.shape[1], dtype=jnp.int32)
+    s = jnp.where(k_pos[None, None, :] <= jnp.asarray(pos)[:, None, None],
+                  s, NEG_INF)
+    p = jax.nn.softmax(s, axis=-1)
+    return jnp.einsum("bhl,blc->bhc", p, lat[..., :v_lanes]).astype(q.dtype)

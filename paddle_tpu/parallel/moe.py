@@ -256,3 +256,110 @@ class MoELayer(Layer):
                                "activation": self.activation})
         self.aux_loss = aux
         return y.reshape(shape)
+
+
+# ------------------------------------------------------------------------
+# A dropless top-k expert layer that is told which experts it holds: one
+# rank's share of an expert-parallel deployment. Routing runs over ALL the
+# experts the router has; the products run over the experts held here.
+# Pure functions on jax arrays: a model's eager forward and a serving
+# runner's jitted step both call them. On one chip the layer runs without
+# its exchange: what the absent experts would add is left out.
+
+
+def sigmoid_topk_route(x, gate_w, bias, top_k: int, *,
+                       norm_topk_prob: bool = True, scale: float = 1.0):
+    """Sigmoid scores with a selection-only bias ("noaux_tc" with one
+    group): x [T, d], gate_w [d, E], bias [E] -> (indices [T, top_k] of the
+    top_k largest of score + bias, weights [T, top_k] float32). The bias
+    moves the selection and never the weights: w_e = s_e / (sum of the
+    selected s + 1e-20) * scale, the sum over all top_k selected whoever
+    holds them."""
+    import jax
+
+    s = jax.nn.sigmoid(jnp.matmul(x, gate_w,
+                                  preferred_element_type=jnp.float32))
+    _, idx = jax.lax.top_k(s + bias.astype(jnp.float32)[None, :], top_k)
+    w = jnp.take_along_axis(s, idx, axis=-1)
+    if norm_topk_prob:
+        w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
+    return idx, w * scale
+
+
+def _swiglu(x, w_gate, w_up, w_down, out_dtype=None):
+    """(silu(x W_g) * x W_u) W_d; the two products come out in x's type,
+    their activation is taken in float32 and rounded once."""
+    import jax
+
+    g = jnp.matmul(x, w_gate).astype(jnp.float32)
+    u = jnp.matmul(x, w_up).astype(jnp.float32)
+    a = (jax.nn.silu(g) * u).astype(x.dtype)
+    return jnp.matmul(a, w_down,
+                      preferred_element_type=out_dtype or x.dtype)
+
+
+def held_experts_ffn(x, idx, weights, w_gate, w_up, w_down,
+                     first_expert: int, valid=None):
+    """The routed part of a top-k expert layer over the experts held here,
+    dropless: y[t] = sum over the selected experts e of token t that lie in
+    [first_expert, first_expert + G) of weights[t, e] * SwiGLU_e(x[t]).
+
+    x [T, d]; idx / weights [T, K] as `sigmoid_topk_route` gives them;
+    w_gate / w_up [G, d, f], w_down [G, f, d]: the held experts, stacked;
+    valid [T] bool (rows that are padding route nowhere). Returns
+    (y [T, d] float32, pairs, touched): the token-expert pairs computed
+    here and the held experts that got at least one.
+
+    No [tokens, experts, capacity] mask and no capacity: the local pairs
+    are sorted by expert into a layout where every expert's group starts
+    at a multiple of a row block, and a loop with a DYNAMIC trip count
+    walks the blocks that exist: each block gathers its tokens' rows,
+    multiplies them by its one expert's matrices and adds the weighted
+    result onto its tokens. An expert no token chose costs nothing (its
+    matrices are not read); if every token chooses the same expert the
+    loop is longer, nothing is dropped. The block is 16 rows for a decode
+    step's few pairs and 256 for a prefill's: shapes decide."""
+    import jax
+
+    T, d = x.shape
+    K, G = idx.shape[1], w_gate.shape[0]
+    N = T * K
+    bm = 16 if N <= 4096 else 256
+    local = (idx >= first_expert) & (idx < first_expert + G)
+    if valid is not None:
+        local = local & valid[:, None]
+    e = jnp.where(local, idx - first_expert, G).reshape(N).astype(jnp.int32)
+    counts = jnp.zeros((G + 1,), jnp.int32).at[e].add(1)[:G]
+    order = jnp.argsort(e, stable=True).astype(jnp.int32)  # held pairs first
+    padded = (counts + bm - 1) // bm * bm
+    p_end = jnp.cumsum(padded)
+    p_start, start = p_end - padded, jnp.cumsum(counts) - counts
+    # rows of the padded layout, at most: every pair held (a token picks
+    # min(K, G) held experts at most) and under a block of padding each
+    R = -(-T * min(K, G) // bm) * bm + G * bm
+    rank = jnp.arange(N, dtype=jnp.int32)
+    e_sorted = jnp.minimum(e[order], G - 1)
+    dest = jnp.where(e[order] < G,
+                     p_start[e_sorted] + rank - start[e_sorted], R)
+    row_pair = jnp.full((R,), N, jnp.int32).at[dest].set(order, mode="drop")
+    real = row_pair < N
+    row_tok = jnp.where(real, row_pair // K, 0)
+    row_w = jnp.where(real, weights.reshape(N)[jnp.minimum(row_pair, N - 1)],
+                      0.0).astype(jnp.float32)
+    block_expert = jnp.minimum(jnp.searchsorted(
+        p_end, jnp.arange(R // bm, dtype=jnp.int32) * bm, side="right"),
+        G - 1).astype(jnp.int32)
+
+    def block(b, y):
+        rows = jax.lax.dynamic_slice_in_dim(row_tok, b * bm, bm)
+        w = jax.lax.dynamic_slice_in_dim(row_w, b * bm, bm)
+        ex = block_expert[b]
+        out = _swiglu(x[rows], w_gate[ex], w_up[ex], w_down[ex],
+                      out_dtype=jnp.float32)
+        # a block's rows are distinct tokens (a token picks an expert
+        # once); its padding rows add zero onto token 0
+        return y.at[rows].add(out * w[:, None])
+
+    y = jax.lax.fori_loop(0, p_end[-1] // bm, block,
+                          jnp.zeros((T, d), jnp.float32))
+    return y, jnp.sum(counts), jnp.sum((counts > 0).astype(jnp.int32))

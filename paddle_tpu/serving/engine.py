@@ -542,12 +542,9 @@ class ServingEngine:
         # a kv_dtype="int8" runner quantizes at append time, so the
         # engine births int8 code pools + the parallel scale pools
         self.kv_dtype = getattr(runner, "kv_dtype", "fp32")
-        self.pool = KVCachePool(runner.num_layers, num_blocks, block_size,
-                                runner.n_kv_heads, runner.head_dim,
-                                runner.dtype, mesh=self.mesh,
-                                model_axis=getattr(runner, "model_axis",
-                                                   "model"),
-                                kv_dtype=self.kv_dtype)
+        self.pool = KVCachePool.for_runner(
+            runner, num_blocks, mesh=self.mesh,
+            model_axis=getattr(runner, "model_axis", "model"))
         self.enable_prefix_cache = bool(enable_prefix_cache)
         if self.enable_prefix_cache:
             self.pool.enable_prefix_cache()
@@ -651,6 +648,10 @@ class ServingEngine:
                                    "") not in ("", "0")
         self.audit = audit
         self.metrics = metrics or EngineMetrics()
+        # what the runner's steps count on the device (expert layers:
+        # `runner.COUNTS`): each launch hands its counts over here, and
+        # the step's one drain reads them with its tokens
+        self._step_counts: list = []
         # static per-pool ratios (ISSUE 9 satellite): the measured page-
         # byte reduction (scale bytes counted) and the matching sessions-
         # per-fixed-HBM factor — 1.0 on fp32 pools
@@ -765,7 +766,15 @@ class ServingEngine:
         """Run one blocking device->host drain under an `engine.drain`
         span: the host waiting for the device."""
         with _prof.span("engine.drain"):
-            return fn()
+            out = fn()
+            if self._step_counts:
+                # the launches drained just now, or earlier ones whose
+                # logits nobody read: ready, so no further wait
+                counts, self._step_counts[:] = list(self._step_counts), []
+                for name, n in zip(self.runner.COUNTS,
+                                   np.sum(jax.device_get(counts), axis=0)):
+                    getattr(self.metrics, name).inc(float(n))
+            return out
 
     # ------------------------------------------------- failure plumbing
 
@@ -951,6 +960,10 @@ class ServingEngine:
         if not self.has_work():
             return []
         self._step_count += 1
+        if getattr(self.runner, "COUNTS", ()):
+            # this step's launches report here (a runner may serve more
+            # than one engine in turn)
+            self.runner.on_step_counts = self._step_counts.append
         # the step's root span; whether this step's sites record at all
         # is decided here, once (a profiler session is live or not)
         with _prof.step_span("engine.step", self._step_count):
@@ -1327,7 +1340,8 @@ class ServingEngine:
                 tokens[s, :end - start] = span_toks
                 starts[s] = start
                 qlens[s] = end - start
-                tables[s, :len(req.kv.pages)] = req.kv.pages
+                row = req.kv.pages_array()
+                tables[s, :len(row)] = row
             build.end()
             prev = self.pool.pools
             try:
@@ -1651,7 +1665,8 @@ class ServingEngine:
                 sl = req.slot
                 sp = req.sampling
                 tokens[sl] = req.output_tokens[-1]
-                tables[sl, :len(req.kv.pages)] = req.kv.pages
+                row = req.kv.pages_array()
+                tables[sl, :len(row)] = row
                 pos[sl] = req.num_context - 1
                 k = row_k[req]
                 chain = chains[req]
@@ -1941,7 +1956,8 @@ class ServingEngine:
                     self.metrics.cow_copies.inc(cow)
                 sl = req.slot
                 tokens[sl] = req.output_tokens[-1]
-                tables[sl, :len(req.kv.pages)] = req.kv.pages
+                row = req.kv.pages_array()
+                tables[sl, :len(row)] = row
                 pos[sl] = req.num_context - 1
             ctx = self._horizon_ctx(batch, s)
             build.end()
@@ -2070,7 +2086,8 @@ class ServingEngine:
                     self.metrics.cow_copies.inc(cow)
                 s = req.slot
                 tokens[s] = req.output_tokens[-1]
-                tables[s, :len(req.kv.pages)] = req.kv.pages
+                row = req.kv.pages_array()
+                tables[s, :len(row)] = row
                 pos[s] = req.num_context - 1   # position of the fed token
             build.end()
             prev = self.pool.pools
@@ -2787,10 +2804,7 @@ def naive_generate(runner: PagedModelRunner, prompt_tokens: Sequence[int],
     sampling = sampling or SamplingParams()
     max_model_len = max_model_len or runner.max_model_len
     max_pages = -(-max_model_len // runner.block_size)
-    pool = KVCachePool(runner.num_layers, max_pages + 1,
-                       runner.block_size, runner.n_kv_heads,
-                       runner.head_dim, runner.dtype,
-                       kv_dtype=getattr(runner, "kv_dtype", "fp32"))
+    pool = KVCachePool.for_runner(runner, max_pages + 1)
     pages = pool.allocator.alloc(max_pages)
     # per-request KV precision (ISSUE 15): the oracle's pages carry the
     # request's effective tag, so a mixed-pool fp8 tenant's oracle

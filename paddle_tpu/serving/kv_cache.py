@@ -639,26 +639,18 @@ class SharedKVStore:
     # ------------------------------------------------------ construction
 
     @classmethod
-    def layout_for(cls, num_layers: int, block_size: int, n_kv_heads: int,
-                   head_dim: int, dtype="float32",
-                   kv_dtype: str = "fp32") -> list:
+    def layout_for(cls, num_layers: int, block_size: int, n_kv_heads=None,
+                   head_dim=None, dtype="float32",
+                   kv_dtype: str = "fp32", page_layout=None) -> list:
         """The host-mirror page layout for a pool geometry — exactly
-        the per-page slices of KVCachePool's layer tuples."""
-        dt = str(np.dtype(str(jnp.zeros((), dtype).dtype))
-                 if not isinstance(dtype, str) else np.dtype(dtype))
-        page = (block_size, n_kv_heads, head_dim)
-        if kv_dtype == "int8":
-            layer = ((page, "int8"), (page, "int8"),
-                     ((n_kv_heads,), "float32"), ((n_kv_heads,), "float32"))
-        elif kv_dtype == "fp8":
-            # native fp8 pages (ISSUE 15): ml_dtypes registers the
-            # numpy dtype, so host mirrors carry the exact bytes
-            layer = ((page, "float8_e4m3fn"), (page, "float8_e4m3fn"))
-        elif kv_dtype == "mixed":
-            # fp32 pages + the per-page tag bit (scalar per page)
-            layer = ((page, dt), (page, dt), ((), "bool"))
-        else:
-            layer = ((page, dt), (page, dt))
+        the per-page slices of KVCachePool's layer tuples (`page_arrays`;
+        `page_layout` as KVCachePool takes it from the runner, else the
+        (k, v) pair of `[n_kv_heads, head_dim]`)."""
+        layer = tuple(
+            (shape, str(np.dtype(dt))) for _, shape, dt, _ in page_arrays(
+                block_size,
+                page_layout or kv_pair_layout(n_kv_heads, head_dim, dtype),
+                kv_dtype))
         return [layer for _ in range(num_layers)]
 
     @classmethod
@@ -669,10 +661,10 @@ class SharedKVStore:
         replica must share the model config, which attach-time shape
         validation enforces loudly)."""
         return cls(cls.layout_for(
-            runner.num_layers, runner.block_size, runner.n_kv_heads,
-            runner.head_dim, runner.dtype,
-            getattr(runner, "kv_dtype", "fp32")), max_pages,
-            use_shm=use_shm)
+            runner.num_layers, runner.block_size,
+            kv_dtype=getattr(runner, "kv_dtype", "fp32"),
+            page_layout=_page_layout_of(runner)),
+            max_pages, use_shm=use_shm)
 
     @classmethod
     def for_geometry(cls, geometry: dict, max_pages: int, *,
@@ -1791,6 +1783,69 @@ class HostKVTier:
             self.metrics.offload_recompute_fallbacks.inc()
 
 
+def kv_pair_layout(n_kv_heads: int, head_dim: int, dtype) -> list:
+    """What a dense-attention layer's page holds, as a runner names it
+    to KVCachePool: K and V, each `[n_kv_heads, head_dim]` a token."""
+    return [((int(n_kv_heads), int(head_dim)), dtype)] * 2
+
+
+def _page_layout_of(runner) -> list:
+    """A runner's page layout: its own word (`page_layout()`), else the
+    (k, v) pair of its head geometry (duck-typed runners need not know
+    the question)."""
+    ask = getattr(runner, "page_layout", None)
+    return ask() if ask is not None else kv_pair_layout(
+        runner.n_kv_heads, runner.head_dim, runner.dtype)
+
+
+def page_arrays(block_size: int, page_layout, kv_dtype: str = "fp32",
+                model_axis: str = "model") -> list:
+    """ONE page of one layer as a pool stores it, `[(what, shape, dtype,
+    PartitionSpec of the pool's array), ...]`: the one description that
+    KVCachePool allocates from (a leading `[num_blocks]` on each shape),
+    that `page_bytes` counts, SharedKVStore mirrors and the auditor
+    checks. `page_layout` is the runner's word: `[(trailing shape,
+    dtype), ...]`, each array `[block_size, *trailing]` a page. Arrays
+    of `[heads, head_dim]` split over `model_axis` in whole heads and
+    come in every `kv_dtype` rung (int8 codes with one float32 scale per
+    page per head, float8 pages, float32 pages with a per-page tag
+    plane); any other layout (a latent layer's one array) comes in the
+    stated dtype on one device only."""
+    layout = [(tuple(int(n) for n in t), jnp.dtype(d))
+              for t, d in page_layout]
+    per_head = all(len(t) == 2 for t, _ in layout)
+    if kv_dtype != "fp32" and not (per_head and len(layout) == 2):
+        raise ValueError(
+            f"kv_dtype={kv_dtype!r} is a rung of (k, v) pages of [heads, "
+            f"head_dim]; the page layout {layout} comes in the runner's "
+            "stated dtype only")
+    names = ("k", "v") if per_head and len(layout) == 2 else tuple(
+        f"page array {j}" for j in range(len(layout)))
+    store = {"int8": jnp.dtype(jnp.int8),
+             "fp8": jnp.dtype(jnp.float8_e4m3fn)}.get(kv_dtype)
+    pages = PartitionSpec(None, None, model_axis, None)
+    arrays = [(what, (block_size,) + t, store or d,
+               pages if per_head else PartitionSpec())
+              for what, (t, d) in zip(names, layout)]
+    if kv_dtype == "int8":
+        # the scale pool shares the pool's page geometry and shards
+        # along the SAME kv-head axis: each model shard dequantizes
+        # its own head slice with its own scales
+        arrays += [(f"{what}-scale: one scale per page per kv-head",
+                    (t[0],), jnp.dtype(jnp.float32),
+                    PartitionSpec(None, model_axis))
+                   for what, (t, _) in zip(names, layout)]
+    elif kv_dtype == "mixed":
+        # mixed-precision tenants (ISSUE 15): fp32 storage + a
+        # per-page tag plane steering the write path — one plane
+        # per layer tuple so the pools stay a uniform pytree
+        # through every jitted step (the planes are kept identical;
+        # tag_pages updates all of them). The tag plane has no head
+        # axis — replicated per shard
+        arrays.append(("tag plane", (), jnp.dtype(bool), PartitionSpec()))
+    return arrays
+
+
 class KVCachePool:
     """The device-side page pool: per-layer (k, v) pools + the allocator.
 
@@ -1809,14 +1864,20 @@ class KVCachePool:
     """
 
     def __init__(self, num_layers: int, num_blocks: int, block_size: int,
-                 n_kv_heads: int, head_dim: int, dtype=jnp.float32,
+                 n_kv_heads: Optional[int] = None,
+                 head_dim: Optional[int] = None, dtype=jnp.float32,
                  mesh=None, model_axis: str = "model",
-                 kv_dtype: str = "fp32"):
+                 kv_dtype: str = "fp32", page_layout=None):
+        """`page_layout` is the runner's word on what a layer's page
+        holds: a list of `(trailing shape, dtype)`, one per array, the
+        same for every layer; each array is `[num_blocks, block_size,
+        *trailing]`. Left out, it is the (k, v) pair of `[n_kv_heads,
+        head_dim]` (`kv_pair_layout`). `page_arrays` says what the pool
+        stores for a layout in the rung `kv_dtype` names, and which
+        layouts come in which rungs."""
         self.num_layers = num_layers
         self.num_blocks = num_blocks
         self.block_size = block_size
-        self.n_kv_heads = n_kv_heads
-        self.head_dim = head_dim
         self.dtype = dtype
         if kv_dtype not in KV_DTYPES:
             raise ValueError(f"kv_dtype={kv_dtype!r}; expected one of "
@@ -1830,36 +1891,25 @@ class KVCachePool:
         self.allocator = BlockAllocator(num_blocks)
         self.prefix_cache: Optional[PrefixCache] = None
         self.host_tier: Optional[HostKVTier] = None
-        store_dtype = jnp.float8_e4m3fn if kv_dtype == "fp8" else dtype
-        shape = (num_blocks, block_size, n_kv_heads, head_dim)
-        sshape = (num_blocks, n_kv_heads)     # one scale per page per head
-        # a layer's arrays as (shape, dtype, PartitionSpec on a mesh)
-        pages = PartitionSpec(None, None, model_axis, None)
-        if kv_dtype == "int8":
-            # the scale pool shares the pool's page geometry and shards
-            # along the SAME kv-head axis: each model shard dequantizes
-            # its own head slice with its own scales
-            scales = PartitionSpec(None, model_axis)
-            layer = [(shape, jnp.int8, pages), (shape, jnp.int8, pages),
-                     (sshape, jnp.float32, scales),
-                     (sshape, jnp.float32, scales)]
-        elif kv_dtype == "mixed":
-            # mixed-precision tenants (ISSUE 15): fp32 storage + a
-            # per-page tag plane steering the write path — one plane
-            # per layer tuple so the pools stay a uniform pytree
-            # through every jitted step (the planes are kept identical;
-            # tag_pages updates all of them). The tag plane has no head
-            # axis — replicated per shard
-            layer = [(shape, dtype, pages), (shape, dtype, pages),
-                     ((num_blocks,), bool, PartitionSpec())]
-        else:                          # fp32 or native fp8 pages
-            layer = [(shape, store_dtype, pages),
-                     (shape, store_dtype, pages)]
+        self.page_layout = [(tuple(t), jnp.dtype(d)) for t, d in (
+            page_layout or kv_pair_layout(n_kv_heads, head_dim, dtype))]
+        self.page_arrays = page_arrays(block_size, self.page_layout,
+                                       kv_dtype, model_axis)
+        # the head geometry of (k, v) pages, None for any other layout
+        per_head = all(len(t) == 2 for t, _ in self.page_layout)
+        self.n_kv_heads, self.head_dim = (
+            self.page_layout[0][0] if per_head else (None, None))
+        layer = [((num_blocks,) + shape, dt, spec)
+                 for _, shape, dt, spec in self.page_arrays]
         if mesh is not None:
             self.tp_size = int(mesh.shape[model_axis])
-            if n_kv_heads % self.tp_size:
+            if self.n_kv_heads is None:
                 raise ValueError(
-                    f"n_kv_heads={n_kv_heads} is not divisible by the "
+                    f"the page layout {self.page_layout} has no head axis "
+                    "to split over a mesh")
+            if self.n_kv_heads % self.tp_size:
+                raise ValueError(
+                    f"n_kv_heads={self.n_kv_heads} is not divisible by the "
                     f"model-axis degree {self.tp_size}: the paged pools "
                     "shard in whole kv-heads (GQA rule)")
 
@@ -1875,6 +1925,16 @@ class KVCachePool:
         with _prof.always_span("kv_pool.alloc", num_blocks=num_blocks):
             self.pools = [tuple(zeros(*a) for a in layer)
                           for _ in range(num_layers)]
+
+    @classmethod
+    def for_runner(cls, runner, num_blocks: int, mesh=None,
+                   model_axis: str = "model") -> "KVCachePool":
+        """The pool a runner's steps read and write: the geometry is the
+        runner's (the page layout it names, in its kv_dtype rung)."""
+        return cls(runner.num_layers, num_blocks, runner.block_size,
+                   dtype=runner.dtype, mesh=mesh, model_axis=model_axis,
+                   kv_dtype=getattr(runner, "kv_dtype", "fp32"),
+                   page_layout=_page_layout_of(runner))
 
     # -------------------------------- per-request kv-dtype tags (ISSUE 15)
 
@@ -2003,27 +2063,20 @@ class KVCachePool:
 
     def page_bytes(self) -> int:
         """HBM bytes ONE page actually occupies across all layers and
-        both (k, v) pools — quantized code bytes PLUS scale bytes on an
-        int8 pool (ISSUE 9: the byte accounting is honest, not derived
-        from the logical dtype's itemsize)."""
-        per_kv = self.block_size * self.n_kv_heads * self.head_dim
-        if self.kv_dtype == "int8":
-            return 2 * self.num_layers * (per_kv + self.n_kv_heads * 4)
-        if self.kv_dtype == "fp8":
-            # native fp8: 1 byte/element, NO scale rows (ISSUE 15)
-            return 2 * self.num_layers * per_kv
-        itemsize = jnp.zeros((), self.dtype).dtype.itemsize
-        base = 2 * self.num_layers * per_kv * itemsize
-        if self.kv_dtype == "mixed":
-            return base + 1            # + the page's dtype tag bit
-        return base
+        all of a layer's arrays, as allocated — quantized code bytes
+        PLUS scale bytes on an int8 pool (ISSUE 9: the byte accounting
+        is honest, not derived from the logical dtype's itemsize), a
+        mixed pool's tag plane, a latent page's lanes padded to whole
+        tiles."""
+        return self.num_layers * sum(
+            int(np.prod(shape)) * dt.itemsize
+            for _, shape, dt, _ in self.page_arrays)
 
     def unquantized_page_bytes(self) -> int:
-        """What the same page would cost stored at the pool's logical
-        dtype — the denominator of the quantization win."""
-        itemsize = jnp.zeros((), self.dtype).dtype.itemsize
-        return (2 * self.num_layers * self.block_size * self.n_kv_heads
-                * self.head_dim * itemsize)
+        """What the same page would cost stored at the layout's own
+        dtypes — the denominator of the quantization win."""
+        return self.num_layers * self.block_size * sum(
+            int(np.prod(t)) * dt.itemsize for t, dt in self.page_layout)
 
     def kv_bytes_reduction_x(self) -> float:
         """Per-page byte reduction vs the unquantized pool, scale bytes
@@ -2069,6 +2122,31 @@ class SequenceKV:
         self.num_tokens = 0
         self.registered_pages = 0          # leading pages already cached
         self.hash_chain: List[int] = []    # chain hash per registered page
+        # `pages` as int32 for the step's block table (`pages_array`):
+        # the list mirrored, how much of it, and `_rewrites` then; every
+        # change to `pages` but an append counts a rewrite
+        self._rewrites = 0
+        self._row = np.zeros((0,), np.int32)
+        self._row_of = (None, 0, 0)
+
+    def pages_array(self) -> np.ndarray:
+        """`pages` as an int32 array (a view, valid until the next call):
+        a step copies it into its block table. Only the pages appended
+        since the last call are converted; a 1000-page list costs 30 us
+        to convert whole, a full batch of them more than the rest of a
+        step's host work."""
+        pages, n = self.pages, len(self.pages)
+        of, have, rewrites = self._row_of
+        if of is not pages or rewrites != self._rewrites or n < have:
+            have = 0
+        if n > len(self._row):
+            row = np.zeros((max(2 * n, 64),), np.int32)
+            row[:have] = self._row[:have]
+            self._row = row
+        if n > have:
+            self._row[have:n] = pages[have:n]
+        self._row_of = (pages, n, self._rewrites)
+        return self._row[:n]
 
     def adopt_prefix(self, matched: List[Tuple[int, int]],
                      block_size: int) -> None:
@@ -2114,6 +2192,7 @@ class SequenceKV:
         dropped = self.pages[keep:]
         if dropped:
             del self.pages[keep:]
+            self._rewrites += 1
             self.pool.allocator.free(dropped)   # decref each
         self.num_tokens = num_tokens
         return len(dropped)
@@ -2137,6 +2216,7 @@ class SequenceKV:
                 self.pool.tag_pages([new], self.kv_tag)
                 alloc.decref(page)
                 self.pages[idx] = new
+                self._rewrites += 1
                 # the fork is private and its content will diverge: it is
                 # no longer covered by this sequence's registered chain
                 if idx < self.registered_pages:

@@ -97,6 +97,7 @@ SUMMABLE_KEYS = (
     "requests_added", "requests_finished", "preemptions",
     "requests_timed_out", "requests_aborted", "step_retries",
     "nan_logit_events", "shed_requests", "tokens_generated",
+    "moe_tokens_routed", "moe_local_pairs", "moe_experts_touched",
     "prefill_tokens", "prefill_chunks", "prefix_hit_tokens", "cow_copies",
     "prefix_cached_pages", "attn_kv_bytes_read", "attn_kv_bytes_gather",
     "tp_comm_bytes", "tp_comm_bytes_fp32",
@@ -180,6 +181,15 @@ class EngineMetrics:
         self.nan_logit_events = Counter("nan_logit_events")
         self.shed_requests = Counter("shed_requests")
         self.tokens_generated = Counter("tokens_generated")
+        # expert layers (a runner that serves one rank's share of an
+        # expert-parallel model): tokens x layers through a router, the
+        # token-expert pairs computed HERE, and held experts with at
+        # least one token, summed over layers and steps. Each step's
+        # program returns them and the engine reads them at the step's
+        # one drain, with its tokens: host_syncs does not rise
+        self.moe_tokens_routed = Counter("moe_tokens_routed")
+        self.moe_local_pairs = Counter("moe_local_pairs")
+        self.moe_experts_touched = Counter("moe_experts_touched")
         # prefill_tokens counts tokens actually COMPUTED by prefill
         # chunks; prefix-cache hits skip the compute and land in
         # prefix_hit_tokens instead, so (computed + hit) = total context
@@ -349,6 +359,9 @@ class EngineMetrics:
             "nan_logit_events": self.nan_logit_events.value,
             "shed_requests": self.shed_requests.value,
             "tokens_generated": self.tokens_generated.value,
+            "moe_tokens_routed": self.moe_tokens_routed.value,
+            "moe_local_pairs": self.moe_local_pairs.value,
+            "moe_experts_touched": self.moe_experts_touched.value,
             "prefill_tokens": self.prefill_tokens.value,
             "prefill_chunks": self.prefill_chunks.value,
             "prefix_hit_tokens": self.prefix_hit_tokens.value,

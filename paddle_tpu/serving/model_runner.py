@@ -125,9 +125,10 @@ from paddle_tpu import profiler as _prof
 from paddle_tpu.models.generation import (
     _block_params, _layer_norm, _mlp, masked_cache_attention, paged_gather,
 )
+from paddle_tpu.models import deepseek_v3 as _dsv3
 from paddle_tpu.models.llama import _rope_tables
 from paddle_tpu.serving.kv_cache import (
-    KV_DTYPES, SCRATCH_PAGE, fp8_page_write, fp8_round,
+    KV_DTYPES, SCRATCH_PAGE, fp8_page_write, fp8_round, kv_pair_layout,
     quantized_page_write, require_fp8,
 )
 
@@ -220,9 +221,47 @@ def _shard_mapped_kernel(kernel, shard_ctx, q_spec, rest_specs=()):
     return run
 
 
+def _latent_attend(q, latent_new, layer_pools, tables, write_page,
+                   write_off, pos_q, q_len, impl: str, scale: float,
+                   v_lanes: int):
+    """paged_attend for a LATENT layer: one array a page, `[num_blocks,
+    page, lanes]`, each token's row its compressed key whose first
+    `v_lanes` lanes are also its value, shared by every query head (the
+    absorbed form of latent attention; models/deepseek_v3.py). q: [B, T,
+    n_h, lanes]; latent_new: [B, T, lanes]. Returns ([B, T, n_h,
+    v_lanes], (pool,)): the per-head sums of p . value, which the caller
+    takes through its value projection. "ragged" is the kernel over
+    latent pages (a decode step: T == 1), "reference" the gather path
+    for any span."""
+    (pool,) = layer_pools
+    pool = pool.at[write_page, write_off].set(latent_new.astype(pool.dtype))
+    B, T = q.shape[0], q.shape[1]
+    if impl == "ragged":
+        from paddle_tpu.ops.pallas.latent_paged_attention import \
+            latent_paged_attention
+
+        if T != 1:
+            raise ValueError(f"the latent kernel is a decode kernel; span "
+                             f"of {T} rows")
+        out = latent_paged_attention(q[:, 0], pool, tables, pos_q,
+                                     v_lanes=v_lanes, scale=scale)
+        return out[:, None], (pool,)
+    lat = pool[tables].reshape(B, -1, pool.shape[-1])           # [B, L, lanes]
+    s = jnp.einsum("bthc,blc->bhtl", q, lat,
+                   preferred_element_type=jnp.float32) * scale
+    t_idx = jnp.arange(T, dtype=jnp.int32)
+    visible = ((jnp.arange(lat.shape[1], dtype=jnp.int32)[None, None, :]
+                <= pos_q[:, None, None] + t_idx[None, :, None])
+               & (t_idx[None, :, None] < q_len[:, None, None]))  # [B, T, L]
+    p = jax.nn.softmax(jnp.where(visible[:, None], s, -1e30), axis=-1)
+    out = jnp.einsum("bhtl,blc->bthc", p.astype(lat.dtype),
+                     lat[..., :v_lanes])
+    return out.astype(q.dtype), (pool,)
+
+
 def paged_attend(q, k_new, v_new, layer_pools, tables, write_page,
                  write_off, pos_q, q_len, n_rep: int, impl: str,
-                 shard_ctx=None):
+                 shard_ctx=None, scale=None, v_lanes=None):
     """Write this step's K/V through the block table, then attend.
 
     q: [B, T, n_h, d]; k_new/v_new: [B, T, n_kv, d]; layer_pools: one
@@ -241,7 +280,15 @@ def paged_attend(q, k_new, v_new, layer_pools, tables, write_page,
     (ISSUE 7): the kernels then run per-shard via shard_map on each
     shard's kv-head slice; the gather reference path needs no wrapper —
     GSPMD partitions it from the pool sharding alone. Returns
-    ([B, T, n_h*d], new_layer_pools)."""
+    ([B, T, n_h*d], new_layer_pools).
+
+    A layer whose pool tuple is ONE array is a latent layer: k_new is
+    the tokens' latent rows, v_new None, `scale` the softmax scale and
+    `v_lanes` the value's lanes (see _latent_attend, whose return it
+    is)."""
+    if len(layer_pools) == 1:
+        return _latent_attend(q, k_new, layer_pools, tables, write_page,
+                              write_off, pos_q, q_len, impl, scale, v_lanes)
     quantized = len(layer_pools) == 4
     mixed = len(layer_pools) == 3
     if quantized:
@@ -332,6 +379,15 @@ class PagedModelRunner:
     vocab_size: int
 
     ATTN_IMPLS = ("auto", "ragged", "reference")
+    COUNTS = ()      # names of the counters a subclass's steps keep
+
+    # what a single-pass step counts on the device, by name: such a
+    # runner's `_forward` returns `(logits, pools, counts[len(COUNTS)])`
+    COUNTS = ()
+    # True: `_forward` takes `head_rows` [B] and returns logits [B, 1, V]
+    # at those rows only, so the steps that want a span's last row never
+    # make [B, T, V] (a 16 k prefill bucket times a vocabulary)
+    HEAD_ROWS = False
 
     def __init__(self, params: Dict[str, jnp.ndarray], block_size: int,
                  max_model_len: int, attn_impl: str = "auto",
@@ -417,6 +473,27 @@ class PagedModelRunner:
         # its own kv-head slice, so sharded = single-device / tp)
         self.attn_kv_bytes_read = 0.0
         self.attn_kv_bytes_gather = 0.0
+        # where a single-pass step's counts go (`COUNTS`): the engine
+        # sets this to collect them for its drain; None drops them
+        self.on_step_counts = None
+
+    def page_layout(self):
+        """What a layer's page holds, for KVCachePool: `[(trailing
+        shape, dtype), ...]`, one entry per array. Here the (k, v) pair
+        of [n_kv_heads, head_dim]; a runner with another cache names its
+        own arrays."""
+        return kv_pair_layout(self.n_kv_heads, self.head_dim, self.dtype)
+
+    def _emit(self, out):
+        """A single-pass step's outputs as its public entry returns
+        them, `(logits, pools)`. A runner that counts (`COUNTS`) has its
+        `_forward` return `(logits, pools, counts)`, the steps pass the
+        third on as an output of the program, and it goes from here to
+        whoever asked for it (`on_step_counts`), still on the device."""
+        logits, pools, *counts = out
+        if counts and self.on_step_counts is not None:
+            self.on_step_counts(counts[0])
+        return logits, pools
 
     @property
     def dtype(self):
@@ -1006,11 +1083,15 @@ class PagedModelRunner:
         valid = offs < real_len
         positions = jnp.where(valid, start_pos + offs, 0)
         page, off = self._write_indices(positions, table, valid)
-        logits, pools = self._forward(params, tokens, positions, page, off,
-                                      table,
-                                      jnp.reshape(start_pos, (1,)),
-                                      jnp.reshape(real_len, (1,)), pools)
-        return logits[0, real_len - 1], pools
+        args = (params, tokens, positions, page, off, table,
+                jnp.reshape(start_pos, (1,)), jnp.reshape(real_len, (1,)),
+                pools)
+        if self.HEAD_ROWS:
+            logits, pools, *counts = self._forward(
+                *args, head_rows=jnp.reshape(real_len - 1, (1,)))
+            return (logits[0, 0], pools, *counts)
+        logits, pools, *counts = self._forward(*args)
+        return (logits[0, real_len - 1], pools, *counts)
 
     def _decode_step(self, params, tokens, tables, pos, pools,
                      write_mask=None):
@@ -1022,10 +1103,10 @@ class PagedModelRunner:
                  else write_mask[:, None])
         page, off = self._write_indices(positions, tables, valid)
         B = tokens.shape[0]
-        logits, pools = self._forward(params, tokens, positions, page, off,
-                                      tables, pos,
-                                      jnp.ones((B,), jnp.int32), pools)
-        return logits[:, 0], pools
+        logits, pools, *counts = self._forward(
+            params, tokens, positions, page, off, tables, pos,
+            jnp.ones((B,), jnp.int32), pools)
+        return (logits[:, 0], pools, *counts)
 
     def _decode_multi_step(self, params, tokens, tables, pos, pools,
                            num_steps: int):
@@ -1044,8 +1125,9 @@ class PagedModelRunner:
 
         def body(carry, _):
             toks, p, pools = carry
-            logits, pools = self._decode_step(params, toks[:, None], tables,
-                                              p, pools)
+            # (the scans keep no counts)
+            logits, pools, *_ = self._decode_step(params, toks[:, None],
+                                                  tables, p, pools)
             nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
             fin = jnp.all(jnp.isfinite(logits), axis=-1)
             return (nxt, p + 1, pools), (nxt, fin)
@@ -1102,7 +1184,7 @@ class PagedModelRunner:
 
         def body(carry, _):
             toks, p, done, cnt, pools = carry
-            logits, pools = self._decode_step(
+            logits, pools, *_ = self._decode_step(
                 params, toks[:, None], tables, p, pools,
                 write_mask=jnp.logical_not(done))
             greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
@@ -1200,8 +1282,9 @@ class PagedModelRunner:
             valid = (offs < q_lens[:, None]) & (p[:, None] + offs < wall)
             positions = jnp.where(valid, p[:, None] + offs, 0)
             page, off = self._write_indices(positions, tables, valid)
-            logits, pools = self._forward(params, span, positions, page,
-                                          off, tables, p, q_lens, pools)
+            logits, pools, *_ = self._forward(params, span, positions,
+                                              page, off, tables, p, q_lens,
+                                              pools)
             greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)  # [B, T]
             fin = jnp.all(jnp.isfinite(logits), axis=-1)            # [B, T]
             if sampling:
@@ -1240,7 +1323,7 @@ class PagedModelRunner:
         return packed, pools
 
     def _ragged_core(self, params, tokens, tables, start_pos, q_lens,
-                     pools):
+                     pools, **head):
         """One mixed ragged batch: every slot carries its own query span
         — decode steps (q_len=1), prefill chunks (q_len=chunk at an
         offset), verify spans (q_len=k+1, ISSUE 5), dead slots (q_len=0)
@@ -1253,17 +1336,24 @@ class PagedModelRunner:
         positions = jnp.where(valid, start_pos[:, None] + offs, 0)
         page, off = self._write_indices(positions, tables, valid)
         return self._forward(params, tokens, positions, page, off,
-                             tables, start_pos, q_lens, pools)
+                             tables, start_pos, q_lens, pools, **head)
 
     def _ragged_step(self, params, tokens, tables, start_pos, q_lens,
                      pools):
         """Ragged batch returning each slot's logits at its span's LAST
         live row only — the fused chunk+decode step's shape."""
-        logits, pools = self._ragged_core(params, tokens, tables, start_pos,
-                                          q_lens, pools)
-        last = jnp.maximum(q_lens - 1, 0).astype(jnp.int32)
-        out = jnp.take_along_axis(logits, last[:, None, None], axis=1)
-        return out[:, 0], pools
+        def last():
+            return jnp.maximum(q_lens - 1, 0).astype(jnp.int32)
+
+        if self.HEAD_ROWS:
+            logits, pools, *counts = self._ragged_core(
+                params, tokens, tables, start_pos, q_lens, pools,
+                head_rows=last())
+            return (logits[:, 0], pools, *counts)
+        logits, pools, *counts = self._ragged_core(
+            params, tokens, tables, start_pos, q_lens, pools)
+        out = jnp.take_along_axis(logits, last()[:, None, None], axis=1)
+        return (out[:, 0], pools, *counts)
 
     def _jitted(self, kind: str, shape_key):
         """Shape-keyed jit cache. Every miss (= a compile) is logged, and
@@ -1309,7 +1399,6 @@ class PagedModelRunner:
         else:
             jitted = jax.jit(fn, donate_argnums=donate,
                              static_argnums=static)
-
         def first_call(*args):
             # tracing and compilation happen here, not at jax.jit above:
             # a failure is the program's, not a transient device fault
@@ -1371,8 +1460,8 @@ class PagedModelRunner:
             # runners stage them in ONE replicated device_put (ISSUE 7)
             toks, table = self._stage(padded,
                                       np.asarray(table_row, np.int32)[None])
-            return fn(self.params, toks, table,
-                      np.int32(t), np.int32(start_pos), pools)
+            return self._emit(fn(self.params, toks, table,
+                                 np.int32(t), np.int32(start_pos), pools))
 
     def decode(self, tokens, tables, pos, pools):
         """Batched decode step; tokens [B], tables [B, P], pos [B]."""
@@ -1388,7 +1477,7 @@ class PagedModelRunner:
             toks, tabs, pos_a = self._stage(
                 np.asarray(tokens, np.int32)[:, None],
                 np.asarray(tables, np.int32), pos_np)
-            return fn(self.params, toks, tabs, pos_a, pools)
+            return self._emit(fn(self.params, toks, tabs, pos_a, pools))
 
     def decode_multi(self, tokens, tables, pos, pools, num_steps: int, *,
                      seeds=None, base_steps=None, temps=None,
@@ -1534,7 +1623,8 @@ class PagedModelRunner:
             launch.set(kind=kind, key=(B, T))
             toks, tabs, starts, lens = self._stage(
                 tokens, np.asarray(tables, np.int32), start_pos, q_lens)
-            return fn(self.params, toks, tabs, starts, lens, pools)
+            return self._emit(
+                fn(self.params, toks, tabs, starts, lens, pools))
 
     def _forward(self, params, tokens, positions, write_page, write_off,
                  tables, pos_q, q_lens, pools):
@@ -1782,11 +1872,176 @@ class GPTRunner(PagedModelRunner):
         return logits, new_pools
 
 
+class DeepseekV3Runner(PagedModelRunner):
+    """Paged-step adapter for models.DeepseekV3ForCausalLM: latent
+    attention over LATENT pages and a routed + shared expert layer, one
+    rank's share of it (models/deepseek_v3.py has the equations and the
+    functions; this class is their paging).
+
+    A layer's cache is ONE array a page, `[num_blocks, page, lanes]`:
+    per token c_kv | k_r (`cfg.latent_dim` values), allocated with its
+    lanes rounded up to whole 128-lane tiles because the chip copies a
+    page only as whole tiles (576 -> 640; PERF.md). Two attention paths
+    from one set of weights, chosen from shapes: ONE sequence's span of
+    several rows (a prefill bucket, a chunk) runs the EXPANDED form
+    (per-head keys and values rebuilt from the table's latent rows,
+    blocked over query and key rows), anything else the ABSORBED form
+    through `paged_attend`: the latent decode kernel where `attn_impl`
+    resolves to "ragged" (a decode step on a TPU), the gather path
+    elsewhere. `weight_dtype="int8"` / "fp8" convert the dense matrices
+    (the experts and the router stay floating); latent pages come in the
+    stated dtype only. The expert layers count (tokens routed, pairs
+    computed here, held experts touched): an output of every single-pass
+    step, handed to `on_step_counts`."""
+
+    COUNTS = ("moe_tokens_routed", "moe_local_pairs", "moe_experts_touched")
+    HEAD_ROWS = True
+
+    def __init__(self, model, block_size: int = 16,
+                 max_model_len: int | None = None, attn_impl: str = "auto",
+                 kv_dtype: str = "fp32", weight_dtype: str = "fp32",
+                 weight_group_size: int = 128):
+        from paddle_tpu.jit.functionalize import functionalize
+
+        cfg = model.cfg
+        if kv_dtype != "fp32":
+            raise ValueError(
+                f"kv_dtype={kv_dtype!r}: latent pages come in the model's "
+                "stated dtype only (no quantized rung for them yet)")
+        if weight_dtype == "int4":
+            raise ValueError("weight_dtype='int4' is not wired for the "
+                             "latent-attention runner (int8 and fp8 are)")
+        params = functionalize(model).param_values()
+        super().__init__(params, block_size,
+                         max_model_len or cfg.max_seq_len, attn_impl,
+                         kv_dtype, weight_dtype, weight_group_size)
+        self.cfg = cfg
+        self.num_layers = cfg.num_hidden_layers
+        self.n_heads = cfg.num_attention_heads
+        self.vocab_size = cfg.vocab_size
+        # a page's lanes: the latent row in whole 128-lane tiles
+        self.page_lanes = -(-cfg.latent_dim // 128) * 128
+        self._rope_cos, self._rope_sin = _dsv3.rope_tables(
+            cfg, self.max_model_len)                   # [L, rope] fp32
+        self._scale = _dsv3.softmax_scale(cfg)
+        if weight_dtype != "fp32":
+            names = ["lm_head.weight"]
+            for i in range(self.num_layers):
+                pre = f"layers.{i}."
+                names += [pre + "self_attn." + n + ".weight" for n in (
+                    "q_a_proj", "q_b_proj", "kv_a_proj_with_mqa",
+                    "kv_b_proj", "o_proj")]
+                mlp = pre + ("mlp." if cfg.is_dense(i)
+                             else "mlp.shared_experts.")
+                names += [mlp + n + ".weight" for n in (
+                    "gate_proj", "up_proj", "down_proj")]
+            self._quantize_weights(names)
+
+    def page_layout(self):
+        return [((self.page_lanes,), self.dtype)]
+
+    def _param_specs(self, layout):
+        raise NotImplementedError(
+            "DeepseekV3Runner serves one chip's share; exchanging experts "
+            "and splitting latent pages over a mesh is not built")
+
+    def _attn_impl_for(self, q_len_bucket: int) -> str:
+        """The ABSORBED paths: the latent kernel for a decode step where
+        a kernel is wanted ("auto" on a TPU, or "ragged": interpret mode
+        off it), else the gather reference. (One sequence's longer span
+        takes the expanded form whatever this says: `_forward`.)"""
+        want_kernel = (self.attn_impl == "ragged"
+                       or (self.attn_impl == "auto"
+                           and jax.default_backend() == "tpu"))
+        impl = "ragged" if want_kernel and q_len_bucket == 1 else "reference"
+        key = (q_len_bucket, impl)
+        if key not in self._impl_logged:
+            self._impl_logged.add(key)
+            logger.info("serving attention impl: latent %s (q_len bucket "
+                        "%d, %d heads over %d lanes, attn_impl=%s)", impl,
+                        q_len_bucket, self.n_heads, self.page_lanes,
+                        self.attn_impl)
+        return impl
+
+    def _kv_page_bytes(self) -> int:
+        return (self.num_layers * self.block_size * self.page_lanes
+                * np.dtype(self.dtype).itemsize)
+
+    def _w(self, params, name):
+        """A named matrix as its floating self (dequantized where
+        `_quantize_weights` converted it): the absorbed form multiplies
+        by slices of kv_b_proj, not by the whole of it."""
+        w, s = params[name], params.get(name + SCALE_SUFFIX)
+        dt = params["embed_tokens.weight"].dtype
+        return w.astype(dt) if s is None else w.astype(dt) * s.astype(dt)
+
+    def _forward(self, params, tokens, positions, write_page, write_off,
+                 tables, pos_q, q_lens, pools, head_rows=None):
+        cfg, m = self.cfg, _dsv3
+        B, T = tokens.shape
+        lanes, nh = self.page_lanes, self.n_heads
+        impl = self._attn_impl_for(T)
+        expanded = B == 1 and T > 1
+        x = jnp.take(params["embed_tokens.weight"], tokens, axis=0)
+        cos = jnp.take(self._rope_cos, positions, axis=0)      # [B, T, rope]
+        sin = jnp.take(self._rope_sin, positions, axis=0)
+        valid = (jnp.arange(T, dtype=jnp.int32)[None, :]
+                 < q_lens[:, None]).reshape(B * T)
+        counts = jnp.zeros((len(self.COUNTS),), jnp.int32)
+        new_pools = []
+        for i in range(cfg.num_hidden_layers):
+            pre = f"layers.{i}."
+            with jax.named_scope("block/mla"):
+                h = m.rms_norm(x, params[pre + "input_layernorm.weight"],
+                               cfg.rms_norm_eps)
+                qn, qr, lat = m.mla_project(cfg, params, pre, h, cos, sin,
+                                            mm=self._mm)
+                lat = jnp.pad(lat, ((0, 0), (0, 0),
+                                    (0, lanes - cfg.latent_dim)))
+                w_kvb = self._w(params, pre + "self_attn.kv_b_proj.weight")
+                if expanded:
+                    (pool,) = pools[i]
+                    pool = pool.at[write_page, write_off].set(
+                        lat.astype(pool.dtype))
+                    o = m.expanded_attention(
+                        cfg, qn[0], qr[0],
+                        pool[tables[0]].reshape(-1, lanes), w_kvb,
+                        pos_q[0], q_lens[0])[None]
+                    layer = (pool,)
+                else:
+                    o, layer = paged_attend(
+                        m.absorb_queries(cfg, qn, qr, w_kvb, lanes), lat,
+                        None, pools[i], tables, write_page, write_off, pos_q,
+                        q_lens, nh, impl, scale=self._scale,
+                        v_lanes=cfg.kv_lora_rank)
+                    o = m.absorb_outputs(cfg, o, w_kvb)
+                x = x + self._mm(params, pre + "self_attn.o_proj.weight", o)
+            h = m.rms_norm(x, params[pre + "post_attention_layernorm.weight"],
+                           cfg.rms_norm_eps).reshape(B * T, -1)
+            if cfg.is_dense(i):
+                with jax.named_scope("block/mlp"):
+                    f = m.dense_ffn(params, pre + "mlp.", h, self._mm)
+            else:
+                f, c = m.moe_ffn(cfg, params, pre + "mlp.", h, valid,
+                                 self._mm)
+                counts = counts + c
+            x = x + f.reshape(B, T, -1)
+            new_pools.append(layer)
+        with jax.named_scope("final_norm"):
+            x = m.rms_norm(x, params["norm.weight"], cfg.rms_norm_eps)
+            if head_rows is not None:
+                x = jnp.take_along_axis(x, head_rows[:, None, None], axis=1)
+        with jax.named_scope("lm_head"):
+            logits = self._mm(params, "lm_head.weight", x)
+        return logits, new_pools, counts
+
+
 def runner_for(model, block_size: int = 16, max_model_len: int | None = None,
                attn_impl: str = "auto", kv_dtype: str = "fp32",
                weight_dtype: str = "fp32",
                weight_group_size: int = 128) -> PagedModelRunner:
     """Pick the runner for a supported decoder Layer."""
+    from paddle_tpu.models.deepseek_v3 import DeepseekV3ForCausalLM
     from paddle_tpu.models.gpt import GPT
     from paddle_tpu.models.llama import Llama
 
@@ -1796,6 +2051,10 @@ def runner_for(model, block_size: int = 16, max_model_len: int | None = None,
     if isinstance(model, GPT):
         return GPTRunner(model, block_size, max_model_len, attn_impl,
                          kv_dtype, weight_dtype, weight_group_size)
+    if isinstance(model, DeepseekV3ForCausalLM):
+        return DeepseekV3Runner(model, block_size, max_model_len, attn_impl,
+                                kv_dtype, weight_dtype, weight_group_size)
     raise TypeError(
         f"no serving runner for {type(model).__name__}; supported: Llama, "
-        "GPT (write a PagedModelRunner subclass for custom decoders)")
+        "GPT, DeepseekV3ForCausalLM (write a PagedModelRunner subclass for "
+        "custom decoders)")

@@ -484,40 +484,22 @@ def audit_engine(engine) -> None:
     #    must carry the plain (k, v) pairs
     pool = engine.pool
     kv_dtype = getattr(pool, "kv_dtype", "fp32")
-    want_len = {"int8": 4, "mixed": 3}.get(kv_dtype, 2)
+    # what the pool said it stores (`kv_cache.page_arrays`) is what
+    # every layer tuple holds, array for array
+    want = pool.page_arrays
     for li, layer in enumerate(pool.pools):
-        if len(layer) != want_len:
+        if len(layer) != len(want):
             problems.append(
                 f"layer {li} pool tuple has {len(layer)} entries != "
-                f"{want_len} for kv_dtype={kv_dtype}")
+                f"{len(want)} for kv_dtype={kv_dtype}")
             continue
-        if kv_dtype == "int8":
-            k, v, ks, vs = layer
-            for nm, arr in (("k", k), ("v", v)):
-                if str(arr.dtype) != "int8":
-                    problems.append(f"layer {li} {nm}-pool dtype "
-                                    f"{arr.dtype} != int8 on an int8 pool")
-            for nm, arr in (("k", ks), ("v", vs)):
-                if tuple(arr.shape) != (pool.num_blocks, pool.n_kv_heads):
-                    problems.append(
-                        f"layer {li} {nm}-scale pool shape "
-                        f"{tuple(arr.shape)} != "
-                        f"{(pool.num_blocks, pool.n_kv_heads)} — one scale "
-                        "per page per kv-head")
-        elif kv_dtype == "fp8":
-            for nm, arr in (("k", layer[0]), ("v", layer[1])):
-                if not str(arr.dtype).startswith("float8"):
-                    problems.append(
-                        f"layer {li} {nm}-pool dtype {arr.dtype} is not "
-                        "a float8 type on an fp8 pool")
-        elif kv_dtype == "mixed":
-            tag = layer[2]
-            if str(tag.dtype) != "bool" or tuple(tag.shape) != (
-                    pool.num_blocks,):
+        for (what, shape, dt, _), arr in zip(want, layer):
+            full = (pool.num_blocks,) + tuple(shape)
+            if tuple(arr.shape) != full or arr.dtype != dt:
                 problems.append(
-                    f"layer {li} tag plane shape/dtype "
-                    f"{tuple(tag.shape)}/{tag.dtype} != "
-                    f"({pool.num_blocks},)/bool")
+                    f"layer {li} {what}: pool array {tuple(arr.shape)}/"
+                    f"{arr.dtype} != {full}/{dt} on a kv_dtype={kv_dtype} "
+                    "pool")
 
     # -- per-request kv-dtype tag bijection (ISSUE 15): every page a
     #    running sequence owns carries exactly its owner's effective

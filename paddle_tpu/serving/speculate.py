@@ -261,11 +261,8 @@ class DraftModelProposer:
         self.runner = runner
         self.max_model_len = max_model_len or runner.max_model_len
         self.max_pages = -(-self.max_model_len // runner.block_size)
-        self.pool = KVCachePool(
-            runner.num_layers,
-            (num_blocks or 4 * (self.max_pages + 1)),
-            runner.block_size, runner.n_kv_heads, runner.head_dim,
-            runner.dtype, kv_dtype=getattr(runner, "kv_dtype", "fp32"))
+        self.pool = KVCachePool.for_runner(
+            runner, num_blocks or 4 * (self.max_pages + 1))
         # request_id -> [tokens covered by draft KV, pages, pools-ref ok]
         self._seqs: Dict[str, dict] = {}
         self._lru: List[str] = []       # least recently proposed first

@@ -2,7 +2,9 @@
 and not attached (on-chip-measurement guide, section 2), at GPT-2 124M
 widths (12 heads x 64, sequence 1024, pages of 16) and, for the ragged
 kernel, at the decode cell's: GPT-3 1.3B's 16 x 128 in bf16 over 3136
-pages with a table 128 wide, and a grouped 32/8 x 128.
+pages with a table 128 wide, and a grouped 32/8 x 128; the latent decode
+kernel at `kimi-k2.7-code.decode-16k`'s (48 sequences, 1280-page tables,
+16-token pages of 640 lanes).
 
 Nothing runs, so this says nothing about results or times; it raises what
 the chip's compiler would raise (an unparsable contraction, a block the
@@ -69,6 +71,24 @@ def _ragged_1p3b(B, T, n_q=16, n_kv=16):
                    pages=3136, table=128)
 
 
+def _latent(B, pages=50752, table=1280, n_q=64, lanes=640, v_lanes=512):
+    """kimi-k2.7-code.decode-16k's decode kernel: 48 sequences, 64 query
+    heads over ONE shared key a token, bf16 latent pages of 16 tokens x 640
+    lanes (576 values padded to whole lane tiles), the pool and the table
+    the bench builds (50752 pages; 20480 / 16 = 1280 entries a row, 245 KB
+    of int32 in scalar memory)."""
+    from paddle_tpu.ops.pallas.latent_paged_attention import \
+        latent_paged_attention
+
+    def fn(q, pool, table, pos):
+        return latent_paged_attention(q, pool, table, pos, v_lanes=v_lanes,
+                                      scale=0.1, interpret=False)
+
+    return fn, [((B, n_q, lanes), jnp.bfloat16),
+                ((pages, PAGE, lanes), jnp.bfloat16),
+                ((B, table), jnp.int32), ((B,), jnp.int32)]
+
+
 def _flash(dtype, backward, mode="dense"):
     """mode: the mask forms chip_smoke's kernel phase validates at batch
     8 — "padbias" (a [b, 1, 1, sk] key-padding mask, streamed as a per-key
@@ -132,6 +152,10 @@ CASES = {
     "ragged-gqa-32-8x128-decode-b32": lambda mp: _ragged_1p3b(32, 1, 32, 8),
     "ragged-gqa-32-8x128-prefill-256": lambda mp: _ragged_1p3b(1, 256, 32,
                                                                8),
+    # the latent decode kernel at the Kimi cell's shapes, and the batch-1
+    # step of the naive_generate oracle
+    "latent-kimi-decode-b48": lambda mp: _latent(48),
+    "latent-kimi-decode-b1": lambda mp: _latent(1),
     "flash-fp32-fwd": lambda mp: _flash(jnp.float32, False),
     "flash-bf16-fwd": lambda mp: _flash(jnp.bfloat16, False),
     "flash-fp32-fwd-bwd": lambda mp: _flash(jnp.float32, True),

@@ -60,6 +60,42 @@ def test_pool_sizing_and_scratch_padding():
         pool.pad_table([1, 2, 3], 2)
 
 
+@pytest.mark.parametrize("change", ["append", "truncate_regrow", "fork",
+                                    "release_regrow", "outside_append"])
+def test_pages_array_follows_the_page_list(change):
+    """SequenceKV.pages_array is `pages` as int32 whatever happened to the
+    list since the last call: only appends are converted incrementally."""
+    from paddle_tpu.serving.kv_cache import SequenceKV
+
+    pool = KVCachePool(num_layers=1, num_blocks=100, block_size=4,
+                       n_kv_heads=1, head_dim=8)
+    kv = SequenceKV(pool)
+    kv.grow(12)
+    assert kv.pages_array().dtype == np.int32
+    assert kv.pages_array().tolist() == kv.pages and len(kv.pages) == 3
+    if change == "append":
+        kv.num_tokens = 12
+        kv.grow(300 - 12)             # past the mirror's first capacity
+    elif change == "truncate_regrow":
+        kv.num_tokens = 12
+        kv.truncate(5)                # drops the third page ...
+        other = pool.allocator.alloc(1)
+        kv.num_tokens = 8
+        kv.grow(4)                    # ... and a different one takes its place
+        assert len(kv.pages) == 3 and other
+    elif change == "fork":
+        pool.allocator.incref(kv.pages[1])      # shared: a write forks it
+        assert kv.ensure_writable(4, 8) == 1
+    elif change == "release_regrow":
+        kv.release()
+        pool.allocator.alloc(2)
+        kv.grow(12)
+    else:                             # the scheduler appends to the list
+        kv.pages.append(pool.allocator.alloc(1)[0])
+    assert kv.pages_array().tolist() == kv.pages
+    assert kv.pages_array().tolist() == kv.pages      # and again, unchanged
+
+
 # ------------------------------------------------------------- scheduler
 
 
