@@ -13,10 +13,21 @@ matmul against each block of the sequence's pages,
 with the online softmax of ops/pallas/ragged_paged_attention.py around it.
 Built as that kernel's walk is (PR 26): grid (batch,), the pool stays in
 HBM, a step loops over the LIVE pages of its sequence only, a block of
-`pages_per_block` pages at a time, one async copy per page into a
-double-buffered VMEM block while the block before is folded; after a
-sequence's last block the copy in flight is the first block of the NEXT
-sequence. The block tables ride SMEM by scalar prefetch.
+`pages_per_block` pages at a time, copied into a double-buffered VMEM
+block while the block before is folded; after a sequence's last block the
+copy in flight is the first block of the NEXT sequence. The block tables
+ride SMEM by scalar prefetch.
+
+A block is copied a GROUP of `run_group` pages at a time. Where a group's
+page ids are consecutive (`page_runs`: the allocator hands a prompt's pages
+out in ascending order, so most are) its pages are consecutive bytes of the
+pool and the group is ONE async copy; any other group (pages appended while
+decoding, a sequence's partial tail, a dead slot's scratch row) is a copy
+per page, unrolled where the whole group is live (a loop's scalar chain, not
+the copy engine, is what a 20 KB page cannot amortise). The flags are data
+beside the table: same pages, same bytes, same fold, so the result does not
+depend on them. `latent_walk_counts` counts the groups and descriptors of a
+step in numpy.
 
 The chip's compiler copies a page only where its minor dims are whole
 tiles, so `lanes` must be a multiple of 128: a pool whose vector is 576
@@ -34,6 +45,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 
 try:  # pragma: no cover - absent on pure-CPU builds
@@ -44,6 +56,8 @@ except Exception:  # pragma: no cover
 NEG_INF = -1e30
 # what one grid step may hold of the chip's 16 MiB of scoped VMEM
 VMEM_BUDGET = 10 * 2 ** 20
+# the size of a run copy: past it a copy's fixed cost no longer shows
+RUN_COPY_BYTES = 320 * 2 ** 10
 
 
 def pages_per_block(n_q: int, page_size: int, lanes: int, v_lanes: int,
@@ -54,36 +68,118 @@ def pages_per_block(n_q: int, page_size: int, lanes: int, v_lanes: int,
     per_page = page_size * (2 * lanes * itemsize + lanes * 4
                             + v_lanes * itemsize + 4 * n_q * 4)
     fixed = n_q * (2 * lanes * itemsize + 3 * v_lanes * 4)
-    ppb = 1
-    while ppb < 64 and fixed + 2 * ppb * per_page <= VMEM_BUDGET:
-        ppb *= 2
+    ppb = 64
+    while ppb > 1 and fixed + ppb * per_page > VMEM_BUDGET:
+        ppb //= 2
     return ppb
 
 
-def _kernel(table_ref, pos_ref, q_ref, pool_hbm, o_ref, buf, sem, m_ref,
-            l_ref, acc_ref, slot_ref, *, page_size: int, v_lanes: int,
-            scale: float):
+def run_group(ppb: int, page_size: int, lanes: int, itemsize: int) -> int:
+    """Pages a run copy moves at once: the largest power of two within
+    RUN_COPY_BYTES, at most a block."""
+    group = ppb
+    while group > 1 and group * page_size * lanes * itemsize > RUN_COPY_BYTES:
+        group //= 2
+    return group
+
+
+def page_runs(block_table, group: int):
+    """Which groups of `group` table entries are runs of consecutive page
+    ids, in order (a permuted range is not one): int32 [B, ceil(width /
+    group)]; a group the table's width cuts short is not a run."""
+    table = jnp.asarray(block_table, jnp.int32)
+    B, width = table.shape
+    full = width // group
+    g = table[:, :full * group].reshape(B, full, group)
+    runs = jnp.all(g[:, :, 1:] == g[:, :, :-1] + 1, axis=-1)
+    return jnp.pad(runs.astype(jnp.int32), ((0, 0), (0, -(-width // group)
+                                                     - full)))
+
+
+def walked_groups(runs, pos, page_size: int, group: int, table_width: int):
+    """What one call of the kernel walks, on the device: int32 [2], the
+    groups that hold a live page and those among them copied as one (a
+    flagged group wholly inside its sequence's live pages)."""
+    n_pages = jnp.minimum(jnp.asarray(pos, jnp.int32) // page_size + 1,
+                          table_width)[:, None]
+    first = jnp.arange(runs.shape[1], dtype=jnp.int32)[None] * group
+    return jnp.stack([jnp.sum(first < n_pages),
+                      jnp.sum((runs != 0) & (first + group <= n_pages))]
+                     ).astype(jnp.int32)
+
+
+def latent_walk_counts(block_table, pos, page_size: int, group: int):
+    """numpy twin of the walk, for tests and accounting: (groups walked,
+    groups copied as one run, copy descriptors issued) by one call over
+    these tables and positions."""
+    table, pos = np.asarray(block_table), np.asarray(pos).reshape(-1)
+    groups = as_run = descriptors = 0
+    for row, p in zip(table, pos):
+        n_pages = min(int(p) // page_size + 1, table.shape[1])
+        for first in range(0, n_pages, group):
+            ids = row[first:first + group]
+            run = (first + group <= n_pages
+                   and bool(np.all(ids[1:] == ids[:-1] + 1)))
+            groups += 1
+            as_run += run
+            descriptors += 1 if run else min(group, n_pages - first)
+    return groups, as_run, descriptors
+
+
+def _kernel(table_ref, pos_ref, runs_ref, q_ref, pool_hbm, o_ref, buf, sem,
+            m_ref, l_ref, acc_ref, slot_ref, *, page_size: int, v_lanes: int,
+            scale: float, group: int):
     b, n_seq = pl.program_id(0), pl.num_programs(0)
     ppb = buf.shape[1]
     keys = ppb * page_size
     table_width = table_ref.shape[1]
+    last_group = runs_ref.shape[1] - 1
 
     def seq_pages(b_):
         return jnp.minimum(pos_ref[b_] // page_size + 1, table_width)
 
     def copies(b_, block, slot, n_pages, wait: bool = False):
-        """Start (or wait for) one copy per live page of `block` of
-        sequence b_'s table row into buffer `slot`."""
+        """Start (or wait for) the copies of the live pages of `block` of
+        sequence b_'s table row into buffer `slot`: one per group that is
+        a run and wholly live, one per page elsewhere (unrolled for a whole
+        group, a loop over a sequence's tail). A wait has the shape of the
+        copy it waits for."""
         first = block * ppb
+        live = jnp.clip(n_pages - first, 0, ppb)
 
-        def page(r, carry):
-            pid = 0 if wait else table_ref[b_, first + r]
-            cp = pltpu.make_async_copy(pool_hbm.at[pid], buf.at[slot, r],
-                                       sem.at[slot])
+        def copy(src, dst):
+            cp = pltpu.make_async_copy(src, dst, sem.at[slot])
             cp.wait() if wait else cp.start()
+
+        def one_group(g, carry):
+            page0 = first + g * group
+            whole = page0 + group <= n_pages
+            is_run = jnp.logical_and(
+                runs_ref[b_, jnp.minimum(page0 // group, last_group)] != 0,
+                whole)
+
+            def page(r, c):
+                pid = 0 if wait else table_ref[b_, page0 + r]
+                copy(pool_hbm.at[pid], buf.at[slot, g * group + r])
+                return c
+
+            @pl.when(is_run)
+            def _as_one():
+                pid = 0 if wait else table_ref[b_, page0]
+                copy(pool_hbm.at[pl.ds(pid, group)],
+                     buf.at[slot, pl.ds(g * group, group)])
+
+            @pl.when(jnp.logical_and(whole, jnp.logical_not(is_run)))
+            def _scattered():
+                jax.lax.fori_loop(0, group, page, 0, unroll=True)
+
+            @pl.when(jnp.logical_not(whole))
+            def _tail():
+                jax.lax.fori_loop(0, live - g * group, page, 0)
+
             return carry
 
-        jax.lax.fori_loop(0, jnp.clip(n_pages - first, 0, ppb), page, 0)
+        jax.lax.fori_loop(0, pl.cdiv(live, group), one_group, 0)
 
     last_pos = pos_ref[b]
     n_pages = seq_pages(b)
@@ -157,32 +253,57 @@ def _kernel(table_ref, pos_ref, q_ref, pool_hbm, o_ref, buf, sem, m_ref,
 
 
 def latent_paged_attention(q, pool, block_table, pos, *, v_lanes: int,
-                           scale: float, interpret: bool | None = None):
+                           scale: float, interpret: bool | None = None,
+                           runs=None, pages_per_block: int | None = None,
+                           group: int | None = None):
     """Decode attention of B sequences over latent pages (see the head).
     Every sequence reads at least its first page (a dead slot's table is
-    all scratch and its position 0)."""
+    all scratch and its position 0). `runs` are `page_runs` of the table
+    at `walk_shape`'s group, for a caller whose layers share one table
+    (None: computed here). `pages_per_block` and `group` override the
+    rule: for tests of the walk at small sizes."""
     if pool.shape[2] % 128:
         raise ValueError(
             f"latent pages of {pool.shape[2]} lanes: the chip copies whole "
             "tiles, allocate the pool in multiples of 128")
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
-    return _call(q, pool, block_table, pos, v_lanes=int(v_lanes),
-                 scale=float(scale), interpret=bool(interpret))
+    ppb, group = walk_shape(q.shape[1], pool, v_lanes, pages_per_block, group)
+    if runs is None:
+        runs = page_runs(block_table, group)
+    return _call(q, pool, block_table, pos, runs, v_lanes=int(v_lanes),
+                 scale=float(scale), interpret=bool(interpret), ppb=ppb,
+                 group=group)
+
+
+def walk_shape(n_q: int, pool, v_lanes: int, ppb: int | None = None,
+               group: int | None = None):
+    """(pages a block, pages a run copy) of the walk over `pool` (an array
+    or its shape-and-dtype) for `n_q` query rows."""
+    _, page_size, lanes = pool.shape
+    itemsize = jnp.dtype(pool.dtype).itemsize
+    if ppb is None:
+        ppb = pages_per_block(n_q, page_size, lanes, v_lanes, itemsize)
+    if group is None:
+        group = run_group(ppb, page_size, lanes, itemsize)
+        while group > pool.shape[0]:       # a run lies inside the pool
+            group //= 2
+    if ppb % group:
+        raise ValueError(f"a block of {ppb} pages is not whole groups of "
+                         f"{group}")
+    return int(ppb), int(group)
 
 
 # jitted here as the ragged kernel's wrapper is: a model's layers call it
 # with the same shapes, and a jitted callee is lowered once per program
-@functools.partial(jax.jit, static_argnames=("v_lanes", "scale",
-                                             "interpret"))
-def _call(q, pool, block_table, pos, *, v_lanes: int, scale: float,
-          interpret: bool):
+@functools.partial(jax.jit, static_argnames=("v_lanes", "scale", "interpret",
+                                             "ppb", "group"))
+def _call(q, pool, block_table, pos, runs, *, v_lanes: int, scale: float,
+          interpret: bool, ppb: int, group: int):
     B, n_q, lanes = q.shape
     page_size = pool.shape[1]
-    ppb = pages_per_block(n_q, page_size, lanes, v_lanes,
-                          pool.dtype.itemsize)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(B,),
         in_specs=[pl.BlockSpec((1, n_q, lanes), lambda b, *_: (b, 0, 0)),
                   pl.BlockSpec(memory_space=pl.ANY)],
@@ -198,7 +319,7 @@ def _call(q, pool, block_table, pos, *, v_lanes: int, scale: float,
     )
     return pl.pallas_call(
         functools.partial(_kernel, page_size=page_size, v_lanes=v_lanes,
-                          scale=scale),
+                          scale=scale, group=group),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, n_q, v_lanes), q.dtype),
         # a step starts the next step's first copies: the grid is a
@@ -208,7 +329,7 @@ def _call(q, pool, block_table, pos, *, v_lanes: int, scale: float,
         interpret=interpret,
         name="latent_paged_attn",
     )(block_table.astype(jnp.int32), jnp.asarray(pos, jnp.int32).reshape(-1),
-      q, pool)
+      jnp.asarray(runs, jnp.int32), q, pool)
 
 
 def latent_reference(q, pool, block_table, pos, *, v_lanes: int,

@@ -98,6 +98,7 @@ SUMMABLE_KEYS = (
     "requests_timed_out", "requests_aborted", "step_retries",
     "nan_logit_events", "shed_requests", "tokens_generated",
     "moe_tokens_routed", "moe_local_pairs", "moe_experts_touched",
+    "latent_copy_groups", "latent_run_groups",
     "prefill_tokens", "prefill_chunks", "prefix_hit_tokens", "cow_copies",
     "prefix_cached_pages", "attn_kv_bytes_read", "attn_kv_bytes_gather",
     "tp_comm_bytes", "tp_comm_bytes_fp32",
@@ -190,6 +191,11 @@ class EngineMetrics:
         self.moe_tokens_routed = Counter("moe_tokens_routed")
         self.moe_local_pairs = Counter("moe_local_pairs")
         self.moe_experts_touched = Counter("moe_experts_touched")
+        # the latent decode kernel's walk, read the same way: groups of
+        # pages it copied, all layers, and those whose pages were
+        # consecutive in the pool, copied as ONE copy
+        self.latent_copy_groups = Counter("latent_copy_groups")
+        self.latent_run_groups = Counter("latent_run_groups")
         # prefill_tokens counts tokens actually COMPUTED by prefill
         # chunks; prefix-cache hits skip the compute and land in
         # prefix_hit_tokens instead, so (computed + hit) = total context
@@ -362,6 +368,8 @@ class EngineMetrics:
             "moe_tokens_routed": self.moe_tokens_routed.value,
             "moe_local_pairs": self.moe_local_pairs.value,
             "moe_experts_touched": self.moe_experts_touched.value,
+            "latent_copy_groups": self.latent_copy_groups.value,
+            "latent_run_groups": self.latent_run_groups.value,
             "prefill_tokens": self.prefill_tokens.value,
             "prefill_chunks": self.prefill_chunks.value,
             "prefix_hit_tokens": self.prefix_hit_tokens.value,

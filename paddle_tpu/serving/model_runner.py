@@ -223,7 +223,7 @@ def _shard_mapped_kernel(kernel, shard_ctx, q_spec, rest_specs=()):
 
 def _latent_attend(q, latent_new, layer_pools, tables, write_page,
                    write_off, pos_q, q_len, impl: str, scale: float,
-                   v_lanes: int):
+                   v_lanes: int, runs=None):
     """paged_attend for a LATENT layer: one array a page, `[num_blocks,
     page, lanes]`, each token's row its compressed key whose first
     `v_lanes` lanes are also its value, shared by every query head (the
@@ -231,8 +231,9 @@ def _latent_attend(q, latent_new, layer_pools, tables, write_page,
     n_h, lanes]; latent_new: [B, T, lanes]. Returns ([B, T, n_h,
     v_lanes], (pool,)): the per-head sums of p . value, which the caller
     takes through its value projection. "ragged" is the kernel over
-    latent pages (a decode step: T == 1), "reference" the gather path
-    for any span."""
+    latent pages (a decode step: T == 1; `runs` its flags of which
+    groups of the table are consecutive pages, where the caller's layers
+    share one table), "reference" the gather path for any span."""
     (pool,) = layer_pools
     pool = pool.at[write_page, write_off].set(latent_new.astype(pool.dtype))
     B, T = q.shape[0], q.shape[1]
@@ -244,7 +245,7 @@ def _latent_attend(q, latent_new, layer_pools, tables, write_page,
             raise ValueError(f"the latent kernel is a decode kernel; span "
                              f"of {T} rows")
         out = latent_paged_attention(q[:, 0], pool, tables, pos_q,
-                                     v_lanes=v_lanes, scale=scale)
+                                     v_lanes=v_lanes, scale=scale, runs=runs)
         return out[:, None], (pool,)
     lat = pool[tables].reshape(B, -1, pool.shape[-1])           # [B, L, lanes]
     s = jnp.einsum("bthc,blc->bhtl", q, lat,
@@ -261,7 +262,7 @@ def _latent_attend(q, latent_new, layer_pools, tables, write_page,
 
 def paged_attend(q, k_new, v_new, layer_pools, tables, write_page,
                  write_off, pos_q, q_len, n_rep: int, impl: str,
-                 shard_ctx=None, scale=None, v_lanes=None):
+                 shard_ctx=None, scale=None, v_lanes=None, runs=None):
     """Write this step's K/V through the block table, then attend.
 
     q: [B, T, n_h, d]; k_new/v_new: [B, T, n_kv, d]; layer_pools: one
@@ -284,11 +285,12 @@ def paged_attend(q, k_new, v_new, layer_pools, tables, write_page,
 
     A layer whose pool tuple is ONE array is a latent layer: k_new is
     the tokens' latent rows, v_new None, `scale` the softmax scale and
-    `v_lanes` the value's lanes (see _latent_attend, whose return it
-    is)."""
+    `v_lanes` the value's lanes, `runs` the kernel's run flags (see
+    _latent_attend, whose return it is)."""
     if len(layer_pools) == 1:
         return _latent_attend(q, k_new, layer_pools, tables, write_page,
-                              write_off, pos_q, q_len, impl, scale, v_lanes)
+                              write_off, pos_q, q_len, impl, scale, v_lanes,
+                              runs)
     quantized = len(layer_pools) == 4
     mixed = len(layer_pools) == 3
     if quantized:
@@ -379,7 +381,6 @@ class PagedModelRunner:
     vocab_size: int
 
     ATTN_IMPLS = ("auto", "ragged", "reference")
-    COUNTS = ()      # names of the counters a subclass's steps keep
 
     # what a single-pass step counts on the device, by name: such a
     # runner's `_forward` returns `(logits, pools, counts[len(COUNTS)])`
@@ -1891,10 +1892,12 @@ class DeepseekV3Runner(PagedModelRunner):
     elsewhere. `weight_dtype="int8"` / "fp8" convert the dense matrices
     (the experts and the router stay floating); latent pages come in the
     stated dtype only. The expert layers count (tokens routed, pairs
-    computed here, held experts touched): an output of every single-pass
-    step, handed to `on_step_counts`."""
+    computed here, held experts touched) and so does the latent kernel's
+    walk (groups of pages copied, those copied as one run): an output of
+    every single-pass step, handed to `on_step_counts`."""
 
-    COUNTS = ("moe_tokens_routed", "moe_local_pairs", "moe_experts_touched")
+    COUNTS = ("moe_tokens_routed", "moe_local_pairs", "moe_experts_touched",
+              "latent_copy_groups", "latent_run_groups")
     HEAD_ROWS = True
 
     def __init__(self, model, block_size: int = 16,
@@ -1987,7 +1990,19 @@ class DeepseekV3Runner(PagedModelRunner):
         sin = jnp.take(self._rope_sin, positions, axis=0)
         valid = (jnp.arange(T, dtype=jnp.int32)[None, :]
                  < q_lens[:, None]).reshape(B * T)
-        counts = jnp.zeros((len(self.COUNTS),), jnp.int32)
+        experts = jnp.zeros((3,), jnp.int32)
+        walked = jnp.zeros((2,), jnp.int32)
+        runs = None
+        if impl == "ragged" and not expanded:
+            # which groups of the table are runs of consecutive pages: the
+            # layers share one table, so once for the step's program
+            from paddle_tpu.ops.pallas import latent_paged_attention as lpa
+
+            (pool,) = pools[0]
+            _, group = lpa.walk_shape(nh, pool, cfg.kv_lora_rank)
+            runs = lpa.page_runs(tables, group)
+            walked = cfg.num_hidden_layers * lpa.walked_groups(
+                runs, pos_q, self.block_size, group, tables.shape[1])
         new_pools = []
         for i in range(cfg.num_hidden_layers):
             pre = f"layers.{i}."
@@ -2013,7 +2028,7 @@ class DeepseekV3Runner(PagedModelRunner):
                         m.absorb_queries(cfg, qn, qr, w_kvb, lanes), lat,
                         None, pools[i], tables, write_page, write_off, pos_q,
                         q_lens, nh, impl, scale=self._scale,
-                        v_lanes=cfg.kv_lora_rank)
+                        v_lanes=cfg.kv_lora_rank, runs=runs)
                     o = m.absorb_outputs(cfg, o, w_kvb)
                 x = x + self._mm(params, pre + "self_attn.o_proj.weight", o)
             h = m.rms_norm(x, params[pre + "post_attention_layernorm.weight"],
@@ -2024,7 +2039,7 @@ class DeepseekV3Runner(PagedModelRunner):
             else:
                 f, c = m.moe_ffn(cfg, params, pre + "mlp.", h, valid,
                                  self._mm)
-                counts = counts + c
+                experts = experts + c
             x = x + f.reshape(B, T, -1)
             new_pools.append(layer)
         with jax.named_scope("final_norm"):
@@ -2033,7 +2048,7 @@ class DeepseekV3Runner(PagedModelRunner):
                 x = jnp.take_along_axis(x, head_rows[:, None, None], axis=1)
         with jax.named_scope("lm_head"):
             logits = self._mm(params, "lm_head.weight", x)
-        return logits, new_pools, counts
+        return logits, new_pools, jnp.concatenate([experts, walked])
 
 
 def runner_for(model, block_size: int = 16, max_model_len: int | None = None,

@@ -3,8 +3,8 @@ and not attached (on-chip-measurement guide, section 2), at GPT-2 124M
 widths (12 heads x 64, sequence 1024, pages of 16) and, for the ragged
 kernel, at the decode cell's: GPT-3 1.3B's 16 x 128 in bf16 over 3136
 pages with a table 128 wide, and a grouped 32/8 x 128; the latent decode
-kernel at `kimi-k2.7-code.decode-16k`'s (48 sequences, 1280-page tables,
-16-token pages of 640 lanes).
+kernel at `kimi-k2.7-code.decode-16k`'s (48 sequences, 1280-page tables and
+their 80 run flags a row, 16-token pages of 640 lanes).
 
 Nothing runs, so this says nothing about results or times; it raises what
 the chip's compiler would raise (an unparsable contraction, a block the
@@ -71,22 +71,31 @@ def _ragged_1p3b(B, T, n_q=16, n_kv=16):
                    pages=3136, table=128)
 
 
-def _latent(B, pages=50752, table=1280, n_q=64, lanes=640, v_lanes=512):
+def _latent(B, pages=50752, table=1280, n_q=64, lanes=640, v_lanes=512,
+            flags_in=True):
     """kimi-k2.7-code.decode-16k's decode kernel: 48 sequences, 64 query
     heads over ONE shared key a token, bf16 latent pages of 16 tokens x 640
     lanes (576 values padded to whole lane tiles), the pool and the table
     the bench builds (50752 pages; 20480 / 16 = 1280 entries a row, 245 KB
-    of int32 in scalar memory)."""
-    from paddle_tpu.ops.pallas.latent_paged_attention import \
-        latent_paged_attention
+    of int32 in scalar memory) and, beside it, the flags of which groups
+    of 16 entries are runs of consecutive pages (48 x 80 int32). The flags
+    are DATA: an all-run and a no-run table are one program. `flags_in`:
+    an operand, as the runner's decode step passes them (computed once
+    for its layers); else computed from the table inside the call."""
+    from paddle_tpu.ops.pallas import latent_paged_attention as lpa
 
-    def fn(q, pool, table, pos):
-        return latent_paged_attention(q, pool, table, pos, v_lanes=v_lanes,
-                                      scale=0.1, interpret=False)
+    pool = jax.ShapeDtypeStruct((pages, PAGE, lanes), jnp.bfloat16)
+    ppb, group = lpa.walk_shape(n_q, pool, v_lanes)
+    assert (ppb, group) == (64, 16)
 
-    return fn, [((B, n_q, lanes), jnp.bfloat16),
-                ((pages, PAGE, lanes), jnp.bfloat16),
-                ((B, table), jnp.int32), ((B,), jnp.int32)]
+    def fn(q, pool, table, pos, *runs):
+        return lpa.latent_paged_attention(
+            q, pool, table, pos, v_lanes=v_lanes, scale=0.1,
+            interpret=False, runs=runs[0] if runs else None)
+
+    return fn, [((B, n_q, lanes), jnp.bfloat16), (pool.shape, pool.dtype),
+                ((B, table), jnp.int32), ((B,), jnp.int32)] + (
+        [((B, table // group), jnp.int32)] if flags_in else [])
 
 
 def _flash(dtype, backward, mode="dense"):
@@ -156,6 +165,8 @@ CASES = {
     # step of the naive_generate oracle
     "latent-kimi-decode-b48": lambda mp: _latent(48),
     "latent-kimi-decode-b1": lambda mp: _latent(1),
+    "latent-kimi-decode-b48-flags-inside": lambda mp: _latent(
+        48, flags_in=False),
     "flash-fp32-fwd": lambda mp: _flash(jnp.float32, False),
     "flash-bf16-fwd": lambda mp: _flash(jnp.bfloat16, False),
     "flash-fp32-fwd-bwd": lambda mp: _flash(jnp.float32, True),

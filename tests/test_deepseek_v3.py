@@ -19,8 +19,10 @@ from paddle_tpu.models import deepseek_v3 as dsv3
 from paddle_tpu.models.deepseek_v3 import (
     DeepseekV3Config, DeepseekV3ForCausalLM,
 )
+from paddle_tpu.ops.pallas import latent_paged_attention as lpa
 from paddle_tpu.ops.pallas.latent_paged_attention import (
-    latent_paged_attention, latent_reference,
+    latent_paged_attention, latent_reference, latent_walk_counts, page_runs,
+    walked_groups,
 )
 from paddle_tpu.parallel.moe import (
     _swiglu, held_experts_ffn, sigmoid_topk_route,
@@ -171,7 +173,8 @@ def test_expert_counters_count_a_decode_step(seeded):
                               np.asarray([0, 0, 0]), pool.pools)
     assert logits.shape == (3, CFG["vocab_size"])     # the entry's own pair
     (counts,) = heard
-    routed, pairs, touched = (int(n) for n in counts)
+    routed, pairs, touched, *walked = (int(n) for n in counts)
+    assert walked == [0, 0]      # the gather path here: no kernel, no walk
     assert routed == 3 * 2       # every slot of a decode step, 2 expert layers
     assert 0 < touched <= pairs <= routed * CFG["num_experts_per_tok"]
     assert touched <= 2 * CFG["experts_held"]
@@ -298,6 +301,151 @@ def test_latent_kernel_equals_the_gather_path(contexts):
                             v_lanes=v_lanes, scale=0.2)
     assert got.shape == (B, n_q, v_lanes)
     assert float(jnp.abs(got - want).max()) < 2e-5
+
+
+WALK_PAGE = 16
+
+
+def _walk_tables(kind, ppb, group, rng):
+    """(table [3, 5 * ppb + group], contexts) of three sequences of 1, 3
+    and 5 blocks whose page ids are laid out as `kind` says. Ids ascend
+    from 1, sequences back to back, as the allocator hands them out."""
+    n_pages = [ppb, 3 * ppb, 5 * ppb]
+    contexts = [n * WALK_PAGE - off for n, off in zip(n_pages, (0, 5, 11))]
+    width = 5 * ppb + group
+    table = np.zeros((3, width), np.int32)
+    first = 1
+    for b, n in enumerate(n_pages):
+        ids = np.arange(first, first + n)
+        if kind == "permuted":
+            ids = rng.permutation(ids)
+            while np.any(np.diff(ids) == 1):     # no two left in order
+                ids = rng.permutation(ids)
+        elif kind == "broken_mid_group":
+            # the run breaks one page into every second group
+            for g0 in range(0, n, 2 * group):
+                ids[g0 + max(group // 2, 1):] += 9
+        elif kind == "starts_mid_group":
+            ids[:max(group // 2, 1)] += 500         # strays, then the run
+        elif kind == "permuted_range":
+            for g0 in range(0, n, group):           # right range, wrong order
+                ids[g0:g0 + group] = ids[g0:g0 + group][::-1]
+        table[b, :n] = ids
+        first = int(ids.max()) + 1
+    if kind == "ends_inside_flagged_group":
+        # pages held past the context's end (a reservation): the last
+        # group is a run in the table and only partly live
+        for b, n in enumerate(n_pages):
+            table[b, :n + group] = np.arange(table[b, 0],
+                                             table[b, 0] + n + group)
+        contexts = [ppb * WALK_PAGE - 3,                     # mid-page, whole
+                    (3 * ppb - 1) * WALK_PAGE - 3,           # partial block
+                    5 * ppb * WALK_PAGE + 2]     # one page into the next
+    if kind == "dead_slot":
+        table[1], contexts[1] = 0, 1             # scratch row, position 0
+    return table, contexts
+
+
+@pytest.mark.parametrize("ppb,group", [(2, 1), (2, 2), (4, 2), (4, 4)])
+@pytest.mark.parametrize("kind", [
+    "ascending", "permuted", "broken_mid_group", "starts_mid_group",
+    "permuted_range", "ends_inside_flagged_group", "dead_slot"])
+def test_latent_walk_across_blocks_and_run_kinds(kind, ppb, group):
+    """The walk over 1, 3 and 5 blocks (the unmasked fold, the buffer swap,
+    the hand-over to the next sequence) with pages copied as runs where the
+    table has them: equal to the oracle, and BIT-equal to the same kernel
+    with every flag forced to 0 (same pages, same fold)."""
+    rng = np.random.default_rng(ppb * 10 + group)
+    table, contexts = _walk_tables(kind, ppb, group, rng)
+    B, n_q, lanes, v_lanes = 3, 8, 128, 64
+    ks = jax.random.split(jax.random.key(7), 2)
+    pool = jax.random.normal(ks[0], (int(table.max()) + 2, WALK_PAGE, lanes),
+                             jnp.float32)
+    q = jax.random.normal(ks[1], (B, n_q, lanes), jnp.float32)
+    pos = jnp.asarray(contexts, jnp.int32) - 1
+    kw = dict(v_lanes=v_lanes, scale=0.2, interpret=True,
+              pages_per_block=ppb, group=group)
+    got = latent_paged_attention(q, pool, jnp.asarray(table), pos, **kw)
+    want = latent_reference(q, pool, jnp.asarray(table), pos,
+                            v_lanes=v_lanes, scale=0.2)
+    assert float(jnp.abs(got - want).max()) < 2e-5
+    runs = page_runs(table, group)
+    plain = latent_paged_attention(q, pool, jnp.asarray(table), pos,
+                                   runs=jnp.zeros_like(runs), **kw)
+    assert np.array_equal(np.asarray(got), np.asarray(plain))
+    # the device's count of the walk = the numpy twin's
+    groups, as_run, descriptors = latent_walk_counts(table, pos, WALK_PAGE,
+                                                     group)
+    assert [int(n) for n in walked_groups(
+        runs, pos, WALK_PAGE, group, table.shape[1])] == [groups, as_run]
+    live = [int(p) // WALK_PAGE + 1 for p in pos]
+    assert groups == sum(-(-n // group) for n in live)
+    if kind == "ascending":
+        assert as_run == groups and descriptors == groups
+    if group > 1 and kind in ("permuted", "permuted_range"):
+        assert as_run == 0 and descriptors == sum(live)
+    if group > 1 and kind == "broken_mid_group":
+        assert 0 < as_run < groups
+    if group > 1 and kind == "starts_mid_group":
+        assert as_run == groups - 3             # each sequence's first group
+    if kind == "dead_slot":
+        assert groups == ppb // group + 1 + 5 * ppb // group
+
+
+def test_page_runs_checks_the_order_and_not_the_range():
+    table = np.asarray([[4, 5, 6, 7, 7, 6, 5, 4, 9, 10, 12, 11, 0, 0]])
+    assert page_runs(table, 4).tolist() == [[1, 0, 0, 0]]
+    assert page_runs(table, 2).tolist() == [[1, 1, 0, 0, 1, 0, 0]]
+    # a group the table's width cuts short is never a run
+    assert page_runs(np.arange(1, 7)[None], 4).tolist() == [[1, 0]]
+
+
+def test_a_block_of_runs_is_four_descriptors():
+    """64 consecutive pages, a full block: 4 copies of 16 pages where a
+    walk that copies page by page issues 64."""
+    table, pos = np.arange(1, 65)[None], np.asarray([64 * 16 - 1])
+    assert latent_walk_counts(table, pos, 16, 16) == (4, 4, 4)
+    assert latent_walk_counts(table[:, ::-1], pos, 16, 16) == (4, 0, 64)
+    # the rule at the Kimi cell's pages (bf16, 16 x 640): blocks of 64
+    # pages, runs of 16 = 320 KiB a copy
+    pool = jax.ShapeDtypeStruct((50752, 16, 640), jnp.bfloat16)
+    assert lpa.walk_shape(64, pool, 512) == (64, 16)
+    with pytest.raises(ValueError, match="whole groups"):
+        lpa.walk_shape(64, pool, 512, 4, 8)
+
+
+def test_engine_counts_the_walk_as_the_numpy_twin_does(seeded, monkeypatch):
+    """`latent_copy_groups` / `latent_run_groups`, outputs of each decode
+    step's program read at the step's drain, against `latent_walk_counts`
+    over the tables and positions the engine launched (3 layers). The run
+    size is steered to 2 pages here (toy pages are 8 KB and a table 6
+    entries wide: the rule's 32 would leave no group to count)."""
+    monkeypatch.setattr(lpa, "RUN_COPY_BYTES", 2 * 16 * 128 * 4)
+    model, _ = seeded
+    eng = create_serving_engine(model, num_blocks=64, max_batch_size=4,
+                                attn_impl="ragged")
+    launched = []
+    decode = eng.runner.decode
+
+    def spy(tokens, tables, pos, pools, *a, **k):
+        launched.append((np.array(tables), np.array(pos)))
+        return decode(tokens, tables, pos, pools, *a, **k)
+
+    monkeypatch.setattr(eng.runner, "decode", spy)
+    prompts = [_tokens(n, n).tolist() for n in (40, 70, 9)]
+    params = SamplingParams(max_tokens=12)
+    rids = [eng.add_request(p, params) for p in prompts]
+    outs = eng.run()
+    for rid, p in zip(rids, prompts):
+        assert outs[rid].output_tokens == naive_generate(eng.runner, p,
+                                                         params)
+    launched = launched[:int(eng.metrics.snapshot()["decode_steps"])]
+    want = np.sum([latent_walk_counts(t, p, 16, 2)[:2]
+                   for t, p in launched], axis=0) * CFG["num_hidden_layers"]
+    snap = eng.metrics.snapshot()
+    assert [snap["latent_copy_groups"], snap["latent_run_groups"]] == \
+        want.tolist()
+    assert 0 < snap["latent_run_groups"] < snap["latent_copy_groups"]
 
 
 def test_latent_kernel_refuses_pages_that_are_not_whole_tiles():
