@@ -161,86 +161,56 @@ def create_predictor(config: Config) -> Predictor:
 
 
 def create_serving_engine(model, dtype=None, **kw):
-    """Build a continuous-batching ServingEngine for a decoder Layer.
+    """Build a continuous-batching ServingEngine for a decoder Layer
+    (Llama, GPT, DeepseekV3ForCausalLM).
 
     The serving-path analogue of create_predictor: where the reference
     pairs fluid/inference with block_multihead_attention and a serving
     framework above it, this hands the model to paddle_tpu.serving
-    (paged KV pool + FCFS continuous batching + Pallas paged decode).
-    `dtype` casts weights (and thus the KV pool) — the serving twin of
-    Config.enable_low_precision. See paddle_tpu/serving/__init__.py for
-    the engine knobs (num_blocks, block_size, max_batch_size, ...).
+    (paged KV pool + FCFS continuous batching + Pallas paged attention).
+    `kw` is split in two by name:
 
-    Robustness knobs pass straight through to the engine (ISSUE 2):
-    per-request deadlines ride SamplingParams.timeout_s; `max_queue_depth`
-    + `shed_policy` bound the admission queue; `admission_watermark` caps
-    pool pressure; `max_step_retries`/`retry_backoff_s` recover transient
-    runner failures; `nan_policy` guards sampling; `audit=True` runs the
-    invariant auditor after every step.
+    the runner's (`serving.model_runner.RUNNER_OPTIONS`, `runner_for`):
+      block_size, max_model_len, attn_impl
+      kv_dtype       "fp32" | "int8" (per-page-per-head scales, dequant
+                     inside the ragged kernel's page walk) | "fp8"
+                     (float8 pages, no scales) | "mixed" (fp32 and fp8
+                     tenants in one pool, by SamplingParams.kv_dtype)
+      weight_dtype   "fp32" | "int8" (per-output-channel scales) |
+                     "int4" (packed nibbles, one scale per
+                     `weight_group_size` reduction rows) | "fp8"; the
+                     dequant sits in the matmul epilogue
+    with `dtype` (cast the floating weights, and so the KV pool: the
+    serving twin of Config.enable_low_precision), `mesh` (a `(data,
+    model)` mesh from parallel.mesh.serving_mesh: weights and K/V pools
+    shard over the model axis, n_kv_heads must divide by its degree,
+    token streams unchanged) and `comm_dtype` ("int8", with a mesh: the
+    row-parallel allreduce and the lm_head's all-gather carry int8 codes
+    with shared per-chunk scales);
 
-    `mesh=` (a `(data, model)` jax mesh — parallel.mesh.serving_mesh)
-    serves tensor-parallel (ISSUE 7): weights and the paged K/V pools
-    shard over the model axis, token streams unchanged.
-
-    `kv_dtype="int8"` / `weight_dtype="int8"` (ISSUE 9) serve quantized:
-    int8 K/V pages with per-page-per-head scales dequantized inside the
-    ragged kernel's page walk, and/or weight-only int8 linears — the
-    serving analogue of the reference weight_only_linear path. Accuracy-
-    gated (top-k overlap vs the fp32 oracle), ~half the attention HBM
-    bytes; composes with `mesh=` (scales shard with their pools).
-
-    ISSUE 15 rungs: `kv_dtype="fp8"` (native float8 pages, 4x fewer KV
-    bytes), `kv_dtype="mixed"` (per-request SamplingParams.kv_dtype
-    tenants in one pool), and `comm_dtype="int8"` (with `mesh=`: the
-    row-parallel allreduce becomes the chunked quantized psum).
-
-    ISSUE 19 rungs: `weight_dtype="int4"` (packed nibble codes + group
-    scales, `weight_group_size` reduction rows per scale, dequant in
-    the matmul epilogue), `weight_dtype="fp8"` (native float8 weights,
-    scale-free); with `mesh=`, `comm_dtype="int8"` also quantizes the
-    lm_head's column-parallel logits all-gather."""
-    import jax.numpy as jnp
-
+    the engine's: every field of `serving.EngineConfig` (num_blocks
+    defaults to 128 here), which documents them, and `metrics`,
+    `tokenizer`, `audit`, `sleep_fn`, `kv_store`, `kv_store_owner`.
+    An unknown name is a TypeError."""
     from paddle_tpu.serving import ServingEngine
-    from paddle_tpu.serving.model_runner import runner_for
+    from paddle_tpu.serving.model_runner import RUNNER_OPTIONS, build_runner
 
     mesh = kw.pop("mesh", None)
     comm_dtype = kw.pop("comm_dtype", "fp32")
-    if comm_dtype != "fp32" and mesh is None:
-        raise ValueError(
-            f"comm_dtype={comm_dtype!r} needs a tensor-parallel mesh — "
-            "the quantized collective replaces the row-parallel "
-            "allreduce, which only exists at tp > 1")
     # runner, weight casts, sharding and the KV pool: part of set-up
     with _prof.always_span("engine.build"):
-        runner = runner_for(model,
-                            **{k: kw.pop(k) for k in
-                               ("block_size", "max_model_len", "attn_impl",
-                                "kv_dtype", "weight_dtype",
-                                "weight_group_size")
-                               if k in kw})
-        if dtype is not None:
-            runner.params = {
-                k: (v.astype(dtype) if jnp.issubdtype(v.dtype, jnp.floating)
-                    else v) for k, v in runner.params.items()}
-        if mesh is not None:
-            # cast first, shard second: the device_put then ships the final
-            # serving dtype, not fp32 weights that get re-cast on device
-            runner.shard(mesh, comm_dtype=comm_dtype)
+        runner = build_runner(
+            model, dtype=dtype, mesh=mesh, comm_dtype=comm_dtype,
+            **{k: kw.pop(k) for k in RUNNER_OPTIONS if k in kw})
         kw.setdefault("num_blocks", 128)
         return ServingEngine(runner, **kw)
 
 
 def create_serving_router(model, *, replicas: int = 2, dtype=None,
-                          mesh=None, meshes=None, attn_impl: str = "auto",
-                          block_size: int = 16,
-                          max_model_len: Optional[int] = None,
+                          mesh=None, meshes=None,
                           data_axis: str = "data",
-                          model_axis: str = "model",
-                          kv_dtype: str = "fp32",
-                          weight_dtype: str = "fp32",
-                          weight_group_size: int = 128, **kw):
-    """Build a multi-engine ServingRouter for a decoder Layer (ISSUE 8).
+                          model_axis: str = "model", **kw):
+    """Build a multi-engine ServingRouter for a decoder Layer.
 
     The fleet-tier analogue of create_serving_engine: N full serving
     engines (thread-per-engine, each with its own paged KV pool and
@@ -255,19 +225,16 @@ def create_serving_router(model, *, replicas: int = 2, dtype=None,
     finally mapping the data axis onto engine replicas. A single mesh
     with data=1 shards every replica identically.
 
-    Every other keyword reaches each replica's ServingEngine verbatim —
-    including the speculation knobs (ISSUE 18): num_speculative_tokens,
-    spec_max_ngram/spec_min_ngram/spec_ngram_window, spec_adaptive_k,
-    and spec_draft_model/spec_draft_blocks. On the process backend
+    The runner's options (create_serving_engine lists them) build each
+    replica's runner; every other keyword reaches the router and, through
+    it, each replica's ServingEngine verbatim. On the process backend
     (backend="process") engine_kw crosses the wire as JSON, so pass the
     draft rung as its "shadow[:int8|int4|fp8|fp32]" string spec (each
     child builds its own shadow from its own runner), not an instance;
     the same string round-trips through engine snapshots, so a
     Supervisor respawn keeps the tier speculating."""
-    import jax.numpy as jnp
-
     from paddle_tpu.serving import ServingRouter
-    from paddle_tpu.serving.model_runner import runner_for
+    from paddle_tpu.serving.model_runner import RUNNER_OPTIONS, build_runner
 
     if meshes is None and mesh is not None:
         data = dict(mesh.shape).get(data_axis, 1)
@@ -280,23 +247,13 @@ def create_serving_router(model, *, replicas: int = 2, dtype=None,
             meshes = [mesh] * replicas
     if meshes is not None and len(meshes) < replicas:
         raise ValueError(f"{len(meshes)} meshes for {replicas} replicas")
+    runner_kw = {k: kw.pop(k) for k in RUNNER_OPTIONS if k in kw}
 
     def factory(idx: int):
-        runner = runner_for(model, block_size=block_size,
-                            max_model_len=max_model_len,
-                            attn_impl=attn_impl, kv_dtype=kv_dtype,
-                            weight_dtype=weight_dtype,
-                            weight_group_size=weight_group_size)
-        if dtype is not None:
-            runner.params = {
-                k: (v.astype(dtype)
-                    if jnp.issubdtype(v.dtype, jnp.floating) else v)
-                for k, v in runner.params.items()}
-        if meshes is not None and meshes[idx] is not None:
-            # cast first, shard second (same order as the single-engine
-            # bridge): the device_put ships the final serving dtype
-            runner.shard(meshes[idx], model_axis=model_axis)
-        return runner
+        return build_runner(
+            model, dtype=dtype,
+            mesh=meshes[idx] if meshes is not None else None,
+            model_axis=model_axis, **runner_kw)
 
     kw.setdefault("num_blocks", 128)
     return ServingRouter(factory, replicas=replicas, **kw)
@@ -307,28 +264,20 @@ def restore_serving_engine(model, state, attn_impl: str = "auto",
     """Rebuild a crashed/killed serving engine from `engine.snapshot()`.
 
     The crash-recovery twin of create_serving_engine: builds a fresh
-    runner for `model` (the weights the snapshot was serving) and replays
-    all serialized request state through ServingEngine.restore — every
-    in-flight request resumes via recompute-on-resume, token-for-token
-    identical to an uninterrupted run. Pass `mesh=` to restore onto a
-    tensor-parallel runner; recompute-on-resume is sharding-agnostic, so
-    the mesh may differ from the snapshot's (config["mesh_axes"]). The
-    snapshot's kv_dtype/weight_dtype knobs (ISSUE 9) are restored the
-    same way: recompute rebuilds KV from tokens, so the fresh runner is
-    built with the recorded quantization."""
+    runner for `model` (the weights the snapshot was serving) with the
+    snapshot's own recipe (block size, model length, kv_dtype,
+    weight_dtype and its group size) and replays all serialized request
+    state through ServingEngine.restore — every in-flight request
+    resumes via recompute-on-resume, token-for-token identical to an
+    uninterrupted run. Pass `mesh=` to restore onto a tensor-parallel
+    runner; recompute-on-resume is sharding-agnostic, so the mesh may
+    differ from the snapshot's (config["mesh_axes"])."""
     from paddle_tpu.serving import ServingEngine
-    from paddle_tpu.serving.model_runner import runner_for
+    from paddle_tpu.serving.model_runner import RUNNER_OPTIONS, build_runner
 
-    runner = runner_for(model, block_size=state["config"]["block_size"],
-                        max_model_len=state["config"]["max_model_len"],
-                        attn_impl=attn_impl,
-                        kv_dtype=state["config"].get("kv_dtype", "fp32"),
-                        weight_dtype=state["config"].get("weight_dtype",
-                                                         "fp32"),
-                        weight_group_size=state["config"].get(
-                            "weight_group_size", 128))
-    if mesh is not None:
-        runner.shard(mesh)
+    cfg = state["config"]
+    runner = build_runner(model, mesh=mesh, attn_impl=attn_impl,
+                          **{k: cfg[k] for k in RUNNER_OPTIONS if k in cfg})
     return ServingEngine.restore(runner, state, **kw)
 
 

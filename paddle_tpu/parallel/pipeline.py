@@ -37,25 +37,14 @@ def manual_shard_map(f, **kw):
     return jax.jit(jax.shard_map(f, check_vma=False, **kw))
 
 
-def varying(v, axis: str = "pp"):
-    """Mark a value as axis-varying for shard_map's vma type system (no-op
-    if already varying). Shared by the pipeline schedules and ring
-    attention."""
-    if axis in jax.typeof(v).vma:
-        return v
-    return lax.pcast(v, (axis,), to="varying")
-
-
-def chain_stages(stage_fn, stacked_local, h, axis: str = "pp"):
+def chain_stages(stage_fn, stacked_local, h):
     """Run h through stage_fn once per leading-axis entry of stacked_local
-    (scan; length-1 fast path). The carry is cast axis-varying for the vma
-    type system. Shared by pipeline_apply, the 1F1B dev_fn, and the GPT
-    interleave chunk chain."""
+    (scan; length-1 fast path). Shared by pipeline_apply, the 1F1B dev_fn,
+    and the GPT interleave chunk chain."""
     n = jax.tree_util.tree_leaves(stacked_local)[0].shape[0]
     if n == 1:
         return stage_fn(jax.tree_util.tree_map(lambda a: a[0],
                                                stacked_local), h)
-    h = varying(h, axis)
     h, _ = lax.scan(lambda c, p: (stage_fn(p, c), None), h, stacked_local)
     return h
 
@@ -96,8 +85,6 @@ def pipeline_apply(stage_fn: Callable[[Any, Any], Any], stacked_params,
     assert total_stages % npp == 0, (
         f"stage count {total_stages} must divide pp={npp}")
 
-    _varying = varying
-
     def per_device(params_local, x):
         pp = lax.axis_index("pp")
 
@@ -128,8 +115,8 @@ def pipeline_apply(stage_fn: Callable[[Any, Any], Any], stacked_params,
             return (nxt, outbuf), None
 
         init = (
-            _varying(jnp.zeros(out_aval.shape, out_aval.dtype)),
-            _varying(jnp.zeros((num_micro,) + out_aval.shape, out_aval.dtype)),
+            jnp.zeros(out_aval.shape, out_aval.dtype),
+            jnp.zeros((num_micro,) + out_aval.shape, out_aval.dtype),
         )
         (_, outbuf), _ = lax.scan(tick, init, jnp.arange(total_ticks))
         return outbuf

@@ -490,7 +490,7 @@ def schedule_stats(pp: int, m: int, schedule: str = "gpipe", v: int = 1):
 
 
 from paddle_tpu.parallel.pipeline import (  # noqa: E402
-    chain_stages, manual_shard_map, varying as _varying,
+    chain_stages, manual_shard_map,
 )
 
 
@@ -561,7 +561,7 @@ def pipeline_apply_interleave(stage_fn: Callable[[Any, Any], Any],
                                            0, keepdims=False)
             h_a = lax.dynamic_index_in_dim(arr_buf, trow["rd_slot"][d], 0,
                                            keepdims=False)
-            h = jnp.where(trow["from_x"][d] > 0, _varying(h_x), h_a)
+            h = jnp.where(trow["from_x"][d] > 0, h_x, h_a)
             chunk = jnp.clip(j // npp, 0, v - 1)
             p_c = jax.tree_util.tree_map(
                 lambda a: lax.dynamic_index_in_dim(a, chunk, 0,
@@ -579,9 +579,9 @@ def pipeline_apply_interleave(stage_fn: Callable[[Any, Any], Any],
             return (arr_buf, outbuf, nxt), None
 
         z = jnp.zeros(mb_shape, x.dtype)
-        init = (_varying(jnp.zeros((A,) + mb_shape, x.dtype)),
-                _varying(jnp.zeros((num_micro,) + mb_shape, x.dtype)),
-                _varying(z))
+        init = (jnp.zeros((A,) + mb_shape, x.dtype),
+                jnp.zeros((num_micro,) + mb_shape, x.dtype),
+                z)
         (_, outbuf, _), _ = lax.scan(tick, init, tab)
         return outbuf
 
@@ -632,12 +632,6 @@ def pipeline_1f1b(stage_fn: Callable[[Any, Any], Any], stacked_params,
         d = lax.axis_index("pp")
         is_first = d == 0
         is_last = d == npp - 1
-        # head params arrive replicated (unvarying). Differentiating the
-        # pp-varying per-device loss w.r.t. an UNVARYING input makes the
-        # shard_map transpose insert a psum over 'pp' — mixing every
-        # device's (masked-out) head recompute into the gradient. Cast to
-        # varying so head grads stay device-local until the final psum.
-        head_p = jax.tree_util.tree_map(_varying, head_p)
         mb_shape = x.shape[1:]
         z = jnp.zeros(mb_shape, x.dtype)
 
@@ -653,7 +647,7 @@ def pipeline_1f1b(stage_fn: Callable[[Any, Any], Any], stacked_params,
             f_valid = f_mb >= 0
             mb_c = jnp.clip(f_mb, 0, m - 1)
             h_x = lax.dynamic_index_in_dim(x, mb_c, 0, keepdims=False)
-            h = jnp.where(is_first, _varying(h_x), f_in)
+            h = jnp.where(is_first, h_x, f_in)
             stash = jnp.where(
                 f_valid,
                 lax.dynamic_update_index_in_dim(stash, h, trow["f_slot"][d],
@@ -686,12 +680,9 @@ def pipeline_1f1b(stage_fn: Callable[[Any, Any], Any], stacked_params,
 
             def skip_branch(op):
                 hp, yy, ll = op
-                # fresh zeros are unvarying; match the head branch's
-                # pp-varying output types for cond
-                return (_varying(jnp.zeros((), jnp.float32)),
-                        jax.tree_util.tree_map(
-                            lambda a: _varying(jnp.zeros_like(a)), hp),
-                        _varying(jnp.zeros_like(yy)))
+                return (jnp.zeros((), jnp.float32),
+                        jax.tree_util.tree_map(jnp.zeros_like, hp),
+                        jnp.zeros_like(yy))
 
             loss_i, g_head_i, gy_last = lax.cond(
                 b_valid & is_last, head_branch, skip_branch,
@@ -713,15 +704,13 @@ def pipeline_1f1b(stage_fn: Callable[[Any, Any], Any], stacked_params,
                     dx_buf), None
 
         init = (
-            _varying(jnp.zeros((S,) + mb_shape, x.dtype)),      # stash
-            _varying(z),                                        # f_in
-            _varying(z),                                        # g_in
-            jax.tree_util.tree_map(
-                lambda a: _varying(jnp.zeros_like(a)), params_local),
-            jax.tree_util.tree_map(
-                lambda a: _varying(jnp.zeros_like(a)), head_p),
-            _varying(jnp.zeros((), jnp.float32)),
-            _varying(jnp.zeros((m,) + mb_shape, x.dtype)),
+            jnp.zeros((S,) + mb_shape, x.dtype),                # stash
+            z,                                                  # f_in
+            z,                                                  # g_in
+            jax.tree_util.tree_map(jnp.zeros_like, params_local),
+            jax.tree_util.tree_map(jnp.zeros_like, head_p),
+            jnp.zeros((), jnp.float32),
+            jnp.zeros((m,) + mb_shape, x.dtype),
         )
         (stash, _, _, gparams, ghead, loss_acc, dx_buf), _ = lax.scan(
             tick, init, tab)
@@ -790,7 +779,6 @@ def pipeline_zbh1(stage_fn: Callable[[Any, Any], Any], stacked_params,
         d = lax.axis_index("pp")
         is_first = d == 0
         is_last = d == npp - 1
-        head_p = jax.tree_util.tree_map(_varying, head_p)  # see 1f1b note
         mb_shape = x.shape[1:]
         z = jnp.zeros(mb_shape, x.dtype)
 
@@ -820,7 +808,7 @@ def pipeline_zbh1(stage_fn: Callable[[Any, Any], Any], stacked_params,
                 h_x = lax.dynamic_index_in_dim(x, mb, 0, keepdims=False)
                 h_a = lax.dynamic_index_in_dim(h_arr, trow["f_rd"][d], 0,
                                                keepdims=False)
-                h = jnp.where(trow["f_from_x"][d] > 0, _varying(h_x), h_a)
+                h = jnp.where(trow["f_from_x"][d] > 0, h_x, h_a)
                 h_st = lax.dynamic_update_index_in_dim(
                     h_st, h, trow["f_st"][d], 0)
                 y = dev_fn(params_local, h)
@@ -847,10 +835,9 @@ def pipeline_zbh1(stage_fn: Callable[[Any, Any], Any], stacked_params,
 
                 def skip_branch(op_):
                     hp, yy, _ = op_
-                    return (_varying(jnp.zeros((), jnp.float32)),
-                            jax.tree_util.tree_map(
-                                lambda a: _varying(jnp.zeros_like(a)), hp),
-                            _varying(jnp.zeros_like(yy)))
+                    return (jnp.zeros((), jnp.float32),
+                            jax.tree_util.tree_map(jnp.zeros_like, hp),
+                            jnp.zeros_like(yy))
 
                 loss_i, g_head_i, gy_last = lax.cond(
                     is_last, head_branch, skip_branch, (head_p, y_b, lbl))
@@ -879,10 +866,10 @@ def pipeline_zbh1(stage_fn: Callable[[Any, Any], Any], stacked_params,
                 (gp_i,) = vjp_p(gy_w)
                 gp = jax.tree_util.tree_map(jnp.add, gp, gp_i)
                 return (h_arr, h_st, g_arr, g_st, gp, gh_, la, dxb,
-                        _varying(z), _varying(z))
+                        z, z)
 
             def idle_branch(c):
-                return c + (_varying(z), _varying(z))
+                return c + (z, z)
 
             (h_arr, h_st, g_arr, g_st, gparams, ghead, loss_acc, dx_buf,
              y_send, gh_send) = lax.switch(
@@ -897,18 +884,18 @@ def pipeline_zbh1(stage_fn: Callable[[Any, Any], Any], stacked_params,
                     dx_buf, h_in_next, g_in_next), None
 
         zeros_like_local = lambda tree: jax.tree_util.tree_map(
-            lambda a: _varying(jnp.zeros_like(a)), tree)
+            jnp.zeros_like, tree)
         init = (
-            _varying(jnp.zeros((n_harr,) + mb_shape, x.dtype)),
-            _varying(jnp.zeros((n_hst,) + mb_shape, x.dtype)),
-            _varying(jnp.zeros((n_garr,) + mb_shape, x.dtype)),
-            _varying(jnp.zeros((n_gst,) + mb_shape, x.dtype)),
+            jnp.zeros((n_harr,) + mb_shape, x.dtype),
+            jnp.zeros((n_hst,) + mb_shape, x.dtype),
+            jnp.zeros((n_garr,) + mb_shape, x.dtype),
+            jnp.zeros((n_gst,) + mb_shape, x.dtype),
             zeros_like_local(params_local),
             zeros_like_local(head_p),
-            _varying(jnp.zeros((), jnp.float32)),
-            _varying(jnp.zeros((m,) + mb_shape, x.dtype)),
-            _varying(z),
-            _varying(z),
+            jnp.zeros((), jnp.float32),
+            jnp.zeros((m,) + mb_shape, x.dtype),
+            z,
+            z,
         )
         (_, _, _, _, gparams, ghead, loss_acc, dx_buf, _, _), _ = lax.scan(
             tick, init, tab)
@@ -988,7 +975,6 @@ def pipeline_zbvpp(stage_fn: Callable[[Any, Any], Any], stacked_params,
         d = lax.axis_index("pp")
         is_first = d == 0
         is_last = d == npp - 1
-        head_p = jax.tree_util.tree_map(_varying, head_p)  # see 1f1b note
         mb_shape = x.shape[1:]
         z = jnp.zeros(mb_shape, x.dtype)
 
@@ -1028,7 +1014,7 @@ def pipeline_zbvpp(stage_fn: Callable[[Any, Any], Any], stacked_params,
                 h_x = lax.dynamic_index_in_dim(x, mb, 0, keepdims=False)
                 h_a = lax.dynamic_index_in_dim(h_arr, trow["f_rd"][d], 0,
                                                keepdims=False)
-                h = jnp.where(trow["f_from_x"][d] > 0, _varying(h_x), h_a)
+                h = jnp.where(trow["f_from_x"][d] > 0, h_x, h_a)
                 h_st = lax.dynamic_update_index_in_dim(
                     h_st, h, trow["f_st"][d], 0)
                 p_c = chunk_params(params_local, trow["f_c"][d])
@@ -1056,10 +1042,9 @@ def pipeline_zbvpp(stage_fn: Callable[[Any, Any], Any], stacked_params,
 
                 def skip_branch(op_):
                     hp, yy, _ = op_
-                    return (_varying(jnp.zeros((), jnp.float32)),
-                            jax.tree_util.tree_map(
-                                lambda a: _varying(jnp.zeros_like(a)), hp),
-                            _varying(jnp.zeros_like(yy)))
+                    return (jnp.zeros((), jnp.float32),
+                            jax.tree_util.tree_map(jnp.zeros_like, hp),
+                            jnp.zeros_like(yy))
 
                 loss_i, g_head_i, gy_head = lax.cond(
                     trow["b_is_head"][d] > 0, head_branch, skip_branch,
@@ -1089,10 +1074,10 @@ def pipeline_zbvpp(stage_fn: Callable[[Any, Any], Any], stacked_params,
                 (gp_i,) = vjp_p(gy_w)
                 gp = acc_chunk(gp, gp_i, trow["w_c"][d])
                 return (h_arr, h_st, g_arr, g_st, gp, gh_, la, dxb,
-                        _varying(z), _varying(z))
+                        z, z)
 
             def idle_branch(c):
-                return c + (_varying(z), _varying(z))
+                return c + (z, z)
 
             (h_arr, h_st, g_arr, g_st, gparams, ghead, loss_acc, dx_buf,
              y_send, gh_send) = lax.switch(
@@ -1107,18 +1092,18 @@ def pipeline_zbvpp(stage_fn: Callable[[Any, Any], Any], stacked_params,
                     dx_buf, h_in_next, g_in_next), None
 
         zeros_like_local = lambda tree: jax.tree_util.tree_map(
-            lambda a: _varying(jnp.zeros_like(a)), tree)
+            jnp.zeros_like, tree)
         init = (
-            _varying(jnp.zeros((n_harr,) + mb_shape, x.dtype)),
-            _varying(jnp.zeros((n_hst,) + mb_shape, x.dtype)),
-            _varying(jnp.zeros((n_garr,) + mb_shape, x.dtype)),
-            _varying(jnp.zeros((n_gst,) + mb_shape, x.dtype)),
+            jnp.zeros((n_harr,) + mb_shape, x.dtype),
+            jnp.zeros((n_hst,) + mb_shape, x.dtype),
+            jnp.zeros((n_garr,) + mb_shape, x.dtype),
+            jnp.zeros((n_gst,) + mb_shape, x.dtype),
             zeros_like_local(params_local),
             zeros_like_local(head_p),
-            _varying(jnp.zeros((), jnp.float32)),
-            _varying(jnp.zeros((m,) + mb_shape, x.dtype)),
-            _varying(z),
-            _varying(z),
+            jnp.zeros((), jnp.float32),
+            jnp.zeros((m,) + mb_shape, x.dtype),
+            z,
+            z,
         )
         (_, _, _, _, gparams, ghead, loss_acc, dx_buf, _, _), _ = lax.scan(
             tick, init, tab)

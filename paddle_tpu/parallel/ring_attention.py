@@ -62,14 +62,9 @@ def _ring_attention_local(q, k, v, axis: str, causal: bool, scale):
         v_nxt = lax.ppermute(v_cur, axis, perm)
         return (k_nxt, v_nxt, new_m, new_l, new_o), None
 
-    from paddle_tpu.parallel.pipeline import varying
-
-    def _varying(x):  # mark accumulators sp-varying
-        return varying(x, axis)
-
-    m0 = _varying(jnp.full((b, h, sq), -jnp.inf, jnp.float32))
-    l0 = _varying(jnp.zeros((b, h, sq), jnp.float32))
-    o0 = _varying(jnp.zeros((b, h, sq, d), jnp.float32))
+    m0 = jnp.full((b, h, sq), -jnp.inf, jnp.float32)
+    l0 = jnp.zeros((b, h, sq), jnp.float32)
+    o0 = jnp.zeros((b, h, sq, d), jnp.float32)
     (_, _, m, l, o), _ = lax.scan(step, (k, v, m0, l0, o0), jnp.arange(n))
     out = o / jnp.maximum(l[..., None], 1e-30)
     return jnp.swapaxes(out, 1, 2).astype(q.dtype)

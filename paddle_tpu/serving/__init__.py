@@ -173,8 +173,8 @@ from paddle_tpu.serving.detokenize import (  # noqa: F401
     StreamDetokenizer, TokenizerAdapter, complete_utf8_prefix,
 )
 from paddle_tpu.serving.engine import (  # noqa: F401
-    RequestOutput, ServingEngine, TokenEvent, create_engine, greedy_grid,
-    naive_generate, sample_token,
+    EngineConfig, RequestOutput, ServingEngine, TokenEvent, create_engine,
+    greedy_grid, naive_generate, sample_token,
 )
 from paddle_tpu.serving.kv_cache import (  # noqa: F401
     BlockAllocator, HostKVTier, KVCachePool, OffloadRecord, PrefixCache,
@@ -185,7 +185,8 @@ from paddle_tpu.serving.metrics import (  # noqa: F401
     Counter, EngineMetrics, Gauge, Histogram, aggregate_snapshots,
 )
 from paddle_tpu.serving.model_runner import (  # noqa: F401
-    GPTRunner, LlamaRunner, PagedModelRunner, bucket_len, runner_for,
+    GPTRunner, LlamaRunner, PagedModelRunner, bucket_len, build_runner,
+    runner_for,
 )
 from paddle_tpu.serving.journal import RouterJournal  # noqa: F401
 from paddle_tpu.serving.resilience import (  # noqa: F401
@@ -227,7 +228,8 @@ from paddle_tpu.parallel.compat import SpecLayout  # noqa: F401
 
 __all__ = [
     "AdaptiveK", "DraftModelProposer", "shadow_runner",
-    "BlockAllocator", "Counter", "EngineMetrics", "EngineReplica",
+    "BlockAllocator", "Counter", "EngineConfig", "EngineMetrics",
+    "EngineReplica",
     "FCFSScheduler", "FaultInjector", "GPTRunner", "Gauge", "Histogram",
     "HostKVTier", "InjectedDeviceError", "InvariantViolation",
     "KVCachePool", "LlamaRunner", "NgramProposer", "OffloadRecord",
@@ -241,7 +243,8 @@ __all__ = [
     "SharedKVStore", "SharedKVStoreClient", "StoreServer",
     "SpecLayout", "StreamDetokenizer", "Supervisor", "TokenEvent",
     "TokenizerAdapter", "audit_engine", "audit_router", "audit_store",
-    "aggregate_snapshots", "bucket_len", "complete_utf8_prefix",
+    "aggregate_snapshots", "bucket_len", "build_runner",
+    "complete_utf8_prefix",
     "create_engine", "greedy_grid", "naive_generate", "page_content_hash",
     "quantized_page_write", "replica_submeshes", "runner_for",
     "sample_token", "serving_mesh",

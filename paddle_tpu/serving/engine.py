@@ -3,90 +3,34 @@
 Reference: the serving loop the reference runs above
 block_multihead_attention (PaddleNLP llm predictor / fastdeploy): an
 admission queue feeds a fixed-slot decode batch; prefill computes a new
-request's context in CHUNKS bounded by a per-step token budget
-(`max_prefill_tokens_per_step`), interleaved with decode so a long
-prompt never stalls running requests for more than one budget per step;
-every step decodes one token for every decode-phase request in a single
-batched call through the paged-attention kernel; finished requests free
-their pages and their slot is refilled from the queue — the batch never
-drains to refill.
+request's context in CHUNKS bounded by a per-step token budget,
+interleaved with decode so a long prompt never stalls running requests
+for more than one budget per step; every step decodes for every
+decode-phase request in a single batched call through the paged-attention
+kernel; finished requests free their pages and their slot is refilled
+from the queue — the batch never drains to refill.
 
-With `enable_prefix_cache=True` (ISSUE 3) identical context prefixes
-stop being recomputed: full KV pages are refcounted and hash-indexed,
-admission maps the longest cached page-aligned prefix straight into the
-block table (prefix_hit_tokens metric), and any write that would touch a
-shared page forks it first (copy-on-write, cow_copies metric) — so
-shared few-shot headers, preemption recompute-on-resume, and
-crash-restore become mostly cache hits while staying token-exact.
-
-With `num_speculative_tokens > 0` (ISSUE 5) decode stops paying one
-engine step per token: a model-free n-gram prompt-lookup proposer drafts
-up to k continuation tokens from the request's own context, one fused
-`runner.ragged_step(full_logits=True)` launch scores all k+1 span
-positions against the paged pools, and the longest draft prefix the
-target model reproduces (argmax equality under greedy; the seeded step-
-indexed sample under temperature > 0) is accepted at once — rejected-
-tail KV rolls back through the refcount machinery (`SequenceKV.truncate`
-+ page decref) so a speculated page never leaks or corrupts the prefix
-cache. ISSUE 18 moves the verify spans INSIDE the device-resident scan
-whenever no prefill chunk shares the step (`runner.decode_multi_spec`:
-per-position accept/reject on device, bit-identical to the host loop,
-one packed drain per horizon), composing speculation with `pipelined`,
-`decode_horizon`, `horizon_sampling`, `horizon_early_stop`, and tp>1 —
-with a model-based draft rung (`spec_draft_model`, a quantized shadow
-or any small runner proposing whole chains) and per-request
-acceptance-adaptive draft lengths (`spec_adaptive_k`) beside the
-n-gram proposer. The per-step ragged path remains the fallback for
-chunk-sharing steps and batches outside the in-scan sampler envelope.
-
-With `decode_horizon=s > 1` (ISSUE 6) the engine stops paying a host
-round-trip per token: a pure-greedy decode batch runs s consecutive
-decode steps in ONE `runner.decode_multi` launch — a device-resident
-lax.scan that feeds each step's argmax token back as the next input —
-against block tables whose pages the scheduler pre-committed for the
-whole horizon, and the host drains a single packed [B, s] token buffer
-per horizon (`host_syncs` drops toward tokens/s) instead of blocking on
-every step's logits. The drained buffer replays token-by-token through
-the same stop/length/NaN bookkeeping, discarding overshoot past a stop
-and reclaiming its pages, so the token streams are the s=1 streams
-verbatim; batches the horizon can't serve (temperature > 0 without
-horizon_sampling, prefill chunks in flight) fall back to the per-step
-path — verify spans ride their own fused scan (ISSUE 18).
-
-With `host_tier_pages=N > 0` (ISSUE 10) preemption stops costing a
-re-prefill: victims spill their exclusively-owned KV pages to a pinned
-host-RAM tier (phase="offloaded") and prefix-cache evictions demote
-there too; resume and host-prefix hits restore by an async page-in —
-device_put issued a step AHEAD of the admission that maps the pages
-(queue-head prefetch at step end, `pagein_hidden_ratio`), scatter
-applied at the fence right after admission — with recompute as the
-fallback for every miss, so token streams are untouched by
-construction.
-
-With `pipelined=True` (ISSUE 11) the loop itself stops costing device
-time: step() plans step N+1 (deadline expiry, admission, chunk slicing,
-prefix matching, page-in staging — pure host work) while step N's
-decode/horizon launch is still executing on device, commits N's drained
-buffer through the standard replay, and only then dispatches N+1 —
-jax's async dispatch makes the whole thing a scheduling reorder with
-ONE launch in flight, counted by `planned_ahead_steps` and shown by
-the step's spans (`engine.plan` ahead of `engine.drain`) against the
-device trace. `horizon_sampling=True` widens horizons
-to temperature > 0 (per-request seeded key schedules inside the
-decode_multi scan, bit-identical to the per-step streams) and
-`horizon_early_stop=True` adds an on-device per-row done bit
-(stop-token/budget hit freezes the row's KV writes and marks the
-drained tail dead), so overshoot is neither computed nor replayed.
+One step is plan (deadlines, admission, prefix matching, page-in staging,
+page reservation) / build (the batch's operands) / launch (one runner
+call) / drain (the host waits for the device) / commit (tokens appended,
+stops and lengths handled, pages released). `EngineConfig` holds every
+option and documents it; the options choose WHICH launch a step makes —
+one decode step, a device-resident horizon of `decode_horizon` steps, a
+fused speculative horizon, or one ragged call over prefill chunks and
+decode spans together — and every kind runs through the same skeleton
+(`ServingEngine._launch`: build, retry, quarantine, defer or drain). With
+`pipelined` the launch of step N stays in flight while step N+1 is
+planned; jax's async dispatch makes that a reorder, with ONE launch in
+flight and pool updates kept functional.
 
 The engine is deterministic end-to-end: FCFS admission, sorted-free-list
 pages, greedy (or seeded per-request) sampling, step-indexed sample keys
 that survive preemption. `naive_generate` is the scheduling oracle: the
 same runner, one request at a time, no scheduler — continuous batching
-(speculation and multi-step horizons included) must reproduce its tokens
-exactly.
+under every option must reproduce its tokens exactly.
 
-Every failure mode has a defined outcome (ISSUE 2 hardening); no step()
-raises for load- or fault-induced conditions:
+Every failure mode has a defined outcome; no step() raises for load- or
+fault-induced conditions:
 
   finish_reason   trigger
   "stop"/"length" normal completion
@@ -115,7 +59,7 @@ from __future__ import annotations
 import logging
 import os
 import time
-from dataclasses import asdict, dataclass
+from dataclasses import asdict, dataclass, fields, replace
 from typing import Callable, Dict, List, Optional, Sequence
 
 import jax
@@ -129,7 +73,8 @@ from paddle_tpu.serving.kv_cache import (
 )
 from paddle_tpu.serving.metrics import EngineMetrics
 from paddle_tpu.serving.model_runner import (
-    PagedModelRunner, require_retryable, runner_for,
+    RUNNER_OPTIONS, PagedModelRunner, bucket_len, build_runner,
+    require_retryable,
 )
 from paddle_tpu.serving.resilience import QueueFullError, audit_engine
 from paddle_tpu.serving.scheduler import (
@@ -164,13 +109,204 @@ class RequestOutput:
     e2e_s: Optional[float] = None
 
 
+@dataclass(frozen=True)
+class EngineConfig:
+    """Every serialisable option of a ServingEngine: one field an option.
+    `ServingEngine(runner, **options)` builds it, the engine carries each
+    field as an attribute of the same name, `snapshot()["config"]` is this
+    record as a dict (plus the runner's `recipe()` and the mesh's shape)
+    and `restore()` builds it back, a missing key taking its default.
+    Every option but `num_blocks` defaults to the plain loop: one decode
+    step a launch, one host sync a step, nothing shared or offloaded.
+    None of them changes a token: what the options change is which
+    launches a step makes and when the host waits.
+
+    The pool
+      num_blocks           pages in the device pool (page 0 is scratch)
+      block_size           tokens a page; None = the runner's (they share
+                           the pool layout, so another value is an error)
+      max_batch_size       decode slots
+      max_model_len        longest prompt + generation; None = the
+                           runner's rope / position table length
+    Load and faults
+      max_queue_depth      bound on the waiting queue; None = unbounded
+      shed_policy          at the bound: "reject" (add_request raises
+                           QueueFullError) or "drop_oldest" (the oldest
+                           waiting request is shed)
+      admission_watermark  pool fraction beyond which admission pauses
+      max_step_retries     transient-failure retries of one runner call
+      retry_backoff_s      first back-off; doubles with every retry
+      nan_policy           "abort" ends a request on NaN/Inf logits;
+                           "greedy" argmaxes the finite entries instead
+    Prefill
+      max_prefill_tokens_per_step
+                           per-step prefill token budget: a long prompt is
+                           computed in chunks of at most this many tokens,
+                           interleaved with decode (None = one chunk)
+      enable_prefix_cache  full KV pages are refcounted and hash-indexed;
+                           admission maps the longest cached page-aligned
+                           prefix into the block table and any write to a
+                           shared page forks it first (copy-on-write)
+      ragged_batch         a step that has both prefill chunks and decode
+                           rows makes ONE `runner.ragged_step` call for all
+                           of them (a request completing its prefill in it
+                           decodes its first token the step after)
+    The decode loop
+      decode_horizon       s > 1: a decode batch with no chunk in flight
+                           runs up to s steps in ONE `runner.decode_multi`
+                           launch (a device-resident scan feeding each
+                           token back) and the host drains one [B, s]
+                           buffer; the scheduler pre-commits the horizon's
+                           pages (trims s, never preempts); overshoot past
+                           a stop is discarded and its pages reclaimed
+      horizon_sampling     horizons also for temperature > 0: per-request
+                           seeded key schedules ride inside the scan,
+                           bit-identical to the per-step streams (a batch
+                           mixing (top_k, top_p) pairs stays per-step)
+      horizon_early_stop   each horizon row carries its stop tokens and
+                           remaining budget into the scan; a hit freezes
+                           the row's KV writes and marks its tail dead, so
+                           overshoot is neither computed nor replayed
+      pipelined            a step plans while the PREVIOUS step's launch
+                           still runs on the device, commits that launch,
+                           then dispatches its own and leaves it in
+                           flight; a step returns the previous launch's
+                           tokens, run() / flush() drain the tail
+    Speculation
+      num_speculative_tokens
+                           k > 0: up to k draft tokens ride each decode
+                           row into one verify launch; the longest draft
+                           prefix the target model reproduces (argmax
+                           under greedy, the request's seeded sample under
+                           temperature > 0) is accepted at once and the
+                           rejected tail's KV rolls back through the
+                           refcounts. Inside the scan when no chunk shares
+                           the step (`runner.decode_multi_spec`), else one
+                           full-logits ragged call
+      spec_max_ngram / spec_min_ngram
+                           suffix n-gram lengths the prompt-lookup
+                           proposer matches (longest first, latest wins)
+      spec_ngram_window    scan only the last N context tokens (None = all)
+      spec_adaptive_k      an EWMA of accepted / proposed clamps each
+                           request's k into [0, num_speculative_tokens]
+      spec_draft_model     None = n-gram prompt lookup; "shadow[:int8|
+                           int4|fp8|fp32]" = a weight-quantized shadow of
+                           the target runner proposing greedy chains from
+                           a small pool of its own; a runner instance
+                           (ServingEngine's argument, recorded here as
+                           "custom": a snapshot cannot rebuild it)
+      spec_draft_blocks    pages of the draft model's pool (None = its
+                           default)
+    The host tier
+      host_tier_pages      N > 0: preemption spills a victim's own pages
+                           to N pages of pinned host RAM and prefix-cache
+                           eviction demotes there; resume restores by a
+                           page-in staged a step ahead (`device_put`
+                           issued at the end of the step before, scatter
+                           at the fence after admission); a miss
+                           recomputes. Host pages are not in a snapshot
+      host_tier_headroom   the admission watermark counts free host slots
+                           as near-headroom
+      pagein_prefetch      how many queue-head offloaded requests get
+                           their pages staged at the end of a step (0 =
+                           stage at the fence itself)
+      spill_async          the device->host copy of a spill runs on a
+                           worker thread against the immutable pool
+                           snapshot; every reader of the bytes joins it
+      role                 "mixed" | "prefill" (prefill, sample the first
+                           token, then stage the request and its pages in
+                           the handoff buffer for a sibling) | "decode" (as
+                           "mixed"; the router sends it handoffs, and it
+                           still prefills for the recompute fallback)
+    """
+
+    num_blocks: int
+    block_size: Optional[int] = None
+    max_batch_size: int = 8
+    max_model_len: Optional[int] = None
+    max_queue_depth: Optional[int] = None
+    shed_policy: str = "reject"
+    admission_watermark: float = 1.0
+    max_step_retries: int = 2
+    retry_backoff_s: float = 0.02
+    nan_policy: str = "abort"
+    max_prefill_tokens_per_step: Optional[int] = None
+    enable_prefix_cache: bool = False
+    host_tier_pages: int = 0
+    host_tier_headroom: bool = False
+    pagein_prefetch: int = 2
+    ragged_batch: bool = False
+    decode_horizon: int = 1
+    pipelined: bool = False
+    horizon_sampling: bool = False
+    horizon_early_stop: bool = False
+    spill_async: bool = False
+    role: str = "mixed"
+    num_speculative_tokens: int = 0
+    spec_max_ngram: int = 3
+    spec_min_ngram: int = 1
+    spec_adaptive_k: bool = False
+    spec_draft_model: Optional[str] = None
+    spec_draft_blocks: Optional[int] = None
+    spec_ngram_window: Optional[int] = None
+
+    def __post_init__(self):
+        put = lambda name, v: object.__setattr__(self, name, v)
+        # flags and counts arrive as whatever the caller had (a numpy
+        # int, 0 for False); the record holds plain ones, so that two
+        # records compare and one serialises
+        plain = {"bool": bool, "int": int}
+        for f in fields(self):
+            if f.type in plain:
+                put(f.name, plain[f.type](getattr(self, f.name)))
+        for name in ("spec_ngram_window", "spec_draft_blocks"):
+            put(name, int(getattr(self, name) or 0) or None)
+        if self.shed_policy not in ("reject", "drop_oldest"):
+            raise ValueError(f"shed_policy={self.shed_policy!r}; expected "
+                             "'reject' or 'drop_oldest'")
+        if self.nan_policy not in ("abort", "greedy"):
+            raise ValueError(f"nan_policy={self.nan_policy!r}; expected "
+                             "'abort' or 'greedy'")
+        if self.max_queue_depth is not None and self.max_queue_depth < 1:
+            raise ValueError("max_queue_depth must be >= 1 (None = unbounded)")
+        if self.host_tier_pages < 0:
+            raise ValueError("host_tier_pages must be >= 0 (0 = no host "
+                             "tier)")
+        if self.pagein_prefetch < 0:
+            raise ValueError("pagein_prefetch must be >= 0")
+        if self.decode_horizon < 1:
+            raise ValueError("decode_horizon must be >= 1 (1 = sync with "
+                             "the host every step)")
+        if self.role not in ("mixed", "prefill", "decode"):
+            raise ValueError(f"role={self.role!r}; expected 'mixed', "
+                             "'prefill', or 'decode'")
+        if self.num_speculative_tokens < 0:
+            raise ValueError("num_speculative_tokens must be >= 0 (0 = "
+                             "speculation off)")
+
+    def for_runner(self, recipe: dict) -> "EngineConfig":
+        """This record with the two fields a runner decides filled in
+        from its `recipe()`."""
+        block_size = self.block_size or recipe["block_size"]
+        if block_size != recipe["block_size"]:
+            raise ValueError(
+                f"engine block_size={block_size} != runner.block_size="
+                f"{recipe['block_size']} — they share the pool layout")
+        max_model_len = self.max_model_len or recipe["max_model_len"]
+        if max_model_len > recipe["max_model_len"]:
+            raise ValueError("max_model_len exceeds the runner's rope/pos "
+                             f"table length {recipe['max_model_len']}")
+        return replace(self, block_size=block_size,
+                       max_model_len=max_model_len)
+
+
 def seeded_sample(logits_row, seed: int, step: int, temperature: float,
                   top_k, top_p) -> int:
     """THE host-side seeded sampler (temperature > 0): one [V] row drawn
     with fold_in(key(seed), step). The in-scan horizon sampler
-    (model_runner._sampled_rows, ISSUE 11) and the test stubs reproduce
-    exactly this math, which is what makes temperature>0 horizons
-    bit-identical to the per-step streams."""
+    (model_runner._sampled_rows) and the test stubs reproduce exactly
+    this math, which is what makes temperature>0 horizons bit-identical
+    to the per-step streams."""
     from paddle_tpu.models.generation import _sample
 
     key = jax.random.fold_in(jax.random.key(int(seed)), int(step))
@@ -197,22 +333,21 @@ def _to_host(x) -> np.ndarray:
     performs funnels through here (greedy_grid's packed pull, the lazy
     full-logits row fetch, the multi-step horizon drain), so a test can
     monkeypatch this one symbol and count exactly how many times a step
-    blocked on the device (the ISSUE 6 one-sync-per-step pin)."""
+    blocked on the device (the one-sync-per-step pin)."""
     return np.asarray(x)
 
 
 def greedy_grid(logits):
-    """Vectorized device-side greedy pass (ISSUE 5 satellite): ONE argmax
-    and ONE finiteness reduction over a [..., V] logits array, computed
-    where the logits live, then ONE tiny host transfer — the argmax ids
-    and finite flags ride a single packed int32 array (ISSUE 6
-    satellite: this used to be two separate np.asarray pulls, i.e. two
-    blocking syncs per decode step). The full array only crosses to
-    host afterwards when a row actually needs it — temperature > 0
-    sampling, or a NaN rescue under nan_policy="greedy". Tie-breaking
-    matches np.argmax (first max wins), which the batched-sampling pin
-    test asserts against the host path `sample_token` /
-    `naive_generate` use."""
+    """Vectorized device-side greedy pass: ONE argmax and ONE finiteness
+    reduction over a [..., V] logits array, computed where the logits
+    live, then ONE tiny host transfer — the argmax ids and finite flags
+    ride a single packed int32 array (two separate pulls would be two
+    blocking syncs a decode step). The full array only crosses to host
+    afterwards when a row actually needs it — temperature > 0 sampling,
+    or a NaN rescue under nan_policy="greedy". Tie-breaking matches
+    np.argmax (first max wins), which the batched-sampling pin test
+    asserts against the host path `sample_token` / `naive_generate`
+    use."""
     packed = _to_host(jnp.stack(
         [jnp.argmax(logits, axis=-1).astype(jnp.int32),
          jnp.all(jnp.isfinite(logits), axis=-1).astype(jnp.int32)]))
@@ -220,31 +355,68 @@ def greedy_grid(logits):
 
 
 @dataclass
-class _InflightLaunch:
-    """One dispatched-but-undrained device launch (the pipelined loop's
-    unit of deferred work, ISSUE 11). `batch` pins (request, slot) pairs
-    as of launch time — a member aborted/expired before the commit is
-    skipped at replay; `prev_pools` is the functional pool snapshot the
-    launch consumed, kept so a drain-time device error can roll back and
-    rerun the step through the normal retry path."""
+class _LaunchKind:
+    """One kind of device launch, as the data `ServingEngine._launch` runs
+    it from; what the kinds share is written there, once.
 
-    kind: str        # "decode" | "decode_multi" | "decode_spec" | "ragged"
-    batch: list                  # [(Request, slot), ...] at launch
-    result: object               # logits [B, V] or packed [2|3, B, s]
-    prev_pools: list             # pool snapshot for drain-failure rollback
-    s: int = 1                   # horizon length (decode_multi)
-    # fused ragged launches (ISSUE 12 satellite) carry their span list
-    # — (req, start, end, prop, slot) per chunk/decode span as of
-    # launch time — so the commit can replay chunk-coverage advances
-    # and completing-chunk samples exactly like the sync path
-    spans: Optional[list] = None
-    # fused speculative horizons (ISSUE 18) carry the launch's draft
-    # grid ([B, s, K] -1-padded) for the commit-time accept replay, and
-    # a per-request {id(req): funded_upcoming_tokens} map so the
-    # auditor's over-provision check credits exactly the pages
-    # plan_spec_horizon committed (s alone under-counts a k>0 row)
-    spec: Optional[dict] = None
+    A ROW is `(request, slot, start, end, fed, draft)`: the row's batch
+    slot as of launch, the position of its first fed token, one past the
+    last position it may WRITE before the next drain (what copy-on-write
+    must make private: 1 for a decode step, the horizon's span, a chunk's
+    length), the token(s) fed, and, for the rows of a ragged step, the
+    draft riding a decode span (`[]` = none) or None for a prefill
+    chunk."""
+
+    name: str
+    # () -> rows, from live scheduler state: rebuilt on every attempt,
+    # because page reservation may have preempted and a quarantine removed
+    rows: Callable[[], list]
+    # (operands, extra) -> (result, new_pools): the runner entry, looked
+    # up on the runner at call time
+    call: Callable
+    drain: Callable              # result -> host arrays: the blocking pull
+    # (_InflightLaunch, drained or None) -> events; None = not drained yet
+    commit: Callable
+    # rows -> what `call` takes besides the batch operands (built under
+    # the same `engine.build_batch` span)
+    extras: Optional[Callable] = None
+    ragged: bool = False         # tokens [B, T] with q_lens, not [B]
+    s: int = 1                   # scan steps before the next drain
+    launched: Optional[Callable[[], None]] = None   # the kind's counters
+    # fused speculative horizons: {id(request): tokens its pages were
+    # funded for}, so that the auditor's over-provision check credits
+    # exactly what plan_spec_horizon committed (`s` alone under-counts a
+    # row with drafts)
     upcoming: Optional[dict] = None
+
+
+@dataclass
+class _InflightLaunch:
+    """One dispatched launch: what its commit reads, and what the
+    pipelined loop keeps while it is in flight. `batch` pins the rows as
+    of launch time — a member aborted or expired before the commit is
+    skipped at replay; `prev_pools` is the functional pool snapshot the
+    launch consumed, kept so that a drain-time device error can roll back
+    and rerun the step through the normal retry path."""
+
+    kind: _LaunchKind
+    batch: list
+    result: object               # logits [B, V] / [B, T, V], or packed
+    prev_pools: list
+    extra: object = None         # what `kind.extras` built for the call
+
+    @property
+    def s(self) -> int:
+        return self.kind.s
+
+    @property
+    def upcoming(self) -> Optional[dict]:
+        return self.kind.upcoming
+
+
+def _youngest(rows) -> Request:
+    """The quarantine victim of a batch: the latest admission."""
+    return max((row[0] for row in rows), key=lambda r: r.admission_index)
 
 
 class ServingEngine:
@@ -256,351 +428,82 @@ class ServingEngine:
     for events in iter(engine.step, []): ...   # streaming
     outputs = engine.run()                     # or drain to completion
 
-    Robustness knobs (all optional; defaults reproduce the happy path):
-      max_queue_depth      bound on the waiting queue; None = unbounded
-      shed_policy          "reject" (add_request raises QueueFullError) or
-                           "drop_oldest" (oldest waiting request is shed)
-      admission_watermark  pool fraction beyond which admission pauses
-      max_step_retries     transient-failure retries per runner step
-      retry_backoff_s      base of the bounded exponential backoff
-      nan_policy           "abort" kills a request on NaN/Inf logits;
-                           "greedy" argmaxes the finite entries instead
-      audit                run resilience.audit_engine after every step
-                           (None = the PADDLE_TPU_SERVING_AUDIT env var)
-      max_prefill_tokens_per_step
-                           per-step prefill token budget: long prompts
-                           are computed in chunks of at most this many
-                           tokens, interleaved with decode (None = whole
-                           context in one chunk, the pre-ISSUE-3 shape)
-      enable_prefix_cache  refcounted shared-prefix KV page cache with
-                           copy-on-write (off by default: sharing changes
-                           page-assignment traces, never tokens)
-      ragged_batch         collapse each step's prefill chunks AND its
-                           batched decode into ONE mixed ragged runner
-                           call (runner.ragged_step over the ragged
-                           paged-attention kernel) whenever a step has
-                           both; off by default — fusing changes the
-                           call trace (fault schedules, jit keys), never
-                           tokens (ISSUE 4)
-      num_speculative_tokens
-                           speculative decoding (ISSUE 5): up to this
-                           many n-gram prompt-lookup draft tokens ride
-                           each decode request's span into one fused
-                           verify launch (runner.ragged_step scoring all
-                           k+1 positions); the longest draft prefix the
-                           target model agrees with is accepted in one
-                           engine step, rejected-tail KV is rolled back
-                           through the refcount machinery. 0 = off.
-                           Token streams stay EXACTLY naive_generate's:
-                           greedy acceptance is argmax equality, and
-                           temperature > 0 compares the draft against
-                           the request's seeded step-indexed sample.
-      host_tier_pages      tiered KV offload (ISSUE 10): capacity (in
-                           pages) of a pinned host-RAM tier under the
-                           device pool. Preemption then SPILLS the
-                           victim's exclusively-owned pages to host
-                           (phase="offloaded") instead of dropping
-                           them, and prefix-cache LRU eviction demotes
-                           cached pages to host; resume and host-prefix
-                           hits restore by an async page-in — the
-                           engine issues jax.device_put for the needed
-                           pages AHEAD of the step that reads them
-                           (prefetched while the previous step's
-                           compute runs) and only applies the scatter
-                           at fence time, so restore-after-preempt is
-                           O(bytes) copied instead of O(prefill)
-                           recomputed. Misses and tier-cap overflow
-                           fall back to the recompute path: token
-                           streams are untouched by construction
-                           (fp32 bit-exact; int8 restores the exact
-                           codes+scales, which recompute could not).
-                           0 = off (the pre-ISSUE-10 engine).
-      host_tier_headroom   knob-gated watermark credit (ISSUE 10): the
-                           admission watermark counts free host-tier
-                           slots as near-headroom, so the pool runs
-                           hotter — overflow degrades to a cheap
-                           spill/page-in instead of a recompute —
-                           raising sustainable concurrent sessions.
-      pagein_prefetch      how many queue-head offloaded requests get
-                           their host pages staged (device_put issued)
-                           at the END of each step, one step before
-                           the fence that will read them — the double
-                           buffer that makes the copy overlap decode
-                           (pagein_hidden_ratio measures it). 0
-                           disables prefetch (page-ins then stage at
-                           the fence itself).
-      decode_horizon       multi-step decode (ISSUE 6): sync with the
-                           host every `s` steps instead of every step.
-                           A pure-greedy decode batch (no prefill
-                           chunks in flight, speculation off, every
-                           request temperature == 0) runs up to `s`
-                           consecutive decode steps in ONE
-                           runner.decode_multi launch — the sampling
-                           loop stays device-resident, each argmax
-                           token fed back on device — and the host
-                           drains a single [B, s] buffer per horizon
-                           (host_syncs metric) instead of one transfer
-                           per token. The scheduler pre-commits every
-                           page the horizon will write
-                           (plan_decode_horizon: trims s, never
-                           preempts). Token streams are EXACTLY the
-                           s=1 streams: the drained buffer replays
-                           token-by-token through the same stop/
-                           length/NaN handling, and overshoot tokens
-                           past a stop are discarded with their pages
-                           reclaimed (horizon_overshoot_tokens).
-                           Default 1 = today's per-step loop, bit-
-                           exact. Batches that can't ride a horizon
-                           (temperature > 0 with horizon_sampling off,
-                           verify spans, chunks in flight) fall back to
-                           the per-step path.
-      pipelined            zero-bubble engine loop (ISSUE 11 tentpole):
-                           step() splits into a PLAN phase (deadline
-                           expiry, admission, chunk slicing, prefix
-                           matching, page-in staging — pure host work,
-                           run against a scheduler snapshot while the
-                           PREVIOUS step's decode launch is still
-                           executing on device) and a COMMIT phase
-                           (drain + replay of that in-flight launch),
-                           after which this step's decode/horizon
-                           launch is dispatched and left in flight.
-                           jax's async dispatch makes this a
-                           scheduling reorder, not a threading change:
-                           one launch is in flight at a time, pool
-                           updates stay functional (dataflow orders
-                           every later write after the launch), and
-                           the drained buffer replays through exactly
-                           the per-step bookkeeping — token streams
-                           are the unpipelined streams verbatim, only
-                           the streaming surface shifts one step (a
-                           step returns the PREVIOUS launch's tokens;
-                           run()/has_work() drain the tail). Off by
-                           default: pipelining changes step timing and
-                           the events-per-step trace, never tokens.
-      horizon_sampling     widen decode horizons to temperature > 0
-                           (ISSUE 11): per-request seeded key
-                           schedules ride INSIDE the decode_multi scan
-                           (fold_in(key(seed), generated-token index)
-                           — the naive_generate keys), so a sampled
-                           batch runs device-resident horizons
-                           bit-identically to the per-step seeded
-                           streams. Batches whose sampled rows mix
-                           (top_k, top_p) configs still take the
-                           per-step path (those are static per jit
-                           entry). Off by default.
-      horizon_early_stop   on-device stop flag (ISSUE 11): each
-                           horizon row carries its stop-token set and
-                           remaining-token budget into the scan; a hit
-                           sets a per-row done bit that freezes the
-                           row's KV writes (masked to scratch) and
-                           marks every later drained token dead, so
-                           overshoot past a stop is neither computed
-                           into the pools nor replayed
-                           (horizon_overshoot_tokens -> ~0), and the
-                           scheduler funds only min(s, remaining)
-                           pages per row. Off by default.
-      role                 disaggregated-serving role (ISSUE 12):
-                           "mixed" (default — the engine both prefills
-                           and decodes), "prefill" (the engine runs
-                           admission + chunked prefill, samples each
-                           request's FIRST token, then STAGES the
-                           request for handoff: its KV pages spill to
-                           the HostKVTier (content-hashed, scale rows
-                           included) and the request waits in the
-                           handoff buffer until extract_handoff() ships
-                           it — raw page bytes over the wire — to a
-                           sibling, which import_handoff()s the pages
-                           into its own tier and continues decoding via
-                           the normal offload page-in path, token-exact
-                           including int8 codes because pages are
-                           COPIED, never recomputed), or "decode" (a
-                           routing designation: the engine behaves like
-                           "mixed" — it must still prefill for the
-                           recompute fallback — but the router sends it
-                           handoffs instead of fresh prompts). A
-                           prefill engine without a host tier (or with
-                           a full one) still hands off, pages-less: the
-                           decode side recomputes
-                           (handoff_recompute_fallbacks), exactness
-                           untouched.
-      kv_store             cluster-wide KV (ISSUE 14): a SharedKVStore
-                           (or process-backend SharedKVStoreClient)
-                           backing the host tier instead of private
-                           buffers. Capacity is the store's; spills
-                           and prefix demotions PUBLISH tier-wide
-                           (content-addressed, dedup by chain hash);
-                           admission resolves its prefix chain against
-                           every replica's demotions; handoffs move
-                           slot references instead of page bytes.
-                           `kv_store_owner` tags this engine
-                           incarnation's refs so a dead replica's
-                           slots are reaped by refcount. Usually wired
-                           by ServingRouter(shared_kv_pages=...); None
-                           = the PR-10 private tier via
-                           host_tier_pages.
-      spill_async          threaded spill I/O (ISSUE 11 satellite):
-                           preemption's device->host page copy runs on
-                           a worker thread against the immutable
-                           functional pool snapshot instead of
-                           blocking the engine loop on one np.asarray
-                           per spilled page; every consumer of the
-                           spilled bytes joins the copy first. Off by
-                           default.
-      spec_max_ngram /     suffix n-gram lengths the draft proposer
-      spec_min_ngram       matches (longest first, most recent wins)
-      spec_ngram_window    bound the stateless n-gram scan to the last
-                           N context tokens (ISSUE 18); None =
-                           unbounded (the per-request incremental
-                           suffix index makes the engine's own calls
-                           O(1) amortized either way)
-      spec_adaptive_k      acceptance-rate-adaptive per-request draft
-                           length (ISSUE 18): an EWMA over
-                           accepted/proposed clamps each request's k
-                           into [0, num_speculative_tokens], so a
-                           low-acceptance stream stops paying dead
-                           verify positions
-      spec_draft_model     model-based draft rung (ISSUE 18): None =
-                           n-gram prompt lookup; "shadow[:int8|fp32]"
-                           = a quantized shadow of the target runner
-                           proposing whole greedy chains from its own
-                           small paged pool (spec_draft_blocks caps
-                           it); or a runner instance (same tokenizer).
-                           Drafts never affect token streams — only
-                           the acceptance rate
-      tokenizer            optional tokenizer (id_to_bytes(tok) or
-                           decode([tok])) enabling stream_text():
-                           incremental detokenization that buffers
-                           until a byte-complete UTF-8 boundary
+    `options` are the fields of `EngineConfig`, which documents them; an
+    unknown name is a TypeError. What a snapshot cannot hold stays a
+    plain argument:
+      metrics          an EngineMetrics to count into (None = a new one)
+      tokenizer        id_to_bytes(tok) or decode([tok]); enables
+                       stream_text()
+      sleep_fn         the retry back-off's sleep (tests pass a recorder)
+      audit            run resilience.audit_engine after every step
+                       (None = the PADDLE_TPU_SERVING_AUDIT variable)
+      kv_store         a SharedKVStore (or the process backend's
+                       SharedKVStoreClient) behind the host tier in place
+                       of private buffers: capacity is the store's, spills
+                       and prefix demotions publish tier-wide
+                       (content-addressed), admission resolves its prefix
+                       chain against every replica's demotions, handoffs
+                       move slot references instead of page bytes.
+                       Usually wired by ServingRouter(shared_kv_pages=)
+      kv_store_owner   tags this engine incarnation's references, so that
+                       a dead replica's slots are reaped by refcount
+      spec_draft_model the EngineConfig field, or a runner instance (same
+                       tokenizer) to draft with
 
-    Tensor parallelism (ISSUE 7) is a RUNNER property, not an engine
-    knob: pass a sharded runner (`runner.shard(mesh)`, or
-    `create_engine(model, mesh=...)`) and the engine builds its K/V
-    pools kv-head-sharded over the runner's mesh. Everything host-side
-    — scheduler, block tables, refcounts, prefix cache, retries,
-    snapshots — is mesh-blind, and token streams are identical to the
-    single-device engine.
+    Tensor parallelism and quantization are RUNNER properties, not engine
+    options: pass a sharded runner (`runner.shard(mesh)`, or
+    `create_engine(model, mesh=...)`) and the engine builds its K/V pools
+    kv-head-sharded over the runner's mesh, in the runner's `kv_dtype`.
+    Everything host-side — scheduler, block tables, refcounts, prefix
+    cache, retries, snapshots — is mesh-blind, and token streams are
+    identical to the single-device engine.
     """
 
-    def __init__(self, runner: PagedModelRunner, *, num_blocks: int,
-                 block_size: Optional[int] = None, max_batch_size: int = 8,
-                 max_model_len: Optional[int] = None,
+    def __init__(self, runner: PagedModelRunner, *,
                  metrics: Optional[EngineMetrics] = None,
-                 max_queue_depth: Optional[int] = None,
-                 shed_policy: str = "reject",
-                 admission_watermark: float = 1.0,
-                 max_step_retries: int = 2,
-                 retry_backoff_s: float = 0.02,
-                 nan_policy: str = "abort",
-                 max_prefill_tokens_per_step: Optional[int] = None,
-                 enable_prefix_cache: bool = False,
-                 host_tier_pages: int = 0,
-                 host_tier_headroom: bool = False,
-                 pagein_prefetch: int = 2,
-                 ragged_batch: bool = False,
-                 decode_horizon: int = 1,
-                 pipelined: bool = False,
-                 horizon_sampling: bool = False,
-                 horizon_early_stop: bool = False,
-                 spill_async: bool = False,
-                 role: str = "mixed",
-                 kv_store=None,
-                 kv_store_owner: Optional[str] = None,
-                 num_speculative_tokens: int = 0,
-                 spec_max_ngram: int = 3,
-                 spec_min_ngram: int = 1,
-                 spec_adaptive_k: bool = False,
-                 spec_draft_model=None,
-                 spec_draft_blocks: Optional[int] = None,
-                 spec_ngram_window: Optional[int] = None,
                  tokenizer=None,
                  sleep_fn: Optional[Callable[[float], None]] = None,
-                 audit: Optional[bool] = None):
+                 audit: Optional[bool] = None,
+                 kv_store=None,
+                 kv_store_owner: Optional[str] = None,
+                 spec_draft_model=None,
+                 **options):
         self.runner = runner
-        block_size = block_size or runner.block_size
-        if block_size != runner.block_size:
-            raise ValueError(
-                f"engine block_size={block_size} != runner.block_size="
-                f"{runner.block_size} — they share the pool layout")
-        self.max_model_len = max_model_len or runner.max_model_len
-        if self.max_model_len > runner.max_model_len:
-            raise ValueError("max_model_len exceeds the runner's rope/pos "
-                             f"table length {runner.max_model_len}")
-        if shed_policy not in ("reject", "drop_oldest"):
-            raise ValueError(f"shed_policy={shed_policy!r}; expected "
-                             "'reject' or 'drop_oldest'")
-        if nan_policy not in ("abort", "greedy"):
-            raise ValueError(f"nan_policy={nan_policy!r}; expected "
-                             "'abort' or 'greedy'")
-        if max_queue_depth is not None and max_queue_depth < 1:
-            raise ValueError("max_queue_depth must be >= 1 (None = unbounded)")
-        # a sharded runner (runner.shard(mesh), ISSUE 7) brings its mesh
-        # along: the K/V pools are then born split on the kv-head axis
-        # over the model axis — everything host-side (allocator, block
-        # tables, scheduler, PrefixCache) stays replicated and mesh-blind
+        recipe = runner.recipe()
+        self.config = EngineConfig(
+            spec_draft_model=(spec_draft_model
+                              if isinstance(spec_draft_model, str)
+                              else None if spec_draft_model is None
+                              else "custom"),
+            **options).for_runner(recipe)
+        # every option under its own name: eng.decode_horizon, eng.role, ...
+        vars(self).update(vars(self.config))
+        # a sharded runner (runner.shard(mesh)) brings its mesh along: the
+        # K/V pools are then born split on the kv-head axis over the
+        # model axis — everything host-side (allocator, block tables,
+        # scheduler, PrefixCache) stays replicated and mesh-blind
         self.mesh = getattr(runner, "mesh", None)
-        # quantized serving (ISSUE 9) is a RUNNER property like the mesh:
-        # a kv_dtype="int8" runner quantizes at append time, so the
-        # engine births int8 code pools + the parallel scale pools
-        self.kv_dtype = getattr(runner, "kv_dtype", "fp32")
+        # quantized serving is a RUNNER property like the mesh: a
+        # kv_dtype="int8" runner quantizes at append time, so the engine
+        # births int8 code pools + the parallel scale pools
+        self.kv_dtype = recipe["kv_dtype"]
         self.pool = KVCachePool.for_runner(
-            runner, num_blocks, mesh=self.mesh,
+            runner, self.num_blocks, mesh=self.mesh,
             model_axis=getattr(runner, "model_axis", "model"))
-        self.enable_prefix_cache = bool(enable_prefix_cache)
         if self.enable_prefix_cache:
             self.pool.enable_prefix_cache()
-        if host_tier_pages < 0:
-            raise ValueError("host_tier_pages must be >= 0 (0 = no host "
-                             "tier)")
-        if pagein_prefetch < 0:
-            raise ValueError("pagein_prefetch must be >= 0")
-        self.host_tier_pages = int(host_tier_pages)
-        self.host_tier_headroom = bool(host_tier_headroom)
-        self.pagein_prefetch = int(pagein_prefetch)
-        self.max_prefill_tokens_per_step = max_prefill_tokens_per_step
-        self.ragged_batch = bool(ragged_batch)
-        if decode_horizon < 1:
-            raise ValueError("decode_horizon must be >= 1 (1 = sync with "
-                             "the host every step)")
-        self.decode_horizon = int(decode_horizon)
-        self.pipelined = bool(pipelined)
-        self.horizon_sampling = bool(horizon_sampling)
-        self.horizon_early_stop = bool(horizon_early_stop)
-        self.spill_async = bool(spill_async)
-        if role not in ("mixed", "prefill", "decode"):
-            raise ValueError(f"role={role!r}; expected 'mixed', "
-                             "'prefill', or 'decode'")
-        self.role = role
-        # handoff buffer (ISSUE 12): requests a prefill-role engine has
-        # finished prefilling (first token sampled), staged for
-        # migration — request id -> OffloadRecord of its spilled pages
-        # (None = pages could not ride; the receiver recomputes). The
-        # requests stay in self._requests until extract_handoff()
+        # handoff buffer: requests a prefill-role engine has finished
+        # prefilling (first token sampled), staged for migration —
+        # request id -> OffloadRecord of its spilled pages (None = pages
+        # could not ride; the receiver recomputes). The requests stay in
+        # self._requests until extract_handoff()
         self._handoffs: Dict[str, Optional["OffloadRecord"]] = {}
-        # the pipelined loop's single in-flight launch (ISSUE 11):
-        # dispatched at the end of one step, drained + replayed at the
-        # next step's commit phase (or by flush())
+        # the pipelined loop's single in-flight launch: dispatched at the
+        # end of one step, drained + replayed at the next step's commit
+        # phase (or by flush())
         self._inflight: Optional[_InflightLaunch] = None
-        if num_speculative_tokens < 0:
-            raise ValueError("num_speculative_tokens must be >= 0 (0 = "
-                             "speculation off)")
-        self.num_speculative_tokens = int(num_speculative_tokens)
-        self.spec_max_ngram = int(spec_max_ngram)
-        self.spec_min_ngram = int(spec_min_ngram)
-        self.spec_adaptive_k = bool(spec_adaptive_k)
-        self.spec_ngram_window = (int(spec_ngram_window)
-                                  if spec_ngram_window else None)
-        self.spec_draft_blocks = (int(spec_draft_blocks)
-                                  if spec_draft_blocks else None)
-        # draft rung spec (ISSUE 18/19): None = n-gram prompt lookup; a
-        # "shadow[:int8|int4|fp8|fp32]" string builds a weight-quantized
-        # shadow of the target runner; a runner instance is used
-        # directly (recorded as "custom" — a snapshot cannot rebuild it)
-        self.spec_draft_model = (spec_draft_model
-                                 if isinstance(spec_draft_model, str)
-                                 else None if spec_draft_model is None
-                                 else "custom")
-        # the proposer validates the n-gram range; built lazily-but-eager
-        # here so a bad knob combination fails at construction time
+        # the proposer validates the n-gram range; built here so that a
+        # bad combination of options fails at construction time
         self.proposer = None
         if self.num_speculative_tokens:
             if spec_draft_model is not None:
@@ -621,7 +524,6 @@ class ServingEngine:
                 self.proposer = NgramProposer(
                     self.spec_max_ngram, self.spec_min_ngram,
                     scan_window=self.spec_ngram_window)
-        # acceptance-rate-adaptive per-request draft length (ISSUE 18)
         self.adaptive_k = (AdaptiveK(self.num_speculative_tokens)
                           if self.num_speculative_tokens
                           and self.spec_adaptive_k else None)
@@ -629,19 +531,12 @@ class ServingEngine:
         self._detoks: Dict[str, StreamDetokenizer] = {}
         self.max_pages_per_seq = self.pool.blocks_for_tokens(
             self.max_model_len)
-        self.scheduler = FCFSScheduler(self.pool, max_batch_size,
+        self.scheduler = FCFSScheduler(self.pool, self.max_batch_size,
                                        self.max_pages_per_seq,
-                                       admission_watermark,
-                                       max_prefill_tokens_per_step,
+                                       self.admission_watermark,
+                                       self.max_prefill_tokens_per_step,
                                        count_host_headroom=(
                                            self.host_tier_headroom))
-        self.max_batch_size = max_batch_size
-        self.max_queue_depth = max_queue_depth
-        self.shed_policy = shed_policy
-        self.admission_watermark = admission_watermark
-        self.max_step_retries = max_step_retries
-        self.retry_backoff_s = retry_backoff_s
-        self.nan_policy = nan_policy
         self._sleep = sleep_fn or time.sleep
         if audit is None:
             audit = os.environ.get("PADDLE_TPU_SERVING_AUDIT",
@@ -652,25 +547,23 @@ class ServingEngine:
         # `runner.COUNTS`): each launch hands its counts over here, and
         # the step's one drain reads them with its tokens
         self._step_counts: list = []
-        # static per-pool ratios (ISSUE 9 satellite): the measured page-
-        # byte reduction (scale bytes counted) and the matching sessions-
-        # per-fixed-HBM factor — 1.0 on fp32 pools
+        # static per-pool ratios: the measured page-byte reduction (scale
+        # bytes counted) and the matching sessions-per-fixed-HBM factor —
+        # 1.0 on fp32 pools
         self.metrics.kv_bytes_reduction_x.set(
             self.pool.kv_bytes_reduction_x())
         self.metrics.sessions_per_pool_x.set(
             self.pool.kv_bytes_reduction_x())
-        # weight-ladder HBM ratio (ISSUE 19): logical fp32 bytes over
-        # resident bytes (packed codes + group scales counted) — 1.0 on
-        # fp32 runners or runners without the accessor
+        # weight-ladder HBM ratio: logical fp32 bytes over resident bytes
+        # (packed codes + group scales counted) — 1.0 on fp32 runners or
+        # runners without the accessor
         wbx = getattr(runner, "weight_bytes_reduction_x", None)
         if callable(wbx):
             self.metrics.weight_bytes_reduction_x.set(float(wbx()))
-        # host-RAM KV tier (ISSUE 10): built after the metrics so the
-        # tier mirrors its spill/drop accounting straight into them.
-        # With `kv_store` (ISSUE 14) the tier is a facade over the
-        # host-wide SharedKVStore instead of private buffers: capacity
-        # is the store's, spills publish tier-wide under this engine's
-        # owner tag, and handoffs move slot references instead of bytes
+        # host-RAM KV tier: built after the metrics so the tier mirrors
+        # its spill/drop accounting straight into them. With `kv_store`
+        # the tier is a facade over the host-wide SharedKVStore instead
+        # of private buffers
         self.kv_store = kv_store
         self.kv_store_owner = (str(kv_store_owner) if kv_store_owner
                                else f"eng-{id(self):x}")
@@ -692,11 +585,16 @@ class ServingEngine:
         self._step_count = 0
         self._requests: Dict[str, Request] = {}
         self._outputs: Dict[str, RequestOutput] = {}
+        # the default path's launch has no state of its own: described once
+        self._decode_kind = _LaunchKind(
+            "decode", self._decode_rows,
+            lambda ops, _: self.runner.decode(*ops, self.pool.pools),
+            greedy_grid, self._commit_logits)
 
     # ----------------------------------------------------------- intake
 
     def _check_kv_dtype(self, sampling: SamplingParams) -> None:
-        """Per-request KV precision gate (ISSUE 15): a homogeneous pool
+        """Per-request KV precision gate: a homogeneous pool
         only serves its own rung; "mixed" pools serve fp32 AND fp8
         tenants side by side (pages tagged at alloc). Loud at intake —
         a silently widened/narrowed tenant would break the byte
@@ -762,9 +660,10 @@ class ServingEngine:
         # still needs its commit step even after the queue drains
         return self.scheduler.has_work() or self._inflight is not None
 
-    def _timed_drain(self, fn):
+    def _drain(self, fn):
         """Run one blocking device->host drain under an `engine.drain`
-        span: the host waiting for the device."""
+        span — the host waiting for the device — and count it as a host
+        sync once it came back."""
         with _prof.span("engine.drain"):
             out = fn()
             if self._step_counts:
@@ -774,7 +673,8 @@ class ServingEngine:
                 for name, n in zip(self.runner.COUNTS,
                                    np.sum(jax.device_get(counts), axis=0)):
                     getattr(self.metrics, name).inc(float(n))
-            return out
+        self.metrics.host_syncs.inc()
+        return out
 
     # ------------------------------------------------- failure plumbing
 
@@ -785,7 +685,7 @@ class ServingEngine:
         the partial generation, bump the matching failure counter."""
         now = self.metrics.clock()
         if req.request_id in self._handoffs:
-            # staged for handoff (ISSUE 12): not in the waiting queue —
+            # staged for handoff: not in the waiting queue —
             # release the spilled host slots and finish in place
             rec = self._handoffs.pop(req.request_id)
             if rec is not None and self.pool.host_tier is not None:
@@ -830,10 +730,10 @@ class ServingEngine:
     def _resolve_token(self, req: Request, step: int, greedy_tok, finite,
                        row_fn: Callable[[], np.ndarray]) -> Optional[int]:
         """NaN/Inf-guarded token for ONE logits row, fed from a
-        `greedy_grid` pass over the whole batch (ISSUE 5 satellite: the
-        greedy/finite-guard path is vectorized device-side; `row_fn`
-        lazily fetches the actual [V] row only for temperature > 0
-        sampling or a NaN rescue). Returns None when the request must be
+        `greedy_grid` pass over the whole batch (the greedy/finite-guard
+        path is vectorized device-side; `row_fn` lazily fetches the
+        actual [V] row only for temperature > 0 sampling or a NaN
+        rescue). Returns None when the request must be
         aborted (nan_policy="abort", or no finite logit exists). The
         seeded temperature path is untouched — per-request step-indexed
         streams stay bit-identical."""
@@ -854,14 +754,13 @@ class ServingEngine:
                         step: Optional[int] = None) -> Optional[int]:
         """Single-row spelling of the guarded sampler (the completing-
         chunk call site): same greedy_grid pass, scalar-shaped."""
-        am, fin = self._timed_drain(lambda: greedy_grid(logits_row))
-        self.metrics.host_syncs.inc()
+        am, fin = self._drain(lambda: greedy_grid(logits_row))
         if step is None:
             step = len(req.output_tokens)
         return self._resolve_token(req, step, am, fin,
                                    lambda: np.asarray(logits_row))
 
-    # ----------------------------------------- async page-in (ISSUE 10)
+    # --------------------------------------------------- async page-in
 
     def _stage_slot(self, tier, slot):
         """Issue the host->device transfer for one host-tier slot: one
@@ -973,8 +872,9 @@ class ServingEngine:
         """PLAN phase of a step, under the caller's `engine.plan` span
         (pure host work; with `pipelined` this runs while the PREVIOUS
         step's launch is still executing on device — jax's async
-        dispatch means nothing here blocks on it). Returns (admitted,
-        prefill plan)."""
+        dispatch means nothing here blocks on it; `planned_ahead_steps`
+        counts those steps, and the spans show `engine.plan` ahead of
+        `engine.drain`). Returns (admitted, prefill plan)."""
         # 0. deadlines first: an expired request must not win admission
         self._expire_deadlines()
 
@@ -996,7 +896,7 @@ class ServingEngine:
                                  request_id=req.request_id)
                 req.queued_ns = None
         if not self.pipelined:
-            # 1b. page-in fence (ISSUE 10): every host-resident page an
+            # 1b. page-in fence: every host-resident page an
             #     admission mapped must be IN the pools before anything
             #     this step computes reads it — prefetched transfers
             #     resolve here (their copy overlapped the previous
@@ -1027,13 +927,13 @@ class ServingEngine:
         # are unchanged). Otherwise: chunks oldest-first under the token
         # budget, then page reservation, then one batched decode.
         #
-        # num_speculative_tokens > 0 (ISSUE 5) reroutes the decode half
-        # through verify spans: each decode request feeds its last token
-        # PLUS an n-gram draft (q_len = 1+k) into one full-logits ragged
-        # launch, accepting the longest draft prefix the target model
-        # reproduces — several tokens per engine step when drafts hit.
-        # Chunks fuse into the same launch under ragged_batch, otherwise
-        # they keep the sequential chunk-then-decode sequencing.
+        # num_speculative_tokens > 0 reroutes the decode half through
+        # verify spans: each decode request feeds its last token PLUS a
+        # draft (q_len = 1+k), accepting the longest draft prefix the
+        # target model reproduces — several tokens per engine step when
+        # drafts hit. Chunks fuse into the same launch under
+        # ragged_batch, otherwise they keep the sequential
+        # chunk-then-decode sequencing.
         if self._inflight is not None:
             # the whole planning interval above ran under an in-flight
             # launch — host time the device no longer waits for (the
@@ -1059,7 +959,7 @@ class ServingEngine:
                 plan = self.scheduler.prefill_plan()
 
         if self.role == "prefill":
-            # disaggregated serving (ISSUE 12): every request that
+            # disaggregated serving: every request that
             # finished its prefill (phase flipped to decode, first
             # token sampled) leaves the running set here — pages
             # spilled to the host tier, request parked in the handoff
@@ -1075,12 +975,11 @@ class ServingEngine:
         if self.num_speculative_tokens > 0 and self.scheduler.decode_ready():
             chunk_tokens = sum(end - start for _, start, end in plan)
             if not plan and self._spec_horizon_ready():
-                # fused verify-in-scan (ISSUE 18): drafts ride the
-                # device-resident horizon — accept/reject on device,
-                # ONE drain per horizon, defers like any horizon
+                # fused verify-in-scan: drafts ride the device-resident
+                # horizon — accept/reject on device, ONE drain per
+                # horizon, defers like any horizon
                 self._reserve_decode()
-                events.extend(self._decode_spec_with_recovery(
-                    defer=self.pipelined))
+                events.extend(self._launch_spec_horizon(self.pipelined))
             else:
                 # per-step verify fallback: prefill chunks this step
                 # (they fuse into the ragged launch under ragged_batch)
@@ -1093,14 +992,13 @@ class ServingEngine:
                             events.append(ev)
                 self._reserve_decode()
                 proposals = self._plan_speculation(chunk_tokens)
-                events.extend(self._ragged_step_with_recovery(
+                events.extend(self._launch_ragged(
                     proposals, include_chunks=fused))
         elif fused:
             self._reserve_decode()
-            # pipelined + ragged_batch compose (ISSUE 12 satellite):
-            # the fused launch defers exactly like a decode launch
-            events.extend(self._ragged_step_with_recovery(
-                defer=self.pipelined))
+            # pipelined + ragged_batch compose: the fused launch defers
+            # exactly like a decode launch
+            events.extend(self._launch_ragged(defer=self.pipelined))
         else:
             for req, start, end in plan:
                 ev = self._prefill_chunk_with_recovery(req, start, end)
@@ -1108,17 +1006,13 @@ class ServingEngine:
                     events.append(ev)
             self._reserve_decode()
             # one batched decode step over every decode-phase sequence —
-            # or, when the batch qualifies (ISSUE 6: decode_horizon > 1,
-            # pure greedy, no chunks in flight), one device-resident
-            # multi-step horizon that drains s tokens per host sync
+            # or, when the batch qualifies (decode_horizon > 1, no chunks
+            # in flight, sampling inside the envelope), one device-
+            # resident multi-step horizon draining s tokens per host sync
             if self.scheduler.running:
-                s = self._plan_horizon(chunks_in_flight=bool(plan))
-                if s > 1:
-                    events.extend(self._decode_multi_with_recovery(
-                        s, defer=self.pipelined))
-                else:
-                    events.extend(self._decode_with_recovery(
-                        defer=self.pipelined))
+                events.extend(self._launch_decode(
+                    self._plan_horizon(chunks_in_flight=bool(plan)),
+                    self.pipelined))
         self.metrics.decode_steps.inc()
 
         # bookkeeping gauges
@@ -1129,7 +1023,7 @@ class ServingEngine:
                 self.runner.attn_kv_bytes_gather)
         comm = getattr(self.runner, "tp_comm_bytes", None)
         if comm is not None:
-            # quantized-collective accounting (ISSUE 15): wire bytes
+            # quantized-collective accounting: wire bytes
             # the row-parallel allreduces moved per shard (scale bytes
             # counted) vs the fp32 cost of the same calls — mirrored
             # from the runner's host-side counters like the attention
@@ -1141,7 +1035,7 @@ class ServingEngine:
                 self.runner.tp_comm_bytes_fp32 / comm if comm else 0.0)
         gather = getattr(self.runner, "tp_gather_bytes", None)
         if gather is not None:
-            # the gather direction (ISSUE 19): wire bytes the column-
+            # the gather direction: wire bytes the column-
             # parallel all-gathers (lm_head logits) moved per shard,
             # scale bytes counted, vs the fp32 cost of the same calls
             self.metrics.tp_gather_bytes.set(gather)
@@ -1168,15 +1062,153 @@ class ServingEngine:
         if self.audit:
             audit_engine(self)
         return events
+    # ---------------------------------------------- the launch skeleton
+
+    def _call_retrying(self, build, call, victim):
+        """THE failure policy of a runner call. `build()` gives what
+        `call` takes, or None once nothing is left to compute. A failure
+        is first checked retryable (an UnrecoverableStepError leaves
+        step()), then retried `max_step_retries` times with a doubling
+        back-off; after that `victim(built)` is quarantined
+        (finish_reason="error") and the call is rebuilt without it. The
+        loop is bounded: each quarantine shrinks the batch, so at worst
+        the batch drains and the step yields no tokens — never an
+        exception. Returns (built, result, new_pools), or None.
+
+        A retried call is exact, not approximate: a failed attempt either
+        never reached the device (injected/raised before compute) or
+        re-writes the same K/V values through the same block tables
+        (copy-on-write forks happen before the call and are idempotent),
+        and `self.pool.pools` is only assigned by the caller after a
+        success — a failed attempt never half-commits."""
+        attempts, delay = 0, self.retry_backoff_s
+        while True:
+            built = build()
+            if built is None:
+                return None
+            try:
+                return (built, *call(built))
+            except Exception as e:
+                require_retryable(e, self.pool.pools)
+                if attempts < self.max_step_retries:
+                    attempts += 1
+                    self.metrics.step_retries.inc()
+                    self._sleep(delay)
+                    delay *= 2
+                    continue
+                self._finish_abnormal(victim(built), "error")
+                attempts, delay = 0, self.retry_backoff_s
+
+    def _build_batch(self, rows, ragged: bool = False) -> tuple:
+        """The operands every launch kind shares, in the order the
+        runner's entries take them: (tokens[B], tables[B, P], pos[B]), or
+        for a ragged step (tokens[B, T], tables, starts[B], q_lens[B]).
+        A slot no row sits in carries an all-scratch table and
+        self-neutralizes (a request mid-way through its chunked prefill
+        has no token to feed a decode step yet)."""
+        B, P = self.max_batch_size, self.max_pages_per_seq
+        tables = np.full((B, P), SCRATCH_PAGE, np.int32)
+        pos = np.zeros((B,), np.int32)
+        if ragged:
+            tokens = np.zeros(
+                (B, bucket_len(max(len(row[4]) for row in rows))), np.int32)
+            q_lens = np.zeros((B,), np.int32)
+        else:
+            tokens = np.zeros((B,), np.int32)
+        for req, sl, start, end, fed, _ in rows:
+            # no write may land on a shared page, so every page the row
+            # may write before the next drain is private BEFORE launch
+            # (idempotent: a forked page is already private on a retry)
+            cow = req.kv.ensure_writable(start, end)
+            if cow:
+                self.metrics.cow_copies.inc(cow)
+            if ragged:
+                tokens[sl, :len(fed)] = fed
+                q_lens[sl] = len(fed)
+            else:
+                tokens[sl] = fed
+            row = req.kv.pages_array()
+            tables[sl, :len(row)] = row
+            pos[sl] = start                  # position of the first fed token
+        return (tokens, tables, pos, q_lens) if ragged else (tokens, tables,
+                                                             pos)
+
+    def _launch(self, kind: _LaunchKind, defer: bool = False
+                ) -> List[TokenEvent]:
+        """One device launch of any kind: build the batch from live
+        scheduler state, call the runner under `_call_retrying` (the
+        youngest row is the victim), assign the pools, then either leave
+        the launch IN FLIGHT (`defer`, the pipelined loop: the next
+        step's commit phase, or flush(), drains and replays it) or drain
+        and commit it now."""
+        def build():
+            rows = kind.rows()
+            if not rows:
+                return None
+            with _prof.span("engine.build_batch"):
+                ops = self._build_batch(rows, kind.ragged)
+                extra = kind.extras(rows) if kind.extras else None
+            return rows, ops, extra
+
+        out = self._call_retrying(build, lambda b: kind.call(b[1], b[2]),
+                                  lambda b: _youngest(b[0]))
+        if out is None:
+            return []
+        (rows, _, extra), result, new_pools = out
+        prev, self.pool.pools = self.pool.pools, new_pools
+        self.metrics.batch_occupancy.observe(len(rows))
+        if kind.launched is not None:
+            kind.launched()
+        launch = _InflightLaunch(kind, rows, result, prev, extra)
+        if defer:
+            self._inflight = launch
+            return []
+        return kind.commit(launch, None)
+
+    def _commit_inflight(self) -> List[TokenEvent]:
+        """COMMIT phase of the pipelined loop: drain the in-flight launch
+        and replay it through the standard per-step bookkeeping. The plan
+        phase that just ran (admission, chunk slicing, page-in staging)
+        overlapped this launch's device time — that ordering IS the
+        optimization. A drain-time device error rolls the pools back to
+        the pre-launch snapshot (no pool write has happened since the
+        launch: the fence deliberately runs after this commit) and
+        reruns the launch synchronously through the normal retry /
+        quarantine path from live state — nothing the launch computed was
+        committed (chunk coverage advances at commit, drafts are
+        deterministic given the unchanged context), so the rerun writes
+        identical K/V through the same block tables and streams stay
+        exact."""
+        inf, self._inflight = self._inflight, None
+        if inf is None:
+            return []
+        try:
+            drained = self._drain(lambda: inf.kind.drain(inf.result))
+        except Exception as e:
+            require_retryable(e, inf.prev_pools)
+            self.metrics.step_retries.inc()
+            self._sleep(self.retry_backoff_s)
+            self.pool.pools = inf.prev_pools
+            return self._launch(inf.kind)
+        return inf.kind.commit(inf, drained)
+
+    def flush(self) -> List[TokenEvent]:
+        """Fence the pipeline: commit any in-flight launch and return its
+        events. No-op on an unpipelined engine (or with nothing in
+        flight). Router workers call this on a graceful stop so
+        committed-but-undelivered tokens reach the delivery registry;
+        tests and tools use it before inspecting engine state mid-run."""
+        return self._commit_inflight()
+
+    # ------------------------------------------------------ prefill chunk
 
     def _prefill_chunk_with_recovery(self, req: Request, start: int,
                                      end: int) -> Optional[TokenEvent]:
         """Compute context positions [start, end) of one request's
-        (re-)prefill, retrying transient runner failures with bounded
-        exponential backoff; a request whose chunk keeps failing is
-        quarantined (finish_reason="error"). The chunk that completes the
-        context (end == num_context) samples the request's next token and
-        flips it into the decode phase."""
+        (re-)prefill under `_call_retrying`, with the request itself as
+        the victim. The chunk that completes the context (end ==
+        num_context) samples the request's next token and flips it into
+        the decode phase."""
         with _prof.span("request.prefill", request_id=req.request_id,
                         start=start, end=end):
             return self._prefill_chunk(req, start, end)
@@ -1188,22 +1220,14 @@ class ServingEngine:
             if cow:
                 self.metrics.cow_copies.inc(cow)
             table = self.pool.pad_table(req.kv.pages, self.max_pages_per_seq)
-            chunk = req.context_tokens[start:end]
-        delay = self.retry_backoff_s
-        for attempt in range(self.max_step_retries + 1):
-            try:
-                logits, new_pools = self.runner.prefill_chunk(
-                    chunk, start, table, self.pool.pools)
-                break
-            except Exception as e:
-                require_retryable(e, self.pool.pools)
-                if attempt >= self.max_step_retries:
-                    self._finish_abnormal(req, "error")
-                    return None
-                self.metrics.step_retries.inc()
-                self._sleep(delay)
-                delay *= 2
-        self.pool.pools = new_pools
+            ops = (req.context_tokens[start:end], start, table)
+        out = self._call_retrying(
+            lambda: None if req.done else ops,
+            lambda ops: self.runner.prefill_chunk(*ops, self.pool.pools),
+            lambda _: req)
+        if out is None:
+            return None
+        _, logits, self.pool.pools = out
         with _prof.span("engine.commit"):
             req.kv.num_tokens = end
             self.metrics.prefill_tokens.inc(end - start)
@@ -1219,6 +1243,50 @@ class ServingEngine:
                 return None
             req.phase = "decode"
             return self._append_token(req, tok)
+
+    # ------------------------------- decode steps and ragged steps: logits
+
+    def _decode_rows(self) -> list:
+        """One decode step's rows: only decode-phase requests join the
+        batch, each feeding its last token at its last position."""
+        return [(r, r.slot, r.num_context - 1, r.num_context,
+                 r.output_tokens[-1], ())
+                for r in self.scheduler.decode_ready()]
+
+    def _launch_decode(self, s: int, defer: bool = False
+                       ) -> List[TokenEvent]:
+        """One batched decode step over every decode-phase sequence (`s`
+        == 1, through `runner.decode`), or one device-resident horizon:
+        the batch's next `s` decode steps in ONE `runner.decode_multi`
+        launch — a lax.scan that feeds each step's token back as the
+        next input — of which the host drains ONE packed buffer
+        (host_syncs += 1, not += s) and replays it through the per-step
+        bookkeeping (`_replay_horizon`)."""
+        if s <= 1:
+            return self._launch(self._decode_kind, defer)
+        early = self.horizon_early_stop
+
+        def rows():
+            # early-stop rows freeze their writes past their own
+            # remaining budget, so only that span needs forking
+            return [(r, r.slot, r.num_context - 1, r.num_context - 1
+                     + (min(s, self._row_remaining(r)) if early else s),
+                     r.output_tokens[-1], ())
+                    for r in self.scheduler.decode_ready()]
+
+        def extras(rows):
+            ctx = self._horizon_ctx(rows, stops=early)
+            if early:
+                ctx["early_stop"] = True
+            return ctx
+
+        return self._launch(_LaunchKind(
+            "decode_multi", rows,
+            lambda ops, ctx: self.runner.decode_multi(
+                *ops, self.pool.pools, s, **ctx),
+            _to_host, self._replay_horizon, extras=extras, s=s,
+            launched=lambda: self.metrics.decode_horizon_steps.inc(s)),
+            defer)
 
     def _release_spec_state(self, req: Request) -> None:
         """Drop per-request proposer/adaptive-k state on ANY terminal
@@ -1236,14 +1304,14 @@ class ServingEngine:
 
     def _plan_speculation(self, chunk_tokens: int) -> Dict[Request,
                                                            List[int]]:
-        """n-gram draft proposals for this step's decode batch (ISSUE 5),
-        capped in admission order by (a) the request's own remaining-
-        token headroom (at most max_tokens - generated - 1 drafts: the
-        bonus/corrected token always fits) and model-length headroom,
-        (b) the scheduler's leftover per-step token budget — verify
-        spans count against max_prefill_tokens_per_step exactly like
-        prefill chunks — and (c) best-effort page reservation: under
-        pool pressure a proposal shrinks instead of preempting anyone."""
+        """Draft proposals for this step's decode batch, capped in
+        admission order by (a) the request's own remaining-token headroom
+        (at most max_tokens - generated - 1 drafts: the bonus/corrected
+        token always fits) and model-length headroom, (b) the scheduler's
+        leftover per-step token budget — verify spans count against
+        max_prefill_tokens_per_step exactly like prefill chunks — and (c)
+        best-effort page reservation: under pool pressure a proposal
+        shrinks instead of preempting anyone."""
         budget = self.scheduler.speculation_budget(chunk_tokens)
         proposals: Dict[Request, List[int]] = {}
         for req in self.scheduler.decode_ready():      # admission order
@@ -1266,195 +1334,116 @@ class ServingEngine:
         self.scheduler.reserve_speculation(proposals)
         return proposals
 
-    def _ragged_step_with_recovery(
+    def _launch_ragged(
             self, proposals: Optional[Dict[Request, List[int]]] = None,
             include_chunks: bool = True,
             defer: bool = False) -> List[TokenEvent]:
         """ONE mixed ragged runner call for this step: every planned
         prefill chunk and every decode-phase request rides its batch
         slot as a (start, q_len) span into runner.ragged_step, which the
-        ragged paged-attention kernel serves in a single launch (ISSUE
-        4). With `proposals` (speculative decoding, ISSUE 5) each decode
+        ragged paged-attention kernel serves in a single launch. With
+        `proposals` (speculation's per-step fallback: chunks in flight,
+        or a batch outside the in-scan sampler's envelope) each decode
         span stretches to q_len = 1 + k — the fed last token plus its
-        n-gram draft — and the call asks the runner for FULL per-position
+        draft — and the call asks the runner for FULL per-position
         logits so `_accept_verify` can score every draft position off
-        the single launch. Transient failures retry the whole call with
-        backoff (exact: a failed attempt either never reached the device
-        or re-writes identical K/V through the same block tables — COW
-        forks happen before the call and are idempotent on retry); once
-        retries are exhausted the YOUNGEST spanning request is
-        quarantined and the batch is rebuilt, so the loop is bounded
-        exactly like the sequential decode path.
-
-        With `defer` (pipelined + ragged_batch composing, ISSUE 12
-        satellite) the fused launch is dispatched and left IN FLIGHT
-        exactly like a deferred decode: the next step's commit phase
-        (or flush()) drains it and replays the span bookkeeping through
-        _finish_ragged — chunk coverage advances, completing-chunk
-        samples, fused decode appends — and the next step's prefill
-        plan is re-sliced AFTER that commit, so no chunk is ever
-        computed twice. Verify spans (proposals) never defer HERE: this
-        is speculation's per-step fallback (chunks in flight, or a
-        batch outside the in-scan sampler's envelope) — the fused path
-        that does defer is _decode_spec_with_recovery (ISSUE 18)."""
-        from paddle_tpu.serving.model_runner import bucket_len
-
+        the single launch; such a call never defers. A deferred fused
+        launch is replayed by the next step's commit, and the next
+        step's prefill plan is re-sliced AFTER that commit, so no chunk
+        is ever computed twice."""
         full = proposals is not None
-        attempts = 0
-        delay = self.retry_backoff_s
-        while True:
-            # rebuild from live scheduler state each attempt: page
-            # reservation may have preempted, quarantine may have removed
-            spans = []
+
+        def rows():
+            # the slot is captured at launch time: the commit of a
+            # deferred launch must index the drained logits by the slots
+            # the launch actually used
+            out = []
             if include_chunks:
-                # slot captured at launch time: the commit of a
-                # deferred launch must index the drained logits by the
-                # slots the launch actually used
-                spans += [(req, start, end, None, req.slot)
-                          for req, start, end
-                          in self.scheduler.prefill_plan()]
+                out += [(req, req.slot, start, end,
+                         req.context_tokens[start:end], None)
+                        for req, start, end in self.scheduler.prefill_plan()]
             for req in self.scheduler.decode_ready():
                 prop = proposals.get(req, []) if full else []
-                spans.append((req, req.num_context - 1,
-                              req.num_context + len(prop), prop,
-                              req.slot))
-            if not spans:
-                return []
-            build = _prof.span("engine.build_batch").begin()
-            B = self.max_batch_size
-            P = self.max_pages_per_seq
-            T = bucket_len(max(end - start
-                               for _, start, end, _, _ in spans))
-            tokens = np.zeros((B, T), np.int32)
-            starts = np.zeros((B,), np.int32)
-            qlens = np.zeros((B,), np.int32)
-            tables = np.full((B, P), SCRATCH_PAGE, np.int32)
-            for req, start, end, prop, s in spans:
-                # no write may land on a shared page (idempotent: a
-                # forked page is already private when the call retries)
-                cow = req.kv.ensure_writable(start, end)
-                if cow:
-                    self.metrics.cow_copies.inc(cow)
-                span_toks = (req.context_tokens[start:end] if prop is None
-                             else req.output_tokens[-1:] + list(prop))
-                tokens[s, :end - start] = span_toks
-                starts[s] = start
-                qlens[s] = end - start
-                row = req.kv.pages_array()
-                tables[s, :len(row)] = row
-            build.end()
-            prev = self.pool.pools
-            try:
-                if full:
-                    logits, new_pools = self.runner.ragged_step(
-                        tokens, tables, starts, qlens, self.pool.pools,
-                        full_logits=True)
-                else:
-                    logits, new_pools = self.runner.ragged_step(
-                        tokens, tables, starts, qlens, self.pool.pools)
-                break
-            except Exception as e:
-                require_retryable(e, self.pool.pools)
-                if attempts < self.max_step_retries:
-                    attempts += 1
-                    self.metrics.step_retries.inc()
-                    self._sleep(delay)
-                    delay *= 2
-                    continue
-                victim = max((r for r, *_ in spans),
-                             key=lambda r: r.admission_index)
-                self._finish_abnormal(victim, "error")
-                attempts = 0
-                delay = self.retry_backoff_s
-        self.pool.pools = new_pools
-        self.metrics.batch_occupancy.observe(len(spans))
-        if defer and not full:
-            # pipelined fused step (ISSUE 12 satellite): leave the
-            # launch in flight; the next step's commit (or flush())
-            # drains and replays the span bookkeeping
-            self._inflight = _InflightLaunch(
-                "ragged", [(r, sl) for r, _, _, _, sl in spans],
-                logits, prev, 1, spans=spans)
-            return []
-        return self._finish_ragged(spans, logits, full)
+                out.append((req, req.slot, req.num_context - 1,
+                            req.num_context + len(prop),
+                            req.output_tokens[-1:] + list(prop), prop))
+            return out
 
-    def _finish_ragged(self, spans, logits, full: bool = False,
+        kw = {"full_logits": True} if full else {}
+        return self._launch(_LaunchKind(
+            "ragged", rows,
+            lambda ops, _: self.runner.ragged_step(
+                *ops, self.pool.pools, **kw),
+            greedy_grid, self._commit_logits, ragged=True), defer)
+
+    def _commit_logits(self, launch: _InflightLaunch,
                        grid=None) -> List[TokenEvent]:
-        """Resolve one drained fused ragged launch: the per-span
-        bookkeeping half of _ragged_step_with_recovery — chunk
-        coverage advances + prefix registration, completing-chunk and
-        fused-decode sampling, verify-span acceptance. Shared by the
+        """Resolve one launch that returned LOGITS — a decode step's or
+        a ragged step's [B, V], or [B, T, V] where verify spans asked
+        for every position: one vectorized greedy/finite pass for the
+        whole batch (the array itself only reaches the host for
+        temperature > 0 or NaN-rescue rows), then per row the
+        bookkeeping of what it was: a prefill chunk's coverage advance
+        and, if it completed the context, its first sample; a decode
+        span's append; a verify span's acceptance. Shared by the
         synchronous path and the pipelined commit (which passes the
-        already-drained grid); a span member that finished while the
-        launch was in flight (pipelined abort/deadline) is skipped —
-        its drained logits are discarded, never half-committed."""
+        already-drained grid); a row whose request finished while the
+        launch was in flight (pipelined abort/deadline) is skipped — its
+        drained logits are discarded, never half-committed."""
         with _prof.span("engine.commit"):
-            return self._commit_ragged(spans, logits, full, grid)
+            logits = launch.result
+            if grid is None:
+                grid = self._drain(lambda: greedy_grid(logits))
+            am, fin = grid
+            full = am.ndim == 2                       # [B, T]: every position
+            host: Dict[str, np.ndarray] = {}
 
-    def _commit_ragged(self, spans, logits, full, grid
-                       ) -> List[TokenEvent]:
-        # vectorized greedy/finite pass over the whole call's logits
-        # ([B, V] or [B, T, V]); rows transfer lazily only when needed
-        if grid is None:
-            grid = self._timed_drain(lambda: greedy_grid(logits))
-            self.metrics.host_syncs.inc()
-        am, fin = grid
-        host: Dict[str, np.ndarray] = {}
+            def _rows() -> np.ndarray:
+                if "l" not in host:
+                    host["l"] = self._drain(lambda: _to_host(logits))
+                return host["l"]
 
-        def _rows() -> np.ndarray:
-            if "l" not in host:
-                host["l"] = self._timed_drain(lambda: _to_host(logits))
-                self.metrics.host_syncs.inc()
-            return host["l"]
-
-        events: List[TokenEvent] = []
-        for req, start, end, prop, s in spans:
-            if req.done:
-                continue
-            if prop is None:                    # prefill chunk span
-                req.kv.num_tokens = end
-                self.metrics.prefill_tokens.inc(end - start)
-                self.metrics.prefill_chunks.inc()
+            events: List[TokenEvent] = []
+            for req, s, start, end, _, prop in launch.batch:
+                if req.done:
+                    continue
+                if full and prop is not None:       # verify span
+                    self._accept_verify(
+                        req, prop, am[s], fin[s],
+                        lambda i, s=s: _rows()[s, i], events)
+                    continue
+                if prop is None:                    # prefill chunk span
+                    req.kv.num_tokens = end
+                    self.metrics.prefill_tokens.inc(end - start)
+                    self.metrics.prefill_chunks.inc()
+                else:                               # plain decode span
+                    req.kv.num_tokens = req.num_context
                 if self.pool.prefix_cache is not None:
                     self.pool.prefix_cache.register_seq(req.kv,
                                                         req.context_tokens)
-                if end == req.num_context:      # completing chunk
-                    r = end - start - 1
-                    if full:
-                        tok = self._resolve_token(
-                            req, len(req.output_tokens), am[s, r],
-                            fin[s, r], lambda s=s, r=r: _rows()[s, r])
-                    else:
-                        tok = self._resolve_token(
-                            req, len(req.output_tokens), am[s], fin[s],
-                            lambda s=s: _rows()[s])
-                    if tok is None:
-                        self._finish_abnormal(req, "error")
-                        continue
-                    req.phase = "decode"
-                    events.append(self._append_token(req, tok))
-            elif not full:                      # plain fused decode
-                req.kv.num_tokens = req.num_context
-                if self.pool.prefix_cache is not None:
-                    self.pool.prefix_cache.register_seq(req.kv,
-                                                        req.context_tokens)
-                tok = self._resolve_token(req, len(req.output_tokens),
-                                          am[s], fin[s],
-                                          lambda s=s: _rows()[s])
+                if prop is None and end < req.num_context:
+                    continue             # intermediate chunk: logits unread
+                if full:
+                    r = end - start - 1             # the chunk's last row
+                    tok = self._resolve_token(
+                        req, len(req.output_tokens), am[s, r], fin[s, r],
+                        lambda s=s, r=r: _rows()[s, r])
+                else:
+                    tok = self._resolve_token(
+                        req, len(req.output_tokens), am[s], fin[s],
+                        lambda s=s: _rows()[s])
                 if tok is None:
                     self._finish_abnormal(req, "error")
                     continue
+                if prop is None:          # the chunk completed the context
+                    req.phase = "decode"
                 events.append(self._append_token(req, tok))
-            else:                               # verify span (ISSUE 5)
-                self._accept_verify(
-                    req, prop, am[s], fin[s],
-                    lambda i, s=s: _rows()[s, i], events)
-        return events
+            return events
 
     def _accept_verify(self, req: Request, prop: List[int], row_am,
                        row_fin, row_fn, events: List[TokenEvent]) -> None:
-        """Token-exact accept loop for one verify span (ISSUE 5
-        tentpole). Span position i scored the logits for the token AFTER
+        """Token-exact accept loop for one verify span. Span position i
+        scored the logits for the token AFTER
         context + prop[:i]; the target token there is resolved with the
         request's own step-indexed sampler — argmax under greedy, the
         seeded per-step sample stream under temperature > 0, exactly the
@@ -1509,22 +1498,16 @@ class ServingEngine:
         if aborted and not req.done:
             self._finish_abnormal(req, "error")
 
-    # ------------------------------- fused verify-in-scan (ISSUE 18)
+    # ------------------------------------------ horizons: packed buffers
 
-    def _spec_horizon_ready(self) -> bool:
-        """Gate for the fused verify-in-scan path (ISSUE 18 tentpole):
-        True when this step's decode batch can ride drafts inside the
-        device-resident scan. Mirrors _plan_horizon's sampling envelope
-        — the in-scan sampler bakes ONE (top_k, top_p) pair per jit
-        entry and carries int32 seeds — and defers to the per-step
-        verify path for a batch carrying a mid-horizon NaN deferral
-        (the per-step path refetches real logits to rescue from).
-        Unlike _plan_horizon there is no decode_horizon >= 2
-        requirement: a fused verify span wins even at s == 1 (one
-        drain resolves k+1 tokens instead of a full-logits pull)."""
-        batch = self.scheduler.decode_ready()
-        if not batch:
-            return False
+    def _horizon_envelope(self, batch: List[Request]) -> bool:
+        """Whether this decode batch can ride a device-resident scan.
+        The in-scan sampler bakes ONE (top_k, top_p) pair per jit entry
+        and carries seeds as int32, so a batch whose sampled rows mix
+        pairs, or hold a wider seed, takes the per-step path; so does
+        any sampled row with `horizon_sampling` off, and a batch holding
+        a request that a mid-horizon NaN deferred (the per-step path
+        refetches real logits to rescue from)."""
         deferred = False
         for r in batch:
             if r.defer_horizon:
@@ -1533,29 +1516,175 @@ class ServingEngine:
         if deferred:
             return False
         sampled = [r for r in batch if r.sampling.temperature != 0.0]
-        if sampled:
-            if not self.horizon_sampling:
-                return False
-            if len({(r.sampling.top_k, r.sampling.top_p)
-                    for r in sampled}) > 1:
-                return False
-            if any((r.sampling.seed if r.sampling.seed is not None
-                    else r.arrival_index) >= 2 ** 31 for r in sampled):
-                return False
-        return True
+        if not sampled:
+            return True
+        return (self.horizon_sampling
+                and len({(r.sampling.top_k, r.sampling.top_p)
+                         for r in sampled}) == 1
+                and all((r.sampling.seed if r.sampling.seed is not None
+                         else r.arrival_index) < 2 ** 31 for r in sampled))
 
-    def _decode_spec_with_recovery(self, defer: bool = False
-                                   ) -> List[TokenEvent]:
-        """One fused speculative horizon (ISSUE 18 tentpole): the
-        batch's next `s` scan steps each carry a per-row draft span —
-        k proposed tokens, -1-padded to the batch's bucketed K —
-        through runner.decode_multi_spec, where accept/reject is
-        resolved ON DEVICE per position and the corrected/bonus token
-        feeds back into the scan. The host drains ONE packed
-        [3, B, s, K+1] buffer per horizon (host_syncs += 1, not one
-        full-logits pull per verify span) and replays acceptance
-        through _replay_spec_horizon, which applies exactly
-        _accept_verify's bookkeeping per kept position.
+    def _plan_horizon(self, chunks_in_flight: bool) -> int:
+        """Effective multi-step horizon for THIS step's decode batch —
+        the fallback matrix in one place. Returns 1 (the per-step path)
+        whenever the batch can't ride a device-resident horizon:
+        decode_horizon off, prefill chunks in flight this step (their
+        completing logits need per-step sampling), or a batch outside
+        `_horizon_envelope`. Otherwise caps s at the batch's token
+        headroom (never scan past every request's max_tokens, never
+        write a K/V position past max_model_len — overshoot past a STOP
+        token is fine and rolled back, the cap is about provable waste)
+        and lets the scheduler pre-commit the horizon's pages, trimming
+        further under pool pressure."""
+        s = self.decode_horizon
+        batch = self.scheduler.decode_ready()
+        if (s <= 1 or not batch or chunks_in_flight
+                or not self._horizon_envelope(batch)):
+            return 1
+        if self.horizon_early_stop:
+            # rows self-freeze on device at their own stop/budget, so
+            # only the LONGEST row's remaining budget caps s, and each
+            # row funds pages for just min(s, its remaining) tokens
+            rem = {r: self._row_remaining(r) for r in batch}
+            s = min(s, max(rem.values()))
+            if s <= 1:
+                return 1
+            return self.scheduler.plan_decode_horizon(s, row_caps=rem)
+        s = min(s, max(r.sampling.max_tokens - len(r.output_tokens)
+                       for r in batch))
+        s = min(s, min(self.max_model_len - r.num_context + 1
+                       for r in batch))
+        if s <= 1:
+            return 1
+        return self.scheduler.plan_decode_horizon(s)
+
+    def _row_remaining(self, req: Request) -> int:
+        """Tokens this request may still emit before a length finish or
+        the model-length wall — the on-device early-stop budget and the
+        per-row page-funding cap."""
+        return min(req.sampling.max_tokens - len(req.output_tokens),
+                   self.max_model_len - req.num_context + 1)
+
+    def _horizon_ctx(self, rows, stops: bool) -> dict:
+        """Extension operands of one horizon launch: the per-row seeded
+        key schedule where a row samples (seeds, generated-token base
+        indices, temperatures, plus the batch's single static (top_k,
+        top_p)) and, with `stops`, the on-device stop state (-1-padded
+        stop-token sets and remaining-token budgets). Empty dict = the
+        classic pure-greedy [2, B, s] scan."""
+        B = self.max_batch_size
+        ctx: dict = {}
+        if any(row[0].sampling.temperature != 0.0 for row in rows):
+            seeds = np.zeros((B,), np.int32)
+            base = np.zeros((B,), np.int32)
+            temps = np.zeros((B,), np.float32)
+            top_k = top_p = None
+            for r, sl, *_ in rows:
+                sp = r.sampling
+                seeds[sl] = (sp.seed if sp.seed is not None
+                             else r.arrival_index)
+                base[sl] = len(r.output_tokens)
+                temps[sl] = sp.temperature
+                if sp.temperature != 0.0:
+                    top_k, top_p = sp.top_k, sp.top_p
+            ctx.update(seeds=seeds, base_steps=base, temps=temps,
+                       top_k=top_k, top_p=top_p)
+        if stops:
+            S = max([1] + [len(row[0].sampling.stop_token_ids)
+                           for row in rows])
+            stop_ids = np.full((B, S), -1, np.int32)
+            remaining = np.ones((B,), np.int32)
+            for r, sl, *_ in rows:
+                ids = tuple(r.sampling.stop_token_ids)
+                stop_ids[sl, :len(ids)] = ids
+                remaining[sl] = self._row_remaining(r)
+            ctx.update(stop_ids=stop_ids, remaining=remaining)
+        return ctx
+
+    def _replay_horizon(self, launch: _InflightLaunch,
+                        drained=None) -> List[TokenEvent]:
+        """Replay one drained horizon buffer through the per-step
+        bookkeeping: _append_token's stop/length handling, prefix-cache
+        registration at each coverage point, the NaN policy — so token
+        streams, finish reasons, and metrics match the s=1 loop
+        verbatim. `drained` is [2, B, s] (tokens, finite) or, on the
+        extended scan, [3, B, s] with a LIVE plane: entries past a row's
+        on-device done bit are dead and never replayed (overshoot -> ~0
+        by construction). A request that stops mid-horizon discards its
+        overshoot tail (horizon_overshoot_tokens); its pre-committed
+        pages go back via the normal finish release. A batch member that
+        finished while the launch was in flight (pipelined
+        abort/deadline) is skipped — its drained tokens are discarded,
+        never half-committed."""
+        if drained is None:                 # the horizon's ONE host sync
+            drained = self._drain(lambda: _to_host(launch.result))
+        s = launch.s
+        with _prof.span("engine.commit"):
+            toks, fins = drained[0], drained[1]
+            live = drained[2] if drained.shape[0] > 2 else None
+            events: List[TokenEvent] = []
+            for req, sl, *_ in launch.batch:
+                if req.done:
+                    continue
+                C = req.num_context
+                accepted = 0
+                for j in range(s):
+                    if live is not None and not live[sl, j]:
+                        break      # row froze on device: tail is dead
+                    if not fins[sl, j]:
+                        self._horizon_nan(req, C, accepted)
+                        break
+                    req.kv.num_tokens = C + j
+                    if self.pool.prefix_cache is not None:
+                        self.pool.prefix_cache.register_seq(
+                            req.kv, req.context_tokens)
+                    events.append(
+                        self._append_token(req, int(toks[sl, j])))
+                    accepted += 1
+                    if req.done:
+                        tail = (s - accepted if live is None
+                                else int(np.sum(live[sl, accepted:] != 0)))
+                        self.metrics.horizon_overshoot_tokens.inc(tail)
+                        break
+            return events
+
+    def _horizon_nan(self, req: Request, C: int, accepted: int) -> None:
+        """Non-finite logits surfaced mid-horizon: the device loop kept
+        no [V] row to rescue from, so under nan_policy="abort" the
+        request ends exactly like an unrescuable per-step row; under
+        "greedy" the horizon tail is rolled back (coverage truncated,
+        over-committed pages decref'd on the spot) and the request is
+        deferred to the per-step path next step, which refetches the
+        real logits and applies the normal finite-entry rescue."""
+        self.metrics.nan_logit_events.inc()
+        if self.nan_policy == "abort":
+            self._finish_abnormal(req, "error")
+            return
+        req.kv.truncate(max(C + accepted - 1, 1))
+        req.defer_horizon = True
+
+    # ------------------------------------------------ fused verify-in-scan
+
+    def _spec_horizon_ready(self) -> bool:
+        """Gate for the fused verify-in-scan path: True when this step's
+        decode batch can ride drafts inside the device-resident scan
+        (`_horizon_envelope`). Unlike _plan_horizon there is no
+        decode_horizon >= 2 requirement: a fused verify span wins even
+        at s == 1 (one drain resolves k+1 tokens instead of a
+        full-logits pull)."""
+        batch = self.scheduler.decode_ready()
+        return bool(batch) and self._horizon_envelope(batch)
+
+    def _launch_spec_horizon(self, defer: bool = False
+                             ) -> List[TokenEvent]:
+        """One fused speculative horizon: the batch's next `s` scan
+        steps each carry a per-row draft span — k proposed tokens,
+        -1-padded to the batch's bucketed K — through
+        runner.decode_multi_spec, where accept/reject is resolved ON
+        DEVICE per position and the corrected/bonus token feeds back
+        into the scan. The host drains ONE packed [3, B, s, K+1] buffer
+        per horizon (not one full-logits pull per verify span) and
+        replays acceptance through _replay_spec_horizon.
 
         Drafts come from ONE proposer chain per row per horizon
         (s*(k+1)-1 tokens — the continuation under full acceptance),
@@ -1572,22 +1701,12 @@ class ServingEngine:
         remaining budgets) — it is what bounds kept emissions by
         `remaining` and makes that funding formula a true worst case.
 
-        Retries are exact like every other launch kind: proposals are
-        deterministic given the (unchanged) context, and acceptance is
-        deterministic given the seeded streams, so a rebuilt launch
-        commits the identical token stream; exhausted retries
-        quarantine the youngest spanning request and rebuild. With
-        `defer` (pipelined) the launch stays IN FLIGHT and the next
-        step's commit drains it; the _InflightLaunch carries the draft
-        grid for commit-time replay and the per-row funded `upcoming`
-        token counts for the auditor's over-provision credit."""
-        from paddle_tpu.serving.model_runner import bucket_len
-
+        The plan is made once: it is deterministic given request state,
+        and acceptance is deterministic given the seeded streams, so a
+        retried or rebuilt launch commits the identical token stream."""
         batch = self.scheduler.decode_ready()
         if not batch:
             return []
-        # ---- plan once: deterministic given request state, so retries
-        # rebuild the identical launch
         rem = {r: self._row_remaining(r) for r in batch}
         s = max(1, min(self.decode_horizon, max(rem.values())))
         budget = self.scheduler.speculation_budget(0)
@@ -1621,10 +1740,7 @@ class ServingEngine:
             # s positions per row (overshoot) — so re-plan through
             # _plan_horizon, which applies the overshoot caps and
             # funds the difference (grow is incremental)
-            s = self._plan_horizon(False)
-            if s > 1:
-                return self._decode_multi_with_recovery(s, defer=defer)
-            return self._decode_with_recovery(defer=defer)
+            return self._launch_decode(self._plan_horizon(False), defer)
         K = bucket_len(1 + kmax) - 1
         # mirrors plan_spec_horizon's funding formula exactly (the
         # auditor's over-provision credit) — including the block-table
@@ -1633,94 +1749,36 @@ class ServingEngine:
         upc = {r: max(1, min(s * (row_k[r] + 1), rem[r] + row_k[r],
                              wall - r.kv.num_tokens))
                for r in batch}
-        attempts = 0
-        delay = self.retry_backoff_s
-        while True:
-            batch = [r for r in self.scheduler.decode_ready()
-                     if r in row_k]
-            if not batch:
-                return []
-            build = _prof.span("engine.build_batch").begin()
-            B = self.max_batch_size
-            P = self.max_pages_per_seq
-            tokens = np.zeros((B,), np.int32)
-            tables = np.full((B, P), SCRATCH_PAGE, np.int32)
-            pos = np.zeros((B,), np.int32)
-            drafts = np.full((B, s, K), -1, np.int32)
-            sampling = any(r.sampling.temperature != 0.0 for r in batch)
-            seeds = np.zeros((B,), np.int32)
-            base = np.zeros((B,), np.int32)
-            temps = np.zeros((B,), np.float32)
-            top_k = top_p = None
-            S = max([1] + [len(r.sampling.stop_token_ids) for r in batch])
-            stop_ids = np.full((B, S), -1, np.int32)
-            remaining = np.ones((B,), np.int32)
-            for req in batch:
-                # every page the horizon may write must be private
-                # BEFORE launch (idempotent: forks survive a retry)
-                cow = req.kv.ensure_writable(req.num_context - 1,
-                                             req.num_context - 1 + upc[req])
-                if cow:
-                    self.metrics.cow_copies.inc(cow)
-                sl = req.slot
-                sp = req.sampling
-                tokens[sl] = req.output_tokens[-1]
-                row = req.kv.pages_array()
-                tables[sl, :len(row)] = row
-                pos[sl] = req.num_context - 1
-                k = row_k[req]
-                chain = chains[req]
+
+        def rows():
+            return [(r, r.slot, r.num_context - 1,
+                     r.num_context - 1 + upc[r], r.output_tokens[-1], ())
+                    for r in self.scheduler.decode_ready() if r in row_k]
+
+        def extras(rows):
+            drafts = np.full((self.max_batch_size, s, K), -1, np.int32)
+            for req, sl, *_ in rows:
+                k, chain = row_k[req], chains[req]
                 for t in range(s):
                     piece = chain[t * (k + 1):t * (k + 1) + k]
                     if piece:
                         drafts[sl, t, :len(piece)] = piece
-                seeds[sl] = (sp.seed if sp.seed is not None
-                             else req.arrival_index)
-                base[sl] = len(req.output_tokens)
-                temps[sl] = sp.temperature
-                if sp.temperature != 0.0:
-                    top_k, top_p = sp.top_k, sp.top_p
-                ids = tuple(sp.stop_token_ids)
-                stop_ids[sl, :len(ids)] = ids
-                remaining[sl] = rem[req]
-            kw: dict = dict(stop_ids=stop_ids, remaining=remaining)
-            if sampling:
-                kw.update(seeds=seeds, base_steps=base, temps=temps,
-                          top_k=top_k, top_p=top_p)
-            build.end()
-            prev = self.pool.pools
-            try:
-                packed, new_pools = self.runner.decode_multi_spec(
-                    tokens, tables, pos, self.pool.pools, drafts, **kw)
-                break
-            except Exception as e:
-                require_retryable(e, self.pool.pools)
-                if attempts < self.max_step_retries:
-                    attempts += 1
-                    self.metrics.step_retries.inc()
-                    self._sleep(delay)
-                    delay *= 2
-                    continue
-                self._finish_abnormal(batch[-1], "error")
-                attempts = 0
-                delay = self.retry_backoff_s
-        self.pool.pools = new_pools
-        self.metrics.batch_occupancy.observe(len(batch))
-        self.metrics.decode_horizon_steps.inc(s)
-        self.metrics.spec_fused_horizons.inc()
-        slots = [(r, r.slot) for r in batch]
-        if defer:
-            self._inflight = _InflightLaunch(
-                "decode_spec", slots, packed, prev, s,
-                spec={"drafts": drafts},
-                upcoming={id(r): upc[r] for r in batch})
-            return []
-        drained = self._timed_drain(lambda: _to_host(packed))
-        self.metrics.host_syncs.inc()       # the horizon's ONE host sync
-        return self._replay_spec_horizon(slots, drained, drafts)
+            return drafts, self._horizon_ctx(rows, stops=True)
 
-    def _replay_spec_horizon(self, batch_slots, drained, drafts
-                             ) -> List[TokenEvent]:
+        def launched():
+            self.metrics.decode_horizon_steps.inc(s)
+            self.metrics.spec_fused_horizons.inc()
+
+        return self._launch(_LaunchKind(
+            "decode_spec", rows,
+            lambda ops, x: self.runner.decode_multi_spec(
+                *ops, self.pool.pools, x[0], **x[1]),
+            _to_host, self._replay_spec_horizon, extras=extras, s=s,
+            launched=launched,
+            upcoming={id(r): n for r, n in upc.items()}), defer)
+
+    def _replay_spec_horizon(self, launch: _InflightLaunch,
+                             drained=None) -> List[TokenEvent]:
         """Replay one drained fused speculative horizon. `drained` is
         [3, B, s, K+1]: per scan step, the span's emitted tokens, a
         finiteness plane, and the KEEP plane — the device's accepted
@@ -1737,487 +1795,63 @@ class ServingEngine:
         on the spot — a speculated page never survives its rejection,
         and the auditor's over-provision check pins it. A batch member
         that finished while the launch was in flight is skipped."""
+        if drained is None:                 # the horizon's ONE host sync
+            drained = self._drain(lambda: _to_host(launch.result))
+        drafts = launch.extra[0]
         with _prof.span("engine.commit"):
-            return self._commit_spec_horizon(batch_slots, drained, drafts)
-
-    def _commit_spec_horizon(self, batch_slots, drained, drafts
-                             ) -> List[TokenEvent]:
-        toks, fins, keeps = drained[0], drained[1], drained[2]
-        s = toks.shape[1]
-        events: List[TokenEvent] = []
-        for req, sl in batch_slots:
-            if req.done:
-                continue
-            C = req.num_context
-            emitted = 0
-            proposed = 0
-            accepted = 0
-            halted = False
-            for t in range(s):
-                krow = keeps[sl, t]
-                if not krow[0]:
-                    break          # row froze on device: tail is dead
-                row_draft = drafts[sl, t]
-                ndraft = int(np.sum(row_draft >= 0))
-                proposed += ndraft
-                m = int(np.sum(krow != 0))
-                for i in range(m):
-                    if not fins[sl, t, i]:
-                        self._horizon_nan(req, C, emitted)
-                        halted = True
-                        break
-                    tok = int(toks[sl, t, i])
-                    if i < ndraft and int(row_draft[i]) == tok:
-                        accepted += 1
-                    req.kv.num_tokens = C + emitted
-                    if self.pool.prefix_cache is not None:
-                        self.pool.prefix_cache.register_seq(
-                            req.kv, req.context_tokens)
-                    events.append(self._append_token(req, tok))
-                    emitted += 1
-                    if req.done:
-                        halted = True
-                        break
-                if halted:
-                    break
-            self.metrics.spec_proposed_tokens.inc(proposed)
-            self.metrics.spec_accepted_tokens.inc(accepted)
-            self.metrics.spec_dead_positions.inc(
-                max(proposed - accepted, 0))
-            if self.adaptive_k is not None:
-                self.adaptive_k.update(req.request_id, proposed, accepted)
-            if not req.done and emitted > 0:
-                # rejected/unreached tail: drop back to the per-step
-                # invariant and decref pages grown past it (NaN rows
-                # already truncated via _horizon_nan)
-                dropped = req.kv.truncate(C + emitted - 1)
-                if dropped:
-                    self.metrics.spec_rollback_pages.inc(dropped)
-        return events
-
-    # ------------------------------------------- multi-step decode (s>1)
-
-    def _plan_horizon(self, chunks_in_flight: bool) -> int:
-        """Effective multi-step horizon for THIS step's decode batch
-        (ISSUE 6) — the fallback matrix in one place. Returns 1 (the
-        per-step path) whenever the batch can't ride a device-resident
-        horizon: decode_horizon off, prefill chunks in flight this step
-        (their completing logits need per-step sampling — speculation
-        itself no longer forces this path: verify spans ride the fused
-        scan via _decode_spec_with_recovery, ISSUE 18), any request
-        sampling at temperature > 0 (needs
-        its [V] rows on host), or a request deferred here by a mid-
-        horizon NaN (the per-step path refetches real logits to rescue
-        from). Otherwise caps s at the batch's token headroom (never
-        scan past every request's max_tokens, never write a K/V
-        position past max_model_len — overshoot past a STOP token is
-        fine and rolled back, the cap is about provable waste) and lets
-        the scheduler pre-commit the horizon's pages, trimming further
-        under pool pressure."""
-        s = self.decode_horizon
-        batch = self.scheduler.decode_ready()
-        if s <= 1 or not batch or chunks_in_flight:
-            return 1
-        deferred = False
-        for r in batch:
-            if r.defer_horizon:
-                r.defer_horizon = False
-                deferred = True
-        if deferred:
-            return 1
-        sampled = [r for r in batch if r.sampling.temperature != 0.0]
-        if sampled:
-            if not self.horizon_sampling:
-                return 1
-            # in-scan seeded sampling (ISSUE 11) bakes ONE (top_k,
-            # top_p) pair per jit entry and carries seeds as int32;
-            # batches outside that envelope take the per-step path
-            if len({(r.sampling.top_k, r.sampling.top_p)
-                    for r in sampled}) > 1:
-                return 1
-            if any((r.sampling.seed if r.sampling.seed is not None
-                    else r.arrival_index) >= 2 ** 31 for r in sampled):
-                return 1
-        if self.horizon_early_stop:
-            # rows self-freeze on device at their own stop/budget, so
-            # only the LONGEST row's remaining budget caps s, and each
-            # row funds pages for just min(s, its remaining) tokens
-            rem = {r: self._row_remaining(r) for r in batch}
-            s = min(s, max(rem.values()))
-            if s <= 1:
-                return 1
-            return self.scheduler.plan_decode_horizon(s, row_caps=rem)
-        s = min(s, max(r.sampling.max_tokens - len(r.output_tokens)
-                       for r in batch))
-        s = min(s, min(self.max_model_len - r.num_context + 1
-                       for r in batch))
-        if s <= 1:
-            return 1
-        return self.scheduler.plan_decode_horizon(s)
-
-    def _row_remaining(self, req: Request) -> int:
-        """Tokens this request may still emit before a length finish or
-        the model-length wall — the on-device early-stop budget and the
-        per-row page-funding cap (ISSUE 11)."""
-        return min(req.sampling.max_tokens - len(req.output_tokens),
-                   self.max_model_len - req.num_context + 1)
-
-    def _horizon_ctx(self, batch: List[Request], s: int) -> dict:
-        """Extension operands for one decode_multi launch (ISSUE 11):
-        the per-row seeded key schedule (horizon_sampling — seeds,
-        generated-token base indices, temperatures, plus the batch's
-        single static (top_k, top_p)) and the on-device stop state
-        (horizon_early_stop — -1-padded stop-token sets and
-        remaining-token budgets). Empty dict = the classic pure-greedy
-        [2, B, s] scan."""
-        sampling = any(r.sampling.temperature != 0.0 for r in batch)
-        if not (sampling or self.horizon_early_stop):
-            return {}
-        B = self.max_batch_size
-        ctx: dict = {}
-        if sampling:
-            seeds = np.zeros((B,), np.int32)
-            base = np.zeros((B,), np.int32)
-            temps = np.zeros((B,), np.float32)
-            top_k = top_p = None
-            for r in batch:
-                sp = r.sampling
-                sl = r.slot
-                seeds[sl] = (sp.seed if sp.seed is not None
-                             else r.arrival_index)
-                base[sl] = len(r.output_tokens)
-                temps[sl] = sp.temperature
-                if sp.temperature != 0.0:
-                    top_k, top_p = sp.top_k, sp.top_p
-            ctx.update(seeds=seeds, base_steps=base, temps=temps,
-                       top_k=top_k, top_p=top_p)
-        if self.horizon_early_stop:
-            S = max([1] + [len(r.sampling.stop_token_ids) for r in batch])
-            stop_ids = np.full((B, S), -1, np.int32)
-            remaining = np.ones((B,), np.int32)
-            for r in batch:
-                ids = tuple(r.sampling.stop_token_ids)
-                stop_ids[r.slot, :len(ids)] = ids
-                remaining[r.slot] = self._row_remaining(r)
-            ctx.update(stop_ids=stop_ids, remaining=remaining,
-                       early_stop=True)
-        return ctx
-
-    def _decode_multi_with_recovery(self, s: int,
-                                    defer: bool = False
-                                    ) -> List[TokenEvent]:
-        """One device-resident multi-step decode horizon (ISSUE 6
-        tentpole) with the per-step path's transient-failure recovery.
-        The batch's next `s` decode steps run in ONE
-        runner.decode_multi launch — a lax.scan that feeds each step's
-        on-device argmax token back as the next input — and the host
-        drains ONE packed [2, B, s] buffer (host_syncs += 1, not += s).
-        The buffer is then replayed token-by-token through exactly the
-        per-step bookkeeping: _append_token's stop/length handling,
-        prefix-cache registration at each coverage point, the NaN
-        policy — so token streams, finish reasons, and metrics match
-        the s=1 loop verbatim. A request that stops mid-horizon
-        discards its overshoot tail (horizon_overshoot_tokens); its
-        pre-committed pages go back via the normal finish release,
-        mirroring speculative rollback. Retries are exact for the same
-        reason decode retries are: a failed attempt either never
-        reached the device or re-writes identical K/V (the greedy
-        feedback chain is deterministic) through the same block tables;
-        exhausted retries quarantine the youngest spanning request and
-        rebuild, exactly like the per-step loop.
-
-        With `defer` (the pipelined loop, ISSUE 11) the launch is
-        dispatched and left IN FLIGHT — the next step's commit phase
-        (or flush()) drains and replays it; dispatch-time failures
-        still retry here, drain-time failures roll the pools back to
-        the captured pre-launch snapshot and rerun synchronously."""
-        attempts = 0
-        delay = self.retry_backoff_s
-        while True:
-            batch = self.scheduler.decode_ready()
-            if not batch:
-                return []
-            build = _prof.span("engine.build_batch").begin()
-            B = self.max_batch_size
-            P = self.max_pages_per_seq
-            tokens = np.zeros((B,), np.int32)
-            tables = np.full((B, P), SCRATCH_PAGE, np.int32)
-            pos = np.zeros((B,), np.int32)
-            for req in batch:
-                # every page the horizon will write must be private
-                # BEFORE launch (idempotent: forks survive a retry).
-                # Early-stop rows freeze their writes past their own
-                # remaining budget, so only that span needs forking
-                w = s if not self.horizon_early_stop else \
-                    min(s, self._row_remaining(req))
-                cow = req.kv.ensure_writable(req.num_context - 1,
-                                             req.num_context - 1 + w)
-                if cow:
-                    self.metrics.cow_copies.inc(cow)
-                sl = req.slot
-                tokens[sl] = req.output_tokens[-1]
-                row = req.kv.pages_array()
-                tables[sl, :len(row)] = row
-                pos[sl] = req.num_context - 1
-            ctx = self._horizon_ctx(batch, s)
-            build.end()
-            prev = self.pool.pools
-            try:
-                packed, new_pools = self.runner.decode_multi(
-                    tokens, tables, pos, self.pool.pools, s, **ctx)
-                break
-            except Exception as e:
-                require_retryable(e, self.pool.pools)
-                if attempts < self.max_step_retries:
-                    attempts += 1
-                    self.metrics.step_retries.inc()
-                    self._sleep(delay)
-                    delay *= 2
-                    continue
-                self._finish_abnormal(batch[-1], "error")
-                attempts = 0
-                delay = self.retry_backoff_s
-        self.pool.pools = new_pools
-        self.metrics.batch_occupancy.observe(len(batch))
-        self.metrics.decode_horizon_steps.inc(s)
-        slots = [(r, r.slot) for r in batch]
-        if defer:
-            self._inflight = _InflightLaunch("decode_multi", slots,
-                                             packed, prev, s)
-            return []
-        drained = self._timed_drain(lambda: _to_host(packed))
-        self.metrics.host_syncs.inc()       # the horizon's ONE host sync
-        return self._replay_horizon(slots, drained, s)
-
-    def _replay_horizon(self, batch_slots, drained, s: int
-                        ) -> List[TokenEvent]:
-        """Replay one drained horizon buffer through the per-step
-        bookkeeping: _append_token's stop/length handling, prefix-cache
-        registration at each coverage point, the NaN policy — so token
-        streams, finish reasons, and metrics match the s=1 loop
-        verbatim. `drained` is [2, B, s] (tokens, finite) or, on the
-        extended scan (ISSUE 11), [3, B, s] with a LIVE plane: entries
-        past a row's on-device done bit are dead and never replayed
-        (overshoot -> ~0 by construction). A batch member that finished
-        while the launch was in flight (pipelined abort/deadline) is
-        skipped — its drained tokens are discarded, never
-        half-committed."""
-        with _prof.span("engine.commit"):
-            return self._commit_horizon(batch_slots, drained, s)
-
-    def _commit_horizon(self, batch_slots, drained, s: int
-                        ) -> List[TokenEvent]:
-        toks, fins = drained[0], drained[1]
-        live = drained[2] if drained.shape[0] > 2 else None
-        events: List[TokenEvent] = []
-        for req, sl in batch_slots:
-            if req.done:
-                continue
-            C = req.num_context
-            accepted = 0
-            for j in range(s):
-                if live is not None and not live[sl, j]:
-                    break          # row froze on device: tail is dead
-                if not fins[sl, j]:
-                    self._horizon_nan(req, C, accepted)
-                    break
-                req.kv.num_tokens = C + j
-                if self.pool.prefix_cache is not None:
-                    self.pool.prefix_cache.register_seq(
-                        req.kv, req.context_tokens)
-                events.append(self._append_token(req, int(toks[sl, j])))
-                accepted += 1
+            toks, fins, keeps = drained[0], drained[1], drained[2]
+            s = toks.shape[1]
+            events: List[TokenEvent] = []
+            for req, sl, *_ in launch.batch:
                 if req.done:
-                    tail = (s - accepted if live is None
-                            else int(np.sum(live[sl, accepted:] != 0)))
-                    self.metrics.horizon_overshoot_tokens.inc(tail)
-                    break
-        return events
-
-    def _horizon_nan(self, req: Request, C: int, accepted: int) -> None:
-        """Non-finite logits surfaced mid-horizon: the device loop kept
-        no [V] row to rescue from, so under nan_policy="abort" the
-        request ends exactly like an unrescuable per-step row; under
-        "greedy" the horizon tail is rolled back (coverage truncated,
-        over-committed pages decref'd on the spot) and the request is
-        deferred to the per-step path next step, which refetches the
-        real logits and applies the normal finite-entry rescue."""
-        self.metrics.nan_logit_events.inc()
-        if self.nan_policy == "abort":
-            self._finish_abnormal(req, "error")
-            return
-        req.kv.truncate(max(C + accepted - 1, 1))
-        req.defer_horizon = True
-
-    def _decode_with_recovery(self, defer: bool = False
-                              ) -> List[TokenEvent]:
-        """One batched decode step with transient-failure recovery: retry
-        with backoff; once retries are exhausted, quarantine the youngest
-        running request (the step is then rebuilt without it). The loop is
-        bounded: each quarantine shrinks the batch, so at worst the batch
-        drains and the step yields no tokens — never an exception.
-
-        A retried decode is exact, not approximate: a failed attempt either
-        never reached the device (injected/raised before compute) or re-
-        writes the same K/V values through the same block tables, so the
-        token stream is unchanged vs a fault-free run.
-
-        Only decode-phase requests join the batch — a request mid-way
-        through its chunked prefill has no token to feed yet; its slot
-        carries an all-scratch table and self-neutralizes."""
-        attempts = 0
-        delay = self.retry_backoff_s
-        while True:
-            batch = self.scheduler.decode_ready()
-            if not batch:
-                return []
-            build = _prof.span("engine.build_batch").begin()
-            B = self.max_batch_size
-            P = self.max_pages_per_seq
-            tokens = np.zeros((B,), np.int32)
-            tables = np.full((B, P), SCRATCH_PAGE, np.int32)
-            pos = np.zeros((B,), np.int32)
-            for req in batch:
-                # the fed token's KV write must never land on a shared
-                # page (idempotent: a forked page is private on retry)
-                cow = req.kv.ensure_writable(req.num_context - 1,
-                                             req.num_context)
-                if cow:
-                    self.metrics.cow_copies.inc(cow)
-                s = req.slot
-                tokens[s] = req.output_tokens[-1]
-                row = req.kv.pages_array()
-                tables[s, :len(row)] = row
-                pos[s] = req.num_context - 1   # position of the fed token
-            build.end()
-            prev = self.pool.pools
-            try:
-                logits, new_pools = self.runner.decode(tokens, tables, pos,
-                                                       self.pool.pools)
-                break
-            except Exception as e:
-                require_retryable(e, self.pool.pools)
-                if attempts < self.max_step_retries:
-                    attempts += 1
-                    self.metrics.step_retries.inc()
-                    self._sleep(delay)
-                    delay *= 2
                     continue
-                self._finish_abnormal(batch[-1], "error")
-                attempts = 0
-                delay = self.retry_backoff_s
-        self.pool.pools = new_pools
-        self.metrics.batch_occupancy.observe(len(batch))
-        slots = [(r, r.slot) for r in batch]
-        if defer:
-            # pipelined (ISSUE 11): leave the launch in flight; the
-            # next step's commit (or flush()) drains and resolves it
-            self._inflight = _InflightLaunch("decode", slots, logits,
-                                             prev, 1)
-            return []
-        return self._finish_decode(slots, logits)
-
-    def _finish_decode(self, batch_slots, logits,
-                       grid=None) -> List[TokenEvent]:
-        """Resolve one drained decode launch: one vectorized greedy/
-        finite pass for the whole batch (the [B, V] array only reaches
-        the host for temp>0 / NaN-rescue rows), then the per-request
-        append/stop/NaN bookkeeping. Shared by the synchronous loop and
-        the pipelined commit (which passes the already-drained grid). A
-        batch member that finished while the launch was in flight is
-        skipped."""
-        with _prof.span("engine.commit"):
-            return self._commit_decode(batch_slots, logits, grid)
-
-    def _commit_decode(self, batch_slots, logits, grid) -> List[TokenEvent]:
-        if grid is None:
-            grid = self._timed_drain(lambda: greedy_grid(logits))
-            self.metrics.host_syncs.inc()
-        am, fin = grid
-        host: Dict[str, np.ndarray] = {}
-
-        def _rows() -> np.ndarray:
-            if "l" not in host:
-                host["l"] = self._timed_drain(lambda: _to_host(logits))
-                self.metrics.host_syncs.inc()
-            return host["l"]
-
-        events = []
-        for req, sl in batch_slots:
-            if req.done:
-                continue
-            req.kv.num_tokens = req.num_context
-            if self.pool.prefix_cache is not None:
-                self.pool.prefix_cache.register_seq(req.kv,
-                                                    req.context_tokens)
-            tok = self._resolve_token(req, len(req.output_tokens),
-                                      am[sl], fin[sl],
-                                      lambda s=sl: _rows()[s])
-            if tok is None:
-                self._finish_abnormal(req, "error")
-                continue
-            events.append(self._append_token(req, tok))
-        return events
-
-    # ------------------------------------------- pipelined loop (ISSUE 11)
-
-    def _commit_inflight(self) -> List[TokenEvent]:
-        """COMMIT phase of the zero-bubble loop: drain the in-flight
-        launch and replay it through the standard per-step bookkeeping.
-        The plan phase that just ran (admission, chunk slicing, page-in
-        staging) overlapped this launch's device time — that ordering
-        IS the optimization. A drain-time device error rolls the pools
-        back to the pre-launch snapshot (no pool write has happened
-        since the launch: the fence deliberately runs after this
-        commit) and reruns the step synchronously through the normal
-        retry/quarantine path — a retried launch re-writes identical
-        K/V through the same block tables, so streams stay exact."""
-        inf = self._inflight
-        if inf is None:
-            return []
-        self._inflight = None
-        try:
-            if inf.kind in ("decode", "ragged"):
-                grid = self._timed_drain(lambda: greedy_grid(inf.result))
-            else:
-                drained = self._timed_drain(lambda: _to_host(inf.result))
-        except Exception as e:
-            require_retryable(e, inf.prev_pools)
-            self.metrics.step_retries.inc()
-            self._sleep(self.retry_backoff_s)
-            self.pool.pools = inf.prev_pools
-            if inf.kind == "decode":
-                return self._decode_with_recovery()
-            if inf.kind == "ragged":
-                # rerun the fused step synchronously from live state:
-                # chunk coverage never advanced (that happens below, at
-                # commit), so the rebuilt spans recompute the identical
-                # chunks and decode feeds — retry-exact like decode
-                return self._ragged_step_with_recovery()
-            if inf.kind == "decode_spec":
-                # proposals are deterministic given the (unchanged)
-                # context and acceptance never depends on draft quality,
-                # so the synchronous rerun commits the identical stream
-                return self._decode_spec_with_recovery()
-            return self._decode_multi_with_recovery(inf.s)
-        self.metrics.host_syncs.inc()
-        if inf.kind == "decode":
-            return self._finish_decode(inf.batch, inf.result, grid)
-        if inf.kind == "ragged":
-            return self._finish_ragged(inf.spans, inf.result, False, grid)
-        if inf.kind == "decode_spec":
-            return self._replay_spec_horizon(inf.batch, drained,
-                                             inf.spec["drafts"])
-        return self._replay_horizon(inf.batch, drained, inf.s)
-
-    def flush(self) -> List[TokenEvent]:
-        """Fence the pipeline (ISSUE 11): commit any in-flight launch
-        and return its events. No-op on an unpipelined engine (or with
-        nothing in flight). Router workers call this on a graceful stop
-        so committed-but-undelivered tokens reach the delivery
-        registry; tests and tools use it before inspecting engine
-        state mid-run."""
-        return self._commit_inflight()
+                C = req.num_context
+                emitted = 0
+                proposed = 0
+                accepted = 0
+                halted = False
+                for t in range(s):
+                    krow = keeps[sl, t]
+                    if not krow[0]:
+                        break      # row froze on device: tail is dead
+                    row_draft = drafts[sl, t]
+                    ndraft = int(np.sum(row_draft >= 0))
+                    proposed += ndraft
+                    m = int(np.sum(krow != 0))
+                    for i in range(m):
+                        if not fins[sl, t, i]:
+                            self._horizon_nan(req, C, emitted)
+                            halted = True
+                            break
+                        tok = int(toks[sl, t, i])
+                        if i < ndraft and int(row_draft[i]) == tok:
+                            accepted += 1
+                        req.kv.num_tokens = C + emitted
+                        if self.pool.prefix_cache is not None:
+                            self.pool.prefix_cache.register_seq(
+                                req.kv, req.context_tokens)
+                        events.append(self._append_token(req, tok))
+                        emitted += 1
+                        if req.done:
+                            halted = True
+                            break
+                    if halted:
+                        break
+                self.metrics.spec_proposed_tokens.inc(proposed)
+                self.metrics.spec_accepted_tokens.inc(accepted)
+                self.metrics.spec_dead_positions.inc(
+                    max(proposed - accepted, 0))
+                if self.adaptive_k is not None:
+                    self.adaptive_k.update(req.request_id, proposed,
+                                           accepted)
+                if not req.done and emitted > 0:
+                    # rejected/unreached tail: drop back to the per-step
+                    # invariant and decref pages grown past it (NaN rows
+                    # already truncated via _horizon_nan)
+                    dropped = req.kv.truncate(C + emitted - 1)
+                    if dropped:
+                        self.metrics.spec_rollback_pages.inc(dropped)
+            return events
 
     def _append_token(self, req: Request, tok: int) -> TokenEvent:
         now = self.metrics.clock()
@@ -2253,7 +1887,7 @@ class ServingEngine:
 
     def stream_text(self, request_id: str) -> str:
         """Incremental detokenized text of a request's generation so far
-        (ISSUE 5 satellite): every output token up to the last byte-
+        : every output token up to the last byte-
         complete UTF-8 boundary — a multi-byte character split across
         tokens stays buffered until its continuation bytes arrive — and
         the fully-flushed text (dangling bytes replaced) once the
@@ -2293,58 +1927,38 @@ class ServingEngine:
 
     # --------------------------------------------- migration (router tier)
 
-    # --- prefill/decode handoff (ISSUE 12): the KV-carrying migration.
-    # A preemption's OffloadRecord + inject_request were already a
-    # migration primitive WITHIN one engine; these four methods stretch
-    # the same machinery across an engine boundary: spill -> serialize
+    # --- prefill/decode handoff: the KV-carrying migration. A
+    # preemption's OffloadRecord + inject_request were already a
+    # migration primitive WITHIN one engine; these methods stretch the
+    # same machinery across an engine boundary: spill -> serialize
     # slots (raw page bytes + scale rows + content hashes) -> import
     # into the sibling's tier -> inject with the record attached, after
     # which the sibling's ordinary admission page-in path takes over.
 
-    def _stage_handoffs(self) -> None:
-        """Park every request that completed its prefill this step
-        (decode phase, >= 1 sampled token) in the handoff buffer: KV
-        pages spill to the host tier from page 0 (shared prefix pages
-        included — the record must be self-contained on a sibling),
-        device pages and the batch slot are released. Coverage is
-        clamped to context-1 exactly like preemption, so the receiving
-        replica always has at least one token to compute — the position
-        whose logits it samples the next token from."""
-        tier = self.pool.host_tier
-        for req in [r for r in self.scheduler.running
-                    if r.phase == "decode" and r.output_tokens
-                    and not r.done]:
-            rec = None
-            if tier is not None:
-                covered = min(req.kv.num_tokens, req.num_context - 1)
-                rec = tier.spill_sequence(req.kv, covered,
-                                          include_registered=True)
-            self.scheduler.release_running(req)
-            req.phase = "handoff"
-            req.offload = None
-            self._handoffs[req.request_id] = rec
-            self.metrics.handoffs_out.inc()
-            if rec is not None:
-                self.metrics.handoff_pages_out.inc(len(rec.slots))
+    def _request_state(self, req: Request, now: float) -> dict:
+        """One request as migration and snapshots carry it (with its
+        live SamplingParams object)."""
+        return {
+            "request_id": req.request_id,
+            "prompt_tokens": list(req.prompt_tokens),
+            "output_tokens": list(req.output_tokens),
+            "sampling": req.sampling,
+            "arrival_index": req.arrival_index,
+            "num_preemptions": req.num_preemptions,
+            "elapsed_s": now - req.arrival_time,
+            "first_token_elapsed_s": (
+                req.first_token_time - req.arrival_time
+                if req.first_token_time is not None else None),
+        }
 
-    def stage_migration(self, request_id: str) -> bool:
-        """Park ONE RUNNING decode-phase request in the handoff buffer
-        on demand — the graceful-drain primitive (ISSUE 13). Exactly
-        the `_stage_handoffs` spill (pages to the host tier from page
-        0, coverage clamped to context-1, slot released) but role-
-        agnostic and per-request: `router.drain_replica` stages a
-        draining replica's running requests so their KV pages ride to
-        a sibling via extract_handoff/import_handoff instead of being
-        recomputed. Returns False when the request is not in a
-        stageable state (waiting, finished, still prefilling, or no
-        sampled token yet) — the caller then falls back to
-        extract_request / registry recompute, which is always
-        correct."""
-        req = self._requests.get(request_id)
-        if (req is None or req.done
-                or req.state is not RequestState.RUNNING
-                or req.phase != "decode" or not req.output_tokens):
-            return False
+    def _park_for_handoff(self, req: Request) -> None:
+        """Move one running decode-phase request into the handoff
+        buffer: KV pages spill to the host tier from page 0 (shared
+        prefix pages included — the record must be self-contained on a
+        sibling), device pages and the batch slot are released. Coverage
+        is clamped to context-1 exactly like preemption, so the
+        receiving replica always has at least one token to compute — the
+        position whose logits it samples the next token from."""
         tier = self.pool.host_tier
         rec = None
         if tier is not None:
@@ -2358,6 +1972,31 @@ class ServingEngine:
         self.metrics.handoffs_out.inc()
         if rec is not None:
             self.metrics.handoff_pages_out.inc(len(rec.slots))
+
+    def _stage_handoffs(self) -> None:
+        """Park every request that completed its prefill this step
+        (decode phase, >= 1 sampled token) in the handoff buffer."""
+        for req in [r for r in self.scheduler.running
+                    if r.phase == "decode" and r.output_tokens
+                    and not r.done]:
+            self._park_for_handoff(req)
+
+    def stage_migration(self, request_id: str) -> bool:
+        """Park ONE RUNNING decode-phase request in the handoff buffer
+        on demand — the graceful-drain primitive, role-agnostic:
+        `router.drain_replica` stages a draining replica's running
+        requests so their KV pages ride to a sibling via
+        extract_handoff/import_handoff instead of being recomputed.
+        Returns False when the request is not in a stageable state
+        (waiting, finished, still prefilling, or no sampled token yet) —
+        the caller then falls back to extract_request / registry
+        recompute, which is always correct."""
+        req = self._requests.get(request_id)
+        if (req is None or req.done
+                or req.state is not RequestState.RUNNING
+                or req.phase != "decode" or not req.output_tokens):
+            return False
+        self._park_for_handoff(req)
         return True
 
     def handoff_ready(self) -> List[str]:
@@ -2377,48 +2016,31 @@ class ServingEngine:
             raise KeyError(f"request {request_id!r} is not staged for "
                            "handoff")
         rec = self._handoffs.pop(request_id)
-        req = self._requests[request_id]
-        now = self.metrics.clock()
-        state = {
-            "request_id": req.request_id,
-            "prompt_tokens": list(req.prompt_tokens),
-            "output_tokens": list(req.output_tokens),
-            "sampling": req.sampling,
-            "arrival_index": req.arrival_index,
-            "num_preemptions": req.num_preemptions,
-            "elapsed_s": now - req.arrival_time,
-            "first_token_elapsed_s": (
-                req.first_token_time - req.arrival_time
-                if req.first_token_time is not None else None),
-        }
+        state = self._request_state(self._requests[request_id],
+                                    self.metrics.clock())
         payload = None
         tier = self.pool.host_tier
         if rec is not None and tier is not None:
+            payload = {
+                "start_page": rec.start_page,
+                "covered_tokens": rec.covered_tokens,
+                "hashes": [tier.slot_hash(s) for s in rec.slots],
+            }
             if tier.store is not None:
-                # slot-REFERENCE handoff (ISSUE 14): the pages already
-                # live in the host-wide store — ownership moves to a
-                # transfer tag and only slot ids + generations + CRCs
-                # cross the wire; the receiving replica adopts the
-                # same bytes by reference. Page bytes cross the wire
-                # ZERO times on the same host.
+                # slot-REFERENCE handoff: the pages already live in the
+                # host-wide store — ownership moves to a transfer tag
+                # and only slot ids + generations + CRCs cross the wire;
+                # the receiving replica adopts the same bytes by
+                # reference. Page bytes cross the wire ZERO times on the
+                # same host.
                 xfer = f"xfer:{request_id}"
-                hashes = [tier.slot_hash(s) for s in rec.slots]
                 tier.retag_out(rec.slots, xfer)
-                payload = {
-                    "start_page": rec.start_page,
-                    "covered_tokens": rec.covered_tokens,
-                    "slot_refs": list(rec.slots),
-                    "gens": [tier.generation(s) for s in rec.slots],
-                    "hashes": hashes,
-                    "xfer_owner": xfer,
-                }
+                payload.update(
+                    slot_refs=list(rec.slots),
+                    gens=[tier.generation(s) for s in rec.slots],
+                    xfer_owner=xfer)
             else:
-                payload = {
-                    "start_page": rec.start_page,
-                    "covered_tokens": rec.covered_tokens,
-                    "hashes": [tier.slot_hash(s) for s in rec.slots],
-                    "layers": tier.export_slots(rec.slots),
-                }
+                payload["layers"] = tier.export_slots(rec.slots)
                 self.metrics.handoff_bytes_out.inc(sum(
                     int(a.nbytes) for layer in payload["layers"]
                     for a in layer))
@@ -2488,7 +2110,7 @@ class ServingEngine:
                        first_token_elapsed_s: Optional[float] = None,
                        offload: Optional[OffloadRecord] = None) -> str:
         """Admit a request WITH prior generation state — the restore /
-        migration primitive (ISSUE 8). The request re-enters the queue
+        migration primitive. The request re-enters the queue
         carrying its prompt AND partial `output_tokens`; admission
         re-prefills the full context (the normal recompute-on-resume
         path) and the step-indexed sample keys make the continued stream
@@ -2536,8 +2158,8 @@ class ServingEngine:
     def extract_request(self, request_id: str) -> dict:
         """Remove a WAITING request and return its serialized state (the
         snapshot per-request shape, with a live SamplingParams object) —
-        the drain/redistribute half of migration (ISSUE 8): the router
-        tier extracts queued requests from a restored replica and
+        the drain/redistribute half of migration: the router tier
+        extracts queued requests from a restored replica and
         `inject_request`s them into siblings. RUNNING requests hold
         device pages and cannot move; FINISHED ones have nothing to."""
         req = self._requests.get(request_id)
@@ -2551,19 +2173,7 @@ class ServingEngine:
         del self._requests[request_id]
         self._detoks.pop(request_id, None)
         self.metrics.queue_depth.set(self.scheduler.queue_depth)
-        now = self.metrics.clock()
-        return {
-            "request_id": req.request_id,
-            "prompt_tokens": list(req.prompt_tokens),
-            "output_tokens": list(req.output_tokens),
-            "sampling": req.sampling,
-            "arrival_index": req.arrival_index,
-            "num_preemptions": req.num_preemptions,
-            "elapsed_s": now - req.arrival_time,
-            "first_token_elapsed_s": (
-                req.first_token_time - req.arrival_time
-                if req.first_token_time is not None else None),
-        }
+        return self._request_state(req, self.metrics.clock())
 
     # ------------------------------------------------ snapshot / restore
 
@@ -2577,124 +2187,64 @@ class ServingEngine:
             return 0
         return self.pool.prefix_cache.clear()
 
+    def _mesh_axes(self) -> Optional[dict]:
+        return ({str(a): int(s) for a, s in self.mesh.shape.items()}
+                if self.mesh is not None else None)
+
     def snapshot(self) -> dict:
         """Crash-safe serialization of ALL request state: prompts,
         generated tokens, sampling params, arrival order, plus finished
         outputs. JSON-serializable; device state is deliberately excluded
         — restore() rebuilds KV via the recompute-on-resume path, which
-        the step-indexed sample keys make token-exact.
+        the step-indexed sample keys make token-exact. What that leaves
+        out, and why nothing is lost:
 
-        The prefix cache's hash index is deliberately DROPPED (not
-        serialized): it points at device pages whose KV does not survive
-        the crash, so a restored engine starts with an empty cache and
-        rebuilds it as the recompute-on-resume prefills register their
-        pages — after which the still-queued siblings hit it again. A
-        snapshot taken mid-chunked-prefill serializes the same way: the
-        resumed request simply re-prefills from its (possibly cached)
-        prefix."""
+        - the prefix cache's hash index: it points at device pages whose
+          KV does not survive the crash, so a restored engine starts with
+          an empty cache and rebuilds it as the recompute-on-resume
+          prefills register their pages — after which the still-queued
+          siblings hit it again. A snapshot taken mid-chunked-prefill
+          serializes the same way: the resumed request re-prefills from
+          its (possibly cached) prefix;
+        - host-tier PAGES (the tier's options ride along, so a restored
+          engine keeps offloading): pinned host RAM has no crash story,
+          so every restored request re-enters through the recompute path
+          and the tier refills from fresh spills. Handoff-staged requests
+          ride along as plain waiters for the same reason, and on a
+          restored prefill-role engine they simply re-stage;
+        - an in-flight launch's drained-but-unreplayed buffer:
+          output_tokens hold only COMMITTED tokens, so the snapshot is
+          always pipeline-consistent and the buffer is regenerated by
+          recompute (never half-committed);
+        - a caller-built draft-model INSTANCE: it snapshots as "custom"
+          and restores as the n-gram proposer (logged); only the
+          "shadow[:dtype]" string round-trips.
+
+        "config" is the engine's `EngineConfig` as a dict, the runner's
+        `recipe()` (dtypes, the int4 group geometry) and the mesh's
+        shape. The runner's keys and `mesh_axes` ride along for the
+        record: restore() follows the NEW runner, because recompute-on-
+        resume rebuilds KV from tokens and is agnostic to quantization
+        and sharding (a tp=2 snapshot restores token-exactly on tp=1/2/4;
+        streams only stay identical when the dtypes match, and a
+        difference is logged)."""
         now = self.metrics.clock()
 
         def req_state(req: Request) -> dict:
             sp = asdict(req.sampling)
             sp["stop_token_ids"] = list(sp["stop_token_ids"])
-            return {
-                "request_id": req.request_id,
-                "prompt_tokens": list(req.prompt_tokens),
-                "output_tokens": list(req.output_tokens),
-                "sampling": sp,
-                "arrival_index": req.arrival_index,
-                "num_preemptions": req.num_preemptions,
-                "elapsed_s": now - req.arrival_time,
-                "first_token_elapsed_s": (
-                    req.first_token_time - req.arrival_time
-                    if req.first_token_time is not None else None),
-            }
+            return {**self._request_state(req, now), "sampling": sp}
 
         # resume priority: running requests first (in admission order —
         # they are the oldest in flight), then the waiting queue left to
-        # right (its head already encodes preempted-first recycle order).
-        # Handoff-staged requests (ISSUE 12) ride along as plain
-        # waiters: their spilled host pages die with the crash like all
-        # host state, so a restored engine re-prefills them — and on a
-        # restored prefill-role engine they simply re-stage
+        # right (its head already encodes preempted-first recycle order)
         reqs = [req_state(r) for r in (*self.scheduler.running,
                                        *self.scheduler.waiting)]
         reqs += [req_state(self._requests[rid]) for rid in self._handoffs]
         return {
             "version": 1,
-            "config": {
-                "num_blocks": self.pool.num_blocks,
-                "block_size": self.pool.block_size,
-                "max_batch_size": self.max_batch_size,
-                "max_model_len": self.max_model_len,
-                "max_queue_depth": self.max_queue_depth,
-                "shed_policy": self.shed_policy,
-                "admission_watermark": self.admission_watermark,
-                "max_step_retries": self.max_step_retries,
-                "retry_backoff_s": self.retry_backoff_s,
-                "nan_policy": self.nan_policy,
-                "max_prefill_tokens_per_step":
-                    self.max_prefill_tokens_per_step,
-                "enable_prefix_cache": self.enable_prefix_cache,
-                # host-tier knobs ride along (ISSUE 10) so a restored
-                # engine keeps offloading — but host PAGES deliberately
-                # do not: they died with the crashed process (pinned
-                # host RAM has no crash story), so every restored
-                # request re-enters through the recompute path and the
-                # tier refills from fresh spills
-                "host_tier_pages": self.host_tier_pages,
-                "host_tier_headroom": self.host_tier_headroom,
-                "pagein_prefetch": self.pagein_prefetch,
-                "ragged_batch": self.ragged_batch,
-                "decode_horizon": self.decode_horizon,
-                # zero-bubble knobs (ISSUE 11) ride along; the snapshot
-                # itself is always pipeline-consistent — output_tokens
-                # hold only COMMITTED tokens, an in-flight launch's
-                # drained-but-unreplayed buffer dies with the crash and
-                # is regenerated by recompute (never half-committed)
-                "pipelined": self.pipelined,
-                "horizon_sampling": self.horizon_sampling,
-                "horizon_early_stop": self.horizon_early_stop,
-                "spill_async": self.spill_async,
-                # disaggregated role (ISSUE 12): a restored prefill
-                # replica must keep prefilling-and-handing-off
-                "role": self.role,
-                "num_speculative_tokens": self.num_speculative_tokens,
-                "spec_max_ngram": self.spec_max_ngram,
-                "spec_min_ngram": self.spec_min_ngram,
-                # fused-speculation knobs (ISSUE 18) ride along so a
-                # restored engine keeps its draft rung; a caller-built
-                # draft-model INSTANCE snapshots as "custom" and is
-                # restored as the n-gram proposer (logged) — only the
-                # "shadow[:dtype]" string spec round-trips losslessly
-                "spec_adaptive_k": self.spec_adaptive_k,
-                "spec_draft_model": self.spec_draft_model,
-                "spec_draft_blocks": self.spec_draft_blocks,
-                "spec_ngram_window": self.spec_ngram_window,
-                # quantization knobs ride along for the record (ISSUE 9);
-                # restore() follows the NEW runner's dtypes — recompute-
-                # on-resume rebuilds KV from scratch, so it is
-                # quantization-agnostic (token streams only stay
-                # identical when the dtypes match, logged otherwise)
-                "kv_dtype": self.kv_dtype,
-                "weight_dtype": getattr(self.runner, "weight_dtype",
-                                        "fp32"),
-                # int4 group geometry rides along with the dtype — the
-                # scale shapes (and thus accuracy) depend on it
-                "weight_group_size": getattr(self.runner,
-                                             "weight_group_size", 128),
-                # quantized-collective knob (ISSUE 15) rides along for
-                # the record like the other dtypes; restore follows
-                # the NEW runner's comm_dtype (logged on mismatch)
-                "comm_dtype": getattr(self.runner, "comm_dtype", "fp32"),
-                # mesh shape rides along for the record (ISSUE 7); the
-                # restored engine follows the NEW runner's mesh — the
-                # recompute-on-resume path is sharding-agnostic, so a
-                # tp=2 snapshot restores token-exactly on tp=1/2/4
-                "mesh_axes": (
-                    {str(a): int(s) for a, s in self.mesh.shape.items()}
-                    if self.mesh is not None else None),
-            },
+            "config": {**self.runner.recipe(), **asdict(self.config),
+                       "mesh_axes": self._mesh_axes()},
             "requests": reqs,
             "finished": [asdict(o) for o in self._outputs.values()],
         }
@@ -2710,51 +2260,23 @@ class ServingEngine:
         in-flight request re-enters the queue with its prompt AND partial
         generation; admission re-prefills the full context (the normal
         recompute-on-resume path), so the continued token stream is
-        identical to an uninterrupted run."""
+        identical to an uninterrupted run. A key the snapshot lacks (an
+        older one) takes its `EngineConfig` default."""
         if state.get("version") != 1:
             raise ValueError(f"unknown snapshot version {state.get('version')}")
         cfg = state["config"]
-        draft_model = cfg.get("spec_draft_model")
-        if draft_model == "custom":
+        names = {f.name for f in fields(EngineConfig)}
+        options = {k: v for k, v in cfg.items() if k in names}
+        if options.get("spec_draft_model") == "custom":
             # a caller-built draft-runner instance can't be rebuilt from
             # JSON; token streams stay exact either way (acceptance
             # never depends on draft quality), only the speedup differs
             logger.info("restore: snapshot used a custom draft-model "
                         "instance; restoring with the n-gram proposer")
-            draft_model = None
-        eng = cls(runner, num_blocks=cfg["num_blocks"],
-                  block_size=cfg["block_size"],
-                  max_batch_size=cfg["max_batch_size"],
-                  max_model_len=cfg["max_model_len"],
-                  max_queue_depth=cfg["max_queue_depth"],
-                  shed_policy=cfg["shed_policy"],
-                  admission_watermark=cfg["admission_watermark"],
-                  max_step_retries=cfg["max_step_retries"],
-                  retry_backoff_s=cfg["retry_backoff_s"],
-                  nan_policy=cfg["nan_policy"],
-                  max_prefill_tokens_per_step=cfg.get(
-                      "max_prefill_tokens_per_step"),
-                  enable_prefix_cache=cfg.get("enable_prefix_cache", False),
-                  host_tier_pages=cfg.get("host_tier_pages", 0),
-                  host_tier_headroom=cfg.get("host_tier_headroom", False),
-                  pagein_prefetch=cfg.get("pagein_prefetch", 2),
-                  ragged_batch=cfg.get("ragged_batch", False),
-                  decode_horizon=cfg.get("decode_horizon", 1),
-                  pipelined=cfg.get("pipelined", False),
-                  horizon_sampling=cfg.get("horizon_sampling", False),
-                  horizon_early_stop=cfg.get("horizon_early_stop", False),
-                  spill_async=cfg.get("spill_async", False),
-                  role=cfg.get("role", "mixed"),
-                  num_speculative_tokens=cfg.get("num_speculative_tokens", 0),
-                  spec_max_ngram=cfg.get("spec_max_ngram", 3),
-                  spec_min_ngram=cfg.get("spec_min_ngram", 1),
-                  spec_adaptive_k=cfg.get("spec_adaptive_k", False),
-                  spec_draft_model=draft_model,
-                  spec_draft_blocks=cfg.get("spec_draft_blocks"),
-                  spec_ngram_window=cfg.get("spec_ngram_window"),
-                  tokenizer=tokenizer,
-                  kv_store=kv_store, kv_store_owner=kv_store_owner,
-                  metrics=metrics, sleep_fn=sleep_fn, audit=audit)
+            options["spec_draft_model"] = None
+        eng = cls(runner, tokenizer=tokenizer, kv_store=kv_store,
+                  kv_store_owner=kv_store_owner, metrics=metrics,
+                  sleep_fn=sleep_fn, audit=audit, **options)
         for r in state["requests"]:
             sp = dict(r["sampling"])
             sp["stop_token_ids"] = tuple(sp.get("stop_token_ids", ()))
@@ -2769,27 +2291,18 @@ class ServingEngine:
         for o in state.get("finished", []):
             eng._outputs[o["request_id"]] = RequestOutput(**o)
         eng.metrics.queue_depth.set(eng.scheduler.queue_depth)
-        snap_mesh = cfg.get("mesh_axes")
-        run_mesh = ({str(a): int(s) for a, s in eng.mesh.shape.items()}
-                    if eng.mesh is not None else None)
-        if snap_mesh != run_mesh:
-            # legal (recompute-on-resume is sharding-agnostic and token-
-            # exact) but worth a breadcrumb: capacity/throughput differ
-            logger.info("restore: snapshot mesh %s -> runner mesh %s",
-                        snap_mesh, run_mesh)
-        snap_q = (cfg.get("kv_dtype", "fp32"),
-                  cfg.get("weight_dtype", "fp32"),
-                  cfg.get("comm_dtype", "fp32"),
-                  cfg.get("weight_group_size", 128))
-        run_q = (eng.kv_dtype, getattr(runner, "weight_dtype", "fp32"),
-                 getattr(runner, "comm_dtype", "fp32"),
-                 getattr(runner, "weight_group_size", 128))
-        if snap_q != run_q:
-            # also legal (restore recomputes KV from tokens), but the
-            # continued stream follows the NEW runner's quantization
-            logger.info("restore: snapshot kv/weight dtype %s -> runner "
-                        "%s", snap_q, run_q)
+        # both legal (recompute-on-resume is token-exact on any mesh, and
+        # rebuilds KV in the NEW runner's quantization) but worth a
+        # breadcrumb: capacity and throughput differ, and the continued
+        # stream follows the new dtypes
+        was = {k: cfg.get(k) for k in cfg if k not in names}
+        here = {**{k: v for k, v in runner.recipe().items()
+                  if k not in names}, "mesh_axes": eng._mesh_axes()}
+        if was != here:
+            logger.info("restore: snapshot runner %s -> this runner %s",
+                        was, here)
         return eng
+
 
 
 def naive_generate(runner: PagedModelRunner, prompt_tokens: Sequence[int],
@@ -2830,54 +2343,13 @@ def naive_generate(runner: PagedModelRunner, prompt_tokens: Sequence[int],
     return out
 
 
-def create_engine(model, *, num_blocks: int = 128,
-                  block_size: int = 16, max_batch_size: int = 8,
-                  max_model_len: Optional[int] = None,
-                  attn_impl: str = "auto", mesh=None,
-                  data_axis: str = "data", model_axis: str = "model",
-                  kv_dtype: str = "fp32", weight_dtype: str = "fp32",
-                  weight_group_size: int = 128,
-                  comm_dtype: str = "fp32",
-                  **engine_kw) -> ServingEngine:
-    """Build a ServingEngine for a supported decoder Layer (Llama, GPT).
-
-    Pass a `(data, model)` jax mesh (parallel.mesh.serving_mesh) to serve
-    tensor-parallel (ISSUE 7): weights and the paged K/V pools shard over
-    the model axis; token streams stay identical to the single-device
-    engine. n_kv_heads must divide by the model-axis degree.
-
-    `kv_dtype="int8"` / `weight_dtype="int8"` (ISSUE 9) serve with
-    quantized K/V pools (per-page-per-head scales, dequant inside the
-    ragged kernel's page walk) and/or weight-only int8 linears —
-    accuracy-gated vs the fp32 oracle, ~half the attention HBM bytes.
-
-    ISSUE 15 rungs: `kv_dtype="fp8"` stores native float8_e4m3fn pages
-    (scale-free casts, 4x fewer KV bytes); `kv_dtype="mixed"` serves
-    fp32 and fp8 tenants from one pool via `SamplingParams.kv_dtype`;
-    `comm_dtype="int8"` (needs a mesh) swaps the row-parallel allreduce
-    for the chunked quantized psum — accuracy-gated vs the fp32 TP
-    engine, ~4x fewer wire bytes (scale bytes counted).
-
-    ISSUE 19 rungs: `weight_dtype="int4"` stores 2-D matmul weights as
-    packed nibble codes + group-wise fp32 scales (`weight_group_size`
-    reduction rows per scale, default 128) with the dequant fused into
-    the matmul epilogue — >= 3.5x fewer resident weight bytes, scale
-    bytes counted; `weight_dtype="fp8"` stores native float8_e4m3fn
-    weights (scale-free); `comm_dtype="int8"` now also quantizes the
-    column-parallel all-gather on the lm_head logits path."""
-    if comm_dtype != "fp32" and mesh is None:
-        raise ValueError(
-            f"comm_dtype={comm_dtype!r} needs a tensor-parallel mesh — "
-            "the quantized collective replaces the row-parallel "
-            "allreduce, which only exists at tp > 1")
-    runner = runner_for(model, block_size=block_size,
-                        max_model_len=max_model_len, attn_impl=attn_impl,
-                        kv_dtype=kv_dtype, weight_dtype=weight_dtype,
-                        weight_group_size=weight_group_size)
-    if mesh is not None:
-        runner.shard(mesh, data_axis=data_axis, model_axis=model_axis,
-                     comm_dtype=comm_dtype)
-    return ServingEngine(runner, num_blocks=num_blocks,
-                         block_size=block_size,
-                         max_batch_size=max_batch_size,
-                         max_model_len=max_model_len, **engine_kw)
+def create_engine(model, *, num_blocks: int = 128, **kw) -> ServingEngine:
+    """Build a ServingEngine for a supported decoder Layer: `build_runner`
+    from the names it knows in `kw` (the runner's options, `mesh`,
+    `data_axis`, `model_axis`, `comm_dtype`), the engine from the rest.
+    `inference.create_serving_engine`, the public entry, adds the cast to
+    a serving dtype and documents the options."""
+    runner = build_runner(model, **{
+        k: kw.pop(k) for k in (*RUNNER_OPTIONS, "mesh", "data_axis",
+                               "model_axis", "comm_dtype") if k in kw})
+    return ServingEngine(runner, num_blocks=num_blocks, **kw)

@@ -371,7 +371,10 @@ class PagedModelRunner:
 
     Subclasses set the architecture fields in __init__ and implement
     `_forward(params, tokens, positions, write_page, write_off, tables,
-    pos_q, pools) -> (logits[B, T, V], pools)`.
+    pos_q, pools) -> (logits[B, T, V], pools)`. Their constructors take
+    `(model, block_size, max_model_len, attn_impl)` and hand `kv_dtype`,
+    `weight_dtype` and `weight_group_size` through to this one, which
+    owns their defaults and their checks.
     """
 
     num_layers: int
@@ -406,8 +409,8 @@ class PagedModelRunner:
         # (this runner quantizes at append time and dequantizes in the
         # attend paths); weight_dtype walks the weight ladder (ISSUE 19)
         # — "int8" per-output-channel scales, "int4" packed nibble codes
-        # + group-wise scales (weight_group_size reduction rows per
-        # scale), "fp8" native float8_e4m3fn, scale-free. Subclasses
+        # + one scale per group of reduction rows, "fp8" native
+        # float8_e4m3fn, scale-free. Subclasses
         # call _quantize_weights at construction. Both knobs default to
         # "fp32", which is bit-identical to the pre-ISSUE-9 runner.
         if kv_dtype not in KV_DTYPES:
@@ -421,12 +424,11 @@ class PagedModelRunner:
                              f"of {WEIGHT_DTYPES}")
         if weight_dtype == "fp8":
             require_fp8(f"PagedModelRunner(weight_dtype={weight_dtype!r})")
-        if int(weight_group_size) < 1:
-            raise ValueError(f"weight_group_size must be >= 1, got "
-                             f"{weight_group_size}")
         self.kv_dtype = kv_dtype
         self.weight_dtype = weight_dtype
-        self.weight_group_size = int(weight_group_size)
+        self.weight_group_size = group = int(weight_group_size)
+        if group < 1:
+            raise ValueError(f"weight_group_size must be >= 1, got {group}")
         # the params _quantize_weights converted (codes under the weight
         # name, scales under name+SCALE_SUFFIX) — the weight_bytes()
         # accounting's map back to logical fp32 shapes
@@ -509,6 +511,18 @@ class PagedModelRunner:
     def n_rep(self) -> int:
         return self.n_heads // self.n_kv_heads
 
+    def recipe(self) -> dict:
+        """What `build_runner` was asked for, as this runner has it: the
+        half of an engine snapshot's "config" that the runner owns (the
+        engine's half is its `EngineConfig`). `restore_serving_engine`
+        builds the next runner from these keys."""
+        return {"block_size": self.block_size,
+                "max_model_len": self.max_model_len,
+                "kv_dtype": self.kv_dtype,
+                "weight_dtype": self.weight_dtype,
+                "weight_group_size": self.weight_group_size,
+                "comm_dtype": self.comm_dtype}
+
     # --------------------------------- the weight ladder (ISSUE 9 / 19)
 
     def _quantize_weights(self, names) -> None:
@@ -524,14 +538,14 @@ class PagedModelRunner:
         if self.weight_dtype == "int4":
             from paddle_tpu.quantization.int4 import int4_quantize
 
+            group = self.weight_group_size
             for name in names:
-                qw, scale = int4_quantize(self.params[name],
-                                          self.weight_group_size)
+                qw, scale = int4_quantize(self.params[name], group)
                 self.params[name] = qw
                 self.params[name + SCALE_SUFFIX] = scale
             logger.info("serving weights quantized int4: %d matrices "
                         "(packed nibbles, group scales, group=%d)",
-                        len(names), self.weight_group_size)
+                        len(names), group)
         elif self.weight_dtype == "fp8":
             for name in names:
                 self.params[name] = self.params[name].astype(
@@ -577,10 +591,15 @@ class PagedModelRunner:
                 return x @ w.astype(x.dtype)
             return x @ w
         if s.ndim == 2:
-            from paddle_tpu.quantization.int4 import int4_matmul
-
-            return int4_matmul(x, w, s, self.weight_group_size)
+            return self._int4_mm(x, w, s)
         return (x @ w.astype(x.dtype)) * s.astype(x.dtype)
+
+    def _int4_mm(self, x, w, s):
+        """x @ (packed int4 codes `w` with group scales `s`): the grouped
+        epilogue at this runner's group size, whole or one shard's part."""
+        from paddle_tpu.quantization.int4 import int4_matmul
+
+        return int4_matmul(x, w, s, self.weight_group_size)
 
     def _row_mm(self, params, name, x):
         """Row-parallel matmul with an EXPLICIT collective (ISSUE 15):
@@ -605,13 +624,9 @@ class PagedModelRunner:
         s = params.get(name + SCALE_SUFFIX)
         x_spec = P(*((None,) * (x.ndim - 1) + (axis,)))
         if s is not None and s.ndim == 2:
-            from paddle_tpu.quantization.int4 import int4_matmul
-
-            g = self.weight_group_size
-
             def f4(x_local, w_local, s_local):
-                part = int4_matmul(x_local, w_local, s_local, g)
-                return reduce_fn(part, axis)
+                return reduce_fn(self._int4_mm(x_local, w_local, s_local),
+                                 axis)
 
             return manual_shard_map(
                 f4, mesh=self.mesh,
@@ -656,13 +671,9 @@ class PagedModelRunner:
                 f, mesh=self.mesh, in_specs=(P(), w_spec),
                 out_specs=P(), axis_names=frozenset({axis}))(x, w)
         if s.ndim == 2:
-            from paddle_tpu.quantization.int4 import int4_matmul
-
-            g = self.weight_group_size
-
             def f4(x_local, w_local, s_local):
-                part = int4_matmul(x_local, w_local, s_local, g)
-                return gather_fn(part, axis)
+                return gather_fn(self._int4_mm(x_local, w_local, s_local),
+                                 axis)
 
             return manual_shard_map(
                 f4, mesh=self.mesh, in_specs=(P(), w_spec, P(axis, None)),
@@ -1640,15 +1651,14 @@ class LlamaRunner(PagedModelRunner):
 
     def __init__(self, model, block_size: int = 16,
                  max_model_len: int | None = None, attn_impl: str = "auto",
-                 kv_dtype: str = "fp32", weight_dtype: str = "fp32",
-                 weight_group_size: int = 128):
+                 **quant):
         from paddle_tpu.jit.functionalize import functionalize
 
         cfg = model.cfg
         params = functionalize(model).param_values()
         super().__init__(params, block_size,
                          max_model_len or cfg.max_seq_len, attn_impl,
-                         kv_dtype, weight_dtype, weight_group_size)
+                         **quant)
         self.cfg = cfg
         self.num_layers = cfg.num_layers
         self.n_heads = cfg.num_heads
@@ -1658,7 +1668,7 @@ class LlamaRunner(PagedModelRunner):
         cos, sin = _rope_tables(self.max_model_len, self.head_dim,
                                 cfg.rope_theta)
         self._rope_cos, self._rope_sin = cos, sin      # [L, d] fp32
-        if weight_dtype != "fp32":
+        if self.weight_dtype != "fp32":
             names = []
             for i in range(self.num_layers):
                 pre = f"layers.{i}."
@@ -1753,22 +1763,21 @@ class GPTRunner(PagedModelRunner):
 
     def __init__(self, model, block_size: int = 16,
                  max_model_len: int | None = None, attn_impl: str = "auto",
-                 kv_dtype: str = "fp32", weight_dtype: str = "fp32",
-                 weight_group_size: int = 128):
+                 **quant):
         from paddle_tpu.jit.functionalize import functionalize
 
         cfg = model.cfg
         params = functionalize(model).param_values()
         super().__init__(params, block_size,
                          max_model_len or cfg.max_seq_len, attn_impl,
-                         kv_dtype, weight_dtype, weight_group_size)
+                         **quant)
         self.cfg = cfg
         self.num_layers = cfg.num_layers
         self.n_heads = cfg.num_heads
         self.n_kv_heads = cfg.num_heads
         self.head_dim = cfg.hidden_size // cfg.num_heads
         self.vocab_size = cfg.vocab_size
-        if weight_dtype != "fp32":
+        if self.weight_dtype != "fp32":
             # GPT stores the fused QKV weight FLAT as [hidden, 3*nh*d]
             # (column order (3, nh, d)), so per-output-channel/group
             # abs-max quantization is exact per fused column; the
@@ -1902,22 +1911,21 @@ class DeepseekV3Runner(PagedModelRunner):
 
     def __init__(self, model, block_size: int = 16,
                  max_model_len: int | None = None, attn_impl: str = "auto",
-                 kv_dtype: str = "fp32", weight_dtype: str = "fp32",
-                 weight_group_size: int = 128):
+                 **quant):
         from paddle_tpu.jit.functionalize import functionalize
 
         cfg = model.cfg
-        if kv_dtype != "fp32":
+        if quant.get("kv_dtype", "fp32") != "fp32":
             raise ValueError(
-                f"kv_dtype={kv_dtype!r}: latent pages come in the model's "
-                "stated dtype only (no quantized rung for them yet)")
-        if weight_dtype == "int4":
+                f"kv_dtype={quant['kv_dtype']!r}: latent pages come in the "
+                "model's stated dtype only (no quantized rung for them yet)")
+        if quant.get("weight_dtype") == "int4":
             raise ValueError("weight_dtype='int4' is not wired for the "
                              "latent-attention runner (int8 and fp8 are)")
         params = functionalize(model).param_values()
         super().__init__(params, block_size,
                          max_model_len or cfg.max_seq_len, attn_impl,
-                         kv_dtype, weight_dtype, weight_group_size)
+                         **quant)
         self.cfg = cfg
         self.num_layers = cfg.num_hidden_layers
         self.n_heads = cfg.num_attention_heads
@@ -1927,7 +1935,7 @@ class DeepseekV3Runner(PagedModelRunner):
         self._rope_cos, self._rope_sin = _dsv3.rope_tables(
             cfg, self.max_model_len)                   # [L, rope] fp32
         self._scale = _dsv3.softmax_scale(cfg)
-        if weight_dtype != "fp32":
+        if self.weight_dtype != "fp32":
             names = ["lm_head.weight"]
             for i in range(self.num_layers):
                 pre = f"layers.{i}."
@@ -2060,16 +2068,45 @@ def runner_for(model, block_size: int = 16, max_model_len: int | None = None,
     from paddle_tpu.models.gpt import GPT
     from paddle_tpu.models.llama import Llama
 
-    if isinstance(model, Llama):
-        return LlamaRunner(model, block_size, max_model_len, attn_impl,
-                           kv_dtype, weight_dtype, weight_group_size)
-    if isinstance(model, GPT):
-        return GPTRunner(model, block_size, max_model_len, attn_impl,
-                         kv_dtype, weight_dtype, weight_group_size)
-    if isinstance(model, DeepseekV3ForCausalLM):
-        return DeepseekV3Runner(model, block_size, max_model_len, attn_impl,
-                                kv_dtype, weight_dtype, weight_group_size)
+    for layer, cls in ((Llama, LlamaRunner), (GPT, GPTRunner),
+                       (DeepseekV3ForCausalLM, DeepseekV3Runner)):
+        if isinstance(model, layer):
+            return cls(model, block_size, max_model_len, attn_impl,
+                       kv_dtype=kv_dtype, weight_dtype=weight_dtype,
+                       weight_group_size=weight_group_size)
     raise TypeError(
         f"no serving runner for {type(model).__name__}; supported: Llama, "
         "GPT, DeepseekV3ForCausalLM (write a PagedModelRunner subclass for "
         "custom decoders)")
+
+
+# what `runner_for` takes besides the model: an entry point that is handed
+# one `**kw` for runner and engine together splits it by these names
+RUNNER_OPTIONS = ("block_size", "max_model_len", "attn_impl", "kv_dtype",
+                  "weight_dtype", "weight_group_size")
+
+
+def build_runner(model, *, dtype=None, mesh=None, data_axis: str = "data",
+                 model_axis: str = "model", comm_dtype: str = "fp32",
+                 **runner_kw) -> PagedModelRunner:
+    """THE recipe "decoder Layer -> runner ready to serve", for every
+    entry point (`create_engine`, `inference.create_serving_engine`, the
+    router's replica factory, `restore_serving_engine`, the replica
+    process): `runner_for` with `runner_kw`, then the floating
+    parameters cast to `dtype`, then `shard(mesh, ...)`. Cast first,
+    shard second: the device_put then ships the final serving dtype, not
+    fp32 weights that get re-cast on the device."""
+    if comm_dtype != "fp32" and mesh is None:
+        raise ValueError(
+            f"comm_dtype={comm_dtype!r} needs a tensor-parallel mesh — "
+            "the quantized collective replaces the row-parallel "
+            "allreduce, which only exists at tp > 1")
+    runner = runner_for(model, **runner_kw)
+    if dtype is not None:
+        runner.params = {
+            k: (v.astype(dtype) if jnp.issubdtype(v.dtype, jnp.floating)
+                else v) for k, v in runner.params.items()}
+    if mesh is not None:
+        runner.shard(mesh, data_axis=data_axis, model_axis=model_axis,
+                     comm_dtype=comm_dtype)
+    return runner

@@ -39,7 +39,6 @@ import argparse
 import importlib
 import socket
 import sys
-from typing import Optional
 
 
 def resolve_factory(spec: dict):
@@ -58,35 +57,30 @@ def resolve_factory(spec: dict):
 
 
 def model_runner_factory(index: int = 0, *, model: str = "llama",
-                         seed: int = 0, block_size: int = 16,
-                         max_model_len: Optional[int] = None,
-                         attn_impl: str = "auto", kv_dtype: str = "fp32",
-                         weight_dtype: str = "fp32",
-                         weight_group_size: int = 128, **cfg_kw):
+                         seed: int = 0, **kw):
     """Built-in factory for real-model replicas: builds a Llama/GPT
-    PagedModelRunner from config kwargs, seeded — every process that
-    calls this with the same arguments holds IDENTICAL weights, which
-    is what makes cross-process migration token-exact without ever
-    shipping parameters over the wire."""
+    PagedModelRunner, seeded — every process that calls this with the
+    same arguments holds IDENTICAL weights, which is what makes
+    cross-process migration token-exact without ever shipping
+    parameters over the wire. `kw` holds the runner's options
+    (`RUNNER_OPTIONS`) and, under every other name, the model's config."""
     import paddle_tpu as paddle
-    from paddle_tpu.serving import runner_for
+    from paddle_tpu.serving.model_runner import RUNNER_OPTIONS, build_runner
 
+    runner_kw = {k: kw.pop(k) for k in RUNNER_OPTIONS if k in kw}
     paddle.seed(seed)
     if model == "llama":
         from paddle_tpu.models.llama import Llama, LlamaConfig
 
-        net = Llama(LlamaConfig(**cfg_kw))
+        net = Llama(LlamaConfig(**kw))
     elif model == "gpt":
         from paddle_tpu.models.gpt import GPT, GPTConfig
 
-        net = GPT(GPTConfig(**cfg_kw))
+        net = GPT(GPTConfig(**kw))
     else:
         raise ValueError(f"model={model!r}; expected 'llama' or 'gpt'")
     net.eval()
-    return runner_for(net, block_size=block_size,
-                      max_model_len=max_model_len, attn_impl=attn_impl,
-                      kv_dtype=kv_dtype, weight_dtype=weight_dtype,
-                      weight_group_size=weight_group_size)
+    return build_runner(net, **runner_kw)
 
 
 class ReplicaServer:

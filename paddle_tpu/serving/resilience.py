@@ -361,7 +361,7 @@ def audit_engine(engine) -> None:
     # length so the over-provision check can credit their pre-committed
     # pages (and pin that at most one launch is ever outstanding)
     inflight = getattr(engine, "_inflight", None)
-    inflight_horizon = ({id(r): inflight.s for r, _ in inflight.batch}
+    inflight_horizon = ({id(row[0]): inflight.s for row in inflight.batch}
                         if inflight is not None else {})
     # a fused speculative launch (ISSUE 18) pre-commits pages for up to
     # min(s*(k+1), remaining+k) tokens per row — the launch records the

@@ -20,7 +20,7 @@ ISSUE 18 adds the rest of the ladder:
   ``scan_window`` knob covers the stateless path.
 * ``propose_chain``: an optimistic s*(k+1)-1 token continuation the
   fused verify-in-scan slices per horizon step (engine
-  ``_decode_spec_with_recovery``).
+  ``_launch_spec_horizon``).
 * ``AdaptiveK``: per-request EWMA over accepted/proposed, mapping the
   acceptance rate into k in [0, num_speculative_tokens] — cold requests
   stop paying dead verify positions.
@@ -235,7 +235,6 @@ def shadow_runner(target, weight_dtype: str = "int8"):
                     and not any(s in name for s in skip)):
                 names.append(name)
         r.weight_dtype = weight_dtype
-        r.weight_group_size = getattr(target, "weight_group_size", 128)
         r._quantize_weights(names)
     return r
 

@@ -5,6 +5,21 @@ it twice under two module names)."""
 import os
 
 
+def assert_dequantized_equal(out, ref):
+    """`out` (a compiled quantized collective) against `ref` (its numpy
+    oracle): code * scale, element by element. The codes are integers
+    and agree exactly. The scale does not: XLA compiles `absmax / 127`
+    as a multiplication by the reciprocal, up to one unit in the last
+    place off numpy's division, and the product then rounds once more,
+    so the floats agree to about two units (measured: max rel 2e-7,
+    1.7-13 % of the elements). One code off would be a relative error
+    of at least 1 / (127 * 127 * shards), fifty times this bound, so
+    the comparison still pins every code."""
+    import numpy as np
+
+    np.testing.assert_allclose(out, ref, rtol=2.5e-7, atol=0)
+
+
 class StubPagedRunner:
     """A numpy paged-KV 'model' with the PagedModelRunner step interface.
 
@@ -32,6 +47,14 @@ class StubPagedRunner:
         # per-row decode_multi steps actually computed (ISSUE 11: the
         # early-stop saves-compute pin counts frozen rows' skipped work)
         self.counted_row_steps = 0
+
+    def recipe(self):
+        """PagedModelRunner.recipe(): the runner's half of a snapshot."""
+        return {"block_size": self.block_size,
+                "max_model_len": self.max_model_len,
+                "kv_dtype": getattr(self, "kv_dtype", "fp32"),
+                "weight_dtype": "fp32", "weight_group_size": 128,
+                "comm_dtype": "fp32"}
 
     def _logits(self, history):
         import numpy as np

@@ -24,6 +24,7 @@ engine's own naive oracle:
 
 import numpy as np
 import pytest
+from _helpers import assert_dequantized_equal
 
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
@@ -112,7 +113,7 @@ def test_quantized_psum_matches_numpy_oracle(tp, chunk):
     run = _psum_shard_map(mesh, quantized_psum, chunk=chunk)
     out = np.asarray(run(parts))
     ref = quantized_allreduce_reference(parts, chunk=chunk)
-    np.testing.assert_array_equal(out, ref)
+    assert_dequantized_equal(out, ref)
 
 
 @pytest.mark.parametrize("tp", [2, 4])

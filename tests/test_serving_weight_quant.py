@@ -27,6 +27,7 @@ against the engine's own quantized twin:
 
 import numpy as np
 import pytest
+from _helpers import assert_dequantized_equal
 
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
@@ -197,7 +198,7 @@ def test_quantized_allgather_matches_numpy_oracle(tp, chunk):
     out = np.asarray(_gather_shard_map(mesh, chunk)(parts))
     ref = quantized_allgather_reference(parts, chunk=chunk)
     assert out.shape == (3, 5, 24 * tp)
-    np.testing.assert_array_equal(out, ref)
+    assert_dequantized_equal(out, ref)
     # tiled in axis-index order, close to the exact concat (honest
     # pmax-shared scales never clip: error <= half a code step)
     exact = np.concatenate(parts, axis=-1)

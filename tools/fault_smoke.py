@@ -208,7 +208,7 @@ pages. Records add the proposed/accepted counters and acceptance rate.
 ISSUE 18: --speculate now composes with --decode-horizon / --pipelined
 — whenever a decode batch has no prefill chunks in flight, verify
 spans ride INSIDE the device-resident multi-step scan
-(engine._decode_spec_with_recovery -> runner.decode_multi_spec): accept
+(engine._launch_spec_horizon -> runner.decode_multi_spec): accept
 /reject happens on device, the corrected token feeds the next scan
 step, and ONE packed drain carries up to s*(k+1)-1 tokens per row.
 FaultInjector wraps the fused launch on the same decode op counter
