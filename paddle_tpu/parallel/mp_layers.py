@@ -11,6 +11,14 @@ collectives the reference writes by hand. Megatron sequence parallelism
 (fleet/utils/sequence_parallel_utils.py) is the `sequence_parallel=True`
 flag: activations outside the matmul pair are sharded on the sequence dim
 over 'tp', turning the allreduce into reduce_scatter + allgather.
+
+The rule of every constraint here: a layer says where 'tp' lies on ONE
+dimension of its activation (`"tp"`: sharded; `None`: whole on every tp
+rank, which is what makes GSPMD emit the all-reduce) and nothing about the
+others (`_tp_spec`). A `None` on the batch dimension would mean "replicated
+over every mesh axis", dp included: each data-parallel replica would be
+handed the whole batch after every layer, and cut back to its half by the
+next constraint that names dp.
 """
 
 from __future__ import annotations
@@ -27,6 +35,14 @@ from paddle_tpu.parallel.mesh import current_mesh
 def _tp_size() -> int:
     m = current_mesh()
     return m.shape.get("tp", 1) if m is not None else 1
+
+
+def _tp_spec(ndim: int, axis: int, placement) -> P:
+    """`placement` ("tp" or None) on dimension `axis`; every other dimension
+    is the partitioner's to place (the batch stays where the step put it)."""
+    spec = [P.UNCONSTRAINED] * ndim
+    spec[axis] = placement
+    return P(*spec)
 
 
 class ColumnParallelLinear(Layer):
@@ -49,12 +65,8 @@ class ColumnParallelLinear(Layer):
 
     def forward(self, x):
         out = F.linear(x, self.weight, self.bias)
-        if self.gather_output:
-            out = sharding_constraint(out, P(*([None] * out.ndim)))
-        else:
-            out = sharding_constraint(
-                out, P(*([None] * (out.ndim - 1) + ["tp"])))
-        return out
+        return sharding_constraint(out, _tp_spec(
+            out.ndim, -1, None if self.gather_output else "tp"))
 
 
 class RowParallelLinear(Layer):
@@ -77,10 +89,9 @@ class RowParallelLinear(Layer):
 
     def forward(self, x):
         if not self.input_is_parallel:
-            x = sharding_constraint(
-                x, P(*([None] * (x.ndim - 1) + ["tp"])))
+            x = sharding_constraint(x, _tp_spec(x.ndim, -1, "tp"))
         out = F.linear(x, self.weight, None)
-        out = sharding_constraint(out, P(*([None] * out.ndim)))
+        out = sharding_constraint(out, _tp_spec(out.ndim, -1, None))
         if self.bias is not None:
             out = out + self.bias
         return out
@@ -101,7 +112,7 @@ class VocabParallelEmbedding(Layer):
 
     def forward(self, x):
         out = F.embedding(x, self.weight)
-        return sharding_constraint(out, P(*([None] * out.ndim)))
+        return sharding_constraint(out, _tp_spec(out.ndim, -1, None))
 
 
 class ParallelCrossEntropy(Layer):
@@ -114,8 +125,7 @@ class ParallelCrossEntropy(Layer):
         self.ignore_index = ignore_index
 
     def forward(self, input, label):
-        input = sharding_constraint(
-            input, P(*([None] * (input.ndim - 1) + ["tp"])))
+        input = sharding_constraint(input, _tp_spec(input.ndim, -1, "tp"))
         return F.cross_entropy(input, label, reduction="none",
                                ignore_index=self.ignore_index)
 
@@ -129,9 +139,7 @@ class ScatterOp:
 
     @staticmethod
     def apply(x, axis=1):
-        spec = [None] * x.ndim
-        spec[axis] = "tp"
-        return sharding_constraint(x, P(*spec))
+        return sharding_constraint(x, _tp_spec(x.ndim, axis, "tp"))
 
 
 class GatherOp:
@@ -139,7 +147,7 @@ class GatherOp:
 
     @staticmethod
     def apply(x, axis=1):
-        return sharding_constraint(x, P(*([None] * x.ndim)))
+        return sharding_constraint(x, _tp_spec(x.ndim, axis, None))
 
 
 def mark_as_sequence_parallel_parameter(param):
