@@ -15,7 +15,9 @@ The device trace is on another clock (ProfileData's `start_ns` is neither
 the k-th `bench.engine_step` / `bench.train_step` of `ctx["trace"].host` and
 the k-th entry of `ctx["steps"]` inside the traced span. `Spans.align()`
 gives the median difference of their starts, and how far the widest pair
-lies from it.
+lies from it. run.py moves the traced span onto the trace's clock with it
+and cuts the table to that (`trace_reduce.clip`): what the readers here and
+under layer_metrics/ get in `ctx["trace"]` is the clipped table.
 
 A program without spans (an older commit) gives an empty ring: every reader
 then returns None, and the result line leaves its metric out.
@@ -23,7 +25,6 @@ then returns None, and the result line leaves its metric out.
 
 from __future__ import annotations
 
-import bisect
 import statistics
 
 import trace_reduce
@@ -148,65 +149,89 @@ class Spans:
         n = min(len(host), len(mine))
         if n == 0:
             return None
-        # the profiler's stop is the sharp edge: where the counts differ,
-        # the LAST n of each are the same calls
-        diffs = [h - m for h, m in zip(host[-n:], mine[-n:])]
-        offset = statistics.median(diffs)
-        return offset, max(abs(d - offset) for d in diffs), n
+        # one loop starts and stops the profiler between its steps, so both
+        # records hold the same calls; should either hold more, the pairing
+        # that agrees best is the one of the same calls (the last n, where
+        # several agree alike: the profiler's stop is the sharper edge)
+        best = None
+        for i in range(len(host) - n + 1):
+            for j in range(len(mine) - n + 1):
+                diffs = [h - m for h, m in zip(host[i:i + n], mine[j:j + n])]
+                offset = statistics.median(diffs)
+                residual = max(abs(d - offset) for d in diffs)
+                if best is None or residual <= best[1]:
+                    best = (offset, residual, n)
+        return best
 
-    # ------------------------------------------------------ device idle
+    # ------------------------------------------------------ device lead
 
-    def device_idle(self, root_name: str = "engine.step",
-                    wait_name: str = "engine.drain"):
-        """Per traced step, the device's idle nanoseconds inside the step
-        as (in a `wait_name` span, outside one); and the idle time of the
-        traced part that lies in no step at all. None without a device
-        trace or anchors."""
+    def device_lead_ms(self, root_name: str = "engine.step",
+                       launch_name: str = "runner.launch") -> list:
+        """Per traced step, how long after the host entered its first
+        `launch_name` span the step's first program began on the device, in
+        ms. No program begins before the host enters the launch that
+        dispatches it, so a reading under 0 says that this trace's device
+        plane lies early on its host plane (the anchors tie only the HOST
+        plane to the bench's clock; PERF.md, PR 33: about 1 ms in some
+        sessions). Empty without a device trace or anchors."""
         tr, al = self.ctx.get("trace"), self.align()
-        if tr is None or not tr.ops or al is None:
-            return None
-        offset = al[0]
-        ivs = trace_reduce.merged(tr.ops[min(tr.ops)])
-        starts = [a for a, _ in ivs]
-        cum = [0]
-        for a, b in ivs:
-            cum.append(cum[-1] + b - a)
-
-        def busy(a, b):
-            """Nanoseconds of [a, b) in which an operation ran."""
-            if b <= a:
-                return 0
-            i = max(bisect.bisect_right(starts, a) - 1, 0)
-            j = bisect.bisect_left(starts, b)
-            total = cum[j] - cum[i]
-            if i < j:
-                total -= min(max(a - ivs[i][0], 0), ivs[i][1] - ivs[i][0])
-                total -= max(ivs[j - 1][1] - b, 0)
-            return max(total, 0)
-
-        steps = self.steps(root_name)
-        waits = {s[SID]: [] for s in steps}
+        if tr is None or not tr.modules or al is None:
+            return []
+        runs = trace_reduce.whole_runs(tr, lambda name: True, min(tr.modules))
+        launched = {}
         for s in self.traced:
-            if s[NAME] == wait_name and self.root(s)[SID] in waits:
-                waits[self.root(s)[SID]].append(s)
-        out, in_steps = [], 0
-        for st in steps:
-            a, b = st[T0] + offset, st[T1] + offset
-            idle = (b - a) - busy(a, b)
-            inside = sum((w[T1] - w[T0]) - busy(w[T0] + offset,
-                                                w[T1] + offset)
-                         for w in waits[st[SID]])
-            out.append((inside, idle - inside))
-            in_steps += idle
-        total_idle = tr.window_s * 1e9 - trace_reduce.union_ns(
-            tr.ops[min(tr.ops)])
-        return out, total_idle - in_steps, total_idle
+            if s[NAME] == launch_name:
+                top = self.root(s)[SID]
+                launched[top] = min(launched.get(top, s[T0]), s[T0])
+        steps = [st for st in self.steps(root_name) if st[SID] in launched]
+        held = runs_held(runs, [(st[T0] + al[0], st[T1] + al[0])
+                                for st in steps])
+        return [(mine[0][0] - launched[st[SID]] - al[0]) / 1e6
+                for st, mine in zip(steps, held) if mine]
+
+
+def runs_held(runs, intervals) -> list:
+    """For each of the ordered, disjoint `intervals` (ns on the trace's
+    clock), the `runs` (trace_reduce.whole_runs, in order) whose middle lies
+    inside it."""
+    out, j = [], 0
+    for a, b in intervals:
+        while j < len(runs) and runs[j][0] + runs[j][1] / 2 < a:
+            j += 1
+        k = j
+        while k < len(runs) and runs[k][0] + runs[k][1] / 2 < b:
+            k += 1
+        out.append(runs[j:k])
+        j = k
+    return out
 
 
 def of(ctx) -> Spans:
     if "_program_spans" not in ctx:
         ctx["_program_spans"] = Spans(ctx)
     return ctx["_program_spans"]
+
+
+def steps_with_whole_runs(ctx, match) -> tuple:
+    """(records, table): the bench's own step records (`ctx["steps"]`) that
+    lie inside the traced span and hold a whole run of a program `match`
+    accepts (first device; a run is a record's where its middle lies inside
+    the record, on the trace's clock), and `ctx["trace"]` with only the
+    operations of those runs. A reader sets the table's kernel seconds
+    against the work of the records: both of the same whole steps, whatever
+    the span cut at its edges. Empty without a device trace or anchors."""
+    tr, al, span = ctx.get("trace"), of(ctx).align(), ctx.get("trace_span")
+    if tr is None or not tr.modules or al is None:
+        return [], trace_reduce.Trace()
+    dev = min(tr.modules)
+    inside = [rec for rec in ctx["steps"]
+              if rec[0] >= span[0] and rec[1] <= span[1]]
+    held = runs_held(trace_reduce.whole_runs(tr, match, dev),
+                     [(rec[0] * 1e9 + al[0], rec[1] * 1e9 + al[0])
+                      for rec in inside])
+    records = [rec for rec, mine in zip(inside, held) if mine]
+    ops = [e for mine in held for _, _, evs in mine for e in evs]
+    return records, trace_reduce.Trace(ops={dev: ops}, span=tr.span)
 
 
 def median_ms(ctx, root_name: str, names, own: bool = False):
@@ -225,7 +250,7 @@ def report(ctx, root_name: str) -> None:
     """One line on stdout, before the result line, with what the consistency
     of a traced run is judged by (PERF.md quotes it): the root spans against
     the bench's own step records, the parts against the whole, the anchors'
-    residual, and where the device's idle time lies."""
+    residual, and how long after the host's launch the device begins."""
     sp, med = of(ctx), ctx["median"]
     steps = sp.steps(root_name)
     if not steps or ctx.get("_program_spans_said"):
@@ -248,11 +273,7 @@ def report(ctx, root_name: str) -> None:
                 if s[0] >= span[0] and s[1] <= span[1]]
         line.update(bench_p50_ms=med(mine), anchor_offset_ns=al[0],
                     anchor_residual_us=al[1] / 1e3, anchors=al[2])
-    idle = sp.device_idle() if root_name == "engine.step" else None
-    if idle:
-        per_step, no_span, total = idle
-        line.update(idle_in_drain_s=sum(i for i, _ in per_step) / 1e9,
-                    idle_outside_drain_s=sum(o for _, o in per_step) / 1e9,
-                    idle_under_no_span_s=no_span / 1e9,
-                    idle_total_s=total / 1e9)
+    lead = sp.device_lead_ms() if root_name == "engine.step" else None
+    if lead:
+        line.update(device_lead_p50_ms=med(lead), device_lead_min_ms=min(lead))
     print(f"[program_spans] {root_name}: {line}", flush=True)

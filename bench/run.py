@@ -75,6 +75,9 @@ class Run:
         self.trace_dir = None
         self.trace_span = None     # (start, end) of the traced part
         self.trace_requested = None  # when the bench asked for the profiler
+        # seconds of the window inside start_trace and stop_trace: a reader
+        # that takes a rate from a traced run leaves them out
+        self.profiler_stall_s = 0.0
         self.checks = []           # (name, value, limit, ok)
 
     # ------------------------------------------------------------ device
@@ -148,21 +151,22 @@ class Run:
 
     def tick(self):
         """Called between steps. A traced run has the profiler on for
-        TRACE_SECONDS. By default those are the window's LAST, and it is
-        stopped after the window has closed: starting and stopping it stall
-        the host for seconds, which inside an open-loop window would build
-        a backlog no user sent. A traffic file whose work changes through
-        the window (a closed loop whose contexts grow) asks for the
-        MIDDLE with `"trace_at": "middle"`: the stall then only pauses its
+        TRACE_SECONDS. By default those are the window's LAST: this only
+        ever STARTS the profiler then, and `close_window()` stops it, once
+        the driver has drained the device and read its clock for the run's
+        own rate, so that neither a step in flight nor the seconds
+        `stop_trace` takes are inside the window. A traffic file whose work
+        changes through the window (a closed loop whose contexts grow) asks
+        for the MIDDLE with `"trace_at": "middle"`: there the first tick
+        TRACE_SECONDS past the start stops it, the stall only pauses the
         loop, and the slice stands for the whole window."""
         if not self.trace or self.trace_span is not None:
             return
         now = time.perf_counter()
-        start = self.seconds - TRACE_SECONDS
-        if self.traffic.get("trace_at") == "middle":
-            start /= 2
+        middle = self.traffic.get("trace_at") == "middle"
+        start = (self.seconds - TRACE_SECONDS) / (2 if middle else 1)
         if self.trace_dir is not None:
-            if now - self.trace_t0 >= TRACE_SECONDS:
+            if middle and now - self.trace_t0 >= TRACE_SECONDS:
                 self.stop_trace()
         elif now - self.t_open >= start:
             import jax.profiler
@@ -176,31 +180,52 @@ class Run:
             jax.profiler.start_trace(self.trace_dir, profiler_options=opts)
             self.trace_requested = now
             self.trace_t0 = time.perf_counter()
+            self.profiler_stall_s += self.trace_t0 - now
 
     def stop_trace(self):
+        """The traced span is [trace_t0, t1] on the bench's clock: after
+        `start_trace` returned, before `stop_trace` is called."""
         import jax.profiler
 
         t1 = time.perf_counter()
         jax.profiler.stop_trace()
         self.trace_span = (self.trace_t0, t1)
+        if self.counting:           # a middle trace: the window is open
+            self.profiler_stall_s += time.perf_counter() - t1
 
     def close_window(self):
+        """The driver calls this with the device drained (train.py blocks on
+        its last step; every `engine.step()` of serve.py ends in the
+        engine's own drain)."""
         self.counting = False
         if self.trace_dir is not None and self.trace_span is None:
             self.stop_trace()
         gc.unfreeze()
 
-    def reduced_trace(self):
-        if self.trace_dir is None:
-            return None
+    def reduced_trace(self, ctx):
+        """The profiler's table cut to the traced span, which the anchors
+        (program_spans) move from the bench's clock onto the trace's: one
+        span on one clock for `busy_s`, `window_s` and every reader. Device
+        events with no anchor have no clock to stand on: no result."""
+        import program_spans
         import trace_reduce
 
+        if self.trace_dir is None:
+            raise SystemExit("the window closed before the profiler was "
+                             "due to start: no result")
         paths = glob.glob(os.path.join(self.trace_dir, "**", "*.xplane.pb"),
                           recursive=True)
-        tr = trace_reduce.load(paths[0], len(self.devices),
-                               self.trace_span[1] - self.trace_span[0])
+        ctx["trace"] = raw = trace_reduce.load(paths[0], len(self.devices))
         shutil.rmtree(self.trace_dir, ignore_errors=True)
-        return tr
+        anchors = program_spans.of(ctx).align()
+        if raw.ops and anchors is None:
+            raise SystemExit(
+                "the trace holds device events but none of the bench's own "
+                "steps (no bench.* anchor inside the traced span), so the "
+                "span cannot be moved onto the trace's clock: no result")
+        offset = anchors[0] if anchors else 0
+        lo, hi = (round(t * 1e9 + offset) for t in self.trace_span)
+        return trace_reduce.clip(raw, lo, hi)
 
     # ----------------------------------------------------------- results
 
@@ -259,10 +284,12 @@ class Run:
         if self.trace:
             import trace_reduce
 
-            tr = ctx["trace"] = self.reduced_trace()
             ctx.update(e2e=e2e, config=self.model_cfg(),
                        traffic=self.traffic, peaks=self.peaks,
-                       chips=len(self.devices))
+                       chips=len(self.devices), trace_span=self.trace_span,
+                       trace_requested=self.trace_requested,
+                       profiler_stall_s=self.profiler_stall_s)
+            tr = ctx["trace"] = self.reduced_trace(ctx)
             out["metrics"] = self.layer_metrics(ctx)
             device["busy_s"] = tr.busy_s
             device["window_s"] = tr.window_s
@@ -272,10 +299,13 @@ class Run:
                               for k, v in e2e.items()}
         out["device"] = device
         out["compiles_in_window"] = self.compiles
-        out["checks"] = [{"name": n, "value": v, "limit": l, "ok": ok}
-                         for n, v, l, ok in self.checks]
+        if self.trace:
+            out["profiler_stall_s"] = self.profiler_stall_s
         out["workload"], out["seed"] = self.cell["name"], self.seed
         out["probe"] = self.probe
+        # every number compared beside its limit: the line's last key
+        out["checks"] = [{"name": n, "value": v, "limit": l, "ok": ok}
+                         for n, v, l, ok in self.checks]
         return out
 
 
@@ -321,6 +351,9 @@ def main(argv=None) -> int:
     say(f"compile cache: {place_compile_cache()}")
     result = run_cell(run)
     print(json.dumps(result), flush=True)
+    for c in result["checks"]:      # and the last lines of standard error
+        print(f"check {c['name']} {c['value']:.6g} limit {c['limit']:.6g} "
+              f"{'ok' if c['ok'] else 'NOT CORRECT'}", file=sys.stderr)
     return 0
 
 

@@ -420,7 +420,6 @@ def drive(run) -> dict:
         lives = [lv for lv in lives
                  if lv.due is None or lv.due < run.trace_requested - 1.0]
     ctx = {"steps": steps, "lives": lives, "counters": counters,
-           "trace_span": run.trace_span,
            "t_close": steps[-1][1] if steps else None,
            "percentile": percentile, "median": statistics.median}
 

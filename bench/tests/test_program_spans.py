@@ -1,10 +1,9 @@
 """The readers built on the program's spans (ISSUE 25): the anchor alignment
-and the device's idle split on the recorded trace plus hand-made spans with
-a known answer, and every new reader on traced CPU toy runs of each traffic
-kind (None where its spans are absent)."""
-import importlib.util
+on the recorded trace, hand-made spans with a known answer (the readers, and
+how long after the host's launch the device begins: ISSUE 33), and every
+reader on traced CPU toy runs of each traffic kind (None where its spans are
+absent)."""
 import json
-import os
 import statistics
 
 import pytest
@@ -13,22 +12,13 @@ import program_spans as ps
 import run as R
 import trace_reduce as tr
 from test_run_cpu import TOY
+from test_trace_reduce import reader, recorded_table
 
-HERE = os.path.dirname(os.path.abspath(__file__))
 SERVE = ["engine_plan_ms", "runner_launch_ms", "engine_drain_wait_ms",
          "engine_commit_ms"]
 SETUP = ["setup_import_s", "setup_build_s", "setup_compile_s"]
-NEW = SERVE + ["device_idle_outside_drain_ms", "train_host_ms_per_step"] \
-    + SETUP
+NEW = SERVE + ["train_host_ms_per_step"] + SETUP
 OFFSET = 7_000_000_123            # trace clock = bench clock + OFFSET (ns)
-
-
-def reader(name):
-    path = os.path.join(R.BENCH, "layer_metrics", name + ".py")
-    spec = importlib.util.spec_from_file_location("lm_" + name, path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
 
 
 def span(name, t0, t1, sid, parent=None, step=None):
@@ -48,17 +38,23 @@ def ctx_for(trace, steps_trace_ns, jitter=(0, 0, 0)):
 
 
 def tiny():
-    """Device busy [0,100) and [200,300) and [1000,1100); two steps."""
-    trace = tr.Trace(window_s=1200e-9, ops={0: [
-        ("a", 0, 100), ("b", 200, 100), ("c", 1000, 100)]},
-        host=[("bench.engine_step", 0, 400), ("bench.engine_step", 500, 700)])
+    """Two engine steps, [0,400) and [500,1200) on the trace's clock, a
+    decode run in each."""
+    trace = tr.clip(tr.Trace(
+        ops={0: [("a", 30, 70), ("b", 200, 100), ("c", 1000, 100)]},
+        modules={0: [("feed", -100, 10), ("_decode_step", 30, 270),
+                     ("_decode_step", 1000, 100), ("feed", 1150, 10)]},
+        host=[("bench.engine_step", 0, 400),
+              ("bench.engine_step", 500, 700)]), 0, 1200)
     ctx = ctx_for(trace, [(0, 400), (500, 1200)])
     b = lambda t: t - OFFSET                    # trace -> bench clock
     ring = [
         span("engine.plan", b(0), b(20), 2, 1, 1),
+        span("runner.launch", b(20), b(60), 7, 1, 1),
         span("engine.drain", b(150), b(350), 4, 3, 1),
         span("engine.commit", b(150), b(390), 3, 1, 1),
         span("engine.step", b(0), b(400), 1, None, 1),
+        span("runner.launch", b(520), b(560), 8, 5, 2),
         span("engine.drain", b(600), b(1150), 6, 5, 2),
         span("engine.step", b(500), b(1200), 5, None, 2),
     ]
@@ -66,26 +62,31 @@ def tiny():
     return ctx
 
 
-def test_idle_split_by_hand():
+def test_readers_by_hand():
     ctx = tiny()
     sp = ps.of(ctx)
     offset, residual, n = sp.align()
     assert (offset, residual, n) == (OFFSET, 0, 2)
-    per_step, no_span, total = sp.device_idle()
-    # step 1 [0,400): idle 100..200 and 300..400 = 200; its drain
-    # [150,350) holds 150..200 and 300..350 = 100 of it
-    # step 2 [500,1200): idle 500..1000 and 1100..1200 = 600; its drain
-    # [600,1150) holds 600..1000 and 1100..1150 = 450
-    assert per_step == [(100, 100), (450, 150)]
-    # the trace's window is 1200 ns with 300 busy; 400..500 is in no step
-    assert (total, no_span) == (900, 100)
-    assert reader("device_idle_outside_drain_ms")(ctx) == \
-        pytest.approx(125e-6)
     assert reader("engine_drain_wait_ms")(ctx) == pytest.approx(375e-6)
     assert reader("engine_plan_ms")(ctx) == pytest.approx(10e-6)
+    assert reader("runner_launch_ms")(ctx) == pytest.approx(40e-6)
     # commit 240 less the drain 200 inside it; none in step 2
     assert reader("engine_commit_ms")(ctx) == pytest.approx(20e-6)
-    assert sp.self_ms("engine.step") == pytest.approx([140e-6, 150e-6])
+    assert sp.self_ms("engine.step") == pytest.approx([100e-6, 110e-6])
+    # the decode run begins 10 ns after the host entered step 1's launch
+    # and 480 ns after step 2's
+    assert sp.device_lead_ms() == pytest.approx([10e-6, 480e-6])
+
+
+def test_a_device_plane_that_lies_early_shows_as_a_negative_lead():
+    ctx = tiny()
+    early = ctx["trace"]
+    shift = lambda evs: [(n, t0 - 25, d) for n, t0, d in evs]
+    ctx["trace"] = tr.clip(tr.Trace(
+        ops={0: shift(early.ops[0])}, modules={0: shift(early.modules[0])},
+        host=early.host), 0, 1200)
+    assert ps.Spans(ctx, ps.of(ctx).all).device_lead_ms() == pytest.approx(
+        [-15e-6, 455e-6])
 
 
 def test_anchors_take_the_last_calls_where_the_counts_differ():
@@ -100,50 +101,30 @@ def test_anchors_take_the_last_calls_where_the_counts_differ():
 
 @pytest.fixture(scope="module")
 def recorded():
-    with open(os.path.join(HERE, "data", "trace_events.json")) as f:
-        table = json.load(f)
-    as_ev = lambda evs: [(n, int(a), int(b)) for n, a, b in evs]
-    return tr.Trace(window_s=table["window_s"],
-                    ops={int(d): as_ev(e) for d, e in table["ops"].items()},
-                    modules={int(d): as_ev(e)
-                             for d, e in table["modules"].items()},
-                    host=as_ev(table["host"]))
+    raw = recorded_table()
+    # a span that holds all three steps, as ctx_for() draws it
+    return tr.clip(raw, raw.host[0][1] - 1_000_000,
+                   raw.host[-1][1] + raw.host[-1][2] + 1_000_000)
 
 
-def test_alignment_and_idle_on_the_recorded_trace(recorded):
+def test_alignment_on_the_recorded_trace(recorded):
     host = [(t0, t0 + dur) for _, t0, dur in recorded.host]
     jitter = (0, 4_000, -6_000)                 # ns, on the bench's stamps
     ctx = ctx_for(recorded, host, jitter)
-    ring, sid = [], 0
-    drains = []
-    for k, (a, b) in enumerate(host):
-        # a drain from 1 ms into the step until 2 ms before its end
-        sid += 2
-        d0, d1 = a + 1_000_000, b - 2_000_000
-        drains.append((d0, d1))
-        ring.append(span("engine.drain", d0 - OFFSET, d1 - OFFSET, sid,
-                         sid - 1, k))
-        ring.append(span("engine.step", a - OFFSET + 1_000, b - OFFSET,
-                         sid - 1, None, k))
+    ring = [span("engine.step", a - OFFSET + 1_000, b - OFFSET, k + 1, None, k)
+            for k, (a, b) in enumerate(host)]
     sp = ps.Spans(ctx, ring)
     offset, residual, n = sp.align()
     assert n == 3 and offset == OFFSET - 0      # the median pair's jitter
     assert residual == 6_000
-    per_step, no_span, total = sp.device_idle()
-    ivs = tr.merged(recorded.ops[0])
-
-    def idle(a, b):                             # the slow way
-        return (b - a) - sum(max(0, min(y, b) - max(x, a)) for x, y in ivs)
-
-    for (inside, outside), (a, b), (d0, d1) in zip(per_step, host, drains):
-        assert inside == idle(d0, d1)
-        assert inside + outside == idle(a + 1_000, b)
-    # each recorded step leaves the device idle for its last 2.9 ms or so
-    # (trace_events.json); the drain as drawn here ends 2 ms before the
-    # step does, so about 2 ms of that idle lie outside it
-    assert all(1.5e6 < outside < 4e6 for _, outside in per_step[:2])
-    assert total == pytest.approx(
-        recorded.window_s * 1e9 - tr.union_ns(recorded.ops[0]))
+    # of its three decode runs the middle one is whole, and it is the
+    # second record's
+    records, table = ps.steps_with_whole_runs(ctx, lambda n: "decode" in n)
+    assert records == [ctx["steps"][1]]
+    assert tr.op_seconds(table, tr.is_kernel) == \
+        pytest.approx(tr.op_seconds(tr.inside_whole_runs(
+            recorded, lambda n: "decode" in n)[0],
+            tr.is_kernel))
 
 
 # ------------------------------------------------------------ CPU toy runs
@@ -183,8 +164,6 @@ def run_traced(workload, seconds=6.0):
 def test_serving_readers_on_a_cpu_toy(workload, capsys):
     got = run_traced(workload)
     assert set(SERVE + SETUP) <= set(got)
-    # no device trace on the CPU: nothing to read, and the line leaves it out
-    assert "device_idle_outside_drain_ms" not in got
     assert "train_host_ms_per_step" not in got
     assert all(got[n]["value"] >= 0 for n in SERVE + SETUP)
     if workload == "toy.decode":

@@ -214,5 +214,5 @@ def _drive(run) -> dict:
     correct = ok and failed == 0 and run.compiles == 0
     ctx = {"memory_peak_bytes": run.memory_peak_bytes(), "steps": spans,
            "batch": batch, "seq": seq, "median": statistics.median,
-           "trace_span": run.trace_span}
+           "t_open": t_open}
     return run.result(correct, n, failed, e2e, ctx)
