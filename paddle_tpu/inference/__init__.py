@@ -162,7 +162,7 @@ def create_predictor(config: Config) -> Predictor:
 
 def create_serving_engine(model, dtype=None, **kw):
     """Build a continuous-batching ServingEngine for a decoder Layer
-    (Llama, GPT, DeepseekV3ForCausalLM).
+    (Llama, GPT, DeepseekV3ForCausalLM, OlmoHybridForCausalLM).
 
     The serving-path analogue of create_predictor: where the reference
     pairs fluid/inference with block_multihead_attention and a serving

@@ -10,6 +10,9 @@ from paddle_tpu.models.ernie import (  # noqa: F401
     ErnieForTokenClassification, ErnieModel, ernie_pretrain_loss_fn,
     mask_tokens,
 )
+from paddle_tpu.models.olmo_hybrid import (  # noqa: F401
+    OlmoHybridConfig, OlmoHybridForCausalLM,
+)
 from paddle_tpu.models.llama import (  # noqa: F401
     Llama, LlamaConfig, llama_loss_fn,
 )

@@ -489,7 +489,10 @@ class ServingEngine:
         self.kv_dtype = recipe["kv_dtype"]
         self.pool = KVCachePool.for_runner(
             runner, self.num_blocks, mesh=self.mesh,
-            model_axis=getattr(runner, "model_axis", "model"))
+            model_axis=getattr(runner, "model_axis", "model"),
+            state_slots=self.max_batch_size + 1)
+        if self.pool.state_layers:
+            self._refuse_state_copies(kv_store)
         if self.enable_prefix_cache:
             self.pool.enable_prefix_cache()
         # handoff buffer: requests a prefill-role engine has finished
@@ -590,6 +593,32 @@ class ServingEngine:
             "decode", self._decode_rows,
             lambda ops, _: self.runner.decode(*ops, self.pool.pools),
             greedy_grid, self._commit_logits)
+
+    def _refuse_state_copies(self, kv_store) -> None:
+        """A runner with recurrent state (`state_layout`) keeps, beside a
+        request's pages, a state that only ever moves forward at the
+        request's decode slot. The options below need a COPY of it (a
+        shared prefix, a spill to the host, a handoff) or a ROLLBACK
+        (rejected drafts), or feed several rows of several sequences to
+        one launch; none of that is built, and pages without their state
+        would serve wrong tokens in silence. Refused by name, here."""
+        asked = {
+            "enable_prefix_cache": self.enable_prefix_cache,
+            "host_tier_pages": self.host_tier_pages,
+            "kv_store": kv_store is not None,
+            "num_speculative_tokens": self.num_speculative_tokens,
+            "ragged_batch": self.ragged_batch,
+            "role": self.role != "mixed",
+        }
+        for name, on in asked.items():
+            if on:
+                raise ValueError(
+                    f"{name} is not built for a runner with recurrent "
+                    f"state ({type(self.runner).__name__}): a state slot "
+                    "cannot be shared, spilled, handed off or rolled back "
+                    "yet, and its pages alone would serve wrong tokens. "
+                    "Supported: max_prefill_tokens_per_step, "
+                    "decode_horizon, horizon_early_stop, pipelined")
 
     # ----------------------------------------------------------- intake
 
@@ -1049,6 +1078,9 @@ class ServingEngine:
         self.metrics.running.set(len(self.scheduler.running))
         self.metrics.pool_used_pages.set(a.num_usable - a.num_free)
         self.metrics.pool_utilization.set(self.pool.utilization())
+        if self.pool.state_layers:
+            # a running request holds its slot, and so its state
+            self.metrics.state_slots_live.set(len(self.scheduler.running))
         if self.pool.prefix_cache is not None:
             self.metrics.prefix_cached_pages.set(len(self.pool.prefix_cache))
         tier = self.pool.host_tier
@@ -1221,9 +1253,12 @@ class ServingEngine:
                 self.metrics.cow_copies.inc(cow)
             table = self.pool.pad_table(req.kv.pages, self.max_pages_per_seq)
             ops = (req.context_tokens[start:end], start, table)
+            # recurrent state lives at the request's decode slot
+            at = {"slot": req.slot} if self.pool.state_layers else {}
         out = self._call_retrying(
             lambda: None if req.done else ops,
-            lambda ops: self.runner.prefill_chunk(*ops, self.pool.pools),
+            lambda ops: self.runner.prefill_chunk(*ops, self.pool.pools,
+                                                  **at),
             lambda _: req)
         if out is None:
             return None

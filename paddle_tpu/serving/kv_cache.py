@@ -1181,7 +1181,7 @@ class HostKVTier:
             self._bufs: List[Tuple[np.ndarray, ...]] = [
                 tuple(np.zeros((self.max_pages,) + tuple(a.shape[1:]),
                                np.dtype(str(a.dtype))) for a in layer)
-                for layer in pool.pools]
+                for layer in pool.page_pools]
             self._free: List[int] = list(range(self.max_pages))  # asc.
             self._hash: Dict[int, int] = {}   # slot -> content hash
             self._gen: Dict[int, int] = {}    # slot -> reuse generation
@@ -1213,7 +1213,7 @@ class HostKVTier:
         the same segments would corrupt every sibling. Loud, at attach
         time."""
         want = [tuple((tuple(a.shape[1:]), str(np.dtype(str(a.dtype))))
-                      for a in layer) for layer in pool.pools]
+                      for a in layer) for layer in pool.page_pools]
         have = [tuple((tuple(shape), str(np.dtype(dt)))
                       for shape, dt in layer) for layer in store.layout]
         if want != have:
@@ -1867,14 +1867,24 @@ class KVCachePool:
                  n_kv_heads: Optional[int] = None,
                  head_dim: Optional[int] = None, dtype=jnp.float32,
                  mesh=None, model_axis: str = "model",
-                 kv_dtype: str = "fp32", page_layout=None):
+                 kv_dtype: str = "fp32", page_layout=None,
+                 state_layout=None, state_slots: int = 0):
         """`page_layout` is the runner's word on what a layer's page
         holds: a list of `(trailing shape, dtype)`, one per array, the
         same for every layer; each array is `[num_blocks, block_size,
         *trailing]`. Left out, it is the (k, v) pair of `[n_kv_heads,
         head_dim]` (`kv_pair_layout`). `page_arrays` says what the pool
         stores for a layout in the rung `kv_dtype` names, and which
-        layouts come in which rungs."""
+        layouts come in which rungs.
+
+        `state_layout` is its word on the layers that keep a fixed
+        RECURRENT STATE per sequence and no pages: `(layers, [(trailing
+        shape, dtype), ...])`; each array is `[state_slots, *trailing]`,
+        indexed by the decode slot the scheduler hands a request (the
+        engine asks for `max_batch_size + 1`: the last is scratch, which
+        no request holds). `num_layers` then counts the layers that page.
+        With states, `pools` (what the runner's steps take and return) is
+        the pair `(pages, states)`."""
         self.num_layers = num_layers
         self.num_blocks = num_blocks
         self.block_size = block_size
@@ -1920,21 +1930,60 @@ class KVCachePool:
             def zeros(shp, dt, spec):
                 return jnp.zeros(shp, dt)
 
+        self.state_layers, self.state_arrays = 0, []
+        if state_layout is not None:
+            if mesh is not None:
+                raise ValueError("recurrent state over a mesh is not built")
+            self.state_layers = int(state_layout[0])
+            self.state_arrays = [(tuple(int(n) for n in t), jnp.dtype(d))
+                                 for t, d in state_layout[1]]
+        self.state_slots = int(state_slots) if self.state_layers else 0
         # the pages themselves: zero fills dispatched here, which finish
         # on the device behind whatever comes next
         with _prof.always_span("kv_pool.alloc", num_blocks=num_blocks):
-            self.pools = [tuple(zeros(*a) for a in layer)
-                          for _ in range(num_layers)]
+            self.page_pools = [tuple(zeros(*a) for a in layer)
+                               for _ in range(num_layers)]
+            self.state_pools = []
+            if self.state_layers:
+                with _prof.always_span("state_pool.alloc",
+                                       slots=self.state_slots):
+                    self.state_pools = [
+                        tuple(jnp.zeros((self.state_slots,) + t, d)
+                              for t, d in self.state_arrays)
+                        for _ in range(self.state_layers)]
+
+    @property
+    def pools(self):
+        """What the runner's steps take and return: the layers' page
+        arrays, or with recurrent state the pair (pages, states)."""
+        if self.state_layers:
+            return (self.page_pools, self.state_pools)
+        return self.page_pools
+
+    @pools.setter
+    def pools(self, value):
+        if self.state_layers:
+            pages, states = value
+            self.page_pools, self.state_pools = list(pages), list(states)
+        else:
+            self.page_pools = value
 
     @classmethod
     def for_runner(cls, runner, num_blocks: int, mesh=None,
-                   model_axis: str = "model") -> "KVCachePool":
+                   model_axis: str = "model",
+                   state_slots: int = 2) -> "KVCachePool":
         """The pool a runner's steps read and write: the geometry is the
-        runner's (the page layout it names, in its kv_dtype rung)."""
-        return cls(runner.num_layers, num_blocks, runner.block_size,
+        runner's (the page layout it names, in its kv_dtype rung; where
+        it names a state layout too, `state_slots` slots of it for the
+        layers that keep a state, and pages for the others only)."""
+        ask = getattr(runner, "state_layout", None)
+        states = ask() if ask is not None else None
+        return cls(runner.num_layers - (states[0] if states else 0),
+                   num_blocks, runner.block_size,
                    dtype=runner.dtype, mesh=mesh, model_axis=model_axis,
                    kv_dtype=getattr(runner, "kv_dtype", "fp32"),
-                   page_layout=_page_layout_of(runner))
+                   page_layout=_page_layout_of(runner),
+                   state_layout=states, state_slots=state_slots)
 
     # -------------------------------- per-request kv-dtype tags (ISSUE 15)
 
@@ -1967,8 +2016,8 @@ class KVCachePool:
         if self.kv_dtype == "mixed":
             idx = jnp.asarray(list(pages), jnp.int32)
             flag = tag == "fp8"
-            self.pools = [(k, v, t.at[idx].set(flag))
-                          for (k, v, t) in self.pools]
+            self.page_pools = [(k, v, t.at[idx].set(flag))
+                               for (k, v, t) in self.page_pools]
 
     def page_tag(self, page: int) -> Optional[str]:
         return self.allocator._tags.get(page)
@@ -2011,7 +2060,7 @@ class KVCachePool:
         thread can np.asarray these at leisure even after the pages are
         freed and reused (the threaded-spill foundation, ISSUE 11)."""
         idx = jnp.asarray(list(pages), jnp.int32)
-        return [tuple(a[idx] for a in layer) for layer in self.pools]
+        return [tuple(a[idx] for a in layer) for layer in self.page_pools]
 
     def read_pages(self, pages: Sequence[int]
                    ) -> List[Tuple[np.ndarray, ...]]:
@@ -2031,10 +2080,10 @@ class KVCachePool:
         write: jax dispatches the scatters asynchronously, so the call
         itself never blocks."""
         idx = jnp.asarray(list(pages), jnp.int32)
-        self.pools = [
+        self.page_pools = [
             tuple(a.at[idx].set(jnp.asarray(d).astype(a.dtype))
                   for a, d in zip(layer, data))
-            for layer, data in zip(self.pools, layer_data)]
+            for layer, data in zip(self.page_pools, layer_data)]
 
     def blocks_for_tokens(self, n_tokens: int) -> int:
         """Pages needed to hold n_tokens KV entries."""
@@ -2054,8 +2103,8 @@ class KVCachePool:
         blocks: on an int8 pool the layer tuples carry the scale pools
         too ([num_blocks, n_kv] — page-indexed like the code pools), so
         a fork carries its source's quantization state verbatim."""
-        self.pools = [tuple(a.at[dst].set(a[src]) for a in layer)
-                      for layer in self.pools]
+        self.page_pools = [tuple(a.at[dst].set(a[src]) for a in layer)
+                           for layer in self.page_pools]
 
     def utilization(self) -> float:
         a = self.allocator
@@ -2089,8 +2138,14 @@ class KVCachePool:
         """Total logical pool bytes across the whole mesh (the single-
         device number — sharding never changes it). Counts what the
         pools actually store: int8 code bytes + scale bytes on a
-        quantized pool."""
+        quantized pool. Recurrent state is counted by `state_bytes`."""
         return self.num_blocks * self.page_bytes()
+
+    def state_bytes(self) -> int:
+        """Bytes of the recurrent-state arrays, every slot and layer (0
+        where every layer pages)."""
+        return self.state_slots * self.state_layers * sum(
+            int(np.prod(t)) * d.itemsize for t, d in self.state_arrays)
 
     def per_shard_memory_bytes(self) -> int:
         """Pool bytes ONE model shard holds: total / tp (each shard

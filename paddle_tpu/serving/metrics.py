@@ -99,6 +99,8 @@ SUMMABLE_KEYS = (
     "nan_logit_events", "shed_requests", "tokens_generated",
     "moe_tokens_routed", "moe_local_pairs", "moe_experts_touched",
     "latent_copy_groups", "latent_run_groups",
+    "delta_decode_seq_steps", "delta_prefill_tokens",
+    "delta_prefill_positions", "state_slot_resets",
     "prefill_tokens", "prefill_chunks", "prefix_hit_tokens", "cow_copies",
     "prefix_cached_pages", "attn_kv_bytes_read", "attn_kv_bytes_gather",
     "tp_comm_bytes", "tp_comm_bytes_fp32",
@@ -196,6 +198,17 @@ class EngineMetrics:
         # consecutive in the pool, copied as ONE copy
         self.latent_copy_groups = Counter("latent_copy_groups")
         self.latent_run_groups = Counter("latent_run_groups")
+        # recurrent state (a runner whose linear layers keep a state slot
+        # a sequence), read the same way: live rows x linear layers a
+        # decode step advanced, real prompt tokens through the chunked
+        # form and the positions it computed (padding and bucket
+        # included), slots a prefill reset; and, set by the engine, the
+        # slots that hold a running request's state
+        self.delta_decode_seq_steps = Counter("delta_decode_seq_steps")
+        self.delta_prefill_tokens = Counter("delta_prefill_tokens")
+        self.delta_prefill_positions = Counter("delta_prefill_positions")
+        self.state_slot_resets = Counter("state_slot_resets")
+        self.state_slots_live = Gauge("state_slots_live")
         # prefill_tokens counts tokens actually COMPUTED by prefill
         # chunks; prefix-cache hits skip the compute and land in
         # prefix_hit_tokens instead, so (computed + hit) = total context
@@ -370,6 +383,11 @@ class EngineMetrics:
             "moe_experts_touched": self.moe_experts_touched.value,
             "latent_copy_groups": self.latent_copy_groups.value,
             "latent_run_groups": self.latent_run_groups.value,
+            "delta_decode_seq_steps": self.delta_decode_seq_steps.value,
+            "delta_prefill_tokens": self.delta_prefill_tokens.value,
+            "delta_prefill_positions": self.delta_prefill_positions.value,
+            "state_slot_resets": self.state_slot_resets.value,
+            "state_slots_live": self.state_slots_live.value,
             "prefill_tokens": self.prefill_tokens.value,
             "prefill_chunks": self.prefill_chunks.value,
             "prefix_hit_tokens": self.prefix_hit_tokens.value,

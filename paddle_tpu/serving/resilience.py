@@ -487,7 +487,7 @@ def audit_engine(engine) -> None:
     # what the pool said it stores (`kv_cache.page_arrays`) is what
     # every layer tuple holds, array for array
     want = pool.page_arrays
-    for li, layer in enumerate(pool.pools):
+    for li, layer in enumerate(pool.page_pools):
         if len(layer) != len(want):
             problems.append(
                 f"layer {li} pool tuple has {len(layer)} entries != "
@@ -523,8 +523,8 @@ def audit_engine(engine) -> None:
                 f"{req.request_id} (kv_tag={want_tag!r}) owns pages "
                 f"with mismatched tags: "
                 f"{[(p, tags.get(p)) for p in bad[:8]]}")
-    if kv_dtype == "mixed" and pool.pools:
-        planes = [np.asarray(layer[2]) for layer in pool.pools]
+    if kv_dtype == "mixed" and pool.page_pools:
+        planes = [np.asarray(layer[2]) for layer in pool.page_pools]
         if any(not np.array_equal(planes[0], pl) for pl in planes[1:]):
             problems.append("mixed-pool tag planes disagree across layers")
         plane = planes[0]
@@ -547,7 +547,7 @@ def audit_engine(engine) -> None:
         expect = (pool.num_blocks, pool.block_size,
                   pool.n_kv_heads // pool.tp_size, pool.head_dim)
         s_expect = (pool.num_blocks, pool.n_kv_heads // pool.tp_size)
-        for li, layer in enumerate(pool.pools):
+        for li, layer in enumerate(pool.page_pools):
             named = [("k", layer[0], expect), ("v", layer[1], expect)]
             if len(layer) == 4:
                 named += [("k-scale", layer[2], s_expect),

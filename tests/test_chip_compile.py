@@ -98,6 +98,31 @@ def _latent(B, pages=50752, table=1280, n_q=64, lanes=640, v_lanes=512,
         [((B, table // group), jnp.int32)] if flags_in else [])
 
 
+def _delta_decode(B, H=30, d_k=96, d_v=192):
+    """olmo-hybrid-7b.decode-wide's single-token update of the gated delta
+    rule: 64 sequences against a layer's pool of 65 float32 states of 96 x
+    5760 (ten heads a grid step), aliased in place; and the batch-1 step
+    of the naive_generate oracle."""
+    from paddle_tpu.ops.pallas.gated_delta_decode import gated_delta_decode
+
+    def fn(state, q, k, v, g, beta, live):
+        return gated_delta_decode(state, q, k, v, g, beta, live,
+                                  interpret=False)
+
+    f32 = jnp.float32
+    return fn, [((B + 1, d_k, H * d_v), f32), ((B, H, d_k), f32),
+                ((B, H, d_k), f32), ((B, H, d_v), f32), ((B, H), f32),
+                ((B, H), f32), ((B,), jnp.bool_)]
+
+
+def _ragged_hybrid(B, T, dtype=jnp.bfloat16):
+    """olmo-hybrid-7b.decode-wide's four paged layers: 30 heads of 128
+    ALLOCATED as 32 (what the chip copies as whole tiles below 32 bits),
+    4160 pages, a table 128 wide; a span attends 128 rows at a time."""
+    return _ragged(dtype, B, T, n_q=32, n_kv=32, d=128, pages=4160,
+                   table=128)
+
+
 def _flash(dtype, backward, mode="dense"):
     """mode: the mask forms chip_smoke's kernel phase validates at batch
     8 — "padbias" (a [b, 1, 1, sk] key-padding mask, streamed as a per-key
@@ -167,6 +192,14 @@ CASES = {
     "latent-kimi-decode-b1": lambda mp: _latent(1),
     "latent-kimi-decode-b48-flags-inside": lambda mp: _latent(
         48, flags_in=False),
+    # the hybrid cell: the delta rule's decode update, and the ragged
+    # kernel at 32 allocated heads (a decode step, a span's piece)
+    "delta-decode-b64": lambda mp: _delta_decode(64),
+    "delta-decode-b1": lambda mp: _delta_decode(1),
+    "ragged-hybrid-32x128-decode-b64": lambda mp: _ragged_hybrid(64, 1),
+    "ragged-hybrid-32x128-span-128": lambda mp: _ragged_hybrid(1, 128),
+    "ragged-hybrid-32x128-fp8-decode-b64": lambda mp: _ragged_hybrid(
+        64, 1, jnp.float8_e4m3fn),
     "flash-fp32-fwd": lambda mp: _flash(jnp.float32, False),
     "flash-bf16-fwd": lambda mp: _flash(jnp.bfloat16, False),
     "flash-fp32-fwd-bwd": lambda mp: _flash(jnp.float32, True),
