@@ -123,19 +123,33 @@ def _ragged_hybrid(B, T, dtype=jnp.bfloat16):
                    table=128)
 
 
-def _flash(dtype, backward, mode="dense"):
+# the training cells' attention: gpt2-124m.train's batch of 28, and
+# gpt3-1.3b.train-4chip's 8 x 16 heads of 128 (4 x 8 a shard of dp 2 x tp 2)
+GPT2_CELL = (28, SEQ, N_HEADS, HEAD_DIM)
+GPT3_CELL = (8, SEQ, 16, 128)
+
+
+def _flash(dtype, backward, mode="dense", shape=None):
     """mode: the mask forms chip_smoke's kernel phase validates at batch
     8 — "padbias" (a [b, 1, 1, sk] key-padding mask, streamed as a per-key
     bias) and "segments" (packed-sequence ids) ride per-batch-row vectors
-    whose blocks broke the TPU block rule at every batch but 1."""
+    whose blocks broke the TPU block rule at every batch but 1; "mask" is
+    flashmask_attention's dense [1, 1, sq, sk] float32 bias, streamed a
+    slab a grid step (the tile rule has to count it). `shape`: a training
+    cell's real [b, s, h, d]: a tile rule that overflows scoped VMEM has
+    to fail here, not on the chip."""
     from paddle_tpu.ops.pallas.flash_attention import flash_attention
+
+    b, s, h, d = shape = shape or (BATCH, SEQ, N_HEADS, HEAD_DIM)
 
     def fwd(q, k, v):
         kw = {}
         if mode == "padbias":
-            kw["mask"] = jnp.zeros((BATCH, 1, 1, SEQ), jnp.float32)
+            kw["mask"] = jnp.zeros((b, 1, 1, s), jnp.float32)
         if mode == "segments":
-            kw["segment_ids"] = jnp.zeros((BATCH, SEQ), jnp.int32)
+            kw["segment_ids"] = jnp.zeros((b, s), jnp.int32)
+        if mode == "mask":
+            kw["mask"] = jnp.zeros((1, 1, s, s), jnp.float32)
         return flash_attention(q, k, v, causal=mode != "padbias",
                                interpret=False, **kw)
 
@@ -143,8 +157,7 @@ def _flash(dtype, backward, mode="dense"):
         return jax.grad(lambda *a: jnp.sum(fwd(*a).astype(jnp.float32) ** 2),
                         argnums=(0, 1, 2))(q, k, v)
 
-    return (fwd_bwd if backward else fwd), \
-        [((BATCH, SEQ, N_HEADS, HEAD_DIM), dtype)] * 3
+    return (fwd_bwd if backward else fwd), [(shape, dtype)] * 3
 
 
 def _auto_decode_kernel(monkeypatch):
@@ -208,6 +221,24 @@ CASES = {
                                                     "padbias"),
     "flash-fp32-segments-fwd-bwd": lambda mp: _flash(jnp.float32, True,
                                                      "segments"),
+    # the training cells' real shapes, and the two streamed mask forms at
+    # 1024 keys
+    "flash-bf16-gpt2-cell-fwd-bwd": lambda mp: _flash(
+        jnp.bfloat16, True, shape=GPT2_CELL),
+    "flash-bf16-gpt3-shard-fwd-bwd": lambda mp: _flash(
+        jnp.bfloat16, True, shape=(4, SEQ, 8, 128)),
+    "flash-bf16-mask-1024-fwd-bwd": lambda mp: _flash(jnp.bfloat16, True,
+                                                      "mask"),
+    "flash-bf16-segments-gpt2-cell-fwd-bwd": lambda mp: _flash(
+        jnp.bfloat16, True, "segments", shape=GPT2_CELL),
+    # a sequence too long for one span: the third grid axis, its clamped
+    # index maps and the accumulators carried across its steps
+    "flash-bf16-16k-keys-fwd-bwd": lambda mp: _flash(
+        jnp.bfloat16, True, shape=(1, 16 * SEQ, 8, 128)),
+    # a sequence under 128 is one tile of its own length, read whole
+    "flash-bf16-segments-100-keys-fwd-bwd": lambda mp: _flash(
+        jnp.bfloat16, True, "segments", shape=(BATCH, 100, N_HEADS,
+                                               HEAD_DIM)),
     "auto-decode-12x64": _auto_decode_kernel,
 }
 
@@ -221,7 +252,18 @@ def test_kernel_compiles_for_v5e(case, v5e, monkeypatch):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-def test_flash_compiles_per_shard_under_a_dp2_tp2_mesh(v5e):
+# GPT-2's toy batch in float32, and gpt3-1.3b.train-4chip's own attention
+# in bfloat16; each device's kernels see its own batch rows and heads: 4 x 6
+# of 64, 4 x 8 of 128
+MESH_CASES = {
+    "toy-fp32": (jnp.float32, None,
+                 f"f32[{BATCH // 2 * N_HEADS // 2},{SEQ},{HEAD_DIM}]"),
+    "gpt3-cell-bf16": (jnp.bfloat16, GPT3_CELL, f"bf16[{4 * 8},{SEQ},128]"),
+}
+
+
+@pytest.mark.parametrize("case", sorted(MESH_CASES))
+def test_flash_compiles_per_shard_under_a_dp2_tp2_mesh(case, v5e):
     """GSPMD cannot partition a Mosaic kernel: in a program compiled for
     a mesh (jit.TrainStep under parallel.init_mesh, the README's
     hybrid-parallel step) flash attention must reach the compiler inside a
@@ -232,7 +274,8 @@ def test_flash_compiles_per_shard_under_a_dp2_tp2_mesh(v5e):
 
     from paddle_tpu.parallel.mesh import program_mesh_scope
 
-    fn, shapes = _flash(jnp.float32, True)
+    dtype, shape, per_shard = MESH_CASES[case]
+    fn, shapes = _flash(dtype, True, shape=shape)
     mesh = Mesh(np.asarray(v5e).reshape(2, 2), ("dp", "tp"))
     spec = NamedSharding(mesh, P("dp", None, "tp", None))
     args = [jax.ShapeDtypeStruct(s, d, sharding=spec) for s, d in shapes]
@@ -240,8 +283,7 @@ def test_flash_compiles_per_shard_under_a_dp2_tp2_mesh(v5e):
         compiled = jax.jit(fn).lower(*args).compile()
     text = compiled.as_text()
     assert "tpu_custom_call" in text
-    # each device's kernels see its own 4 batch rows x 6 heads
-    assert f"f32[{BATCH // 2 * N_HEADS // 2},{SEQ},{HEAD_DIM}]" in text
+    assert per_shard in text
 
 
 def test_ragged_compiles_per_shard_under_a_model4_mesh(v5e):
