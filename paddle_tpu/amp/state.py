@@ -17,6 +17,9 @@ WHITE_LIST = {
     "matmul", "bmm", "mv", "addmm", "linear", "conv2d", "conv1d",
     "conv2d_transpose", "einsum", "scaled_dot_product_attention",
     "flash_attn_unpadded", "flashmask_attention",
+    # models/zaya.py: the convolutions of the compressed latent, and the
+    # expert layer's products (ops/pallas/grouped_matmul.py)
+    "cca_conv", "grouped_matmul",
 }
 
 # Ops that must run in fp32 (reductions / exp-family, loss ops).

@@ -13,6 +13,7 @@ step (paddle_tpu.parallel).
 
 from __future__ import annotations
 
+import weakref
 from contextlib import contextmanager
 from typing import Any, Callable, Dict, Optional, Tuple
 
@@ -450,6 +451,12 @@ class TrainStep:
                 is_leaf=lambda v: not isinstance(v, dict))
             self._restore_opt_state()
             self._maybe_shard_state()
+        if hasattr(model, "step_counts"):
+            # a model that counts in its buffers: the newest step's are the
+            # process's (profiler.step_counters() reads them when asked)
+            step = weakref.ref(self)
+            _prof.publish_step_counters(
+                lambda: step() and step().model.step_counts(step().buffers))
 
     # ---------------------------------------------------------------- sharding
 

@@ -16,3 +16,6 @@ from paddle_tpu.models.olmo_hybrid import (  # noqa: F401
 from paddle_tpu.models.llama import (  # noqa: F401
     Llama, LlamaConfig, llama_loss_fn,
 )
+from paddle_tpu.models.zaya import (  # noqa: F401
+    ZayaConfig, ZayaForCausalLM, zaya_loss_fn,
+)

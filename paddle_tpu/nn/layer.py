@@ -180,6 +180,7 @@ class Layer:
     def set_state_dict(self, state_dict):
         with _prof.always_span("model.set_state_dict"):
             own = self.state_dict(include_non_persistable_buffer=True)
+            kept = self.state_dict()
             missing, unexpected = [], []
             for name, t in own.items():
                 if name in state_dict:
@@ -187,7 +188,7 @@ class Layer:
                     v = (src._value if isinstance(src, Tensor)
                          else np.asarray(src))
                     t.copy_(Tensor._wrap(v))
-                else:
+                elif name in kept:  # a buffer no state dict keeps is not owed
                     missing.append(name)
             for name in state_dict:
                 if name not in own:

@@ -14,6 +14,9 @@ lowers the resharding to the same all-to-all the reference calls explicitly.
 
 from __future__ import annotations
 
+import functools
+
+import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
@@ -298,33 +301,21 @@ def _swiglu(x, w_gate, w_up, w_down, out_dtype=None):
                       preferred_element_type=out_dtype or x.dtype)
 
 
-def held_experts_ffn(x, idx, weights, w_gate, w_up, w_down,
-                     first_expert: int, valid=None):
-    """The routed part of a top-k expert layer over the experts held here,
-    dropless: y[t] = sum over the selected experts e of token t that lie in
-    [first_expert, first_expert + G) of weights[t, e] * SwiGLU_e(x[t]).
-
-    x [T, d]; idx / weights [T, K] as `sigmoid_topk_route` gives them;
-    w_gate / w_up [G, d, f], w_down [G, f, d]: the held experts, stacked;
-    valid [T] bool (rows that are padding route nowhere). Returns
-    (y [T, d] float32, pairs, touched): the token-expert pairs computed
-    here and the held experts that got at least one.
-
-    No [tokens, experts, capacity] mask and no capacity: the local pairs
-    are sorted by expert into a layout where every expert's group starts
-    at a multiple of a row block, and a loop with a DYNAMIC trip count
-    walks the blocks that exist: each block gathers its tokens' rows,
-    multiplies them by its one expert's matrices and adds the weighted
-    result onto its tokens. An expert no token chose costs nothing (its
-    matrices are not read); if every token chooses the same expert the
-    loop is longer, nothing is dropped. The block is 16 rows for a decode
-    step's few pairs and 256 for a prefill's: shapes decide."""
-    import jax
-
-    T, d = x.shape
-    K, G = idx.shape[1], w_gate.shape[0]
-    N = T * K
-    bm = 16 if N <= 4096 else 256
+def expert_layout(idx, first_expert: int, n_held: int, block_rows: int,
+                  valid=None):
+    """The token-expert pairs whose expert lies in [first_expert,
+    first_expert + n_held), sorted by expert into rows where every expert's
+    group starts at a multiple of `block_rows`. idx [T, K] int; valid [T]
+    bool (rows that are padding route nowhere). Returns
+    (counts [n_held], row_pair [R], p_end [n_held], block_expert
+    [R / block_rows]): pairs per held expert; for each row of the layout
+    the flat pair t * K + k it holds, T * K where it is padding; where each
+    group's padded rows end (the last entry is the rows that hold
+    anything); the expert of each row block. R is static: every pair held
+    (a token picks min(K, n_held) held experts at most) and under a block
+    of padding an expert."""
+    T, K = idx.shape
+    G, bm, N = n_held, block_rows, T * K
     local = (idx >= first_expert) & (idx < first_expert + G)
     if valid is not None:
         local = local & valid[:, None]
@@ -334,22 +325,65 @@ def held_experts_ffn(x, idx, weights, w_gate, w_up, w_down,
     padded = (counts + bm - 1) // bm * bm
     p_end = jnp.cumsum(padded)
     p_start, start = p_end - padded, jnp.cumsum(counts) - counts
-    # rows of the padded layout, at most: every pair held (a token picks
-    # min(K, G) held experts at most) and under a block of padding each
     R = -(-T * min(K, G) // bm) * bm + G * bm
     rank = jnp.arange(N, dtype=jnp.int32)
     e_sorted = jnp.minimum(e[order], G - 1)
     dest = jnp.where(e[order] < G,
                      p_start[e_sorted] + rank - start[e_sorted], R)
     row_pair = jnp.full((R,), N, jnp.int32).at[dest].set(order, mode="drop")
+    block_expert = jnp.minimum(jnp.searchsorted(
+        p_end, jnp.arange(R // bm, dtype=jnp.int32) * bm, side="right"),
+        G - 1).astype(jnp.int32)
+    return counts, row_pair, p_end, block_expert
+
+
+def held_experts_ffn(x, idx, weights, w_gate, w_up, w_down,
+                     first_expert: int, valid=None):
+    """The routed part of a top-k expert layer over the experts held here,
+    dropless: y[t] = sum over the selected experts e of token t that lie in
+    [first_expert, first_expert + G) of weights[t, e] * SwiGLU_e(x[t]).
+
+    The SERVING form: its loop has a dynamic trip count, which JAX cannot
+    differentiate in reverse mode; under `jax.grad` it raises by name. A
+    training step calls `held_experts_ffn_train`, the same layer over the
+    grouped product that has a gradient.
+
+    x [T, d]; idx / weights [T, K] as `sigmoid_topk_route` gives them;
+    w_gate / w_up [G, d, f], w_down [G, f, d]: the held experts, stacked;
+    valid [T] bool (rows that are padding route nowhere). Returns
+    (y [T, d] float32, pairs, touched): the token-expert pairs computed
+    here and the held experts that got at least one.
+
+    No [tokens, experts, capacity] mask and no capacity: the local pairs
+    are sorted by expert into a layout where every expert's group starts
+    at a multiple of a row block (`expert_layout`), and a loop with a
+    DYNAMIC trip count walks the blocks that exist: each block gathers its
+    tokens' rows, multiplies them by its one expert's matrices and adds the
+    weighted result onto its tokens. An expert no token chose costs nothing
+    (its matrices are not read); if every token chooses the same expert the
+    loop is longer, nothing is dropped. The block is 16 rows for a decode
+    step's few pairs and 256 for a prefill's: shapes decide."""
+    import jax
+
+    T, d = x.shape
+    K, G = idx.shape[1], w_gate.shape[0]
+    N = T * K
+    bm = 16 if N <= 4096 else 256
+    counts, row_pair, p_end, block_expert = expert_layout(
+        idx, first_expert, G, bm, valid)
     real = row_pair < N
     row_tok = jnp.where(real, row_pair // K, 0)
     row_w = jnp.where(real, weights.reshape(N)[jnp.minimum(row_pair, N - 1)],
                       0.0).astype(jnp.float32)
-    block_expert = jnp.minimum(jnp.searchsorted(
-        p_end, jnp.arange(R // bm, dtype=jnp.int32) * bm, side="right"),
-        G - 1).astype(jnp.int32)
 
+    y = _walk_blocks(bm, p_end[-1] // bm, x, row_tok, row_w, block_expert,
+                     w_gate, w_up, w_down)
+    return y, jnp.sum(counts), jnp.sum((counts > 0).astype(jnp.int32))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _walk_blocks(bm, n_blocks, x, row_tok, row_w, block_expert,
+                 w_gate, w_up, w_down):
     def block(b, y):
         rows = jax.lax.dynamic_slice_in_dim(row_tok, b * bm, bm)
         w = jax.lax.dynamic_slice_in_dim(row_w, b * bm, bm)
@@ -360,6 +394,98 @@ def held_experts_ffn(x, idx, weights, w_gate, w_up, w_down,
         # once); its padding rows add zero onto token 0
         return y.at[rows].add(out * w[:, None])
 
-    y = jax.lax.fori_loop(0, p_end[-1] // bm, block,
-                          jnp.zeros((T, d), jnp.float32))
-    return y, jnp.sum(counts), jnp.sum((counts > 0).astype(jnp.int32))
+    return jax.lax.fori_loop(0, n_blocks, block,
+                             jnp.zeros(x.shape, jnp.float32))
+
+
+def _no_gradient(*_):
+    raise TypeError(
+        "parallel.moe.held_experts_ffn is the serving form of the held-"
+        "experts layer: its loop over the blocks that exist has a dynamic "
+        "trip count, which has no reverse mode. Differentiate "
+        "parallel.moe.held_experts_ffn_train, the same layer over "
+        "ops.pallas.grouped_matmul")
+
+
+_walk_blocks.defvjp(_no_gradient, _no_gradient)
+
+
+# ------------------------------------------------------------------------
+# The training form: the same share of the same layer, every step of it
+# differentiable. Top-k of softmax scores, the pairs sorted by expert, the
+# three products as ops.pallas.grouped_matmul, the results weighted and
+# gathered back onto their tokens. Nothing is dropped and no capacity
+# exists.
+
+
+def softmax_topk_route(logits, bias, top_k: int):
+    """Softmax scores with a selection-only bias: logits [T, E], bias [E]
+    -> (indices [T, top_k] of the top_k largest of score + bias, weights
+    [T, top_k] = the selected experts' own scores, scores [T, E]), float32.
+    The bias moves the selection and never a weight, and no gradient
+    reaches it through this function."""
+    s = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
+    _, idx = jax.lax.top_k(
+        s + jax.lax.stop_gradient(bias.astype(jnp.float32))[None, :], top_k)
+    return idx, jnp.take_along_axis(s, idx, axis=-1), s
+
+
+def held_experts_ffn_train(x, idx, weights, w_gate, w_up, w_down,
+                           first_expert: int, block_rows: int = None):
+    """`held_experts_ffn` for a training step: y[t] = sum over the selected
+    experts e of token t held here of weights[t, e] * SwiGLU_e(x[t]), with
+    a gradient in x, weights and the three stacks of matrices. A token whose
+    experts are all absent gets zeros.
+
+    x [T, d] (bfloat16 under autocast, and the products then run in it);
+    idx / weights [T, K]; w_gate / w_up [G, d, f], w_down [G, f, d].
+    Returns (y [T, d] float32, pairs, rows_padded): the pairs computed here
+    and the rows the grouped product multiplied (whole blocks of
+    `block_rows`: 256 where there are pairs enough, 16 below)."""
+    from paddle_tpu.ops.pallas.grouped_matmul import grouped_matmul
+
+    T, d = x.shape
+    K, G = idx.shape[1], w_gate.shape[0]
+    N = T * K
+    bm = block_rows or (256 if N >= 2048 else 16)
+    counts, row_pair, p_end, block_expert = expert_layout(
+        idx, first_expert, G, bm)
+    R = row_pair.shape[0]
+    product = functools.partial(
+        grouped_matmul, block_group=block_expert, n_live=p_end[-1] // bm,
+        block_rows=bm)
+    # the row of each pair, R for a pair whose expert is absent; the layout
+    # holds each held pair in exactly one row, so rows and pairs are two
+    # views of one one-to-one map and both directions of it are gathers
+    pair_row = jnp.full((N + 1,), R, jnp.int32).at[row_pair].set(
+        jnp.arange(R, dtype=jnp.int32))[:N]
+    rows = _take_rows(jnp.repeat(x, K, axis=0) if K > 1 else x,
+                      row_pair, pair_row)
+    g = product(rows, w_gate).astype(jnp.float32)
+    u = product(rows, w_up).astype(jnp.float32)
+    out = product((jax.nn.silu(g) * u).astype(x.dtype), w_down,
+                  out_dtype=jnp.float32)
+    picked = _take_rows(out, pair_row, row_pair).reshape(T, K, d)
+    y = jnp.sum(picked * weights.astype(jnp.float32)[..., None], axis=1)
+    return y, jnp.sum(counts), p_end[-1]
+
+
+@jax.custom_vjp
+def _take_rows(x, index, inverse):
+    """x[index], zeros where index is out of range. `inverse` is the same
+    one-to-one map read the other way (inverse[index[i]] == i wherever
+    index[i] is in range), so the gradient is a gather too: a scatter-add
+    costs the chip several times as much."""
+    return x.at[index].get(mode="fill", fill_value=0)
+
+
+def _take_rows_fwd(x, index, inverse):
+    return _take_rows(x, index, inverse), (index, inverse)
+
+
+def _take_rows_bwd(res, dy):
+    index, inverse = res
+    return _take_rows(dy, inverse, index), None, None
+
+
+_take_rows.defvjp(_take_rows_fwd, _take_rows_bwd)

@@ -197,6 +197,30 @@ def clear() -> None:
     _ring.clear()
 
 
+# A training step's counters are outputs of its program, accumulated on
+# the device in the model's buffers. The newest TrainStep whose model counts
+# publishes a way to read them; nothing touches the device until someone asks.
+_read_step_counters: Optional[Callable[[], dict]] = None
+
+
+def publish_step_counters(read: Optional[Callable[[], dict]]) -> None:
+    """`read() -> {name: device scalar}`, or None where the step that
+    published is gone; the process has one such read, the newest."""
+    global _read_step_counters
+    _read_step_counters = read
+
+
+def step_counters() -> dict:
+    """{name: float, or a list of floats for a count kept step by step} of
+    the published step's counters, fetched from the device in one transfer
+    now; empty where no step counts."""
+    counts = _read_step_counters() if _read_step_counters else None
+    if not counts:
+        return {}
+    return {k: float(v) if v.ndim == 0 else v.astype(float).tolist()
+            for k, v in jax.device_get(counts).items()}
+
+
 def self_ns(recorded=None) -> dict:
     """span_id -> the span's duration less what its children cover."""
     recorded = spans() if recorded is None else recorded

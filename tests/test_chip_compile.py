@@ -160,6 +160,26 @@ def _flash(dtype, backward, mode="dense", shape=None):
     return (fwd_bwd if backward else fwd), [(shape, dtype)] * 3
 
 
+def _grouped(dtype, backward, rows=10240, width=2048, groups=8, block=256):
+    """zaya1-8b.train-8k's expert products: the pairs of 8192 tokens in
+    blocks of 256 rows (8192 + 8 x 256 of layout), 8 experts of 2048 x
+    2048; forward, and with it dx and dw."""
+    from paddle_tpu.ops.pallas.grouped_matmul import grouped_matmul
+
+    def fwd(x, w, block_group, n_live):
+        return grouped_matmul(x, w, block_group, n_live, block_rows=block,
+                              interpret=False)
+
+    def fwd_bwd(x, w, block_group, n_live):
+        return jax.grad(lambda x, w: jnp.sum(fwd(
+            x, w, block_group, n_live).astype(jnp.float32) ** 2),
+            argnums=(0, 1))(x, w)
+
+    return (fwd_bwd if backward else fwd), [
+        ((rows, width), dtype), ((groups, width, width), dtype),
+        ((rows // block,), jnp.int32), ((), jnp.int32)]
+
+
 def _auto_decode_kernel(monkeypatch):
     """Whatever attn_impl="auto" resolves to on a TPU for a GPT-2 decode
     step (q_len bucket 1): the test steers the backend question, the
@@ -240,6 +260,14 @@ CASES = {
         jnp.bfloat16, True, "segments", shape=(BATCH, 100, N_HEADS,
                                                HEAD_DIM)),
     "auto-decode-12x64": _auto_decode_kernel,
+    # the sparse training cell: its attention in the latent (one sequence
+    # of 8192 keys, 8 heads of 128) and its grouped expert products
+    "flash-bf16-zaya-cell-fwd-bwd": lambda mp: _flash(
+        jnp.bfloat16, True, shape=(1, 8 * SEQ, 8, 128)),
+    "grouped-bf16-zaya-cell-fwd": lambda mp: _grouped(jnp.bfloat16, False),
+    "grouped-bf16-zaya-cell-fwd-bwd": lambda mp: _grouped(jnp.bfloat16,
+                                                          True),
+    "grouped-fp32-fwd-bwd": lambda mp: _grouped(jnp.float32, True),
 }
 
 
