@@ -84,8 +84,12 @@ class GPTAttention(Layer):
     def forward(self, x):
         b, s, h = x.shape
         qkv = self.qkv(x)
-        qkv = qkv.reshape([b, s, 3, self.num_heads, self.head_dim])
-        q, k, v = qkv.unbind(axis=2)
+        # columns first, heads after: a [.., heads, 64] array between the
+        # projection and the kernels costs a transposed copy each way (the
+        # chip tiles the last two dimensions), a slice of whole columns of
+        # [b, s, 3h] is read as it lies
+        q, k, v = (qkv[:, :, i * h:(i + 1) * h].reshape(
+            [b, s, self.num_heads, self.head_dim]) for i in range(3))
         out = F.scaled_dot_product_attention(q, k, v, is_causal=True)
         out = out.reshape([b, s, h])
         return self.drop(self.out(out))

@@ -190,14 +190,13 @@ def cca_attention(cfg: ZayaConfig, params: dict, pre: str, u):
     """The attention sublayer's output [b, s, hidden] for the normed
     stream u [b, s, hidden]."""
     b, s, _ = u.shape
-    rep = cfg.num_attention_heads // cfg.num_key_value_heads
     q, k, v = cca_qkv(cfg, params, pre, u)
     with jax.named_scope("attn"):
         sdpa = "scaled_dot_product_attention"
-        # every key/value head is laid out once per query head that reads it
+        # a key/value head stays one head: each group of query heads reads
+        # it where it lies
         o = scaled_dot_product_attention(
-            _low(q, sdpa), _low(jnp.repeat(k, rep, axis=2), sdpa),
-            _low(jnp.repeat(v, rep, axis=2), sdpa), is_causal=True)
+            _low(q, sdpa), _low(k, sdpa), _low(v, sdpa), is_causal=True)
     return _mm(o.reshape(b, s, -1), params[pre + "o_proj.weight"])
 
 

@@ -639,11 +639,14 @@ def repeat_interleave(x, repeats, axis=None):
 
 
 def unbind(x, axis=0):
-    return tuple(jnp.moveaxis(x, axis, 0))
+    # plain slices: moving the axis first is a transposed copy of the whole
+    # of x where the compiler does not see through it
+    return tuple(jax.lax.index_in_dim(x, i, axis, keepdims=False)
+                 for i in range(x.shape[axis]))
 
 
 def unstack(x, axis=0, num=None):
-    return tuple(jnp.moveaxis(x, axis, 0))
+    return unbind(x, axis)
 
 
 def as_strided_slice(x, axes, starts, ends, strides=None):
@@ -1292,13 +1295,18 @@ def scaled_dot_product_attention(q, k, v, attn_mask=None, dropout_p=0.0,
     """Reference: paddle.nn.functional.scaled_dot_product_attention /
     flash_attention (python/paddle/nn/functional/flash_attention.py:358).
 
-    Layout [batch, seq, heads, head_dim] (paddle flash-attn convention).
-    Computed at fp32 accumulation. When the shapes tile (d % 8 == 0,
-    seq % 128 == 0) and no dropout is requested, dispatches to the Pallas
-    flash kernel (paddle_tpu/ops/pallas/flash_attention.py) — including
-    masked attention: broadcastable attn_masks ([b,1,1,sk] padding form,
-    [b,1|h,sq,sk] dense form, bool or additive) are streamed tile-wise into
-    the kernel, so ERNIE-style padded pretraining takes the flash path.
+    Layout [batch, seq, heads, head_dim] (paddle flash-attn convention);
+    k and v may have fewer heads, one per group of heads // kv_heads
+    consecutive query heads (on every path: nothing is repeated by the
+    caller). Computed at fp32 accumulation. When the shapes tile
+    (d % 8 == 0, seq % 128 == 0) and no dropout is requested, dispatches to
+    the Pallas flash kernel (paddle_tpu/ops/pallas/flash_attention.py) —
+    including masked attention: broadcastable attn_masks ([b,1,1,sk]
+    padding form, [b,1|h,sq,sk] dense form, bool or additive) are streamed
+    tile-wise into the kernel, so ERNIE-style padded pretraining takes the
+    flash path. The kernels read q, k, v where they lie, as [b, s, h*d],
+    when a head (or 128 // d of them) fills whole 128-lane columns; other
+    head sizes and counts cost a transposed copy of every operand.
     """
     b, sq, h, d = q.shape
     sk = k.shape[1]
@@ -1357,6 +1365,8 @@ def scaled_dot_product_attention(q, k, v, attn_mask=None, dropout_p=0.0,
                                   scale=scale, mask=mp)
             return out[:, :sq]
         _warn_sdpa_fallback(q, k, mask_ok)
+    if k.shape[2] != h:         # grouped key/value heads, laid out a query
+        k, v = (jnp.repeat(t, h // k.shape[2], axis=2) for t in (k, v))
     qT = jnp.swapaxes(q, 1, 2)  # b h s d
     kT = jnp.swapaxes(k, 1, 2)
     vT = jnp.swapaxes(v, 1, 2)

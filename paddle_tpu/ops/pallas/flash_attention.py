@@ -12,11 +12,12 @@ each, whatever they compute), so a head takes a handful of them:
     below that, the block table's granularity where one is given) and a
     SPAN: how many rows of the walked operand one grid step holds in VMEM
     (the whole sequence while it fits VMEM_BUDGET);
-  * forward and dQ run on grid (batch*head, sq / block_q, sk / span): K and
-    V of the span are VMEM-resident and the walk over their tiles is a loop
-    INSIDE the kernel, bounded by the causal diagonal, so no step and no
-    fetch is spent above it; dK/dV runs on (batch*head, sk / block_k,
-    sq / span) and walks Q / dO tiles from the diagonal on. Where a
+  * forward and dQ run on grid (batch * head blocks, sq / block_q,
+    sk / span): K and V of the span are VMEM-resident and the walk over
+    their tiles is a loop INSIDE the kernel, bounded by the causal diagonal,
+    so no step and no fetch is spent above it; dK/dV runs on (batch * head
+    blocks, sk / block_k, sq / span) and walks Q / dO tiles from the
+    diagonal on. Where a
     sequence outgrows one span the third axis has several steps, the
     accumulators ride VMEM scratch across them, and the index map of a
     span wholly above the diagonal clamps to the last live one (a dead step
@@ -30,7 +31,8 @@ each, whatever they compute), so a head takes a handful of them:
     statistics are read as the lane-dense rows they are stored as.
 
 The attention matrix never exists in HBM; per-row statistics (lse, delta)
-are [batch*head, 1, sq] float32, whole 128-lane rows.
+are [batch*head, 1, sq] float32, whole 128-lane rows: lse an output of the
+forward, delta = rowsum(dO * O) of the dQ kernel, which has both blocks.
 
 Masking (four independent mechanisms, composable with `causal`):
   * additive mask — an fp32 [b, 1|h, sq, sk] bias streamed a (block_q,
@@ -55,7 +57,32 @@ Forward and backward are Pallas kernels (FlashAttention-2 style backward:
 a dQ kernel accumulating over K tiles and a dK/dV kernel accumulating over
 Q tiles, both recomputing P from the saved per-row log-sum-exp).
 
-Layout: [batch, seq, heads, head_dim] (paddle flash-attn convention).
+Layout: [batch, seq, heads, head_dim] (paddle flash-attn convention), and
+the kernels read q, k, v, o, dO and write o, dq, dk, dv in that memory, as
+[b, s, h*d] (a free reshape): an operand block is (1, rows, W) of whole
+128-lane columns, W = max(d, 128), and the BlockSpec's index map picks
+(batch row, row tile, head block), so nothing is transposed around the
+calls. `Schedule.heads_per_block` says which layout a call runs, from its
+shapes alone:
+  * d a multiple of 128: one head a block. k and v may then have h / rep
+    heads; forward and dQ read head block `head // rep` where it lies, and
+    dK / dV come out a QUERY head and are summed over each group outside;
+  * d a divisor of 128 and h a multiple of 128 // d: that many heads share
+    a block, and a grid step takes them in a static loop. The MXU
+    contracts 128 lanes whether half of them are padding or another head,
+    so a head's scores are its own lanes of the grid-side operand (the
+    others zeroed, once a grid step) against the whole walked tile, and
+    each product that lands in the block's lanes (P V, dS K, P^T dO,
+    dS^T Q) is taken whole and kept in that head's lanes of ONE lane-dense
+    (rows, 128) accumulator: no MXU pass is added. Running max, sum, lse
+    and delta stay a head;
+  * otherwise (d = 96, three heads of 64): 0, the kernels run on flat
+    [b*h, s, d] copies with the lanes padded, `_flat` before and `_unflat`
+    after, one warning a shape (`_log_flat`).
+A caller that wants no copy either side hands over whole columns: slice
+q, k, v out of a fused projection as [b, s, h*d] BEFORE naming heads (the
+chip tiles the last two dimensions, so a [.., h, 64] array is no bitcast
+of [.., h*64] and the compiler transposes its way to one).
 Causal masking is bottom-right aligned (tril k=sk-sq), matching the XLA
 reference path for cross-length (KV-decode) shapes.
 """
@@ -115,32 +142,58 @@ class Schedule(NamedTuple):
     steps: tuple
     tiles: tuple
     dead_steps: tuple
+    # heads a block holds where the kernels read q, k, v, o and dO in the
+    # caller's own memory, [b, s, h*d]; 0: they run on flat [b*h, s, d]
+    # copies (a head does not fill whole 128-lane columns)
+    heads_per_block: int = 0
+
+    @property
+    def heads(self) -> int:
+        """Heads one grid step takes."""
+        return max(self.heads_per_block, 1)
+
+
+def _heads_per_block(h: int, hk: int, d: int) -> int:
+    """Heads to a block of whole 128-lane columns of [b, s, h*d]: one
+    where a head is a multiple of 128 wide (k and v may then have `hk`
+    heads, a divisor of h), 128 // d where that many divide the heads; 0
+    where no such block exists."""
+    if d % LANES == 0:
+        return 1 if h % hk == 0 else 0
+    hp = LANES // d
+    return hp if LANES % d == 0 and hk == h and h % hp == 0 else 0
 
 
 def _vmem_bytes(tile_rows: int, walked_rows: int, block_q: int, block_k: int,
-                d: int, itemsize: int, mask: bool) -> int:
+                d: int, itemsize: int, mask: int, heads: int = 1) -> int:
     """Scoped VMEM of one grid step, counted from above: a tile of
-    `tile_rows` on the grid's side (at most four blocks: k, v, dk, dv) and
+    `tile_rows` on the grid's side (at most four blocks: k, v, dk, dv, or
+    q, dO, O, dq) and
     `walked_rows` of the two operands it walks, each block twice for the
-    pipeline; the accumulators and row statistics; a dense mask's slab;
-    five score tiles of float32 temporaries. A minor dimension pads to
-    whole 128-lane tiles."""
+    pipeline; the accumulators and each head's row statistics; a dense
+    mask's slab (`mask` heads of it); five score tiles of float32
+    temporaries; where `heads` share a block, each one's lanes of the two
+    grid-side operands. A block's minor dimension is whole 128-lane tiles:
+    a shared block's own, a flat head's padded."""
     dl = -(-d // LANES) * LANES
     blocks = 2 * (4 * tile_rows + 2 * walked_rows) * dl * itemsize
-    scratch = 2 * tile_rows * (dl + LANES) * 4
-    slab = 2 * tile_rows * walked_rows * 4 if mask else 0
-    return blocks + scratch + slab + 5 * block_q * block_k * 4
+    scratch = 2 * tile_rows * (dl + heads * LANES) * 4
+    slab = 2 * mask * tile_rows * walked_rows * 4
+    alone = 2 * heads * tile_rows * dl * itemsize if heads > 1 else 0
+    return blocks + scratch + slab + alone + 5 * block_q * block_k * 4
 
 
-def schedule(q_shape, k_shape, dtype, causal: bool, *, mask: bool = False,
+def schedule(q_shape, k_shape, dtype, causal: bool, *, mask: int = 0,
              block_mask_shape=None, block_q=None, block_k=None, span=None):
-    """The tiles and grids of one call, a pure function of what the call
-    can see: q [b, sq, h, d], k [b, sk, h, d], the operand dtype, whether a
-    dense additive mask streams, a block table's shape. `block_q`,
-    `block_k` and `span` force a choice (tests at toy sizes only). None
-    where the shapes do not tile."""
+    """The tiles, grids and layout of one call, a pure function of what the
+    call can see: q [b, sq, h, d], k [b, sk, hk, d], the operand dtype, the
+    heads of a dense additive mask that streams (0: none, 1: one for all),
+    a block table's shape. `block_q`, `block_k` and `span` force a choice
+    (tests at toy sizes only). None where the shapes do not tile."""
     b, sq, h, d = q_shape
     sk = k_shape[1]
+    hp = _heads_per_block(h, k_shape[2], d)
+    slabs = min(int(mask), max(hp, 1))      # a grid step's share of the mask
     if block_mask_shape is not None:       # the table's granularity rules
         nqb, nkb = block_mask_shape
         if sq % nqb or sk % nkb:
@@ -160,7 +213,8 @@ def schedule(q_shape, k_shape, dtype, causal: bool, *, mask: bool = False,
         return next((t for t in range(n_tiles, 0, -1) if n_tiles % t == 0
                      and _vmem_bytes(tile_rows, t * walked_tile, block_q,
                                      block_k, d, jnp.dtype(dtype).itemsize,
-                                     mask) <= VMEM_BUDGET), None)
+                                     slabs, max(hp, 1)) <= VMEM_BUDGET),
+                    None)
 
     tq, tk = walked(nq, block_k, block_q), walked(nk, block_q, block_k)
     if tq is None or tk is None:
@@ -182,10 +236,11 @@ def schedule(q_shape, k_shape, dtype, causal: bool, *, mask: bool = False,
     dead_q = sum(sum(j * tk >= n for j in range(nk // tk)) for n in live)
     dead_k = sum(sum((m + 1) * tq <= f for m in range(nq // tq))
                  for f in first)
-    walk_q, walk_k = b * h * nq * (nk // tk), b * h * nk * (nq // tq)
+    blocks = b * h // max(hp, 1)            # the grid's first axis
+    walk_q, walk_k = blocks * nq * (nk // tk), blocks * nk * (nq // tq)
     return Schedule(block_q, block_k, tq * block_q, tk * block_k,
                     (walk_q, walk_q, walk_k), (b * h * sum(live),) * 3,
-                    (b * h * dead_q, b * h * dead_q, b * h * dead_k))
+                    (blocks * dead_q, blocks * dead_q, blocks * dead_k), hp)
 
 
 def _dot(a, b, contract):
@@ -197,21 +252,46 @@ _NT = ((1,), (1,))      # a @ b.T
 _NN = ((1,), (0,))      # a @ b
 
 
-def _column(ref):
-    """A per-row vector stored lane-dense, (1, 1, n), as a column (n, 1)."""
-    return ref[0, 0][:, None]
+def _column(ref, j: int = 0):
+    """Head j's per-row vector, stored lane-dense in (heads, 1, n), as a
+    column (n, 1)."""
+    return ref[j, 0][:, None]
 
 
-def _tile_scores(q, k_tile, keys_on_rows: bool, scale, mask=None,
+def _only_head(x, j: int, heads: int, d: int):
+    """A (rows, heads * d) block with every lane but head j's zeroed: a
+    contraction over the block's lanes is then over that head's alone.
+    (Lane slices of both operands, and the walked tile transposed once for
+    its heads, compile too and ran no faster on the chip: PERF.md §6, PR
+    38.)"""
+    if heads == 1:
+        return x
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, heads * d), 1)
+    mine = (lane >= j * d) & (lane < (j + 1) * d)
+    return jnp.where(mine, x.astype(jnp.float32), 0.0).astype(x.dtype)
+
+
+def _by_head(parts, d: int):
+    """One (rows, heads * d) array that is parts[j] in head j's lanes; a
+    part is (rows, heads * d), or (rows, 1) to spread over its lanes."""
+    out = parts[0]
+    if len(parts) > 1:
+        lane = jax.lax.broadcasted_iota(jnp.int32, (1, len(parts) * d), 1)
+        for j, part in enumerate(parts[1:], 1):
+            out = jnp.where(lane >= j * d, part, out)
+    return out
+
+
+def _tile_scores(s, keys_on_rows: bool, scale, mask=None,
                  kbias=None, qseg=None, kseg=None, q_pos=None, k_pos=None):
-    """Shared per-tile scaled+masked scores (ONE definition of the causal /
-    additive / kv-bias / segment masks for fwd and both bwd kernels):
-    (block_q, block_k), or its transpose where `keys_on_rows`. The vectors
+    """Shared per-tile scaled+masked scores of the raw products `s` (ONE
+    definition of the causal / additive / kv-bias / segment masks for fwd
+    and both bwd kernels): (block_q, block_k), or its transpose where
+    `keys_on_rows`. The vectors
     arrive oriented to it (per-query ones columns and per-key ones rows, or
     the other way round); `mask` is the (block_q, block_k) tile as stored;
     `q_pos` / `k_pos` are the positions the causal rule compares (None on
     a tile wholly below the diagonal)."""
-    s = (_dot(k_tile, q, _NT) if keys_on_rows else _dot(q, k_tile, _NT))
     s = s * scale
     if mask is not None:
         mask = mask.astype(jnp.float32)
@@ -282,36 +362,46 @@ def _walk(phases, tile, live=None):
         jax.lax.fori_loop(lo, hi, body, None)
 
 
-def _q_walk_kernel(*refs, block_k: int, causal: bool, scale: float,
-                   off: int, has_mask: bool, has_kbias: bool, has_seg: bool,
-                   has_blockmask: bool, backward: bool, with_lse: bool):
+def _q_walk_kernel(*refs, block_k: int, heads: int, causal: bool,
+                   scale: float, off: int, has_mask: bool, has_kbias: bool,
+                   has_seg: bool, has_blockmask: bool, backward: bool,
+                   with_lse: bool):
     """Forward (`backward` False) and dQ: one grid step folds the live K /
-    V tiles of its span into this Q block's accumulators.
-    dQ_i = scale * sum_j dS_ij K_j, dS = P * (dO V^T - delta)."""
-    n_lead = 4 if backward else 3
+    V tiles of its span into this Q block's accumulators, for each of the
+    block's `heads` in turn.
+    dQ_i = scale * sum_j dS_ij K_j, dS = P * (dO V^T - delta), with
+    delta_i = rowsum(dO_i * O_i) taken here from the O and dO blocks and
+    handed on, lane-dense like lse, to the dK/dV kernel.
+    Where heads share a block, a head's scores are its own lanes of Q (or
+    dO) against the whole K (or V) tile, and each product that lands in
+    the block's lanes, P V or dS K, is taken whole and kept in that head's
+    lanes of the one lane-dense accumulator."""
+    n_lead = 5 if backward else 3
     q_ref, k_ref, v_ref = refs[:3]
     mask_ref, kbias_ref, qseg_ref, kseg_ref, bm_ref, rest = _split_refs(
         refs, n_lead, has_mask, has_kbias, has_seg, has_blockmask)
     if backward:
-        do_ref = refs[3]
-        lse_ref, delta_ref, dq_ref, acc_ref = rest
+        do_ref, o_ref = refs[3:5]
+        lse_ref, dq_ref, delta_ref, acc_ref = rest
     elif with_lse:
         o_ref, lse_ref, m_ref, l_ref, acc_ref = rest
     else:
         (o_ref, m_ref, l_ref, acc_ref), lse_ref = rest, None
-    block_q, d = q_ref.shape[1:]
+    block_q, lanes = q_ref.shape[1:]
+    d = lanes // heads
+    each = range(heads)
     tiles = k_ref.shape[1] // block_k          # of this step's span
     qi, kj = pl.program_id(1), pl.program_id(2)
     guard = has_mask or has_kbias or has_seg or has_blockmask
 
     @pl.when(kj == 0)
     def _init():
-        acc_ref[:] = jnp.zeros((block_q, d), jnp.float32)
+        acc_ref[:] = jnp.zeros((block_q, lanes), jnp.float32)
         if not backward:
-            m_ref[:] = jnp.full((block_q, 1), NEG_INF, jnp.float32)
-            l_ref[:] = jnp.zeros((block_q, 1), jnp.float32)
+            m_ref[:] = jnp.full((heads, block_q, 1), NEG_INF, jnp.float32)
+            l_ref[:] = jnp.zeros((heads, block_q, 1), jnp.float32)
 
-    q = q_ref[0]
+    q = [_only_head(q_ref[0], j, heads, d) for j in each]
     # bottom-right-aligned causal offset: query i sees keys <= i + (sk - sq)
     q_start = off + qi * block_q
     phases = ((0, tiles, False),)
@@ -326,8 +416,16 @@ def _q_walk_kernel(*refs, block_k: int, causal: bool, scale: float,
         q_pos, k_pos = _positions(block_q, block_k, False)
     qseg = _column(qseg_ref) if has_seg else None
     if backward:
-        do = do_ref[0]
-        lse, delta = _column(lse_ref), _column(delta_ref)
+        do = [_only_head(do_ref[0], j, heads, d) for j in each]
+        lse = [_column(lse_ref, j) for j in each]
+        o_do = o_ref[0].astype(jnp.float32) * do_ref[0].astype(jnp.float32)
+        delta = [jnp.sum(_only_head(o_do, j, heads, d), axis=-1,
+                         keepdims=True) for j in each]
+
+        @pl.when(kj == 0)
+        def _hand_on():
+            for j in each:
+                delta_ref[j, 0] = delta[j][:, 0]
 
     def tile(t, on_diagonal):
         at = _tile_at(t, block_k, tiles)
@@ -336,37 +434,44 @@ def _q_walk_kernel(*refs, block_k: int, causal: bool, scale: float,
         if on_diagonal:     # q_start + i >= k_start + j, the shift on the row
             at_diagonal = dict(q_pos=q_pos, k_pos=k_pos + (
                 (kj * tiles + t) * block_k - q_start))
-        s = _tile_scores(
-            q, k_tile, False, scale,
-            mask=mask_ref[0, :, at] if has_mask else None,
-            kbias=kbias_ref[0, :, at] if has_kbias else None,
-            qseg=qseg, kseg=kseg_ref[0, :, at] if has_seg else None,
-            **at_diagonal)
-        if backward:
-            # hard-masked entries get exactly 0 even on fully-masked rows
-            # where the saved lse is itself ~NEG_INF (exp(s - lse) would
-            # be exp(0) = 1 there)
-            p = jnp.exp(s - lse)
+        kbias = kbias_ref[0, :, at] if has_kbias else None
+        kseg = kseg_ref[0, :, at] if has_seg else None
+        into, corr = [], []     # per head: its product, its rescale
+        for j in each:
+            s = _tile_scores(
+                _dot(q[j], k_tile, _NT), False, scale,
+                mask=mask_ref[j % mask_ref.shape[0], :, at] if has_mask
+                else None, kbias=kbias, qseg=qseg, kseg=kseg, **at_diagonal)
+            if backward:
+                # hard-masked entries get exactly 0 even on fully-masked
+                # rows where the saved lse is itself ~NEG_INF (exp(s - lse)
+                # would be exp(0) = 1 there)
+                p = jnp.exp(s - lse[j])
+                if guard:
+                    p = jnp.where(s <= MASKED_BELOW, 0.0, p)
+                ds = p * (_dot(do[j], v_tile, _NT) - delta[j])
+                into.append(_dot(ds.astype(k_tile.dtype), k_tile, _NN))
+                continue
+            m = m_ref[j]
+            new_m = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+            p = jnp.exp(s - new_m)
             if guard:
+                # on a row where every key so far is hard-masked, new_m is
+                # still NEG_INF and exp(s - new_m) would be exp(0) = 1 —
+                # force 0 so the row's l stays 0 and its output is exactly
+                # zero (causal alone needs none: the walk starts at tile 0,
+                # where every row sees key 0, and exp(NEG_INF - finite) is
+                # exactly 0)
                 p = jnp.where(s <= MASKED_BELOW, 0.0, p)
-            ds = p * (_dot(do, v_tile, _NT) - delta)
-            acc_ref[:] += _dot(ds.astype(k_tile.dtype), k_tile, _NN)
-            return
-        m = m_ref[:]
-        new_m = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
-        p = jnp.exp(s - new_m)
-        if guard:
-            # on a row where every key so far is hard-masked, new_m is
-            # still NEG_INF and exp(s - new_m) would be exp(0) = 1 — force
-            # 0 so the row's l stays 0 and its output is exactly zero
-            # (causal alone needs none: the walk starts at tile 0, where
-            # every row sees key 0, and exp(NEG_INF - finite) is exactly 0)
-            p = jnp.where(s <= MASKED_BELOW, 0.0, p)
-        corr = jnp.exp(m - new_m)
-        m_ref[:] = new_m
-        l_ref[:] = l_ref[:] * corr + jnp.sum(p, axis=-1, keepdims=True)
-        acc_ref[:] = acc_ref[:] * corr + _dot(p.astype(v_tile.dtype),
-                                              v_tile, _NN)
+            corr.append(jnp.exp(m - new_m))
+            m_ref[j] = new_m
+            l_ref[j] = l_ref[j] * corr[j] + jnp.sum(p, axis=-1,
+                                                    keepdims=True)
+            into.append(_dot(p.astype(v_tile.dtype), v_tile, _NN))
+        if backward:
+            acc_ref[:] += _by_head(into, d)
+        else:
+            acc_ref[:] = acc_ref[:] * _by_head(corr, d) + _by_head(into, d)
 
     _walk(phases, tile, None if bm_ref is None else
           lambda t: bm_ref[qi, kj * tiles + t] > 0)
@@ -376,34 +481,40 @@ def _q_walk_kernel(*refs, block_k: int, causal: bool, scale: float,
         if backward:
             dq_ref[0] = (acc_ref[:] * scale).astype(dq_ref.dtype)
             return
-        l = jnp.maximum(l_ref[:], 1e-30)
-        o_ref[0] = (acc_ref[:] / l).astype(o_ref.dtype)
+        l = [jnp.maximum(l_ref[j], 1e-30) for j in each]
+        o_ref[0] = (acc_ref[:] / _by_head(l, d)).astype(o_ref.dtype)
         if lse_ref is not None:
             # log-sum-exp per row, saved lane-dense for the backward kernels
-            lse_ref[0, 0] = (m_ref[:] + jnp.log(l))[:, 0]
+            for j in each:
+                lse_ref[j, 0] = (m_ref[j] + jnp.log(l[j]))[:, 0]
 
 
-def _k_walk_kernel(*refs, block_q: int, causal: bool, scale: float,
-                   off: int, has_mask: bool, has_kbias: bool, has_seg: bool,
-                   has_blockmask: bool):
+def _k_walk_kernel(*refs, block_q: int, heads: int, causal: bool,
+                   scale: float, off: int, has_mask: bool, has_kbias: bool,
+                   has_seg: bool, has_blockmask: bool):
     """dV_j = P^T dO; dK_j = scale * dS^T Q: one grid step folds the live
     Q / dO tiles of its span into this K block's accumulators, on
-    transposed score tiles (keys on rows, queries on lanes)."""
+    transposed score tiles (keys on rows, queries on lanes), for each of
+    the block's `heads` in turn: its own lanes of K and V against the whole
+    Q and dO tiles, P^T dO and dS^T Q kept in its lanes."""
     q_ref, k_ref, v_ref, do_ref = refs[:4]
     mask_ref, kbias_ref, qseg_ref, kseg_ref, bm_ref, rest = _split_refs(
         refs, 4, has_mask, has_kbias, has_seg, has_blockmask)
     lse_ref, delta_ref, dk_ref, dv_ref, dk_acc, dv_acc = rest
-    block_k, d = k_ref.shape[1:]
+    block_k, lanes = k_ref.shape[1:]
+    d = lanes // heads
+    each = range(heads)
     tiles = q_ref.shape[1] // block_q          # of this step's span
     kj, qm = pl.program_id(1), pl.program_id(2)
     guard = has_mask or has_kbias or has_seg or has_blockmask
 
     @pl.when(qm == 0)
     def _init():
-        dk_acc[:] = jnp.zeros((block_k, d), jnp.float32)
-        dv_acc[:] = jnp.zeros((block_k, d), jnp.float32)
+        dk_acc[:] = jnp.zeros((block_k, lanes), jnp.float32)
+        dv_acc[:] = jnp.zeros((block_k, lanes), jnp.float32)
 
-    k_tile, v_tile = k_ref[0], v_ref[0]
+    k_tile = [_only_head(k_ref[0], j, heads, d) for j in each]
+    v_tile = [_only_head(v_ref[0], j, heads, d) for j in each]
     k_start = kj * block_k - off
     phases = ((0, tiles, False),)
     if causal:
@@ -426,17 +537,21 @@ def _k_walk_kernel(*refs, block_q: int, causal: bool, scale: float,
         if on_diagonal:
             at_diagonal = dict(k_pos=k_pos, q_pos=q_pos + (
                 (qm * tiles + t) * block_q - k_start))
-        s = _tile_scores(
-            q, k_tile, True, scale,
-            mask=mask_ref[0, at, :] if has_mask else None, kbias=kbias,
-            qseg=qseg_ref[0, :, at] if has_seg else None, kseg=kseg,
-            **at_diagonal)
-        p = jnp.exp(s - lse_ref[0, :, at])
-        if guard:
-            p = jnp.where(s <= MASKED_BELOW, 0.0, p)
-        ds = p * (_dot(v_tile, do, _NT) - delta_ref[0, :, at])
-        dv_acc[:] += _dot(p.astype(do.dtype), do, _NN)
-        dk_acc[:] += _dot(ds.astype(q.dtype), q, _NN)
+        qseg = qseg_ref[0, :, at] if has_seg else None
+        dv, dk = [], []
+        for j in each:
+            s = _tile_scores(
+                _dot(k_tile[j], q, _NT), True, scale,
+                mask=mask_ref[j % mask_ref.shape[0], at, :] if has_mask
+                else None, kbias=kbias, qseg=qseg, kseg=kseg, **at_diagonal)
+            p = jnp.exp(s - lse_ref[j, :, at])
+            if guard:
+                p = jnp.where(s <= MASKED_BELOW, 0.0, p)
+            ds = p * (_dot(v_tile[j], do, _NT) - delta_ref[j, :, at])
+            dv.append(_dot(p.astype(do.dtype), do, _NN))
+            dk.append(_dot(ds.astype(q.dtype), q, _NN))
+        dv_acc[:] += _by_head(dv, d)
+        dk_acc[:] += _by_head(dk, d)
 
     _walk(phases, tile, None if bm_ref is None else
           lambda t: bm_ref[qm * tiles + t, kj] > 0)
@@ -447,14 +562,17 @@ def _k_walk_kernel(*refs, block_q: int, causal: bool, scale: float,
         dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
 
 
-def _specs(sch: Schedule, d: int, h: int, causal: bool, off: int,
+def _specs(sch: Schedule, d: int, h: int, rep: int, causal: bool, off: int,
            walk: str):
     """The BlockSpecs of one kernel's grid, by what they carry. walk 'k':
-    grid (bh, q tile, k span) — fwd and dQ; walk 'q': grid (bh, k tile,
-    q span) — dK/dV. 'q' / 'k': [bh, rows, d] operands of either side;
-    'stat_q': per-head query rows (lse, delta); 'row_q' / 'row_k':
+    grid (head block, q tile, k span) — fwd and dQ; walk 'q': grid (head
+    block, k tile, q span) — dK/dV. 'q' / 'k': the operands of either side,
+    `sch.heads_per_block` heads of [b, s, h*d] in place ('k' at the key /
+    value head that `rep` query heads share, 'dk' key rows at the query's
+    own head) or one head of a flat [b*h, s, d]; 'stat_q': those heads'
+    query rows of [b*h, 1, sq] (lse, delta); 'row_q' / 'row_k':
     per-batch-row vectors (segment ids, key bias); 'mask'(per_head): the
-    dense mask's slab."""
+    dense mask's slab, for each of the block's heads where it has heads."""
     bq, bk = sch.block_q, sch.block_k
     if walk == "k":
         nq_rows, nk_rows = bq, sch.span_k
@@ -474,22 +592,36 @@ def _specs(sch: Schedule, d: int, h: int, causal: bool, off: int,
             return g2, g1
 
     def spec(shape, index):
-        return pl.BlockSpec(shape, lambda bh, g1, g2: index(bh, *at(g1, g2)))
+        return pl.BlockSpec(shape, lambda g, g1, g2: index(g, *at(g1, g2)))
+
+    heads = sch.heads
+    per_row = h // heads                    # grid steps to a batch row
+    if sch.heads_per_block:
+        lanes = heads * d
+
+        def operand(g, rows, share=1):
+            return g // per_row, rows, g % per_row // share
+    else:
+        lanes = d
+
+        def operand(g, rows, share=1):
+            return g, rows, 0
 
     return dict(
-        q=spec((1, nq_rows, d), lambda bh, i, j: (bh, i, 0)),
-        k=spec((1, nk_rows, d), lambda bh, i, j: (bh, j, 0)),
+        q=spec((1, nq_rows, lanes), lambda g, i, j: operand(g, i)),
+        k=spec((1, nk_rows, lanes), lambda g, i, j: operand(g, j, rep)),
+        dk=spec((1, nk_rows, lanes), lambda g, i, j: operand(g, j)),
         # per-row vectors ride as [n, 1, s] with (1, 1, rows) blocks: a
         # bare (1, rows) block over [n, s] breaks the TPU block rule
         # (second-to-last block dim 8-aligned or the whole dim) for every
         # n but 1
-        stat_q=spec((1, 1, nq_rows), lambda bh, i, j: (bh, 0, i)),
-        row_q=spec((1, 1, nq_rows), lambda bh, i, j: (bh // h, 0, i)),
-        row_k=spec((1, 1, nk_rows), lambda bh, i, j: (bh // h, 0, j)),
+        stat_q=spec((heads, 1, nq_rows), lambda g, i, j: (g, 0, i)),
+        row_q=spec((1, 1, nq_rows), lambda g, i, j: (g // per_row, 0, i)),
+        row_k=spec((1, 1, nk_rows), lambda g, i, j: (g // per_row, 0, j)),
         mask=lambda per_head: spec(
-            (1, nq_rows, nk_rows),
-            (lambda bh, i, j: (bh, i, j)) if per_head else
-            (lambda bh, i, j: (bh // h, i, j))),
+            (heads if per_head else 1, nq_rows, nk_rows),
+            (lambda g, i, j: (g, i, j)) if per_head else
+            (lambda g, i, j: (g // per_row, i, j))),
     )
 
 
@@ -536,21 +668,40 @@ def _unflat(t, b):
     return jnp.swapaxes(t.reshape(b, bh // b, s, d), 1, 2)
 
 
+def _laid(t, sch: Schedule):
+    """What the kernels read of a [b, s, h, d] operand: the caller's own
+    memory as [b, s, h*d], or the flat copy."""
+    if sch.heads_per_block:
+        return t.reshape(t.shape[0], t.shape[1], -1)
+    return _flat(t)
+
+
+def _unlaid(t, b: int, h: int, sch: Schedule):
+    """A kernel's result back as [b, s, h, d]."""
+    if sch.heads_per_block:
+        return t.reshape(b, t.shape[1], h, -1)
+    return _unflat(t, b)
+
+
 def _flash_forward(q, k, v, mask, kbias, qseg, kseg, block_mask,
                    causal: bool, scale: float, sch: Schedule,
                    interpret: bool, with_lse: bool = False):
-    """q/k/v: [b, s, h, d] -> out [b, s, h, d] (+ lse [b*h, 1, sq] fp32)."""
+    """q [b, sq, h, d], k/v [b, sk, hk, d] -> out [b, sq, h, d] (+ lse
+    [b*h, 1, sq] fp32)."""
     b, sq, h, d = q.shape
     sk = k.shape[1]
-    specs = _specs(sch, d, h, causal, sk - sq, "k")
+    heads = sch.heads
+    specs = _specs(sch, d, h, h // k.shape[2], causal, sk - sq, "k")
     extra_in, extra_specs = _extra_inputs_specs(
         mask, kbias, qseg, kseg, specs, block_mask=block_mask)
     kernel = functools.partial(
-        _q_walk_kernel, block_k=sch.block_k, causal=causal, scale=scale,
-        off=sk - sq, has_mask=mask is not None, has_kbias=kbias is not None,
-        has_seg=qseg is not None, has_blockmask=block_mask is not None,
-        backward=False, with_lse=with_lse)
-    out_shape = jax.ShapeDtypeStruct((b * h, sq, d), q.dtype)
+        _q_walk_kernel, block_k=sch.block_k, heads=heads, causal=causal,
+        scale=scale, off=sk - sq, has_mask=mask is not None,
+        has_kbias=kbias is not None, has_seg=qseg is not None,
+        has_blockmask=block_mask is not None, backward=False,
+        with_lse=with_lse)
+    ql = _laid(q, sch)
+    out_shape = jax.ShapeDtypeStruct(ql.shape, q.dtype)
     out_specs = specs["q"]
     if with_lse:
         out_shape = (out_shape,
@@ -558,70 +709,89 @@ def _flash_forward(q, k, v, mask, kbias, qseg, kseg, block_mask,
         out_specs = (out_specs, specs["stat_q"])
     res = pl.pallas_call(
         kernel, out_shape=out_shape,
-        grid=(b * h, sq // sch.block_q, sk // sch.span_k),
+        grid=(b * h // heads, sq // sch.block_q, sk // sch.span_k),
         in_specs=[specs["q"], specs["k"], specs["k"]] + extra_specs,
         out_specs=out_specs,
-        scratch_shapes=[_scratch((sch.block_q, 1)),
-                        _scratch((sch.block_q, 1)),
-                        _scratch((sch.block_q, d))],
+        scratch_shapes=[_scratch((heads, sch.block_q, 1)),
+                        _scratch((heads, sch.block_q, 1)),
+                        _scratch((sch.block_q, heads * d))],
         interpret=interpret, name="flash_fwd",
-    )(_flat(q), _flat(k), _flat(v), *extra_in)
+    )(ql, _laid(k, sch), _laid(v, sch), *extra_in)
     if with_lse:
-        return _unflat(res[0], b), res[1]
-    return _unflat(res, b)
+        return _unlaid(res[0], b, h, sch), res[1]
+    return _unlaid(res, b, h, sch)
 
 
 def _flash_backward(q, k, v, o, do, lse, mask, kbias, qseg, kseg,
                     block_mask, causal, scale, sch: Schedule, interpret):
-    """Returns (dq, dk, dv) in the [b, s, h, d] layout."""
+    """Returns (dq, dk, dv) in the layouts of q, k and v."""
     b, sq, h, d = q.shape
-    sk = k.shape[1]
-    qf, kf, vf, of, dof = (_flat(t) for t in (q, k, v, o, do))
-    # delta_i = rowsum(dO_i * O_i) — cheap elementwise, XLA fuses it
-    delta = jnp.sum(of.astype(jnp.float32) * dof.astype(jnp.float32),
-                    axis=-1)[:, None]
-    common = dict(causal=causal, scale=scale, off=sk - sq,
+    sk, hk = k.shape[1:3]
+    heads = sch.heads
+    ql, kl, vl, dol, ol = (_laid(t, sch) for t in (q, k, v, do, o))
+    common = dict(heads=heads, causal=causal, scale=scale, off=sk - sq,
                   has_mask=mask is not None, has_kbias=kbias is not None,
                   has_seg=qseg is not None,
                   has_blockmask=block_mask is not None)
 
-    # ---- dQ: grid (bh, q tile, k span) -----------------------------------
-    specs = _specs(sch, d, h, causal, sk - sq, "k")
+    # ---- dQ: grid (head block, q tile, k span) ---------------------------
+    specs = _specs(sch, d, h, h // hk, causal, sk - sq, "k")
     extra_in, extra_specs = _extra_inputs_specs(
         mask, kbias, qseg, kseg, specs, block_mask=block_mask)
-    dq = pl.pallas_call(
+    dq, delta = pl.pallas_call(
         functools.partial(_q_walk_kernel, block_k=sch.block_k,
                           backward=True, with_lse=False, **common),
-        out_shape=jax.ShapeDtypeStruct((b * h, sq, d), q.dtype),
-        grid=(b * h, sq // sch.block_q, sk // sch.span_k),
-        in_specs=[specs["q"], specs["k"], specs["k"], specs["q"]]
-        + extra_specs + [specs["stat_q"], specs["stat_q"]],
-        out_specs=specs["q"],
-        scratch_shapes=[_scratch((sch.block_q, d))],
+        out_shape=(jax.ShapeDtypeStruct(ql.shape, q.dtype),
+                   jax.ShapeDtypeStruct(lse.shape, jnp.float32)),
+        grid=(b * h // heads, sq // sch.block_q, sk // sch.span_k),
+        in_specs=[specs["q"], specs["k"], specs["k"], specs["q"],
+                  specs["q"]] + extra_specs + [specs["stat_q"]],
+        out_specs=(specs["q"], specs["stat_q"]),
+        scratch_shapes=[_scratch((sch.block_q, heads * d))],
         interpret=interpret, name="flash_bwd_dq",
-    )(qf, kf, vf, dof, *extra_in, lse, delta)
+    )(ql, kl, vl, dol, ol, *extra_in, lse)
 
-    # ---- dK/dV: grid (bh, k tile, q span) --------------------------------
-    specs = _specs(sch, d, h, causal, sk - sq, "q")
+    # ---- dK/dV: grid (head block, k tile, q span), a result a QUERY head -
+    specs = _specs(sch, d, h, h // hk, causal, sk - sq, "q")
     extra_in, extra_specs = _extra_inputs_specs(
         mask, kbias, qseg, kseg, specs, block_mask=block_mask)
+    per_query = ql.shape[:1] + (sk,) + ql.shape[2:]
     dk, dv = pl.pallas_call(
         functools.partial(_k_walk_kernel, block_q=sch.block_q, **common),
-        out_shape=(jax.ShapeDtypeStruct((b * h, sk, d), k.dtype),
-                   jax.ShapeDtypeStruct((b * h, sk, d), v.dtype)),
-        grid=(b * h, sk // sch.block_k, sq // sch.span_q),
+        out_shape=(jax.ShapeDtypeStruct(per_query, k.dtype),
+                   jax.ShapeDtypeStruct(per_query, v.dtype)),
+        grid=(b * h // heads, sk // sch.block_k, sq // sch.span_q),
         in_specs=[specs["q"], specs["k"], specs["k"], specs["q"]]
         + extra_specs + [specs["stat_q"], specs["stat_q"]],
-        out_specs=(specs["k"], specs["k"]),
-        scratch_shapes=[_scratch((sch.block_k, d)),
-                        _scratch((sch.block_k, d))],
+        out_specs=(specs["dk"], specs["dk"]),
+        scratch_shapes=[_scratch((sch.block_k, heads * d)),
+                        _scratch((sch.block_k, heads * d))],
         interpret=interpret, name="flash_bwd_dkv",
-    )(qf, kf, vf, dof, *extra_in, lse, delta)
-    return _unflat(dq, b), _unflat(dk, b), _unflat(dv, b)
+    )(ql, kl, vl, dol, *extra_in, lse, delta)
+
+    def grouped(t):
+        """[b, sk, h, d] a query head -> the sum over each key/value
+        head's group, what the gradient of a repeated head is."""
+        if hk == h:
+            return t
+        t = t.reshape(b, sk, hk, h // hk, d).astype(jnp.float32)
+        return jnp.sum(t, axis=3).astype(k.dtype)
+
+    return (_unlaid(dq, b, h, sch), grouped(_unlaid(dk, b, h, sch)),
+            grouped(_unlaid(dv, b, h, sch)))
+
+
+def _a_head_a_query(k, v, h: int):
+    """k and v with each key/value head laid out once per query head of
+    its group (as they are where they have `h` heads already)."""
+    if k.shape[2] == h:
+        return k, v
+    return tuple(jnp.repeat(t, h // k.shape[2], axis=2) for t in (k, v))
 
 
 def _reference(q, k, v, causal, scale, mask=None, kbias=None, qseg=None,
                kseg=None):
+    k, v = _a_head_a_query(k, v, q.shape[2])
     qT = jnp.swapaxes(q, 1, 2).astype(jnp.float32)
     kT = jnp.swapaxes(k, 1, 2).astype(jnp.float32)
     vT = jnp.swapaxes(v, 1, 2).astype(jnp.float32)
@@ -682,11 +852,13 @@ _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
 def _operands_ok(q, k, v=None) -> bool:
-    # d % 8 == 0: Mosaic pads sub-128 lane dims, so head_dim 64 (the GPT
-    # 512/8 flagship and most small/medium models) runs the flash kernel
-    # instead of silently falling back to the O(seq^2) XLA path.
+    # d % 8 == 0: heads that fill no whole 128-lane block run on flat
+    # copies, where Mosaic pads the lanes, so any such head size runs the
+    # flash kernel instead of silently falling back to the O(seq^2) XLA
+    # path. k and v may have one head per group of query heads.
     return (q.shape[-1] % 8 == 0
-            and q.shape[:1] + q.shape[2:] == k.shape[:1] + k.shape[2:]
+            and (q.shape[0], q.shape[3]) == (k.shape[0], k.shape[3])
+            and q.shape[2] % k.shape[2] == 0
             and (v is None or tuple(v.shape) == tuple(k.shape)))
 
 
@@ -799,7 +971,7 @@ def validate_against_reference(shapes=DEFAULT_CHECK_SHAPES, interpret=None,
                 scale=scale):
             qs, ks = (segs, segs) if segs is not None else (None, None)
             sch = schedule(q.shape, k.shape, q.dtype, causal,
-                           mask=mask is not None)
+                           mask=0 if mask is None else mask.shape[1])
             return _flash(q, k, v, mask, kbias, qs, ks, None, causal,
                           scale, sch, interpret)
 
@@ -827,18 +999,30 @@ def validate_against_reference(shapes=DEFAULT_CHECK_SHAPES, interpret=None,
 _FALLBACK_WARNED: set = set()
 
 
-def _log_fallback(q, k):
-    """The silent-fallback condition is a dead-kernel bug magnet — warn once
-    per shape so it is visible which configs miss the flash path."""
-    key = (tuple(q.shape), tuple(k.shape))
+def _warn_once(q, k, what: str):
+    key = (tuple(q.shape), tuple(k.shape), what)
     if key not in _FALLBACK_WARNED:
         _FALLBACK_WARNED.add(key)
         import warnings
 
         warnings.warn(
             f"flash_attention: shapes q={tuple(q.shape)} k={tuple(k.shape)} "
-            "don't tile; using the O(seq^2) XLA reference path",
-            stacklevel=3)
+            + what, stacklevel=4)
+
+
+def _log_fallback(q, k):
+    """The silent-fallback condition is a dead-kernel bug magnet — warn once
+    per shape so it is visible which configs miss the flash path."""
+    _warn_once(q, k, "don't tile; using the O(seq^2) XLA reference path")
+
+
+def _log_flat(q, k):
+    """Likewise for the layout: once per shape whose heads fill no whole
+    128-lane block of [b, s, h*d], so that the kernels run on transposed
+    copies, twelve a call and its gradient."""
+    _warn_once(q, k, "fill no whole 128-lane block a head (or 128 // "
+               "head_dim of them); the kernels run on flat [b*h, s, d] "
+               "copies")
 
 
 def flash_attention(q, k, v, causal: bool = True, scale=None,
@@ -870,6 +1054,10 @@ def flash_attention(q, k, v, causal: bool = True, scale=None,
     scale = scale if scale is not None else 1.0 / math.sqrt(d)
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
+    ok = not (causal and sq > sk) and _operands_ok(q, k, v)
+    if ok and not _heads_per_block(h, k.shape[2], d):
+        # a shared key/value head is read in place only as a whole block
+        k, v = _a_head_a_query(k, v, h)
     kbias = None
     if mask is not None:
         mask, kbias = _canon_mask(mask, b, h, sq, sk)
@@ -886,26 +1074,30 @@ def flash_attention(q, k, v, causal: bool = True, scale=None,
     # bottom-right alignment gives the early queries of a causal sq > sk
     # call ZERO visible keys — handled by the masked-row guard, but parity
     # with the XLA path is simplest via the reference for this rare shape
+    # in a program compiled for a mesh each device runs the kernels on its
+    # own batch rows ('dp') and heads ('tp'): the schedule is a shard's
+    hk = k.shape[2]
+    mesh, bax, hax = _program_mesh_axes(b, hk) or (None, None, None)
+    nb, nh = (mesh.shape[a] if a else 1 for a in (bax, hax))
     sch = None
-    if not (causal and sq > sk) and _operands_ok(q, k, v):
-        sch = schedule(q.shape, k.shape, q.dtype, causal,
-                       mask=mask is not None,
+    if ok:
+        sch = schedule((b // nb, sq, h // nh, d), (b // nb, sk, hk // nh, d),
+                       q.dtype, causal,
+                       mask=0 if mask is None else mask.shape[1],
                        block_mask_shape=None if block_mask is None
                        else block_mask.shape,
                        block_q=block_q, block_k=block_k)
     if sch is None:
         _log_fallback(q, k)
         return _reference(q, k, v, causal, scale, mask, kbias, qseg, kseg)
+    if not sch.heads_per_block:
+        _log_flat(q, k)
     statics = (causal, scale, sch, interpret)
-    placed = _program_mesh_axes(b, h)
-    if placed is None:
+    if mesh is None:
         return _flash(q, k, v, mask, kbias, qseg, kseg, block_mask,
                       *statics)
     # GSPMD cannot partition a Mosaic kernel ("wrap the call in a
-    # shard_map"): in a program compiled for a mesh each device runs the
-    # kernel on its own batch rows ('dp') and heads ('tp'), every mesh
-    # axis manual
-    mesh, bax, hax = placed
+    # shard_map"), so every mesh axis is manual
     qkv, row = P(bax, None, hax, None), P(bax, None)
     extras = {n: x for n, x in (("mask", mask), ("kbias", kbias),
                                 ("qseg", qseg), ("kseg", kseg),
@@ -931,7 +1123,8 @@ def _program_mesh_axes(b: int, h: int):
     """(mesh, batch axis, head axis) when the program being traced computes
     on a multi-device mesh (parallel.mesh.program_mesh) and the call is
     not already inside a manual region; None otherwise. An axis is named
-    only where it divides the dimension; unnamed axes compute replicated."""
+    only where it divides the dimension (`h`: the key/value heads, which
+    divide the queries'); unnamed axes compute replicated."""
     from paddle_tpu.parallel.mesh import program_mesh
 
     mesh = program_mesh()

@@ -129,15 +129,17 @@ GPT2_CELL = (28, SEQ, N_HEADS, HEAD_DIM)
 GPT3_CELL = (8, SEQ, 16, 128)
 
 
-def _flash(dtype, backward, mode="dense", shape=None):
+def _flash(dtype, backward, mode="dense", shape=None, kv_heads=None):
     """mode: the mask forms chip_smoke's kernel phase validates at batch
     8 — "padbias" (a [b, 1, 1, sk] key-padding mask, streamed as a per-key
     bias) and "segments" (packed-sequence ids) ride per-batch-row vectors
     whose blocks broke the TPU block rule at every batch but 1; "mask" is
     flashmask_attention's dense [1, 1, sq, sk] float32 bias, streamed a
-    slab a grid step (the tile rule has to count it). `shape`: a training
+    slab a grid step (the tile rule has to count it), "headmask" one of
+    [b, h, sq, sk], a slab a head of the block. `shape`: a training
     cell's real [b, s, h, d]: a tile rule that overflows scoped VMEM has
-    to fail here, not on the chip."""
+    to fail here, not on the chip. `kv_heads`: k and v with fewer heads,
+    each read in place by its group of query heads."""
     from paddle_tpu.ops.pallas.flash_attention import flash_attention
 
     b, s, h, d = shape = shape or (BATCH, SEQ, N_HEADS, HEAD_DIM)
@@ -150,6 +152,8 @@ def _flash(dtype, backward, mode="dense", shape=None):
             kw["segment_ids"] = jnp.zeros((b, s), jnp.int32)
         if mode == "mask":
             kw["mask"] = jnp.zeros((1, 1, s, s), jnp.float32)
+        if mode == "headmask":
+            kw["mask"] = jnp.zeros((b, h, s, s), jnp.float32)
         return flash_attention(q, k, v, causal=mode != "padbias",
                                interpret=False, **kw)
 
@@ -157,7 +161,9 @@ def _flash(dtype, backward, mode="dense", shape=None):
         return jax.grad(lambda *a: jnp.sum(fwd(*a).astype(jnp.float32) ** 2),
                         argnums=(0, 1, 2))(q, k, v)
 
-    return (fwd_bwd if backward else fwd), [(shape, dtype)] * 3
+    kv = (b, s, kv_heads or h, d)
+    return (fwd_bwd if backward else fwd), [(shape, dtype), (kv, dtype),
+                                            (kv, dtype)]
 
 
 def _grouped(dtype, backward, rows=10240, width=2048, groups=8, block=256):
@@ -261,9 +267,19 @@ CASES = {
                                                HEAD_DIM)),
     "auto-decode-12x64": _auto_decode_kernel,
     # the sparse training cell: its attention in the latent (one sequence
-    # of 8192 keys, 8 heads of 128) and its grouped expert products
+    # of 8192 keys, 8 query heads of 128 on 2 key/value heads, each read
+    # where it lies by its four query heads) and its grouped expert products
     "flash-bf16-zaya-cell-fwd-bwd": lambda mp: _flash(
-        jnp.bfloat16, True, shape=(1, 8 * SEQ, 8, 128)),
+        jnp.bfloat16, True, shape=(1, 8 * SEQ, 8, 128), kv_heads=2),
+    # heads that share a 128-lane block: four of 32; two of 64 under a mask
+    # a head (a slab each) in float32, where the rule halves the tiles; and
+    # a head size that fills no block, on the flat copies
+    "flash-bf16-4x32-fwd-bwd": lambda mp: _flash(
+        jnp.bfloat16, True, shape=(BATCH, SEQ, 4, 32)),
+    "flash-fp32-headmask-fwd-bwd": lambda mp: _flash(
+        jnp.float32, True, "headmask", shape=(2, SEQ, 4, HEAD_DIM)),
+    "flash-bf16-flat-2x96-fwd-bwd": lambda mp: _flash(
+        jnp.bfloat16, True, shape=(BATCH, SEQ, 2, 96)),
     "grouped-bf16-zaya-cell-fwd": lambda mp: _grouped(jnp.bfloat16, False),
     "grouped-bf16-zaya-cell-fwd-bwd": lambda mp: _grouped(jnp.bfloat16,
                                                           True),
@@ -281,12 +297,12 @@ def test_kernel_compiles_for_v5e(case, v5e, monkeypatch):
 
 
 # GPT-2's toy batch in float32, and gpt3-1.3b.train-4chip's own attention
-# in bfloat16; each device's kernels see its own batch rows and heads: 4 x 6
-# of 64, 4 x 8 of 128
+# in bfloat16; each device's kernels see its own batch rows and heads where
+# they lie, [b, s, h*d]: 4 x 6 of 64, 4 x 8 of 128
 MESH_CASES = {
     "toy-fp32": (jnp.float32, None,
-                 f"f32[{BATCH // 2 * N_HEADS // 2},{SEQ},{HEAD_DIM}]"),
-    "gpt3-cell-bf16": (jnp.bfloat16, GPT3_CELL, f"bf16[{4 * 8},{SEQ},128]"),
+                 f"f32[{BATCH // 2},{SEQ},{N_HEADS // 2 * HEAD_DIM}]"),
+    "gpt3-cell-bf16": (jnp.bfloat16, GPT3_CELL, f"bf16[4,{SEQ},{8 * 128}]"),
 }
 
 

@@ -5,10 +5,15 @@ from a call's shapes, and parity of forward and all three gradients with
 and bfloat16. The sequences are several tiles long, so the walks cross the
 diagonal bound, a span wholly above it (a grid step whose index map clamps
 and which folds nothing), a tile the block table skips, and rows with no
-visible key."""
+visible key. The same over the LAYOUTS: heads read where the caller left
+them, [b, s, h*d], one to a 128-lane block, two or four sharing one, a
+key/value head shared by a group of query heads; and the head sizes and
+counts that stay on flat [b*h, s, d] copies, which warn once a shape. The
+jaxpr of a step says what stands around the kernels."""
 
 import functools
 import math
+import warnings
 
 import jax
 import jax.numpy as jnp
@@ -24,23 +29,30 @@ S = 512
 TILES = {"t128": (128, 128, None), "t128-span256": (128, 128, 256),
          "t256x128": (256, 128, None), "rule": (None, None, None)}
 MODES = ("causal", "causal-sq<sk", "kbias", "mask", "segments", "blocks")
+# (query heads, key/value heads, head size) -> heads a block in place, 0
+# for the flat copies: two heads of 32 do not fill a 128-lane block, three
+# of 64 do not pair, 96 divides nothing
+LAYOUTS = {"2x32": ((2, 2, 32), 0), "4x64": ((4, 4, 64), 2),
+           "2x128": ((2, 2, 128), 1), "4x32": ((4, 4, 32), 4),
+           "8over2x128": ((8, 2, 128), 1), "3x64": ((3, 3, 64), 0),
+           "2x96": ((2, 2, 96), 0)}
 
 
-def _case(mode, dtype, seed=0):
+def _case(mode, dtype, seed=0, heads=LAYOUTS["2x32"][0]):
     """(q, k, v, causal, wrapper keywords, reference keywords)."""
     rng = np.random.default_rng(seed)
-    b, h, d = 1, 2, 32
+    b, (h, hk, d) = 1, heads
     sq = S // 2 if mode == "causal-sq<sk" else S
     q = jnp.asarray(rng.standard_normal((b, sq, h, d)), dtype)
-    k, v = (jnp.asarray(rng.standard_normal((b, S, h, d)), dtype)
+    k, v = (jnp.asarray(rng.standard_normal((b, S, hk, d)), dtype)
             for _ in range(2))
     causal, kw, ref = mode.startswith("causal"), {}, {}
     if mode == "kbias":                     # the last keys are padding
         bias = jnp.where(jnp.arange(S) < S - 72, 0.0, fa.NEG_INF)
         kw["mask"] = bias[None, None, None, :].astype(jnp.float32)
         ref["kbias"] = jnp.broadcast_to(bias, (b, S)).astype(jnp.float32)
-    if mode == "mask":                      # random, forty rows see nothing
-        keep = rng.random((1, 1, sq, S)) > 0.3
+    if mode in ("mask", "headmask"):        # random, forty rows see nothing
+        keep = rng.random((1, h if mode == "headmask" else 1, sq, S)) > 0.3
         keep[:, :, 100:140, :] = False
         ref["mask"] = kw["mask"] = jnp.asarray(
             np.where(keep, 0.0, fa.NEG_INF), jnp.float32)
@@ -60,13 +72,30 @@ def _case(mode, dtype, seed=0):
     return q, k, v, causal, kw, ref
 
 
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
-                         ids=["f32", "bf16"])
-@pytest.mark.parametrize("mode", MODES)
-@pytest.mark.parametrize("tiles", sorted(TILES))
-def test_forward_and_gradients_match_reference(tiles, mode, dtype,
+DTYPES = {"f32": jnp.float32, "bf16": jnp.bfloat16}
+# every tile choice on the toy heads; forced tiles and the rule's on each
+# other layout, with a mask a head where heads share a block or a key
+PARITY = [(t, m, dt, "2x32") for t in sorted(TILES) for m in MODES
+          for dt in DTYPES]
+PARITY += [(t, m, dt, lay) for lay in sorted(LAYOUTS) if lay != "2x32"
+           for t in ("t128", "rule") for m in MODES for dt in DTYPES
+           if LAYOUTS[lay][1] or (m, t) in (("causal", "rule"),
+                                            ("mask", "t128"))]
+PARITY += [("t128", "headmask", dt, lay) for lay in ("4x64", "8over2x128")
+           for dt in DTYPES]
+
+
+@pytest.mark.parametrize(
+    "tiles,mode,dtype,layout",
+    [pytest.param(t, m, DTYPES[dt], lay, id="-".join(
+        (t, m, dt) + ((lay,) if lay != "2x32" else ())))
+     for t, m, dt, lay in PARITY])
+def test_forward_and_gradients_match_reference(tiles, mode, dtype, layout,
                                                monkeypatch):
-    q, k, v, causal, kw, ref = _case(mode, dtype)
+    heads, in_place = LAYOUTS[layout]
+    q, k, v, causal, kw, ref = _case(mode, dtype, heads=heads)
+    sch = fa.schedule(q.shape, k.shape, dtype, causal)
+    assert sch.heads_per_block == in_place
     block_q, block_k, span = TILES[tiles]
     if span:        # what the rule does to a sequence that outgrows VMEM
         monkeypatch.setattr(fa, "schedule",
@@ -76,12 +105,20 @@ def test_forward_and_gradients_match_reference(tiles, mode, dtype,
                     jnp.float32)
 
     def run(f):
-        out, vjp = jax.vjp(f, q, k, v)
-        return (out,) + vjp(w.astype(out.dtype))
+        def out_and_gradients(q, k, v):
+            out, vjp = jax.vjp(f, q, k, v)
+            return (out,) + vjp(w.astype(out.dtype))
+        return jax.jit(out_and_gradients)(q, k, v)  # one compile, not three
 
-    got = run(lambda q, k, v: fa.flash_attention(
-        q, k, v, causal=causal, interpret=True, block_q=block_q,
-        block_k=block_k, **kw))
+    with warnings.catch_warnings(record=True) as said:
+        warnings.simplefilter("always")
+        got = run(lambda q, k, v: fa.flash_attention(
+            q, k, v, causal=causal, interpret=True, block_q=block_q,
+            block_k=block_k, **kw))
+    # a flat shape says so (once: test_a_flat_shape_warns_once), and no
+    # shape falls back to the reference
+    assert not any("XLA reference" in str(w.message) for w in said)
+    assert in_place == 0 or not any("flat" in str(w.message) for w in said)
     want = run(lambda q, k, v: fa._reference(q, k, v, causal, scale, **ref))
     # float32 operands: products exact on the CPU; bfloat16 operands: P and
     # dS are rounded to bfloat16 for their second matmul (2^-9 each)
@@ -91,30 +128,123 @@ def test_forward_and_gradients_match_reference(tiles, mode, dtype,
         assert np.isfinite(a).all(), name
         bound = tol * max(1.0, float(np.abs(r).max()))
         assert float(np.abs(a - r).max()) <= bound, (name, tiles, mode)
-    if mode == "mask":      # rows with no visible key: exactly zero
+    if mode in ("mask", "headmask"):  # rows with no visible key: zero
         assert not np.asarray(got[0], np.float32)[0, 100:140].any()
         assert not np.asarray(got[1], np.float32)[0, 100:140].any()
 
 
-CELLS = {"gpt2-124m.train": ((28, 1024, 12, 64), 336),
-         "gpt3-1.3b.train-4chip, a shard": ((4, 1024, 8, 128), 32)}
+# q's shape, key/value heads, heads a block in place, spans a sequence
+CELLS = {"gpt2-124m.train": ((28, 1024, 12, 64), 12, 2, 1),
+         "gpt3-1.3b.train-4chip, a shard": ((4, 1024, 8, 128), 8, 1, 1),
+         "zaya1-8b.train-8k": ((1, 8192, 8, 128), 2, 1, 2)}
 
 
 @pytest.mark.parametrize("cell", sorted(CELLS))
 def test_schedule_at_the_training_cells(cell):
-    """512-wide tiles, the whole sequence in one span, two grid steps a
-    head and kernel where the 128 x 128 grid took 64, none of them dead."""
-    shape, heads = CELLS[cell]
-    sch = fa.schedule(shape, shape, jnp.bfloat16, True)
+    """512-wide tiles and 1024 keys in one span: two grid steps a block of
+    heads and kernel where the 128 x 128 grid took 64 a head, none of them
+    dead (ZAYA's 8192 keys sit in VMEM as two spans, and a tile of the
+    first half steps once over the span above its diagonal). Every cell's
+    heads are read in place, two of 64 to a block, one of 128 (ZAYA's two
+    key/value heads by four query heads each)."""
+    shape, kv_heads, in_place, spans = CELLS[cell]
+    b, s, h, d = shape
+    sch = fa.schedule(shape, (b, s, kv_heads, d), jnp.bfloat16, True)
+    assert sch.heads_per_block == in_place
     assert (sch.block_q, sch.block_k) == (512, 512)
-    assert (sch.span_q, sch.span_k) == (1024, 1024)
-    before = heads * (1024 // 128) ** 2        # 21,504 at the one-chip cell
-    assert sch.steps == (heads * 2,) * 3
-    assert all(10 * s <= before for s in sch.steps)
-    assert sch.dead_steps == (0, 0, 0)
-    assert sch.tiles == (heads * 3,) * 3       # the diagonal leaves 3 of 4
-    assert fa._vmem_bytes(512, 1024, 512, 512, shape[-1], 2,
-                          False) <= fa.VMEM_BUDGET
+    assert (sch.span_q, sch.span_k) == (s // spans,) * 2
+    blocks, n = b * h // in_place, s // 512
+    assert sch.steps == (blocks * n * spans,) * 3
+    assert all(10 * steps <= b * h * (s // 128) ** 2 for steps in sch.steps)
+    assert sch.dead_steps == (blocks * n * (spans - 1) // 2,) * 3
+    # the diagonal leaves 3 tiles of 4 at 1024 keys, 136 of 256 at 8192
+    assert sch.tiles == (b * h * n * (n + 1) // 2,) * 3
+    assert fa._vmem_bytes(512, s // spans, 512, 512, d, 2, 0,
+                          in_place) <= fa.VMEM_BUDGET
+    assert spans == 1 or fa._vmem_bytes(512, s, 512, 512, d, 2, 0,
+                                        in_place) > fa.VMEM_BUDGET
+
+
+@pytest.mark.parametrize("layout", ["3x64", "2x96"])
+def test_a_flat_shape_warns_once(layout):
+    """The layout is chosen per compiled program, so its counter is the
+    schedule's field and ONE warning a shape."""
+    h, hk, d = LAYOUTS[layout][0]
+    q = jnp.zeros((2, 128, h, d), jnp.float32)      # a shape of its own
+    assert fa.schedule(q.shape, q.shape, q.dtype, True).heads_per_block == 0
+    with warnings.catch_warnings(record=True) as said:
+        warnings.simplefilter("always")
+        for _ in range(2):
+            fa.flash_attention(q, q, q, interpret=True)
+    flat = [w for w in said if "flat [b*h, s, d] copies" in str(w.message)]
+    assert len(flat) == 1 and str(q.shape) in str(flat[0].message)
+
+
+def _equations(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs its equations carry
+    (custom_vjp, pjit, ...), a kernel's own body apart."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        if eqn.primitive.name != "pallas_call":
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from _equations(sub)
+
+
+def test_no_transposed_copy_stands_around_the_kernels():
+    """At the one-chip cell's shape a step's three kernels read q, k, v, o
+    and dO as [b, s, h*d], a free reshape of what the projections wrote,
+    and write o, dq, dk, dv the same way: no rank-4 transpose is left."""
+    shape = (28, 1024, 12, 64)
+    args = [jax.ShapeDtypeStruct(shape, jnp.bfloat16)] * 3
+    jaxpr = jax.make_jaxpr(jax.value_and_grad(
+        lambda q, k, v: jnp.sum(fa.flash_attention(
+            q, k, v, interpret=True).astype(jnp.float32)),
+        argnums=(0, 1, 2)))(*args).jaxpr
+    eqns = list(_equations(jaxpr))
+    kernels = [e for e in eqns if e.primitive.name == "pallas_call"]
+    assert len(kernels) == 3
+    for e in kernels:
+        big = [v.aval.shape for v in list(e.invars) + list(e.outvars)
+               if v.aval.dtype == jnp.bfloat16]
+        assert big and set(big) == {(28, 1024, 12 * 64)}
+    assert not [e for e in eqns if e.primitive.name == "transpose"
+                and len(e.invars[0].aval.shape) >= 4]
+
+
+def test_zayas_attention_repeats_no_key_or_value_head(monkeypatch):
+    """`cca_attention` hands SDPA its 2 key/value heads as they are: the
+    kernels' k and v operands are [b, s, 2 * 128] beside q's [b, s, 8 *
+    128]: no copy of a head a query exists for them to read."""
+    from paddle_tpu.models import zaya
+    from paddle_tpu.ops import impl
+
+    monkeypatch.setattr(impl, "_flash_enabled", lambda: True)
+    cfg = zaya.ZayaConfig(
+        vocab_size=64, hidden_size=256, num_hidden_layers=1,
+        num_attention_heads=8, num_key_value_heads=2, head_dim=128,
+        cca_time0=2, cca_time1=2, partial_rotary_factor=0.5,
+        rope_theta=5e6, num_experts=2, num_experts_per_tok=1,
+        moe_intermediate_size=32, router_hidden_size=16, rms_norm_eps=1e-5,
+        experts_held=2, first_expert=0)
+    model = zaya.ZayaForCausalLM(cfg)
+    params = {k: jax.ShapeDtypeStruct(t.shape, t._value.dtype)
+              for k, t in model.named_parameters()}
+    pre = "layers.0.attn."
+    u = jax.ShapeDtypeStruct((1, 256, 256), jnp.float32)
+    jaxpr = jax.make_jaxpr(jax.grad(
+        lambda p, u: jnp.sum(zaya.cca_attention(cfg, p, pre, u)),
+        argnums=(0, 1)))(params, u).jaxpr
+    eqns = list(_equations(jaxpr))
+    kernels = [e for e in eqns if e.primitive.name == "pallas_call"]
+    assert len(kernels) == 3
+    for e in kernels:       # q, k, v lead every kernel's operands
+        assert [v.aval.shape for v in e.invars[:3]] == [
+            (1, 256, 8 * 128), (1, 256, 2 * 128), (1, 256, 2 * 128)]
+    # (the second convolution transposes its 8 + 2 heads; nothing does q's
+    # 8 or k's and v's 2)
+    assert not [e for e in eqns if e.primitive.name == "transpose"
+                and e.invars[0].aval.shape in ((1, 256, 8, 128),
+                                               (1, 256, 2, 128))]
 
 
 @pytest.mark.parametrize("keys", [64, 100, 128, 256])
@@ -125,7 +255,9 @@ def test_short_sequence_is_its_own_tile(keys):
     shape = (2, keys, 4, 64)
     sch = fa.schedule(shape, shape, jnp.float32, True)
     assert (sch.block_q, sch.block_k, sch.span_q, sch.span_k) == (keys,) * 4
-    assert sch.steps == (8, 8, 8) and sch.dead_steps == (0, 0, 0)
+    # two heads of 64 to a block: a grid step a pair
+    assert sch.heads_per_block == 2
+    assert sch.steps == (4, 4, 4) and sch.dead_steps == (0, 0, 0)
     rng = np.random.default_rng(keys)
     q, k, v = (jnp.asarray(rng.standard_normal(shape), jnp.float32)
                for _ in range(3))
@@ -141,21 +273,31 @@ def test_short_sequence_is_its_own_tile(keys):
 
 
 def test_schedule_follows_masks_tables_and_length():
-    shape = (8, 1024, 12, 64)
+    wide, shape = (8, 1024, 12, 128), (8, 1024, 12, 64)
     # a dense mask streams a (block_q, span) float32 slab, which the rule
     # counts: with float32 operands the span shrinks to a tile, and the
     # step above the diagonal is stepped but copies nothing
-    sch = fa.schedule(shape, shape, jnp.float32, True, mask=True)
+    sch = fa.schedule(wide, wide, jnp.float32, True, mask=True)
     assert (sch.block_q, sch.span_k) == (512, 512)
-    assert fa._vmem_bytes(512, 512, 512, 512, 64, 4, True) <= fa.VMEM_BUDGET
-    assert fa._vmem_bytes(512, 1024, 512, 512, 64, 4, True) > fa.VMEM_BUDGET
+    assert fa._vmem_bytes(512, 512, 512, 512, 128, 4, 1) <= fa.VMEM_BUDGET
+    assert fa._vmem_bytes(512, 1024, 512, 512, 128, 4, 1) > fa.VMEM_BUDGET
     assert sch.steps == (96 * 4,) * 3 and sch.dead_steps == (96,) * 3
     assert sch.tiles == (96 * 3,) * 3
+    # two heads of 64 in a block keep each head's own lanes of the
+    # grid-side operands besides: one 512-wide tile no longer fits with
+    # the slab, a mask a head doubles the slab, and the tiles halve
+    assert fa._vmem_bytes(512, 512, 512, 512, 64, 4, 1, 2) > fa.VMEM_BUDGET
+    for heads_of_mask in (1, 12):
+        sch = fa.schedule(shape, shape, jnp.float32, True,
+                          mask=heads_of_mask)
+        assert sch.heads_per_block == 2
+        assert (sch.block_q, sch.block_k, sch.span_k) == (256, 256, 1024)
+        assert sch.steps == (48 * 4,) * 3 and sch.dead_steps == (0, 0, 0)
     # a block table's granularity is the caller's
     sch = fa.schedule(shape, shape, jnp.bfloat16, False,
                       block_mask_shape=(8, 8), block_q=512)
     assert (sch.block_q, sch.block_k, sch.span_k) == (128, 128, 1024)
-    assert sch.steps == (96 * 8,) * 3
+    assert sch.steps == (48 * 8,) * 3           # a step a pair of heads
     # a sequence too long to sit in VMEM whole keeps a third grid axis
     long = (1, 16384, 8, 128)
     sch = fa.schedule(long, long, jnp.bfloat16, True)
