@@ -333,8 +333,11 @@ def _to_host(x) -> np.ndarray:
     performs funnels through here (greedy_grid's packed pull, the lazy
     full-logits row fetch, the multi-step horizon drain), so a test can
     monkeypatch this one symbol and count exactly how many times a step
-    blocked on the device (the one-sync-per-step pin)."""
-    return np.asarray(x)
+    blocked on the device (the one-sync-per-step pin). Its `drain.fetch`
+    span ends when the host holds the values: the later of the two edges
+    that bound the device's clock (bench/README-idle.md)."""
+    with _prof.span("drain.fetch"):
+        return np.asarray(x)
 
 
 def greedy_grid(logits):
@@ -348,9 +351,11 @@ def greedy_grid(logits):
     np.argmax (first max wins), which the batched-sampling pin test
     asserts against the host path `sample_token` / `naive_generate`
     use."""
-    packed = _to_host(jnp.stack(
-        [jnp.argmax(logits, axis=-1).astype(jnp.int32),
-         jnp.all(jnp.isfinite(logits), axis=-1).astype(jnp.int32)]))
+    with _prof.span("drain.enqueue"):     # the host dispatching the pass
+        stacked = jnp.stack(
+            [jnp.argmax(logits, axis=-1).astype(jnp.int32),
+             jnp.all(jnp.isfinite(logits), axis=-1).astype(jnp.int32)])
+    packed = _to_host(stacked)
     return packed[0], packed[1].astype(bool)
 
 
@@ -699,8 +704,10 @@ class ServingEngine:
                 # the launches drained just now, or earlier ones whose
                 # logits nobody read: ready, so no further wait
                 counts, self._step_counts[:] = list(self._step_counts), []
+                with _prof.span("drain.fetch", what="counts"):
+                    counts = jax.device_get(counts)
                 for name, n in zip(self.runner.COUNTS,
-                                   np.sum(jax.device_get(counts), axis=0)):
+                                   np.sum(counts, axis=0)):
                     getattr(self.metrics, name).inc(float(n))
         self.metrics.host_syncs.inc()
         return out

@@ -1430,9 +1430,10 @@ class PagedModelRunner:
             # tracing and compilation happen here, not at jax.jit above:
             # a failure is the program's, not a transient device fault
             try:
-                # trace + lower + compile (or the compile cache's load)
-                # and this call's dispatch: always recorded, a child of
-                # the step that caused it
+                # trace + lower + compile (or the compile cache's load),
+                # this call's dispatch and whatever that dispatch waits
+                # for: the host's time in a first call, not compiler time.
+                # Always recorded, a child of the step that caused it
                 with _prof.always_span("runner.compile", kind=kind,
                                        key=shape_key):
                     out = jitted(*args)
@@ -1475,40 +1476,48 @@ class PagedModelRunner:
         with _prof.span("runner.launch") as launch:
             t = len(tokens)
             tb = bucket_len(t)
-            padded = np.zeros((1, tb), np.int32)
-            padded[0, :t] = tokens
-            self._account_attn(self._attn_impl_for(tb),
-                               np.asarray([start_pos]), np.asarray([t]),
-                               len(table_row))
-            self._account_comm(tb)
-            fn = self._jitted("prefill", tb)
-            launch.set(kind="prefill", key=tb)
-            # host operands go to the jitted fn as-is — jit commits them in
-            # one hop; a jnp.asarray(np.asarray(...)) round-trip here used to
-            # stage an extra host copy per call (ISSUE 6 satellite). Sharded
-            # runners stage them in ONE replicated device_put (ISSUE 7)
-            toks, table = self._stage(padded,
-                                      np.asarray(table_row, np.int32)[None])
-            start = np.int32(start_pos) if slot is None else np.asarray(
-                [start_pos, slot], np.int32)
-            return self._emit(fn(self.params, toks, table,
-                                 np.int32(t), start, pools))
+            with _prof.span("runner.account"):
+                self._account_attn(self._attn_impl_for(tb),
+                                   np.asarray([start_pos]), np.asarray([t]),
+                                   len(table_row))
+                self._account_comm(tb)
+            with _prof.span("runner.stage"):
+                padded = np.zeros((1, tb), np.int32)
+                padded[0, :t] = tokens
+                fn = self._jitted("prefill", tb)
+                launch.set(kind="prefill", key=tb)
+                # host operands go to the jitted fn as-is — jit commits them
+                # in one hop; a jnp.asarray(np.asarray(...)) round-trip here
+                # used to stage an extra host copy per call (ISSUE 6
+                # satellite). Sharded runners stage them in ONE replicated
+                # device_put (ISSUE 7)
+                toks, table = self._stage(
+                    padded, np.asarray(table_row, np.int32)[None])
+                start = np.int32(start_pos) if slot is None else np.asarray(
+                    [start_pos, slot], np.int32)
+            with _prof.span("runner.dispatch"):
+                out = fn(self.params, toks, table, np.int32(t), start, pools)
+            return self._emit(out)
 
     def decode(self, tokens, tables, pos, pools):
         """Batched decode step; tokens [B], tables [B, P], pos [B]."""
         with _prof.span("runner.launch") as launch:
             pos_np = np.asarray(pos, np.int32)
-            self._account_attn(self._attn_impl_for(1), pos_np,
-                               np.ones_like(pos_np),
-                               np.asarray(tables).shape[1])
-            self._account_comm(pos_np.shape[0])
-            B = np.asarray(tokens).shape[0]
-            fn = self._jitted("decode", B)
-            launch.set(kind="decode", key=B)
-            toks, tabs, pos_a = self._stage(
-                np.asarray(tokens, np.int32)[:, None],
-                np.asarray(tables, np.int32), pos_np)
-            return self._emit(fn(self.params, toks, tabs, pos_a, pools))
+            with _prof.span("runner.account"):
+                self._account_attn(self._attn_impl_for(1), pos_np,
+                                   np.ones_like(pos_np),
+                                   np.asarray(tables).shape[1])
+                self._account_comm(pos_np.shape[0])
+            with _prof.span("runner.stage"):
+                B = np.asarray(tokens).shape[0]
+                fn = self._jitted("decode", B)
+                launch.set(kind="decode", key=B)
+                toks, tabs, pos_a = self._stage(
+                    np.asarray(tokens, np.int32)[:, None],
+                    np.asarray(tables, np.int32), pos_np)
+            with _prof.span("runner.dispatch"):
+                out = fn(self.params, toks, tabs, pos_a, pools)
+            return self._emit(out)
 
     def decode_multi(self, tokens, tables, pos, pools, num_steps: int, *,
                      seeds=None, base_steps=None, temps=None,
@@ -1540,44 +1549,52 @@ class PagedModelRunner:
             if num_steps < 1:
                 raise ValueError("decode_multi needs num_steps >= 1")
             pos_np = np.asarray(pos, np.int32)
-            impl = self._attn_impl_for(1)
-            width = np.asarray(tables).shape[1]
-            for t in range(num_steps):      # inner step t attends at pos + t
-                # host-side byte analytics; early-stopped rows may freeze
-                # earlier, so this upper-bounds the extended horizon's reads
-                self._account_attn(impl, pos_np + t, np.ones_like(pos_np),
-                                   width)
-            self._account_comm(pos_np.shape[0], steps=num_steps)
+            with _prof.span("runner.account"):
+                impl = self._attn_impl_for(1)
+                width = np.asarray(tables).shape[1]
+                for t in range(num_steps):  # inner step t attends at pos + t
+                    # host-side byte analytics; early-stopped rows may
+                    # freeze earlier, so this upper-bounds the extended
+                    # horizon's reads
+                    self._account_attn(impl, pos_np + t,
+                                       np.ones_like(pos_np), width)
+                self._account_comm(pos_np.shape[0], steps=num_steps)
             B = pos_np.shape[0]
             sampling = temps is not None
             extended = sampling or early_stop
             if not extended:
-                fn = self._jitted("decode_multi", (B, num_steps))
-                launch.set(kind="decode_multi", key=(B, num_steps))
-                toks, tabs, pos_a = self._stage(np.asarray(tokens, np.int32),
-                                                np.asarray(tables, np.int32),
-                                                pos_np)
-                return fn(self.params, toks, tabs, pos_a, pools, num_steps)
-            seeds = np.zeros((B,), np.int32) if seeds is None \
-                else np.asarray(seeds, np.int32)
-            base_steps = np.zeros((B,), np.int32) if base_steps is None \
-                else np.asarray(base_steps, np.int32)
-            temps = np.zeros((B,), np.float32) if temps is None \
-                else np.asarray(temps, np.float32)
-            stop_ids = np.full((B, 1), -1, np.int32) if stop_ids is None \
-                else np.asarray(stop_ids, np.int32)
-            remaining = np.full((B,), num_steps, np.int32) \
-                if remaining is None else np.asarray(remaining, np.int32)
-            key = (B, num_steps, top_k, top_p, sampling, bool(early_stop),
-                   stop_ids.shape[1])
-            fn = self._jitted("decode_multi_x", key)
-            launch.set(kind="decode_multi_x", key=key)
-            toks, tabs, pos_a, sd, bs, tp, si, rem = self._stage(
-                np.asarray(tokens, np.int32), np.asarray(tables, np.int32),
-                pos_np, seeds, base_steps, temps, stop_ids, remaining)
-            return fn(self.params, toks, tabs, pos_a, pools, sd, bs, tp, si,
-                      rem, num_steps, top_k, top_p, sampling,
-                      bool(early_stop))
+                with _prof.span("runner.stage"):
+                    fn = self._jitted("decode_multi", (B, num_steps))
+                    launch.set(kind="decode_multi", key=(B, num_steps))
+                    toks, tabs, pos_a = self._stage(
+                        np.asarray(tokens, np.int32),
+                        np.asarray(tables, np.int32), pos_np)
+                with _prof.span("runner.dispatch"):
+                    return fn(self.params, toks, tabs, pos_a, pools,
+                              num_steps)
+            with _prof.span("runner.stage"):
+                seeds = np.zeros((B,), np.int32) if seeds is None \
+                    else np.asarray(seeds, np.int32)
+                base_steps = np.zeros((B,), np.int32) if base_steps is None \
+                    else np.asarray(base_steps, np.int32)
+                temps = np.zeros((B,), np.float32) if temps is None \
+                    else np.asarray(temps, np.float32)
+                stop_ids = np.full((B, 1), -1, np.int32) \
+                    if stop_ids is None else np.asarray(stop_ids, np.int32)
+                remaining = np.full((B,), num_steps, np.int32) \
+                    if remaining is None else np.asarray(remaining, np.int32)
+                key = (B, num_steps, top_k, top_p, sampling,
+                       bool(early_stop), stop_ids.shape[1])
+                fn = self._jitted("decode_multi_x", key)
+                launch.set(kind="decode_multi_x", key=key)
+                toks, tabs, pos_a, sd, bs, tp, si, rem = self._stage(
+                    np.asarray(tokens, np.int32),
+                    np.asarray(tables, np.int32), pos_np, seeds, base_steps,
+                    temps, stop_ids, remaining)
+            with _prof.span("runner.dispatch"):
+                return fn(self.params, toks, tabs, pos_a, pools, sd, bs, tp,
+                          si, rem, num_steps, top_k, top_p, sampling,
+                          bool(early_stop))
 
     def decode_multi_spec(self, tokens, tables, pos, pools, drafts, *,
                           seeds=None, base_steps=None, temps=None,
@@ -1603,32 +1620,37 @@ class PagedModelRunner:
                     f"drafts must be [B, num_steps>=1, K], got {drafts.shape}")
             B, num_steps, K = drafts.shape
             pos_np = np.asarray(pos, np.int32)
-            width = np.asarray(tables).shape[1]
-            impl = self._attn_impl_for(K + 1)
-            spans = np.full((B,), K + 1, np.int32)
-            for t in range(num_steps):   # upper-bounds the per-step reads
-                self._account_attn(impl, pos_np + t * (K + 1), spans, width)
-            self._account_comm(B * (K + 1), steps=num_steps)
-            sampling = temps is not None
-            seeds = np.zeros((B,), np.int32) if seeds is None \
-                else np.asarray(seeds, np.int32)
-            base_steps = np.zeros((B,), np.int32) if base_steps is None \
-                else np.asarray(base_steps, np.int32)
-            temps = np.zeros((B,), np.float32) if temps is None \
-                else np.asarray(temps, np.float32)
-            stop_ids = np.full((B, 1), -1, np.int32) if stop_ids is None \
-                else np.asarray(stop_ids, np.int32)
-            remaining = np.full((B,), num_steps * (K + 1), np.int32) \
-                if remaining is None else np.asarray(remaining, np.int32)
-            key = (B, num_steps, K, top_k, top_p, sampling,
-                   stop_ids.shape[1])
-            fn = self._jitted("decode_multi_spec", key)
-            launch.set(kind="decode_multi_spec", key=key)
-            toks, tabs, pos_a, dr, sd, bs, tp, si, rem = self._stage(
-                np.asarray(tokens, np.int32), np.asarray(tables, np.int32),
-                pos_np, drafts, seeds, base_steps, temps, stop_ids, remaining)
-            return fn(self.params, toks, tabs, pos_a, pools, dr, sd, bs, tp,
-                      si, rem, num_steps, top_k, top_p, sampling)
+            with _prof.span("runner.account"):
+                width = np.asarray(tables).shape[1]
+                impl = self._attn_impl_for(K + 1)
+                spans = np.full((B,), K + 1, np.int32)
+                for t in range(num_steps):  # upper-bounds the per-step reads
+                    self._account_attn(impl, pos_np + t * (K + 1), spans,
+                                       width)
+                self._account_comm(B * (K + 1), steps=num_steps)
+            with _prof.span("runner.stage"):
+                sampling = temps is not None
+                seeds = np.zeros((B,), np.int32) if seeds is None \
+                    else np.asarray(seeds, np.int32)
+                base_steps = np.zeros((B,), np.int32) if base_steps is None \
+                    else np.asarray(base_steps, np.int32)
+                temps = np.zeros((B,), np.float32) if temps is None \
+                    else np.asarray(temps, np.float32)
+                stop_ids = np.full((B, 1), -1, np.int32) \
+                    if stop_ids is None else np.asarray(stop_ids, np.int32)
+                remaining = np.full((B,), num_steps * (K + 1), np.int32) \
+                    if remaining is None else np.asarray(remaining, np.int32)
+                key = (B, num_steps, K, top_k, top_p, sampling,
+                       stop_ids.shape[1])
+                fn = self._jitted("decode_multi_spec", key)
+                launch.set(kind="decode_multi_spec", key=key)
+                toks, tabs, pos_a, dr, sd, bs, tp, si, rem = self._stage(
+                    np.asarray(tokens, np.int32),
+                    np.asarray(tables, np.int32), pos_np, drafts, seeds,
+                    base_steps, temps, stop_ids, remaining)
+            with _prof.span("runner.dispatch"):
+                return fn(self.params, toks, tabs, pos_a, pools, dr, sd, bs,
+                          tp, si, rem, num_steps, top_k, top_p, sampling)
 
     def ragged_step(self, tokens, tables, start_pos, q_lens, pools,
                     full_logits: bool = False):
@@ -1646,16 +1668,19 @@ class PagedModelRunner:
             B, T = tokens.shape
             start_pos = np.asarray(start_pos, np.int32)
             q_lens = np.asarray(q_lens, np.int32)
-            self._account_attn(self._attn_impl_for(T), start_pos, q_lens,
-                               np.asarray(tables).shape[1])
-            self._account_comm(B * T)
-            kind = "ragged_full" if full_logits else "ragged"
-            fn = self._jitted(kind, (B, T))
-            launch.set(kind=kind, key=(B, T))
-            toks, tabs, starts, lens = self._stage(
-                tokens, np.asarray(tables, np.int32), start_pos, q_lens)
-            return self._emit(
-                fn(self.params, toks, tabs, starts, lens, pools))
+            with _prof.span("runner.account"):
+                self._account_attn(self._attn_impl_for(T), start_pos, q_lens,
+                                   np.asarray(tables).shape[1])
+                self._account_comm(B * T)
+            with _prof.span("runner.stage"):
+                kind = "ragged_full" if full_logits else "ragged"
+                fn = self._jitted(kind, (B, T))
+                launch.set(kind=kind, key=(B, T))
+                toks, tabs, starts, lens = self._stage(
+                    tokens, np.asarray(tables, np.int32), start_pos, q_lens)
+            with _prof.span("runner.dispatch"):
+                out = fn(self.params, toks, tabs, starts, lens, pools)
+            return self._emit(out)
 
     def _forward(self, params, tokens, positions, write_page, write_off,
                  tables, pos_q, q_lens, pools):

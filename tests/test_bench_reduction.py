@@ -1,0 +1,58 @@
+"""The benchmark's reduction, guarded by the driver's own run (ISSUE 33's
+copy, ISSUE 40): the pure-Python cases of `bench/tests/test_trace_reduce.py`,
+`test_program_spans.py` and `test_idle_attribution.py` (hand-made tables and
+one recorded trace; no engine, no chip), collected here under their own names
+so that each counts. They are the bench's functions, imported: one
+definition, two places it runs. The cases that drive a toy engine through
+`bench/run.py` stay with `bench/tests`, run by hand."""
+import os
+import sys
+
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+for p in (os.path.join(ROOT, "bench"), os.path.join(ROOT, "bench", "tests")):
+    if p not in sys.path:
+        sys.path.insert(0, p)
+pytest.register_assert_rewrite("test_trace_reduce", "test_program_spans",
+                               "test_idle_attribution")
+
+from test_trace_reduce import (  # noqa: E402,F401
+    test_a_last_seconds_trace_is_stopped_by_close_window_alone,
+    test_a_middle_trace_is_stopped_by_tick_once,
+    test_a_run_cut_by_either_edge_is_no_step,
+    test_busy_never_exceeds_the_window,
+    test_collective_exposed_ms_is_per_whole_step,
+    test_decode_rooflines_set_kernel_time_against_the_same_steps,
+    test_device_events_without_an_anchor_give_no_result,
+    test_events_that_overhang_the_span_are_clipped_to_it,
+    test_flash_roofline_is_the_same_from_6_93_steps_and_from_7_9,
+    test_module_ms_counts_only_busy_time,
+    test_names,
+    test_recorded_trace,
+    test_train_mfu_takes_the_rate_before_the_profiler_was_asked_for,
+    test_union_and_exposed,
+)
+from test_program_spans import (  # noqa: E402,F401
+    recorded,
+    test_a_device_plane_that_lies_early_shows_as_a_negative_lead,
+    test_alignment_on_the_recorded_trace,
+    test_anchors_take_the_last_calls_where_the_counts_differ,
+    test_reader_returns_none_without_spans,
+    test_readers_by_hand,
+    test_ring_is_empty_where_the_program_has_no_spans,
+)
+from test_idle_attribution import (  # noqa: E402,F401
+    test_a_ring_without_the_new_spans_gives_the_device_idle_alone,
+    test_an_empty_interval_gives_none_and_says_why,
+    test_busy_union_and_last_end,
+    test_own_time_names_the_innermost_span,
+    test_readers_return_none_without_spans_or_without_a_trace,
+    test_runner_programs_are_told_from_the_drains_passes,
+    test_skew_is_bounded_by_the_fences_and_moves_neither_one_clock_metric,
+    test_steps_with_a_prefill_are_kept_apart,
+    test_the_accepted_readers_read_what_they_read_without_the_children,
+    test_the_identity_holds_for_any_offset,
+    test_the_manifest_lists_the_five_for_the_serving_cells,
+    test_turnaround_parts_add_up_and_name_the_new_spans,
+)

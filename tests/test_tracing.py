@@ -283,7 +283,8 @@ def test_spans_lie_in_the_xplane_at_a_constant_offset(traced_serve):
     for plane in pd.planes:
         for line in plane.lines:
             for e in line.events:
-                if e.name.startswith(("engine.", "runner.", "request.")):
+                if e.name.startswith(("engine.", "runner.", "request.",
+                                         "drain.")):
                     in_trace.setdefault(e.name, []).append(
                         (float(e.start_ns), float(e.duration_ns)))
     ring = {}
