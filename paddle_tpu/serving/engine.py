@@ -60,6 +60,7 @@ import logging
 import os
 import time
 from dataclasses import asdict, dataclass, fields, replace
+from functools import partial
 from typing import Callable, Dict, List, Optional, Sequence
 
 import jax
@@ -701,16 +702,29 @@ class ServingEngine:
         with _prof.span("engine.drain"):
             out = fn()
             if self._step_counts:
-                # the launches drained just now, or earlier ones whose
-                # logits nobody read: ready, so no further wait
-                counts, self._step_counts[:] = list(self._step_counts), []
-                with _prof.span("drain.fetch", what="counts"):
-                    counts = jax.device_get(counts)
-                for name, n in zip(self.runner.COUNTS,
-                                   np.sum(counts, axis=0)):
-                    getattr(self.metrics, name).inc(float(n))
+                # of the launches drained just now, or of earlier ones
+                # whose logits nobody read: their copies set out when the
+                # launches did (`_take_counts`) and those are done, so
+                # they are in host memory and reading them waits for
+                # nothing
+                counts, self._step_counts = self._step_counts, []
+                for c in counts:
+                    for name, n in zip(self.runner.COUNTS,
+                                       np.asarray(c).tolist()):
+                        getattr(self.metrics, name).inc(n)
         self.metrics.host_syncs.inc()
         return out
+
+    def _take_counts(self, counts) -> None:
+        """A counting runner's hand-over (`on_step_counts`), as a
+        launch's jitted call returns: the counts are an output of the
+        program that has just been queued, so their copy to the host is
+        queued behind it here and arrives with the step's end. The
+        drain that reads them after the tokens then pays no device
+        round trip of its own (0.5 ms a step with the device standing
+        still, PERF.md PR 40)."""
+        counts.copy_to_host_async()
+        self._step_counts.append(counts)
 
     # ------------------------------------------------- failure plumbing
 
@@ -898,7 +912,7 @@ class ServingEngine:
         if getattr(self.runner, "COUNTS", ()):
             # this step's launches report here (a runner may serve more
             # than one engine in turn)
-            self.runner.on_step_counts = self._step_counts.append
+            self.runner.on_step_counts = self._take_counts
         # the step's root span; whether this step's sites record at all
         # is decided here, once (a profiler session is live or not)
         with _prof.step_span("engine.step", self._step_count):
@@ -1051,56 +1065,84 @@ class ServingEngine:
                     self.pipelined))
         self.metrics.decode_steps.inc()
 
-        # bookkeeping gauges
-        read = getattr(self.runner, "attn_kv_bytes_read", None)
+        if self.pool.host_tier is not None:
+            # stage the NEXT resumable requests' host pages while this
+            # step's compute is still in flight on the device — the
+            # double buffer the pagein_hidden_ratio metric measures
+            self._prefetch_pagein()
+        # the gauges show the state this step's commit left. It is read
+        # here, where it is that state; the writes wait until the next
+        # launch has the device busy (`_call_retrying`), or until
+        # somebody looks (`EngineMetrics.settle`)
+        self.metrics.put_off(partial(self._write_gauges,
+                                     *self._read_gauges()))
+        if self.audit:
+            audit_engine(self)
+        return events
+
+    def _read_gauges(self) -> tuple:
+        """What a step's gauges mirror, as the engine's state has it
+        now (`_write_gauges` takes it in this order): the runner's
+        host-side byte counters, then scheduler, pool and host tier."""
+        r, a, tier = self.runner, self.pool.allocator, self.pool.host_tier
+        return (getattr(r, "attn_kv_bytes_read", None),
+                getattr(r, "attn_kv_bytes_gather", None),
+                getattr(r, "tp_comm_bytes", None),
+                getattr(r, "tp_comm_bytes_fp32", None),
+                getattr(r, "tp_gather_bytes", None),
+                getattr(r, "tp_gather_bytes_fp32", None),
+                self.scheduler.queue_depth, len(self.scheduler.running),
+                a.num_usable - a.num_free, self.pool.utilization(),
+                (len(self.pool.prefix_cache)
+                 if self.pool.prefix_cache is not None else None),
+                tier.bytes_used if tier is not None else None,
+                tier.used_count if tier is not None else None)
+
+    def _write_gauges(self, read, gathered, comm, comm32, gather, gather32,
+                      queued, running, used, utilization, cached,
+                      tier_bytes, tier_used) -> None:
+        m = self.metrics
         if read is not None:
-            self.metrics.attn_kv_bytes_read.set(read)
-            self.metrics.attn_kv_bytes_gather.set(
-                self.runner.attn_kv_bytes_gather)
-        comm = getattr(self.runner, "tp_comm_bytes", None)
+            m.attn_kv_bytes_read.set(read)
+            m.attn_kv_bytes_gather.set(gathered)
         if comm is not None:
             # quantized-collective accounting: wire bytes
             # the row-parallel allreduces moved per shard (scale bytes
             # counted) vs the fp32 cost of the same calls — mirrored
             # from the runner's host-side counters like the attention
             # bytes above, so the comm reduction is measured
-            self.metrics.tp_comm_bytes.set(comm)
-            self.metrics.tp_comm_bytes_fp32.set(
-                self.runner.tp_comm_bytes_fp32)
-            self.metrics.tp_comm_bytes_reduction_x.set(
-                self.runner.tp_comm_bytes_fp32 / comm if comm else 0.0)
-        gather = getattr(self.runner, "tp_gather_bytes", None)
+            m.tp_comm_bytes.set(comm)
+            m.tp_comm_bytes_fp32.set(comm32)
+            m.tp_comm_bytes_reduction_x.set(comm32 / comm if comm else 0.0)
         if gather is not None:
             # the gather direction: wire bytes the column-
             # parallel all-gathers (lm_head logits) moved per shard,
             # scale bytes counted, vs the fp32 cost of the same calls
-            self.metrics.tp_gather_bytes.set(gather)
-            self.metrics.tp_gather_bytes_fp32.set(
-                self.runner.tp_gather_bytes_fp32)
-            self.metrics.tp_gather_bytes_reduction_x.set(
-                self.runner.tp_gather_bytes_fp32 / gather
-                if gather else 0.0)
-        a = self.pool.allocator
-        self.metrics.queue_depth.set(self.scheduler.queue_depth)
-        self.metrics.running.set(len(self.scheduler.running))
-        self.metrics.pool_used_pages.set(a.num_usable - a.num_free)
-        self.metrics.pool_utilization.set(self.pool.utilization())
+            m.tp_gather_bytes.set(gather)
+            m.tp_gather_bytes_fp32.set(gather32)
+            m.tp_gather_bytes_reduction_x.set(
+                gather32 / gather if gather else 0.0)
+        m.queue_depth.set(queued)
+        m.running.set(running)
+        m.pool_used_pages.set(used)
+        m.pool_utilization.set(utilization)
         if self.pool.state_layers:
             # a running request holds its slot, and so its state
-            self.metrics.state_slots_live.set(len(self.scheduler.running))
-        if self.pool.prefix_cache is not None:
-            self.metrics.prefix_cached_pages.set(len(self.pool.prefix_cache))
-        tier = self.pool.host_tier
-        if tier is not None:
-            # stage the NEXT resumable requests' host pages while this
-            # step's compute is still in flight on the device — the
-            # double buffer the pagein_hidden_ratio metric measures
-            self._prefetch_pagein()
-            self.metrics.host_tier_bytes.set(tier.bytes_used)
-            self.metrics.host_tier_pages_used.set(tier.used_count)
-        if self.audit:
-            audit_engine(self)
-        return events
+            m.state_slots_live.set(running)
+        if cached is not None:
+            m.prefix_cached_pages.set(cached)
+        if tier_bytes is not None:
+            m.host_tier_bytes.set(tier_bytes)
+            m.host_tier_pages_used.set(tier_used)
+
+    def _settle(self) -> None:
+        """What the last step owes its gauges, written now that a launch
+        has the device busy: off the interval in which the device waits
+        for the host."""
+        if self.metrics.owed is not None:
+            with _prof.span("engine.settle"):
+                self.metrics.settle()
+
     # ---------------------------------------------- the launch skeleton
 
     def _call_retrying(self, build, call, victim):
@@ -1126,7 +1168,7 @@ class ServingEngine:
             if built is None:
                 return None
             try:
-                return (built, *call(built))
+                out = call(built)
             except Exception as e:
                 require_retryable(e, self.pool.pools)
                 if attempts < self.max_step_retries:
@@ -1137,6 +1179,11 @@ class ServingEngine:
                     continue
                 self._finish_abnormal(victim(built), "error")
                 attempts, delay = 0, self.retry_backoff_s
+                continue
+            # the device has the launch: book-keeping goes here, not
+            # between a drain and the next dispatch
+            self._settle()
+            return (built, *out)
 
     def _build_batch(self, rows, ragged: bool = False) -> tuple:
         """The operands every launch kind shares, in the order the
@@ -1237,7 +1284,9 @@ class ServingEngine:
         flight). Router workers call this on a graceful stop so
         committed-but-undelivered tokens reach the delivery registry;
         tests and tools use it before inspecting engine state mid-run."""
-        return self._commit_inflight()
+        events = self._commit_inflight()
+        self.metrics.settle()
+        return events
 
     # ------------------------------------------------------ prefill chunk
 
@@ -1579,8 +1628,10 @@ class ServingEngine:
         and lets the scheduler pre-commit the horizon's pages, trimming
         further under pool pressure."""
         s = self.decode_horizon
+        if s <= 1:
+            return 1
         batch = self.scheduler.decode_ready()
-        if (s <= 1 or not batch or chunks_in_flight
+        if (not batch or chunks_in_flight
                 or not self._horizon_envelope(batch)):
             return 1
         if self.horizon_early_stop:
@@ -1962,6 +2013,7 @@ class ServingEngine:
         last iteration commits the tail of the pipeline."""
         while self.has_work():
             self.step()
+        self.metrics.settle()
         return dict(self._outputs)
 
     def outputs(self) -> Dict[str, RequestOutput]:

@@ -30,18 +30,37 @@ class Counter:
         self.value += n
 
 
+def _nothing_owed() -> None:
+    pass
+
+
 class Gauge:
-    """Last-write-wins instantaneous value; remembers its peak."""
+    """Last-write-wins instantaneous value; remembers its peak. Where its
+    owner puts writes off (`EngineMetrics.put_off`), `due` settles them
+    before this gauge is read or written, so a reader sees every write
+    that was made or owed, in the order they were."""
 
     def __init__(self, name: str):
         self.name = name
-        self.value = 0.0
-        self.peak = 0.0
+        self.due: Callable[[], None] = _nothing_owed
+        self._value = 0.0
+        self._peak = 0.0
 
     def set(self, v: float) -> None:
-        self.value = float(v)
-        if self.value > self.peak:
-            self.peak = self.value
+        self.due()
+        self._value = float(v)
+        if self._value > self._peak:
+            self._peak = self._value
+
+    @property
+    def value(self) -> float:
+        self.due()
+        return self._value
+
+    @property
+    def peak(self) -> float:
+        self.due()
+        return self._peak
 
 
 class Histogram:
@@ -340,6 +359,26 @@ class EngineMetrics:
         self.batch_occupancy = Histogram("batch_occupancy")
         self.ttft_s = Histogram("ttft_s")
         self.e2e_latency_s = Histogram("e2e_latency_s")
+        # gauge writes a step put off (`put_off`), settled by whoever
+        # reads or writes a gauge first
+        self.owed: Optional[Callable[[], None]] = None
+        for inst in vars(self).values():
+            if isinstance(inst, Gauge):
+                inst.due = self.settle
+
+    def put_off(self, write: Callable[[], None]) -> None:
+        """Owe the gauges `write()`: the engine's end-of-step readings,
+        taken when the step ended and written while the device runs the
+        next one. Whoever reads or sets a gauge first settles what is
+        owed, so no reader can tell; one debt at a time."""
+        self.settle()
+        self.owed = write
+
+    def settle(self) -> None:
+        # taken first: the gauge writes below come back here (`due`)
+        write, self.owed = self.owed, None
+        if write is not None:
+            write()
 
     def spec_acceptance_rate(self) -> float:
         """Accepted / proposed draft tokens (0.0 when nothing proposed)."""

@@ -1458,6 +1458,20 @@ class PagedModelRunner:
                     "evicting %s", cap, evicted)
         return first_call
 
+    def _dispatch(self, fn, args, account):
+        """The jitted call (`runner.dispatch`), then the call's byte
+        accounting (`runner.account`: `_account_attn`, `_account_comm`).
+        The counters are host arithmetic on the call's own operands, so
+        they are taken once the device has the program and does not wait
+        for them; a call that raised is counted like one that ran, as it
+        was when the accounting came first."""
+        try:
+            with _prof.span("runner.dispatch"):
+                return fn(*args)
+        finally:
+            with _prof.span("runner.account"):
+                account()
+
     def prefill(self, tokens: List[int], table_row: List[int], pools):
         """Run one sequence's (re-)prefill; returns (last_logits[V], pools)."""
         return self.prefill_chunk(tokens, 0, table_row, pools)
@@ -1476,11 +1490,6 @@ class PagedModelRunner:
         with _prof.span("runner.launch") as launch:
             t = len(tokens)
             tb = bucket_len(t)
-            with _prof.span("runner.account"):
-                self._account_attn(self._attn_impl_for(tb),
-                                   np.asarray([start_pos]), np.asarray([t]),
-                                   len(table_row))
-                self._account_comm(tb)
             with _prof.span("runner.stage"):
                 padded = np.zeros((1, tb), np.int32)
                 padded[0, :t] = tokens
@@ -1495,19 +1504,21 @@ class PagedModelRunner:
                     padded, np.asarray(table_row, np.int32)[None])
                 start = np.int32(start_pos) if slot is None else np.asarray(
                     [start_pos, slot], np.int32)
-            with _prof.span("runner.dispatch"):
-                out = fn(self.params, toks, table, np.int32(t), start, pools)
-            return self._emit(out)
+
+            def account():
+                self._account_attn(self._attn_impl_for(tb),
+                                   np.asarray([start_pos]), np.asarray([t]),
+                                   len(table_row))
+                self._account_comm(tb)
+
+            return self._emit(self._dispatch(
+                fn, (self.params, toks, table, np.int32(t), start, pools),
+                account))
 
     def decode(self, tokens, tables, pos, pools):
         """Batched decode step; tokens [B], tables [B, P], pos [B]."""
         with _prof.span("runner.launch") as launch:
             pos_np = np.asarray(pos, np.int32)
-            with _prof.span("runner.account"):
-                self._account_attn(self._attn_impl_for(1), pos_np,
-                                   np.ones_like(pos_np),
-                                   np.asarray(tables).shape[1])
-                self._account_comm(pos_np.shape[0])
             with _prof.span("runner.stage"):
                 B = np.asarray(tokens).shape[0]
                 fn = self._jitted("decode", B)
@@ -1515,9 +1526,15 @@ class PagedModelRunner:
                 toks, tabs, pos_a = self._stage(
                     np.asarray(tokens, np.int32)[:, None],
                     np.asarray(tables, np.int32), pos_np)
-            with _prof.span("runner.dispatch"):
-                out = fn(self.params, toks, tabs, pos_a, pools)
-            return self._emit(out)
+
+            def account():
+                self._account_attn(self._attn_impl_for(1), pos_np,
+                                   np.ones_like(pos_np),
+                                   np.asarray(tables).shape[1])
+                self._account_comm(pos_np.shape[0])
+
+            return self._emit(self._dispatch(
+                fn, (self.params, toks, tabs, pos_a, pools), account))
 
     def decode_multi(self, tokens, tables, pos, pools, num_steps: int, *,
                      seeds=None, base_steps=None, temps=None,
@@ -1549,7 +1566,8 @@ class PagedModelRunner:
             if num_steps < 1:
                 raise ValueError("decode_multi needs num_steps >= 1")
             pos_np = np.asarray(pos, np.int32)
-            with _prof.span("runner.account"):
+
+            def account():
                 impl = self._attn_impl_for(1)
                 width = np.asarray(tables).shape[1]
                 for t in range(num_steps):  # inner step t attends at pos + t
@@ -1559,6 +1577,7 @@ class PagedModelRunner:
                     self._account_attn(impl, pos_np + t,
                                        np.ones_like(pos_np), width)
                 self._account_comm(pos_np.shape[0], steps=num_steps)
+
             B = pos_np.shape[0]
             sampling = temps is not None
             extended = sampling or early_stop
@@ -1569,9 +1588,9 @@ class PagedModelRunner:
                     toks, tabs, pos_a = self._stage(
                         np.asarray(tokens, np.int32),
                         np.asarray(tables, np.int32), pos_np)
-                with _prof.span("runner.dispatch"):
-                    return fn(self.params, toks, tabs, pos_a, pools,
-                              num_steps)
+                return self._dispatch(
+                    fn, (self.params, toks, tabs, pos_a, pools, num_steps),
+                    account)
             with _prof.span("runner.stage"):
                 seeds = np.zeros((B,), np.int32) if seeds is None \
                     else np.asarray(seeds, np.int32)
@@ -1591,10 +1610,10 @@ class PagedModelRunner:
                     np.asarray(tokens, np.int32),
                     np.asarray(tables, np.int32), pos_np, seeds, base_steps,
                     temps, stop_ids, remaining)
-            with _prof.span("runner.dispatch"):
-                return fn(self.params, toks, tabs, pos_a, pools, sd, bs, tp,
-                          si, rem, num_steps, top_k, top_p, sampling,
-                          bool(early_stop))
+            return self._dispatch(
+                fn, (self.params, toks, tabs, pos_a, pools, sd, bs, tp, si,
+                     rem, num_steps, top_k, top_p, sampling,
+                     bool(early_stop)), account)
 
     def decode_multi_spec(self, tokens, tables, pos, pools, drafts, *,
                           seeds=None, base_steps=None, temps=None,
@@ -1620,14 +1639,6 @@ class PagedModelRunner:
                     f"drafts must be [B, num_steps>=1, K], got {drafts.shape}")
             B, num_steps, K = drafts.shape
             pos_np = np.asarray(pos, np.int32)
-            with _prof.span("runner.account"):
-                width = np.asarray(tables).shape[1]
-                impl = self._attn_impl_for(K + 1)
-                spans = np.full((B,), K + 1, np.int32)
-                for t in range(num_steps):  # upper-bounds the per-step reads
-                    self._account_attn(impl, pos_np + t * (K + 1), spans,
-                                       width)
-                self._account_comm(B * (K + 1), steps=num_steps)
             with _prof.span("runner.stage"):
                 sampling = temps is not None
                 seeds = np.zeros((B,), np.int32) if seeds is None \
@@ -1648,9 +1659,19 @@ class PagedModelRunner:
                     np.asarray(tokens, np.int32),
                     np.asarray(tables, np.int32), pos_np, drafts, seeds,
                     base_steps, temps, stop_ids, remaining)
-            with _prof.span("runner.dispatch"):
-                return fn(self.params, toks, tabs, pos_a, pools, dr, sd, bs,
-                          tp, si, rem, num_steps, top_k, top_p, sampling)
+
+            def account():
+                width = np.asarray(tables).shape[1]
+                impl = self._attn_impl_for(K + 1)
+                spans = np.full((B,), K + 1, np.int32)
+                for t in range(num_steps):  # upper-bounds the per-step reads
+                    self._account_attn(impl, pos_np + t * (K + 1), spans,
+                                       width)
+                self._account_comm(B * (K + 1), steps=num_steps)
+
+            return self._dispatch(
+                fn, (self.params, toks, tabs, pos_a, pools, dr, sd, bs, tp,
+                     si, rem, num_steps, top_k, top_p, sampling), account)
 
     def ragged_step(self, tokens, tables, start_pos, q_lens, pools,
                     full_logits: bool = False):
@@ -1668,19 +1689,20 @@ class PagedModelRunner:
             B, T = tokens.shape
             start_pos = np.asarray(start_pos, np.int32)
             q_lens = np.asarray(q_lens, np.int32)
-            with _prof.span("runner.account"):
-                self._account_attn(self._attn_impl_for(T), start_pos, q_lens,
-                                   np.asarray(tables).shape[1])
-                self._account_comm(B * T)
             with _prof.span("runner.stage"):
                 kind = "ragged_full" if full_logits else "ragged"
                 fn = self._jitted(kind, (B, T))
                 launch.set(kind=kind, key=(B, T))
                 toks, tabs, starts, lens = self._stage(
                     tokens, np.asarray(tables, np.int32), start_pos, q_lens)
-            with _prof.span("runner.dispatch"):
-                out = fn(self.params, toks, tabs, starts, lens, pools)
-            return self._emit(out)
+
+            def account():
+                self._account_attn(self._attn_impl_for(T), start_pos, q_lens,
+                                   np.asarray(tables).shape[1])
+                self._account_comm(B * T)
+
+            return self._emit(self._dispatch(
+                fn, (self.params, toks, tabs, starts, lens, pools), account))
 
     def _forward(self, params, tokens, positions, write_page, write_off,
                  tables, pos_q, q_lens, pools):

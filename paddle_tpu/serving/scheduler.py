@@ -570,11 +570,13 @@ class FCFSScheduler:
         every decode step."""
         victims: List[Request] = []
         for req in list(self.running):      # admission order = oldest first
-            if req not in self.running:     # already preempted this pass
-                continue
+            if victims and req not in self.running:
+                continue                    # already preempted this pass
             while True:
                 short = req.kv.pages_short(1)
-                if short == 0 or self.pool.allocator.can_alloc(short):
+                if short == 0:              # most steps: nothing to grow
+                    break
+                if self.pool.allocator.can_alloc(short):
                     req.kv.grow(1)
                     break
                 victim = self.running[-1]   # youngest

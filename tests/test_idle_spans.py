@@ -1,12 +1,14 @@
 """The spans that split the launch and the drain where the device starts and
 stops (ISSUE 40): under a live profiler session every `runner.launch` holds
-`runner.account`, `runner.stage`, `runner.dispatch` in that order and every
+`runner.stage`, `runner.dispatch`, `runner.account` in that order and every
 `engine.drain` holds `drain.enqueue` (where the drain has device work of its
 own to dispatch) and `drain.fetch`; with no session a step records nothing
 and makes the calls it made before the spans were there; set-up compiles a
-fixed list of programs."""
+fixed list of programs. And what lies between a step's tokens and the next
+dispatch (ISSUE 41): only what that dispatch reads."""
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -16,7 +18,8 @@ from paddle_tpu.serving import engine as engine_mod
 from test_tracing import mark, since, toy_engine
 
 NAME, T0, T1, SID, PARENT, STEP, REQUEST, ATTRS = range(8)
-LAUNCH_PARTS = ["runner.account", "runner.stage", "runner.dispatch"]
+# the call's byte accounting comes once the device has the program
+LAUNCH_PARTS = ["runner.stage", "runner.dispatch", "runner.account"]
 
 
 def serve(eng, prompts, max_tokens):
@@ -90,37 +93,6 @@ def test_launch_and_drain_hold_their_parts_under_a_session(which, tmp_path):
             assert by_id[s[PARENT]][NAME] == "engine.drain"
 
 
-def test_the_counts_fetch_is_a_second_drain_fetch(tmp_path, monkeypatch):
-    """A runner that counts (`COUNTS`) has its counters fetched inside the
-    drain, after the tokens: a `drain.fetch` of its own, told by `what`."""
-    eng = toy_engine()
-    serve(eng, [[1, 2, 3]], 3)
-    runner = eng.runner
-    monkeypatch.setattr(type(runner), "COUNTS", ("prefill_chunks",),
-                        raising=False)
-    emit = runner._emit
-
-    def counting_emit(out):
-        logits, pools = emit(out)
-        if runner.on_step_counts is not None:
-            runner.on_step_counts(np.ones((1,), np.int32))
-        return logits, pools
-
-    monkeypatch.setattr(runner, "_emit", counting_emit)
-    m = mark()
-    with jax.profiler.trace(str(tmp_path)):
-        serve(eng, [[4, 5, 6]], 4)
-    spans = since(m)
-    fetches = [s for s in spans if s[NAME] == "drain.fetch"]
-    counted = [s for s in fetches if (s[ATTRS] or {}).get("what") == "counts"]
-    assert counted and len(counted) < len(fetches)
-    by_id = {s[SID]: s for s in spans}
-    for c in counted:
-        tokens = [s for s in fetches if s[PARENT] == c[PARENT] and s is not c]
-        assert len(tokens) == 1 and tokens[0][T1] <= c[T0]
-        assert by_id[c[PARENT]][NAME] == "engine.drain"
-
-
 class Calls:
     """How often a step crosses to the device by each door."""
 
@@ -143,6 +115,139 @@ class Calls:
     def take(self):
         got, self.n = self.n, dict.fromkeys(self.n, 0)
         return got
+
+
+# what the next dispatch reads, and the spans that hold it
+NEEDED = {"engine.commit", "engine.drain", "engine.step", "engine.plan",
+          "engine.build_batch", "runner.launch", "runner.stage"}
+LOOPS = {"default": {}, "pipelined": {"pipelined": True},
+         "horizon": {"decode_horizon": 4}}
+
+
+class Counts:
+    """What a counting runner hands over at a launch: an output of the
+    step's program, with its two doors to the host logged."""
+
+    def __init__(self, log):
+        self.log, self.array = log, jnp.ones((1,), jnp.int32)
+
+    def copy_to_host_async(self):
+        self.log.append("copy")
+        self.array.copy_to_host_async()
+
+    def __array__(self, dtype=None, copy=None):
+        self.log.append("read")
+        return np.asarray(self.array)
+
+
+def counting(eng, monkeypatch, log):
+    """Make the toy runner count: every single-pass launch hands the engine
+    one `Counts`, as a runner with `COUNTS` does from `_emit`."""
+    runner = eng.runner
+    monkeypatch.setattr(type(runner), "COUNTS", ("prefill_chunks",),
+                        raising=False)
+    emit = runner._emit
+
+    def counting_emit(out):
+        logits, pools = emit(out)
+        if runner.on_step_counts is not None:
+            runner.on_step_counts(Counts(log))
+        return logits, pools
+
+    monkeypatch.setattr(runner, "_emit", counting_emit)
+
+
+@pytest.mark.parametrize("which", sorted(LOOPS))
+def test_between_tokens_and_dispatch_only_what_the_dispatch_reads(
+        which, tmp_path, monkeypatch):
+    """A counting runner on each loop: from a decode-only step's token fetch
+    to the next decode's dispatch the host opens no span but the ones that
+    build that dispatch; the counts set out for the host at the hand-over
+    and are read after the tokens without a call that waits; the byte
+    accounting and the last step's gauges follow a dispatch."""
+    eng = toy_engine(**LOOPS[which])
+    serve(eng, [[1, 2, 3]], 6)                  # the programs exist
+    log = []
+    counting(eng, monkeypatch, log)
+    calls = Calls(monkeypatch)
+    to_host = engine_mod._to_host
+
+    def logged(x):
+        log.append("to_host")
+        return to_host(x)
+
+    monkeypatch.setattr(engine_mod, "_to_host", logged)
+    m = mark()
+    per_step = []
+    with jax.profiler.trace(str(tmp_path)):
+        eng.add_request([4, 5, 6, 7], SamplingParams(max_tokens=26))
+        eng.add_request([8, 9, 10], SamplingParams(max_tokens=26))
+        while eng.has_work():
+            calls.take()
+            eng.step()
+            per_step.append(calls.take())
+    spans = since(m)
+    # one blocking door a step at most (a step that prefills and decodes
+    # crosses it twice), and never the two calls that wait on their own
+    assert all(c["device_get"] == 0 and c["block_until_ready"] == 0
+               for c in per_step)
+    by_step = {}
+    for s in spans:
+        by_step.setdefault(s[STEP], []).append(s)
+    decode_only = {
+        k for k, mine in by_step.items()
+        if any(s[NAME] == "runner.launch" for s in mine)
+        and all(str(s[ATTRS]["kind"]).startswith("decode")
+                for s in mine if s[NAME] == "runner.launch")}
+    for k, c in zip(sorted(by_step), per_step):
+        if k in decode_only:
+            assert c["to_host"] == 1
+    fetches = sorted((s for s in spans if s[NAME] == "drain.fetch"),
+                     key=lambda s: s[T1])
+    assert not any((s[ATTRS] or {}).get("what") == "counts"
+                   for s in fetches)
+    dispatches = sorted((s for s in spans if s[NAME] == "runner.dispatch"),
+                        key=lambda s: s[T0])
+    checked = 0
+    for d in dispatches:
+        before = [f for f in fetches if f[T1] <= d[T0]]
+        if not before or d[STEP] not in decode_only:
+            continue
+        r = before[-1]
+        if r[STEP] not in decode_only or d[STEP] - r[STEP] > 1:
+            continue
+        between = {s[NAME] for s in spans
+                   if s[T1] > r[T1] and s[T0] < d[T0]}
+        assert between <= NEEDED, (which, d[STEP], between - NEEDED)
+        checked += 1
+    assert checked >= 3
+    # the work that left the interval is done all the same, behind a
+    # dispatch of its own step
+    by_id = {s[SID]: s for s in spans}
+    for name in ("runner.account", "engine.settle"):
+        moved = [s for s in spans if s[NAME] == name]
+        assert moved, name
+        for s in moved:
+            mine = [d for d in dispatches if d[STEP] == s[STEP]
+                    and d[T1] <= s[T0]]
+            assert mine, (name, s[STEP])
+    assert all(by_id[s[PARENT]][NAME] in ("engine.step", "request.prefill")
+               for s in spans if s[NAME] == "engine.settle")
+    # the counts: asked for at the hand-over, before the drain that reads
+    # them blocks for the tokens; read once, after those
+    assert log.count("copy") == log.count("read") > 0
+    waiting = 0                     # handed over, not yet read
+    fetched = False
+    for what in log:
+        if what == "copy":
+            waiting, fetched = waiting + 1, False
+        elif what == "to_host":
+            fetched = True
+        else:
+            assert waiting > 0 and fetched, log
+            waiting -= 1
+    assert waiting == 0
+    assert eng.metrics.snapshot()["prefill_chunks"] >= log.count("copy")
 
 
 def stepped_counts(eng, calls):
@@ -198,3 +303,30 @@ def test_set_up_compiles_a_fixed_list_of_programs():
     eng.run()
     assert [s[NAME] for s in since(m)
             if s[NAME] == "runner.compile"] == ["runner.compile"] * 2
+
+
+def test_dispatch_floor_times_every_variant_on_a_toy_engine():
+    """tools/dispatch_floor.py's `measure` off the chip: every variant of
+    the decode call and of the empty program is timed, the engine's pools
+    come back threaded through the calls, and the engine serves on."""
+    import os
+    import sys
+
+    sys.path.insert(0, os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "tools"))
+    import dispatch_floor
+
+    eng = toy_engine()
+    for p in ([1, 2, 3], [4, 5, 6, 7]):
+        eng.add_request(p, SamplingParams(max_tokens=12))
+    for _ in range(3):
+        eng.step()
+    got = dispatch_floor.measure(eng, steps=3)
+    assert got["workload_batch"] == 4 and got["leaves"] > 0
+    for variant in ("as_passed", "on_device", "packed", "packed_dev", "nop",
+                    "nop_leaves", "nop_host3"):
+        t = got[variant]
+        assert t["n"] == 3 and 0 < t["call_ms"] <= t["wall_ms"], variant
+    assert got["program_ms"] is None            # no device plane on the CPU
+    outs = eng.run()
+    assert all(len(o.output_tokens) == 12 for o in outs.values())

@@ -360,3 +360,181 @@ def test_scheduler_fuzz_no_leaks_and_oracle_equivalence():
                 runner, p, sp, max_model_len=max_model_len), \
                 f"trial {trial}: {rid} diverged from the oracle"
     assert total_preemptions > 0, "fuzz never exercised preemption churn"
+
+
+# ------------------------------------------ the step's book-keeping, put off
+
+
+class CountingStubRunner(StubPagedRunner):
+    """The stub as a runner that counts: each single-pass launch hands its
+    counts over on the device (`COUNTS`, `on_step_counts`), bumps the
+    host-side byte counters a real runner keeps, and fails once when told
+    to, before anything reaches the pools."""
+
+    COUNTS = ("moe_tokens_routed", "moe_local_pairs")
+
+    def __init__(self, fail_decode_calls=(), **kw):
+        super().__init__(**kw)
+        self.on_step_counts = None
+        self.attn_kv_bytes_read = 0.0
+        self.attn_kv_bytes_gather = 0.0
+        self.handed = []                  # every launch's counts, in order
+        self.decode_calls = 0
+        self.fail_decode_calls = set(fail_decode_calls)
+
+    def _count(self, tokens: int, rows: int) -> None:
+        import jax.numpy as jnp
+
+        self.attn_kv_bytes_read += 16.0 * tokens
+        self.attn_kv_bytes_gather += 64.0 * rows
+        self.handed.append((tokens, 3 * rows))
+        if self.on_step_counts is not None:
+            self.on_step_counts(jnp.asarray([tokens, 3 * rows], jnp.int32))
+
+    def prefill_chunk(self, tokens, start_pos, table, pools):
+        out = super().prefill_chunk(tokens, start_pos, table, pools)
+        self._count(len(tokens), 1)
+        return out
+
+    def decode(self, tokens, tables, pos, pools):
+        from paddle_tpu.serving.resilience import InjectedDeviceError
+
+        self.decode_calls += 1
+        if self.decode_calls in self.fail_decode_calls:
+            raise InjectedDeviceError(f"decode call {self.decode_calls}")
+        out = super().decode(tokens, tables, pos, pools)
+        self._count(len(np.asarray(tokens)), len(np.asarray(tokens)))
+        return out
+
+
+# the gauges a step's end mirrors, and where the engine's state holds each
+STEP_GAUGES = {
+    "attn_kv_bytes_read": lambda e: e.runner.attn_kv_bytes_read,
+    "attn_kv_bytes_gather": lambda e: e.runner.attn_kv_bytes_gather,
+    "queue_depth": lambda e: len(e.scheduler.waiting),
+    "running": lambda e: len(e.scheduler.running),
+    "pool_used_pages": lambda e: (e.pool.allocator.num_usable
+                                  - e.pool.allocator.num_free),
+    "pool_utilization": lambda e: e.pool.utilization(),
+}
+
+
+def bookkeeping_scenario(pipelined: bool):
+    """40 steps of a tight pool: staggered admissions, finishes, an abort, a
+    preemption and a decode call that fails once. Yields, after every
+    observable point, (what, engine, events so far): the caller reads."""
+    runner = CountingStubRunner(fail_decode_calls={7}, vocab_size=31,
+                                block_size=4, max_model_len=32)
+    eng = ServingEngine(runner, num_blocks=9, max_batch_size=3,
+                        max_model_len=32, pipelined=pipelined,
+                        sleep_fn=lambda s: None)
+    wl = np.random.default_rng(41)
+    work, events = [], []
+
+    def add():
+        p = list(map(int, wl.integers(0, 31, int(wl.integers(2, 9)))))
+        sp = SamplingParams(max_tokens=int(wl.integers(3, 12)))
+        work.append((eng.add_request(p, sp), p, sp))
+
+    add(), add()
+    yield "added", eng, events, work
+    for step in range(40):
+        if step in (1, 2, 4, 7, 11, 16, 22, 29):
+            add()
+            yield "added", eng, events, work
+        if step == 9:
+            victim = next(r for r in eng.scheduler.running)
+            assert eng.abort(victim.request_id)
+            yield "aborted", eng, events, work
+        events.extend(eng.step())
+        yield "stepped", eng, events, work
+    events.extend(eng.flush())
+    yield "flushed", eng, events, work
+    while eng.has_work():
+        events.extend(eng.step())
+        yield "stepped", eng, events, work
+    yield "done", eng, events, work
+
+
+@pytest.mark.parametrize("pipelined", [False, True],
+                         ids=["default", "pipelined"])
+def test_bookkeeping_put_off_is_exact_at_every_observation(pipelined):
+    """What a step puts off until the device is busy again (its gauges) and
+    what it reads without a round trip (a counting runner's counts) is, at
+    every point a caller can look, what a step that did it all at once
+    shows: after every step(), add_request(), abort(), after flush() and at
+    the end, `snapshot()`, each gauge's `value` and `peak`, the runner's
+    byte counters and the counts against the engine's own state, the
+    events so far and the sum of the launches' counts."""
+    peaks = dict.fromkeys(STEP_GAUGES, 0.0)
+    seen = set()
+    for n, (what, eng, events, work) in enumerate(
+            bookkeeping_scenario(pipelined)):
+        seen.add(what)
+        m = eng.metrics
+        # either door settles what is owed: the snapshot first at every
+        # other point, a gauge first at the rest
+        snap = m.snapshot() if n % 2 else None
+        want = {name: float(read(eng)) for name, read in STEP_GAUGES.items()}
+        if what in ("added", "aborted"):
+            # between steps only the queue's gauge is written
+            want = {"queue_depth": want["queue_depth"]}
+        for name, v in want.items():
+            peaks[name] = max(peaks[name], v)
+            assert getattr(m, name).value == v, (what, name)
+        for name in STEP_GAUGES:
+            assert getattr(m, name).peak == peaks[name], (what, name)
+        snap = snap or m.snapshot()
+        assert snap["queue_depth_peak"] == peaks["queue_depth"]
+        assert snap["pool_utilization_peak"] == peaks["pool_utilization"]
+        assert snap["tokens_generated"] == len(events)
+        assert snap["requests_finished"] == sum(e.finished for e in events)
+        # the counts: every launch whose drain is behind us, no other
+        inflight = eng._inflight is not None
+        handed = eng.runner.handed[:len(eng.runner.handed) - inflight]
+        assert snap["moe_tokens_routed"] == sum(t for t, _ in handed), what
+        assert snap["moe_local_pairs"] == sum(p for _, p in handed), what
+        assert not eng._step_counts or inflight
+        if what in ("flushed", "done"):
+            assert not inflight and m.owed is None
+    assert seen == {"added", "aborted", "stepped", "flushed", "done"}
+    snap = eng.metrics.snapshot()
+    assert snap["preemptions"] >= 1 and snap["step_retries"] == 1
+    assert snap["requests_aborted"] == 1 and snap["requests_finished"] >= 5
+    assert snap["host_syncs"] <= snap["decode_steps"] + snap["prefill_chunks"]
+    outs = eng.outputs()
+    assert len(outs) == len(work)
+    eng.runner.on_step_counts = None          # the oracle's launches: nobody's
+    for rid, p, sp in work:
+        ref = naive_generate(eng.runner, p, sp, max_model_len=32)
+        got = outs[rid].output_tokens
+        if outs[rid].finish_reason == "aborted":
+            assert got == ref[:len(got)]
+        else:
+            assert got == ref and outs[rid].finish_reason == "length"
+    assert eng.pool.allocator.check_no_leaks()
+
+
+def test_a_failed_call_is_accounted_like_one_that_ran(llama_runner,
+                                                     monkeypatch):
+    """The runner counts a call's bytes once the call is on its way; a call
+    that raised counts as it did when the accounting came first."""
+    runner = llama_runner
+    pool = KVCachePool.for_runner(runner, 8)
+    tables = np.zeros((2, 8), np.int32)
+    tables[0, :2] = pool.allocator.alloc(2)
+    args = (np.asarray([3, 0]), tables, np.asarray([9, 0]), pool.pools)
+    before = runner.attn_kv_bytes_gather
+    runner.decode(*args)
+    once = runner.attn_kv_bytes_gather - before
+    assert once > 0
+
+    def broken(kind, key):
+        def fn(*a):
+            raise RuntimeError("device lost")
+        return fn
+
+    monkeypatch.setattr(runner, "_jitted", broken)
+    with pytest.raises(RuntimeError, match="device lost"):
+        runner.decode(*args)
+    assert runner.attn_kv_bytes_gather - before == 2 * once

@@ -21,23 +21,56 @@ import os
 import sys
 
 
+def cell_run(root: str, workload: str, seed: int):
+    """`bench/run.py`'s `Run` of one cell of the checkout `root`, as its
+    `main()` sets it up: the checkout's `bench/` and `paddle_tpu` on the
+    path, the devices found, every program cached. Needs the chip."""
+    root = os.path.abspath(root)
+    os.chdir(root)
+    sys.path[:0] = [os.path.join(root, "bench"), root]
+
+    import run as R                 # stamps T_START: the process is young
+
+    run = R.Run(R.parse(["--workload", workload, "--seed", str(seed),
+                         "--seconds", "0.5"]),
+                R.load_json(root, "BENCHMARK.json"))
+    run.find_devices()
+    import jax
+    from paddle_tpu.utils.compile_cache import place_compile_cache
+
+    # as bench/run.py's main(): every program is cached, the small ones too
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    run.cache = place_compile_cache()
+    return run
+
+
+def filled(run):
+    """(driver, index of the window's first step): the cell's engine built
+    from the seed and filled by the benchmark's own closed loop, then a
+    window of `run.seconds`."""
+    import serve
+    import traffic_gen
+
+    drv = serve.Driver(serve.build_engine(run))
+    clients = traffic_gen.closed_clients(run.traffic,
+                                         run.config["vocab_size"], run.seed)
+    n0, _, _ = serve._window_closed(run, drv, clients)
+    return drv, n0
+
+
 def measure(run, label: str = "") -> dict:
     """Build and fill `run`'s cell as the benchmark does and read the ring.
     `run` has its devices; `bench/` and the checkout are on `sys.path`."""
     import jax
 
     import program_spans as ps
-    import serve
-    import traffic_gen
 
     backend = []
     jax.monitoring.register_event_duration_secs_listener(
         lambda name, secs, **kw: backend.append(secs)
         if name.endswith("backend_compile_duration") else None)
-    drv = serve.Driver(serve.build_engine(run))
-    clients = traffic_gen.closed_clients(run.traffic,
-                                         run.config["vocab_size"], run.seed)
-    n0, _, _ = serve._window_closed(run, drv, clients)
+    drv, n0 = filled(run)
     first = drv.steps[n0][0] * 1e9
     ring = [s for s in ps.ring() if s[ps.T1] <= first]
     secs = lambda s: (s[ps.T1] - s[ps.T0]) / 1e9
@@ -63,25 +96,10 @@ def main(argv=None) -> int:
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--label", default="")
     args = ap.parse_args(argv)
-    root = os.path.abspath(args.root)
-    os.chdir(root)
-    sys.path[:0] = [os.path.join(root, "bench"), root]
-
-    import run as R                 # stamps T_START: the process is young
-
-    run = R.Run(R.parse(["--workload", args.workload, "--seed",
-                         str(args.seed), "--seconds", "0.5"]),
-                R.load_json(root, "BENCHMARK.json"))
-    run.find_devices()
-    import jax
-    from paddle_tpu.utils.compile_cache import place_compile_cache
-
-    # as bench/run.py's main(): every program is cached, the small ones too
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-    cache = place_compile_cache()
-    print(json.dumps(dict(measure(run, args.label), root=root,
-                          cache=str(cache))), flush=True)
+    run = cell_run(args.root, args.workload, args.seed)
+    print(json.dumps(dict(measure(run, args.label),
+                          root=os.path.abspath(args.root),
+                          cache=str(run.cache))), flush=True)
     return 0
 
 
