@@ -162,7 +162,8 @@ def create_predictor(config: Config) -> Predictor:
 
 def create_serving_engine(model, dtype=None, **kw):
     """Build a continuous-batching ServingEngine for a decoder Layer
-    (Llama, GPT, DeepseekV3ForCausalLM, OlmoHybridForCausalLM).
+    (Llama, GPT, DeepseekV3ForCausalLM, OlmoHybridForCausalLM,
+    Phi4FlashForCausalLM).
 
     The serving-path analogue of create_predictor: where the reference
     pairs fluid/inference with block_multihead_attention and a serving

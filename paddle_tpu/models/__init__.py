@@ -13,6 +13,9 @@ from paddle_tpu.models.ernie import (  # noqa: F401
 from paddle_tpu.models.olmo_hybrid import (  # noqa: F401
     OlmoHybridConfig, OlmoHybridForCausalLM,
 )
+from paddle_tpu.models.phi4flash import (  # noqa: F401
+    Phi4FlashConfig, Phi4FlashForCausalLM,
+)
 from paddle_tpu.models.llama import (  # noqa: F401
     Llama, LlamaConfig, llama_loss_fn,
 )

@@ -48,6 +48,18 @@ ops/pallas/flash_attention.py, with the page walk inside the kernel):
 
 Layout: q [B, T, n_q_heads, d]; pools [num_pages, page_size, n_kv, d];
 block_table [B, pages_per_seq] int32; start_pos/q_len [B] int32.
+ROW POOLS, [num_pages, page_size * n_kv, d] with `kv_heads=n_kv`: a page as
+its (key, kv-head) rows, which is how the few-rows layout folds it anyway.
+Such a page is whole tiles whatever n_kv is (10 heads of 128 lanes: 160
+rows), where `[page_size, 10, d]` would be allocated and copied as 16 heads;
+only few-row spans (decode) take them.
+A LOWER BOUND (`lower` [B]): sequence b's rows see no key before position
+lower[b] (a sliding window's edge, or the first position a ring of pages
+still holds). Pages wholly before it are neither copied nor folded, and the
+first live page is masked from the bound on. Positions are the table's: its
+column c holds positions [c * page_size, (c + 1) * page_size), so a caller
+whose table starts at a later page passes start_pos and lower less that
+page's first position.
 Causality is absolute-position based: query row t of sequence b sees
 keys at positions <= start_pos[b] + t, i.e. masked_cache_attention
 semantics — everything already written through the block table (earlier
@@ -118,7 +130,8 @@ def _span_tile(T: int, n_rep: int) -> int:
 
 
 def pages_per_block(T: int, n_q: int, q_itemsize: int, page_size: int,
-                    n_kv: int, d: int, kv_itemsize: int) -> int:
+                    n_kv: int, d: int, kv_itemsize: int,
+                    row_pools: bool = False) -> int:
     """Pages one block of the in-kernel walk copies and folds: the
     largest power of two at which a grid step fits VMEM_BUDGET (whatever
     the table's width: the same spans fold in the same order under any).
@@ -128,10 +141,16 @@ def pages_per_block(T: int, n_q: int, q_itemsize: int, page_size: int,
     the pages hold it, 5 with the head-major copies of the batched
     matmuls) and 4-5 score tiles; per step the q and out blocks twice
     and three float32 copies of the tile's rows."""
-    n_q = n_q // n_kv * _page_copy_heads(n_kv, kv_itemsize)
-    n_kv, d = _page_copy_heads(n_kv, kv_itemsize), -(-d // 128) * 128
-    page_f32 = page_size * _tile_elems(n_kv, d, 4) * 4
-    per_page = 4 * page_size * _tile_elems(n_kv, d, kv_itemsize) * kv_itemsize
+    if row_pools:
+        # a page is its rows: no head is padded
+        page_f32 = page_size * n_kv * d * 4
+        per_page = 4 * page_size * n_kv * d * kv_itemsize
+    else:
+        n_q = n_q // n_kv * _page_copy_heads(n_kv, kv_itemsize)
+        n_kv, d = _page_copy_heads(n_kv, kv_itemsize), -(-d // 128) * 128
+        page_f32 = page_size * _tile_elems(n_kv, d, 4) * 4
+        per_page = (4 * page_size * _tile_elems(n_kv, d, kv_itemsize)
+                    * kv_itemsize)
     if _flat(T, n_q):
         rows = -(-T * n_q // 8) * 8
         per_page += 2 * page_f32 + 5 * rows * page_size * n_kv * 4
@@ -157,7 +176,8 @@ def _tile_pages(start, qlen, t0, tq: int, page_size: int, table_width: int):
 
 def _ragged_kernel(table_ref, start_ref, qlen_ref, *refs, page_size: int,
                    n_q: int, tq: int, flat: bool, scale: float,
-                   quantized: bool):
+                   quantized: bool, bounded: bool = False,
+                   kv_heads: int | None = None):
     """Grid (b, q_tile): one step folds every live page of sequence b
     into one tile of its span rows, a block of pages at a time.
 
@@ -174,14 +194,24 @@ def _ragged_kernel(table_ref, start_ref, qlen_ref, *refs, page_size: int,
     softmax stays fp32.
 
     flat: the q block is [rows, d], rows (t, q-head); else [n_kv, G, d],
-    G rows (t, rep) of each kv-head's group."""
+    G rows (t, rep) of each kv-head's group.
+
+    bounded: a fourth scalar row, `lower`: the walk of sequence b starts at
+    the block that holds position lower[b], copies no page before that
+    position's, and masks the keys before it. kv_heads: the pools are row
+    pools (a page [page_size * kv_heads, d]) and so are the buffers."""
+    if bounded:
+        lower_ref, *refs = refs
     if quantized:
         kscale_ref, vscale_ref, *refs = refs
     (q_ref, k_hbm, v_hbm, o_ref, kbuf, vbuf, sem, m_ref, l_ref, acc_ref,
      slot_ref) = refs
     b, i = pl.program_id(0), pl.program_id(1)
     n_seq, n_tiles = pl.num_programs(0), pl.num_programs(1)
-    ppb, n_kv, d = kbuf.shape[1], kbuf.shape[3], kbuf.shape[4]
+    if kv_heads is None:
+        ppb, n_kv, d = kbuf.shape[1], kbuf.shape[3], kbuf.shape[4]
+    else:
+        ppb, n_kv, d = kbuf.shape[1], kv_heads, kbuf.shape[3]
     n_rep = n_q // n_kv
     keys = ppb * page_size             # keys in one block
     table_width = table_ref.shape[1]
@@ -189,6 +219,10 @@ def _ragged_kernel(table_ref, start_ref, qlen_ref, *refs, page_size: int,
     def tile_pages(b_, i_):
         return _tile_pages(start_ref[b_], qlen_ref[b_], i_ * tq, tq,
                            page_size, table_width)
+
+    def first_page(b_):
+        """The page that holds sequence b_'s lower bound: its walk's first."""
+        return lower_ref[b_] // page_size if bounded else 0
 
     def copies(b_, block, slot, n_pages, wait: bool = False):
         """Start (or wait for) one copy per live page of `block` of
@@ -204,7 +238,9 @@ def _ragged_kernel(table_ref, start_ref, qlen_ref, *refs, page_size: int,
                 cp.wait() if wait else cp.start()
             return carry
 
-        jax.lax.fori_loop(0, jnp.clip(n_pages - first, 0, ppb), page, 0)
+        jax.lax.fori_loop(jnp.clip(first_page(b_) - first, 0, ppb)
+                          if bounded else 0,
+                          jnp.clip(n_pages - first, 0, ppb), page, 0)
 
     start, qlen, t0 = start_ref[b], qlen_ref[b], i * tq
     # last key position any live row of this tile sees (causal: rows
@@ -212,6 +248,10 @@ def _ragged_kernel(table_ref, start_ref, qlen_ref, *refs, page_size: int,
     last_pos = start + jnp.minimum(qlen, t0 + tq) - 1
     n_pages = tile_pages(b, i)
     n_blocks = pl.cdiv(n_pages, ppb)
+    # the walk's first block (0 without a bound) and how many it folds
+    block0 = first_page(b) // ppb
+    n_walk = jnp.maximum(n_blocks - block0, 0) if bounded else n_blocks
+    lower = lower_ref[b] if bounded else 0
     # the grid step after this one: its first block is this step's to start
     wraps = i == n_tiles - 1
     nb = jnp.minimum(jnp.where(wraps, b + 1, b), n_seq - 1)
@@ -221,7 +261,7 @@ def _ragged_kernel(table_ref, start_ref, qlen_ref, *refs, page_size: int,
     @pl.when((b == 0) & (i == 0))
     def _first_step():
         slot_ref[0] = 0
-        copies(b, 0, 0, n_pages)
+        copies(b, block0, 0, n_pages)
 
     slot0 = slot_ref[0]                # buffer of this tile's block 0
     m_ref[...] = jnp.full(m_ref.shape, NEG_INF, jnp.float32)
@@ -239,15 +279,24 @@ def _ragged_kernel(table_ref, start_ref, qlen_ref, *refs, page_size: int,
 
     def fold(block, slot):
         key0 = block * keys
-        k = kbuf[slot].reshape(keys, n_kv, d)
-        v = vbuf[slot].reshape(keys, n_kv, d)
+        if kv_heads is None:
+            k = kbuf[slot].reshape(keys, n_kv, d)
+            v = vbuf[slot].reshape(keys, n_kv, d)
+            v_pos = key0 + jax.lax.broadcasted_iota(jnp.int32, v.shape, 0)
+        else:
+            k = kbuf[slot].reshape(keys * n_kv, d)
+            v = vbuf[slot].reshape(keys * n_kv, d)
+            v_pos = key0 + jax.lax.broadcasted_iota(
+                jnp.int32, v.shape, 0) // n_kv
         if quantized:
             k = dequantized(k, kscale_ref, block * ppb)
             v = dequantized(v, vscale_ref, block * ppb)
         # the slots of a partial block that no copy filled hold what was
         # there before, and 0 * NaN is NaN: V is masked, not only scores
-        v = jnp.where(key0 + jax.lax.broadcasted_iota(
-            jnp.int32, (keys, n_kv, d), 0) <= last_pos, v, jnp.zeros_like(v))
+        v_live = v_pos <= last_pos
+        if bounded:
+            v_live &= v_pos >= lower
+        v = jnp.where(v_live, v, jnp.zeros_like(v))
         if v.dtype != jnp.bfloat16:
             v = v.astype(jnp.float32)
         if flat:
@@ -255,7 +304,7 @@ def _ragged_kernel(table_ref, start_ref, qlen_ref, *refs, page_size: int,
             # (key, kv-head) row of the block as the pages hold them, one
             # matmul; a row keeps the columns of its own kv-head
             q = q_ref[0]                               # row (t, q-head)
-            k = k.reshape(keys * n_kv, d)
+            k = k.reshape(keys * n_kv, d)              # (row pools: as is)
             v = v.reshape(keys * n_kv, d)
             if k.dtype != q.dtype:
                 q, k = q.astype(jnp.float32), k.astype(jnp.float32)
@@ -281,8 +330,10 @@ def _ragged_kernel(table_ref, start_ref, qlen_ref, *refs, page_size: int,
             k_pos = key0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
             own = True
         # rows t >= qlen are padding
-        s = jnp.where(own & (k_pos <= start + t_idx) & (t_idx < qlen),
-                      s * scale, NEG_INF)
+        seen = own & (k_pos <= start + t_idx) & (t_idx < qlen)
+        if bounded:
+            seen &= k_pos >= lower
+        s = jnp.where(seen, s * scale, NEG_INF)
         m = m_ref[...]
         new_m = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
         # masked-row guard: where every key so far is hard-masked, new_m
@@ -308,31 +359,35 @@ def _ragged_kernel(table_ref, start_ref, qlen_ref, *refs, page_size: int,
                 preferred_element_type=jnp.float32)    # [n_kv, G, d]
         acc_ref[...] = acc_ref[...] * corr + pv
 
+    next_block0 = first_page(nb) // ppb
+
     def walk(block, carry):
-        slot = (slot0 + block) % 2
+        slot = (slot0 + block - block0) % 2
         # in flight while this block is folded: the tile's next block,
         # or after its last the first block of the next grid step
         more = block + 1 < n_blocks
-        copies(jnp.where(more, b, nb), jnp.where(more, block + 1, 0),
+        copies(jnp.where(more, b, nb),
+               jnp.where(more, block + 1, next_block0),
                1 - slot, jnp.where(more, n_pages, next_pages))
         copies(b, block, slot, n_pages, wait=True)
         fold(block, slot)
         return carry
 
-    jax.lax.fori_loop(0, n_blocks, walk, 0)
+    jax.lax.fori_loop(block0, block0 + n_walk, walk, 0)
 
-    @pl.when(n_blocks == 0)
+    @pl.when(n_walk == 0)
     def _dead_step():                  # nothing to fold: hand on the start
-        copies(nb, 0, slot0, next_pages)
+        copies(nb, next_block0, slot0, next_pages)
 
-    slot_ref[0] = (slot0 + n_blocks) % 2
+    slot_ref[0] = (slot0 + n_walk) % 2
     o_ref[0] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
                 ).astype(o_ref.dtype)
 
 
 def ragged_paged_attention(q, k_pool, v_pool, block_table, start_pos, q_len,
                            scale=None, interpret: bool | None = None,
-                           k_scale=None, v_scale=None):
+                           k_scale=None, v_scale=None, lower=None,
+                           kv_heads: int | None = None):
     """Causal attention for a ragged batch of query spans over paged KV.
 
     q: [B, T, n_q_heads, d] — T is the PADDED span length (power-of-2
@@ -353,9 +408,22 @@ def ragged_paged_attention(q, k_pool, v_pool, block_table, start_pos, q_len,
     A head layout whose pages are not whole tiles (head_dim not a
     multiple of 128; see _page_copy_heads) is padded to one HERE, pools
     included: a copy of both pools per call. Such a model's pools
-    should be allocated padded (ROADMAP S1).
+    should be allocated padded (ROADMAP S1), or as row pools.
+
+    lower [B] int32: no row of sequence b sees a key before position
+    lower[b] (the head of this file). kv_heads: the pools are ROW POOLS,
+    [num_pages, page_size * kv_heads, d] (few-row spans only, d a multiple
+    of 128, no int8 scales).
     """
-    n_q, n_kv = q.shape[2], k_pool.shape[2]
+    n_q, n_kv = q.shape[2], kv_heads or k_pool.shape[2]
+    if kv_heads is not None:
+        if k_pool.ndim != 3 or k_pool.shape[1] % kv_heads \
+                or q.shape[3] % 128 or k_scale is not None \
+                or not _flat(q.shape[1], n_q):
+            raise ValueError(
+                f"row pools are [pages, page_size * {kv_heads}, d] with d a "
+                "multiple of 128, unquantized, under few-row spans; got "
+                f"pools {k_pool.shape}, q {q.shape}")
     if n_q % n_kv:
         raise ValueError(f"n_q_heads={n_q} not a multiple of "
                          f"n_kv_heads={n_kv}")
@@ -365,22 +433,30 @@ def ragged_paged_attention(q, k_pool, v_pool, block_table, start_pos, q_len,
         interpret = jax.default_backend() != "tpu"
     return _ragged_call(
         q, k_pool, v_pool, block_table, start_pos, q_len, k_scale, v_scale,
+        lower,
         scale=float(scale if scale is not None else 1.0 / np.sqrt(q.shape[3])),
-        interpret=bool(interpret))
+        interpret=bool(interpret), kv_heads=kv_heads)
 
 
 # jitted here, not only by the caller: a model's layers call it with the
 # same shapes, and a jitted callee is traced and lowered once per
 # program, not once per layer (24 kernels of some 20 k characters of
 # MLIR each in a GPT-3 1.3B step, which set-up pays for warm or cold)
-@functools.partial(jax.jit, static_argnames=("scale", "interpret"))
+@functools.partial(jax.jit,
+                   static_argnames=("scale", "interpret", "kv_heads"))
 def _ragged_call(q, k_pool, v_pool, block_table, start_pos, q_len, k_scale,
-                 v_scale, *, scale: float, interpret: bool):
+                 v_scale, lower=None, *, scale: float, interpret: bool,
+                 kv_heads: int | None = None):
     B, T, n_q, d = q.shape
-    page_size, n_kv = k_pool.shape[1], k_pool.shape[2]
+    if kv_heads is None:
+        page_size, n_kv = k_pool.shape[1], k_pool.shape[2]
+    else:
+        page_size, n_kv = k_pool.shape[1] // kv_heads, kv_heads
     quantized = k_scale is not None
+    bounded = lower is not None
     n_rep = n_q // n_kv
-    pad_kv = _page_copy_heads(n_kv, k_pool.dtype.itemsize) - n_kv
+    pad_kv = 0 if kv_heads else \
+        _page_copy_heads(n_kv, k_pool.dtype.itemsize) - n_kv
     pad_d = -d % 128
     if pad_kv or pad_d:
         # zero heads and zero lanes: the scores of the real heads are
@@ -392,14 +468,15 @@ def _ragged_call(q, k_pool, v_pool, block_table, start_pos, q_len, k_scale,
             k_scale, v_scale = (jnp.pad(x, ((0, 0), (0, pad_kv)))
                                 for x in (k_scale, v_scale))
         out = _ragged_call(q, k_pool, v_pool, block_table, start_pos, q_len,
-                           k_scale, v_scale, scale=scale, interpret=interpret)
+                           k_scale, v_scale, lower, scale=scale,
+                           interpret=interpret)
         return out[:, :, :n_q, :d]
     start_arr = jnp.broadcast_to(
         jnp.asarray(start_pos, jnp.int32).reshape(-1), (B,))
     qlen_arr = jnp.broadcast_to(
         jnp.asarray(q_len, jnp.int32).reshape(-1), (B,))
     ppb = pages_per_block(T, n_q, q.dtype.itemsize, page_size, n_kv, d,
-                          k_pool.dtype.itemsize)
+                          k_pool.dtype.itemsize, row_pools=bool(kv_heads))
     flat = _flat(T, n_q)
     if flat:
         # rows stay (t, q-head) as q has them: one tile, no transposes
@@ -417,11 +494,12 @@ def _ragged_call(q, k_pool, v_pool, block_table, start_pos, q_len, k_scale,
         qg = qg.reshape(B, n_kv, T * n_rep, d)
         q_spec = pl.BlockSpec((1, n_kv, G, d), lambda b, i, *_: (b, 0, i, 0))
         stats, acc = (n_kv, G, 1), (n_kv, G, d)
-    kv_buf = pltpu.VMEM((2, ppb, page_size, n_kv, d), k_pool.dtype)
+    kv_buf = pltpu.VMEM((2, ppb) + k_pool.shape[1:], k_pool.dtype)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         # quantized pools prefetch the scale rows alongside the tables:
-        # scalars 3/4 are k_scale/v_scale, read per page id
-        num_scalar_prefetch=5 if quantized else 3,
+        # the last two scalars are k_scale/v_scale, read per page id; a
+        # bounded walk's lower bounds ride before them
+        num_scalar_prefetch=3 + bounded + 2 * quantized,
         grid=(B, T // tq),
         in_specs=[q_spec,
                   pl.BlockSpec(memory_space=pl.ANY),
@@ -437,13 +515,17 @@ def _ragged_call(q, k_pool, v_pool, block_table, start_pos, q_len, k_scale,
         ],
     )
     scalars = (block_table.astype(jnp.int32), start_arr, qlen_arr)
+    if bounded:
+        scalars += (jnp.broadcast_to(
+            jnp.asarray(lower, jnp.int32).reshape(-1), (B,)),)
     if quantized:
         scalars += (jnp.asarray(k_scale, jnp.float32),
                     jnp.asarray(v_scale, jnp.float32))
     out = pl.pallas_call(
         functools.partial(_ragged_kernel, page_size=page_size, n_q=n_q,
                           tq=tq, flat=flat, scale=scale,
-                          quantized=quantized),
+                          quantized=quantized, bounded=bounded,
+                          kv_heads=kv_heads),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(qg.shape,
                                        jnp.float32 if quantized else q.dtype),
@@ -468,7 +550,8 @@ def ragged_attention_ok(head_dim: int, n_q_heads: int,
 
 
 def ragged_reference(q, k_pool, v_pool, block_table, start_pos, q_len,
-                     scale=None, k_scale=None, v_scale=None):
+                     scale=None, k_scale=None, v_scale=None, lower=None,
+                     kv_heads: int | None = None):
     """Gather + dense-mask oracle with the kernel's exact output contract
     (padded rows and dead slots produce exact zeros). O(B * pages_per_seq
     * page_size) HBM — the path the kernel exists to retire; kept as the
@@ -479,6 +562,9 @@ def ragged_reference(q, k_pool, v_pool, block_table, start_pos, q_len,
     — kernel-vs-reference comparisons stay exact in the int8 domain
     (both dequantize identical codes with identical scales)."""
     B, T, n_q, d = q.shape
+    if kv_heads is not None:             # row pools: a page's rows by head
+        k_pool, v_pool = (a.reshape(a.shape[0], -1, kv_heads, d)
+                          for a in (k_pool, v_pool))
     page_size = k_pool.shape[1]
     n_kv = k_pool.shape[2]
     n_rep = n_q // n_kv
@@ -507,6 +593,9 @@ def ragged_reference(q, k_pool, v_pool, block_table, start_pos, q_len,
     k_pos = jnp.arange(L, dtype=jnp.int32)
     visible = ((k_pos[None, None, :] <= q_pos[:, :, None])
                & (t_idx[None, :, None] < qlen[:, None, None]))  # [B, T, L]
+    if lower is not None:
+        visible &= k_pos[None, None, :] >= jnp.asarray(
+            lower, jnp.int32).reshape(-1)[:, None, None]
     s = jnp.where(visible[:, None], s, NEG_INF)
     row_live = jnp.any(s > NEG_INF * 0.5, axis=-1, keepdims=True)
     p = jax.nn.softmax(s, axis=-1)

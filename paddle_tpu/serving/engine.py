@@ -496,7 +496,8 @@ class ServingEngine:
         self.pool = KVCachePool.for_runner(
             runner, self.num_blocks, mesh=self.mesh,
             model_axis=getattr(runner, "model_axis", "model"),
-            state_slots=self.max_batch_size + 1)
+            state_slots=self.max_batch_size,
+            window_span=max(1, self.decode_horizon))
         if self.pool.state_layers:
             self._refuse_state_copies(kv_store)
         if self.enable_prefix_cache:
@@ -607,7 +608,12 @@ class ServingEngine:
         shared prefix, a spill to the host, a handoff) or a ROLLBACK
         (rejected drafts), or feed several rows of several sequences to
         one launch; none of that is built, and pages without their state
-        would serve wrong tokens in silence. Refused by name, here."""
+        would serve wrong tokens in silence. A runner's WINDOW GROUP (a
+        ring of the last positions' pages, `pool.window`) is refused with
+        them for the same reason: its pages are a state that moves forward
+        and gives back what fell behind, so a shared prefix, a spilled or
+        handed-off sequence and a rolled-back draft would each need pages
+        it no longer has. Refused by name, here."""
         asked = {
             "enable_prefix_cache": self.enable_prefix_cache,
             "host_tier_pages": self.host_tier_pages,
@@ -622,7 +628,9 @@ class ServingEngine:
                     f"{name} is not built for a runner with recurrent "
                     f"state ({type(self.runner).__name__}): a state slot "
                     "cannot be shared, spilled, handed off or rolled back "
-                    "yet, and its pages alone would serve wrong tokens. "
+                    "yet (nor can a window group's ring of pages, for the "
+                    "same reason: what fell behind the window is gone), "
+                    "and its pages alone would serve wrong tokens. "
                     "Supported: max_prefill_tokens_per_step, "
                     "decode_horizon, horizon_early_stop, pipelined")
 
@@ -1129,6 +1137,11 @@ class ServingEngine:
         if self.pool.state_layers:
             # a running request holds its slot, and so its state
             m.state_slots_live.set(running)
+        ring = self.pool.window
+        if ring is not None:
+            m.window_pages_held.set(ring.held_page_rows)
+            m.window_pages_whole_context.set(ring.whole_context_page_rows)
+            m.window_pages_returned.set(ring.pages_returned)
         if cached is not None:
             m.prefix_cached_pages.set(cached)
         if tier_bytes is not None:
@@ -1216,6 +1229,11 @@ class ServingEngine:
             row = req.kv.pages_array()
             tables[sl, :len(row)] = row
             pos[sl] = start                  # position of the first fed token
+        if self.pool.window is not None:
+            # a second group of pages rides in the same table: its live
+            # pages and their base, by slot (`WindowGroup.extend_tables`)
+            tables = self.pool.window.extend_tables(
+                tables, [(sl, start, end) for _, sl, start, end, _, _ in rows])
         return (tokens, tables, pos, q_lens) if ragged else (tokens, tables,
                                                              pos)
 
@@ -1311,6 +1329,9 @@ class ServingEngine:
             ops = (req.context_tokens[start:end], start, table)
             # recurrent state lives at the request's decode slot
             at = {"slot": req.slot} if self.pool.state_layers else {}
+            if self.pool.window is not None:
+                # the window group's ring as the chunk finds and leaves it
+                at["ring"] = self.pool.window.advance(req.slot, end)
         out = self._call_retrying(
             lambda: None if req.done else ops,
             lambda ops: self.runner.prefill_chunk(*ops, self.pool.pools,
@@ -2421,7 +2442,11 @@ def naive_generate(runner: PagedModelRunner, prompt_tokens: Sequence[int],
                    or pool.native_kv_tag())
     table = pool.pad_table(pages, max_pages)
     tokens = list(map(int, prompt_tokens))
-    logits, pools = runner.prefill(tokens, table, pool.pools)
+    ring, at = pool.window, {}
+    if ring is not None:
+        # a window group beside the pages: the one sequence is slot 0's
+        at = {"slot": 0, "ring": ring.advance(0, len(tokens))}
+    logits, pools = runner.prefill_chunk(tokens, 0, table, pool.pools, **at)
     out: List[int] = []
     tok = sample_token(np.asarray(logits), sampling, 0, fallback_seed)
     out.append(tok)
@@ -2429,8 +2454,10 @@ def naive_generate(runner: PagedModelRunner, prompt_tokens: Sequence[int],
     while len(out) < sampling.max_tokens and tok not in \
             sampling.stop_token_ids:
         pos = np.asarray([len(tokens) + len(out) - 1], np.int32)
-        logits, pools = runner.decode(np.asarray([tok], np.int32), tables,
-                                      pos, pools)
+        logits, pools = runner.decode(
+            np.asarray([tok], np.int32),
+            tables if ring is None else ring.extend_tables(
+                tables, [(0, int(pos[0]), int(pos[0]) + 1)]), pos, pools)
         tok = sample_token(np.asarray(logits)[0], sampling, len(out),
                            fallback_seed)
         out.append(tok)

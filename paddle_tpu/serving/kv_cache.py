@@ -72,6 +72,7 @@ dtypes).
 
 from __future__ import annotations
 
+import heapq
 import threading
 from bisect import insort
 from dataclasses import dataclass, field
@@ -1799,7 +1800,7 @@ def _page_layout_of(runner) -> list:
 
 
 def page_arrays(block_size: int, page_layout, kv_dtype: str = "fp32",
-                model_axis: str = "model") -> list:
+                model_axis: str = "model", row_pages: bool = False) -> list:
     """ONE page of one layer as a pool stores it, `[(what, shape, dtype,
     PartitionSpec of the pool's array), ...]`: the one description that
     KVCachePool allocates from (a leading `[num_blocks]` on each shape),
@@ -1810,10 +1811,23 @@ def page_arrays(block_size: int, page_layout, kv_dtype: str = "fp32",
     come in every `kv_dtype` rung (int8 codes with one float32 scale per
     page per head, float8 pages, float32 pages with a per-page tag
     plane); any other layout (a latent layer's one array) comes in the
-    stated dtype on one device only."""
+    stated dtype on one device only. `row_pages`: a (k, v) page is kept
+    as its rows, `[block_size * heads, head_dim]` (key-major), which is
+    whole tiles for any count of heads where `[block_size, heads,
+    head_dim]` pads the heads to the tile (10 heads of 128 lanes would be
+    allocated as 16); in the stated dtype or float8, on one device."""
     layout = [(tuple(int(n) for n in t), jnp.dtype(d))
               for t, d in page_layout]
     per_head = all(len(t) == 2 for t, _ in layout)
+    if row_pages:
+        if not (per_head and len(layout) == 2) or kv_dtype not in ("fp32",
+                                                                   "fp8"):
+            raise ValueError(
+                "row pages are (k, v) pages of [heads, head_dim] in the "
+                f"stated dtype or fp8; got {layout} in {kv_dtype!r}")
+        store = jnp.dtype(jnp.float8_e4m3fn) if kv_dtype == "fp8" else None
+        return [(what + " rows", (block_size * t[0], t[1]), store or d,
+                 PartitionSpec()) for what, (t, d) in zip("kv", layout)]
     if kv_dtype != "fp32" and not (per_head and len(layout) == 2):
         raise ValueError(
             f"kv_dtype={kv_dtype!r} is a rung of (k, v) pages of [heads, "
@@ -1846,6 +1860,127 @@ def page_arrays(block_size: int, page_layout, kv_dtype: str = "fp32",
     return arrays
 
 
+class WindowGroup:
+    """The host side of a page GROUP whose layers keep only a sequence's
+    last `window` positions (sliding-window attention): its own pages
+    (page 0 is its scratch), its own free list, and per decode slot the
+    pages that hold the positions still inside the window, oldest first.
+
+    The group is sized by the program, not by an option: every one of
+    `slots` sequences may hold the pages of `window - 1 + span` consecutive
+    positions (`span`: the most positions one launch writes, 1 for a plain
+    decode step), which touch at most `pages_per_seq = ceil((window - 1 +
+    span) / block_size) + 1` pages. So `cover` never fails and admission
+    has nothing to count here: a slot is a sequence's whole claim.
+
+    `cover(slot, lo, hi)` makes the slot hold exactly the pages of
+    positions [lo, hi): pages that fell behind `lo` go back to the free
+    list (the next `cover` of any slot may hand them out), pages up to
+    `hi` are taken. `row(slot)` is what the device sees: the live pages
+    only, then the index of the first one's page in the sequence, so that
+    a kernel's positions are the table's (`position - base * block_size`)
+    and its lower bound is a position inside the first live page."""
+
+    def __init__(self, layers: int, window: int, block_size: int,
+                 slots: int, span: int = 1):
+        self.layers, self.window = int(layers), int(window)
+        self.block_size = int(block_size)
+        self.pages_per_seq = -(-(self.window - 1 + max(1, int(span)))
+                               // self.block_size) + 1
+        self.num_blocks = 1 + int(slots) * self.pages_per_seq
+        self._free = list(range(1, self.num_blocks))   # a heap: lowest first
+        self._held: Dict[int, Tuple[int, List[int]]] = {}
+        self.pages_taken = 0          # ever handed out
+        self.pages_returned = 0       # ever given back
+        # summed over the rows of every launch `extend_tables` built: the
+        # pages a layer held for the row, and what a cache of its whole
+        # context would have held
+        self.held_page_rows = 0
+        self.whole_context_page_rows = 0
+
+    @property
+    def width(self) -> int:
+        """Columns a row adds to a block table: the pages, then the base."""
+        return self.pages_per_seq + 1
+
+    @property
+    def num_held(self) -> int:
+        return self.num_blocks - 1 - len(self._free)
+
+    def held(self, slot: int) -> int:
+        return len(self._held.get(slot, (0, ()))[1])
+
+    def cover(self, slot: int, lo: int, hi: int) -> None:
+        bs = self.block_size
+        first, last = max(0, lo) // bs, (hi - 1) // bs
+        base, pages = self._held.get(slot, (first, []))
+        if pages and not base <= first <= base + len(pages):
+            # not a continuation (a slot's new holder, a restart): all go
+            self._give_back(pages)
+            base, pages = first, []
+        drop = first - base
+        self._give_back(pages[:drop])
+        pages = pages[drop:]
+        need = last + 1 - first - len(pages)
+        if need > 0:
+            if len(pages) + need > self.pages_per_seq:
+                raise ValueError(
+                    f"positions [{lo}, {hi}) span more pages than a window "
+                    f"sequence holds ({self.pages_per_seq})")
+            pages = pages + [heapq.heappop(self._free) for _ in range(need)]
+            self.pages_taken += need
+        self._held[slot] = (first, pages)
+
+    def _give_back(self, pages) -> None:
+        for page in pages:
+            heapq.heappush(self._free, page)
+        self.pages_returned += len(pages)
+
+    def release(self, slot: int) -> None:
+        """Everything the slot holds goes back (its request ended or was
+        preempted)."""
+        self._give_back(self._held.pop(slot, (0, []))[1])
+
+    def row(self, slot: int) -> np.ndarray:
+        """[width] int32: the slot's live pages, scratch after them, then
+        the base (all scratch and 0 for a slot that holds nothing)."""
+        out = np.zeros((self.width,), np.int32)
+        if slot in self._held:
+            base, pages = self._held[slot]
+            out[:len(pages)] = pages
+            out[-1] = base
+        return out
+
+    def advance(self, slot: int, end: int) -> Tuple[np.ndarray, np.ndarray]:
+        """A prefill chunk that ends at position `end`: the slot's row as
+        the chunk finds it, and as it leaves it, holding the last `window -
+        1` positions before `end` (pages behind them are given back here,
+        before the launch that stops reading them: it reads `before`
+        first)."""
+        before = self.row(slot)
+        self.cover(slot, end - (self.window - 1), end)
+        return before, self.row(slot)
+
+    def extend_tables(self, tables: np.ndarray, rows) -> np.ndarray:
+        """A launch's block tables with this group's columns appended.
+        `rows`: (slot, start, end) of each live row, which feeds positions
+        [start, end): the slot is made to cover what those rows read and
+        write, [start - (window - 1), end). Idempotent (a retried launch
+        builds its batch again)."""
+        out = np.zeros((tables.shape[0], tables.shape[1] + self.width),
+                       np.int32)
+        out[:, :tables.shape[1]] = tables
+        for slot, start, end in rows:
+            self.cover(slot, start - (self.window - 1), end)
+            out[slot, tables.shape[1]:] = self.row(slot)
+            self.held_page_rows += self.held(slot)
+            self.whole_context_page_rows += -(-end // self.block_size)
+        return out
+
+    def check_no_leaks(self) -> bool:
+        return not self._held and len(self._free) == self.num_blocks - 1
+
+
 class KVCachePool:
     """The device-side page pool: per-layer (k, v) pools + the allocator.
 
@@ -1868,7 +2003,8 @@ class KVCachePool:
                  head_dim: Optional[int] = None, dtype=jnp.float32,
                  mesh=None, model_axis: str = "model",
                  kv_dtype: str = "fp32", page_layout=None,
-                 state_layout=None, state_slots: int = 0):
+                 state_layout=None, state_slots: int = 0,
+                 row_pages: bool = False, window=None):
         """`page_layout` is the runner's word on what a layer's page
         holds: a list of `(trailing shape, dtype)`, one per array, the
         same for every layer; each array is `[num_blocks, block_size,
@@ -1881,10 +2017,20 @@ class KVCachePool:
         RECURRENT STATE per sequence and no pages: `(layers, [(trailing
         shape, dtype), ...])`; each array is `[state_slots, *trailing]`,
         indexed by the decode slot the scheduler hands a request (the
-        engine asks for `max_batch_size + 1`: the last is scratch, which
-        no request holds). `num_layers` then counts the layers that page.
-        With states, `pools` (what the runner's steps take and return) is
-        the pair `(pages, states)`."""
+        engine asks for `max_batch_size`: a dead row of a decode batch
+        writes back what it read at its own row, so no slot is scratch).
+        `num_layers` then counts the layers that page. With states,
+        `pools` (what the runner's steps take and return) is the pair
+        `(pages, states)`.
+
+        `window`, `(layers, window length, span)`, is its word on a second
+        page GROUP (`WindowGroup`): that many layers keep only a
+        sequence's last `window` positions, in pages of this pool's layout
+        with their own table columns and free list, sized here for
+        `state_slots` sequences. `num_layers` and `num_blocks` are then
+        the "full" group's, whose pages grow with the context under the
+        allocator as ever; `pools` is the triple `(pages, states,
+        window pages)`. `row_pages`: see `page_arrays`."""
         self.num_layers = num_layers
         self.num_blocks = num_blocks
         self.block_size = block_size
@@ -1904,7 +2050,8 @@ class KVCachePool:
         self.page_layout = [(tuple(t), jnp.dtype(d)) for t, d in (
             page_layout or kv_pair_layout(n_kv_heads, head_dim, dtype))]
         self.page_arrays = page_arrays(block_size, self.page_layout,
-                                       kv_dtype, model_axis)
+                                       kv_dtype, model_axis,
+                                       row_pages=row_pages)
         # the head geometry of (k, v) pages, None for any other layout
         per_head = all(len(t) == 2 for t, _ in self.page_layout)
         self.n_kv_heads, self.head_dim = (
@@ -1951,18 +2098,40 @@ class KVCachePool:
                         tuple(jnp.zeros((self.state_slots,) + t, d)
                               for t, d in self.state_arrays)
                         for _ in range(self.state_layers)]
+            self.window: Optional[WindowGroup] = None
+            self.window_pools = []
+            if window is not None:
+                if mesh is not None or not self.state_layers:
+                    raise ValueError("a window group is built for one "
+                                     "device, beside state slots")
+                layers, length, span = window
+                self.window = WindowGroup(layers, length, block_size,
+                                          self.state_slots, span)
+                with _prof.always_span("window_pool.alloc",
+                                       num_blocks=self.window.num_blocks):
+                    self.window_pools = [
+                        tuple(jnp.zeros((self.window.num_blocks,) + shape, dt)
+                              for _, shape, dt, _ in self.page_arrays)
+                        for _ in range(self.window.layers)]
 
     @property
     def pools(self):
         """What the runner's steps take and return: the layers' page
-        arrays, or with recurrent state the pair (pages, states)."""
+        arrays, or with recurrent state the pair (pages, states), or with
+        a window group besides the triple (pages, states, window pages)."""
+        if self.window is not None:
+            return (self.page_pools, self.state_pools, self.window_pools)
         if self.state_layers:
             return (self.page_pools, self.state_pools)
         return self.page_pools
 
     @pools.setter
     def pools(self, value):
-        if self.state_layers:
+        if self.window is not None:
+            pages, states, ring = value
+            self.page_pools, self.state_pools, self.window_pools = (
+                list(pages), list(states), list(ring))
+        elif self.state_layers:
             pages, states = value
             self.page_pools, self.state_pools = list(pages), list(states)
         else:
@@ -1970,20 +2139,35 @@ class KVCachePool:
 
     @classmethod
     def for_runner(cls, runner, num_blocks: int, mesh=None,
-                   model_axis: str = "model",
-                   state_slots: int = 2) -> "KVCachePool":
+                   model_axis: str = "model", state_slots: int = 1,
+                   window_span: int = 1) -> "KVCachePool":
         """The pool a runner's steps read and write: the geometry is the
         runner's (the page layout it names, in its kv_dtype rung; where
         it names a state layout too, `state_slots` slots of it for the
-        layers that keep a state, and pages for the others only)."""
+        layers that keep a state, and pages for the others only). A
+        runner that names page GROUPS (`page_groups()`: `{"full": layers,
+        "window": (layers, window length)}`) gets `num_blocks` pages for
+        the layers that keep their whole context and a `WindowGroup` for
+        `state_slots` sequences that write `window_span` positions a
+        launch; one that names none gets one table for every paged layer,
+        as ever."""
         ask = getattr(runner, "state_layout", None)
         states = ask() if ask is not None else None
-        return cls(runner.num_layers - (states[0] if states else 0),
-                   num_blocks, runner.block_size,
+        ask = getattr(runner, "page_groups", None)
+        groups = ask() if ask is not None else None
+        if groups is None:
+            paged = runner.num_layers - (states[0] if states else 0)
+            window = None
+        else:
+            paged = groups["full"]
+            window = (*groups["window"], window_span)
+        return cls(paged, num_blocks, runner.block_size,
                    dtype=runner.dtype, mesh=mesh, model_axis=model_axis,
                    kv_dtype=getattr(runner, "kv_dtype", "fp32"),
                    page_layout=_page_layout_of(runner),
-                   state_layout=states, state_slots=state_slots)
+                   state_layout=states, state_slots=state_slots,
+                   row_pages=bool(getattr(runner, "ROW_PAGES", False)),
+                   window=window)
 
     # -------------------------------- per-request kv-dtype tags (ISSUE 15)
 
@@ -2146,6 +2330,14 @@ class KVCachePool:
         where every layer pages)."""
         return self.state_slots * self.state_layers * sum(
             int(np.prod(t)) * d.itemsize for t, d in self.state_arrays)
+
+    def window_bytes(self) -> int:
+        """Bytes of the window group's pages, every layer (0 without)."""
+        if self.window is None:
+            return 0
+        return self.window.num_blocks * self.window.layers * sum(
+            int(np.prod(shape)) * dt.itemsize
+            for _, shape, dt, _ in self.page_arrays)
 
     def per_shard_memory_bytes(self) -> int:
         """Pool bytes ONE model shard holds: total / tp (each shard

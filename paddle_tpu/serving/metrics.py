@@ -120,6 +120,9 @@ SUMMABLE_KEYS = (
     "latent_copy_groups", "latent_run_groups",
     "delta_decode_seq_steps", "delta_prefill_tokens",
     "delta_prefill_positions", "state_slot_resets",
+    "ssm_decode_seq_steps", "ssm_prefill_tokens", "cross_rows_skipped",
+    "window_pages_held", "window_pages_whole_context",
+    "window_pages_returned",
     "prefill_tokens", "prefill_chunks", "prefix_hit_tokens", "cow_copies",
     "prefix_cached_pages", "attn_kv_bytes_read", "attn_kv_bytes_gather",
     "tp_comm_bytes", "tp_comm_bytes_fp32",
@@ -228,6 +231,21 @@ class EngineMetrics:
         self.delta_prefill_positions = Counter("delta_prefill_positions")
         self.state_slot_resets = Counter("state_slot_resets")
         self.state_slots_live = Gauge("state_slots_live")
+        # a runner with a selective scan and page groups (Phi-4-mini-
+        # flash): live rows x scan layers a decode step advanced, real
+        # prompt tokens through the chunked scan, prompt rows that stopped
+        # before the cross-decoder (outputs of each step's program); and,
+        # mirrored from the pool's window group, the pages a layer of it
+        # held for the rows of every decode launch, the pages a cache of
+        # the whole context would have held for the same rows, and the
+        # pages it gave back: sums over launches, so a window's delta
+        # divides
+        self.ssm_decode_seq_steps = Counter("ssm_decode_seq_steps")
+        self.ssm_prefill_tokens = Counter("ssm_prefill_tokens")
+        self.cross_rows_skipped = Counter("cross_rows_skipped")
+        self.window_pages_held = Gauge("window_pages_held")
+        self.window_pages_whole_context = Gauge("window_pages_whole_context")
+        self.window_pages_returned = Gauge("window_pages_returned")
         # prefill_tokens counts tokens actually COMPUTED by prefill
         # chunks; prefix-cache hits skip the compute and land in
         # prefix_hit_tokens instead, so (computed + hit) = total context
@@ -427,6 +445,13 @@ class EngineMetrics:
             "delta_prefill_positions": self.delta_prefill_positions.value,
             "state_slot_resets": self.state_slot_resets.value,
             "state_slots_live": self.state_slots_live.value,
+            "ssm_decode_seq_steps": self.ssm_decode_seq_steps.value,
+            "ssm_prefill_tokens": self.ssm_prefill_tokens.value,
+            "cross_rows_skipped": self.cross_rows_skipped.value,
+            "window_pages_held": self.window_pages_held.value,
+            "window_pages_whole_context":
+                self.window_pages_whole_context.value,
+            "window_pages_returned": self.window_pages_returned.value,
             "prefill_tokens": self.prefill_tokens.value,
             "prefill_chunks": self.prefill_chunks.value,
             "prefix_hit_tokens": self.prefix_hit_tokens.value,

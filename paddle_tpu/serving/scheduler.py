@@ -659,6 +659,10 @@ class FCFSScheduler:
         req.finish_reason = reason
 
     def _release_slot(self, req: Request) -> None:
+        ring = getattr(self.pool, "window", None)
+        if ring is not None:
+            # the window group's pages go back with the slot that held them
+            ring.release(req.slot)
         self._free_slots.append(req.slot)
         self._free_slots.sort()            # lowest slot reused first
         req.slot = None

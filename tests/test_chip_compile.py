@@ -123,6 +123,43 @@ def _ragged_hybrid(B, T, dtype=jnp.bfloat16):
                    table=128)
 
 
+def _ragged_phi4(B, table, pages, bounded, dtype=jnp.bfloat16):
+    """phi-4-mini-flash.reason-12k's attention: 40 query rows of 128 lanes
+    (a head padded to its pair's width) over ROW pools of 10 key/value
+    pairs, 16 x 10 = 160 rows a page; the whole-context layer's table is
+    1024 wide, a window layer's 33 with a lower bound a sequence."""
+    from paddle_tpu.ops.pallas.ragged_paged_attention import \
+        ragged_paged_attention
+
+    def fn(q, kp, vp, table, start, qlen, *lower):
+        return ragged_paged_attention(q, kp, vp, table, start, qlen,
+                                      scale=0.125, interpret=False,
+                                      lower=lower[0] if lower else None,
+                                      kv_heads=10)
+
+    pool = ((pages, PAGE * 10, 128), dtype)
+    vec = ((B,), jnp.int32)
+    return fn, [((B, 1, 40, 128), jnp.bfloat16), pool, pool,
+                ((B, table), jnp.int32), vec, vec] + [vec] * bounded
+
+
+def _scan_decode(B, n=16, c=5120):
+    """The selective scan's decode update at the published widths: B
+    sequences against a layer's pool of B + 1 float32 states of 16 x 5120,
+    aliased in place."""
+    from paddle_tpu.ops.pallas.selective_scan_decode import \
+        selective_scan_decode
+
+    def fn(state, x, dt, A, Bm, C, live):
+        return selective_scan_decode(state, x, dt, A, Bm, C, live,
+                                     interpret=False)
+
+    f32 = jnp.float32
+    return fn, [((B + 1, n, c), f32), ((B, c), f32), ((B, c), f32),
+                ((n, c), f32), ((B, n), f32), ((B, n), f32),
+                ((B,), jnp.bool_)]
+
+
 # the training cells' attention: gpt2-124m.train's batch of 28, and
 # gpt3-1.3b.train-4chip's 8 x 16 heads of 128 (4 x 8 a shard of dp 2 x tp 2)
 GPT2_CELL = (28, SEQ, N_HEADS, HEAD_DIM)
@@ -239,6 +276,19 @@ CASES = {
     "ragged-hybrid-32x128-span-128": lambda mp: _ragged_hybrid(1, 128),
     "ragged-hybrid-32x128-fp8-decode-b64": lambda mp: _ragged_hybrid(
         64, 1, jnp.float8_e4m3fn),
+    # the Phi-4-mini-flash cell: row pools of 10 pairs, the shared cache's
+    # wide table, a window's ring with its lower bound, fp8 pages (the
+    # control), the oracle's batch of 1; the scan's decode update
+    "ragged-phi4-shared-b48": lambda mp: _ragged_phi4(48, 1024, 49216,
+                                                      False),
+    "ragged-phi4-window-b48": lambda mp: _ragged_phi4(48, 33, 1585, True),
+    "ragged-phi4-window-b1": lambda mp: _ragged_phi4(1, 33, 1585, True),
+    "ragged-phi4-shared-fp8-b48": lambda mp: _ragged_phi4(
+        48, 1024, 49216, False, jnp.float8_e4m3fn),
+    "ragged-phi4-window-fp8-b48": lambda mp: _ragged_phi4(
+        48, 33, 1585, True, jnp.float8_e4m3fn),
+    "scan-decode-b48": lambda mp: _scan_decode(48),
+    "scan-decode-b1": lambda mp: _scan_decode(1),
     "flash-fp32-fwd": lambda mp: _flash(jnp.float32, False),
     "flash-bf16-fwd": lambda mp: _flash(jnp.bfloat16, False),
     "flash-fp32-fwd-bwd": lambda mp: _flash(jnp.float32, True),

@@ -395,11 +395,12 @@ def test_pool_bytes_equal_the_configurations_arithmetic(toy):
     # pages for the two full layers only: K and V of 4 heads x 16
     assert pool.num_layers == 2 and len(pool.page_pools) == 2
     assert pool.memory_bytes() == 20 * 2 * 2 * 8 * 4 * 16 * 4
-    # max_batch_size + 1 slots of every linear layer's state and window
-    assert pool.state_slots == 6 and len(pool.state_pools) == 6
+    # max_batch_size slots of every linear layer's state and window (no
+    # scratch slot: a dead row writes back what it read at its own row)
+    assert pool.state_slots == 5 and len(pool.state_pools) == 6
     per_seq = c.state_bytes_per_sequence(4)
     assert per_seq == 6 * (4 * 8 * 16 * 4 + 3 * (32 + 32 + 64) * 4)
-    assert pool.state_bytes() == 6 * per_seq
+    assert pool.state_bytes() == 5 * per_seq
     assert pool.state_bytes() == sum(
         a.nbytes for layer in pool.state_pools for a in layer)
     pages, states = pool.pools
@@ -422,7 +423,7 @@ def test_state_pool_alloc_is_a_span_inside_kv_pool_alloc(toy):
     by = {s[name]: s for s in profiler.spans()}
     assert by["state_pool.alloc"][parent] == by["kv_pool.alloc"][sid]
     assert by["kv_pool.alloc"][parent] == by["engine.build"][sid]
-    assert by["state_pool.alloc"][7]["slots"] == 6
+    assert by["state_pool.alloc"][7]["slots"] == 5        # max_batch_size
     # nothing a step records while no session is live
     eng.add_request([1, 2, 3], SamplingParams(max_tokens=3))
     eng.run()
