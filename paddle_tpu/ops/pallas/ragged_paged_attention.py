@@ -42,6 +42,17 @@ ops/pallas/flash_attention.py, with the page walk inside the kernel):
     tiles): q heads are grouped by their KV head OUTSIDE the kernel
     ([B, T, n_q, d] -> [B, n_kv, T*n_rep, d]) and the matmuls batch
     over n_kv and contract d with no head replication;
+  * a block costs what it needs (few query rows): a block of a walk is
+    INTERIOR when a copy filled every slot of it and every live row
+    sees every key in it: its last key stands at or before row 0
+    (`start_pos`) and, under a lower bound, its first key at or after
+    the bound. Such a block needs no position compared: a row keeps its
+    own head's columns (one additive mask, made once a launch) and
+    nothing else is masked, V included. The EDGE blocks, the first
+    under a bound and the last of a walk (the last ones of a span of
+    several rows: its causal triangle lies in them), compare positions
+    and mask V, whose slots no copy filled hold what was there before
+    (0 * NaN is NaN). The walk is up to three ranges of one fold;
   * fp32 online softmax with running (m, l, acc) in VMEM scratch across
     the walk — the attention matrix never exists in HBM, and fully
     masked rows are guarded to exact zero output.
@@ -164,6 +175,20 @@ def pages_per_block(T: int, n_q: int, q_itemsize: int, page_size: int,
     return ppb
 
 
+def few_rows_block_pages(T: int, n_q: int, q_itemsize: int, page_size: int,
+                         n_kv: int, d: int, kv_itemsize: int,
+                         row_pools: bool = False) -> int:
+    """pages_per_block of a launch whose tiles take the few-rows fold
+    (the heads as the kernel pads them), 0 of one whose tiles do not:
+    what a caller needs to count that fold's blocks."""
+    heads = n_q if row_pools else \
+        n_q // n_kv * _page_copy_heads(n_kv, kv_itemsize)
+    if not _flat(T, heads):
+        return 0
+    return pages_per_block(T, n_q, q_itemsize, page_size, n_kv, d,
+                           kv_itemsize, row_pools=row_pools)
+
+
 def _tile_pages(start, qlen, t0, tq: int, page_size: int, table_width: int):
     """Pages the walk of one tile (span rows [t0, t0 + tq) of a span
     standing at `start` with `qlen` live rows) copies: those up to the
@@ -172,6 +197,27 @@ def _tile_pages(start, qlen, t0, tq: int, page_size: int, table_width: int):
     last_pos = start + jnp.minimum(qlen, t0 + tq) - 1
     return jnp.where(qlen > t0,
                      jnp.minimum(last_pos // page_size + 1, table_width), 0)
+
+
+def _lean_range(start, n_pages, lower, block0, end, keys: int, ppb: int,
+                xp=jnp):
+    """[first, last) of a few-rows walk's blocks [block0, end) that are
+    INTERIOR: every slot copied, every key seen by every live row. The
+    block of the lower bound is one only with the bound on its first
+    key; a block ends the range when its last key stands past row 0
+    (`start`) or its last page past the walk's. The kernel's three
+    ranges and `ragged_block_counts` (xp=np) both come from here."""
+    first = xp.minimum(xp.where(lower > block0 * keys, block0 + 1, block0),
+                       end)
+    last = xp.minimum((start + 1) // keys, n_pages // ppb)
+    return first, xp.clip(last, first, end)
+
+
+def _stacked(rows: int) -> bool:
+    """The three bf16 terms of p go through the MXU as ONE product,
+    stacked as rows: where they fit its rows, and each term is whole
+    float32 tiles (8 rows) so the stack is made without a shuffle."""
+    return 3 * rows <= FLAT_ROWS and rows % 8 == 0
 
 
 def _ragged_kernel(table_ref, start_ref, qlen_ref, *refs, page_size: int,
@@ -204,6 +250,8 @@ def _ragged_kernel(table_ref, start_ref, qlen_ref, *refs, page_size: int,
         lower_ref, *refs = refs
     if quantized:
         kscale_ref, vscale_ref, *refs = refs
+    if flat:
+        *refs, own_ref = refs
     (q_ref, k_hbm, v_hbm, o_ref, kbuf, vbuf, sem, m_ref, l_ref, acc_ref,
      slot_ref) = refs
     b, i = pl.program_id(0), pl.program_id(1)
@@ -262,6 +310,14 @@ def _ragged_kernel(table_ref, start_ref, qlen_ref, *refs, page_size: int,
     def _first_step():
         slot_ref[0] = 0
         copies(b, block0, 0, n_pages)
+        if flat:
+            # a row keeps the columns of its own kv-head: 0 there and
+            # NEG_INF elsewhere, the same for every block of every
+            # sequence, so it is made (with its divisions) once a launch
+            row = jax.lax.broadcasted_iota(jnp.int32, own_ref.shape, 0)
+            col = jax.lax.broadcasted_iota(jnp.int32, own_ref.shape, 1)
+            own_ref[...] = jnp.where(
+                col % n_kv == row % n_q // n_rep, 0.0, NEG_INF)
 
     slot0 = slot_ref[0]                # buffer of this tile's block 0
     m_ref[...] = jnp.full(m_ref.shape, NEG_INF, jnp.float32)
@@ -277,26 +333,35 @@ def _ragged_kernel(table_ref, start_ref, qlen_ref, *refs, page_size: int,
         x = x.astype(jnp.float32).reshape(ppb, page_size, n_kv, d)
         return (x * sc[:, None, :, None]).reshape(keys, n_kv, d)
 
-    def fold(block, slot):
+    def fold(block, slot, edge: bool):
+        """Fold block `block` of the walk, in buffer `slot`, into (m, l,
+        acc). edge (static): the block may hold a key that some live row
+        does not see, or a slot that no copy filled, so key positions are
+        compared with each row's and V is masked. An INTERIOR block
+        (edge=False, few-row tiles only; `_lean_range` says which) was
+        copied whole and lies at or before row 0's position and at or
+        after the bound: every live row sees all of it, so the fold
+        compares no position and masks no V. Rows past q_len then fold
+        what they do not see; the output cuts them to zero."""
         key0 = block * keys
-        if kv_heads is None:
-            k = kbuf[slot].reshape(keys, n_kv, d)
-            v = vbuf[slot].reshape(keys, n_kv, d)
-            v_pos = key0 + jax.lax.broadcasted_iota(jnp.int32, v.shape, 0)
-        else:
-            k = kbuf[slot].reshape(keys * n_kv, d)
-            v = vbuf[slot].reshape(keys * n_kv, d)
-            v_pos = key0 + jax.lax.broadcasted_iota(
-                jnp.int32, v.shape, 0) // n_kv
+        # a block as (key, kv-head, d), or as its rows from row pools
+        as_held = (keys, n_kv, d) if kv_heads is None else (keys * n_kv, d)
+        k, v = kbuf[slot].reshape(as_held), vbuf[slot].reshape(as_held)
         if quantized:
             k = dequantized(k, kscale_ref, block * ppb)
             v = dequantized(v, vscale_ref, block * ppb)
-        # the slots of a partial block that no copy filled hold what was
-        # there before, and 0 * NaN is NaN: V is masked, not only scores
-        v_live = v_pos <= last_pos
-        if bounded:
-            v_live &= v_pos >= lower
-        v = jnp.where(v_live, v, jnp.zeros_like(v))
+        if edge:
+            # the slots of a partial block that no copy filled hold what
+            # was there before, and 0 * NaN is NaN: V is masked, not only
+            # scores. Only an edge block has such slots. Rows are
+            # key-major (a key's heads, in row pools): the live ones are
+            # a range, no row is divided
+            per_key = 1 if kv_heads is None else n_kv
+            v_row = jax.lax.broadcasted_iota(jnp.int32, v.shape, 0)
+            v_live = v_row < (last_pos - key0 + 1) * per_key
+            if bounded:
+                v_live &= v_row >= (lower - key0) * per_key
+            v = jnp.where(v_live, v, jnp.zeros_like(v))
         if v.dtype != jnp.bfloat16:
             v = v.astype(jnp.float32)
         if flat:
@@ -310,11 +375,22 @@ def _ragged_kernel(table_ref, start_ref, qlen_ref, *refs, page_size: int,
                 q, k = q.astype(jnp.float32), k.astype(jnp.float32)
             s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                     preferred_element_type=jnp.float32)
-            row = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-            col = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-            t_idx = row // n_q                         # one tile: t0 is 0
-            k_pos = key0 + col // n_kv
-            own = col % n_kv == row % n_q // n_rep
+            s = s * scale + own_ref[...]
+            if edge:
+                # one compare over the tile: a column (key-major, so a
+                # key's columns are a range; BIG before the bound) against
+                # the end of what its row sees (none for a row past q_len);
+                # one tile: t0 is 0
+                col = jax.lax.broadcasted_iota(jnp.int32, (1, s.shape[1]), 1)
+                if bounded:
+                    col = jnp.where(col >= (lower - key0) * n_kv, col,
+                                    jnp.iinfo(jnp.int32).max)
+                t_idx = jax.lax.broadcasted_iota(
+                    jnp.int32, (s.shape[0], 1), 0) // n_q
+                sees = jnp.where(t_idx < qlen,
+                                 (start + t_idx - key0 + 1) * n_kv, 0)
+                s = jnp.where(jnp.broadcast_to(col, s.shape)
+                              < jnp.broadcast_to(sees, s.shape), s, NEG_INF)
         else:
             # scores[n_kv, G, keys]: batch the KV-head dim, contract d —
             # each KV head serves its n_rep grouped query rows with no
@@ -328,31 +404,50 @@ def _ragged_kernel(table_ref, start_ref, qlen_ref, *refs, page_size: int,
             t_idx = t0 + jax.lax.broadcasted_iota(
                 jnp.int32, s.shape, 1) // n_rep
             k_pos = key0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
-            own = True
-        # rows t >= qlen are padding
-        seen = own & (k_pos <= start + t_idx) & (t_idx < qlen)
-        if bounded:
-            seen &= k_pos >= lower
-        s = jnp.where(seen, s * scale, NEG_INF)
+            # rows t >= qlen are padding
+            seen = (k_pos <= start + t_idx) & (t_idx < qlen)
+            if bounded:
+                seen &= k_pos >= lower
+            s = jnp.where(seen, s * scale, NEG_INF)
         m = m_ref[...]
         new_m = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
-        # masked-row guard: where every key so far is hard-masked, new_m
-        # is still NEG_INF and exp(s - new_m) would be 1 — force 0 so the
-        # row's l stays 0 and its output is exactly zero
-        p = jnp.where(s <= NEG_INF * 0.5, 0.0, jnp.exp(s - new_m))
+        p = jnp.exp(s - new_m)
+        if edge:
+            # masked-row guard: where every key so far is hard-masked,
+            # new_m is still NEG_INF and exp(s - new_m) would be 1 — force
+            # 0 so the row's l stays 0 and its output is exactly zero. (In
+            # an interior block every live row has a key, and a masked
+            # column's exp is 0 by itself)
+            p = jnp.where(s <= NEG_INF * 0.5, 0.0, p)
         corr = jnp.exp(m - new_m)
         m_ref[...] = new_m
         l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=-1, keepdims=True)
         if flat:
+            def p_v(t):
+                return jax.lax.dot_general(
+                    t.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32)
+
             # p stays float32: against bf16 pages it goes through the
             # MXU as three bf16 terms that sum to it, not rounded to one
-            terms = [p]
-            if v.dtype == jnp.bfloat16:
+            if v.dtype != jnp.bfloat16:
+                pv = p_v(p)
+            else:
+                rows = p.shape[0]
                 lo = p - p.astype(v.dtype).astype(jnp.float32)
                 terms = [p, lo, lo - lo.astype(v.dtype).astype(jnp.float32)]
-            pv = sum(jax.lax.dot_general(
-                t.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32) for t in terms)
+                if _stacked(rows):
+                    # ONE product of the terms stacked as rows: with few
+                    # rows a product costs what loading V's tiles into the
+                    # MXU costs, and they are loaded once. The same
+                    # products and the same sum of three; the chip adds a
+                    # taller product's pieces in another order, which a
+                    # bf16 output shows in its last bit here and there
+                    pv3 = p_v(jnp.concatenate(terms, axis=0))
+                    terms = [pv3[n * rows:(n + 1) * rows] for n in range(3)]
+                else:
+                    terms = [p_v(t) for t in terms]
+                pv = terms[0] + terms[1] + terms[2]
         else:
             pv = jax.lax.dot_general(
                 p, v.astype(jnp.float32), (((2,), (0,)), ((0,), (1,))),
@@ -361,27 +456,42 @@ def _ragged_kernel(table_ref, start_ref, qlen_ref, *refs, page_size: int,
 
     next_block0 = first_page(nb) // ppb
 
-    def walk(block, carry):
-        slot = (slot0 + block - block0) % 2
-        # in flight while this block is folded: the tile's next block,
-        # or after its last the first block of the next grid step
-        more = block + 1 < n_blocks
-        copies(jnp.where(more, b, nb),
-               jnp.where(more, block + 1, next_block0),
-               1 - slot, jnp.where(more, n_pages, next_pages))
-        copies(b, block, slot, n_pages, wait=True)
-        fold(block, slot)
-        return carry
+    def walk(edge: bool):
+        def step(block, carry):
+            slot = (slot0 + block - block0) % 2
+            # in flight while this block is folded: the tile's next block,
+            # or after its last the first block of the next grid step
+            more = block + 1 < n_blocks
+            copies(jnp.where(more, b, nb),
+                   jnp.where(more, block + 1, next_block0),
+                   1 - slot, jnp.where(more, n_pages, next_pages))
+            copies(b, block, slot, n_pages, wait=True)
+            fold(block, slot, edge)
+            return carry
+        return step
 
-    jax.lax.fori_loop(block0, block0 + n_walk, walk, 0)
+    # the walk in up to three ranges: the bound's block, the interior
+    # blocks, the last block(s). Prefill tiles fold every block in full
+    end = block0 + n_walk
+    first, last = _lean_range(start, n_pages, lower, block0, end, keys,
+                              ppb) if flat else (end, end)
+    if bounded or not flat:
+        jax.lax.fori_loop(block0, first, walk(True), 0)
+    if flat:
+        jax.lax.fori_loop(first, last, walk(False), 0)
+        jax.lax.fori_loop(last, end, walk(True), 0)
 
     @pl.when(n_walk == 0)
     def _dead_step():                  # nothing to fold: hand on the start
         copies(nb, next_block0, slot0, next_pages)
 
     slot_ref[0] = (slot0 + n_walk) % 2
-    o_ref[0] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
-                ).astype(o_ref.dtype)
+    out = acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
+    if flat and tq > 1:
+        # rows past q_len: an interior block folded them unmasked
+        t_idx = jax.lax.broadcasted_iota(jnp.int32, out.shape, 0) // n_q
+        out = jnp.where(t_idx < qlen, out, 0.0)
+    o_ref[0] = out.astype(o_ref.dtype)
 
 
 def ragged_paged_attention(q, k_pool, v_pool, block_table, start_pos, q_len,
@@ -512,7 +622,8 @@ def _ragged_call(q, k_pool, v_pool, block_table, start_pos, q_len, k_scale,
             pltpu.VMEM(stats, jnp.float32),
             pltpu.VMEM(acc, jnp.float32),
             pltpu.SMEM((1,), jnp.int32),           # slot of the next block 0
-        ],
+        ] + ([pltpu.VMEM((rows, ppb * page_size * n_kv), jnp.float32)]
+             if flat else []),                     # a row's own-head mask
     )
     scalars = (block_table.astype(jnp.int32), start_arr, qlen_arr)
     if bounded:
@@ -614,3 +725,22 @@ def attention_page_reads(start_pos, q_len, page_size: int):
     qlen = np.asarray(q_len, np.int64).reshape(-1)
     last = np.maximum(start + qlen - 1, 0)
     return np.where(qlen > 0, last // page_size + 1, 0)
+
+
+def ragged_block_counts(start_pos, q_len, page_size: int, ppb: int,
+                        lower=None):
+    """(blocks, edge blocks) the few-rows walk of a launch folds, per
+    sequence: all the blocks of `ppb` pages from the lower bound's to
+    the last visible key's, and those of them folded in full (positions
+    compared, V masked) because they are not interior. Host arithmetic
+    on the launch's own operands, beside attention_page_reads; the
+    kernel splits its walk by the same `_lean_range`."""
+    start = np.asarray(start_pos, np.int64).reshape(-1)
+    lower = np.zeros_like(start) if lower is None else \
+        np.asarray(lower, np.int64).reshape(-1)
+    n_pages = attention_page_reads(start, q_len, page_size)
+    block0 = lower // page_size // ppb
+    end = np.maximum(-(-n_pages // ppb), block0)
+    first, last = _lean_range(start, n_pages, lower, block0, end,
+                              ppb * page_size, ppb, xp=np)
+    return end - block0, end - block0 - (last - first)

@@ -1091,10 +1091,13 @@ class ServingEngine:
     def _read_gauges(self) -> tuple:
         """What a step's gauges mirror, as the engine's state has it
         now (`_write_gauges` takes it in this order): the runner's
-        host-side byte counters, then scheduler, pool and host tier."""
+        host-side byte and block counters, then scheduler, pool and host
+        tier."""
         r, a, tier = self.runner, self.pool.allocator, self.pool.host_tier
         return (getattr(r, "attn_kv_bytes_read", None),
                 getattr(r, "attn_kv_bytes_gather", None),
+                getattr(r, "ragged_blocks", None),
+                getattr(r, "ragged_edge_blocks", None),
                 getattr(r, "tp_comm_bytes", None),
                 getattr(r, "tp_comm_bytes_fp32", None),
                 getattr(r, "tp_gather_bytes", None),
@@ -1106,13 +1109,16 @@ class ServingEngine:
                 tier.bytes_used if tier is not None else None,
                 tier.used_count if tier is not None else None)
 
-    def _write_gauges(self, read, gathered, comm, comm32, gather, gather32,
-                      queued, running, used, utilization, cached,
-                      tier_bytes, tier_used) -> None:
+    def _write_gauges(self, read, gathered, blocks, edge_blocks, comm, comm32,
+                      gather, gather32, queued, running, used, utilization,
+                      cached, tier_bytes, tier_used) -> None:
         m = self.metrics
         if read is not None:
             m.attn_kv_bytes_read.set(read)
             m.attn_kv_bytes_gather.set(gathered)
+        if blocks is not None:
+            m.ragged_blocks.set(blocks)
+            m.ragged_edge_blocks.set(edge_blocks)
         if comm is not None:
             # quantized-collective accounting: wire bytes
             # the row-parallel allreduces moved per shard (scale bytes

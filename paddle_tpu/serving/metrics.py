@@ -125,6 +125,7 @@ SUMMABLE_KEYS = (
     "window_pages_returned",
     "prefill_tokens", "prefill_chunks", "prefix_hit_tokens", "cow_copies",
     "prefix_cached_pages", "attn_kv_bytes_read", "attn_kv_bytes_gather",
+    "ragged_blocks", "ragged_edge_blocks",
     "tp_comm_bytes", "tp_comm_bytes_fp32",
     "tp_gather_bytes", "tp_gather_bytes_fp32",
     "spec_proposed_tokens", "spec_accepted_tokens", "spec_rollback_pages",
@@ -341,6 +342,12 @@ class EngineMetrics:
         # CPU-countable form of the ragged kernel's bandwidth win
         self.attn_kv_bytes_read = Gauge("attn_kv_bytes_read")
         self.attn_kv_bytes_gather = Gauge("attn_kv_bytes_gather")
+        # blocks of pages the ragged kernel's few-rows walks folded (one
+        # layer's walk a launch) and those of them folded in full, as a
+        # walk's edge blocks are: 1 - edge / all is how often the lean
+        # fold engages; mirrored from the runner like the bytes
+        self.ragged_blocks = Gauge("ragged_blocks")
+        self.ragged_edge_blocks = Gauge("ragged_edge_blocks")
         # quantized collectives (ISSUE 15), mirrored from the runner's
         # host-side comm accounting each step: wire bytes the
         # row-parallel allreduces moved PER SHARD at the configured
@@ -459,6 +466,8 @@ class EngineMetrics:
             "prefix_cached_pages": self.prefix_cached_pages.value,
             "attn_kv_bytes_read": self.attn_kv_bytes_read.value,
             "attn_kv_bytes_gather": self.attn_kv_bytes_gather.value,
+            "ragged_blocks": self.ragged_blocks.value,
+            "ragged_edge_blocks": self.ragged_edge_blocks.value,
             "tp_comm_bytes": self.tp_comm_bytes.value,
             "tp_comm_bytes_fp32": self.tp_comm_bytes_fp32.value,
             "tp_comm_bytes_reduction_x":

@@ -485,6 +485,13 @@ class PagedModelRunner:
         # its own kv-head slice, so sharded = single-device / tp)
         self.attn_kv_bytes_read = 0.0
         self.attn_kv_bytes_gather = 0.0
+        # blocks of pages the ragged kernel's few-rows walks folded (one
+        # layer's walk a launch: every layer walks the same), and those
+        # of them folded in full, as a walk's edge blocks are; counted
+        # on the host like the bytes (`ragged_block_counts`)
+        self.ragged_blocks = 0
+        self.ragged_edge_blocks = 0
+        self._fold_pages = {}       # span bucket -> _fold_block_pages
         # where a single-pass step's counts go (`COUNTS`): the engine
         # sets this to collect them for its drain; None drops them
         self.on_step_counts = None
@@ -1002,7 +1009,37 @@ class PagedModelRunner:
         # path, the attend path never reads it) — fp32-width reads
         return 2 * self.num_layers * data * np.dtype(self.dtype).itemsize
 
-    def _account_attn(self, impl: str, starts, q_lens, table_width: int):
+    def _fold_block_pages(self, span: int) -> int:
+        """Pages in one block of the ragged kernel's walk under a launch
+        of `span` padded rows a sequence, PER SHARD; 0 where its tiles do
+        not take the few-rows fold, whose blocks are what is counted."""
+        from paddle_tpu.ops.pallas.ragged_paged_attention import \
+            few_rows_block_pages
+
+        itemsize = np.dtype(self.dtype).itemsize
+        return few_rows_block_pages(
+            span, self.n_heads // self.tp_size, itemsize, self.block_size,
+            self.n_kv_heads // self.tp_size, self.head_dim,
+            1 if self.kv_dtype in ("int8", "fp8") else itemsize,
+            row_pools=self.ROW_PAGES)
+
+    def _account_blocks(self, starts, q_lens, span: int, lower=None):
+        """Bump the few-rows fold's block counters for one layer's walk
+        of a ragged launch (`ragged_block_counts`)."""
+        from paddle_tpu.ops.pallas.ragged_paged_attention import \
+            ragged_block_counts
+
+        ppb = self._fold_pages.get(span)
+        if ppb is None:
+            ppb = self._fold_pages[span] = self._fold_block_pages(span)
+        if ppb:
+            blocks, edges = ragged_block_counts(starts, q_lens,
+                                                self.block_size, ppb, lower)
+            self.ragged_blocks += int(blocks.sum())
+            self.ragged_edge_blocks += int(edges.sum())
+
+    def _account_attn(self, impl: str, starts, q_lens, table_width: int,
+                      span: int = 1):
         """Bump the instrumented-pool counters for one step call: the
         kernels read only each span's live pages (the in-kernel walk);
         the gather path reads every table entry of every slot. Counted
@@ -1013,7 +1050,9 @@ class PagedModelRunner:
         single-device bytes / tp (the ISSUE 7 acceptance number). On an
         int8 pool (ISSUE 9) the per-page bytes are the quantized bytes
         + scale bytes, so fp32-vs-int8 arms of the same workload expose
-        the real bandwidth reduction."""
+        the real bandwidth reduction. `span`: the call's padded rows a
+        sequence, which says whether its tiles take the kernel's few-rows
+        fold, whose blocks are counted too."""
         from paddle_tpu.ops.pallas.ragged_paged_attention import \
             attention_page_reads
 
@@ -1022,10 +1061,16 @@ class PagedModelRunner:
         if impl == "ragged":
             pages = int(attention_page_reads(starts, q_lens,
                                              self.block_size).sum())
+            self._account_blocks(starts, q_lens, span)
         else:
             pages = gather_pages
         self.attn_kv_bytes_read += pages * per_page
         self.attn_kv_bytes_gather += gather_pages * per_page
+
+    def _account_decode(self, pos, tables) -> None:
+        """One decode step's attention, a row a sequence at `pos`."""
+        self._account_attn(self._attn_impl_for(1), pos, np.ones_like(pos),
+                           tables.shape[1])
 
     def _account_comm(self, rows: int, steps: int = 1) -> None:
         """Bump the instrumented comm counters for one step call
@@ -1059,6 +1104,7 @@ class PagedModelRunner:
     def reset_attn_counters(self) -> None:
         self.attn_kv_bytes_read = 0.0
         self.attn_kv_bytes_gather = 0.0
+        self.ragged_blocks = self.ragged_edge_blocks = 0
         self.tp_comm_bytes = 0.0
         self.tp_comm_bytes_fp32 = 0.0
         self.tp_gather_bytes = 0.0
@@ -1522,7 +1568,7 @@ class PagedModelRunner:
             def account():
                 self._account_attn(self._attn_impl_for(tb),
                                    np.asarray([start_pos]), np.asarray([t]),
-                                   len(table_row))
+                                   len(table_row), span=tb)
                 self._account_comm(tb)
 
             return self._emit(self._dispatch(
@@ -1542,9 +1588,7 @@ class PagedModelRunner:
                     np.asarray(tables, np.int32), pos_np)
 
             def account():
-                self._account_attn(self._attn_impl_for(1), pos_np,
-                                   np.ones_like(pos_np),
-                                   np.asarray(tables).shape[1])
+                self._account_decode(pos_np, np.asarray(tables))
                 self._account_comm(pos_np.shape[0])
 
             return self._emit(self._dispatch(
@@ -1582,14 +1626,12 @@ class PagedModelRunner:
             pos_np = np.asarray(pos, np.int32)
 
             def account():
-                impl = self._attn_impl_for(1)
-                width = np.asarray(tables).shape[1]
+                tabs_np = np.asarray(tables)
                 for t in range(num_steps):  # inner step t attends at pos + t
                     # host-side byte analytics; early-stopped rows may
                     # freeze earlier, so this upper-bounds the extended
                     # horizon's reads
-                    self._account_attn(impl, pos_np + t,
-                                       np.ones_like(pos_np), width)
+                    self._account_decode(pos_np + t, tabs_np)
                 self._account_comm(pos_np.shape[0], steps=num_steps)
 
             B = pos_np.shape[0]
@@ -1680,7 +1722,7 @@ class PagedModelRunner:
                 spans = np.full((B,), K + 1, np.int32)
                 for t in range(num_steps):  # upper-bounds the per-step reads
                     self._account_attn(impl, pos_np + t * (K + 1), spans,
-                                       width)
+                                       width, span=K + 1)
                 self._account_comm(B * (K + 1), steps=num_steps)
 
             return self._dispatch(
@@ -1712,7 +1754,7 @@ class PagedModelRunner:
 
             def account():
                 self._account_attn(self._attn_impl_for(T), start_pos, q_lens,
-                                   np.asarray(tables).shape[1])
+                                   np.asarray(tables).shape[1], span=T)
                 self._account_comm(B * T)
 
             return self._emit(self._dispatch(
@@ -2057,6 +2099,9 @@ class DeepseekV3Runner(PagedModelRunner):
     def _kv_page_bytes(self) -> int:
         return (self.num_layers * self.block_size * self.page_lanes
                 * np.dtype(self.dtype).itemsize)
+
+    def _fold_block_pages(self, span: int) -> int:
+        return 0        # the latent kernel's walk, not the ragged one's
 
     def _w(self, params, name):
         """A named matrix as its floating self (dequantized where
@@ -2534,6 +2579,17 @@ class Phi4FlashRunner(PagedModelRunner):
         readers = self.kinds.count("full") + self.kinds.count("cross")
         return (2 * readers * self.block_size * self.n_kv_heads
                 * self.head_dim * self._kv_itemsize())
+
+    def _account_decode(self, pos, tables) -> None:
+        """The full group's walk as any runner's, then the window
+        group's: the ring's own positions from its base (the table's last
+        column), bounded where the window begins, as `_forward` has it."""
+        super()._account_decode(pos, tables)
+        if self._attn_impl_for(1) == "ragged":
+            rel = pos - tables[:, -1] * self.block_size
+            self._account_blocks(
+                rel, np.ones_like(pos), 1,
+                np.maximum(rel - (self.cfg.sliding_window - 1), 0))
 
     def _scan_kernel(self) -> bool:
         return self.attn_impl == "ragged" or (

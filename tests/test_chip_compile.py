@@ -257,6 +257,10 @@ CASES = {
     # the decode cell: 32 slots a step, its three prefill buckets' ends
     "ragged-1p3b-decode-b32": lambda mp: _ragged_1p3b(32, 1),
     "ragged-1p3b-prefill-1024": lambda mp: _ragged_1p3b(1, 1024),
+    # few-row spans (speculative verify): 32 rows stack the three terms of
+    # P V into one product of 96, 128 rows keep three products
+    "ragged-1p3b-verify-span-2-b32": lambda mp: _ragged_1p3b(32, 2),
+    "ragged-1p3b-verify-span-8-b32": lambda mp: _ragged_1p3b(32, 8),
     "ragged-1p3b-prefill-256": lambda mp: _ragged_1p3b(1, 256),
     # grouped heads at 32/8 x 128 (ROADMAP R3/R4's shape)
     "ragged-gqa-32-8x128-decode-b32": lambda mp: _ragged_1p3b(32, 1, 32, 8),

@@ -120,8 +120,11 @@ class Calls:
 # what the next dispatch reads, and the spans that hold it
 NEEDED = {"engine.commit", "engine.drain", "engine.step", "engine.plan",
           "engine.build_batch", "runner.launch", "runner.stage"}
+# the last with the ragged kernel (interpret mode here), whose launches
+# count the few-rows fold's blocks beside their bytes
 LOOPS = {"default": {}, "pipelined": {"pipelined": True},
-         "horizon": {"decode_horizon": 4}}
+         "horizon": {"decode_horizon": 4},
+         "ragged-kernel": {"attn_impl": "ragged"}}
 
 
 class Counts:
@@ -171,6 +174,14 @@ def test_between_tokens_and_dispatch_only_what_the_dispatch_reads(
     counting(eng, monkeypatch, log)
     calls = Calls(monkeypatch)
     to_host = engine_mod._to_host
+    block_counts = []                           # when each was taken
+    count_blocks = eng.runner._account_blocks
+
+    def counted_blocks(*a, **k):
+        block_counts.append(prof.stamp())
+        count_blocks(*a, **k)
+
+    monkeypatch.setattr(eng.runner, "_account_blocks", counted_blocks)
 
     def logged(x):
         log.append("to_host")
@@ -233,6 +244,12 @@ def test_between_tokens_and_dispatch_only_what_the_dispatch_reads(
             assert mine, (name, s[STEP])
     assert all(by_id[s[PARENT]][NAME] in ("engine.step", "request.prefill")
                for s in spans if s[NAME] == "engine.settle")
+    # the ragged kernel's block counts are taken inside `runner.account`,
+    # so behind their step's dispatch like the bytes
+    accounts = [s for s in spans if s[NAME] == "runner.account"]
+    assert all(any(s[T0] <= t <= s[T1] for s in accounts)
+               for t in block_counts)
+    assert bool(block_counts) == (which == "ragged-kernel")
     # the counts: asked for at the hand-over, before the drain that reads
     # them blocks for the tokens; read once, after those
     assert log.count("copy") == log.count("read") > 0

@@ -477,6 +477,56 @@ def test_engine_options_serve_the_oracles_tokens(toy, options):
     assert eng.pool.window.check_no_leaks()
 
 
+def test_engine_counts_the_edge_blocks_of_both_groups_walks(toy, monkeypatch):
+    """`ragged_blocks` / `ragged_edge_blocks` after a toy serve are the pure
+    function's counts over every decode launch's own operands: the full
+    group's walk at the contexts, the window group's at the ring's own
+    positions under its bound. (The toy's pages are too narrow for the
+    kernel on the CPU, so the programs keep the gather path and only the
+    host's accounting is told the kernel runs: it is arithmetic on what a
+    launch was given.)"""
+    from paddle_tpu.ops.pallas.ragged_paged_attention import \
+        ragged_block_counts
+
+    _, _, model = toy
+    eng = _engine(model)
+    eng.add_request([1, 2, 3], SamplingParams(max_tokens=3))
+    eng.run()                                   # the programs exist
+    runner = eng.runner
+    monkeypatch.setattr(runner, "_attn_impl_for", lambda span: "ragged")
+    ppb = 2                                     # blocks of 8 keys
+    runner._fold_pages = {1: ppb}
+    runner.reset_attn_counters()
+    launches, real = [], runner._account_decode
+
+    def logged(pos, tables):
+        launches.append((np.array(pos), np.array(tables)))
+        real(pos, tables)
+
+    monkeypatch.setattr(runner, "_account_decode", logged)
+    rng = np.random.default_rng(1)
+    for n, m in ((5, 4), (30, 21), (17, 9)):
+        eng.add_request(rng.integers(0, VOCAB, n).tolist(),
+                        SamplingParams(max_tokens=m))
+    eng.run()
+    want = np.zeros(2, np.int64)
+    shares = []
+    for pos, tables in launches:
+        rel = pos - tables[:, -1] * PAGE
+        for start, lower in ((pos, None),
+                             (rel, np.maximum(rel - (WINDOW - 1), 0))):
+            got = ragged_block_counts(start, np.ones_like(pos), PAGE, ppb,
+                                      lower)
+            want += [got[0].sum(), got[1].sum()]
+            shares.append(got[1].sum() / got[0].sum())
+    snap = eng.metrics.snapshot()
+    assert launches and 0 < want[1] < want[0]
+    assert [snap["ragged_blocks"], snap["ragged_edge_blocks"]] == want.tolist()
+    assert snap["ragged_blocks"] == runner.ragged_blocks
+    # the rings hold little but edges; the whole contexts' walks less
+    assert np.mean(shares[1::2]) > np.mean(shares[0::2])
+
+
 @pytest.mark.parametrize("option", [
     {"enable_prefix_cache": True}, {"host_tier_pages": 8},
     {"num_speculative_tokens": 2}, {"ragged_batch": True},

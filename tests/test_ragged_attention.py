@@ -250,6 +250,190 @@ def test_walk_crosses_block_boundaries_and_reads_no_dead_page(case, int8):
         assert (out[b, n:] == 0.0).all()
 
 
+# --------------------------------- interior and edge blocks of a walk (43)
+
+
+def _edge_layouts():
+    """name -> (dtype, n_q, n_kv, d, row pools?): the toy layout in float32,
+    and the three cells' head layouts in bfloat16 (10 pair heads in row
+    pools, 16 heads, 30 heads that the kernel pads to 32)."""
+    return {"toy-f32": (jnp.float32, N_KV * N_REP, N_KV, D, False),
+            "rows-10x128": (jnp.bfloat16, 40, 10, 128, True),
+            "heads-16x128": (jnp.bfloat16, 16, 16, 128, False),
+            "heads-30x128": (jnp.bfloat16, 30, 30, 128, False)}
+
+
+def _edge_cases(keys):
+    """name -> (T, starts, q_lens, lowers or None) around the places where
+    the walk's three ranges split, `keys` the keys of one block."""
+    return {
+        # row 0 on a block's last key (that block is interior), one
+        # before it (it is an edge), one after (the next block holds one key)
+        "start-on-one-before-one-after-a-last-key": (
+            1, [2 * keys - 1, 2 * keys - 2, 2 * keys], [1, 1, 1], None),
+        "one-block-one-key-and-a-dead-slot": (
+            1, [keys - 1, 0, 3 * keys + 5], [1, 1, 0], None),
+        # the bound on a block's first key (interior from there), inside a
+        # block, and inside the walk's last block
+        "lower-on-a-first-key-mid-block-in-the-last-block": (
+            1, [3 * keys - 1, 3 * keys + 7, 2 * keys + 9],
+            [1, 1, 1], [keys, keys + 5, 2 * keys + 3]),
+        "lower-with-one-block-and-a-dead-slot": (
+            1, [keys - 1, keys + 4, 40], [1, 0, 1], [0, 3, 33]),
+        # a span of several rows: its causal triangle crosses a block's
+        # end, lies in one block, stands on a block's first key; rows past
+        # q_len, and a dead slot
+        "span-triangle-across-a-block-end": (
+            4, [keys - 2, 2 * keys + 3, 2 * keys, 5], [4, 2, 3, 0], None),
+        "span-under-a-lower-bound": (
+            4, [2 * keys - 3, keys, 2 * keys + 1], [4, 1, 3],
+            [keys, 7, keys + 2]),
+    }
+
+
+def _exact_live_inputs(layout, T, starts, qlens, lowers, keys, ppb, ps):
+    """Pools that hold NaN wherever no row of any sequence may look: pages
+    outside every table, pages of a table outside [lower, last key], and
+    inside a live page the keys before the bound and past the last key.
+    The reference reads the same pools with zeros there."""
+    dtype, n_q, n_kv, d, rows = _edge_layouts()[layout]
+    B = len(starts)
+    width = 4 * ppb
+    nb = 1 + B * width + 3
+    prng = np.random.default_rng(43)
+    tbl = prng.permutation(np.arange(1, nb - 3)).reshape(B, width).astype(
+        np.int32)
+    live = np.zeros((nb, ps), bool)
+    for b in range(B):
+        lo = 0 if lowers is None else lowers[b]
+        for pos in range(lo, starts[b] + qlens[b]):
+            live[tbl[b, pos // ps], pos % ps] = True
+    q = jnp.asarray(prng.standard_normal((B, T, n_q, d)), dtype)
+    kv = prng.standard_normal((2, nb, ps, n_kv, d)).astype(np.float32)
+    pools, ref_pools = [], []
+    for x in kv:
+        for out, dead in ((pools, np.nan), (ref_pools, 0.0)):
+            y = jnp.asarray(np.where(live[:, :, None, None], x, dead), dtype)
+            out.append(y.reshape(nb, ps * n_kv, d) if rows else y)
+    kw = dict(kv_heads=n_kv) if rows else {}
+    if lowers is not None:
+        kw["lower"] = jnp.asarray(lowers, jnp.int32)
+    args = (jnp.asarray(tbl), jnp.asarray(starts, jnp.int32),
+            jnp.asarray(qlens, jnp.int32))
+    return q, pools, ref_pools, args, kw
+
+
+@pytest.mark.parametrize("case", sorted(_edge_cases(1)))
+@pytest.mark.parametrize("layout", sorted(_edge_layouts()))
+def test_interior_and_edge_blocks_equal_the_reference(layout, case):
+    """The walk's ranges split where a block stops being interior; on each
+    side of every such place the kernel equals the gather oracle, rows past
+    q_len and dead slots are exact zeros, and no NaN outside what a row
+    sees reaches the result (an interior block masks nothing: it must hold
+    nothing to mask)."""
+    dtype, n_q, n_kv, d, rows = _edge_layouts()[layout]
+    ps = PS if layout == "toy-f32" else 16
+    # a span as long as the few-rows arm takes of these heads
+    T = min(_edge_cases(1)[case][0], rpa.FLAT_ROWS // n_q)
+    ppb = pages_per_block(T, n_q, q_itemsize := np.dtype(dtype).itemsize, ps,
+                          n_kv, d, q_itemsize, row_pools=rows)
+    keys = ppb * ps
+    _, starts, qlens, lowers = _edge_cases(keys)[case]
+    qlens = [min(n, T) for n in qlens]
+    q, pools, ref_pools, args, kw = _exact_live_inputs(
+        layout, T, starts, qlens, lowers, keys, ppb, ps)
+    out = np.asarray(ragged_paged_attention(
+        q, *pools, *args, interpret=True, **kw).astype(jnp.float32))
+    ref = np.asarray(ragged_reference(q, *ref_pools, *args, **kw).astype(
+        jnp.float32))
+    assert np.isfinite(out).all(), "a key no row sees reached the result"
+    tol = 1e-5 if dtype == jnp.float32 else 2e-2
+    np.testing.assert_allclose(out, ref, rtol=tol, atol=tol)
+    for b, n in enumerate(qlens):
+        assert (out[b, n:] == 0.0).all()
+    if case == "start-on-one-before-one-after-a-last-key":
+        # and the split is where the counter says it is: a walk that ends
+        # on a block's last key has no edge block at all
+        blocks, edges = rpa.ragged_block_counts(starts, qlens, ps, ppb)
+        assert (blocks.tolist(), edges.tolist()) == ([2, 2, 3], [0, 1, 1])
+
+
+def test_a_span_too_wide_to_stack_keeps_three_products():
+    """The three bf16 terms of p are one MXU pass where they fit its rows
+    as whole float32 tiles; a verify span of 64 rows keeps three products
+    and the same result."""
+    assert rpa._stacked(40) and rpa._stacked(16) and rpa._stacked(32)
+    assert not rpa._stacked(64) and not rpa._stacked(12)
+    n_q, n_kv, d, ps = 32, 8, 128, 16
+    ppb = pages_per_block(2, n_q, 2, ps, n_kv, d, 2)
+    keys = ppb * ps
+    prng = np.random.default_rng(5)
+    nb = 1 + 2 * 2 * ppb
+    tbl = jnp.asarray(prng.permutation(np.arange(1, nb)).reshape(
+        2, 2 * ppb).astype(np.int32))
+    q = jnp.asarray(prng.standard_normal((2, 2, n_q, d)), jnp.bfloat16)
+    kp, vp = (jnp.asarray(prng.standard_normal((nb, ps, n_kv, d)),
+                          jnp.bfloat16) for _ in range(2))
+    starts = jnp.asarray([keys + 3, keys - 1], jnp.int32)
+    qlens = jnp.asarray([2, 1], jnp.int32)
+    out = ragged_paged_attention(q, kp, vp, tbl, starts, qlens,
+                                 interpret=True).astype(jnp.float32)
+    ref = ragged_reference(q, kp, vp, tbl, starts, qlens).astype(jnp.float32)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               rtol=2e-2, atol=2e-2)
+    assert (np.asarray(out)[1, 1:] == 0.0).all()
+
+
+@pytest.mark.parametrize("cell,shape,want", [
+    ("phi-4-mini-flash.reason-12k", (1, 40, 2, 16, 10, 128, 2, True), 16),
+    ("gpt3-1.3b.decode", (1, 16, 2, 16, 16, 128, 2, False), 16),
+    ("olmo-hybrid-7b.decode-wide", (1, 30, 2, 16, 30, 128, 2, False), 8),
+])
+def test_block_pages_of_the_cells_decode_shapes(cell, shape, want):
+    """What the three serving cells' walks copy and fold at a time: the
+    benchmark's rooflines were read with these."""
+    *dims, rows = shape
+    assert pages_per_block(*dims, row_pools=rows) == want
+    assert rpa.few_rows_block_pages(*dims, row_pools=rows) == want
+    # a prefill span's tiles take the other arm: no few-rows block
+    assert rpa.few_rows_block_pages(256, *dims[1:], row_pools=rows) == 0 \
+        or rows
+
+
+def _brute_block_counts(start, qlen, ps, ppb, lower):
+    """Key by key: the blocks between the bound's and the last visible
+    key's, and those with a key some live row cannot see (past row 0, or
+    before the bound) or a slot past the walk's last page."""
+    if qlen <= 0:
+        return 0, 0
+    keys, last = ps * ppb, start + qlen - 1
+    blocks = edges = 0
+    for j in range(lower // keys, last // keys + 1):
+        blocks += 1
+        positions = range(j * keys, (j + 1) * keys)
+        edges += any(pos < lower or pos > start or pos // ps > last // ps
+                     for pos in positions)
+    return blocks, edges
+
+
+@pytest.mark.parametrize("seed", range(4))
+def test_block_counts_equal_a_brute_force_count(seed):
+    prng = np.random.default_rng(seed)
+    ps, ppb = int(prng.choice([4, 8, 16])), int(prng.choice([1, 2, 8]))
+    n = 200
+    start = prng.integers(0, 40 * ps * ppb // 8 + 8, n)
+    qlen = prng.integers(0, 6, n)
+    lower = np.minimum(prng.integers(0, 30 * ps, n) * prng.integers(0, 2, n),
+                       start)
+    for bound in (None, lower):
+        blocks, edges = rpa.ragged_block_counts(start, qlen, ps, ppb, bound)
+        want = [_brute_block_counts(int(s), int(q), ps, ppb,
+                                    0 if bound is None else int(lo))
+                for s, q, lo in zip(start, qlen, lower)]
+        assert blocks.tolist() == [w[0] for w in want]
+        assert edges.tolist() == [w[1] for w in want]
+
+
 def test_page_reads_count_the_copies_the_kernel_starts(monkeypatch):
     """attention_page_reads' contract: for a mixed batch (decode rows, a
     prefill chunk, a dead slot, a span across a block boundary) its count
