@@ -126,60 +126,76 @@ def latent_walk_counts(block_table, pos, page_size: int, group: int):
     return groups, as_run, descriptors
 
 
+def page_copies(table_ref, runs_ref, pool_hbm, buf, sem, b_, block, slot,
+                n_pages, *, group: int, wait: bool = False):
+    """Start (or wait for) the copies of the live pages of `block` of
+    sequence b_'s table row into buffer `slot` of `buf` [2, pages a block,
+    page, lanes]: one per group that is a run and wholly live, one per page
+    elsewhere (unrolled for a whole group, a loop over a sequence's tail).
+    A wait has the shape of the copy it waits for. The walk of every
+    kernel over pages of `pool_hbm` [pages, page, lanes]."""
+    ppb = buf.shape[1]
+    last_group = runs_ref.shape[1] - 1
+    first = block * ppb
+    live = jnp.clip(n_pages - first, 0, ppb)
+
+    def copy(src, dst):
+        cp = pltpu.make_async_copy(src, dst, sem.at[slot])
+        cp.wait() if wait else cp.start()
+
+    def one_group(g, carry):
+        page0 = first + g * group
+        whole = page0 + group <= n_pages
+        is_run = jnp.logical_and(
+            runs_ref[b_, jnp.minimum(page0 // group, last_group)] != 0,
+            whole)
+
+        def page(r, c):
+            pid = 0 if wait else table_ref[b_, page0 + r]
+            copy(pool_hbm.at[pid], buf.at[slot, g * group + r])
+            return c
+
+        @pl.when(is_run)
+        def _as_one():
+            pid = 0 if wait else table_ref[b_, page0]
+            copy(pool_hbm.at[pl.ds(pid, group)],
+                 buf.at[slot, pl.ds(g * group, group)])
+
+        @pl.when(jnp.logical_and(whole, jnp.logical_not(is_run)))
+        def _scattered():
+            jax.lax.fori_loop(0, group, page, 0, unroll=True)
+
+        @pl.when(jnp.logical_not(whole))
+        def _tail():
+            jax.lax.fori_loop(0, live - g * group, page, 0)
+
+        return carry
+
+    jax.lax.fori_loop(0, pl.cdiv(live, group), one_group, 0)
+
+
+def _select_kernel(table_ref, pos_ref, runs_ref, value_ref, last_ref, q_ref,
+                   score_ref, *refs, **kw):
+    """The kernel under a selection: two more scalar rows and the
+    sequence's row of scores in front of the pool."""
+    _kernel(table_ref, pos_ref, runs_ref, q_ref, *refs,
+            select=(value_ref, last_ref, score_ref), **kw)
+
+
 def _kernel(table_ref, pos_ref, runs_ref, q_ref, pool_hbm, o_ref, buf, sem,
             m_ref, l_ref, acc_ref, slot_ref, *, page_size: int, v_lanes: int,
-            scale: float, group: int):
+            scale: float, group: int, select=None):
     b, n_seq = pl.program_id(0), pl.num_programs(0)
     ppb = buf.shape[1]
     keys = ppb * page_size
     table_width = table_ref.shape[1]
-    last_group = runs_ref.shape[1] - 1
 
     def seq_pages(b_):
         return jnp.minimum(pos_ref[b_] // page_size + 1, table_width)
 
     def copies(b_, block, slot, n_pages, wait: bool = False):
-        """Start (or wait for) the copies of the live pages of `block` of
-        sequence b_'s table row into buffer `slot`: one per group that is
-        a run and wholly live, one per page elsewhere (unrolled for a whole
-        group, a loop over a sequence's tail). A wait has the shape of the
-        copy it waits for."""
-        first = block * ppb
-        live = jnp.clip(n_pages - first, 0, ppb)
-
-        def copy(src, dst):
-            cp = pltpu.make_async_copy(src, dst, sem.at[slot])
-            cp.wait() if wait else cp.start()
-
-        def one_group(g, carry):
-            page0 = first + g * group
-            whole = page0 + group <= n_pages
-            is_run = jnp.logical_and(
-                runs_ref[b_, jnp.minimum(page0 // group, last_group)] != 0,
-                whole)
-
-            def page(r, c):
-                pid = 0 if wait else table_ref[b_, page0 + r]
-                copy(pool_hbm.at[pid], buf.at[slot, g * group + r])
-                return c
-
-            @pl.when(is_run)
-            def _as_one():
-                pid = 0 if wait else table_ref[b_, page0]
-                copy(pool_hbm.at[pl.ds(pid, group)],
-                     buf.at[slot, pl.ds(g * group, group)])
-
-            @pl.when(jnp.logical_and(whole, jnp.logical_not(is_run)))
-            def _scattered():
-                jax.lax.fori_loop(0, group, page, 0, unroll=True)
-
-            @pl.when(jnp.logical_not(whole))
-            def _tail():
-                jax.lax.fori_loop(0, live - g * group, page, 0)
-
-            return carry
-
-        jax.lax.fori_loop(0, pl.cdiv(live, group), one_group, 0)
+        page_copies(table_ref, runs_ref, pool_hbm, buf, sem, b_, block, slot,
+                    n_pages, group=group, wait=wait)
 
     last_pos = pos_ref[b]
     n_pages = seq_pages(b)
@@ -207,10 +223,22 @@ def _kernel(table_ref, pos_ref, runs_ref, q_ref, pool_hbm, o_ref, buf, sem,
         s = jax.lax.dot_general(q, kv, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
         v = kv[:, :v_lanes]
-        if masked:
+        if select is not None:
+            # only the keys the sequence's query chose (a score above the
+            # k-th largest, or equal to it and early enough) and can see
+            value_ref, last_ref, score_ref = select
+            score = score_ref[0, :, pl.ds(pl.multiple_of(key0, keys), keys)]
+            k_pos = key0 + jax.lax.broadcasted_iota(jnp.int32, score.shape, 1)
+            value = value_ref[b]
+            live = (((score > value) | ((score == value)
+                                        & (k_pos <= last_ref[b])))
+                    & (k_pos <= last_pos))
+            s = jnp.where(live, s, NEG_INF)
+        elif masked:
             live = key0 + jax.lax.broadcasted_iota(
                 jnp.int32, s.shape, 1) <= last_pos
             s = jnp.where(live, s, NEG_INF)
+        if masked:
             # the slots of a partial block that no copy filled hold what
             # was there before, and 0 * NaN is NaN: the values are masked
             v = jnp.where(key0 + jax.lax.broadcasted_iota(
@@ -218,7 +246,7 @@ def _kernel(table_ref, pos_ref, runs_ref, q_ref, pool_hbm, o_ref, buf, sem,
         m = m_ref[...]
         new_m = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
         p = jnp.exp(s - new_m)
-        if masked:
+        if masked or select is not None:
             p = jnp.where(live, p, 0.0)
         corr = jnp.exp(m - new_m)
         m_ref[...] = new_m
@@ -255,13 +283,18 @@ def _kernel(table_ref, pos_ref, runs_ref, q_ref, pool_hbm, o_ref, buf, sem,
 def latent_paged_attention(q, pool, block_table, pos, *, v_lanes: int,
                            scale: float, interpret: bool | None = None,
                            runs=None, pages_per_block: int | None = None,
-                           group: int | None = None):
+                           group: int | None = None, select=None):
     """Decode attention of B sequences over latent pages (see the head).
     Every sequence reads at least its first page (a dead slot's table is
     all scratch and its position 0). `runs` are `page_runs` of the table
     at `walk_shape`'s group, for a caller whose layers share one table
     (None: computed here). `pages_per_block` and `group` override the
-    rule: for tests of the walk at small sizes."""
+    rule: for tests of the walk at small sizes. `select` = (scores [B,
+    keys] float32, value [B] float32, last [B] int32) restricts each
+    sequence to the keys it chose: those whose score lies above `value`, or
+    equals it at a position <= `last` (models/deepseek_v3.topk_threshold);
+    the walk still reads every live page, and every block is folded under
+    the mask."""
     if pool.shape[2] % 128:
         raise ValueError(
             f"latent pages of {pool.shape[2]} lanes: the chip copies whole "
@@ -271,9 +304,18 @@ def latent_paged_attention(q, pool, block_table, pos, *, v_lanes: int,
     ppb, group = walk_shape(q.shape[1], pool, v_lanes, pages_per_block, group)
     if runs is None:
         runs = page_runs(block_table, group)
-    return _call(q, pool, block_table, pos, runs, v_lanes=int(v_lanes),
-                 scale=float(scale), interpret=bool(interpret), ppb=ppb,
-                 group=group)
+    if select is not None:
+        scores, value, last = select
+        # whole blocks of scores, + 0.0 so that -0.0 counts as 0.0
+        keys = ppb * pool.shape[1]
+        width = -(-block_table.shape[1] * pool.shape[1] // keys) * keys
+        select = (jnp.pad(scores.astype(jnp.float32) + 0.0,
+                          ((0, 0), (0, width - scores.shape[1])),
+                          constant_values=-jnp.inf)[:, None, :],
+                  value.astype(jnp.float32), last.astype(jnp.int32))
+    return _call(q, pool, block_table, pos, runs, select,
+                 v_lanes=int(v_lanes), scale=float(scale),
+                 interpret=bool(interpret), ppb=ppb, group=group)
 
 
 def walk_shape(n_q: int, pool, v_lanes: int, ppb: int | None = None,
@@ -298,15 +340,25 @@ def walk_shape(n_q: int, pool, v_lanes: int, ppb: int | None = None,
 # with the same shapes, and a jitted callee is lowered once per program
 @functools.partial(jax.jit, static_argnames=("v_lanes", "scale", "interpret",
                                              "ppb", "group"))
-def _call(q, pool, block_table, pos, runs, *, v_lanes: int, scale: float,
-          interpret: bool, ppb: int, group: int):
+def _call(q, pool, block_table, pos, runs, select=None, *, v_lanes: int,
+          scale: float, interpret: bool, ppb: int, group: int):
     B, n_q, lanes = q.shape
     page_size = pool.shape[1]
+    scalars = (block_table.astype(jnp.int32),
+               jnp.asarray(pos, jnp.int32).reshape(-1),
+               jnp.asarray(runs, jnp.int32))
+    in_specs = [pl.BlockSpec((1, n_q, lanes), lambda b, *_: (b, 0, 0))]
+    inputs = (q,)
+    if select is not None:
+        scores, value, last = select
+        scalars += (value, last)
+        in_specs.append(pl.BlockSpec((1, 1, scores.shape[2]),
+                                     lambda b, *_: (b, 0, 0)))
+        inputs += (scores,)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
+        num_scalar_prefetch=len(scalars),
         grid=(B,),
-        in_specs=[pl.BlockSpec((1, n_q, lanes), lambda b, *_: (b, 0, 0)),
-                  pl.BlockSpec(memory_space=pl.ANY)],
+        in_specs=in_specs + [pl.BlockSpec(memory_space=pl.ANY)],
         out_specs=pl.BlockSpec((1, n_q, v_lanes), lambda b, *_: (b, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((2, ppb, page_size, lanes), pool.dtype),
@@ -318,8 +370,9 @@ def _call(q, pool, block_table, pos, runs, *, v_lanes: int, scale: float,
         ],
     )
     return pl.pallas_call(
-        functools.partial(_kernel, page_size=page_size, v_lanes=v_lanes,
-                          scale=scale, group=group),
+        functools.partial(_kernel if select is None else _select_kernel,
+                          page_size=page_size, v_lanes=v_lanes, scale=scale,
+                          group=group),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, n_q, v_lanes), q.dtype),
         # a step starts the next step's first copies: the grid is a
@@ -328,8 +381,7 @@ def _call(q, pool, block_table, pos, runs, *, v_lanes: int, scale: float,
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
         name="latent_paged_attn",
-    )(block_table.astype(jnp.int32), jnp.asarray(pos, jnp.int32).reshape(-1),
-      jnp.asarray(runs, jnp.int32), q, pool)
+    )(*scalars, *inputs, pool)
 
 
 def latent_reference(q, pool, block_table, pos, *, v_lanes: int,

@@ -271,18 +271,33 @@ class MoELayer(Layer):
 
 
 def sigmoid_topk_route(x, gate_w, bias, top_k: int, *,
-                       norm_topk_prob: bool = True, scale: float = 1.0):
-    """Sigmoid scores with a selection-only bias ("noaux_tc" with one
-    group): x [T, d], gate_w [d, E], bias [E] -> (indices [T, top_k] of the
-    top_k largest of score + bias, weights [T, top_k] float32). The bias
-    moves the selection and never the weights: w_e = s_e / (sum of the
-    selected s + 1e-20) * scale, the sum over all top_k selected whoever
-    holds them."""
+                       norm_topk_prob: bool = True, scale: float = 1.0,
+                       n_group: int = 1, topk_group: int = 1):
+    """Sigmoid scores with a selection-only bias ("noaux_tc"): x [T, d],
+    gate_w [d, E], bias [E] -> (indices [T, top_k] of the top_k largest of
+    score + bias, weights [T, top_k] float32). With `n_group` > 1 the
+    selection is group-limited: the E experts are `n_group` groups of
+    consecutive ids, a group's score is the sum of its two largest score +
+    bias, only the `topk_group` best groups stay, and the top_k are taken
+    among their experts (one group: no limit, the same program as before
+    the option). The bias moves the selection and never the weights: w_e =
+    s_e / (sum of the selected s + 1e-20) * scale, the sum over all top_k
+    selected whoever holds them."""
     import jax
 
     s = jax.nn.sigmoid(jnp.matmul(x, gate_w,
                                   preferred_element_type=jnp.float32))
-    _, idx = jax.lax.top_k(s + bias.astype(jnp.float32)[None, :], top_k)
+    choice = s + bias.astype(jnp.float32)[None, :]
+    if n_group > 1:
+        T, E = choice.shape
+        grouped = choice.reshape(T, n_group, E // n_group)
+        group_score = jnp.sum(jax.lax.top_k(grouped, 2)[0], axis=-1)
+        _, best = jax.lax.top_k(group_score, topk_group)       # [T, kept]
+        kept = jnp.any(best[:, :, None] == jnp.arange(n_group)[None, None],
+                       axis=1)                                  # [T, groups]
+        choice = jnp.where(kept[:, :, None], grouped, -jnp.inf
+                           ).reshape(T, E)
+    _, idx = jax.lax.top_k(choice, top_k)
     w = jnp.take_along_axis(s, idx, axis=-1)
     if norm_topk_prob:
         w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)

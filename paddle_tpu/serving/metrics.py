@@ -118,6 +118,7 @@ SUMMABLE_KEYS = (
     "nan_logit_events", "shed_requests", "tokens_generated",
     "moe_tokens_routed", "moe_local_pairs", "moe_experts_touched",
     "latent_copy_groups", "latent_run_groups",
+    "dsa_keys_scored", "dsa_keys_selected",
     "delta_decode_seq_steps", "delta_prefill_tokens",
     "delta_prefill_positions", "state_slot_resets",
     "ssm_decode_seq_steps", "ssm_prefill_tokens", "cross_rows_skipped",
@@ -221,6 +222,11 @@ class EngineMetrics:
         # consecutive in the pool, copied as ONE copy
         self.latent_copy_groups = Counter("latent_copy_groups")
         self.latent_run_groups = Counter("latent_run_groups")
+        # learned sparse attention, read the same way: index keys a step's
+        # query rows scored (each row's context, all layers) and the keys
+        # their selections kept (min(context, index_topk) a row and layer)
+        self.dsa_keys_scored = Counter("dsa_keys_scored")
+        self.dsa_keys_selected = Counter("dsa_keys_selected")
         # recurrent state (a runner whose linear layers keep a state slot
         # a sequence), read the same way: live rows x linear layers a
         # decode step advanced, real prompt tokens through the chunked
@@ -447,6 +453,8 @@ class EngineMetrics:
             "moe_experts_touched": self.moe_experts_touched.value,
             "latent_copy_groups": self.latent_copy_groups.value,
             "latent_run_groups": self.latent_run_groups.value,
+            "dsa_keys_scored": self.dsa_keys_scored.value,
+            "dsa_keys_selected": self.dsa_keys_selected.value,
             "delta_decode_seq_steps": self.delta_decode_seq_steps.value,
             "delta_prefill_tokens": self.delta_prefill_tokens.value,
             "delta_prefill_positions": self.delta_prefill_positions.value,

@@ -262,9 +262,80 @@ def _latent_attend(q, latent_new, layer_pools, tables, write_page,
     return out.astype(q.dtype), (pool,)
 
 
+def _sparse_latent_attend(q, latent_new, index, layer_pools, tables,
+                          write_page, write_off, pos_q, q_len, impl: str,
+                          scale: float, v_lanes: int, topk: int, runs=None):
+    """paged_attend for a latent layer under a learned selection (DeepSeek
+    Sparse Attention): TWO arrays a page behind one table, the latent rows
+    and the indexer's keys `[num_blocks, page, index lanes]`. `index` is
+    the indexer's view of the new tokens (models/deepseek_v3.index_project,
+    padded to the page's lanes): queries [B, T, heads, lanes], the tokens'
+    keys [B, T, lanes], the heads' weights [B, T, heads] float32. Each
+    query row scores the index keys of its context, keeps the `topk` best
+    (ties to the lower position) and attends over those rows alone.
+    "ragged" is a decode step on the chip: the scan kernel over index
+    pages, then the latent kernel's walk over every live page with each
+    block folded under the selection (`topk_threshold`: exact, no list of
+    rows is made; ops/pallas/sparse_latent_attention.py says why the walk
+    and not a fetch by row). `runs`: (the scan's, the walk's) flags of
+    consecutive pages. "reference" is the gather path for any span. Returns ([B, T,
+    n_h, v_lanes], (pool, index pool))."""
+    from paddle_tpu.ops.pallas import sparse_latent_attention as sla
+
+    pool, ipool = layer_pools
+    q_i, k_i, w_i = index
+    pool = pool.at[write_page, write_off].set(latent_new.astype(pool.dtype))
+    ipool = ipool.at[write_page, write_off].set(k_i.astype(ipool.dtype))
+    B, T = q.shape[0], q.shape[1]
+    if impl == "ragged":
+        if T != 1:
+            raise ValueError(f"the sparse latent kernels are decode "
+                             f"kernels; span of {T} rows")
+        scan_runs, walk_runs = runs if runs is not None else (None, None)
+        with jax.named_scope("block/dsa/index"):
+            scores = sla.paged_index_scores(q_i[:, 0], w_i[:, 0], ipool,
+                                            tables, pos_q, runs=scan_runs)
+        keys = scores.shape[1]
+        if keys <= topk:                 # every visible key is chosen
+            with jax.named_scope("block/dsa/attend"):
+                out = sla.latent_paged_attention(
+                    q[:, 0], pool, tables, pos_q, v_lanes=v_lanes,
+                    scale=scale, runs=walk_runs)
+        else:
+            with jax.named_scope("block/dsa/select"):
+                value, last = _dsv3.topk_threshold(scores, topk)
+            with jax.named_scope("block/dsa/attend"):
+                out = sla.latent_paged_attention(
+                    q[:, 0], pool, tables, pos_q, v_lanes=v_lanes,
+                    scale=scale, runs=walk_runs,
+                    select=(scores, value, last))
+        return out[:, None], (pool, ipool)
+    L = tables.shape[1] * pool.shape[1]
+    t_idx = jnp.arange(T, dtype=jnp.int32)
+    visible = ((jnp.arange(L, dtype=jnp.int32)[None, None, :]
+                <= pos_q[:, None, None] + t_idx[None, :, None])
+               & (t_idx[None, :, None] < q_len[:, None, None]))  # [B, T, L]
+    with jax.named_scope("block/dsa/index"):
+        scores = jax.vmap(_dsv3.index_scores)(
+            q_i, w_i, ipool[tables].reshape(B, L, ipool.shape[-1]))
+    with jax.named_scope("block/dsa/select"):
+        chosen = visible & _dsv3.topk_mask(
+            jnp.where(visible, scores, -jnp.inf).reshape(B * T, L), topk
+        ).reshape(B, T, L)
+    with jax.named_scope("block/dsa/attend"):
+        lat = pool[tables].reshape(B, L, pool.shape[-1])
+        s = jnp.einsum("bthc,blc->bhtl", q, lat,
+                       preferred_element_type=jnp.float32) * scale
+        p = jax.nn.softmax(jnp.where(chosen[:, None], s, -1e30), axis=-1)
+        out = jnp.einsum("bhtl,blc->bthc", p.astype(lat.dtype),
+                         lat[..., :v_lanes])
+    return out.astype(q.dtype), (pool, ipool)
+
+
 def paged_attend(q, k_new, v_new, layer_pools, tables, write_page,
                  write_off, pos_q, q_len, n_rep: int, impl: str,
-                 shard_ctx=None, scale=None, v_lanes=None, runs=None):
+                 shard_ctx=None, scale=None, v_lanes=None, runs=None,
+                 kind: str = "kv", index=None, topk=None):
     """Write this step's K/V through the block table, then attend.
 
     q: [B, T, n_h, d]; k_new/v_new: [B, T, n_kv, d]; layer_pools: one
@@ -285,14 +356,23 @@ def paged_attend(q, k_new, v_new, layer_pools, tables, write_page,
     GSPMD partitions it from the pool sharding alone. Returns
     ([B, T, n_h*d], new_layer_pools).
 
-    A layer whose pool tuple is ONE array is a latent layer: k_new is
-    the tokens' latent rows, v_new None, `scale` the softmax scale and
-    `v_lanes` the value's lanes, `runs` the kernel's run flags (see
-    _latent_attend, whose return it is)."""
-    if len(layer_pools) == 1:
+    `kind` says what the layer's pages hold, "kv" above. "latent": ONE
+    array a page; k_new is the tokens' latent rows, v_new None, `scale`
+    the softmax scale and `v_lanes` the value's lanes, `runs` the
+    kernel's run flags (see _latent_attend, whose return it is).
+    "latent+index": the latent rows and the indexer's keys, attended
+    under the `topk` selection of `index` (see _sparse_latent_attend)."""
+    if kind == "latent":
         return _latent_attend(q, k_new, layer_pools, tables, write_page,
                               write_off, pos_q, q_len, impl, scale, v_lanes,
                               runs)
+    if kind == "latent+index":
+        return _sparse_latent_attend(q, k_new, index, layer_pools, tables,
+                                     write_page, write_off, pos_q, q_len,
+                                     impl, scale, v_lanes, topk, runs)
+    if kind != "kv":
+        raise ValueError(f"paged_attend(kind={kind!r}); expected 'kv', "
+                         "'latent' or 'latent+index'")
     quantized = len(layer_pools) == 4
     mixed = len(layer_pools) == 3
     if quantized:
@@ -2013,7 +2093,13 @@ class DeepseekV3Runner(PagedModelRunner):
     A layer's cache is ONE array a page, `[num_blocks, page, lanes]`:
     per token c_kv | k_r (`cfg.latent_dim` values), allocated with its
     lanes rounded up to whole 128-lane tiles because the chip copies a
-    page only as whole tiles (576 -> 640; PERF.md). Two attention paths
+    page only as whole tiles (576 -> 640; PERF.md). A configuration with
+    an indexer (`cfg.index_topk`: DeepSeek-V3.2) names a SECOND array a
+    page behind the same table, the indexer's key of each token, and
+    every query row attends over the `index_topk` keys it scored best
+    (`_sparse_latent_attend`; a prompt's span in the expanded form under
+    `selection_mask`, its heads a group at a time); it counts the keys
+    scored and kept beside the rest. Two attention paths
     from one set of weights, chosen from shapes: ONE sequence's span of
     several rows (a prefill bucket, a chunk) runs the EXPANDED form
     (per-head keys and values rebuilt from the table's latent rows,
@@ -2029,6 +2115,8 @@ class DeepseekV3Runner(PagedModelRunner):
 
     COUNTS = ("moe_tokens_routed", "moe_local_pairs", "moe_experts_touched",
               "latent_copy_groups", "latent_run_groups")
+    # what a runner with an indexer counts besides
+    SPARSE_COUNTS = ("dsa_keys_scored", "dsa_keys_selected")
     HEAD_ROWS = True
 
     def __init__(self, model, block_size: int = 16,
@@ -2054,6 +2142,10 @@ class DeepseekV3Runner(PagedModelRunner):
         self.vocab_size = cfg.vocab_size
         # a page's lanes: the latent row in whole 128-lane tiles
         self.page_lanes = -(-cfg.latent_dim // 128) * 128
+        self.sparse = cfg.index_topk is not None
+        if self.sparse:
+            self.index_lanes = -(-cfg.index_head_dim // 128) * 128
+            self.COUNTS = self.COUNTS + self.SPARSE_COUNTS
         self._rope_cos, self._rope_sin = _dsv3.rope_tables(
             cfg, self.max_model_len)                   # [L, rope] fp32
         self._scale = _dsv3.softmax_scale(cfg)
@@ -2063,7 +2155,9 @@ class DeepseekV3Runner(PagedModelRunner):
                 pre = f"layers.{i}."
                 names += [pre + "self_attn." + n + ".weight" for n in (
                     "q_a_proj", "q_b_proj", "kv_a_proj_with_mqa",
-                    "kv_b_proj", "o_proj")]
+                    "kv_b_proj", "o_proj") + (
+                        ("indexer.wq_b", "indexer.wk",
+                         "indexer.weights_proj") if self.sparse else ())]
                 mlp = pre + ("mlp." if cfg.is_dense(i)
                              else "mlp.shared_experts.")
                 names += [mlp + n + ".weight" for n in (
@@ -2071,7 +2165,10 @@ class DeepseekV3Runner(PagedModelRunner):
             self._quantize_weights(names)
 
     def page_layout(self):
-        return [((self.page_lanes,), self.dtype)]
+        layout = [((self.page_lanes,), self.dtype)]
+        if self.sparse:
+            layout.append(((self.index_lanes,), self.dtype))
+        return layout
 
     def _param_specs(self, layout):
         raise NotImplementedError(
@@ -2097,7 +2194,11 @@ class DeepseekV3Runner(PagedModelRunner):
         return impl
 
     def _kv_page_bytes(self) -> int:
-        return (self.num_layers * self.block_size * self.page_lanes
+        """A page's bytes in every layer; under a selection both its arrays
+        (the scan reads every live page's index keys, the walk its latent
+        rows: each block is folded under the selection, none is skipped)."""
+        lanes = self.page_lanes + (self.index_lanes if self.sparse else 0)
+        return (self.num_layers * self.block_size * lanes
                 * np.dtype(self.dtype).itemsize)
 
     def _fold_block_pages(self, span: int) -> int:
@@ -2131,39 +2232,54 @@ class DeepseekV3Runner(PagedModelRunner):
             # layers share one table, so once for the step's program
             from paddle_tpu.ops.pallas import latent_paged_attention as lpa
 
-            (pool,) = pools[0]
+            pool = pools[0][0]
             _, group = lpa.walk_shape(nh, pool, cfg.kv_lora_rank)
             runs = lpa.page_runs(tables, group)
             walked = cfg.num_hidden_layers * lpa.walked_groups(
                 runs, pos_q, self.block_size, group, tables.shape[1])
+            if self.sparse:
+                # the scan over index pages walks in groups of its own
+                from paddle_tpu.ops.pallas.sparse_latent_attention import \
+                    scan_shape
+
+                runs = (lpa.page_runs(tables, scan_shape(pools[0][1])[1]),
+                        runs)
         new_pools = []
         for i in range(cfg.num_hidden_layers):
             pre = f"layers.{i}."
-            with jax.named_scope("block/mla"):
-                h = m.rms_norm(x, params[pre + "input_layernorm.weight"],
-                               cfg.rms_norm_eps)
-                qn, qr, lat = m.mla_project(cfg, params, pre, h, cos, sin,
-                                            mm=self._mm)
-                lat = jnp.pad(lat, ((0, 0), (0, 0),
-                                    (0, lanes - cfg.latent_dim)))
-                w_kvb = self._w(params, pre + "self_attn.kv_b_proj.weight")
-                if expanded:
-                    (pool,) = pools[i]
-                    pool = pool.at[write_page, write_off].set(
-                        lat.astype(pool.dtype))
-                    o = m.expanded_attention(
-                        cfg, qn[0], qr[0],
-                        pool[tables[0]].reshape(-1, lanes), w_kvb,
-                        pos_q[0], q_lens[0])[None]
-                    layer = (pool,)
-                else:
-                    o, layer = paged_attend(
-                        m.absorb_queries(cfg, qn, qr, w_kvb, lanes), lat,
-                        None, pools[i], tables, write_page, write_off, pos_q,
-                        q_lens, nh, impl, scale=self._scale,
-                        v_lanes=cfg.kv_lora_rank, runs=runs)
-                    o = m.absorb_outputs(cfg, o, w_kvb)
-                x = x + self._mm(params, pre + "self_attn.o_proj.weight", o)
+            if self.sparse:
+                x, layer = self._sparse_attention(
+                    params, pre, x, cos, sin, pools[i], tables, write_page,
+                    write_off, pos_q, q_lens, impl, expanded, runs)
+            else:
+                with jax.named_scope("block/mla"):
+                    h = m.rms_norm(x, params[pre + "input_layernorm.weight"],
+                                   cfg.rms_norm_eps)
+                    qn, qr, lat, _ = m.mla_project(cfg, params, pre, h, cos,
+                                                   sin, mm=self._mm)
+                    lat = jnp.pad(lat, ((0, 0), (0, 0),
+                                        (0, lanes - cfg.latent_dim)))
+                    w_kvb = self._w(params,
+                                    pre + "self_attn.kv_b_proj.weight")
+                    if expanded:
+                        (pool,) = pools[i]
+                        pool = pool.at[write_page, write_off].set(
+                            lat.astype(pool.dtype))
+                        o = m.expanded_attention(
+                            cfg, qn[0], qr[0],
+                            pool[tables[0]].reshape(-1, lanes), w_kvb,
+                            pos_q[0], q_lens[0])[None]
+                        layer = (pool,)
+                    else:
+                        o, layer = paged_attend(
+                            m.absorb_queries(cfg, qn, qr, w_kvb, lanes), lat,
+                            None, pools[i], tables, write_page, write_off,
+                            pos_q, q_lens, nh, impl, scale=self._scale,
+                            v_lanes=cfg.kv_lora_rank, runs=runs,
+                            kind="latent")
+                        o = m.absorb_outputs(cfg, o, w_kvb)
+                    x = x + self._mm(params, pre + "self_attn.o_proj.weight",
+                                     o)
             h = m.rms_norm(x, params[pre + "post_attention_layernorm.weight"],
                            cfg.rms_norm_eps).reshape(B * T, -1)
             if cfg.is_dense(i):
@@ -2181,7 +2297,67 @@ class DeepseekV3Runner(PagedModelRunner):
                 x = jnp.take_along_axis(x, head_rows[:, None, None], axis=1)
         with jax.named_scope("lm_head"):
             logits = self._mm(params, "lm_head.weight", x)
-        return logits, new_pools, jnp.concatenate([experts, walked])
+        counts = [experts, walked]
+        if self.sparse:
+            # every live query row scored its context and kept the best
+            t_idx = jnp.arange(T, dtype=jnp.int32)[None, :]
+            context = jnp.where(t_idx < q_lens[:, None],
+                                pos_q[:, None] + t_idx + 1, 0)
+            counts.append(cfg.num_hidden_layers * jnp.stack(
+                [jnp.sum(context),
+                 jnp.sum(jnp.minimum(context, cfg.index_topk))]))
+        return logits, new_pools, jnp.concatenate(counts)
+
+    def _sparse_attention(self, params, pre, x, cos, sin, layer_pools,
+                          tables, write_page, write_off, pos_q, q_lens, impl,
+                          expanded, runs):
+        """One layer's attention under the indexer's selection, residual
+        added: (x, the layer's (latent pool, index pool))."""
+        cfg, m = self.cfg, _dsv3
+        lanes = self.page_lanes
+        pad = lambda a, n: jnp.pad(
+            a, ((0, 0),) * (a.ndim - 1) + ((0, n - a.shape[-1]),))
+        with jax.named_scope("block/mla"):
+            h = m.rms_norm(x, params[pre + "input_layernorm.weight"],
+                           cfg.rms_norm_eps)
+            # (a prompt's span makes its queries a group of heads at a
+            # time and leaves these to the compiler's dead-code pass)
+            qn, qr, lat, c_q = m.mla_project(cfg, params, pre, h, cos, sin,
+                                             mm=self._mm)
+            lat = pad(lat, lanes)
+            w_kvb = self._w(params, pre + "self_attn.kv_b_proj.weight")
+        with jax.named_scope("block/dsa/index"):
+            q_i, k_i, w_i = m.index_project(cfg, params, pre, h, c_q, cos,
+                                            sin, mm=self._mm)
+            q_i, k_i = pad(q_i, self.index_lanes), pad(k_i, self.index_lanes)
+        if expanded:
+            pool, ipool = layer_pools
+            pool = pool.at[write_page, write_off].set(lat.astype(pool.dtype))
+            ipool = ipool.at[write_page, write_off].set(
+                k_i.astype(ipool.dtype))
+            with jax.named_scope("block/dsa/select"):
+                chosen = m.selection_mask(
+                    cfg, q_i[0], w_i[0],
+                    ipool[tables[0]].reshape(-1, self.index_lanes),
+                    pos_q[0], q_lens[0])
+            with jax.named_scope("block/dsa/attend"):
+                o = m.sparse_expanded_attention(
+                    cfg, c_q[0], cos[0], sin[0],
+                    pool[tables[0]].reshape(-1, lanes), chosen,
+                    self._w(params, pre + "self_attn.q_b_proj.weight"),
+                    w_kvb, self._w(params, pre + "self_attn.o_proj.weight"),
+                    pos_q[0], q_lens[0])[None]
+            return x + o, (pool, ipool)
+        o, layer = paged_attend(
+            m.absorb_queries(cfg, qn, qr, w_kvb, lanes), lat, None,
+            layer_pools, tables, write_page, write_off, pos_q, q_lens,
+            self.n_heads, impl, scale=self._scale, v_lanes=cfg.kv_lora_rank,
+            runs=runs, kind="latent+index", index=(q_i, k_i, w_i),
+            topk=cfg.index_topk)
+        with jax.named_scope("block/mla"):
+            o = m.absorb_outputs(cfg, o, w_kvb)
+            return x + self._mm(params, pre + "self_attn.o_proj.weight",
+                                o), layer
 
 
 class OlmoHybridRunner(PagedModelRunner):
@@ -3039,7 +3215,9 @@ def runner_for(model, block_size: int = 16, max_model_len: int | None = None,
                attn_impl: str = "auto", kv_dtype: str = "fp32",
                weight_dtype: str = "fp32",
                weight_group_size: int = 128) -> PagedModelRunner:
-    """Pick the runner for a supported decoder Layer."""
+    """Pick the runner for a supported decoder Layer, by its class (a
+    DeepseekV3ForCausalLM is DeepSeek-V3, Kimi K2 or, with an indexer in
+    its configuration, DeepSeek-V3.2: one runner)."""
     from paddle_tpu.models.deepseek_v3 import DeepseekV3ForCausalLM
     from paddle_tpu.models.gpt import GPT
     from paddle_tpu.models.llama import Llama
@@ -3056,7 +3234,8 @@ def runner_for(model, block_size: int = 16, max_model_len: int | None = None,
                        weight_group_size=weight_group_size)
     raise TypeError(
         f"no serving runner for {type(model).__name__}; supported: Llama, "
-        "GPT, DeepseekV3ForCausalLM, OlmoHybridForCausalLM, "
+        "GPT, DeepseekV3ForCausalLM (DeepSeek-V3, Kimi K2, and DeepSeek-V3.2 "
+        "where its configuration sets index_topk), OlmoHybridForCausalLM, "
         "Phi4FlashForCausalLM (write a PagedModelRunner subclass for "
         "custom decoders)")
 

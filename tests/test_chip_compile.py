@@ -4,7 +4,9 @@ widths (12 heads x 64, sequence 1024, pages of 16) and, for the ragged
 kernel, at the decode cell's: GPT-3 1.3B's 16 x 128 in bf16 over 3136
 pages with a table 128 wide, and a grouped 32/8 x 128; the latent decode
 kernel at `kimi-k2.7-code.decode-16k`'s (48 sequences, 1280-page tables and
-their 80 run flags a row, 16-token pages of 640 lanes).
+their 80 run flags a row, 16-token pages of 640 lanes); the sparse cell's
+scan over index pages and its attention over a selection, in both forms
+(`deepseek-v3.2.decode-sparse-16k`: 36 sequences, 128 heads, 40384 pages).
 
 Nothing runs, so this says nothing about results or times; it raises what
 the chip's compiler would raise (an unparsable contraction, a block the
@@ -96,6 +98,75 @@ def _latent(B, pages=50752, table=1280, n_q=64, lanes=640, v_lanes=512,
     return fn, [((B, n_q, lanes), jnp.bfloat16), (pool.shape, pool.dtype),
                 ((B, table), jnp.int32), ((B,), jnp.int32)] + (
         [((B, table // group), jnp.int32)] if flags_in else [])
+
+
+# deepseek-v3.2.decode-sparse-16k: 36 sequences, the pool and the table the
+# bench builds (40384 pages of 16 tokens, 1280 entries a row)
+DSA_B, DSA_PAGES, DSA_TABLE = 36, 40384, 1280
+
+
+def _dsa_scan():
+    """The indexer's scan: 64 index heads of 128 over bf16 index pages of
+    16 x 128, blocks of 128 pages copied 64 at a time (the run flags an
+    operand, as the runner's step passes them)."""
+    from paddle_tpu.ops.pallas import sparse_latent_attention as sla
+
+    ipool = jax.ShapeDtypeStruct((DSA_PAGES, PAGE, 128), jnp.bfloat16)
+    assert sla.scan_shape(ipool) == (128, 64)
+
+    def fn(q_i, w_i, ipool, table, pos, runs):
+        return sla.paged_index_scores(q_i, w_i, ipool, table, pos,
+                                      interpret=False, runs=runs)
+
+    return fn, [((DSA_B, 64, 128), jnp.bfloat16), ((DSA_B, 64), jnp.float32),
+                (ipool.shape, ipool.dtype), ((DSA_B, DSA_TABLE), jnp.int32),
+                ((DSA_B,), jnp.int32), ((DSA_B, DSA_TABLE // 64), jnp.int32)]
+
+
+def _dsa_attend():
+    """Attention over the selection at 128 heads: the latent kernel's walk
+    with every block folded under the sequence's row of 20480 scores."""
+    from paddle_tpu.ops.pallas import latent_paged_attention as lpa
+
+    def walk(q, pool, table, pos, runs, scores, value, last):
+        return lpa.latent_paged_attention(
+            q, pool, table, pos, v_lanes=512, scale=0.1, interpret=False,
+            runs=runs, select=(scores, value, last))
+
+    return walk, [((DSA_B, 128, 640), jnp.bfloat16),
+                  ((DSA_PAGES, PAGE, 640), jnp.bfloat16),
+                  ((DSA_B, DSA_TABLE), jnp.int32), ((DSA_B,), jnp.int32),
+                  ((DSA_B, DSA_TABLE // 16), jnp.int32),
+                  ((DSA_B, DSA_TABLE * PAGE), jnp.float32),
+                  ((DSA_B,), jnp.float32), ((DSA_B,), jnp.int32)]
+
+
+def _dsa_layer(monkeypatch):
+    """A sparse layer's whole decode attention as the runner's step calls
+    it on a TPU: both page writes, the scan, the selection's two searches
+    (no sort), the walk under the selection."""
+    from paddle_tpu.serving.model_runner import paged_attend
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+    def fn(q, latent, q_i, k_i, w_i, pool, ipool, table, page, off, pos,
+           scan_runs, walk_runs):
+        out, _ = paged_attend(
+            q, latent, None, (pool, ipool), table, page, off, pos,
+            jnp.ones_like(pos), 1, "ragged", scale=0.1, v_lanes=512,
+            runs=(scan_runs, walk_runs), kind="latent+index",
+            index=(q_i, k_i, w_i), topk=2048)
+        return out
+
+    bf16, i32 = jnp.bfloat16, jnp.int32
+    return fn, [((DSA_B, 1, 128, 640), bf16), ((DSA_B, 1, 640), bf16),
+                ((DSA_B, 1, 64, 128), bf16), ((DSA_B, 1, 128), bf16),
+                ((DSA_B, 1, 64), jnp.float32),
+                ((DSA_PAGES, PAGE, 640), bf16), ((DSA_PAGES, PAGE, 128), bf16),
+                ((DSA_B, DSA_TABLE), i32), ((DSA_B, 1), i32),
+                ((DSA_B, 1), i32), ((DSA_B,), i32),
+                ((DSA_B, DSA_TABLE // 64), i32),
+                ((DSA_B, DSA_TABLE // 16), i32)]
 
 
 def _delta_decode(B, H=30, d_k=96, d_v=192):
@@ -272,6 +343,11 @@ CASES = {
     "latent-kimi-decode-b1": lambda mp: _latent(1),
     "latent-kimi-decode-b48-flags-inside": lambda mp: _latent(
         48, flags_in=False),
+    # the sparse cell: the indexer's scan, the walk over every live page
+    # under the selection, and a layer's attention whole
+    "dsa-scan-b36": lambda mp: _dsa_scan(),
+    "dsa-masked-walk-b36": lambda mp: _dsa_attend(),
+    "dsa-layer-b36": _dsa_layer,
     # the hybrid cell: the delta rule's decode update, and the ragged
     # kernel at 32 allocated heads (a decode step, a span's piece)
     "delta-decode-b64": lambda mp: _delta_decode(64),
