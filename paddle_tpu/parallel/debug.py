@@ -11,6 +11,7 @@ in their `dp` coordinate alone is a collective over `dp`.
 
 from __future__ import annotations
 
+import math
 import re
 from typing import List, Tuple
 
@@ -29,6 +30,10 @@ _LISTED = re.compile(r"(?:replica_groups|source_target_pairs)=\{([0-9,{} ]*)\}")
 _IOTA = re.compile(
     r"replica_groups=\[(\d+),(\d+)\]<=\[([0-9,]+)\](?:T\(([0-9,]+)\))?")
 _CHANNEL = re.compile(r"channel_id=(\d+)")
+_OP_NAME = re.compile(r'op_name="([^"]*)"')
+# computations that are a fusion's body: a collective printed inside one is
+# a piece of an asynchronous collective, never an op the program waits at
+_FUSIONS = ("fused_computation", "async_collective_fusion")
 
 
 def _ints(text: str) -> List[int]:
@@ -60,6 +65,44 @@ def _axes(groups: List[List[int]], mesh) -> Tuple[str, ...]:
     return tuple(n for n, v in zip(mesh.axis_names, varies) if v)
 
 
+def _walk(compiled_text: str, mesh):
+    """(op, mesh axes, [(dtype, shape)], phase, form) of every collective
+    in the text, once each."""
+    n = int(mesh.devices.size)
+    found, where = [], {}
+    fused = False       # inside a fusion's computation, not the program's
+    for line in compiled_text.splitlines():
+        if line[:1] not in " }":            # a computation's header
+            fused = line.lstrip("%").startswith(_FUSIONS)
+            continue
+        m = _LINE.search(line)
+        if not m:
+            continue
+        # the TPU compiler prints one collective once in each computation of
+        # its asynchronous form (start, steps, done): same channel, one op
+        channel = _CHANNEL.search(line)
+        key = channel.group(1) if channel else len(found)
+        asynchronous = bool(m.group("start")) or fused
+        if key in where:
+            if asynchronous:
+                found[where[key]][4] = "async"
+            continue
+        where[key] = len(found)
+        arrays = [(d, tuple(_ints(s))) for d, s in _ARRAY.findall(
+            m.group("result"))]
+        if m.group("start") and m.group("op") != "all-reduce":
+            # a start yields (operands..., results..., context scalars)
+            arrays = [a for a in arrays if a[1] or a[0] not in ("u32", "s32")]
+            arrays = arrays[len(arrays) // 2:]
+        name = _OP_NAME.search(line)
+        phase = "" if not name else (
+            "backward" if "transpose(" in name.group(1) else
+            "forward" if "jvp(" in name.group(1) else "")
+        found.append([m.group("op"), _axes(_groups(line, n), mesh), arrays,
+                      phase, "async" if asynchronous else "sync"])
+    return found
+
+
 def collectives(compiled_text: str, mesh):
     """[(op, mesh axes, dtype, shape)] for every collective in the text of a
     compiled (partitioned) program, one entry per array it yields.
@@ -68,26 +111,27 @@ def collectives(compiled_text: str, mesh):
     for one whose groups hold a single device; `shape` is the per-device
     shape of the result (of an async pair, the result its `-done` hands on).
     """
-    n = int(mesh.devices.size)
-    found, seen = [], set()
-    for line in compiled_text.splitlines():
-        m = _LINE.search(line)
-        if not m:
-            continue
-        # the TPU compiler prints one collective once in each computation of
-        # its asynchronous form (start, steps, done): same channel, one op
-        channel = _CHANNEL.search(line)
-        if channel:
-            if channel.group(1) in seen:
-                continue
-            seen.add(channel.group(1))
-        arrays = [(d, tuple(_ints(s))) for d, s in _ARRAY.findall(
-            m.group("result"))]
-        if m.group("start") and m.group("op") != "all-reduce":
-            # a start yields (operands..., results..., context scalars)
-            arrays = [a for a in arrays if a[1] or a[0] not in ("u32", "s32")]
-            arrays = arrays[len(arrays) // 2:]
-        axes = _axes(_groups(line, n), mesh)
-        found.extend((m.group("op"), axes, dtype, shape)
-                     for dtype, shape in arrays)
-    return found
+    return [(op, axes, dtype, shape)
+            for op, axes, arrays, _, _ in _walk(compiled_text, mesh)
+            for dtype, shape in arrays]
+
+
+def collective_forms(compiled_text: str, mesh):
+    """[(op, mesh axes, phase, form, bytes)], one entry per collective OP
+    (a tuple all-reduce of forty gradients is one).
+
+    `form` is "async" where the program can compute beside the collective:
+    a `-start` / `-done` pair, or, as the TPU compiler writes an all-reduce
+    it overlaps, a collective inside fusions' computations (the start's, the
+    done's, and an `async_collective_fusion` for each product it runs
+    under); "sync" for a plain op of the program itself, which the chip
+    waits for. `phase` is read from the op's `op_name`: "backward" under a
+    `transpose(jvp(..))`, "forward" under a `jvp(..)`, "" elsewhere.
+    `bytes` is the per-device size of what it yields.
+    """
+    def nbytes(dtype, shape):       # bf16, f32, f8e4m3fn; pred has no digits
+        bits = re.search(r"\d+", dtype)
+        return math.prod(shape) * (int(bits.group()) if bits else 8) // 8
+
+    return [(op, axes, phase, form, sum(nbytes(*a) for a in arrays))
+            for op, axes, arrays, phase, form in _walk(compiled_text, mesh)]

@@ -80,3 +80,47 @@ def test_the_manifest_lists_the_five_for_the_serving_cells():
     for e in mine:
         assert e["workloads"] == serving and e["better"] == "lower"
         assert e["moves"] == "serve_tokens_per_s"
+
+
+def test_collective_exposed_all_forms_ms_counts_the_asynchronous_forms():
+    """PR 45's reader beside the accepted one on one hand-made table: a
+    step with a synchronous all-reduce (100 ns, 50 of them under the
+    fusion), an `async-collective-start` nothing runs beside (30 ns) and an
+    `async-collective-done` that outlasts the fusion by 40 ns. The accepted
+    reader sees the first alone; without asynchronous forms the two agree,
+    and neither reads anything on one chip."""
+    import test_trace_reduce as T
+
+    both = [T.reader("collective_exposed_ms"),
+            T.reader("collective_exposed_all_forms_ms")]
+
+    def ctx_with(per_step):
+        table = T.stepped(whole=3, head=500, tail=500)
+        table.ops[0] += [(name, k * T.PERIOD + at, dur)
+                         for k in range(-1, 3) for name, at, dur in per_step]
+        table.ops[1] = list(table.ops[0])
+        table.modules[1] = list(table.modules[0])
+        return T.train_ctx(T.tr.Trace(ops=table.ops, modules=table.modules),
+                           -400, 3400)
+
+    sync = ("all-reduce", 550, 100)
+    fusion_ends = 600 + T.REST
+    ctx = ctx_with([sync, ("async-collective-start", 20, 30),
+                    ("async-collective-done", fusion_ends - 20, 60)])
+    assert [read(ctx) for read in both] == [
+        pytest.approx(50e-6), pytest.approx((50 + 30 + 40) * 1e-6)]
+    plain = ctx_with([sync])
+    assert both[0](plain) == both[1](plain) == pytest.approx(50e-6)
+    one_chip = T.train_ctx(T.stepped(3, 500, 500), -400, 3400)
+    assert [read(one_chip) for read in both] == [None, None]
+
+
+def test_the_manifest_lists_the_all_forms_reader_for_the_four_chip_cell():
+    import run as R
+
+    m = R.load_json(R.ROOT, "BENCHMARK.json")
+    by_name = {e["name"]: e for e in m["per_layer"]}
+    mine = by_name["collective_exposed_all_forms_ms"]
+    accepted = by_name["collective_exposed_ms"]
+    assert {k: mine[k] for k in mine if k != "name"} == \
+        {k: accepted[k] for k in accepted if k != "name"}

@@ -479,3 +479,118 @@ def test_ragged_compiles_per_shard_under_a_model4_mesh(v5e):
             for (s, d), sp in zip(shapes, specs)]
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def _toy_train_step(tensor_parallel, hidden=128):
+    """A `jit.TrainStep` of a two-layer GPT `hidden` wide, built with no
+    mesh installed (nothing can be put on a described device), and the
+    shapes of what its step takes."""
+    import paddle_tpu as paddle
+    from paddle_tpu.core.random import default_generator
+    from paddle_tpu.models.gpt import GPT, GPTConfig, gpt_loss_fn
+
+    paddle.seed(0)
+    model = GPT(GPTConfig(vocab_size=256, hidden_size=hidden, num_layers=2,
+                          num_heads=hidden // 128 or 4,
+                          ffn_hidden=4 * hidden, max_seq_len=128,
+                          tensor_parallel=tensor_parallel))
+    opt = paddle.optimizer.AdamW(parameters=model.parameters(),
+                                 learning_rate=1e-3)
+    step = paddle.jit.TrainStep(model, gpt_loss_fn, opt, amp_level="O1")
+    tokens = jax.ShapeDtypeStruct((BATCH, 128), jnp.int32)
+    scalars = (default_generator.next_key(), jnp.float32(0), jnp.int32(0))
+    return step, scalars, tokens
+
+
+def _placed(tree, sharding_of):
+    """`tree` as `ShapeDtypeStruct`s, each leaf placed by
+    `sharding_of(path, leaf)`."""
+    return jax.tree_util.tree_map_with_path(
+        lambda path, v: jax.ShapeDtypeStruct(
+            v.shape, v.dtype, sharding=sharding_of(path, v)), tree)
+
+
+def test_train_step_on_a_dp2_tp2_mesh_overlaps_its_all_reduces(v5e):
+    """The step `TrainStep._build` makes for a two-layer tensor-parallel
+    GPT, compiled for the described 2 x 2 with the options `TrainStep`
+    passes for a mesh of TPU devices: what PR 45 reached, by family."""
+    import collections
+
+    import numpy as np
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from paddle_tpu import parallel as dist
+    from paddle_tpu.jit import api
+    from paddle_tpu.parallel.debug import collective_forms, collectives
+    from paddle_tpu.parallel.mesh import program_mesh_scope
+
+    step, scalars, tokens = _toy_train_step(tensor_parallel=True,
+                                            hidden=1024)
+    mesh = dist.init_mesh({"dp": 2, "tp": 2}, devices=v5e)
+    try:
+        assert api._mesh_compiler_options(mesh) == api._MESH_COMPILER_OPTIONS
+        step._build()
+        specs = step.func.param_shardings()
+
+        def of_param(path, v):      # a moment lies where its parameter does
+            spec = specs.get(path[0].key) if v.ndim else None
+            return NamedSharding(mesh, spec or P())
+
+        rep = lambda path, v: NamedSharding(mesh, P())
+        batch = jax.ShapeDtypeStruct(tokens.shape, tokens.dtype,
+                                     sharding=NamedSharding(mesh, P("dp")))
+        args = (_placed(step.params, of_param), _placed(step.buffers, rep),
+                _placed(step.opt_state, of_param), *_placed(scalars, rep),
+                (batch, batch))
+        plain = jax.jit(step._compiled.__wrapped__, donate_argnums=(0, 1, 2))
+        with program_mesh_scope(mesh):
+            with_options, without = (
+                f.lower(*args).compile().as_text()
+                for f in (step._compiled, plain))
+    finally:
+        dist.set_mesh(None)
+
+    def forms(text):
+        return collections.Counter(
+            (axes, phase, form)
+            for op, axes, phase, form, nbytes in collective_forms(text, mesh)
+            if op == "all-reduce" and nbytes >= 4096)
+
+    before, after = forms(without), forms(with_options)
+    # the same collectives over the same axes, in the same types and sizes:
+    # only when they run differs
+    assert collections.Counter(collectives(without, mesh)) == \
+        collections.Counter(collectives(with_options, mesh))
+    # left alone the compiler overlaps no all-reduce
+    assert not [k for k in before if k[2] == "async"], before
+    # tp's backward all-reduces (one a column-parallel product, two a
+    # layer, and the head's) run under a `dw` product where one is left to
+    # run under: 2 of these two layers' 5, 38 of the cell's 49
+    assert after[("tp",), "backward", "async"] >= 2
+    assert sum(after[("tp",), "backward", f] for f in ("sync", "async")) \
+        == before[("tp",), "backward", "sync"]
+    # dp's gradients are left as they were: merged into a few ops behind
+    # the last layer's backward, which the chip waits at (PERF.md, PR 45:
+    # what runs them beside the backward costs the compiler too much)
+    assert after[("dp",), "backward", "sync"] >= 1
+    assert after[("dp",), "backward", "async"] <= 1
+    # the forward's still wait: the next operation needs their sums
+    assert after[("tp",), "forward", "sync"] >= 4
+
+
+def test_one_chip_train_step_is_text_for_text_the_plain_jit(v5e):
+    """Without a mesh `TrainStep` passes the compiler nothing: its program
+    is the text of a plain `jax.jit` of the same step."""
+    from paddle_tpu.jit import api
+
+    assert api._mesh_compiler_options(None) is None
+    step, scalars, tokens = _toy_train_step(tensor_parallel=False)
+    step._build()
+    one_chip = SingleDeviceSharding(v5e[0])
+    args = _placed((step.params, step.buffers, step.opt_state, *scalars,
+                    (tokens, tokens)), lambda path, v: one_chip)
+    plain = jax.jit(step._compiled.__wrapped__, donate_argnums=(0, 1, 2))
+    texts = [f.lower(*args).compile().as_text()
+             for f in (step._compiled, plain)]
+    assert "all-reduce" not in texts[0]
+    assert texts[0] == texts[1]

@@ -9,6 +9,7 @@ These tests read the partitioned HLO (`parallel.debug.collectives`).
 """
 
 import contextlib
+import hashlib
 import math
 import re
 
@@ -23,7 +24,7 @@ from paddle_tpu import parallel as dist
 from paddle_tpu.models.gpt import GPT, GPTConfig, gpt_loss_fn
 from paddle_tpu.models.llama import Llama, LlamaConfig, llama_loss_fn
 from paddle_tpu.parallel.api import static_trace
-from paddle_tpu.parallel.debug import collectives
+from paddle_tpu.parallel.debug import collective_forms, collectives
 from paddle_tpu.parallel.mesh import program_mesh_scope
 
 B, S, VOCAB = 8, 80, 256            # B x S = 640 is no width of the models
@@ -31,16 +32,20 @@ WIDTHS = dict(vocab_size=VOCAB, hidden_size=128, num_layers=2, num_heads=4,
               ffn_hidden=512, max_seq_len=S, dropout=0.0)
 
 # name -> (model, loss, the three losses the parent's program gave: seed 0,
-# AdamW 1e-3, O1, tokens = labels = default_rng(0).integers(0, 256, (8, 80)))
+# AdamW 1e-3, O1, tokens = labels = default_rng(0).integers(0, 256, (8, 80));
+# a digest of the parameters those three steps left, bit for bit: PR 45's
+# parent, e2e7483. Both guard the CPU's path alone, where `TrainStep` hands
+# the compiler no option: what the TPU's options do to the sums is read on
+# the chip, by the four-chip cell's `correct`)
 PARENT_GPT = [4.5, 4.0, 3.671875]
 CASES = {
     "gpt": (lambda: GPT(GPTConfig(**WIDTHS, tensor_parallel=True)),
-            gpt_loss_fn, PARENT_GPT),
+            gpt_loss_fn, PARENT_GPT, "4d9541a82260e7f1"),
     "gpt-sp": (lambda: GPT(GPTConfig(**WIDTHS, tensor_parallel=True,
                                      sequence_parallel=True)),
-               gpt_loss_fn, PARENT_GPT),
+               gpt_loss_fn, PARENT_GPT, "ea63bf284a4a4d9b"),
     "llama": (lambda: Llama(LlamaConfig(**WIDTHS, tensor_parallel=True)),
-              llama_loss_fn, [5.5625, 5.0625, 4.59375]),
+              llama_loss_fn, [5.5625, 5.0625, 4.59375], "2c2148d094e64512"),
 }
 
 
@@ -55,8 +60,17 @@ def _installed(axes):
         dist.set_mesh(None)
 
 
+def _digest(params) -> str:
+    h = hashlib.sha256()
+    for name in sorted(params):
+        h.update(name.encode())
+        h.update(np.asarray(params[name]).tobytes())
+    return h.hexdigest()[:16]
+
+
 def _train(build, loss_fn, mesh_axes):
-    """Three O1 steps; (losses, mesh, text of the compiled step)."""
+    """Three O1 steps; (losses, mesh, text of the compiled step, digest of
+    the parameters)."""
     with _installed(mesh_axes) as mesh:
         paddle.seed(0)
         model = build()
@@ -71,13 +85,13 @@ def _train(build, loss_fn, mesh_axes):
             text = step._compiled.lower(
                 step.params, step.buffers, step.opt_state,
                 *args).compile().as_text()
-        return losses, mesh, text
+        return losses, mesh, text, _digest(step.params)
 
 
 @pytest.mark.parametrize("case", list(CASES))
 def test_train_step_keeps_the_batch_on_dp(case):
-    build, loss_fn, parent_losses = CASES[case]
-    losses, mesh, text = _train(build, loss_fn, {"dp": 2, "tp": 2})
+    build, loss_fn, parent_losses, parent_params = CASES[case]
+    losses, mesh, text, params = _train(build, loss_fn, {"dp": 2, "tp": 2})
     found = collectives(text, mesh)
     assert any(op == "all-reduce" and axes == ("tp",) for op, axes, _, _
                in found), "no tp all-reduce found: is this the tp program?"
@@ -98,7 +112,8 @@ def test_train_step_keeps_the_batch_on_dp(case):
     assert dots and not [d for d in dots if d[0] in (B * S, B)], dots
 
     assert losses == parent_losses
-    one_device, _, _ = _train(build, loss_fn, None)
+    assert params == parent_params
+    one_device, _, _, _ = _train(build, loss_fn, None)
     # O1 hands back a bfloat16 loss: one step of it at 4..8 is 2 ** -5
     np.testing.assert_allclose(losses, one_device, atol=2 ** -5, rtol=0)
 
@@ -125,6 +140,96 @@ def test_collectives_reads_groups_against_the_mesh():
         ("all-gather", ("tp",), "f32", (8, 8)),
         ("collective-permute", ("dp", "tp"), "f32", (4, 8)),
     ]
+
+
+def test_collective_forms_tells_a_fused_all_reduce_from_a_plain_one():
+    """Lines as the TPU compiler wrote them for the toy step on a described
+    v5e 2x2 (cut to what is read): an all-reduce it overlaps is printed in
+    its start's and its done's fused computations and in an
+    `async_collective_fusion` a product it runs under, all on one channel;
+    one it waits at is an op of the program itself."""
+    mesh = jax.sharding.Mesh(
+        np.array(jax.devices()[:4]).reshape(2, 2), ("dp", "tp"))
+    meta = 'metadata={op_name="jit(step)/loss/%s/mlp/jit(f)/dot_general"}'
+    bwd, fwd = meta % "transpose(jvp(block))", meta % "jvp(block)"
+    text = f"""HloModule jit_step, is_scheduled=true
+
+%fused_computation.501 (param_0.1: bf16[4,80,128]) -> bf16[4,80,128] {{
+  %all-reduce.125 = bf16[4,80,128]{{2,1,0}} all-reduce(%param_0.1), channel_id=22, replica_groups=[2,2]<=[4], to_apply=%add, {bwd}
+}}
+
+%async_collective_fusion.370 (param_0.2: bf16[4,80,128]) -> (bf16[128,256], bf16[4,80,128]) {{
+  %all-reduce.127 = bf16[4,80,128]{{2,1,0}} all-reduce(%param_0.2), channel_id=22, replica_groups=[2,2]<=[4], to_apply=%add, {bwd}
+}}
+
+%fused_computation.505 (param_0.3: bf16[4,80,128]) -> bf16[4,80,128] {{
+  %all-reduce.133 = bf16[4,80,128]{{2,1,0}} all-reduce(%param_0.3), channel_id=22, replica_groups=[2,2]<=[4], to_apply=%add, {bwd}
+}}
+
+ENTRY %main.32_spmd (param.35: f32[128]) -> f32[128] {{
+  %all-reduce.147 = bf16[4,80,128]{{2,1,0}} all-reduce(%fusion.209), channel_id=9, replica_groups=[2,2]<=[4], frontend_attributes={{async_collective_name="all-reduce-start.1"}}, to_apply=%add, {fwd}
+  %async-collective-start = (bf16[4,80,128]{{2,1,0}}, bf16[4,80,128]{{2,1,0}}) fusion(%fusion.155), kind=kCustom, calls=%fused_computation.501
+  %fusion.370 = (bf16[128,256]{{1,0}}, bf16[4,80,128]{{2,1,0}}) fusion(%gte.380), kind=kOutput, calls=%async_collective_fusion.370
+  %async-collective-done = bf16[4,80,128]{{2,1,0}} fusion(%gte.424), kind=kCustom, calls=%fused_computation.505
+  %all-reduce.156 = (bf16[64,128]{{1,0}}, f32[128]{{0}}) all-reduce(%a, %b), channel_id=25, replica_groups=[2,2]<=[2,2]T(1,0), to_apply=%add, {bwd}
+  %cps = (bf16[4,80,64]{{2,1,0}}, bf16[4,80,64]{{2,1,0}}, u32[], u32[]) collective-permute-start(%c), channel_id=7, source_target_pairs={{{{0,1}},{{1,0}},{{2,3}},{{3,2}}}}
+}}
+"""
+    assert collective_forms(text, mesh) == [
+        ("all-reduce", ("tp",), "backward", "async", 4 * 80 * 128 * 2),
+        ("all-reduce", ("tp",), "forward", "sync", 4 * 80 * 128 * 2),
+        ("all-reduce", ("dp",), "backward", "sync", 64 * 128 * 2 + 128 * 4),
+        ("collective-permute", ("tp",), "", "async", 4 * 80 * 64 * 2),
+    ]
+    # one entry an array, whatever the form: what `collectives` always gave
+    assert [c[0] for c in collectives(text, mesh)] == [
+        "all-reduce"] * 4 + ["collective-permute"]
+
+
+def test_train_step_on_the_cpu_passes_no_compiler_option(monkeypatch):
+    """The options are the TPU compiler's: a mesh of CPU devices (these
+    tests' eight) gets none, nor does a step without a mesh or a mesh of
+    one chip; a mesh of more than one TPU device gets the constant."""
+    from paddle_tpu.jit import api
+
+    seen = []
+    real_jit = jax.jit
+    monkeypatch.setattr(jax, "jit", lambda f, **kw: (
+        seen.append(kw.get("compiler_options")), real_jit(f, **kw))[1])
+    _train(CASES["gpt"][0], gpt_loss_fn, {"dp": 2, "tp": 2})
+    assert seen and all(opts is None for opts in seen)
+
+    class Chip:
+        platform = "tpu"
+
+    def mesh_of(n):
+        return type("M", (), {"devices": np.array(
+            [Chip()] * n, object).reshape(n // 2 or 1, -1)})()
+
+    assert api._mesh_compiler_options(None) is None
+    assert api._mesh_compiler_options(mesh_of(1)) is None
+    assert api._mesh_compiler_options(mesh_of(4)) == \
+        api._MESH_COMPILER_OPTIONS
+    assert all(k.startswith("xla_") for k in api._MESH_COMPILER_OPTIONS)
+
+
+def test_collective_forms_counts_the_cpu_step_by_family():
+    """`collective_forms` on the step the CPU's compiler made: ops by mesh
+    axes, phase and form, one an op. The CPU overlaps no all-reduce, so
+    every one is an op its program waits at; what `TrainStep` reaches on a
+    mesh of TPU devices is `tests/test_chip_compile.py`'s to say."""
+    import collections
+
+    _, mesh, text, _ = _train(CASES["gpt"][0], gpt_loss_fn,
+                              {"dp": 2, "tp": 2})
+    counts = collections.Counter(
+        (axes, phase, form)
+        for op, axes, phase, form, _ in collective_forms(text, mesh)
+        if op == "all-reduce")
+    assert counts[("tp",), "forward", "sync"] >= 4       # two a layer
+    assert counts[("tp",), "backward", "sync"] >= 4
+    assert counts[("dp",), "backward", "sync"] >= 1
+    assert not [k for k in counts if k[2] == "async"]
 
 
 # ---- each layer alone, inside jit: what it does to a dp-sharded batch
