@@ -12,27 +12,51 @@ each, whatever they compute), so a head takes a handful of them:
     below that, the block table's granularity where one is given) and a
     SPAN: how many rows of the walked operand one grid step holds in VMEM
     (the whole sequence while it fits VMEM_BUDGET);
-  * forward and dQ run on grid (batch * head blocks, sq / block_q,
+  * the forward runs on grid (batch * head blocks, sq / block_q,
     sk / span): K and V of the span are VMEM-resident and the walk over
     their tiles is a loop INSIDE the kernel, bounded by the causal diagonal,
-    so no step and no fetch is spent above it; dK/dV runs on (batch * head
-    blocks, sk / block_k, sq / span) and walks Q / dO tiles from the
-    diagonal on. Where a
-    sequence outgrows one span the third axis has several steps, the
-    accumulators ride VMEM scratch across them, and the index map of a
-    span wholly above the diagonal clamps to the last live one (a dead step
-    copies nothing);
+    so no step and no fetch is spent above it. Where a sequence outgrows
+    one span the third axis has several steps, the accumulators ride VMEM
+    scratch across them, and the index map of a span wholly above the
+    diagonal clamps to the last live one (a dead step copies nothing);
+  * the backward is a small pre-pass and ONE kernel (`flash_bwd_delta`,
+    `flash_bwd`) wherever `schedule()` says dQ's accumulator fits
+    (`Schedule.fused_backward`, from the shapes alone): grid (batch * head
+    blocks, k steps, q spans), on TRANSPOSED score tiles (keys on rows,
+    queries on lanes, so the per-query statistics are read as the
+    lane-dense rows they are stored as). For each k tile and each live q
+    tile of the span, S^T = K Q^T and P^T = exp(S^T - lse) are taken ONCE,
+    then dV += P^T dO, dS^T = P^T (V dO^T - delta), dK += dS^T Q and
+    dQ[q tile] += dS K: five matmuls and one exponent a tile, where two
+    kernels that each recompute P take seven and two. dQ's float32
+    accumulator holds ALL of sq in VMEM across the k steps, which run in
+    order (k tile 0 first: the order the dQ kernel sums in), and each q
+    span's rows go out, scaled and cast, with the last k step, written
+    once. Where a head block has few tiles (ONE_STEP_TILES) and all of
+    its sq and sk fit the chip's default VMEM, they are ONE grid step of
+    straight-line code, nothing rides scratch across steps, and a tile the
+    diagonal crosses is folded in strips of 128 queries, each against the
+    keys it can see; otherwise a k tile a step beside the longest q span
+    that fits (all of sq at ZAYA's 8192 keys, so that q and dO are fetched
+    once a head block and not again for every k tile), with
+    FUSED_VMEM_LIMIT asked of the compiler where the count passes
+    VMEM_BUDGET;
+  * where that accumulator would take more than half of the fused
+    kernel's VMEM (32768 queries of 128 lanes are 16 MiB) the backward is
+    two kernels, each recomputing P from lse:
+    `flash_bwd_dq` on the forward's grid (it takes delta from its O and dO
+    blocks and hands it on) and `flash_bwd_dkv` on (batch * head blocks,
+    sk / block_k, sq / span), walking Q / dO tiles from the diagonal on;
   * the MXU gets the operands' own dtype (bfloat16 under autocast O1,
     float32 where the caller gave float32) with float32 products; P and dS
     are cast to it for the second matmul of each pair. Scores, running max
-    and sum, lse, delta and the accumulators are float32;
-  * dK/dV works on TRANSPOSED score tiles (keys on rows, queries on lanes):
-    every matmul of it is then plain or transposed-right, and the per-query
-    statistics are read as the lane-dense rows they are stored as.
+    and sum, lse, delta and the accumulators are float32.
 
 The attention matrix never exists in HBM; per-row statistics (lse, delta)
 are [batch*head, 1, sq] float32, whole 128-lane rows: lse an output of the
-forward, delta = rowsum(dO * O) of the dQ kernel, which has both blocks.
+forward, delta = rowsum(dO * O) of the pre-pass, which reads O and dO once.
+`profiler.traced_counts()` says which backward a program's trace took
+(`flash_bwd_fused` / `flash_bwd_two_kernels`, a Python count a compile).
 
 Masking (four independent mechanisms, composable with `causal`):
   * additive mask — an fp32 [b, 1|h, sq, sk] bias streamed a (block_q,
@@ -53,9 +77,9 @@ Fully-masked rows are well-defined: the online-softmax guard zeroes
 probabilities where the score is hard-masked, so such rows produce 0
 output and 0 gradient instead of NaN.
 
-Forward and backward are Pallas kernels (FlashAttention-2 style backward:
-a dQ kernel accumulating over K tiles and a dK/dV kernel accumulating over
-Q tiles, both recomputing P from the saved per-row log-sum-exp).
+Forward and backward are Pallas kernels; the backward recomputes P from
+the saved per-row log-sum-exp (once a tile where it is fused,
+FlashAttention-2's two walks where it is not).
 
 Layout: [batch, seq, heads, head_dim] (paddle flash-attn convention), and
 the kernels read q, k, v, o, dO and write o, dq, dk, dv in that memory, as
@@ -65,7 +89,7 @@ the kernels read q, k, v, o, dO and write o, dq, dk, dv in that memory, as
 calls. `Schedule.heads_per_block` says which layout a call runs, from its
 shapes alone:
   * d a multiple of 128: one head a block. k and v may then have h / rep
-    heads; forward and dQ read head block `head // rep` where it lies, and
+    heads; the kernels read head block `head // rep` where it lies, and
     dK / dV come out a QUERY head and are summed over each group outside;
   * d a divisor of 128 and h a multiple of 128 // d: that many heads share
     a block, and a grid step takes them in a static loop. The MXU
@@ -117,6 +141,18 @@ TILE_CAP = 512
 # schedule()'s own count (blocks twice for the pipeline, scratch, a few
 # score tiles of temporaries)
 VMEM_BUDGET = 12 * 2 ** 20
+# the fused backward keeps dQ's float32 accumulator for all of sq beside
+# its blocks: where that outgrows VMEM_BUDGET it asks the compiler for
+# FUSED_VMEM_LIMIT of the chip's 128 MiB and counts against FUSED_VMEM_BUDGET
+FUSED_VMEM_LIMIT = 28 * 2 ** 20
+FUSED_VMEM_BUDGET = 24 * 2 ** 20
+# rows of O and dO a grid step of the delta pre-pass reads (the sequence
+# where it is no longer): the pass is as fast as it reads
+DELTA_ROWS = 2048
+# score tiles of a head block that the fused backward takes as ONE grid step
+# of straight-line code (each tile's code is written out: the compiler then
+# runs one tile's matmuls beside another's softmax)
+ONE_STEP_TILES = 8
 LANES = 128
 
 
@@ -130,10 +166,12 @@ def _tile(n: int, cap: int = TILE_CAP):
 
 
 class Schedule(NamedTuple):
-    """What one call of the three kernels runs. `steps`, `tiles` and
-    `dead_steps` are per kernel, (fwd, bwd_dq, bwd_dkv): grid steps, score
-    tiles folded (causal geometry; a block table may skip more), and grid
-    steps whose span lies wholly above the diagonal (they copy and fold
+    """What one call and its gradient run. `steps`, `tiles` and
+    `dead_steps` are per kernel, in the order the kernels run: (fwd, delta,
+    bwd) where the backward is fused, (fwd, bwd_dq, bwd_dkv) where it is
+    two kernels: grid steps, score tiles folded (causal geometry; a block
+    table may skip more; the delta pre-pass folds none), and grid steps
+    whose span lies wholly above the diagonal (they copy and fold
     nothing)."""
     block_q: int
     block_k: int
@@ -146,11 +184,24 @@ class Schedule(NamedTuple):
     # caller's own memory, [b, s, h*d]; 0: they run on flat [b*h, s, d]
     # copies (a head does not fill whole 128-lane columns)
     heads_per_block: int = 0
+    # rows of q / dO and of k / v one grid step of the fused backward holds
+    # beside dQ's accumulator for all of sq; 0: they do not fit and the
+    # backward is two kernels
+    bwd_span_q: int = 0
+    bwd_span_k: int = 0
+    # the scoped VMEM the fused backward asks the compiler for; None: the
+    # chip's default holds it
+    bwd_vmem_limit: int | None = None
 
     @property
     def heads(self) -> int:
         """Heads one grid step takes."""
         return max(self.heads_per_block, 1)
+
+    @property
+    def fused_backward(self) -> bool:
+        """Whether dQ, dK and dV come from one pass over the score tiles."""
+        return self.bwd_span_q > 0
 
 
 def _heads_per_block(h: int, hk: int, d: int) -> int:
@@ -183,13 +234,37 @@ def _vmem_bytes(tile_rows: int, walked_rows: int, block_q: int, block_k: int,
     return blocks + scratch + slab + alone + 5 * block_q * block_k * 4
 
 
+def _fused_vmem_bytes(q_rows: int, k_rows: int, sq: int, block_q: int,
+                      block_k: int, d: int, itemsize: int, mask: int,
+                      heads: int = 1) -> int:
+    """Scoped VMEM of one grid step of the fused backward: `k_rows` of k,
+    v, dk and dv and `q_rows` of q, dO and dq, each block twice for the
+    pipeline; lse's and delta's rows (a sublane tile a head); the float32
+    accumulators, dK's and dV's and dQ's for ALL of sq; a dense mask's
+    slab; each head's lanes of a k and a v tile where heads share a block;
+    four score tiles of float32 temporaries (the compiler's own count for
+    a described v5e comes out 1 to 5 MiB under this one at the three
+    training cells' shapes: 7.25 of 9.75 at gpt2's, 20.25 of 22.5 at
+    ZAYA's)."""
+    dl = -(-d // LANES) * LANES
+    blocks = 2 * (4 * k_rows + 3 * q_rows) * dl * itemsize
+    stats = 2 * 2 * heads * 8 * q_rows * 4
+    scratch = (2 * k_rows + sq) * dl * 4
+    slab = 2 * mask * q_rows * k_rows * 4
+    alone = 2 * heads * block_k * dl * itemsize if heads > 1 else 0
+    return (blocks + stats + scratch + slab + alone
+            + 4 * block_q * block_k * 4)
+
+
 def schedule(q_shape, k_shape, dtype, causal: bool, *, mask: int = 0,
-             block_mask_shape=None, block_q=None, block_k=None, span=None):
-    """The tiles, grids and layout of one call, a pure function of what the
-    call can see: q [b, sq, h, d], k [b, sk, hk, d], the operand dtype, the
-    heads of a dense additive mask that streams (0: none, 1: one for all),
-    a block table's shape. `block_q`, `block_k` and `span` force a choice
-    (tests at toy sizes only). None where the shapes do not tile."""
+             block_mask_shape=None, block_q=None, block_k=None, span=None,
+             fused: bool = True):
+    """The tiles, grids and layout of one call, and which backward it
+    takes: a pure function of what the call can see: q [b, sq, h, d], k [b,
+    sk, hk, d], the operand dtype, the heads of a dense additive mask that
+    streams (0: none, 1: one for all), a block table's shape. `block_q`,
+    `block_k`, `span` and `fused=False` force a choice (tests at toy sizes
+    only). None where the shapes do not tile."""
     b, sq, h, d = q_shape
     sk = k_shape[1]
     hp = _heads_per_block(h, k_shape[2], d)
@@ -223,7 +298,8 @@ def schedule(q_shape, k_shape, dtype, causal: bool, *, mask: int = 0,
             return None
         return schedule(q_shape, k_shape, dtype, causal, mask=mask,
                         block_q=_tile(sq, max(LANES, block_q // 2)),
-                        block_k=_tile(sk, max(LANES, block_k // 2)))
+                        block_k=_tile(sk, max(LANES, block_k // 2)),
+                        fused=fused)
     if nq % tq or nk % tk:
         return None
     off = sk - sq
@@ -238,9 +314,43 @@ def schedule(q_shape, k_shape, dtype, causal: bool, *, mask: int = 0,
                  for f in first)
     blocks = b * h // max(hp, 1)            # the grid's first axis
     walk_q, walk_k = blocks * nq * (nk // tk), blocks * nk * (nq // tq)
-    return Schedule(block_q, block_k, tq * block_q, tk * block_k,
-                    (walk_q, walk_q, walk_k), (b * h * sum(live),) * 3,
-                    (blocks * dead_q, blocks * dead_q, blocks * dead_k), hp)
+    two = Schedule(block_q, block_k, tq * block_q, tk * block_k,
+                   (walk_q, walk_q, walk_k), (b * h * sum(live),) * 3,
+                   (blocks * dead_q, blocks * dead_q, blocks * dead_k), hp)
+
+    def fused_bytes(q_rows, k_rows):
+        return _fused_vmem_bytes(q_rows, k_rows, sq, block_q, block_k, d,
+                                 jnp.dtype(dtype).itemsize, slabs,
+                                 max(hp, 1))
+
+    # the fused backward: all of a head block's sq and sk in one grid step
+    # where the chip's default holds that, else a k tile a step beside the
+    # longest q span that fits
+    if span is not None:
+        fits = [(max(1, min(span, sq) // block_q) * block_q, block_k)]
+    else:
+        fits = [(t * block_q, block_k)
+                for t in range(nq, 0, -1) if nq % t == 0]
+        if nq * nk <= ONE_STEP_TILES and fused_bytes(sq, sk) <= VMEM_BUDGET:
+            fits = [(sq, sk)]
+    fits = [f for f in fits if fused_bytes(*f) <= FUSED_VMEM_BUDGET]
+    # an accumulator that leaves its steps less than half of the budget
+    # leaves them short q spans, fetched again for every k tile
+    acc = sq * -(-d // LANES) * LANES * 4
+    if not fits or not fused or 2 * acc > FUSED_VMEM_BUDGET:
+        return two
+    q_rows, k_rows = fits[0]
+    fq, fk = nq // (q_rows // block_q), sk // k_rows    # steps a head block
+    dead_f = sum((m + 1) * q_rows <= max(j * k_rows - off, 0)
+                 for j in range(fk) for m in range(fq)) if causal else 0
+    return two._replace(
+        steps=(walk_q, blocks * (sq // _tile(sq, DELTA_ROWS)),
+               blocks * fk * fq),
+        tiles=(two.tiles[0], 0, two.tiles[0]),
+        dead_steps=(blocks * dead_q, 0, blocks * dead_f),
+        bwd_span_q=q_rows, bwd_span_k=k_rows,
+        bwd_vmem_limit=None if fused_bytes(q_rows, k_rows) <= VMEM_BUDGET
+        else FUSED_VMEM_LIMIT)
 
 
 def _dot(a, b, contract):
@@ -250,6 +360,7 @@ def _dot(a, b, contract):
 
 _NT = ((1,), (1,))      # a @ b.T
 _NN = ((1,), (0,))      # a @ b
+_TN = ((0,), (0,))      # a.T @ b
 
 
 def _column(ref, j: int = 0):
@@ -340,9 +451,11 @@ def _split_refs(refs, n_lead, has_mask, has_kbias, has_seg,
 def _tile_at(t, block: int, tiles: int):
     """Rows (or lanes) of tile t of a span; a span of one tile is read
     whole, statically, so a sequence shorter than 128 needs no aligned
-    dynamic slice."""
+    dynamic slice, and so is a tile whose index is known at trace time."""
     if tiles == 1:
         return pl.ds(0, block)
+    if isinstance(t, int):
+        return pl.ds(t * block, block)
     return pl.ds(pl.multiple_of(t * block, block), block)
 
 
@@ -350,7 +463,8 @@ def _walk(phases, tile, live=None):
     """Fold this grid step's tiles: `phases` is ((lo, hi, on_diagonal),
     ...), `tile(t, on_diagonal)` folds tile t of the span (only a tile the
     diagonal crosses pays for the causal compare), `live(t)` says whether
-    a block table keeps it."""
+    a block table keeps it. Bounds known at trace time (a grid of one step
+    along the walk) give straight-line code, a tile after the other."""
     for lo, hi, on_diagonal in phases:
         def body(t, carry, on_diagonal=on_diagonal):
             if live is None:
@@ -359,7 +473,31 @@ def _walk(phases, tile, live=None):
                 pl.when(live(t))(lambda: tile(t, on_diagonal))
             return carry
 
-        jax.lax.fori_loop(lo, hi, body, None)
+        if isinstance(lo, int) and isinstance(hi, int):
+            for t in range(lo, hi):
+                body(t, None)
+        else:
+            jax.lax.fori_loop(lo, hi, body, None)
+
+
+def _when(cond, fn):
+    """`pl.when` for a condition that may be known at trace time."""
+    if isinstance(cond, bool):
+        if cond:
+            fn()
+    else:
+        pl.when(cond)(fn)
+
+
+def _clip(x, lo, hi):
+    """jnp.clip that stays a Python integer where all three are."""
+    if all(isinstance(v, int) for v in (x, lo, hi)):
+        return max(lo, min(x, hi))
+    return jnp.clip(x, lo, hi)
+
+
+def _not_below_0(x):
+    return max(x, 0) if isinstance(x, int) else jnp.maximum(x, 0)
 
 
 def _q_walk_kernel(*refs, block_k: int, heads: int, causal: bool,
@@ -562,17 +700,172 @@ def _k_walk_kernel(*refs, block_q: int, heads: int, causal: bool,
         dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
 
 
+def _delta_kernel(o_ref, do_ref, delta_ref, *, heads: int):
+    """delta_i = rowsum(dO_i * O_i) a head, lane-dense as lse is: the
+    fused backward's pre-pass over O and dO, read once. Whole 128-lane
+    tiles are transposed, so that a head's lanes are rows and their sum
+    comes out along the lanes it is stored in (a quarter of the bundles of
+    a lane reduction a row and its turn to lanes, which took 0.61 ms a
+    layer at [28, 1024, 12, 64] for 0.11 ms of reading)."""
+    rows, lanes = o_ref.shape[1:]
+    d = lanes // heads
+    o_do = o_ref[0].astype(jnp.float32) * do_ref[0].astype(jnp.float32)
+    if rows % LANES == 0 and lanes % LANES == 0:
+        by_lane = o_do.T
+        for j in range(heads):
+            delta_ref[j, 0] = jnp.sum(by_lane[j * d:(j + 1) * d], axis=0)
+        return
+    for j in range(heads):
+        delta_ref[j, 0] = jnp.sum(_only_head(o_do, j, heads, d), axis=-1)
+
+
+def _fused_kernel(*refs, block_q: int, block_k: int, steps: tuple,
+                  heads: int, causal: bool, scale: float, off: int,
+                  has_mask: bool, has_kbias: bool, has_seg: bool,
+                  has_blockmask: bool):
+    """dQ, dK and dV from ONE pass over the score tiles, transposed (keys
+    on rows, queries on lanes). For each k tile of this grid step and each
+    live q tile of its span: S^T = K Q^T, P^T = exp(S^T - lse), dV += P^T
+    dO, dS^T = P^T (V dO^T - delta), dK += dS^T Q and dQ[q tile] += dS K:
+    five matmuls and one exponent a tile. dQ's float32 accumulator holds
+    ALL of sq and rides VMEM across the k steps (that grid axis runs in
+    order, k tile 0 first); the q span's rows of it go out, scaled and
+    cast, with the last k step. `steps` is the grid's (k steps, q steps):
+    an axis of one step is index 0 at trace time, and the walk's bounds
+    with it. Where heads share a block a head's own lanes of K and V meet
+    the whole Q and dO tiles; dS K on those lanes of K lands in the head's
+    lanes of dQ by itself."""
+    q_ref, k_ref, v_ref, do_ref = refs[:4]
+    mask_ref, kbias_ref, qseg_ref, kseg_ref, bm_ref, rest = _split_refs(
+        refs, 4, has_mask, has_kbias, has_seg, has_blockmask)
+    (lse_ref, delta_ref, dq_ref, dk_ref, dv_ref,
+     dq_acc, dk_acc, dv_acc) = rest
+    q_rows, lanes = q_ref.shape[1:]
+    d = lanes // heads
+    each = range(heads)
+    k_tiles = k_ref.shape[1] // block_k        # of this step's spans
+    q_tiles = q_rows // block_q
+    k_steps, q_steps = steps
+    kj = pl.program_id(1) if k_steps > 1 else 0
+    qm = pl.program_id(2) if q_steps > 1 else 0
+    span = _tile_at(qm, q_rows, q_steps)       # this step's rows of dQ
+    guard = has_mask or has_kbias or has_seg or has_blockmask
+
+    def _init_dq():
+        dq_acc[span, :] = jnp.zeros((q_rows, lanes), jnp.float32)
+
+    def _init_dkv():
+        dk_acc[:] = jnp.zeros(dk_acc.shape, jnp.float32)
+        dv_acc[:] = jnp.zeros(dv_acc.shape, jnp.float32)
+
+    _when(kj == 0, _init_dq)
+    _when(qm == 0, _init_dkv)
+    first = qm * q_tiles
+
+    for kt in range(k_tiles):
+        rows = pl.ds(kt * block_k, block_k)
+        k_tile = [_only_head(k_ref[0, rows, :], j, heads, d) for j in each]
+        v_tile = [_only_head(v_ref[0, rows, :], j, heads, d) for j in each]
+        tile_k = kj * k_tiles + kt             # of all of sk
+        k_start = tile_k * block_k - off
+        phases = ((0, q_tiles, False),)
+        if causal:
+            # a q block contributes iff its LAST query can see this k tile;
+            # the diagonal crosses it unless its FIRST query sees the
+            # tile's last key
+            lo = _clip(_not_below_0(k_start) // block_q - first, 0, q_tiles)
+            mid = _clip((_not_below_0(k_start + block_k - 1) + block_q - 1)
+                        // block_q - first, lo, q_tiles)
+            phases = ((lo, mid, True), (mid, q_tiles, False))
+        kbias = kbias_ref[0, 0, rows][:, None] if has_kbias else None
+        kseg = kseg_ref[0, 0, rows][:, None] if has_seg else None
+
+        def fold(t, on_diagonal, q_lo, q_n, k_n, kt=kt, k_tile=k_tile,
+                 v_tile=v_tile, k_start=k_start, kbias=kbias, kseg=kseg):
+            """Rows q_lo.. (q_n of them) of q tile t against the first k_n
+            rows of this k tile."""
+            if (q_lo, q_n) == (0, block_q):
+                at = _tile_at(t, block_q, q_tiles)
+                of_sq = _tile_at(first + t, block_q, q_steps * q_tiles)
+            else:       # part of a tile: a static index
+                at = pl.ds(t * block_q + q_lo, q_n)
+                of_sq = pl.ds((first + t) * block_q + q_lo, q_n)
+            rows = pl.ds(kt * block_k, k_n)
+            q, do = q_ref[0, at, :], do_ref[0, at, :]
+            at_diagonal = {}
+            if on_diagonal:
+                q_pos, k_pos = _positions(q_n, k_n, True)
+                at_diagonal = dict(k_pos=k_pos, q_pos=q_pos + (
+                    (first + t) * block_q + q_lo - k_start))
+            qseg = qseg_ref[0, :, at] if has_seg else None
+            dv, dk, dq = [], [], None
+            for j in each:
+                k_j, v_j = k_tile[j][:k_n], v_tile[j][:k_n]
+                s = _tile_scores(
+                    _dot(k_j, q, _NT), True, scale,
+                    mask=mask_ref[j % mask_ref.shape[0], at, rows]
+                    if has_mask else None,
+                    kbias=None if kbias is None else kbias[:k_n], qseg=qseg,
+                    kseg=None if kseg is None else kseg[:k_n], **at_diagonal)
+                # hard-masked entries get exactly 0 even on fully-masked
+                # rows where the saved lse is itself ~NEG_INF
+                p = jnp.exp(s - lse_ref[j, :, at])
+                if guard:
+                    p = jnp.where(s <= MASKED_BELOW, 0.0, p)
+                ds = (p * (_dot(v_j, do, _NT)
+                           - delta_ref[j, :, at])).astype(q.dtype)
+                dv.append(_dot(p.astype(do.dtype), do, _NN))
+                dk.append(_dot(ds, q, _NN))
+                mine = _dot(ds, k_j, _TN)
+                dq = mine if dq is None else dq + mine
+            dv_acc[rows, :] += _by_head(dv, d)
+            dk_acc[rows, :] += _by_head(dk, d)
+            dq_acc[of_sq, :] += dq
+
+        def tile(t, on_diagonal, fold=fold, k_start=k_start):
+            strips = block_q // LANES
+            if not (on_diagonal and isinstance(t, int)
+                    and isinstance(k_start, int) and strips > 1
+                    and block_q % LANES == 0):
+                return fold(t, on_diagonal, 0, block_q, block_k)
+            # the diagonal's place in the tile is known at trace time: a
+            # strip of 128 queries meets only the keys its last one sees
+            shift = (first + t) * block_q - k_start
+            for c in range(strips):
+                seen = -(-((c + 1) * LANES + shift) // LANES) * LANES
+                if seen > 0:
+                    fold(t, True, c * LANES, LANES, min(seen, block_k))
+
+        _walk(phases, tile, None if bm_ref is None else
+              lambda t, tile_k=tile_k: bm_ref[first + t, tile_k] > 0)
+
+    def _finish_dkv():
+        dk_ref[0] = (dk_acc[:] * scale).astype(dk_ref.dtype)
+        dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
+
+    def _finish_dq():
+        dq_ref[0] = (dq_acc[span, :] * scale).astype(dq_ref.dtype)
+
+    _when(qm == q_steps - 1, _finish_dkv)
+    _when(kj == k_steps - 1, _finish_dq)
+
+
 def _specs(sch: Schedule, d: int, h: int, rep: int, causal: bool, off: int,
            walk: str):
     """The BlockSpecs of one kernel's grid, by what they carry. walk 'k':
-    grid (head block, q tile, k span) — fwd and dQ; walk 'q': grid (head
-    block, k tile, q span) — dK/dV. 'q' / 'k': the operands of either side,
-    `sch.heads_per_block` heads of [b, s, h*d] in place ('k' at the key /
-    value head that `rep` query heads share, 'dk' key rows at the query's
-    own head) or one head of a flat [b*h, s, d]; 'stat_q': those heads'
-    query rows of [b*h, 1, sq] (lse, delta); 'row_q' / 'row_k':
-    per-batch-row vectors (segment ids, key bias); 'mask'(per_head): the
-    dense mask's slab, for each of the block's heads where it has heads."""
+    grid (head block, q tile, k span): fwd and dQ (and the delta pre-pass,
+    its third axis one step); walk 'q': grid (head block, k tile, q span),
+    dK/dV; walk 'fused': grid (head block, k step, q span) of the fused
+    backward, whose k step holds `sch.bwd_span_k` rows. 'q' / 'k': the
+    operands of either side, `sch.heads_per_block` heads of [b, s, h*d] in
+    place ('k' at the key / value head that `rep` query heads share, 'dk'
+    key rows at the query's own head) or one head of a flat [b*h, s, d];
+    'stat_q': those heads' query rows of [b*h, 1, sq] (lse, delta); 'row_q'
+    / 'row_k': per-batch-row vectors (segment ids, key bias);
+    'mask'(per_head): the dense mask's slab, for each of the block's heads
+    where it has heads; 'dq'(k steps): the fused backward's dQ rows, which
+    stay where they are until the last k step and then follow the q span,
+    dead steps too, so that each block is written once."""
     bq, bk = sch.block_q, sch.block_k
     if walk == "k":
         nq_rows, nk_rows = bq, sch.span_k
@@ -583,12 +876,13 @@ def _specs(sch: Schedule, d: int, h: int, rep: int, causal: bool, off: int,
                                  // sch.span_k)
             return g1, g2
     else:
-        nq_rows, nk_rows = sch.span_q, bk
+        nq_rows, nk_rows = ((sch.bwd_span_q, sch.bwd_span_k)
+                            if walk == "fused" else (sch.span_q, bk))
 
         def at(g1, g2):
             if causal:
-                g2 = jnp.maximum(g2, jnp.maximum(g1 * bk - off, 0)
-                                 // sch.span_q)
+                g2 = jnp.maximum(g2, jnp.maximum(g1 * nk_rows - off, 0)
+                                 // nq_rows)
             return g2, g1
 
     def spec(shape, index):
@@ -622,6 +916,9 @@ def _specs(sch: Schedule, d: int, h: int, rep: int, causal: bool, off: int,
             (heads if per_head else 1, nq_rows, nk_rows),
             (lambda g, i, j: (g, i, j)) if per_head else
             (lambda g, i, j: (g // per_row, i, j))),
+        dq=lambda k_steps: pl.BlockSpec(
+            (1, nq_rows, lanes), lambda g, g1, g2: operand(
+                g, jnp.where(g1 == k_steps - 1, g2, 0))),
     )
 
 
@@ -733,6 +1030,57 @@ def _flash_backward(q, k, v, o, do, lse, mask, kbias, qseg, kseg,
                   has_mask=mask is not None, has_kbias=kbias is not None,
                   has_seg=qseg is not None,
                   has_blockmask=block_mask is not None)
+    per_query = ql.shape[:1] + (sk,) + ql.shape[2:]
+
+    def grouped(t):
+        """[b, sk, h, d] a query head -> the sum over each key/value
+        head's group, what the gradient of a repeated head is."""
+        if hk == h:
+            return t
+        t = t.reshape(b, sk, hk, h // hk, d).astype(jnp.float32)
+        return jnp.sum(t, axis=3).astype(k.dtype)
+
+    def results(dq, dk, dv):
+        return (_unlaid(dq, b, h, sch), grouped(_unlaid(dk, b, h, sch)),
+                grouped(_unlaid(dv, b, h, sch)))
+
+    if sch.fused_backward:
+        # ---- delta, then dQ, dK and dV in one pass: grid (head block, k
+        # step, q span), dK and dV a QUERY head -----------------------------
+        rows = _tile(sq, DELTA_ROWS)    # a step reads; block_q divides sq
+        specs = _specs(sch._replace(block_q=rows), d, h, h // hk, causal,
+                       sk - sq, "k")
+        delta = pl.pallas_call(
+            functools.partial(_delta_kernel, heads=heads),
+            out_shape=jax.ShapeDtypeStruct(lse.shape, jnp.float32),
+            grid=(b * h // heads, sq // rows, 1),
+            in_specs=[specs["q"], specs["q"]], out_specs=specs["stat_q"],
+            interpret=interpret, name="flash_bwd_delta",
+        )(ol, dol)
+        specs = _specs(sch, d, h, h // hk, causal, sk - sq, "fused")
+        extra_in, extra_specs = _extra_inputs_specs(
+            mask, kbias, qseg, kseg, specs, block_mask=block_mask)
+        steps = (sk // sch.bwd_span_k, sq // sch.bwd_span_q)
+        lanes = heads * d                       # of a block
+        kw = {}
+        if sch.bwd_vmem_limit and not interpret:
+            kw["compiler_params"] = pltpu.CompilerParams(
+                vmem_limit_bytes=sch.bwd_vmem_limit)
+        return results(*pl.pallas_call(
+            functools.partial(_fused_kernel, block_q=sch.block_q,
+                              block_k=sch.block_k, steps=steps, **common),
+            out_shape=(jax.ShapeDtypeStruct(ql.shape, q.dtype),
+                       jax.ShapeDtypeStruct(per_query, k.dtype),
+                       jax.ShapeDtypeStruct(per_query, v.dtype)),
+            grid=(b * h // heads,) + steps,
+            in_specs=[specs["q"], specs["k"], specs["k"], specs["q"]]
+            + extra_specs + [specs["stat_q"], specs["stat_q"]],
+            out_specs=(specs["dq"](steps[0]), specs["dk"], specs["dk"]),
+            scratch_shapes=[_scratch((sq, lanes)),
+                            _scratch((sch.bwd_span_k, lanes)),
+                            _scratch((sch.bwd_span_k, lanes))],
+            interpret=interpret, name="flash_bwd", **kw,
+        )(ql, kl, vl, dol, *extra_in, lse, delta))
 
     # ---- dQ: grid (head block, q tile, k span) ---------------------------
     specs = _specs(sch, d, h, h // hk, causal, sk - sq, "k")
@@ -755,7 +1103,6 @@ def _flash_backward(q, k, v, o, do, lse, mask, kbias, qseg, kseg,
     specs = _specs(sch, d, h, h // hk, causal, sk - sq, "q")
     extra_in, extra_specs = _extra_inputs_specs(
         mask, kbias, qseg, kseg, specs, block_mask=block_mask)
-    per_query = ql.shape[:1] + (sk,) + ql.shape[2:]
     dk, dv = pl.pallas_call(
         functools.partial(_k_walk_kernel, block_q=sch.block_q, **common),
         out_shape=(jax.ShapeDtypeStruct(per_query, k.dtype),
@@ -768,17 +1115,7 @@ def _flash_backward(q, k, v, o, do, lse, mask, kbias, qseg, kseg,
                         _scratch((sch.block_k, heads * d))],
         interpret=interpret, name="flash_bwd_dkv",
     )(ql, kl, vl, dol, *extra_in, lse, delta)
-
-    def grouped(t):
-        """[b, sk, h, d] a query head -> the sum over each key/value
-        head's group, what the gradient of a repeated head is."""
-        if hk == h:
-            return t
-        t = t.reshape(b, sk, hk, h // hk, d).astype(jnp.float32)
-        return jnp.sum(t, axis=3).astype(k.dtype)
-
-    return (_unlaid(dq, b, h, sch), grouped(_unlaid(dk, b, h, sch)),
-            grouped(_unlaid(dv, b, h, sch)))
+    return results(dq, dk, dv)
 
 
 def _a_head_a_query(k, v, h: int):
@@ -840,6 +1177,12 @@ def _flash_fwd(q, k, v, mask, kbias, qseg, kseg, block_mask, causal,
 
 
 def _flash_bwd(causal, scale, sch, interpret, res, g):
+    # runs when a program's backward is TRACED: once a compile, a Python
+    # integer, nothing a step
+    from paddle_tpu import profiler
+
+    profiler.count_traced("flash_bwd_fused" if sch.fused_backward
+                          else "flash_bwd_two_kernels")
     q, k, v, mask, kbias, qseg, kseg, block_mask, o, lse = res
     dq, dk, dv = _flash_backward(q, k, v, o, g, lse, mask, kbias, qseg,
                                  kseg, block_mask, causal, scale, sch,
@@ -922,10 +1265,13 @@ def validate_against_reference(shapes=DEFAULT_CHECK_SHAPES, interpret=None,
     return {"max_abs_err", "shapes": [[b,s,h,d,mode,err_o,err_g],...],
     "pass"} — each shapes row carries 7 elements, with the attention mode
     string at index 4 (one of "dense", "densemask", "padbias", "segments",
-    matching the case list built below).
+    "two-kernels", matching the case list built below).
 
     Covers the dense-causal, additive-padding-mask, and segment-id (varlen)
-    paths. Single source of truth for the kernel-vs-reference criterion —
+    paths, each with the backward `schedule()` gives it (the fused one at
+    every shape whose dQ accumulator fits VMEM: all of these), and the
+    dense-causal path once more with the two-kernel backward forced.
+    Single source of truth for the kernel-vs-reference criterion —
     used by both the bench ladder's on-hardware check and the TPU pytest
     tier, so the two can't drift apart."""
     if interpret is None:
@@ -946,7 +1292,7 @@ def validate_against_reference(shapes=DEFAULT_CHECK_SHAPES, interpret=None,
     # kv-bias (padding) and a packed-segment case on the first shape
     cases = [(sh, "dense") for sh in shapes]
     cases += [(shapes[0], "densemask"), (shapes[0], "padbias"),
-              (shapes[0], "segments")]
+              (shapes[0], "segments"), (shapes[0], "two-kernels")]
     for (b, s, h, d), mode in cases:
         q, k, v = (jnp.asarray(rng.standard_normal((b, s, h, d)),
                                jnp.float32) for _ in range(3))
@@ -968,10 +1314,11 @@ def validate_against_reference(shapes=DEFAULT_CHECK_SHAPES, interpret=None,
                                     ).astype(jnp.int32)
 
         def f_f(q, k, v, mask=mask, kbias=kbias, segs=segs, causal=causal,
-                scale=scale):
+                scale=scale, fused=mode != "two-kernels"):
             qs, ks = (segs, segs) if segs is not None else (None, None)
             sch = schedule(q.shape, k.shape, q.dtype, causal,
-                           mask=0 if mask is None else mask.shape[1])
+                           mask=0 if mask is None else mask.shape[1],
+                           fused=fused)
             return _flash(q, k, v, mask, kbias, qs, ks, None, causal,
                           scale, sch, interpret)
 

@@ -221,6 +221,22 @@ def step_counters() -> dict:
             for k, v in jax.device_get(counts).items()}
 
 
+# What a program's TRACE chose, counted where the choice is made (a custom
+# VJP's backward, a dispatch on shapes): Python integers bumped once a
+# compile. No output of any program, nothing read from the device, nothing
+# a step.
+_traced: collections.Counter = collections.Counter()
+
+
+def count_traced(name: str) -> None:
+    _traced[name] += 1
+
+
+def traced_counts() -> dict:
+    """{name: times a trace took that path} since the process started."""
+    return dict(_traced)
+
+
 def self_ns(recorded=None) -> dict:
     """span_id -> the span's duration less what its children cover."""
     recorded = spans() if recorded is None else recorded

@@ -375,3 +375,22 @@ def child_env(repo_on_pythonpath=True, num_cpu_devices=None):
     if num_cpu_devices is not None:
         env["JAX_NUM_CPU_DEVICES"] = str(num_cpu_devices)
     return env
+
+
+def equations(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs its equations carry
+    (custom_vjp, pjit, ...), a kernel's own body apart."""
+    import jax
+
+    for eqn in jaxpr.eqns:
+        yield eqn
+        if eqn.primitive.name != "pallas_call":
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from equations(sub)
+
+
+def kernel_names(jaxpr) -> list:
+    """The `pallas_call`s a jaxpr reaches, in order, by their own names."""
+    return [e.params["name"] for e in equations(jaxpr)
+            if e.primitive.name == "pallas_call"]
+

@@ -391,6 +391,10 @@ CASES = {
     # index maps and the accumulators carried across its steps
     "flash-bf16-16k-keys-fwd-bwd": lambda mp: _flash(
         jnp.bfloat16, True, shape=(1, 16 * SEQ, 8, 128)),
+    # dQ's float32 accumulator for 32768 queries is 16 MiB: the backward
+    # stays two kernels, a q walk and a k walk
+    "flash-bf16-32k-keys-fwd-bwd": lambda mp: _flash(
+        jnp.bfloat16, True, shape=(1, 32 * SEQ, 8, 128)),
     # a sequence under 128 is one tile of its own length, read whole
     "flash-bf16-segments-100-keys-fwd-bwd": lambda mp: _flash(
         jnp.bfloat16, True, "segments", shape=(BATCH, 100, N_HEADS,
@@ -424,6 +428,34 @@ def test_kernel_compiles_for_v5e(case, v5e, monkeypatch):
     args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in shapes]
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_one_flash_call_is_one_kernel_forward_and_two_backward():
+    """What `bench/zaya_trace.py` pins and a `perf_opt` PR may not edit:
+    it tells `zaya1-8b.train-8k`'s Mosaic calls apart by count and order,
+    `FORWARD = "FGGG"` and `BACKWARD = "GGGGGGFF"`, twelve a layer, and
+    `cca_attn_roofline`, `expert_ffn_roofline` and `moe_train_mfu` read
+    nothing where a step has another count. So the gradient of ONE flash
+    call is exactly two Mosaic calls after the forward's one, both
+    attention's: the delta pre-pass as a kernel of its own (not an XLA
+    fusion, not folded into the fused kernel), then the fused backward; at
+    the cell's own shape, and at the other two cells'."""
+    from _helpers import kernel_names
+    from paddle_tpu.ops.pallas.flash_attention import flash_attention
+
+    for shape, kv_heads in (((1, 8 * SEQ, 8, 128), 2), (GPT2_CELL, N_HEADS),
+                            ((4, SEQ, 8, 128), 8)):
+        b, s, h, d = shape
+        q = jax.ShapeDtypeStruct(shape, jnp.bfloat16)
+        kv = jax.ShapeDtypeStruct((b, s, kv_heads, d), jnp.bfloat16)
+        forward = jax.make_jaxpr(lambda *a: flash_attention(
+            *a, interpret=False))(q, kv, kv)
+        both = jax.make_jaxpr(jax.value_and_grad(
+            lambda *a: jnp.sum(flash_attention(*a, interpret=False).astype(
+                jnp.float32)), argnums=(0, 1, 2)))(q, kv, kv)
+        assert kernel_names(forward.jaxpr) == ["flash_fwd"]
+        assert kernel_names(both.jaxpr) == [
+            "flash_fwd", "flash_bwd_delta", "flash_bwd"]
 
 
 # GPT-2's toy batch in float32, and gpt3-1.3b.train-4chip's own attention

@@ -8,8 +8,15 @@ and which folds nothing), a tile the block table skips, and rows with no
 visible key. The same over the LAYOUTS: heads read where the caller left
 them, [b, s, h*d], one to a 128-lane block, two or four sharing one, a
 key/value head shared by a group of query heads; and the head sizes and
-counts that stay on flat [b*h, s, d] copies, which warn once a shape. The
-jaxpr of a step says what stands around the kernels."""
+counts that stay on flat [b*h, s, d] copies, which warn once a shape. And
+over the two BACKWARDS: the fused one (a delta pre-pass, then dQ, dK and dV
+from one pass over the score tiles: all of a toy's sq and sk in ONE grid
+step of straight-line code where no span is forced, its diagonal tiles in
+strips where they are wider than 128; a k tile a step with dQ's accumulator
+riding across the steps and its blocks written on the last where one is),
+which every toy shape takes by the rule, and the two-kernel one, forced
+(`schedule(fused=False)`). The jaxpr of a step says what stands around the
+kernels, and `profiler.traced_counts()` which backward a trace took."""
 
 import functools
 import math
@@ -20,12 +27,15 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from _helpers import equations
+from paddle_tpu import profiler
 from paddle_tpu.ops.pallas import flash_attention as fa
 
 S = 512
 # (block_q, block_k, span): 128-wide tiles walked inside ONE grid step a
 # q tile; the same tiles in spans of two (the third grid axis has two
-# steps, one of them dead above the diagonal); uneven tiles; the rule's
+# steps, one of them dead above the diagonal); uneven tiles (the fused
+# backward folds a 256-wide diagonal tile in two strips); the rule's
 TILES = {"t128": (128, 128, None), "t128-span256": (128, 128, 256),
          "t256x128": (256, 128, None), "rule": (None, None, None)}
 MODES = ("causal", "causal-sq<sk", "kbias", "mask", "segments", "blocks")
@@ -83,23 +93,38 @@ PARITY += [(t, m, dt, lay) for lay in sorted(LAYOUTS) if lay != "2x32"
                                             ("mask", "t128"))]
 PARITY += [("t128", "headmask", dt, lay) for lay in ("4x64", "8over2x128")
            for dt in DTYPES]
+# every case above takes the fused backward; the two-kernel one, which the
+# rule keeps for sequences whose dQ accumulator no VMEM holds, over every
+# tile choice and mask form on the toy heads and the rule's on each layout
+# that is read in place
+PARITY = [case + (True,) for case in PARITY]
+PARITY += [(t, m, "f32", "2x32", False) for t in sorted(TILES)
+           for m in MODES]
+PARITY += [("rule", m, "bf16", lay, False)
+           for lay in ("4x64", "2x128", "8over2x128", "2x96")
+           for m in ("causal", "causal-sq<sk", "mask", "segments")]
 
 
 @pytest.mark.parametrize(
-    "tiles,mode,dtype,layout",
-    [pytest.param(t, m, DTYPES[dt], lay, id="-".join(
-        (t, m, dt) + ((lay,) if lay != "2x32" else ())))
-     for t, m, dt, lay in PARITY])
+    "tiles,mode,dtype,layout,fused",
+    [pytest.param(t, m, DTYPES[dt], lay, fused, id="-".join(
+        (t, m, dt) + ((lay,) if lay != "2x32" else ())
+        + (() if fused else ("two-kernels",))))
+     for t, m, dt, lay, fused in PARITY])
 def test_forward_and_gradients_match_reference(tiles, mode, dtype, layout,
-                                               monkeypatch):
+                                               fused, monkeypatch):
     heads, in_place = LAYOUTS[layout]
     q, k, v, causal, kw, ref = _case(mode, dtype, heads=heads)
     sch = fa.schedule(q.shape, k.shape, dtype, causal)
-    assert sch.heads_per_block == in_place
+    assert sch.heads_per_block == in_place and sch.fused_backward
     block_q, block_k, span = TILES[tiles]
-    if span:        # what the rule does to a sequence that outgrows VMEM
+    forced = {"span": span} if span else {}     # what the rule does to a
+    if not fused:                               # sequence that outgrows VMEM
+        forced["fused"] = False
+    if forced:
         monkeypatch.setattr(fa, "schedule",
-                            functools.partial(fa.schedule, span=span))
+                            functools.partial(fa.schedule, **forced))
+    taken = profiler.traced_counts()
     scale = 1.0 / math.sqrt(q.shape[-1])
     w = jnp.asarray(np.random.default_rng(1).standard_normal(q.shape),
                     jnp.float32)
@@ -119,6 +144,11 @@ def test_forward_and_gradients_match_reference(tiles, mode, dtype, layout,
     # shape falls back to the reference
     assert not any("XLA reference" in str(w.message) for w in said)
     assert in_place == 0 or not any("flat" in str(w.message) for w in said)
+    # the trace of the gradient took the backward it was told to, once
+    took = {n: c - taken.get(n, 0)
+            for n, c in profiler.traced_counts().items() if c != taken.get(n)}
+    assert took == {"flash_bwd_fused" if fused
+                    else "flash_bwd_two_kernels": 1}
     want = run(lambda q, k, v: fa._reference(q, k, v, causal, scale, **ref))
     # float32 operands: products exact on the CPU; bfloat16 operands: P and
     # dS are rounded to bfloat16 for their second matmul (2^-9 each)
@@ -142,27 +172,81 @@ CELLS = {"gpt2-124m.train": ((28, 1024, 12, 64), 12, 2, 1),
 @pytest.mark.parametrize("cell", sorted(CELLS))
 def test_schedule_at_the_training_cells(cell):
     """512-wide tiles and 1024 keys in one span: two grid steps a block of
-    heads and kernel where the 128 x 128 grid took 64 a head, none of them
-    dead (ZAYA's 8192 keys sit in VMEM as two spans, and a tile of the
+    heads for the forward where the 128 x 128 grid took 64 a head, none of
+    them dead (ZAYA's 8192 keys sit in VMEM as two spans, and a tile of the
     first half steps once over the span above its diagonal). Every cell's
     heads are read in place, two of 64 to a block, one of 128 (ZAYA's two
-    key/value heads by four query heads each)."""
+    key/value heads by four query heads each). Every cell's backward is the
+    fused one: at 1024 keys all of a head block in ONE grid step, under the
+    chip's default VMEM; ZAYA's a k tile a step beside all of sq and dQ's
+    4 MiB accumulator, for which it asks the compiler for more. The
+    two-kernel backward's schedule is what it was."""
     shape, kv_heads, in_place, spans = CELLS[cell]
     b, s, h, d = shape
     sch = fa.schedule(shape, (b, s, kv_heads, d), jnp.bfloat16, True)
-    assert sch.heads_per_block == in_place
+    two = fa.schedule(shape, (b, s, kv_heads, d), jnp.bfloat16, True,
+                      fused=False)
+    assert sch[:4] == two[:4] and not two.fused_backward
+    assert sch.heads_per_block == two.heads_per_block == in_place
     assert (sch.block_q, sch.block_k) == (512, 512)
     assert (sch.span_q, sch.span_k) == (s // spans,) * 2
     blocks, n = b * h // in_place, s // 512
-    assert sch.steps == (blocks * n * spans,) * 3
-    assert all(10 * steps <= b * h * (s // 128) ** 2 for steps in sch.steps)
-    assert sch.dead_steps == (blocks * n * (spans - 1) // 2,) * 3
+    assert two.steps == (blocks * n * spans,) * 3
+    assert all(10 * steps <= b * h * (s // 128) ** 2 for steps in two.steps)
+    assert two.dead_steps == (blocks * n * (spans - 1) // 2,) * 3
     # the diagonal leaves 3 tiles of 4 at 1024 keys, 136 of 256 at 8192
-    assert sch.tiles == (b * h * n * (n + 1) // 2,) * 3
+    live = b * h * n * (n + 1) // 2
+    assert two.tiles == (live,) * 3 and sch.tiles == (live, 0, live)
     assert fa._vmem_bytes(512, s // spans, 512, 512, d, 2, 0,
                           in_place) <= fa.VMEM_BUDGET
     assert spans == 1 or fa._vmem_bytes(512, s, 512, 512, d, 2, 0,
                                         in_place) > fa.VMEM_BUDGET
+    # the fused backward: the forward's steps, a delta step a 2048 rows
+    assert sch.fused_backward
+    assert sch.steps[:2] == (two.steps[0], blocks * max(s // 2048, 1))
+    count = fa._fused_vmem_bytes(sch.bwd_span_q, sch.bwd_span_k, s, 512,
+                                 512, d, 2, 0, max(in_place, 1))
+    assert sch.dead_steps[1:] == (0, 0)
+    if spans == 1:      # one step a head block
+        assert (sch.bwd_span_q, sch.bwd_span_k) == (s, s)
+        assert sch.steps[2] == blocks
+        assert count <= fa.VMEM_BUDGET and sch.bwd_vmem_limit is None
+    else:   # 16 k tiles a head, each beside all of sq: q and dO come once
+        assert (sch.bwd_span_q, sch.bwd_span_k) == (s, 512)
+        assert sch.steps[2] == blocks * n
+        assert fa.VMEM_BUDGET < count <= fa.FUSED_VMEM_BUDGET
+        assert sch.bwd_vmem_limit == fa.FUSED_VMEM_LIMIT
+
+
+def test_which_backward_is_a_pure_function_of_the_shapes():
+    """`Schedule.fused_backward` follows from shapes, dtype and masks
+    alone: fused at the three cells' shapes (above) and wherever dQ's
+    float32 accumulator for all of sq fits beside a step's blocks; two
+    kernels where that accumulator would take more than half of the budget
+    (32768 queries of 128 lanes are 16 MiB); equal for equal inputs; and a shape that does not tile
+    has no schedule at all."""
+    def sch(s, h=8, d=128, dtype=jnp.bfloat16, **kw):
+        return fa.schedule((1, s, h, d), (1, s, h, d), dtype, True, **kw)
+
+    assert sch(16384).fused_backward and sch(16384).bwd_span_k == 512
+    assert sch(16384).bwd_vmem_limit == fa.FUSED_VMEM_LIMIT
+    long = sch(32768)
+    assert not long.fused_backward and long.bwd_vmem_limit is None
+    assert (long.bwd_span_q, long.bwd_span_k) == (0, 0)
+    assert long.steps[1] == long.steps[0] and long.tiles[1] == long.tiles[0]
+    # (a k tile beside ONE q tile would fit; more than half the budget for
+    # the accumulator is what the rule refuses)
+    assert 2 * 32768 * 128 * 4 > fa.FUSED_VMEM_BUDGET >= 2 * 16384 * 128 * 4
+    assert long == sch(32768) and sch(16384) == sch(16384)
+    assert sch(1024) != sch(1024, dtype=jnp.float32)    # the dtype counts
+    # few tiles: one step of straight-line code; many: a k tile a step
+    assert sch(1024).bwd_span_k == 1024
+    assert sch(1024, block_q=128, block_k=128).bwd_span_k == 128
+    assert 2 * 2 <= fa.ONE_STEP_TILES < 8 * 8
+    # forcing the two-kernel backward changes nothing else
+    two = sch(1024, fused=False)
+    assert not two.fused_backward and two[:4] == sch(1024)[:4]
+    assert sch(192) is None and sch(192, fused=False) is None
 
 
 @pytest.mark.parametrize("layout", ["3x64", "2x96"])
@@ -180,16 +264,6 @@ def test_a_flat_shape_warns_once(layout):
     assert len(flat) == 1 and str(q.shape) in str(flat[0].message)
 
 
-def _equations(jaxpr):
-    """Every equation of a jaxpr and of the jaxprs its equations carry
-    (custom_vjp, pjit, ...), a kernel's own body apart."""
-    for eqn in jaxpr.eqns:
-        yield eqn
-        if eqn.primitive.name != "pallas_call":
-            for sub in jax.core.jaxprs_in_params(eqn.params):
-                yield from _equations(sub)
-
-
 def test_no_transposed_copy_stands_around_the_kernels():
     """At the one-chip cell's shape a step's three kernels read q, k, v, o
     and dO as [b, s, h*d], a free reshape of what the projections wrote,
@@ -200,7 +274,7 @@ def test_no_transposed_copy_stands_around_the_kernels():
         lambda q, k, v: jnp.sum(fa.flash_attention(
             q, k, v, interpret=True).astype(jnp.float32)),
         argnums=(0, 1, 2)))(*args).jaxpr
-    eqns = list(_equations(jaxpr))
+    eqns = list(equations(jaxpr))
     kernels = [e for e in eqns if e.primitive.name == "pallas_call"]
     assert len(kernels) == 3
     for e in kernels:
@@ -234,10 +308,11 @@ def test_zayas_attention_repeats_no_key_or_value_head(monkeypatch):
     jaxpr = jax.make_jaxpr(jax.grad(
         lambda p, u: jnp.sum(zaya.cca_attention(cfg, p, pre, u)),
         argnums=(0, 1)))(params, u).jaxpr
-    eqns = list(_equations(jaxpr))
+    eqns = list(equations(jaxpr))
     kernels = [e for e in eqns if e.primitive.name == "pallas_call"]
-    assert len(kernels) == 3
-    for e in kernels:       # q, k, v lead every kernel's operands
+    assert [e.params["name"] for e in kernels] == [
+        "flash_fwd", "flash_bwd_delta", "flash_bwd"]
+    for e in kernels[::2]:  # q, k, v lead the walking kernels' operands
         assert [v.aval.shape for v in e.invars[:3]] == [
             (1, 256, 8 * 128), (1, 256, 2 * 128), (1, 256, 2 * 128)]
     # (the second convolution transposes its 8 + 2 heads; nothing does q's
@@ -272,6 +347,31 @@ def test_short_sequence_is_its_own_tile(keys):
         np.testing.assert_allclose(a, r, atol=2e-5, rtol=2e-5)
 
 
+@pytest.mark.parametrize("heads", ["4x64", "2x128"])
+def test_a_block_table_of_few_tiles_in_one_straight_line_step(heads):
+    """Four tiles are ONE grid step of the fused backward, every index known
+    at trace time, the table's too: a dead tile's code is there and a
+    `pl.when` on the table's entry skips it."""
+    (h, hk, d), _ = LAYOUTS[heads]
+    rng = np.random.default_rng(7)
+    q, k, v = (jnp.asarray(rng.standard_normal((1, 256, n, d)), jnp.float32)
+               for n in (h, hk, hk))
+    table = np.array([[1, 0], [1, 1]])      # the diagonal's own: causal
+    sch = fa.schedule(q.shape, k.shape, q.dtype, True,
+                      block_mask_shape=table.shape)
+    assert (sch.block_q, sch.bwd_span_q, sch.bwd_span_k) == (128, 256, 256)
+
+    def grads(f):
+        return jax.grad(lambda *a: jnp.sum(f(*a) ** 2), argnums=(0, 1, 2))(
+            q, k, v)
+
+    got = grads(lambda q, k, v: fa.flash_attention(
+        q, k, v, causal=True, block_mask=table, interpret=True))
+    want = grads(lambda q, k, v: fa._reference(q, k, v, True, d ** -0.5))
+    for a, r in zip(got, want):
+        np.testing.assert_allclose(a, r, atol=2e-5, rtol=2e-5)
+
+
 def test_schedule_follows_masks_tables_and_length():
     wide, shape = (8, 1024, 12, 128), (8, 1024, 12, 64)
     # a dense mask streams a (block_q, span) float32 slab, which the rule
@@ -281,8 +381,15 @@ def test_schedule_follows_masks_tables_and_length():
     assert (sch.block_q, sch.span_k) == (512, 512)
     assert fa._vmem_bytes(512, 512, 512, 512, 128, 4, 1) <= fa.VMEM_BUDGET
     assert fa._vmem_bytes(512, 1024, 512, 512, 128, 4, 1) > fa.VMEM_BUDGET
-    assert sch.steps == (96 * 4,) * 3 and sch.dead_steps == (96,) * 3
-    assert sch.tiles == (96 * 3,) * 3
+    two = fa.schedule(wide, wide, jnp.float32, True, mask=True, fused=False)
+    assert two.steps == (96 * 4,) * 3 and two.dead_steps == (96,) * 3
+    assert two.tiles == (96 * 3,) * 3
+    # the fused backward streams a (q span, k tile) slab: all of sq beside
+    # a k tile, two steps a head, with more VMEM asked for
+    assert (sch.bwd_span_q, sch.bwd_span_k) == (1024, 512)
+    assert sch.steps == (96 * 4, 96, 96 * 2)
+    assert sch.dead_steps == (96, 0, 0) and sch.tiles == (288, 0, 288)
+    assert sch.bwd_vmem_limit == fa.FUSED_VMEM_LIMIT
     # two heads of 64 in a block keep each head's own lanes of the
     # grid-side operands besides: one 512-wide tile no longer fits with
     # the slab, a mask a head doubles the slab, and the tiles halve
@@ -292,12 +399,16 @@ def test_schedule_follows_masks_tables_and_length():
                           mask=heads_of_mask)
         assert sch.heads_per_block == 2
         assert (sch.block_q, sch.block_k, sch.span_k) == (256, 256, 1024)
-        assert sch.steps == (48 * 4,) * 3 and sch.dead_steps == (0, 0, 0)
+        assert sch.steps[0] == 48 * 4 and sch.dead_steps == (0, 0, 0)
+        # sixteen tiles: a k tile a step, all of sq beside it
+        assert (sch.bwd_span_q, sch.bwd_span_k) == (1024, 256)
+        assert sch.steps[1:] == (48, 48 * 4)
     # a block table's granularity is the caller's
     sch = fa.schedule(shape, shape, jnp.bfloat16, False,
                       block_mask_shape=(8, 8), block_q=512)
     assert (sch.block_q, sch.block_k, sch.span_k) == (128, 128, 1024)
-    assert sch.steps == (48 * 8,) * 3           # a step a pair of heads
+    assert sch.steps == (48 * 8, 48, 48 * 8)    # a step a pair of heads
+    assert (sch.bwd_span_q, sch.bwd_span_k) == (1024, 128)
     # a sequence too long to sit in VMEM whole keeps a third grid axis
     long = (1, 16384, 8, 128)
     sch = fa.schedule(long, long, jnp.bfloat16, True)
@@ -305,11 +416,20 @@ def test_schedule_follows_masks_tables_and_length():
     assert fa._vmem_bytes(512, sch.span_k, 512, 512, 128, 2,
                           False) <= fa.VMEM_BUDGET
     n = 16384 // 512
-    assert sch.tiles == (8 * n * (n + 1) // 2,) * 3
+    assert sch.tiles == (8 * n * (n + 1) // 2, 0, 8 * n * (n + 1) // 2)
     assert sch.steps[0] == 8 * n * (16384 // sch.span_k)
+    # and the fused backward's q span is the longest that leaves room for
+    # dQ's 8 MiB: a k tile's steps above the diagonal are dead
+    assert 0 < sch.bwd_span_q < 16384 and sch.bwd_span_k == 512
+    spans = 16384 // sch.bwd_span_q
+    assert sch.steps[2] == 8 * n * spans
+    assert sch.dead_steps[2] == 8 * sum(
+        (m + 1) * sch.bwd_span_q <= j * 512 for j in range(n)
+        for m in range(spans))
     # cross-length causal: bottom-right aligned, every key tile is seen
     sch = fa.schedule((1, 512, 2, 64), (1, 1024, 2, 64), jnp.float32, True)
-    assert sch.tiles == (2 * 2,) * 3 and sch.dead_steps == (0, 0, 0)
+    assert sch.tiles == (2 * 2, 0, 2 * 2) and sch.dead_steps == (0, 0, 0)
+    assert (sch.bwd_span_q, sch.bwd_span_k) == (512, 1024)
     # lengths with no tile
     assert fa.schedule((1, 192, 2, 64), (1, 192, 2, 64), jnp.float32,
                        True) is None

@@ -3,6 +3,7 @@ where the engine, the runner, TrainStep and set-up open it, that the same
 spans reach the profiler's own trace, and the scope names on the device."""
 
 import glob
+import re
 import statistics
 import time
 
@@ -404,8 +405,8 @@ def test_pallas_kernels_are_named():
     text = str(jax.make_jaxpr(jax.grad(
         lambda *a: flash_attention(*a, causal=True, interpret=True).sum(),
         argnums=(0, 1, 2)))(q, q, q))
-    for name in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"):
-        assert name in text
+    kernels = [n for n in re.findall(r"name=(\w+)", text) if "flash" in n]
+    assert kernels == ["flash_fwd", "flash_bwd_delta", "flash_bwd"]
     pool = jnp.zeros((4, 8, 2, 64), jnp.float32)
     text = str(jax.make_jaxpr(lambda q, k, v, t, s, n: ragged_paged_attention(
         q, k, v, t, s, n, interpret=True))(
