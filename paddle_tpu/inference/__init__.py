@@ -161,9 +161,9 @@ def create_predictor(config: Config) -> Predictor:
 
 
 def create_serving_engine(model, dtype=None, **kw):
-    """Build a continuous-batching ServingEngine for a decoder Layer
-    (Llama, GPT, DeepseekV3ForCausalLM, OlmoHybridForCausalLM,
-    Phi4FlashForCausalLM).
+    """Build a continuous-batching ServingEngine for a decoder Layer that
+    `serving/runners/__init__.py`'s table has a runner for (`runner_for`
+    names them where it has none).
 
     The serving-path analogue of create_predictor: where the reference
     pairs fluid/inference with block_multihead_attention and a serving

@@ -13,7 +13,7 @@ Served, one chip's share; not trained: the `Layer` holds ONE rank's part of
 an expert-parallel deployment (`experts_held` of the `n_routed_experts`,
 from `first_expert`; attention, router and shared expert whole) and its
 eager `forward` is the expanded form of the attention in plain ops, with
-no autograd tape. `serving.model_runner.DeepseekV3Runner` serves it through
+no autograd tape. `serving/runners/deepseek_v3.py` serves it through
 latent pages from the same functions below.
 
 The equations (x [T, hidden]; RMSNorm in float32; linears [in, out], no bias):

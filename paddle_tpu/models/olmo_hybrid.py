@@ -6,7 +6,7 @@ Olmo 2 / Olmo 3 placement of the norms.
 
 Served, not trained: the `Layer` holds the weights and its eager `forward`
 is the plain form (the recurrence token by token, dense causal attention),
-with no autograd tape. `serving.model_runner.OlmoHybridRunner` serves it
+with no autograd tape. `serving/runners/olmo_hybrid.py` serves it
 from the functions below: pages for the full layers, a state slot per
 sequence for the linear ones.
 

@@ -8,7 +8,7 @@ keys and values.
 
 Served, not trained: the `Layer` holds the weights and its eager `forward`
 is the plain form (the scan token by token, dense masks), with no autograd
-tape. `serving.model_runner.Phi4FlashRunner` serves it from the functions
+tape. `serving/runners/phi4flash.py` serves it from the functions
 below: a state slot per sequence for the Mamba layers, a ring of pages for
 the window layers, whole-context pages for the one full layer, nothing for
 the cross-decoder.

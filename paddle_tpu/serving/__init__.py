@@ -40,8 +40,11 @@ paged-decode kernel (`ops/pallas/paged_attention.py`):
                    (max_prefill_tokens_per_step), and youngest-first
                    preemption under pool pressure (recompute-on-resume —
                    mostly prefix-cache hits when the cache is on);
-  model_runner.py  jitted paged prefill/decode step functions adapting
-                   models.Llama / models.GPT (the fluid/inference role);
+  model_runner.py  the runner chassis: jitted paged prefill/decode step
+                   functions adapting a decoder Layer (the
+                   fluid/inference role);
+  runners/         a module a served configuration, and the table of
+                   which runner serves which Layer (`runner_for`);
   engine.py        ServingEngine: per-request sampling params, stop
                    conditions, token streaming, plus `naive_generate`,
                    the sequential oracle continuous batching must match
@@ -185,9 +188,10 @@ from paddle_tpu.serving.metrics import (  # noqa: F401
     Counter, EngineMetrics, Gauge, Histogram, aggregate_snapshots,
 )
 from paddle_tpu.serving.model_runner import (  # noqa: F401
-    GPTRunner, LlamaRunner, PagedModelRunner, bucket_len, build_runner,
-    runner_for,
+    PagedModelRunner, bucket_len, build_runner, runner_for,
 )
+from paddle_tpu.serving.runners.gpt import GPTRunner  # noqa: F401
+from paddle_tpu.serving.runners.llama import LlamaRunner  # noqa: F401
 from paddle_tpu.serving.journal import RouterJournal  # noqa: F401
 from paddle_tpu.serving.resilience import (  # noqa: F401
     FaultInjector, InjectedDeviceError, InvariantViolation, QueueFullError,

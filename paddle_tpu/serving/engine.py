@@ -72,7 +72,7 @@ from paddle_tpu.serving.detokenize import StreamDetokenizer
 from paddle_tpu.serving.kv_cache import (
     KVCachePool, OffloadRecord, SCRATCH_PAGE,
 )
-from paddle_tpu.serving.metrics import EngineMetrics
+from paddle_tpu.serving.metrics import Counter, EngineMetrics, Gauge
 from paddle_tpu.serving.model_runner import (
     RUNNER_OPTIONS, PagedModelRunner, bucket_len, build_runner,
     require_retryable,
@@ -553,9 +553,15 @@ class ServingEngine:
                                    "") not in ("", "0")
         self.audit = audit
         self.metrics = metrics or EngineMetrics()
-        # what the runner's steps count on the device (expert layers:
-        # `runner.COUNTS`): each launch hands its counts over here, and
-        # the step's one drain reads them with its tokens
+        # what the runner's steps count on the device (`runner.COUNTS`),
+        # under the names the runner gives: each launch hands its counts
+        # over here, and the step's one drain reads them with its tokens.
+        # The same for the gauges a runner keeps on the host
+        # (`runner.GAUGES`: attributes of it) and the pool's own
+        self.metrics.declare(Counter, getattr(runner, "COUNTS", ()))
+        self._runner_gauges = self.metrics.declare(
+            Gauge, getattr(runner, "GAUGES", ()))
+        self._pool_gauges = self.metrics.declare(Gauge, self.pool.gauges(0))
         self._step_counts: list = []
         # static per-pool ratios: the measured page-byte reduction (scale
         # bytes counted) and the matching sessions-per-fixed-HBM factor —
@@ -716,10 +722,10 @@ class ServingEngine:
                 # they are in host memory and reading them waits for
                 # nothing
                 counts, self._step_counts = self._step_counts, []
+                counters = self.metrics.declare(Counter, self.runner.COUNTS)
                 for c in counts:
-                    for name, n in zip(self.runner.COUNTS,
-                                       np.asarray(c).tolist()):
-                        getattr(self.metrics, name).inc(n)
+                    for counter, n in zip(counters, np.asarray(c).tolist()):
+                        counter.inc(n)
         self.metrics.host_syncs.inc()
         return out
 
@@ -1090,18 +1096,11 @@ class ServingEngine:
 
     def _read_gauges(self) -> tuple:
         """What a step's gauges mirror, as the engine's state has it
-        now (`_write_gauges` takes it in this order): the runner's
-        host-side byte and block counters, then scheduler, pool and host
+        now (`_write_gauges` takes it in this order): the host-side
+        counters the runner said it keeps, then scheduler, pool and host
         tier."""
         r, a, tier = self.runner, self.pool.allocator, self.pool.host_tier
-        return (getattr(r, "attn_kv_bytes_read", None),
-                getattr(r, "attn_kv_bytes_gather", None),
-                getattr(r, "ragged_blocks", None),
-                getattr(r, "ragged_edge_blocks", None),
-                getattr(r, "tp_comm_bytes", None),
-                getattr(r, "tp_comm_bytes_fp32", None),
-                getattr(r, "tp_gather_bytes", None),
-                getattr(r, "tp_gather_bytes_fp32", None),
+        return ([getattr(r, g.name) for g in self._runner_gauges],
                 self.scheduler.queue_depth, len(self.scheduler.running),
                 a.num_usable - a.num_free, self.pool.utilization(),
                 (len(self.pool.prefix_cache)
@@ -1109,45 +1108,19 @@ class ServingEngine:
                 tier.bytes_used if tier is not None else None,
                 tier.used_count if tier is not None else None)
 
-    def _write_gauges(self, read, gathered, blocks, edge_blocks, comm, comm32,
-                      gather, gather32, queued, running, used, utilization,
+    def _write_gauges(self, runners, queued, running, used, utilization,
                       cached, tier_bytes, tier_used) -> None:
         m = self.metrics
-        if read is not None:
-            m.attn_kv_bytes_read.set(read)
-            m.attn_kv_bytes_gather.set(gathered)
-        if blocks is not None:
-            m.ragged_blocks.set(blocks)
-            m.ragged_edge_blocks.set(edge_blocks)
-        if comm is not None:
-            # quantized-collective accounting: wire bytes
-            # the row-parallel allreduces moved per shard (scale bytes
-            # counted) vs the fp32 cost of the same calls — mirrored
-            # from the runner's host-side counters like the attention
-            # bytes above, so the comm reduction is measured
-            m.tp_comm_bytes.set(comm)
-            m.tp_comm_bytes_fp32.set(comm32)
-            m.tp_comm_bytes_reduction_x.set(comm32 / comm if comm else 0.0)
-        if gather is not None:
-            # the gather direction: wire bytes the column-
-            # parallel all-gathers (lm_head logits) moved per shard,
-            # scale bytes counted, vs the fp32 cost of the same calls
-            m.tp_gather_bytes.set(gather)
-            m.tp_gather_bytes_fp32.set(gather32)
-            m.tp_gather_bytes_reduction_x.set(
-                gather32 / gather if gather else 0.0)
+        for gauge, v in zip(self._runner_gauges, runners):
+            gauge.set(v)
         m.queue_depth.set(queued)
         m.running.set(running)
         m.pool_used_pages.set(used)
         m.pool_utilization.set(utilization)
-        if self.pool.state_layers:
-            # a running request holds its slot, and so its state
-            m.state_slots_live.set(running)
-        ring = self.pool.window
-        if ring is not None:
-            m.window_pages_held.set(ring.held_page_rows)
-            m.window_pages_whole_context.set(ring.whole_context_page_rows)
-            m.window_pages_returned.set(ring.pages_returned)
+        # the pool's own, as it has them when this is written
+        for gauge, v in zip(self._pool_gauges,
+                            self.pool.gauges(running).values()):
+            gauge.set(v)
         if cached is not None:
             m.prefix_cached_pages.set(cached)
         if tier_bytes is not None:

@@ -1977,6 +1977,15 @@ class WindowGroup:
             self.whole_context_page_rows += -(-end // self.block_size)
         return out
 
+    def gauges(self) -> Dict[str, float]:
+        """Sums over launches, so a window's delta divides: the pages a
+        layer held for the rows of every decode launch, the pages a cache
+        of the whole context would have held for the same rows, and the
+        pages given back."""
+        return {"window_pages_held": self.held_page_rows,
+                "window_pages_whole_context": self.whole_context_page_rows,
+                "window_pages_returned": self.pages_returned}
+
     def check_no_leaks(self) -> bool:
         return not self._held and len(self._free) == self.num_blocks - 1
 
@@ -2293,6 +2302,19 @@ class KVCachePool:
     def utilization(self) -> float:
         a = self.allocator
         return 1.0 - a.num_free / a.num_usable
+
+    def gauges(self, running: int) -> Dict[str, float]:
+        """What this pool's cache mechanisms show at a step's end, under
+        the names an engine's snapshot gives them (the engine declares
+        whatever is here; a pool of pages alone has nothing to add).
+        `running`: the sequences that run, each of which holds its slot,
+        and so its state."""
+        out: Dict[str, float] = {}
+        if self.state_layers:
+            out["state_slots_live"] = running
+        if self.window is not None:
+            out.update(self.window.gauges())
+        return out
 
     def page_bytes(self) -> int:
         """HBM bytes ONE page actually occupies across all layers and

@@ -105,80 +105,177 @@ class Histogram:
         rank = max(0, min(len(s) - 1, int(round(p / 100.0 * (len(s) - 1)))))
         return s[rank]
 
+    p50 = property(lambda self: self.percentile(50))
+    p99 = property(lambda self: self.percentile(99))
 
-# EngineMetrics.snapshot() keys that are cumulative event counts — the
-# keys a multi-engine tier can meaningfully SUM across replicas (ISSUE 8
-# metrics aggregation). Gauges/peaks take max, ratios are recomputed from
-# the summed counters, and exact percentiles are dropped: scalar
-# snapshots cannot be merged into a percentile, so tier-level latency
-# lives in the router's own histograms instead.
-SUMMABLE_KEYS = (
-    "requests_added", "requests_finished", "preemptions",
-    "requests_timed_out", "requests_aborted", "step_retries",
-    "nan_logit_events", "shed_requests", "tokens_generated",
-    "moe_tokens_routed", "moe_local_pairs", "moe_experts_touched",
-    "latent_copy_groups", "latent_run_groups",
-    "dsa_keys_scored", "dsa_keys_selected",
-    "delta_decode_seq_steps", "delta_prefill_tokens",
-    "delta_prefill_positions", "state_slot_resets",
-    "ssm_decode_seq_steps", "ssm_prefill_tokens", "cross_rows_skipped",
-    "window_pages_held", "window_pages_whole_context",
-    "window_pages_returned",
-    "prefill_tokens", "prefill_chunks", "prefix_hit_tokens", "cow_copies",
-    "prefix_cached_pages", "attn_kv_bytes_read", "attn_kv_bytes_gather",
-    "ragged_blocks", "ragged_edge_blocks",
-    "tp_comm_bytes", "tp_comm_bytes_fp32",
-    "tp_gather_bytes", "tp_gather_bytes_fp32",
-    "spec_proposed_tokens", "spec_accepted_tokens", "spec_rollback_pages",
-    "spec_fused_horizons", "spec_dead_positions",
-    "host_syncs", "decode_horizon_steps", "horizon_overshoot_tokens",
-    "planned_ahead_steps",
-    "offload_spill_pages", "pagein_pages", "pagein_hidden_pages",
-    "offload_resumes", "offload_recompute_fallbacks", "host_tier_drops",
-    "host_tier_bytes",
-    "handoffs_out", "handoffs_in", "handoff_pages_out", "handoff_pages_in",
-    "handoff_recompute_fallbacks", "handoff_bytes_out",
-    "store_hit_pages", "store_dedup_pages",
-    "decode_steps", "queue_depth", "running", "pool_used_pages",
+
+# The engine's OWN instruments, a line each: the attribute of EngineMetrics
+# (and its key in snapshot()), its kind, and whether a tier of replicas
+# SUMS its value (`aggregate_snapshots`: an event count, or a level that
+# adds up over engines; the rest are ratios and per-engine levels). What a
+# runner's programs count and what a runner or a pool keeps as gauges is
+# not here: the engine declares those by the names THEY give (`declare`).
+_OWN = (
+    ("requests_added", Counter, True),
+    ("requests_finished", Counter, True),
+    ("preemptions", Counter, True),
+    # every abnormal outcome is counted, so an overloaded or faulty
+    # deployment shows in snapshot() and not in a stack trace
+    ("requests_timed_out", Counter, True),
+    ("requests_aborted", Counter, True),
+    ("step_retries", Counter, True),
+    ("nan_logit_events", Counter, True),
+    ("shed_requests", Counter, True),
+    ("tokens_generated", Counter, True),
+    # prefill_tokens are tokens prefill chunks COMPUTED; a prefix-cache
+    # hit skips the compute and lands in prefix_hit_tokens, so computed +
+    # hit = total context and the hits ARE the saving
+    ("prefill_tokens", Counter, True),
+    ("prefill_chunks", Counter, True),
+    ("prefix_hit_tokens", Counter, True),
+    ("cow_copies", Counter, True),
+    ("prefix_cached_pages", Gauge, True),
+    # speculative decoding: draft tokens put into verify spans and those
+    # the target accepted; pages the rejected tails returned (the leak
+    # audit is the guarantee, this the gauge); horizons that carried
+    # drafts through decode_multi_spec (one drain each) and proposed-but-
+    # rejected verify positions, the waste adaptive-k exists to shrink
+    ("spec_proposed_tokens", Counter, True),
+    ("spec_accepted_tokens", Counter, True),
+    ("spec_rollback_pages", Counter, True),
+    ("spec_fused_horizons", Counter, True),
+    ("spec_dead_positions", Counter, True),
+    # host_syncs: every blocking device->host drain (one a step on the
+    # s=1 path, one a HORIZON on the multi-step path, and a counting
+    # runner's counts ride it: they add none); device decode steps run
+    # inside decode_multi horizons; drained tokens discarded because
+    # their request stopped earlier in the horizon (pages reclaimed on
+    # the spot)
+    ("host_syncs", Counter, True),
+    ("decode_horizon_steps", Counter, True),
+    ("horizon_overshoot_tokens", Counter, True),
+    # steps whose host planning ran while a previous launch was still in
+    # flight. Where a step's TIME goes is read from the engine's spans
+    # against the device trace, not counted here
+    ("planned_ahead_steps", Counter, True),
+    # tiered KV offload: device pages copied to the host tier (preemption
+    # spills AND prefix demotions), pages restored, and those of them
+    # whose device_put was issued in an EARLIER step than the fence that
+    # consumed it (a whole step of device compute to hide behind);
+    # resumed requests by path; spills a full tier refused (those resumes
+    # degrade to recompute, exactness kept)
+    ("offload_spill_pages", Counter, True),
+    ("pagein_pages", Counter, True),
+    ("pagein_hidden_pages", Counter, True),
+    ("offload_resumes", Counter, True),
+    ("offload_recompute_fallbacks", Counter, True),
+    ("host_tier_drops", Counter, True),
+    ("host_tier_bytes", Gauge, True),
+    ("host_tier_pages_used", Gauge, False),
+    # prefill/decode split: requests a prefill-role engine staged for
+    # migration after their first token and the KV pages spilled for
+    # them; requests a decode-role engine accepted with a wire-transferred
+    # payload and the pages imported (content-hash-verified); a handoff
+    # whose pages could not ride along resumes by recompute, token-exact
+    ("handoffs_out", Counter, True),
+    ("handoffs_in", Counter, True),
+    ("handoff_pages_out", Counter, True),
+    ("handoff_pages_in", Counter, True),
+    ("handoff_recompute_fallbacks", Counter, True),
+    # cluster-wide KV store: raw page-payload bytes a handoff serialized
+    # (slot-reference handoffs over the shared store add ZERO); pages
+    # paged in from the host-wide content index; copies skipped because
+    # the chain was already store-resident
+    ("handoff_bytes_out", Counter, True),
+    ("store_hit_pages", Counter, True),
+    ("store_dedup_pages", Counter, True),
+    ("decode_steps", Counter, True),
+    ("queue_depth", Gauge, True),
+    ("running", Gauge, True),
+    ("pool_used_pages", Gauge, True),
+    ("pool_utilization", Gauge, False),
+    # MEASURED from what the params dict and the pools actually store,
+    # scale bytes counted, never assumed: logical fp32 weight bytes over
+    # resident bytes; a page's bytes at the logical dtype over its bytes
+    # as stored, and the matching sessions-per-fixed-HBM factor (1.0 on
+    # fp32 runners and pools)
+    ("weight_bytes_reduction_x", Gauge, False),
+    ("kv_bytes_reduction_x", Gauge, False),
+    ("sessions_per_pool_x", Gauge, False),
+    ("batch_occupancy", Histogram, False),
+    # from add_request() to the request's first sampled token (admission
+    # wait + prefill), by the injectable clock
+    ("ttft_s", Histogram, False),
+    ("e2e_latency_s", Histogram, False),
 )
 
-MAX_KEYS = ("queue_depth_peak", "pool_utilization_peak")
+# What snapshot() shows of an instrument where that is not its value
+# alone, each a property of its kind: key `<name>` for "value",
+# `<name>_<stat>` for the rest.
+_SHOWN = {"queue_depth": ("value", "peak"), "pool_utilization": ("peak",),
+          "batch_occupancy": ("mean",), "ttft_s": ("p50", "p99", "mean"),
+          "e2e_latency_s": ("p50", "p99")}
+
+# snapshot() keys made of two others, numerator over denominator (0.0
+# over nothing), wherever both are there; a tier makes them again from
+# its SUMS (per-replica ratios over different traffic cannot be averaged
+# honestly). The last two divide gauges a tensor-parallel runner keeps:
+# the fp32 cost of its collectives over the wire bytes they moved.
+RATIOS = {
+    "spec_acceptance_rate": ("spec_accepted_tokens", "spec_proposed_tokens"),
+    "pagein_hidden_ratio": ("pagein_hidden_pages", "pagein_pages"),
+    "steps_per_token": ("decode_steps", "tokens_generated"),
+    "host_syncs_per_token": ("host_syncs", "tokens_generated"),
+    "tp_comm_bytes_reduction_x": ("tp_comm_bytes_fp32", "tp_comm_bytes"),
+    "tp_gather_bytes_reduction_x": ("tp_gather_bytes_fp32",
+                                    "tp_gather_bytes"),
+}
+
+
+def _key(name: str, stat: str) -> str:
+    return name if stat == "value" else f"{name}_{stat}"
+
+
+def _over(num: float, den: float) -> float:
+    return num / den if den > 0 else 0.0
+
+
+def _with_ratios(out: Dict[str, float]) -> Dict[str, float]:
+    for key, (num, den) in RATIOS.items():
+        if num in out and den in out:
+            out[key] = _over(out[num], out[den])
+    return out
+
+
+# How a tier merges snapshots, told from their keys alone (plain dicts
+# cross a process boundary, and a runner's or a pool's keys are not known
+# here): a key is SUMMED unless it is named below. Peaks take the max;
+# ratios are made again from the sums; the engine's own levels that do not
+# add, means and exact percentiles are dropped (scalar snapshots cannot be
+# merged into a percentile: tier-level latency lives in the router's own
+# histograms).
+MAX_KEYS = tuple(_key(name, "peak") for name, stats in _SHOWN.items()
+                 if "peak" in stats)
+NOT_SUMMED = frozenset(
+    [_key(name, stat) for name, stats in _SHOWN.items() for stat in stats
+     if stat != "value"]
+    + [name for name, _, summed in _OWN if not summed] + list(RATIOS))
 
 
 def aggregate_snapshots(snaps) -> Dict[str, float]:
-    """Merge several EngineMetrics snapshots into one tier-level view:
-    counters sum, peaks take the max, and derived ratios are recomputed
-    from the summed counters. Percentile keys are intentionally absent
-    (see SUMMABLE_KEYS)."""
+    """Merge several EngineMetrics snapshots into one tier-level view
+    (`NOT_SUMMED` has the rule): the engine's own sums are there even over
+    no snapshot, a runner's and a pool's keys where a snapshot has them."""
     snaps = list(snaps)
-    out: Dict[str, float] = {k: 0.0 for k in SUMMABLE_KEYS}
-    for k in MAX_KEYS:
-        out[k] = 0.0
+    out: Dict[str, float] = {name: 0.0 for name, _, summed in _OWN if summed}
+    out.update(dict.fromkeys(MAX_KEYS, 0.0))
     for s in snaps:
-        for k in SUMMABLE_KEYS:
-            out[k] += float(s.get(k, 0.0))
-        for k in MAX_KEYS:
-            out[k] = max(out[k], float(s.get(k, 0.0)))
-    toks = out["tokens_generated"]
-    prop = out["spec_proposed_tokens"]
-    out["spec_acceptance_rate"] = (out["spec_accepted_tokens"] / prop
-                                   if prop > 0 else 0.0)
-    pin = out["pagein_pages"]
-    out["pagein_hidden_ratio"] = (out["pagein_hidden_pages"] / pin
-                                  if pin > 0 else 0.0)
-    out["steps_per_token"] = out["decode_steps"] / toks if toks > 0 else 0.0
-    out["host_syncs_per_token"] = out["host_syncs"] / toks if toks > 0 \
-        else 0.0
-    # quantized collectives (ISSUE 15): the tier-level comm reduction
-    # is recomputed from the SUMMED byte counters, never averaged
-    # (per-replica ratios over different traffic cannot be averaged
-    # honestly)
-    comm = out["tp_comm_bytes"]
-    out["tp_comm_bytes_reduction_x"] = (out["tp_comm_bytes_fp32"] / comm
-                                        if comm > 0 else 0.0)
-    gather = out["tp_gather_bytes"]
-    out["tp_gather_bytes_reduction_x"] = (
-        out["tp_gather_bytes_fp32"] / gather if gather > 0 else 0.0)
+        for k, v in s.items():
+            if k in MAX_KEYS:
+                out[k] = max(out[k], float(v))
+            elif k not in NOT_SUMMED:
+                out[k] = out.get(k, 0.0) + float(v)
+    _with_ratios(out)
     out["replicas"] = float(len(snaps))
     return out
 
@@ -188,214 +285,43 @@ class EngineMetrics:
 
     Counts, gauges and histograms of what the engine did, counted where
     it happens. How long each part of a step took is not here: the
-    engine's spans (`paddle_tpu.profiler`) carry that. TTFT is measured
-    from add_request() to the first sampled token of that request
-    (admission wait + prefill), by the injectable clock that also serves
-    arrival times and deadlines.
+    engine's spans (`paddle_tpu.profiler`) carry that. Every instrument
+    is an attribute under its name: the engine's own (`_OWN`), and what
+    the engine declared for its runner and its pool (`declare`).
     """
 
     def __init__(self, clock: Optional[Callable[[], float]] = None):
         self.clock = clock or time.monotonic
-        self.requests_added = Counter("requests_added")
-        self.requests_finished = Counter("requests_finished")
-        self.preemptions = Counter("preemptions")
-        # failure-side instruments (ISSUE 2): every abnormal outcome is
-        # counted, so an overloaded or faulty deployment is visible in
-        # snapshot() instead of in a stack trace
-        self.requests_timed_out = Counter("requests_timed_out")
-        self.requests_aborted = Counter("requests_aborted")
-        self.step_retries = Counter("step_retries")
-        self.nan_logit_events = Counter("nan_logit_events")
-        self.shed_requests = Counter("shed_requests")
-        self.tokens_generated = Counter("tokens_generated")
-        # expert layers (a runner that serves one rank's share of an
-        # expert-parallel model): tokens x layers through a router, the
-        # token-expert pairs computed HERE, and held experts with at
-        # least one token, summed over layers and steps. Each step's
-        # program returns them and the engine reads them at the step's
-        # one drain, with its tokens: host_syncs does not rise
-        self.moe_tokens_routed = Counter("moe_tokens_routed")
-        self.moe_local_pairs = Counter("moe_local_pairs")
-        self.moe_experts_touched = Counter("moe_experts_touched")
-        # the latent decode kernel's walk, read the same way: groups of
-        # pages it copied, all layers, and those whose pages were
-        # consecutive in the pool, copied as ONE copy
-        self.latent_copy_groups = Counter("latent_copy_groups")
-        self.latent_run_groups = Counter("latent_run_groups")
-        # learned sparse attention, read the same way: index keys a step's
-        # query rows scored (each row's context, all layers) and the keys
-        # their selections kept (min(context, index_topk) a row and layer)
-        self.dsa_keys_scored = Counter("dsa_keys_scored")
-        self.dsa_keys_selected = Counter("dsa_keys_selected")
-        # recurrent state (a runner whose linear layers keep a state slot
-        # a sequence), read the same way: live rows x linear layers a
-        # decode step advanced, real prompt tokens through the chunked
-        # form and the positions it computed (padding and bucket
-        # included), slots a prefill reset; and, set by the engine, the
-        # slots that hold a running request's state
-        self.delta_decode_seq_steps = Counter("delta_decode_seq_steps")
-        self.delta_prefill_tokens = Counter("delta_prefill_tokens")
-        self.delta_prefill_positions = Counter("delta_prefill_positions")
-        self.state_slot_resets = Counter("state_slot_resets")
-        self.state_slots_live = Gauge("state_slots_live")
-        # a runner with a selective scan and page groups (Phi-4-mini-
-        # flash): live rows x scan layers a decode step advanced, real
-        # prompt tokens through the chunked scan, prompt rows that stopped
-        # before the cross-decoder (outputs of each step's program); and,
-        # mirrored from the pool's window group, the pages a layer of it
-        # held for the rows of every decode launch, the pages a cache of
-        # the whole context would have held for the same rows, and the
-        # pages it gave back: sums over launches, so a window's delta
-        # divides
-        self.ssm_decode_seq_steps = Counter("ssm_decode_seq_steps")
-        self.ssm_prefill_tokens = Counter("ssm_prefill_tokens")
-        self.cross_rows_skipped = Counter("cross_rows_skipped")
-        self.window_pages_held = Gauge("window_pages_held")
-        self.window_pages_whole_context = Gauge("window_pages_whole_context")
-        self.window_pages_returned = Gauge("window_pages_returned")
-        # prefill_tokens counts tokens actually COMPUTED by prefill
-        # chunks; prefix-cache hits skip the compute and land in
-        # prefix_hit_tokens instead, so (computed + hit) = total context
-        # and the hit counter IS the prefill-token savings (ISSUE 3)
-        self.prefill_tokens = Counter("prefill_tokens")
-        self.prefill_chunks = Counter("prefill_chunks")
-        self.prefix_hit_tokens = Counter("prefix_hit_tokens")
-        self.cow_copies = Counter("cow_copies")
-        # speculative decoding (ISSUE 5): draft tokens the n-gram
-        # proposer put into verify spans vs how many the target model
-        # accepted; spec_rollback_pages counts pages the rejected tails
-        # returned (must be matched by truncate — the leak audit's
-        # over-provision check is the hard guarantee, this the gauge)
-        self.spec_proposed_tokens = Counter("spec_proposed_tokens")
-        self.spec_accepted_tokens = Counter("spec_accepted_tokens")
-        self.spec_rollback_pages = Counter("spec_rollback_pages")
-        # fused verify-in-scan (ISSUE 18): horizons that carried drafts
-        # through decode_multi_spec (one drain each), and proposed-but-
-        # rejected verify positions — the waste adaptive-k exists to
-        # shrink on low-acceptance streams
-        self.spec_fused_horizons = Counter("spec_fused_horizons")
-        self.spec_dead_positions = Counter("spec_dead_positions")
-        # multi-step decode (ISSUE 6): host_syncs counts every blocking
-        # device->host drain the engine performs (one per step on the
-        # s=1 path, one per HORIZON on the multi-step path — the number
-        # the decode_horizon knob exists to shrink);
-        # decode_horizon_steps counts device decode steps executed
-        # inside decode_multi horizons; horizon_overshoot_tokens counts
-        # drained tokens discarded because their request stopped earlier
-        # in the horizon (their pages are reclaimed on the spot)
-        self.host_syncs = Counter("host_syncs")
-        self.decode_horizon_steps = Counter("decode_horizon_steps")
-        self.horizon_overshoot_tokens = Counter("horizon_overshoot_tokens")
-        # zero-bubble pipelined loop (ISSUE 11): planned_ahead_steps
-        # counts steps whose host planning ran while a previous launch
-        # was still in flight on the device. Where a step's time goes
-        # (plan, batch build, launch, drain, commit) is read from the
-        # engine's spans against the device trace, not counted here
-        self.planned_ahead_steps = Counter("planned_ahead_steps")
-        # tiered KV offload (ISSUE 10): offload_spill_pages counts device
-        # pages copied to the host tier (preemption spills AND prefix
-        # demotions), pagein_pages counts pages restored to device, and
-        # pagein_hidden_pages the subset whose device_put was issued in
-        # an EARLIER engine step than the fence that consumed it — i.e.
-        # the host->device copy had a whole step of device compute to
-        # hide behind (pagein_hidden_ratio is the overlap headline).
-        # offload_resumes / offload_recompute_fallbacks split resumed
-        # requests by path; host_tier_drops counts spills a full tier
-        # refused (those resumes degrade to recompute, exactness kept).
-        self.offload_spill_pages = Counter("offload_spill_pages")
-        self.pagein_pages = Counter("pagein_pages")
-        self.pagein_hidden_pages = Counter("pagein_hidden_pages")
-        self.offload_resumes = Counter("offload_resumes")
-        self.offload_recompute_fallbacks = Counter(
-            "offload_recompute_fallbacks")
-        self.host_tier_drops = Counter("host_tier_drops")
-        self.host_tier_bytes = Gauge("host_tier_bytes")
-        self.host_tier_pages_used = Gauge("host_tier_pages_used")
-        # prefill/decode split (ISSUE 12): handoffs_out counts requests
-        # a prefill-role engine staged for migration after their first
-        # sampled token (handoff_pages_out = KV pages spilled for them);
-        # handoffs_in counts requests a decode-role engine accepted with
-        # a wire-transferred page payload (handoff_pages_in = pages
-        # imported, content-hash-verified at receive); a handoff whose
-        # pages could not ride along — no host tier, tier full — lands
-        # in handoff_recompute_fallbacks and resumes by recompute,
-        # token-exact as ever
-        self.handoffs_out = Counter("handoffs_out")
-        self.handoffs_in = Counter("handoffs_in")
-        self.handoff_pages_out = Counter("handoff_pages_out")
-        self.handoff_pages_in = Counter("handoff_pages_in")
-        self.handoff_recompute_fallbacks = Counter(
-            "handoff_recompute_fallbacks")
-        # cluster-wide KV store (ISSUE 14): handoff_bytes_out counts
-        # raw page-payload bytes a handoff actually serialized (the
-        # byte-copy path; slot-reference handoffs over the shared
-        # store add ZERO here — the number the bench arms compare);
-        # store_hit_pages counts pages this engine paged in from the
-        # host-wide content index (a sibling's demotion served this
-        # replica), store_dedup_pages counts copies skipped because
-        # the chain was already store-resident
-        self.handoff_bytes_out = Counter("handoff_bytes_out")
-        self.store_hit_pages = Counter("store_hit_pages")
-        self.store_dedup_pages = Counter("store_dedup_pages")
-        self.decode_steps = Counter("decode_steps")
-        self.queue_depth = Gauge("queue_depth")
-        self.running = Gauge("running")
-        self.prefix_cached_pages = Gauge("prefix_cached_pages")
-        # instrumented-pool counters (ISSUE 4), mirrored from the
-        # runner's host-side accounting each step: KV-pool bytes the
-        # chosen attention path actually touched vs what the gather
-        # reference path would have read for the same calls — the
-        # CPU-countable form of the ragged kernel's bandwidth win
-        self.attn_kv_bytes_read = Gauge("attn_kv_bytes_read")
-        self.attn_kv_bytes_gather = Gauge("attn_kv_bytes_gather")
-        # blocks of pages the ragged kernel's few-rows walks folded (one
-        # layer's walk a launch) and those of them folded in full, as a
-        # walk's edge blocks are: 1 - edge / all is how often the lean
-        # fold engages; mirrored from the runner like the bytes
-        self.ragged_blocks = Gauge("ragged_blocks")
-        self.ragged_edge_blocks = Gauge("ragged_edge_blocks")
-        # quantized collectives (ISSUE 15), mirrored from the runner's
-        # host-side comm accounting each step: wire bytes the
-        # row-parallel allreduces moved PER SHARD at the configured
-        # comm_dtype (int8 code bytes PLUS the per-(row, chunk) scale
-        # bytes — honest accounting) vs the fp32 cost of the same
-        # calls; the reduction gauge is their ratio, i.e. the measured
-        # interconnect win, CPU-countable like the attention bytes
-        self.tp_comm_bytes = Gauge("tp_comm_bytes")
-        self.tp_comm_bytes_fp32 = Gauge("tp_comm_bytes_fp32")
-        self.tp_comm_bytes_reduction_x = Gauge("tp_comm_bytes_reduction_x")
-        # the gather direction (ISSUE 19): wire bytes the column-
-        # parallel all-gathers (the lm_head logits path) moved per
-        # shard at the configured comm_dtype vs fp32 — same honest
-        # scale-bytes-counted accounting as the allreduce gauges
-        self.tp_gather_bytes = Gauge("tp_gather_bytes")
-        self.tp_gather_bytes_fp32 = Gauge("tp_gather_bytes_fp32")
-        self.tp_gather_bytes_reduction_x = Gauge(
-            "tp_gather_bytes_reduction_x")
-        # weight-ladder accounting (ISSUE 19): logical fp32 weight
-        # bytes over resident bytes (packed int4 codes + group scales /
-        # fp8 casts, scale bytes counted; 1.0 on fp32 runners) —
-        # measured from what the params dict actually stores
-        self.weight_bytes_reduction_x = Gauge("weight_bytes_reduction_x")
-        # quantized-KV accounting (ISSUE 9): per-page byte reduction of
-        # the pool vs storing at the logical dtype (scale bytes counted;
-        # 1.0 on fp32 pools), and the matching concurrent-sessions-per-
-        # fixed-HBM factor — page count per byte budget scales by the
-        # same ratio. Set from KVCachePool geometry, i.e. MEASURED from
-        # what the pools actually store, never assumed
-        self.kv_bytes_reduction_x = Gauge("kv_bytes_reduction_x")
-        self.sessions_per_pool_x = Gauge("sessions_per_pool_x")
-        self.pool_used_pages = Gauge("pool_used_pages")
-        self.pool_utilization = Gauge("pool_utilization")
-        self.batch_occupancy = Histogram("batch_occupancy")
-        self.ttft_s = Histogram("ttft_s")
-        self.e2e_latency_s = Histogram("e2e_latency_s")
         # gauge writes a step put off (`put_off`), settled by whoever
         # reads or writes a gauge first
         self.owed: Optional[Callable[[], None]] = None
-        for inst in vars(self).values():
-            if isinstance(inst, Gauge):
-                inst.due = self.settle
+        self._instruments: Dict[str, object] = {}
+        for name, kind, _ in _OWN:
+            self.declare(kind, (name,))
+
+    def declare(self, kind, names) -> list:
+        """The instruments of `kind` under `names`, made where they are
+        new: how a runner's counts (`COUNTS`) and a runner's and a pool's
+        gauges come to be here, under the names their owner gives and
+        nobody else spells. They show in snapshot() from then on, and a
+        tier sums them."""
+        out = []
+        for name in names:
+            inst = self._instruments.get(name)
+            if inst is None:
+                if hasattr(self, name):
+                    raise ValueError(f"an instrument cannot be named "
+                                     f"{name!r}: EngineMetrics has that")
+                inst = self._instruments[name] = kind(name)
+                if kind is Gauge:
+                    inst.due = self.settle
+                setattr(self, name, inst)
+            elif type(inst) is not kind:
+                raise ValueError(
+                    f"{name!r} is a {type(inst).__name__} here and cannot "
+                    f"be declared a {kind.__name__}")
+            out.append(inst)
+        return out
 
     def put_off(self, write: Callable[[], None]) -> None:
         """Owe the gauges `write()`: the engine's end-of-step readings,
@@ -411,124 +337,19 @@ class EngineMetrics:
         if write is not None:
             write()
 
-    def spec_acceptance_rate(self) -> float:
-        """Accepted / proposed draft tokens (0.0 when nothing proposed)."""
-        p = self.spec_proposed_tokens.value
-        return self.spec_accepted_tokens.value / p if p > 0 else 0.0
-
-    def pagein_hidden_ratio(self) -> float:
-        """Fraction of paged-in pages whose host->device transfer was
-        issued at least one engine step before the fence that read them
-        (ISSUE 10) — the overlap the async double-buffered page-in
-        exists to create. 0.0 when nothing paged in."""
-        p = self.pagein_pages.value
-        return self.pagein_hidden_pages.value / p if p > 0 else 0.0
-
-    def host_syncs_per_token(self) -> float:
-        """Blocking device->host drains per generated token (ISSUE 6) —
-        1.0 on the per-step loop, ~1/s with decode_horizon=s."""
-        t = self.tokens_generated.value
-        return self.host_syncs.value / t if t > 0 else 0.0
-
-    def steps_per_token(self) -> float:
-        """Engine steps per generated token — the number speculation
-        drives BELOW 1/batch-occupancy: each accepted draft token is a
-        token that never paid its own engine step."""
-        t = self.tokens_generated.value
-        return self.decode_steps.value / t if t > 0 else 0.0
+    def ratio(self, key: str) -> float:
+        """One of `RATIOS` as the instruments stand (0.0 over nothing):
+        spec_acceptance_rate, accepted over proposed draft tokens;
+        pagein_hidden_ratio, the share of paged-in pages whose
+        host->device copy was issued a step before the fence that read
+        them; host_syncs_per_token, 1.0 on the per-step loop and ~1/s
+        with decode_horizon=s; steps_per_token, which speculation drives
+        below 1/batch-occupancy."""
+        return _over(*(self._instruments[n].value for n in RATIOS[key]))
 
     def snapshot(self) -> Dict[str, float]:
-        return {
-            "requests_added": self.requests_added.value,
-            "requests_finished": self.requests_finished.value,
-            "preemptions": self.preemptions.value,
-            "requests_timed_out": self.requests_timed_out.value,
-            "requests_aborted": self.requests_aborted.value,
-            "step_retries": self.step_retries.value,
-            "nan_logit_events": self.nan_logit_events.value,
-            "shed_requests": self.shed_requests.value,
-            "tokens_generated": self.tokens_generated.value,
-            "moe_tokens_routed": self.moe_tokens_routed.value,
-            "moe_local_pairs": self.moe_local_pairs.value,
-            "moe_experts_touched": self.moe_experts_touched.value,
-            "latent_copy_groups": self.latent_copy_groups.value,
-            "latent_run_groups": self.latent_run_groups.value,
-            "dsa_keys_scored": self.dsa_keys_scored.value,
-            "dsa_keys_selected": self.dsa_keys_selected.value,
-            "delta_decode_seq_steps": self.delta_decode_seq_steps.value,
-            "delta_prefill_tokens": self.delta_prefill_tokens.value,
-            "delta_prefill_positions": self.delta_prefill_positions.value,
-            "state_slot_resets": self.state_slot_resets.value,
-            "state_slots_live": self.state_slots_live.value,
-            "ssm_decode_seq_steps": self.ssm_decode_seq_steps.value,
-            "ssm_prefill_tokens": self.ssm_prefill_tokens.value,
-            "cross_rows_skipped": self.cross_rows_skipped.value,
-            "window_pages_held": self.window_pages_held.value,
-            "window_pages_whole_context":
-                self.window_pages_whole_context.value,
-            "window_pages_returned": self.window_pages_returned.value,
-            "prefill_tokens": self.prefill_tokens.value,
-            "prefill_chunks": self.prefill_chunks.value,
-            "prefix_hit_tokens": self.prefix_hit_tokens.value,
-            "cow_copies": self.cow_copies.value,
-            "prefix_cached_pages": self.prefix_cached_pages.value,
-            "attn_kv_bytes_read": self.attn_kv_bytes_read.value,
-            "attn_kv_bytes_gather": self.attn_kv_bytes_gather.value,
-            "ragged_blocks": self.ragged_blocks.value,
-            "ragged_edge_blocks": self.ragged_edge_blocks.value,
-            "tp_comm_bytes": self.tp_comm_bytes.value,
-            "tp_comm_bytes_fp32": self.tp_comm_bytes_fp32.value,
-            "tp_comm_bytes_reduction_x":
-                self.tp_comm_bytes_reduction_x.value,
-            "tp_gather_bytes": self.tp_gather_bytes.value,
-            "tp_gather_bytes_fp32": self.tp_gather_bytes_fp32.value,
-            "tp_gather_bytes_reduction_x":
-                self.tp_gather_bytes_reduction_x.value,
-            "weight_bytes_reduction_x":
-                self.weight_bytes_reduction_x.value,
-            "kv_bytes_reduction_x": self.kv_bytes_reduction_x.value,
-            "sessions_per_pool_x": self.sessions_per_pool_x.value,
-            "spec_proposed_tokens": self.spec_proposed_tokens.value,
-            "spec_accepted_tokens": self.spec_accepted_tokens.value,
-            "spec_rollback_pages": self.spec_rollback_pages.value,
-            "spec_fused_horizons": self.spec_fused_horizons.value,
-            "spec_dead_positions": self.spec_dead_positions.value,
-            "spec_acceptance_rate": self.spec_acceptance_rate(),
-            "steps_per_token": self.steps_per_token(),
-            "host_syncs": self.host_syncs.value,
-            "host_syncs_per_token": self.host_syncs_per_token(),
-            "decode_horizon_steps": self.decode_horizon_steps.value,
-            "horizon_overshoot_tokens": self.horizon_overshoot_tokens.value,
-            "planned_ahead_steps": self.planned_ahead_steps.value,
-            "offload_spill_pages": self.offload_spill_pages.value,
-            "pagein_pages": self.pagein_pages.value,
-            "pagein_hidden_pages": self.pagein_hidden_pages.value,
-            "pagein_hidden_ratio": self.pagein_hidden_ratio(),
-            "offload_resumes": self.offload_resumes.value,
-            "offload_recompute_fallbacks":
-                self.offload_recompute_fallbacks.value,
-            "host_tier_drops": self.host_tier_drops.value,
-            "host_tier_bytes": self.host_tier_bytes.value,
-            "host_tier_pages_used": self.host_tier_pages_used.value,
-            "handoffs_out": self.handoffs_out.value,
-            "handoffs_in": self.handoffs_in.value,
-            "handoff_pages_out": self.handoff_pages_out.value,
-            "handoff_pages_in": self.handoff_pages_in.value,
-            "handoff_recompute_fallbacks":
-                self.handoff_recompute_fallbacks.value,
-            "handoff_bytes_out": self.handoff_bytes_out.value,
-            "store_hit_pages": self.store_hit_pages.value,
-            "store_dedup_pages": self.store_dedup_pages.value,
-            "decode_steps": self.decode_steps.value,
-            "queue_depth": self.queue_depth.value,
-            "queue_depth_peak": self.queue_depth.peak,
-            "running": self.running.value,
-            "pool_used_pages": self.pool_used_pages.value,
-            "pool_utilization_peak": self.pool_utilization.peak,
-            "batch_occupancy_mean": self.batch_occupancy.mean,
-            "ttft_s_p50": self.ttft_s.percentile(50),
-            "ttft_s_p99": self.ttft_s.percentile(99),
-            "ttft_s_mean": self.ttft_s.mean,
-            "e2e_latency_s_p50": self.e2e_latency_s.percentile(50),
-            "e2e_latency_s_p99": self.e2e_latency_s.percentile(99),
-        }
+        out: Dict[str, float] = {}
+        for name, inst in self._instruments.items():
+            for stat in _SHOWN.get(name, ("value",)):
+                out[_key(name, stat)] = getattr(inst, stat)
+        return _with_ratios(out)

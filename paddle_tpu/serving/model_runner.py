@@ -3,8 +3,10 @@
 Reference: the reference serving stack splits "model" from "engine" the
 same way — fluid/inference executes the network, the serving layer above
 owns batching — with block_multihead_attention as the seam. Here a
-runner adapts a decoder Layer (models.Llama, models.GPT) into two jitted
-step functions over the shared page pool:
+runner adapts a decoder Layer into jitted step functions over the shared
+page pool. This module is the chassis every runner shares
+(`PagedModelRunner`) and names no served configuration: those are a
+module each under `serving/runners/`, whose table `runner_for` reads.
 
   prefill(tokens[1, T], table[1, P], real_len, pools) -> (logits[V], pools)
   prefill_chunk(tokens, start_pos, table, pools)      -> (logits[V], pools)
@@ -122,13 +124,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 logger = logging.getLogger(__name__)
 
 from paddle_tpu import profiler as _prof
-from paddle_tpu.models.generation import (
-    _block_params, _layer_norm, _mlp, masked_cache_attention, paged_gather,
-)
-from paddle_tpu.models import deepseek_v3 as _dsv3
-from paddle_tpu.models import olmo_hybrid as _olmo
-from paddle_tpu.models import phi4flash as _phi
-from paddle_tpu.models.llama import _rope_tables
+from paddle_tpu.models.generation import masked_cache_attention, paged_gather
 from paddle_tpu.serving.kv_cache import (
     KV_DTYPES, SCRATCH_PAGE, fp8_page_write, fp8_round, kv_pair_layout,
     quantized_page_write, require_fp8,
@@ -190,9 +186,6 @@ def bucket_len(t: int, minimum: int = 8) -> int:
     return b
 
 
-_bucket_len = bucket_len          # pre-rename spelling (internal callers)
-
-
 def _shard_mapped_kernel(kernel, shard_ctx, q_spec, rest_specs=()):
     """Wrap a paged-attention Pallas kernel so it runs PER MODEL SHARD
     (ISSUE 7): q and the K/V pools split on their (kv-)head axis, the
@@ -221,121 +214,9 @@ def _shard_mapped_kernel(kernel, shard_ctx, q_spec, rest_specs=()):
         )(q, k_pool, v_pool, tables, pos_q, *rest)
 
     return run
-
-
-def _latent_attend(q, latent_new, layer_pools, tables, write_page,
-                   write_off, pos_q, q_len, impl: str, scale: float,
-                   v_lanes: int, runs=None):
-    """paged_attend for a LATENT layer: one array a page, `[num_blocks,
-    page, lanes]`, each token's row its compressed key whose first
-    `v_lanes` lanes are also its value, shared by every query head (the
-    absorbed form of latent attention; models/deepseek_v3.py). q: [B, T,
-    n_h, lanes]; latent_new: [B, T, lanes]. Returns ([B, T, n_h,
-    v_lanes], (pool,)): the per-head sums of p . value, which the caller
-    takes through its value projection. "ragged" is the kernel over
-    latent pages (a decode step: T == 1; `runs` its flags of which
-    groups of the table are consecutive pages, where the caller's layers
-    share one table), "reference" the gather path for any span."""
-    (pool,) = layer_pools
-    pool = pool.at[write_page, write_off].set(latent_new.astype(pool.dtype))
-    B, T = q.shape[0], q.shape[1]
-    if impl == "ragged":
-        from paddle_tpu.ops.pallas.latent_paged_attention import \
-            latent_paged_attention
-
-        if T != 1:
-            raise ValueError(f"the latent kernel is a decode kernel; span "
-                             f"of {T} rows")
-        out = latent_paged_attention(q[:, 0], pool, tables, pos_q,
-                                     v_lanes=v_lanes, scale=scale, runs=runs)
-        return out[:, None], (pool,)
-    lat = pool[tables].reshape(B, -1, pool.shape[-1])           # [B, L, lanes]
-    s = jnp.einsum("bthc,blc->bhtl", q, lat,
-                   preferred_element_type=jnp.float32) * scale
-    t_idx = jnp.arange(T, dtype=jnp.int32)
-    visible = ((jnp.arange(lat.shape[1], dtype=jnp.int32)[None, None, :]
-                <= pos_q[:, None, None] + t_idx[None, :, None])
-               & (t_idx[None, :, None] < q_len[:, None, None]))  # [B, T, L]
-    p = jax.nn.softmax(jnp.where(visible[:, None], s, -1e30), axis=-1)
-    out = jnp.einsum("bhtl,blc->bthc", p.astype(lat.dtype),
-                     lat[..., :v_lanes])
-    return out.astype(q.dtype), (pool,)
-
-
-def _sparse_latent_attend(q, latent_new, index, layer_pools, tables,
-                          write_page, write_off, pos_q, q_len, impl: str,
-                          scale: float, v_lanes: int, topk: int, runs=None):
-    """paged_attend for a latent layer under a learned selection (DeepSeek
-    Sparse Attention): TWO arrays a page behind one table, the latent rows
-    and the indexer's keys `[num_blocks, page, index lanes]`. `index` is
-    the indexer's view of the new tokens (models/deepseek_v3.index_project,
-    padded to the page's lanes): queries [B, T, heads, lanes], the tokens'
-    keys [B, T, lanes], the heads' weights [B, T, heads] float32. Each
-    query row scores the index keys of its context, keeps the `topk` best
-    (ties to the lower position) and attends over those rows alone.
-    "ragged" is a decode step on the chip: the scan kernel over index
-    pages, then the latent kernel's walk over every live page with each
-    block folded under the selection (`topk_threshold`: exact, no list of
-    rows is made; ops/pallas/sparse_latent_attention.py says why the walk
-    and not a fetch by row). `runs`: (the scan's, the walk's) flags of
-    consecutive pages. "reference" is the gather path for any span. Returns ([B, T,
-    n_h, v_lanes], (pool, index pool))."""
-    from paddle_tpu.ops.pallas import sparse_latent_attention as sla
-
-    pool, ipool = layer_pools
-    q_i, k_i, w_i = index
-    pool = pool.at[write_page, write_off].set(latent_new.astype(pool.dtype))
-    ipool = ipool.at[write_page, write_off].set(k_i.astype(ipool.dtype))
-    B, T = q.shape[0], q.shape[1]
-    if impl == "ragged":
-        if T != 1:
-            raise ValueError(f"the sparse latent kernels are decode "
-                             f"kernels; span of {T} rows")
-        scan_runs, walk_runs = runs if runs is not None else (None, None)
-        with jax.named_scope("block/dsa/index"):
-            scores = sla.paged_index_scores(q_i[:, 0], w_i[:, 0], ipool,
-                                            tables, pos_q, runs=scan_runs)
-        keys = scores.shape[1]
-        if keys <= topk:                 # every visible key is chosen
-            with jax.named_scope("block/dsa/attend"):
-                out = sla.latent_paged_attention(
-                    q[:, 0], pool, tables, pos_q, v_lanes=v_lanes,
-                    scale=scale, runs=walk_runs)
-        else:
-            with jax.named_scope("block/dsa/select"):
-                value, last = _dsv3.topk_threshold(scores, topk)
-            with jax.named_scope("block/dsa/attend"):
-                out = sla.latent_paged_attention(
-                    q[:, 0], pool, tables, pos_q, v_lanes=v_lanes,
-                    scale=scale, runs=walk_runs,
-                    select=(scores, value, last))
-        return out[:, None], (pool, ipool)
-    L = tables.shape[1] * pool.shape[1]
-    t_idx = jnp.arange(T, dtype=jnp.int32)
-    visible = ((jnp.arange(L, dtype=jnp.int32)[None, None, :]
-                <= pos_q[:, None, None] + t_idx[None, :, None])
-               & (t_idx[None, :, None] < q_len[:, None, None]))  # [B, T, L]
-    with jax.named_scope("block/dsa/index"):
-        scores = jax.vmap(_dsv3.index_scores)(
-            q_i, w_i, ipool[tables].reshape(B, L, ipool.shape[-1]))
-    with jax.named_scope("block/dsa/select"):
-        chosen = visible & _dsv3.topk_mask(
-            jnp.where(visible, scores, -jnp.inf).reshape(B * T, L), topk
-        ).reshape(B, T, L)
-    with jax.named_scope("block/dsa/attend"):
-        lat = pool[tables].reshape(B, L, pool.shape[-1])
-        s = jnp.einsum("bthc,blc->bhtl", q, lat,
-                       preferred_element_type=jnp.float32) * scale
-        p = jax.nn.softmax(jnp.where(chosen[:, None], s, -1e30), axis=-1)
-        out = jnp.einsum("bhtl,blc->bthc", p.astype(lat.dtype),
-                         lat[..., :v_lanes])
-    return out.astype(q.dtype), (pool, ipool)
-
-
 def paged_attend(q, k_new, v_new, layer_pools, tables, write_page,
                  write_off, pos_q, q_len, n_rep: int, impl: str,
-                 shard_ctx=None, scale=None, v_lanes=None, runs=None,
-                 kind: str = "kv", index=None, topk=None):
+                 shard_ctx=None):
     """Write this step's K/V through the block table, then attend.
 
     q: [B, T, n_h, d]; k_new/v_new: [B, T, n_kv, d]; layer_pools: one
@@ -354,25 +235,8 @@ def paged_attend(q, k_new, v_new, layer_pools, tables, write_page,
     (ISSUE 7): the kernels then run per-shard via shard_map on each
     shard's kv-head slice; the gather reference path needs no wrapper —
     GSPMD partitions it from the pool sharding alone. Returns
-    ([B, T, n_h*d], new_layer_pools).
-
-    `kind` says what the layer's pages hold, "kv" above. "latent": ONE
-    array a page; k_new is the tokens' latent rows, v_new None, `scale`
-    the softmax scale and `v_lanes` the value's lanes, `runs` the
-    kernel's run flags (see _latent_attend, whose return it is).
-    "latent+index": the latent rows and the indexer's keys, attended
-    under the `topk` selection of `index` (see _sparse_latent_attend)."""
-    if kind == "latent":
-        return _latent_attend(q, k_new, layer_pools, tables, write_page,
-                              write_off, pos_q, q_len, impl, scale, v_lanes,
-                              runs)
-    if kind == "latent+index":
-        return _sparse_latent_attend(q, k_new, index, layer_pools, tables,
-                                     write_page, write_off, pos_q, q_len,
-                                     impl, scale, v_lanes, topk, runs)
-    if kind != "kv":
-        raise ValueError(f"paged_attend(kind={kind!r}); expected 'kv', "
-                         "'latent' or 'latent+index'")
+    ([B, T, n_h*d], new_layer_pools). A runner whose pages hold
+    something else than a (k, v) pair writes and attends them itself."""
     quantized = len(layer_pools) == 4
     mixed = len(layer_pools) == 3
     if quantized:
@@ -470,6 +334,22 @@ class PagedModelRunner:
     # what a single-pass step counts on the device, by name: such a
     # runner's `_forward` returns `(logits, pools, counts[len(COUNTS)])`
     COUNTS = ()
+    # the host-side counters this runner keeps as attributes under these
+    # names (`_account_attn`, `_account_blocks`, `_account_comm`), which an
+    # engine mirrors into gauges of the same names at each step's end.
+    # KV-pool bytes the chosen attention path touched against what the
+    # gather reference would have read for the same calls (PER SHARD on a
+    # sharded runner: each shard walks only its own kv-head slice); blocks
+    # of pages the ragged kernel's few-rows walks folded (one layer's walk
+    # a launch: every layer walks the same) and those of them folded in
+    # full, as a walk's edge blocks are; wire bytes PER SHARD the
+    # row-parallel allreduces and the column-parallel all-gathers moved at
+    # the configured comm dtype (scale bytes counted) against what fp32
+    # collectives would have moved for the same calls
+    GAUGES = ("attn_kv_bytes_read", "attn_kv_bytes_gather",
+              "ragged_blocks", "ragged_edge_blocks",
+              "tp_comm_bytes", "tp_comm_bytes_fp32",
+              "tp_gather_bytes", "tp_gather_bytes_fp32")
     # True: `_forward` takes `head_rows` [B] and returns logits [B, 1, V]
     # at those rows only, so the steps that want a span's last row never
     # make [B, T, V] (a 16 k prefill bucket times a vocabulary)
@@ -548,29 +428,7 @@ class PagedModelRunner:
         # are their per-shard output widths (the gather wire operands)
         self._gather_names: frozenset = frozenset()
         self._gather_out_dims: tuple = ()
-        # instrumented-comm counters (ISSUE 15): wire bytes PER SHARD
-        # the row-parallel allreduces moved at the configured comm
-        # dtype vs what fp32 psums would have moved for the same calls
-        # (scale bytes counted on the int8 side) — host-side analytics
-        # like the attention byte counters below. ISSUE 19 adds the
-        # gather direction's pair (the column-parallel all-gather)
-        self.tp_comm_bytes = 0.0
-        self.tp_comm_bytes_fp32 = 0.0
-        self.tp_gather_bytes = 0.0
-        self.tp_gather_bytes_fp32 = 0.0
-        # instrumented-pool counters: HBM bytes of KV pool the chosen
-        # attention path touches (host-side analytics, CPU-countable) vs
-        # what the gather path would have read for the same calls.
-        # Sharded runners count PER-SHARD bytes (each shard walks only
-        # its own kv-head slice, so sharded = single-device / tp)
-        self.attn_kv_bytes_read = 0.0
-        self.attn_kv_bytes_gather = 0.0
-        # blocks of pages the ragged kernel's few-rows walks folded (one
-        # layer's walk a launch: every layer walks the same), and those
-        # of them folded in full, as a walk's edge blocks are; counted
-        # on the host like the bytes (`ragged_block_counts`)
-        self.ragged_blocks = 0
-        self.ragged_edge_blocks = 0
+        self.reset_attn_counters()          # `GAUGES`, all from zero
         self._fold_pages = {}       # span bucket -> _fold_block_pages
         # where a single-pass step's counts go (`COUNTS`): the engine
         # sets this to collect them for its drain; None drops them
@@ -595,15 +453,22 @@ class PagedModelRunner:
         first writes it."""
         return None
 
+    def _hand_over(self, counts) -> None:
+        """A launch's counts, an output of its program and still on the
+        device, to whoever asked for them (`on_step_counts`): THE site
+        they leave a runner by."""
+        if self.on_step_counts is not None:
+            self.on_step_counts(counts)
+
     def _emit(self, out):
         """A single-pass step's outputs as its public entry returns
         them, `(logits, pools)`. A runner that counts (`COUNTS`) has its
         `_forward` return `(logits, pools, counts)`, the steps pass the
-        third on as an output of the program, and it goes from here to
-        whoever asked for it (`on_step_counts`), still on the device."""
+        third on as an output of the program, and it is handed over
+        from here."""
         logits, pools, *counts = out
-        if counts and self.on_step_counts is not None:
-            self.on_step_counts(counts[0])
+        if counts:
+            self._hand_over(counts[0])
         return logits, pools
 
     @property
@@ -1182,13 +1047,8 @@ class PagedModelRunner:
             self.tp_gather_bytes += allgather_bytes(r, d, self.comm_dtype)
 
     def reset_attn_counters(self) -> None:
-        self.attn_kv_bytes_read = 0.0
-        self.attn_kv_bytes_gather = 0.0
-        self.ragged_blocks = self.ragged_edge_blocks = 0
-        self.tp_comm_bytes = 0.0
-        self.tp_comm_bytes_fp32 = 0.0
-        self.tp_gather_bytes = 0.0
-        self.tp_gather_bytes_fp32 = 0.0
+        for name in self.GAUGES:
+            setattr(self, name, 0.0)
 
     # ----------------------------------- weight byte accounting (ISSUE 19)
 
@@ -1845,1399 +1705,23 @@ class PagedModelRunner:
         raise NotImplementedError
 
 
-class LlamaRunner(PagedModelRunner):
-    """Paged-step adapter for models.Llama (RMSNorm + RoPE + GQA + SwiGLU).
-
-    Params come from jit.functionalize, so the runner serves exactly the
-    weights of the Layer it was built from."""
-
-    def __init__(self, model, block_size: int = 16,
-                 max_model_len: int | None = None, attn_impl: str = "auto",
-                 **quant):
-        from paddle_tpu.jit.functionalize import functionalize
-
-        cfg = model.cfg
-        params = functionalize(model).param_values()
-        super().__init__(params, block_size,
-                         max_model_len or cfg.max_seq_len, attn_impl,
-                         **quant)
-        self.cfg = cfg
-        self.num_layers = cfg.num_layers
-        self.n_heads = cfg.num_heads
-        self.n_kv_heads = cfg.num_kv_heads
-        self.head_dim = cfg.hidden_size // cfg.num_heads
-        self.vocab_size = cfg.vocab_size
-        cos, sin = _rope_tables(self.max_model_len, self.head_dim,
-                                cfg.rope_theta)
-        self._rope_cos, self._rope_sin = cos, sin      # [L, d] fp32
-        if self.weight_dtype != "fp32":
-            names = []
-            for i in range(self.num_layers):
-                pre = f"layers.{i}."
-                names += [pre + n for n in (
-                    "self_attn.q_proj.weight", "self_attn.k_proj.weight",
-                    "self_attn.v_proj.weight", "self_attn.o_proj.weight",
-                    "mlp.gate_proj.weight", "mlp.up_proj.weight",
-                    "mlp.down_proj.weight")]
-            if "lm_head.weight" in self.params:
-                names.append("lm_head.weight")
-            # embeddings stay floating (lookup table; tied heads reuse it)
-            self._quantize_weights(names)
-
-    def _param_specs(self, layout):
-        """Megatron placements for the Llama block (ISSUE 7): column-
-        wise Q/K/V and gate/up (each shard computes its own head /
-        hidden slice), row-wise o_proj/down_proj (allreduce on the row
-        output), vocab-sharded embeddings; norms replicated (default)."""
-        col, row = layout.column_parallel(), layout.row_parallel()
-        specs = {"embed_tokens.weight": layout.embeddings()}
-        for i in range(self.num_layers):
-            pre = f"layers.{i}."
-            specs[pre + "self_attn.q_proj.weight"] = col
-            specs[pre + "self_attn.k_proj.weight"] = col
-            specs[pre + "self_attn.v_proj.weight"] = col
-            specs[pre + "self_attn.o_proj.weight"] = row
-            specs[pre + "mlp.gate_proj.weight"] = col
-            specs[pre + "mlp.up_proj.weight"] = col
-            specs[pre + "mlp.down_proj.weight"] = row
-        if "lm_head.weight" in self.params:        # [H, V]: column-wise
-            specs["lm_head.weight"] = col
-        return specs
-
-    def _rope(self, x, cos, sin):
-        # same rotate-half convention as ops.rotary_embedding
-        x1, x2 = jnp.split(x, 2, axis=-1)
-        rot = jnp.concatenate([-x2, x1], axis=-1)
-        return (x * cos[:, :, None, :] + rot * sin[:, :, None, :]
-                ).astype(x.dtype)
-
-    def _rms(self, x, w, eps):
-        xf = x.astype(jnp.float32)
-        var = jnp.mean(jnp.square(xf), axis=-1, keepdims=True)
-        return (xf * jax.lax.rsqrt(var + eps)).astype(x.dtype) * w
-
-    def _forward(self, params, tokens, positions, write_page, write_off,
-                 tables, pos_q, q_lens, pools):
-        cfg = self.cfg
-        B, T = tokens.shape
-        d = self.head_dim
-        impl = self._attn_impl_for(T)
-        x = jnp.take(params["embed_tokens.weight"], tokens, axis=0)
-        cos = jnp.take(self._rope_cos, positions, axis=0)   # [B, T, d]
-        sin = jnp.take(self._rope_sin, positions, axis=0)
-        new_pools = []
-        for i in range(cfg.num_layers):
-            pre = f"layers.{i}."
-            h = self._rms(x, params[pre + "input_layernorm.weight"],
-                          cfg.rms_eps)
-            q = self._mm(params, pre + "self_attn.q_proj.weight", h
-                         ).reshape(B, T, self.n_heads, d)
-            k = self._mm(params, pre + "self_attn.k_proj.weight", h
-                         ).reshape(B, T, self.n_kv_heads, d)
-            v = self._mm(params, pre + "self_attn.v_proj.weight", h
-                         ).reshape(B, T, self.n_kv_heads, d)
-            q = self._rope(q, cos, sin)
-            k = self._rope(k, cos, sin)
-            q, k, v = self._constrain_heads(q, k, v)
-            out, layer = paged_attend(
-                q, k, v, pools[i], tables, write_page,
-                write_off, pos_q, q_lens, self.n_rep, impl,
-                shard_ctx=self._shard_ctx)
-            x = x + self._mm(params, pre + "self_attn.o_proj.weight", out)
-            h = self._rms(x, params[pre + "post_attention_layernorm.weight"],
-                          cfg.rms_eps)
-            gate = self._mm(params, pre + "mlp.gate_proj.weight", h)
-            up = self._mm(params, pre + "mlp.up_proj.weight", h)
-            x = x + self._mm(params, pre + "mlp.down_proj.weight",
-                             jax.nn.silu(gate) * up)
-            new_pools.append(layer)
-        x = self._rms(x, params["norm.weight"], cfg.rms_eps)
-        if cfg.tie_embeddings:
-            logits = x @ params["embed_tokens.weight"].T
-        else:
-            logits = self._mm(params, "lm_head.weight", x)
-        return logits, new_pools
-
-
-class GPTRunner(PagedModelRunner):
-    """Paged-step adapter for models.GPT — reuses the functional block
-    helpers the dense-cache generator already runs."""
-
-    def __init__(self, model, block_size: int = 16,
-                 max_model_len: int | None = None, attn_impl: str = "auto",
-                 **quant):
-        from paddle_tpu.jit.functionalize import functionalize
-
-        cfg = model.cfg
-        params = functionalize(model).param_values()
-        super().__init__(params, block_size,
-                         max_model_len or cfg.max_seq_len, attn_impl,
-                         **quant)
-        self.cfg = cfg
-        self.num_layers = cfg.num_layers
-        self.n_heads = cfg.num_heads
-        self.n_kv_heads = cfg.num_heads
-        self.head_dim = cfg.hidden_size // cfg.num_heads
-        self.vocab_size = cfg.vocab_size
-        if self.weight_dtype != "fp32":
-            # GPT stores the fused QKV weight FLAT as [hidden, 3*nh*d]
-            # (column order (3, nh, d)), so per-output-channel/group
-            # abs-max quantization is exact per fused column; the
-            # quantizers reject a raw (3, nh, d) tensor loudly (ISSUE 9
-            # satellite, generalized to int4 in ISSUE 19) rather than
-            # silently scaling over the qkv axis.
-            # MoE blocks (mlp.gate present) keep their expert weights
-            # floating — only dense matmul matrices quantize.
-            names = []
-            for i in range(self.num_layers):
-                pre = f"blocks.{i}."
-                names += [pre + "attn.qkv.weight", pre + "attn.out.weight"]
-                if pre + "mlp.fc1.weight" in self.params:
-                    names += [pre + "mlp.fc1.weight", pre + "mlp.fc2.weight"]
-            if "lm_head.weight" in self.params:
-                names.append("lm_head.weight")
-            self._quantize_weights(names)
-
-    def _param_specs(self, layout):
-        """GPT placements (ISSUE 7). The fused attn.qkv weight keeps its
-        (3, n_heads, d) column layout — a flat column shard would split
-        across the q/k/v boundary — so it stays replicated and the
-        sharded K/V POOLS carry the attention split instead (the head-
-        sharded pool makes the whole attention block compute per-shard;
-        out-proj then reduces row-wise). MLP and the vocab matrices
-        shard the standard Megatron way."""
-        col, row = layout.column_parallel(), layout.row_parallel()
-        specs = {"wte.weight": layout.embeddings()}
-        for i in range(self.num_layers):
-            pre = f"blocks.{i}."
-            specs[pre + "attn.out.weight"] = row
-            specs[pre + "mlp.fc1.weight"] = col
-            specs[pre + "mlp.fc1.bias"] = layout.bias_column()
-            specs[pre + "mlp.fc2.weight"] = row
-        if "lm_head.weight" in self.params:        # [H, V]: column-wise
-            specs["lm_head.weight"] = col
-        return specs
-
-    def _forward(self, params, tokens, positions, write_page, write_off,
-                 tables, pos_q, q_lens, pools):
-        cfg = self.cfg
-        B, T = tokens.shape
-        d = self.head_dim
-        impl = self._attn_impl_for(T)
-        # scope names reach the device trace: embed, block/attn, block/mlp,
-        # final_norm, lm_head (the same as models/gpt.py gives training)
-        with jax.named_scope("embed"):
-            x = (jnp.take(params["wte.weight"], tokens, axis=0)
-                 + jnp.take(params["wpe.weight"], positions, axis=0))
-        new_pools = []
-        for i in range(cfg.num_layers):
-            p = _block_params(params, i)
-            with jax.named_scope("block/attn"):
-                h = _layer_norm(x, p["ln1.weight"], p["ln1.bias"])
-                qkv = (self._mm(p, "attn.qkv.weight", h)
-                       + p["attn.qkv.bias"]
-                       ).reshape(B, T, 3, self.n_heads, d)
-                q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
-                q, k, v = self._constrain_heads(q, k, v)
-                out, layer = paged_attend(
-                    q, k, v, pools[i], tables, write_page,
-                    write_off, pos_q, q_lens, 1, impl,
-                    shard_ctx=self._shard_ctx)
-                x = x + (self._mm(p, "attn.out.weight", out)
-                         + p["attn.out.bias"])
-            with jax.named_scope("block/mlp"):
-                h = _layer_norm(x, p["ln2.weight"], p["ln2.bias"])
-                fc1 = p.get("mlp.fc1.weight")
-                if fc1 is not None and (
-                        "mlp.fc1.weight" + SCALE_SUFFIX in p
-                        or str(fc1.dtype).startswith("float8")):
-                    # dense MLP with quantized weights (scale-carrying
-                    # int8/int4 or scale-free fp8 — keyed on both, since
-                    # fp8 has no scale entry): same gelu(fc1)+fc2 math,
-                    # matmuls through the dequant epilogue (_mlp stays
-                    # the untouched fp32 path so the default is
-                    # bit-identical)
-                    hm = jax.nn.gelu(self._mm(p, "mlp.fc1.weight", h)
-                                     + p["mlp.fc1.bias"], approximate=True)
-                    x = (x + self._mm(p, "mlp.fc2.weight", hm)
-                         + p["mlp.fc2.bias"])
-                else:
-                    x = x + _mlp(p, h)
-            new_pools.append(layer)
-        with jax.named_scope("final_norm"):
-            x = _layer_norm(x, params["ln_f.weight"], params["ln_f.bias"])
-        with jax.named_scope("lm_head"):
-            if "lm_head.weight" in params and (
-                    "lm_head.weight" + SCALE_SUFFIX in params
-                    or str(params["lm_head.weight"].dtype
-                           ).startswith("float8")
-                    or (self.comm_dtype != "fp32"
-                        and "lm_head.weight" in self._gather_names)):
-                # quantized head, or a head whose gather is routed through
-                # the explicit quantized collective (ISSUE 19)
-                logits = self._mm(params, "lm_head.weight", x)
-            elif "lm_head.weight" in params:
-                logits = jnp.einsum("bth,hv->btv", x,
-                                    params["lm_head.weight"])
-            else:
-                logits = jnp.einsum("bth,vh->btv", x, params["wte.weight"])
-        return logits, new_pools
-
-
-class DeepseekV3Runner(PagedModelRunner):
-    """Paged-step adapter for models.DeepseekV3ForCausalLM: latent
-    attention over LATENT pages and a routed + shared expert layer, one
-    rank's share of it (models/deepseek_v3.py has the equations and the
-    functions; this class is their paging).
-
-    A layer's cache is ONE array a page, `[num_blocks, page, lanes]`:
-    per token c_kv | k_r (`cfg.latent_dim` values), allocated with its
-    lanes rounded up to whole 128-lane tiles because the chip copies a
-    page only as whole tiles (576 -> 640; PERF.md). A configuration with
-    an indexer (`cfg.index_topk`: DeepSeek-V3.2) names a SECOND array a
-    page behind the same table, the indexer's key of each token, and
-    every query row attends over the `index_topk` keys it scored best
-    (`_sparse_latent_attend`; a prompt's span in the expanded form under
-    `selection_mask`, its heads a group at a time); it counts the keys
-    scored and kept beside the rest. Two attention paths
-    from one set of weights, chosen from shapes: ONE sequence's span of
-    several rows (a prefill bucket, a chunk) runs the EXPANDED form
-    (per-head keys and values rebuilt from the table's latent rows,
-    blocked over query and key rows), anything else the ABSORBED form
-    through `paged_attend`: the latent decode kernel where `attn_impl`
-    resolves to "ragged" (a decode step on a TPU), the gather path
-    elsewhere. `weight_dtype="int8"` / "fp8" convert the dense matrices
-    (the experts and the router stay floating); latent pages come in the
-    stated dtype only. The expert layers count (tokens routed, pairs
-    computed here, held experts touched) and so does the latent kernel's
-    walk (groups of pages copied, those copied as one run): an output of
-    every single-pass step, handed to `on_step_counts`."""
-
-    COUNTS = ("moe_tokens_routed", "moe_local_pairs", "moe_experts_touched",
-              "latent_copy_groups", "latent_run_groups")
-    # what a runner with an indexer counts besides
-    SPARSE_COUNTS = ("dsa_keys_scored", "dsa_keys_selected")
-    HEAD_ROWS = True
-
-    def __init__(self, model, block_size: int = 16,
-                 max_model_len: int | None = None, attn_impl: str = "auto",
-                 **quant):
-        from paddle_tpu.jit.functionalize import functionalize
-
-        cfg = model.cfg
-        if quant.get("kv_dtype", "fp32") != "fp32":
-            raise ValueError(
-                f"kv_dtype={quant['kv_dtype']!r}: latent pages come in the "
-                "model's stated dtype only (no quantized rung for them yet)")
-        if quant.get("weight_dtype") == "int4":
-            raise ValueError("weight_dtype='int4' is not wired for the "
-                             "latent-attention runner (int8 and fp8 are)")
-        params = functionalize(model).param_values()
-        super().__init__(params, block_size,
-                         max_model_len or cfg.max_seq_len, attn_impl,
-                         **quant)
-        self.cfg = cfg
-        self.num_layers = cfg.num_hidden_layers
-        self.n_heads = cfg.num_attention_heads
-        self.vocab_size = cfg.vocab_size
-        # a page's lanes: the latent row in whole 128-lane tiles
-        self.page_lanes = -(-cfg.latent_dim // 128) * 128
-        self.sparse = cfg.index_topk is not None
-        if self.sparse:
-            self.index_lanes = -(-cfg.index_head_dim // 128) * 128
-            self.COUNTS = self.COUNTS + self.SPARSE_COUNTS
-        self._rope_cos, self._rope_sin = _dsv3.rope_tables(
-            cfg, self.max_model_len)                   # [L, rope] fp32
-        self._scale = _dsv3.softmax_scale(cfg)
-        if self.weight_dtype != "fp32":
-            names = ["lm_head.weight"]
-            for i in range(self.num_layers):
-                pre = f"layers.{i}."
-                names += [pre + "self_attn." + n + ".weight" for n in (
-                    "q_a_proj", "q_b_proj", "kv_a_proj_with_mqa",
-                    "kv_b_proj", "o_proj") + (
-                        ("indexer.wq_b", "indexer.wk",
-                         "indexer.weights_proj") if self.sparse else ())]
-                mlp = pre + ("mlp." if cfg.is_dense(i)
-                             else "mlp.shared_experts.")
-                names += [mlp + n + ".weight" for n in (
-                    "gate_proj", "up_proj", "down_proj")]
-            self._quantize_weights(names)
-
-    def page_layout(self):
-        layout = [((self.page_lanes,), self.dtype)]
-        if self.sparse:
-            layout.append(((self.index_lanes,), self.dtype))
-        return layout
-
-    def _param_specs(self, layout):
-        raise NotImplementedError(
-            "DeepseekV3Runner serves one chip's share; exchanging experts "
-            "and splitting latent pages over a mesh is not built")
-
-    def _attn_impl_for(self, q_len_bucket: int) -> str:
-        """The ABSORBED paths: the latent kernel for a decode step where
-        a kernel is wanted ("auto" on a TPU, or "ragged": interpret mode
-        off it), else the gather reference. (One sequence's longer span
-        takes the expanded form whatever this says: `_forward`.)"""
-        want_kernel = (self.attn_impl == "ragged"
-                       or (self.attn_impl == "auto"
-                           and jax.default_backend() == "tpu"))
-        impl = "ragged" if want_kernel and q_len_bucket == 1 else "reference"
-        key = (q_len_bucket, impl)
-        if key not in self._impl_logged:
-            self._impl_logged.add(key)
-            logger.info("serving attention impl: latent %s (q_len bucket "
-                        "%d, %d heads over %d lanes, attn_impl=%s)", impl,
-                        q_len_bucket, self.n_heads, self.page_lanes,
-                        self.attn_impl)
-        return impl
-
-    def _kv_page_bytes(self) -> int:
-        """A page's bytes in every layer; under a selection both its arrays
-        (the scan reads every live page's index keys, the walk its latent
-        rows: each block is folded under the selection, none is skipped)."""
-        lanes = self.page_lanes + (self.index_lanes if self.sparse else 0)
-        return (self.num_layers * self.block_size * lanes
-                * np.dtype(self.dtype).itemsize)
-
-    def _fold_block_pages(self, span: int) -> int:
-        return 0        # the latent kernel's walk, not the ragged one's
-
-    def _w(self, params, name):
-        """A named matrix as its floating self (dequantized where
-        `_quantize_weights` converted it): the absorbed form multiplies
-        by slices of kv_b_proj, not by the whole of it."""
-        w, s = params[name], params.get(name + SCALE_SUFFIX)
-        dt = params["embed_tokens.weight"].dtype
-        return w.astype(dt) if s is None else w.astype(dt) * s.astype(dt)
-
-    def _forward(self, params, tokens, positions, write_page, write_off,
-                 tables, pos_q, q_lens, pools, head_rows=None):
-        cfg, m = self.cfg, _dsv3
-        B, T = tokens.shape
-        lanes, nh = self.page_lanes, self.n_heads
-        impl = self._attn_impl_for(T)
-        expanded = B == 1 and T > 1
-        x = jnp.take(params["embed_tokens.weight"], tokens, axis=0)
-        cos = jnp.take(self._rope_cos, positions, axis=0)      # [B, T, rope]
-        sin = jnp.take(self._rope_sin, positions, axis=0)
-        valid = (jnp.arange(T, dtype=jnp.int32)[None, :]
-                 < q_lens[:, None]).reshape(B * T)
-        experts = jnp.zeros((3,), jnp.int32)
-        walked = jnp.zeros((2,), jnp.int32)
-        runs = None
-        if impl == "ragged" and not expanded:
-            # which groups of the table are runs of consecutive pages: the
-            # layers share one table, so once for the step's program
-            from paddle_tpu.ops.pallas import latent_paged_attention as lpa
-
-            pool = pools[0][0]
-            _, group = lpa.walk_shape(nh, pool, cfg.kv_lora_rank)
-            runs = lpa.page_runs(tables, group)
-            walked = cfg.num_hidden_layers * lpa.walked_groups(
-                runs, pos_q, self.block_size, group, tables.shape[1])
-            if self.sparse:
-                # the scan over index pages walks in groups of its own
-                from paddle_tpu.ops.pallas.sparse_latent_attention import \
-                    scan_shape
-
-                runs = (lpa.page_runs(tables, scan_shape(pools[0][1])[1]),
-                        runs)
-        new_pools = []
-        for i in range(cfg.num_hidden_layers):
-            pre = f"layers.{i}."
-            if self.sparse:
-                x, layer = self._sparse_attention(
-                    params, pre, x, cos, sin, pools[i], tables, write_page,
-                    write_off, pos_q, q_lens, impl, expanded, runs)
-            else:
-                with jax.named_scope("block/mla"):
-                    h = m.rms_norm(x, params[pre + "input_layernorm.weight"],
-                                   cfg.rms_norm_eps)
-                    qn, qr, lat, _ = m.mla_project(cfg, params, pre, h, cos,
-                                                   sin, mm=self._mm)
-                    lat = jnp.pad(lat, ((0, 0), (0, 0),
-                                        (0, lanes - cfg.latent_dim)))
-                    w_kvb = self._w(params,
-                                    pre + "self_attn.kv_b_proj.weight")
-                    if expanded:
-                        (pool,) = pools[i]
-                        pool = pool.at[write_page, write_off].set(
-                            lat.astype(pool.dtype))
-                        o = m.expanded_attention(
-                            cfg, qn[0], qr[0],
-                            pool[tables[0]].reshape(-1, lanes), w_kvb,
-                            pos_q[0], q_lens[0])[None]
-                        layer = (pool,)
-                    else:
-                        o, layer = paged_attend(
-                            m.absorb_queries(cfg, qn, qr, w_kvb, lanes), lat,
-                            None, pools[i], tables, write_page, write_off,
-                            pos_q, q_lens, nh, impl, scale=self._scale,
-                            v_lanes=cfg.kv_lora_rank, runs=runs,
-                            kind="latent")
-                        o = m.absorb_outputs(cfg, o, w_kvb)
-                    x = x + self._mm(params, pre + "self_attn.o_proj.weight",
-                                     o)
-            h = m.rms_norm(x, params[pre + "post_attention_layernorm.weight"],
-                           cfg.rms_norm_eps).reshape(B * T, -1)
-            if cfg.is_dense(i):
-                with jax.named_scope("block/mlp"):
-                    f = m.dense_ffn(params, pre + "mlp.", h, self._mm)
-            else:
-                f, c = m.moe_ffn(cfg, params, pre + "mlp.", h, valid,
-                                 self._mm)
-                experts = experts + c
-            x = x + f.reshape(B, T, -1)
-            new_pools.append(layer)
-        with jax.named_scope("final_norm"):
-            x = m.rms_norm(x, params["norm.weight"], cfg.rms_norm_eps)
-            if head_rows is not None:
-                x = jnp.take_along_axis(x, head_rows[:, None, None], axis=1)
-        with jax.named_scope("lm_head"):
-            logits = self._mm(params, "lm_head.weight", x)
-        counts = [experts, walked]
-        if self.sparse:
-            # every live query row scored its context and kept the best
-            t_idx = jnp.arange(T, dtype=jnp.int32)[None, :]
-            context = jnp.where(t_idx < q_lens[:, None],
-                                pos_q[:, None] + t_idx + 1, 0)
-            counts.append(cfg.num_hidden_layers * jnp.stack(
-                [jnp.sum(context),
-                 jnp.sum(jnp.minimum(context, cfg.index_topk))]))
-        return logits, new_pools, jnp.concatenate(counts)
-
-    def _sparse_attention(self, params, pre, x, cos, sin, layer_pools,
-                          tables, write_page, write_off, pos_q, q_lens, impl,
-                          expanded, runs):
-        """One layer's attention under the indexer's selection, residual
-        added: (x, the layer's (latent pool, index pool))."""
-        cfg, m = self.cfg, _dsv3
-        lanes = self.page_lanes
-        pad = lambda a, n: jnp.pad(
-            a, ((0, 0),) * (a.ndim - 1) + ((0, n - a.shape[-1]),))
-        with jax.named_scope("block/mla"):
-            h = m.rms_norm(x, params[pre + "input_layernorm.weight"],
-                           cfg.rms_norm_eps)
-            # (a prompt's span makes its queries a group of heads at a
-            # time and leaves these to the compiler's dead-code pass)
-            qn, qr, lat, c_q = m.mla_project(cfg, params, pre, h, cos, sin,
-                                             mm=self._mm)
-            lat = pad(lat, lanes)
-            w_kvb = self._w(params, pre + "self_attn.kv_b_proj.weight")
-        with jax.named_scope("block/dsa/index"):
-            q_i, k_i, w_i = m.index_project(cfg, params, pre, h, c_q, cos,
-                                            sin, mm=self._mm)
-            q_i, k_i = pad(q_i, self.index_lanes), pad(k_i, self.index_lanes)
-        if expanded:
-            pool, ipool = layer_pools
-            pool = pool.at[write_page, write_off].set(lat.astype(pool.dtype))
-            ipool = ipool.at[write_page, write_off].set(
-                k_i.astype(ipool.dtype))
-            with jax.named_scope("block/dsa/select"):
-                chosen = m.selection_mask(
-                    cfg, q_i[0], w_i[0],
-                    ipool[tables[0]].reshape(-1, self.index_lanes),
-                    pos_q[0], q_lens[0])
-            with jax.named_scope("block/dsa/attend"):
-                o = m.sparse_expanded_attention(
-                    cfg, c_q[0], cos[0], sin[0],
-                    pool[tables[0]].reshape(-1, lanes), chosen,
-                    self._w(params, pre + "self_attn.q_b_proj.weight"),
-                    w_kvb, self._w(params, pre + "self_attn.o_proj.weight"),
-                    pos_q[0], q_lens[0])[None]
-            return x + o, (pool, ipool)
-        o, layer = paged_attend(
-            m.absorb_queries(cfg, qn, qr, w_kvb, lanes), lat, None,
-            layer_pools, tables, write_page, write_off, pos_q, q_lens,
-            self.n_heads, impl, scale=self._scale, v_lanes=cfg.kv_lora_rank,
-            runs=runs, kind="latent+index", index=(q_i, k_i, w_i),
-            topk=cfg.index_topk)
-        with jax.named_scope("block/mla"):
-            o = m.absorb_outputs(cfg, o, w_kvb)
-            return x + self._mm(params, pre + "self_attn.o_proj.weight",
-                                o), layer
-
-
-class OlmoHybridRunner(PagedModelRunner):
-    """Paged-step adapter for models.OlmoHybridForCausalLM: pages for the
-    full-attention layers, a STATE SLOT per sequence for the Gated
-    DeltaNet layers (models/olmo_hybrid.py has the equations and the
-    functions; this class is their caching).
-
-    `pools` is the pair (pages, states). pages: the (k, v) arrays of the
-    full layers only, through `paged_attend` like any dense runner's,
-    their heads rounded up to what the chip copies as whole tiles (30 ->
-    32 below 32 bits: allocated so, never padded per call); a span longer
-    than ATTN_SPAN rows attends in pieces, its keys written first. states:
-    per linear layer `(state [slots, d_k, H * d_v] float32, conv [slots,
-    (taps - 1) * conv_dim])`, a sequence's row its decode slot. A decode
-    step (one token a row) advances rows 0..B-1 in place where the row is
-    LIVE, which is read off the write indices: a dead slot's all-scratch
-    table and a horizon's frozen row (`write_mask`) both write to the
-    scratch page. The update is the Pallas kernel where `attn_impl`
-    resolves to "ragged" (a TPU, or forced), plain jnp elsewhere. A
-    prefill or a chunk of one (one sequence, `slot`) runs the chunked form
-    from the slot's state, or from zeros where it starts at position 0:
-    the program that first writes a slot resets it; padding rows change
-    nothing (beta = 0, no decay). The steps count on the device
-    (`COUNTS`): live rows x linear layers a decode step advanced, a
-    prefill's real tokens and computed positions, slots reset.
-
-    What needs a copy or a rollback of a state is not built: spans of
-    several rows for several sequences (`ragged_step`, speculation) raise
-    here, and ServingEngine refuses the options that need them by name."""
-
-    COUNTS = ("delta_decode_seq_steps", "delta_prefill_tokens",
-              "delta_prefill_positions", "state_slot_resets")
-    HEAD_ROWS = True
-    ATTN_SPAN = 128      # query rows of one call of the attention kernel
-
-    def __init__(self, model, block_size: int = 16,
-                 max_model_len: int | None = None, attn_impl: str = "auto",
-                 **quant):
-        from paddle_tpu.jit.functionalize import functionalize
-        from paddle_tpu.ops.pallas.ragged_paged_attention import \
-            _page_copy_heads
-
-        cfg = model.cfg
-        if quant.get("weight_dtype") == "int4":
-            raise ValueError("weight_dtype='int4' is not wired for the "
-                             "hybrid runner (int8 and fp8 are)")
-        if quant.get("kv_dtype", "fp32") not in ("fp32", "fp8"):
-            raise ValueError(
-                f"kv_dtype={quant['kv_dtype']!r}: the hybrid runner's paged "
-                "layers come in the model's dtype or in fp8 (a long span "
-                "writes its keys once and attends in pieces, which the "
-                "int8 and mixed write paths are not built for)")
-        params = functionalize(model).param_values()
-        if cfg.init == "deferred":
-            # the Layer was the weights' way in: they live here now
-            model.release_weights()
-        super().__init__(params, block_size,
-                         max_model_len or cfg.max_seq_len, attn_impl,
-                         **quant)
-        self.cfg = cfg
-        self.num_layers = cfg.num_hidden_layers
-        self.linear_layers = [i for i in range(self.num_layers)
-                              if cfg.is_linear(i)]
-        self.n_heads = self.n_kv_heads = cfg.num_attention_heads
-        self.head_dim = cfg.head_dim
-        self.vocab_size = cfg.vocab_size
-        # heads of a page: what the chip copies as whole tiles
-        self.page_heads = _page_copy_heads(self.n_heads,
-                                           self._kv_itemsize())
-        self._rope = _olmo.rope_tables(cfg, self.max_model_len)
-        if self.weight_dtype != "fp32":
-            names = ["lm_head.weight"]
-            for i in range(self.num_layers):
-                pre = f"layers.{i}."
-                mixer = ("linear_attn.", "qkvgo") if cfg.is_linear(i) \
-                    else ("self_attn.", "qkvo")
-                names += [pre + mixer[0] + n + "_proj.weight"
-                          for n in mixer[1]]
-                names += [pre + "mlp." + n + "_proj.weight"
-                          for n in ("gate", "up", "down")]
-            self._quantize_weights(names)
-
-    def page_layout(self):
-        return kv_pair_layout(self.page_heads, self.head_dim, self.dtype)
-
-    def state_layout(self):
-        cfg = self.cfg
-        return (len(self.linear_layers), [
-            ((cfg.linear_key_head_dim, cfg.linear_num_value_heads
-              * cfg.linear_value_head_dim), jnp.float32),
-            (((cfg.linear_conv_kernel_dim - 1) * cfg.conv_dim,),
-             self.dtype)])
-
-    def _param_specs(self, layout):
-        raise NotImplementedError(
-            "OlmoHybridRunner serves one chip; splitting state slots over "
-            "a mesh is not built")
-
-    def _kv_page_bytes(self) -> int:
-        """Bytes a page costs the attention of the layers that page."""
-        full = self.num_layers - len(self.linear_layers)
-        return (2 * full * self.block_size * self.page_heads * self.head_dim
-                * self._kv_itemsize())
-
-    def _kv_itemsize(self) -> int:
-        """Bytes of a cached value: fp8 pages, or the model's dtype."""
-        return 1 if self.kv_dtype == "fp8" else np.dtype(self.dtype).itemsize
-
-    @staticmethod
-    def _starts_fresh(pos_q):
-        """A span that starts at position 0 starts from a zero state, not
-        from what the slot's last holder left."""
-        return pos_q[0] == 0
-
-    def _delta_kernel(self) -> bool:
-        return self.attn_impl == "ragged" or (
-            self.attn_impl == "auto" and jax.default_backend() == "tpu")
-
-    # ------------------------------------------------------------- steps
-
-    def _prefill_step(self, params, tokens, table, real_len, start_slot,
-                      pools):
-        """The chassis's prefill with the sequence's state slot beside its
-        start position (`prefill_chunk(..., slot=)`; slot 0 where the
-        caller named none: the oracle's private pool)."""
-        start_slot = jnp.reshape(start_slot, (-1,))
-        slot = start_slot[1:] if start_slot.shape[0] > 1 \
-            else jnp.zeros((1,), jnp.int32)
-        return super()._prefill_step(params, tokens, table, real_len,
-                                     start_slot[0], pools, slots=slot)
-
-    def _attend(self, q, k, v, layer_pools, tables, write_page, write_off,
-                pos_q, q_lens, impl):
-        """One full layer's attention through the pages: the span's keys
-        written once, then its query rows ATTN_SPAN at a time."""
-        B, T = q.shape[:2]
-        pad = ((0, 0), (0, 0), (0, self.page_heads - self.n_heads), (0, 0))
-        q, k, v = (jnp.pad(a, pad) for a in (q, k, v))
-        span = min(T, self.ATTN_SPAN) if impl == "ragged" else T
-        out = []
-        for lo in range(0, T, span):
-            wrote = lo > 0            # later pieces write nothing
-            cut = lambda a: a[:, :0] if wrote else a
-            o, layer_pools = paged_attend(
-                q[:, lo:lo + span], cut(k), cut(v), layer_pools, tables,
-                cut(write_page), cut(write_off), pos_q + lo,
-                jnp.clip(q_lens - lo, 0, span), 1, impl)
-            out.append(o)
-        o = out[0] if len(out) == 1 else jnp.concatenate(out, 1)
-        o = o.reshape(B, T, self.page_heads, self.head_dim)
-        return o[:, :, :self.n_heads].reshape(B, T, -1), layer_pools
-
-    def _linear(self, params, pre, x, valid, fresh, slots, layer_states):
-        """One Gated DeltaNet mixer on x [B, T, hidden] against its state
-        arrays. T == 1: a decode step, row b at slot b. T > 1: one
-        sequence (B == 1) at `slots[0]`."""
-        from paddle_tpu.ops import gated_delta as gd
-        from paddle_tpu.ops.pallas import gated_delta_decode as gk
-
-        cfg = self.cfg
-        B, T = x.shape[:2]
-        H, taps = cfg.linear_num_value_heads, cfg.linear_conv_kernel_dim
-        state, conv = layer_states
-        rows = _olmo.conv_inputs(params, pre, x, self._mm)     # [B, T, C]
-        w = _olmo.conv_weights(params, pre)
-        if T == 1:
-            live = valid[:, 0]
-            before = conv[:B].reshape(B, taps - 1, -1)
-            rows = jnp.concatenate([before, rows.astype(conv.dtype)], 1)
-            q, k, v, g, beta = _olmo.delta_inputs(
-                cfg, params, pre, x[:, 0], _olmo.conv_silu(rows, w)[:, 0],
-                self._mm)
-            conv = jax.lax.dynamic_update_slice(conv, jnp.where(
-                live[:, None], rows[:, 1:].reshape(B, -1), conv[:B]), (0, 0))
-            if self._delta_kernel():
-                o, state = gk.gated_delta_decode(state, q, k, v, g, beta,
-                                                 live)
-            else:
-                on = live[:, None]
-                o, new = gd.gated_delta_step(
-                    gk.head_form(state[:B], H), q, k, v,
-                    jnp.where(on, g, 0.0), jnp.where(on, beta, 0.0))
-                state = jax.lax.dynamic_update_slice(
-                    state, gk.pool_form(new), (0, 0, 0))
-            return _olmo.gated_output(cfg, params, pre, x[:, 0], o,
-                                      self._mm)[:, None], (state, conv)
-        if B != 1:
-            raise NotImplementedError(
-                "spans of several rows for several sequences at once (the "
-                "fused ragged step, speculative verify spans) are not "
-                "built for recurrent state")
-        slot = slots[0]
-        before = jnp.where(fresh, 0, conv[slot]).reshape(taps - 1, -1)
-        rows = jnp.concatenate([before, rows[0].astype(conv.dtype)], 0)
-        q, k, v, g, beta = _olmo.delta_inputs(
-            cfg, params, pre, x[0], _olmo.conv_silu(rows, w), self._mm)
-        on = valid[0][:, None]
-        o, new = gd.gated_delta_chunked(
-            q, k, v, jnp.where(on, g, 0.0), jnp.where(on, beta, 0.0),
-            jnp.where(fresh, 0.0, gk.head_form(state[slot], H)))
-        n_real = jnp.sum(valid[0].astype(jnp.int32))
-        # what the next token's convolution reads: the last real rows
-        kept = jax.lax.dynamic_slice_in_dim(rows, n_real, taps - 1, 0)
-        state = jax.lax.dynamic_update_index_in_dim(
-            state, gk.pool_form(new), slot, 0)
-        conv = jax.lax.dynamic_update_index_in_dim(
-            conv, kept.reshape(-1), slot, 0)
-        return _olmo.gated_output(cfg, params, pre, x[0], o,
-                                  self._mm)[None], (state, conv)
-
-    def _forward(self, params, tokens, positions, write_page, write_off,
-                 tables, pos_q, q_lens, pools, head_rows=None, slots=None):
-        cfg, m = self.cfg, _olmo
-        B, T = tokens.shape
-        impl = self._attn_impl_for(T)
-        pages, states = pools
-        # a position is real where its write lands on a page of its own
-        valid = write_page != SCRATCH_PAGE                          # [B, T]
-        fresh = self._starts_fresh(pos_q)
-        if slots is None:
-            slots = jnp.arange(B, dtype=jnp.int32)
-        x = jnp.take(params["embed_tokens.weight"], tokens, axis=0)
-        cos_sin = None if self._rope is None else tuple(
-            jnp.take(t, positions, axis=0) for t in self._rope)
-        new_pages, new_states = [], []
-        for i in range(cfg.num_hidden_layers):
-            pre = f"layers.{i}."
-            if cfg.is_linear(i):
-                with jax.named_scope("block/delta"):
-                    mix, layer = self._linear(
-                        params, pre + "linear_attn.", x, valid, fresh, slots,
-                        states[len(new_states)])
-                new_states.append(layer)
-            else:
-                with jax.named_scope("block/attention"):
-                    a = pre + "self_attn."
-                    q, k, v = m.attention_qkv(cfg, params, a, x, cos_sin,
-                                              self._mm)
-                    o, layer = self._attend(
-                        q, k, v, pages[len(new_pages)], tables, write_page,
-                        write_off, pos_q, q_lens, impl)
-                    mix = self._mm(params, a + "o_proj.weight", o)
-                new_pages.append(layer)
-            x = x + m.rms_norm(
-                mix, params[pre + "post_attention_layernorm.weight"],
-                cfg.rms_norm_eps)
-            with jax.named_scope("block/mlp"):
-                f = m.swiglu(params, pre + "mlp.", x, self._mm)
-            x = x + m.rms_norm(
-                f, params[pre + "post_feedforward_layernorm.weight"],
-                cfg.rms_norm_eps)
-        with jax.named_scope("final_norm"):
-            x = m.rms_norm(x, params["norm.weight"], cfg.rms_norm_eps)
-            if head_rows is not None:
-                x = jnp.take_along_axis(x, head_rows[:, None, None], axis=1)
-        with jax.named_scope("lm_head"):
-            logits = self._mm(params, "lm_head.weight", x)
-        real = jnp.sum(valid.astype(jnp.int32))
-        zero = jnp.int32(0)
-        counts = jnp.stack(
-            [real * len(self.linear_layers), zero, zero, zero] if T == 1
-            else [zero, real, jnp.int32(B * T), fresh.astype(jnp.int32)])
-        return logits, (new_pages, new_states), counts
-
-
-class Phi4FlashRunner(PagedModelRunner):
-    """Paged-step adapter for models.Phi4FlashForCausalLM, whose layers keep
-    a cache of four kinds (models/phi4flash.py has the equations and the
-    functions; this class is their caching). It names page GROUPS to the
-    pool (`page_groups`), and `pools` is the triple (pages, states, ring):
-
-    pages   the ONE full-attention layer's keys and values, whole context,
-            through the block table as any dense runner's (the "full"
-            group: `num_blocks` counts its pages). The cross-attention
-            layers of the cross-decoder own no cache: they read these pages
-            with the same kernel and write nothing.
-    ring    the window layers' pages (the "window" group, `WindowGroup`):
-            only a sequence's last `sliding_window` positions. Its table
-            columns ride behind the full group's in the one block table a
-            step takes, `[pages | ring pages | ring base]`; positions
-            there are the ring's own (less `base * block_size`), and the
-            kernel is given the first position still inside the window.
-    states  per Mamba layer `(state [slots, d_state, d_inner] float32, conv
-            [slots, (taps - 1) * d_inner])`, a sequence's row its decode
-            slot, as OlmoHybridRunner keeps its delta rule's.
-
-    A page is kept as ROWS, `[block_size * pairs, 2 head_dim]` (10 pairs of
-    128 lanes at the published widths: whole tiles, where `[16, 10, 128]`
-    would be allocated as 16 pairs). The differential pairing costs no
-    second walk: a query head padded to its pair's width scores its own key
-    head against the pair (`models.phi4flash.pair_queries`), so ONE pass of
-    the ragged kernel over pair heads gives both softmaxes' products.
-
-    A decode step (one token a row) runs every layer; the gated memory
-    units read the memory layer's scan output of the same step. A prefill,
-    or a chunk of one (one sequence, `slot`), runs in pieces of
-    PREFILL_SPAN rows: layers up to the full layer's key/value write for
-    EVERY row, the full layer's attention and the whole cross-decoder for
-    the chunk's LAST row only (nothing after that write keeps anything of
-    an earlier row, so this is exact: tests hold it equal to the unskipped
-    forward). Its window attention is dense over the chunk's own keys and
-    the `window - 1` before them, which the pieces hand on as an array:
-    loaded from the ring before the first piece (`ring=(before, after)`,
-    the group's rows as the engine found and left them), stored into it
-    after the last. The steps count on the device (`COUNTS`).
-
-    Precision: weights, pages and convolution rows in the model's dtype;
-    the scan state, dt, exp(dt A), the softmax, lambda and both norms'
-    statistics float32. What needs a copy or a rollback of a state or of
-    the ring is not built: spans of several rows for several sequences
-    raise here, and ServingEngine refuses the options by name."""
-
-    COUNTS = ("ssm_decode_seq_steps", "ssm_prefill_tokens",
-              "cross_rows_skipped", "state_slot_resets")
-    HEAD_ROWS = True
-    ROW_PAGES = True
-    PREFILL_SPAN = 2048    # rows of one piece of a prefill
-    SHORT_CHUNK = 256      # a chunk up to this long is one piece of its bucket
-    WINDOW_ROWS = 512      # query rows of one block of its window attention
-    EXTRA_STEPS = {"phi_body": ("_piece_body", 6, ()),
-                   "phi_head": ("_piece_head", None, ()),
-                   "phi_ring_load": ("_ring_load", None, ()),
-                   "phi_ring_store": ("_ring_store", 0, ())}
-
-    def __init__(self, model, block_size: int = 16,
-                 max_model_len: int | None = None, attn_impl: str = "auto",
-                 **quant):
-        from paddle_tpu.jit.functionalize import functionalize
-
-        cfg = model.cfg
-        if quant.get("weight_dtype") == "int4":
-            raise ValueError("weight_dtype='int4' is not wired for the "
-                             "Phi-4-flash runner (int8 and fp8 are)")
-        if quant.get("kv_dtype", "fp32") not in ("fp32", "fp8"):
-            raise ValueError(
-                f"kv_dtype={quant['kv_dtype']!r}: row pages come in the "
-                "model's dtype or in fp8")
-        params = functionalize(model).param_values()
-        if cfg.init == "deferred":
-            # the Layer was the weights' way in: they live here now
-            model.release_weights()
-        super().__init__(params, block_size,
-                         max_model_len or cfg.max_seq_len, attn_impl,
-                         **quant)
-        self.cfg = cfg
-        self.num_layers = cfg.num_hidden_layers
-        # the geometry the attention kernel sees: PAIR heads
-        self.n_heads = cfg.num_attention_heads
-        self.n_kv_heads = cfg.kv_pairs
-        self.head_dim = 2 * cfg.head_dim
-        self.vocab_size = cfg.vocab_size
-        self.kinds = [cfg.kind(i) for i in range(self.num_layers)]
-        self.table_pages = -(-self.max_model_len // block_size)
-        if self.weight_dtype != "fp32":
-            per_kind = {
-                "mamba": ["mamba." + n for n in ("in_proj", "x_proj",
-                                                 "dt_proj", "out_proj")],
-                "gmu": ["gmu.in_proj", "gmu.out_proj"],
-                "cross": ["attn.q_proj", "attn.o_proj"]}
-            names = []
-            for i, kind in enumerate(self.kinds):
-                names += [f"layers.{i}.{n}.weight" for n in per_kind.get(
-                    kind, ["attn.qkv_proj", "attn.o_proj"])
-                    + ["mlp.gate_up_proj", "mlp.down_proj"]]
-            self._quantize_weights(names)
-
-    def page_layout(self):
-        return kv_pair_layout(self.n_kv_heads, self.head_dim, self.dtype)
-
-    def page_groups(self):
-        """The pool's page groups by name: layers that keep their whole
-        context, and (layers, window) that keep a window of it."""
-        return {"full": self.kinds.count("full"),
-                "window": (self.kinds.count("window"),
-                           self.cfg.sliding_window)}
-
-    def state_layout(self):
-        cfg = self.cfg
-        return (self.kinds.count("mamba"), [
-            ((cfg.mamba_d_state, cfg.d_inner), jnp.float32),
-            (((cfg.mamba_d_conv - 1) * cfg.d_inner,), self.dtype)])
-
-    def _param_specs(self, layout):
-        raise NotImplementedError(
-            "Phi4FlashRunner serves one chip; splitting state slots and "
-            "page groups over a mesh is not built")
-
-    def _kv_itemsize(self) -> int:
-        return 1 if self.kv_dtype == "fp8" else np.dtype(self.dtype).itemsize
-
-    def _kv_page_bytes(self) -> int:
-        """Bytes a page of the full group costs a step's attention: the
-        full layer and every cross layer read it."""
-        readers = self.kinds.count("full") + self.kinds.count("cross")
-        return (2 * readers * self.block_size * self.n_kv_heads
-                * self.head_dim * self._kv_itemsize())
-
-    def _account_decode(self, pos, tables) -> None:
-        """The full group's walk as any runner's, then the window
-        group's: the ring's own positions from its base (the table's last
-        column), bounded where the window begins, as `_forward` has it."""
-        super()._account_decode(pos, tables)
-        if self._attn_impl_for(1) == "ragged":
-            rel = pos - tables[:, -1] * self.block_size
-            self._account_blocks(
-                rel, np.ones_like(pos), 1,
-                np.maximum(rel - (self.cfg.sliding_window - 1), 0))
-
-    def _scan_kernel(self) -> bool:
-        return self.attn_impl == "ragged" or (
-            self.attn_impl == "auto" and jax.default_backend() == "tpu")
-
-    # ----------------------------------------------------- cache plumbing
-
-    def _split_tables(self, tables):
-        """[.., pages | ring pages | ring base] -> the three."""
-        P = self.table_pages
-        if tables.shape[-1] < P + 2:
-            raise ValueError(
-                f"a block table of {tables.shape[-1]} columns holds no "
-                f"window group behind {P} pages (max_model_len "
-                f"{self.max_model_len}): build it with "
-                "WindowGroup.extend_tables")
-        return tables[..., :P], tables[..., P:-1], tables[..., -1]
-
-    def _write_rows(self, pool, page, off, new):
-        """page, off [...]; new [..., pairs, lanes] -> the row pool with
-        those tokens' rows written: a page's rows are key-major, so a
-        token's pairs are ONE window of consecutive rows."""
-        n = self.n_kv_heads
-        at = jnp.stack([page, off * n], -1).reshape(-1, 2)
-        return jax.lax.scatter(
-            pool, at, new.astype(pool.dtype).reshape(-1, n, new.shape[-1]),
-            jax.lax.ScatterDimensionNumbers(
-                update_window_dims=(1, 2), inserted_window_dims=(0,),
-                scatter_dims_to_operand_dims=(0, 1)))
-
-    def _take_rows(self, pool, page, off):
-        """page, off [n] -> those tokens' rows [n, pairs, lanes]."""
-        n = self.n_kv_heads
-        return jax.lax.gather(
-            pool, jnp.stack([page, off * n], -1),
-            jax.lax.GatherDimensionNumbers(
-                offset_dims=(1, 2), collapsed_slice_dims=(0,),
-                start_index_map=(0, 1)),
-            slice_sizes=(1, n, pool.shape[-1]))
-
-    def _attend(self, q, layer_pools, table, pos, q_len, lower=None):
-        """q [B, heads, head_dim]: one row a sequence, at `pos` of the
-        table's own positions -> [B, heads, 2 head_dim]: each head's
-        softmax applied to its pair's values."""
-        from paddle_tpu.ops.pallas.ragged_paged_attention import (
-            ragged_paged_attention, ragged_reference,
-        )
-
-        fn = (ragged_paged_attention if self._attn_impl_for(1) == "ragged"
-              else ragged_reference)
-        return fn(_phi.pair_queries(q)[:, None], *layer_pools, table, pos,
-                  q_len, scale=self.cfg.head_dim ** -0.5, lower=lower,
-                  kv_heads=self.n_kv_heads)[:, 0]
-
-    def _ring_at(self, row, end):
-        """(page, offset) of the positions [end - (W - 1), end) through a
-        window group's `row`; positions before 0 go to the scratch page."""
-        W, bs = self.cfg.sliding_window, self.block_size
-        pos = end - (W - 1) + jnp.arange(W - 1, dtype=jnp.int32)
-        at = jnp.clip(pos // bs - row[-1], 0, row.shape[0] - 2)
-        return jnp.where(pos >= 0, row[at], SCRATCH_PAGE), pos % bs
-
-    def _ring_load(self, ring, row, start):
-        """The window layers' keys and values of positions [start - (W -
-        1), start) as arrays ([layers, W - 1, pairs, lanes] each; rows of
-        positions before 0 are whatever the scratch page holds, and
-        masked)."""
-        page, off = self._ring_at(row, start)
-        take = lambda pool: self._take_rows(pool, page, off).astype(
-            self.dtype)
-        return (jnp.stack([take(k) for k, _ in ring]),
-                jnp.stack([take(v) for _, v in ring]))
-
-    def _ring_store(self, ring, tail, row, end):
-        """The ring with the positions [end - (W - 1), end) of `tail`
-        written through `row` (the group's row after the chunk)."""
-        page, off = self._ring_at(row, end)
-        return [(self._write_rows(k, page, off, tail[0][i]),
-                 self._write_rows(v, page, off, tail[1][i]))
-                for i, (k, v) in enumerate(ring)]
-
-    # ------------------------------------------------------------ layers
-
-    def _mamba(self, params, pre, u, valid, fresh, slots, layer_states):
-        """One Mamba mixer on u [B, T, hidden] against its state arrays.
-        T == 1: a decode step, row b at slot b. T > 1: one sequence (B ==
-        1) at `slots[0]`. Returns (out, the memory y float32, states)."""
-        from paddle_tpu.ops import selective_scan as ss
-        from paddle_tpu.ops.pallas.selective_scan_decode import \
-            selective_scan_decode
-
-        cfg, m = self.cfg, _phi
-        B, T = u.shape[:2]
-        taps, c = cfg.mamba_d_conv, cfg.d_inner
-        state, conv = layer_states
-        xin, z = m.mamba_inputs(params, pre, u, self._mm)
-        if T == 1:
-            live = valid[:, 0]
-            before = conv[:B].reshape(B, taps - 1, c)
-            rows = jnp.concatenate([before, xin.astype(conv.dtype)], 1)
-            xc = m.conv_silu(params, pre, rows)[:, 0]            # [B, c]
-            dt, Bm, Cm, A = m.ssm_inputs(cfg, params, pre, xc, u.dtype,
-                                         self._mm)
-            conv = jax.lax.dynamic_update_slice(conv, jnp.where(
-                live[:, None], rows[:, 1:].reshape(B, -1), conv[:B]), (0, 0))
-            with jax.named_scope("block/ssm/scan"):
-                if self._scan_kernel():
-                    s, state = selective_scan_decode(state, xc, dt, A, Bm,
-                                                     Cm, live)
-                else:
-                    s, new = ss.selective_scan_step(
-                        state[:B], xc, jnp.where(live[:, None], dt, 0.0), A,
-                        Bm, Cm)
-                    state = jax.lax.dynamic_update_slice(state, new,
-                                                         (0, 0, 0))
-            y = m.mamba_memory(params, pre, s, xc)
-            return (m.mamba_output(params, pre, y, z[:, 0], self._mm)[:, None],
-                    y[:, None], (state, conv))
-        if B != 1:
-            raise NotImplementedError(
-                "spans of several rows for several sequences at once (the "
-                "fused ragged step, speculative verify spans) are not "
-                "built for recurrent state")
-        slot = slots[0]
-        before = jnp.where(fresh, 0, conv[slot]).reshape(taps - 1, c)
-        rows = jnp.concatenate([before, xin[0].astype(conv.dtype)], 0)
-        xc = m.conv_silu(params, pre, rows)                      # [T, c]
-        dt, Bm, Cm, A = m.ssm_inputs(cfg, params, pre, xc, u.dtype, self._mm)
-        with jax.named_scope("block/ssm/scan"):
-            s, new = ss.selective_scan_chunked(
-                xc, jnp.where(valid[0][:, None], dt, 0.0), A, Bm, Cm,
-                jnp.where(fresh, 0.0, state[slot]))
-        n_real = jnp.sum(valid[0].astype(jnp.int32))
-        # what the next token's convolution reads: the last real rows
-        kept = jax.lax.dynamic_slice_in_dim(rows, n_real, taps - 1, 0)
-        state = jax.lax.dynamic_update_index_in_dim(state, new, slot, 0)
-        conv = jax.lax.dynamic_update_index_in_dim(conv, kept.reshape(-1),
-                                                   slot, 0)
-        y = m.mamba_memory(params, pre, s, xc)
-        return (m.mamba_output(params, pre, y, z[0], self._mm)[None],
-                y[None], (state, conv))
-
-    def _window_prefill(self, q, k, v, tail, start, real_len):
-        """Dense window attention of one sequence's rows: q [T, heads,
-        d]; k, v [T, pairs, 2d] its own; `tail` (k, v) [W - 1, pairs, 2d]
-        of the positions before `start`. Returns (o [T, heads, 2d], the
-        tail after the rows)."""
-        cfg = self.cfg
-        T, W, d = q.shape[0], cfg.sliding_window, cfg.head_dim
-        g, rep = cfg.kv_pairs, cfg.num_attention_heads // cfg.kv_pairs // 2
-        ks = jnp.concatenate([tail[0], k], 0)          # index = W - 1 + t
-        vs = jnp.concatenate([tail[1], v], 0)
-        # [pairs', rep, 2, T, d]: query pair p = p' * rep + r
-        qh = q.reshape(T, g, rep, 2, d).transpose(1, 2, 3, 0, 4)
-        rows = min(T, self.WINDOW_ROWS)
-        out = []
-        for t0 in range(0, T, rows):
-            S = rows + W - 1
-            kb = ks[t0:t0 + S].reshape(S, g, 2, d).transpose(1, 2, 0, 3)
-            s = jnp.einsum("grjtd,gjsd->grjts", qh[:, :, :, t0:t0 + rows],
-                           kb, preferred_element_type=jnp.float32
-                           ) * d ** -0.5
-            t = t0 + jnp.arange(rows)[:, None]
-            idx = t0 + jnp.arange(S)[None, :]
-            # row t sees indices [t, t + W - 1] at positions >= 0
-            seen = (idx >= t) & (idx <= t + W - 1) & (
-                start - (W - 1) + idx >= 0)
-            p = jax.nn.softmax(jnp.where(seen, s, -1e30), axis=-1)
-            o = jnp.einsum("grjts,sgd->tgrjd", p.astype(vs.dtype),
-                           vs[t0:t0 + S],
-                           preferred_element_type=jnp.float32)
-            out.append(o.reshape(rows, cfg.num_attention_heads, 2 * d))
-        keep = lambda a: jax.lax.dynamic_slice_in_dim(a, real_len, W - 1, 0)
-        return (out[0] if len(out) == 1 else jnp.concatenate(out, 0),
-                (keep(ks), keep(vs)))
-
-    def _block(self, params, i, x, mixer):
-        """h = x + Mixer(LN(x)); y = h + MLP(LN(h)); mixer(u) -> (m,
-        extra)."""
-        cfg, m, pre = self.cfg, _phi, f"layers.{i}."
-        mix, extra = mixer(
-            m.block_norm(cfg, params, pre + "input_layernorm", x))
-        x = x + mix
-        with jax.named_scope("block/mlp"):
-            x = x + m.mlp(params, pre + "mlp.", m.block_norm(
-                cfg, params, pre + "post_attention_layernorm", x), self._mm)
-        return x, extra
-
-    def _cross_decoder(self, params, x, memory, pages, table, pos, q_len):
-        """The layers after the full layer on x [B, 1, hidden]: gated
-        memory units on `memory` [B, 1, d_inner], cross-attention to the
-        full layer's pages (no write)."""
-        cfg, m = self.cfg, _phi
-        for i in range(cfg.split, cfg.num_hidden_layers):
-            pre = f"layers.{i}."
-            if self.kinds[i] == "gmu":
-                def mixer(u, pre=pre):
-                    with jax.named_scope("block/gmu"):
-                        return m.gmu(params, pre + "gmu.", u, memory,
-                                     self._mm), None
-            else:
-                def mixer(u, pre=pre, i=i):
-                    with jax.named_scope("block/attn/shared"):
-                        q = m.cross_q(cfg, params, pre + "attn.", u, self._mm)
-                        o = self._attend(q[:, 0], pages, table, pos, q_len)
-                        return m.differential_output(
-                            cfg, params, pre + "attn.", i, o[:, None],
-                            u.dtype, self._mm), None
-            x, _ = self._block(params, i, x, mixer)
-        return x
-
-    def _head(self, params, x):
-        with jax.named_scope("final_norm"):
-            x = _phi.block_norm(self.cfg, params, "final_layernorm", x)
-        with jax.named_scope("lm_head"):
-            return x @ params["embed_tokens.weight"].T
-
-    # ------------------------------------------------------------- steps
-
-    def _forward(self, params, tokens, positions, write_page, write_off,
-                 tables, pos_q, q_lens, pools, head_rows=None):
-        """A decode step: one token a row, every layer."""
-        cfg, m = self.cfg, _phi
-        B, T = tokens.shape
-        if T != 1:
-            raise NotImplementedError(
-                "spans of several rows for several sequences at once (the "
-                "fused ragged step, speculative verify spans) are not "
-                "built for this runner; a prefill goes through "
-                "prefill_chunk")
-        pages, states, ring = pools
-        full_tab, ring_tab, ring_base = self._split_tables(tables)
-        valid = write_page != SCRATCH_PAGE                          # [B, 1]
-        live = valid[:, 0]
-        n_live = live.astype(jnp.int32)
-        bs, W = self.block_size, cfg.sliding_window
-        # the ring's own positions: its table's column 0 holds `base`
-        rel = pos_q - ring_base * bs
-        ring_page = jnp.where(live, jnp.take_along_axis(
-            ring_tab, jnp.clip(rel // bs, 0, ring_tab.shape[1] - 1)[:, None],
-            axis=1)[:, 0], SCRATCH_PAGE)
-        lower = jnp.maximum(rel - (W - 1), 0)
-        x = jnp.take(params["embed_tokens.weight"], tokens, axis=0)
-        new_states, new_ring, new_pages, memory = [], [], list(pages), None
-        for i in range(cfg.split):
-            pre, kind = f"layers.{i}.", self.kinds[i]
-            if kind == "mamba":
-                def mixer(u, pre=pre):
-                    out, y, layer = self._mamba(
-                        params, pre + "mamba.", u, valid, None, None,
-                        states[len(new_states)])
-                    new_states.append(layer)
-                    return out, y
-                x, y = self._block(params, i, x, mixer)
-                if i == cfg.memory_layer:
-                    memory = y
-                continue
-
-            def mixer(u, pre=pre, i=i, kind=kind):
-                a = pre + "attn."
-                q, k, v = m.attention_qkv(cfg, params, a, u, self._mm)
-                if kind == "window":
-                    with jax.named_scope("block/attn/window"):
-                        kp, vp = ring[len(new_ring)]
-                        layer = (self._write_rows(kp, ring_page, rel % bs,
-                                                  k[:, 0]),
-                                 self._write_rows(vp, ring_page, rel % bs,
-                                                  v[:, 0]))
-                        new_ring.append(layer)
-                        o = self._attend(q[:, 0], layer, ring_tab, rel,
-                                         n_live, lower)
-                else:
-                    with jax.named_scope("block/attn/shared"):
-                        kp, vp = pages[0]
-                        layer = (self._write_rows(kp, write_page[:, 0],
-                                                  write_off[:, 0], k[:, 0]),
-                                 self._write_rows(vp, write_page[:, 0],
-                                                  write_off[:, 0], v[:, 0]))
-                        new_pages[0] = layer
-                        o = self._attend(q[:, 0], layer, full_tab, pos_q,
-                                         n_live)
-                return m.differential_output(cfg, params, a, i, o[:, None],
-                                             u.dtype, self._mm), None
-            x, _ = self._block(params, i, x, mixer)
-        x = self._cross_decoder(params, x, memory, new_pages[0], full_tab,
-                                pos_q, n_live)
-        logits = self._head(params, x)
-        zero = jnp.int32(0)
-        counts = jnp.stack([jnp.sum(n_live) * len(states), zero, zero, zero])
-        return logits, (new_pages, new_states, new_ring), counts
-
-    def _self_decoder(self, params, tokens, table, real_len, start_slot,
-                      tail, cache):
-        """A prefill piece's rows through the layers before the full one,
-        and the full layer's key/value write: tokens [1, T] of ONE
-        sequence at positions start.. . Returns (x [1, T, hidden] before
-        the full layer, the memory, the full layer's (q, written pages),
-        the states, the tail after the piece, valid)."""
-        cfg, m = self.cfg, _phi
-        pages, states = cache
-        T = tokens.shape[1]
-        start, slots = start_slot[0], start_slot[1:]
-        offs = jnp.arange(T, dtype=jnp.int32)[None, :]
-        valid = offs < real_len
-        positions = jnp.where(valid, start + offs, 0)
-        page, off = self._write_indices(positions, table[None, :self.table_pages],
-                                        valid)
-        fresh = start == 0
-        x = jnp.take(params["embed_tokens.weight"], tokens, axis=0)
-        new_states, tail_k, tail_v, memory = [], [], [], None
-        for i in range(cfg.split - 1):
-            pre = f"layers.{i}."
-            if self.kinds[i] == "mamba":
-                def mixer(u, pre=pre):
-                    out, y, layer = self._mamba(
-                        params, pre + "mamba.", u, valid, fresh, slots,
-                        states[len(new_states)])
-                    new_states.append(layer)
-                    return out, y
-                x, y = self._block(params, i, x, mixer)
-                if i == cfg.memory_layer:
-                    memory = y
-                continue
-
-            def mixer(u, pre=pre, i=i):
-                a, n = pre + "attn.", len(tail_k)
-                with jax.named_scope("block/attn/window"):
-                    q, k, v = m.attention_qkv(cfg, params, a, u, self._mm)
-                    o, (tk, tv) = self._window_prefill(
-                        q[0], k[0], v[0], (tail[0][n], tail[1][n]), start,
-                        real_len)
-                    tail_k.append(tk)
-                    tail_v.append(tv)
-                return m.differential_output(
-                    cfg, params, a, i, o[None].astype(u.dtype), u.dtype,
-                    self._mm), None
-            x, _ = self._block(params, i, x, mixer)
-        # the full layer: keys and values of every row go to its pages
-        i = cfg.split - 1
-        a = f"layers.{i}.attn."
-        with jax.named_scope("block/attn/shared"):
-            u = m.block_norm(cfg, params, f"layers.{i}.input_layernorm", x)
-            q, k, v = m.attention_qkv(cfg, params, a, u, self._mm)
-            kp, vp = pages[0]
-            written = (self._write_rows(kp, page[0], off[0], k[0]),
-                       self._write_rows(vp, page[0], off[0], v[0]))
-        return (x, memory, q, written, new_states,
-                (jnp.stack(tail_k), jnp.stack(tail_v)), valid)
-
-    def _piece_body(self, params, tokens, table, real_len, start_slot, tail,
-                    cache):
-        """A piece's rows through the layers before the full one and the
-        full layer's key/value write; `start_slot` is (start, slot, whether
-        the piece is its chunk's last). No row of it reaches the full
-        layer's attention or the cross-decoder here: of its LAST real row
-        it hands on what `_piece_head` takes there (the stream before the
-        full layer, the memory, the full layer's query)."""
-        x, memory, q, written, states, tail, valid = self._self_decoder(
-            params, tokens, table, real_len, start_slot[:2], tail, cache)
-        last = jnp.reshape(real_len - 1, (1,))
-        row = lambda a: jnp.take_along_axis(a, last[:, None, None], axis=1)
-        q_row = jnp.take_along_axis(q, last[:, None, None, None],
-                                    axis=1)[:, 0]
-        real = jnp.sum(valid.astype(jnp.int32))
-        counts = jnp.stack([jnp.int32(0), real, real - start_slot[2],
-                            (start_slot[0] == 0).astype(jnp.int32)])
-        return ([written], states), tail, counts, (row(x), row(memory), q_row)
-
-    def _piece_head(self, params, last_row, table, pos, pages):
-        """A chunk's LAST real row (what `_piece_body` handed on, at
-        position `pos` [1]) through the full layer's attention and the
-        cross-decoder to the logits [vocab]. Reads the full group's pages,
-        writes nothing."""
-        cfg, m = self.cfg, _phi
-        x, memory, q = last_row
-        one = jnp.ones((1,), jnp.int32)
-        full_tab = table[None, :self.table_pages]
-        i = cfg.split - 1
-
-        def mixer(u):
-            # u is the last row's norm again: the same numbers
-            with jax.named_scope("block/attn/shared"):
-                o = self._attend(q, pages[0], full_tab, pos, one)
-                return m.differential_output(
-                    cfg, params, f"layers.{i}.attn.", i, o[:, None], u.dtype,
-                    self._mm), None
-        x, _ = self._block(params, i, x, mixer)
-        x = self._cross_decoder(params, x, memory, pages[0], full_tab, pos,
-                                one)
-        return self._head(params, x)[0, 0]
-
-    def _piece_rows(self, t: int) -> int:
-        """Rows of the pieces a chunk of t tokens runs in (the last one is
-        padded to it): a short chunk is one piece of its power-of-two
-        bucket, as every runner's prefill is; a longer one runs in pieces
-        of PREFILL_SPAN rows whatever is left for the last, so that long
-        prompts of any length share ONE program of the 17 layers (a quarter
-        of a minute to compile; a piece reads every weight once, 9.4 ms of
-        a v5e's memory at the published sizes, whatever its rows)."""
-        return bucket_len(t) if t <= min(self.SHORT_CHUNK,
-                                         self.PREFILL_SPAN) \
-            else self.PREFILL_SPAN
-
-    def prefill_chunk(self, tokens: List[int], start_pos: int,
-                      table_row: List[int], pools, slot=None, ring=None):
-        """The chassis's entry, in pieces of PREFILL_SPAN rows (the head of
-        this class). `ring`: the window group's row for this sequence
-        before and after the chunk (`WindowGroup.row`); `table_row` may
-        carry the group's columns behind the pages (they are not read)."""
-        if ring is None:
-            raise ValueError(
-                "Phi4FlashRunner.prefill_chunk needs ring=(before, after), "
-                "the window group's rows for this sequence around the "
-                "chunk (ServingEngine and naive_generate pass them)")
-        with _prof.span("runner.launch") as launch:
-            pages, states, win = pools
-            t, span = len(tokens), self._piece_rows(len(tokens))
-            launch.set(kind="prefill", key=span)
-            table = np.asarray(table_row, np.int32)[:self.table_pages]
-            slot = 0 if slot is None else slot
-            tail = self._jitted("phi_ring_load", 0)(
-                win, np.asarray(ring[0], np.int32), np.int32(start_pos))
-            body = self._jitted("phi_body", span)
-            for lo in range(0, t, span):
-                piece = tokens[lo:lo + span]
-                padded = np.zeros((1, span), np.int32)
-                padded[0, :len(piece)] = piece
-                with _prof.span("runner.dispatch"):
-                    (pages, states), tail, counts, last_row = body(
-                        self.params, padded, table, np.int32(len(piece)),
-                        np.asarray([start_pos + lo, slot, lo + span >= t],
-                                   np.int32), tail, (pages, states))
-                if self.on_step_counts is not None:
-                    self.on_step_counts(counts)
-            with _prof.span("runner.dispatch"):
-                logits = self._jitted("phi_head", 0)(
-                    self.params, last_row, table,
-                    np.asarray([start_pos + t - 1], np.int32), pages)
-            win = self._jitted("phi_ring_store", 0)(
-                win, tail, np.asarray(ring[1], np.int32),
-                np.int32(start_pos + t))
-            return logits, (pages, states, win)
-
-
-
 def runner_for(model, block_size: int = 16, max_model_len: int | None = None,
                attn_impl: str = "auto", kv_dtype: str = "fp32",
                weight_dtype: str = "fp32",
                weight_group_size: int = 128) -> PagedModelRunner:
-    """Pick the runner for a supported decoder Layer, by its class (a
-    DeepseekV3ForCausalLM is DeepSeek-V3, Kimi K2 or, with an indexer in
-    its configuration, DeepSeek-V3.2: one runner)."""
-    from paddle_tpu.models.deepseek_v3 import DeepseekV3ForCausalLM
-    from paddle_tpu.models.gpt import GPT
-    from paddle_tpu.models.llama import Llama
-    from paddle_tpu.models.olmo_hybrid import OlmoHybridForCausalLM
-    from paddle_tpu.models.phi4flash import Phi4FlashForCausalLM
+    """The runner for a supported decoder Layer, by its class:
+    `serving/runners/__init__.py` has the table, a module a runner."""
+    from paddle_tpu.serving import runners
 
-    for layer, cls in ((Llama, LlamaRunner), (GPT, GPTRunner),
-                       (DeepseekV3ForCausalLM, DeepseekV3Runner),
-                       (OlmoHybridForCausalLM, OlmoHybridRunner),
-                       (Phi4FlashForCausalLM, Phi4FlashRunner)):
-        if isinstance(model, layer):
-            return cls(model, block_size, max_model_len, attn_impl,
-                       kv_dtype=kv_dtype, weight_dtype=weight_dtype,
-                       weight_group_size=weight_group_size)
-    raise TypeError(
-        f"no serving runner for {type(model).__name__}; supported: Llama, "
-        "GPT, DeepseekV3ForCausalLM (DeepSeek-V3, Kimi K2, and DeepSeek-V3.2 "
-        "where its configuration sets index_topk), OlmoHybridForCausalLM, "
-        "Phi4FlashForCausalLM (write a PagedModelRunner subclass for "
-        "custom decoders)")
+    cls = runners.runner_class(model)
+    if cls is None:
+        raise TypeError(
+            f"no serving runner for {type(model).__name__}; supported: "
+            f"{runners.supported()} (write a PagedModelRunner subclass for "
+            "custom decoders)")
+    return cls(model, block_size, max_model_len, attn_impl,
+               kv_dtype=kv_dtype, weight_dtype=weight_dtype,
+               weight_group_size=weight_group_size)
 
 
 # what `runner_for` takes besides the model: an entry point that is handed

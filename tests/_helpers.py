@@ -326,6 +326,49 @@ class PeriodicStubRunner(StubPagedRunner):
         return row
 
 
+class CountingStubRunner(StubPagedRunner):
+    """The stub as a runner that counts: each single-pass launch hands its
+    counts over on the device (`COUNTS`, `on_step_counts`), bumps the two
+    host-side counters it says it keeps (`GAUGES`), and fails once when
+    told to, before anything reaches the pools. A subclass renames both."""
+
+    COUNTS = ("moe_tokens_routed", "moe_local_pairs")
+    GAUGES = ("attn_kv_bytes_read", "attn_kv_bytes_gather")
+
+    def __init__(self, fail_decode_calls=(), **kw):
+        super().__init__(**kw)
+        self.on_step_counts = None
+        for name in self.GAUGES:
+            setattr(self, name, 0.0)
+        self.handed = []                  # every launch's counts, in order
+        self.decode_calls = 0
+        self.fail_decode_calls = set(fail_decode_calls)
+
+    def _count(self, tokens: int, rows: int) -> None:
+        import jax.numpy as jnp
+
+        for name, n in zip(self.GAUGES, (16.0 * tokens, 64.0 * rows)):
+            setattr(self, name, getattr(self, name) + n)
+        self.handed.append((tokens, 3 * rows))
+        if self.on_step_counts is not None:
+            self.on_step_counts(jnp.asarray([tokens, 3 * rows], jnp.int32))
+
+    def prefill_chunk(self, tokens, start_pos, table, pools):
+        out = super().prefill_chunk(tokens, start_pos, table, pools)
+        self._count(len(tokens), 1)
+        return out
+
+    def decode(self, tokens, tables, pos, pools):
+        from paddle_tpu.serving.resilience import InjectedDeviceError
+
+        self.decode_calls += 1
+        if self.decode_calls in self.fail_decode_calls:
+            raise InjectedDeviceError(f"decode call {self.decode_calls}")
+        out = super().decode(tokens, tables, pos, pools)
+        self._count(len(tokens), len(tokens))
+        return out
+
+
 def stub_runner_factory(index=0, vocab_size=31, block_size=4,
                         max_model_len=64, period=0):
     """Importable replica-process factory (ISSUE 12): the launcher spec

@@ -145,17 +145,16 @@ def _dsa_layer(monkeypatch):
     """A sparse layer's whole decode attention as the runner's step calls
     it on a TPU: both page writes, the scan, the selection's two searches
     (no sort), the walk under the selection."""
-    from paddle_tpu.serving.model_runner import paged_attend
+    from paddle_tpu.serving.runners.deepseek_v3 import _sparse_latent_attend
 
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
 
     def fn(q, latent, q_i, k_i, w_i, pool, ipool, table, page, off, pos,
            scan_runs, walk_runs):
-        out, _ = paged_attend(
-            q, latent, None, (pool, ipool), table, page, off, pos,
-            jnp.ones_like(pos), 1, "ragged", scale=0.1, v_lanes=512,
-            runs=(scan_runs, walk_runs), kind="latent+index",
-            index=(q_i, k_i, w_i), topk=2048)
+        out, _ = _sparse_latent_attend(
+            q, latent, (q_i, k_i, w_i), (pool, ipool), table, page, off, pos,
+            jnp.ones_like(pos), "ragged", scale=0.1, v_lanes=512, topk=2048,
+            runs=(scan_runs, walk_runs))
         return out
 
     bf16, i32 = jnp.bfloat16, jnp.int32

@@ -540,12 +540,6 @@ def test_pool_is_two_arrays_a_layer_behind_one_table(seeded):
                for a in layer)
 
 
-def test_paged_attend_takes_its_layers_kind_by_name():
-    with pytest.raises(ValueError, match="kind"):
-        mr.paged_attend(None, None, None, (None,), None, None, None, None,
-                        None, 1, "reference", kind="latent-ish")
-
-
 def test_int8_rung_converts_the_indexers_matrices(seeded):
     model, _ = seeded
     runner = _engine(model, weight_dtype="int8").runner

@@ -22,7 +22,8 @@ from paddle_tpu.models.olmo_hybrid import (
 from paddle_tpu.ops import gated_delta as gd
 from paddle_tpu.ops.pallas import gated_delta_decode as gk
 from paddle_tpu.serving import KVCachePool, SamplingParams, naive_generate
-from paddle_tpu.serving.model_runner import OlmoHybridRunner, build_runner
+from paddle_tpu.serving.model_runner import build_runner
+from paddle_tpu.serving.runners.olmo_hybrid import OlmoHybridRunner
 
 sys.path.insert(0, os.path.join(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))), "bench"))

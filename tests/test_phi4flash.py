@@ -391,7 +391,7 @@ def test_prefill_that_skips_equals_the_unskipped_forward(toy, span, budget,
     chunks at 5 tokens, the logits are the dense forward's last row, and
     the decode steps that follow read a cache as good as its own."""
     cfg, weights, model = toy
-    from paddle_tpu.serving.model_runner import Phi4FlashRunner
+    from paddle_tpu.serving.runners.phi4flash import Phi4FlashRunner
     monkeypatch.setattr(Phi4FlashRunner, "PREFILL_SPAN", span)
     eng = _engine(model, max_prefill_tokens_per_step=budget)
     rows = _tap(eng.runner)

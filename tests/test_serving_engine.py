@@ -8,7 +8,7 @@ import numpy as np
 import pytest
 
 import paddle_tpu as paddle
-from _helpers import StubPagedRunner
+from _helpers import CountingStubRunner, StubPagedRunner
 from paddle_tpu.serving import (
     BlockAllocator, EngineMetrics, FCFSScheduler, Histogram, KVCachePool,
     Request, RequestState, SamplingParams, ServingEngine, naive_generate,
@@ -363,49 +363,6 @@ def test_scheduler_fuzz_no_leaks_and_oracle_equivalence():
 
 
 # ------------------------------------------ the step's book-keeping, put off
-
-
-class CountingStubRunner(StubPagedRunner):
-    """The stub as a runner that counts: each single-pass launch hands its
-    counts over on the device (`COUNTS`, `on_step_counts`), bumps the
-    host-side byte counters a real runner keeps, and fails once when told
-    to, before anything reaches the pools."""
-
-    COUNTS = ("moe_tokens_routed", "moe_local_pairs")
-
-    def __init__(self, fail_decode_calls=(), **kw):
-        super().__init__(**kw)
-        self.on_step_counts = None
-        self.attn_kv_bytes_read = 0.0
-        self.attn_kv_bytes_gather = 0.0
-        self.handed = []                  # every launch's counts, in order
-        self.decode_calls = 0
-        self.fail_decode_calls = set(fail_decode_calls)
-
-    def _count(self, tokens: int, rows: int) -> None:
-        import jax.numpy as jnp
-
-        self.attn_kv_bytes_read += 16.0 * tokens
-        self.attn_kv_bytes_gather += 64.0 * rows
-        self.handed.append((tokens, 3 * rows))
-        if self.on_step_counts is not None:
-            self.on_step_counts(jnp.asarray([tokens, 3 * rows], jnp.int32))
-
-    def prefill_chunk(self, tokens, start_pos, table, pools):
-        out = super().prefill_chunk(tokens, start_pos, table, pools)
-        self._count(len(tokens), 1)
-        return out
-
-    def decode(self, tokens, tables, pos, pools):
-        from paddle_tpu.serving.resilience import InjectedDeviceError
-
-        self.decode_calls += 1
-        if self.decode_calls in self.fail_decode_calls:
-            raise InjectedDeviceError(f"decode call {self.decode_calls}")
-        out = super().decode(tokens, tables, pos, pools)
-        self._count(len(np.asarray(tokens)), len(np.asarray(tokens)))
-        return out
-
 
 # the gauges a step's end mirrors, and where the engine's state holds each
 STEP_GAUGES = {

@@ -354,7 +354,7 @@ def test_syncs_per_token_drops_4x_at_horizon_8():
             eng.add_request([i + 1, 2, 3, 4], SamplingParams(max_tokens=40))
         while eng.has_work():
             eng.step()
-        spt[s] = eng.metrics.host_syncs_per_token()
+        spt[s] = eng.metrics.ratio("host_syncs_per_token")
     assert spt[1] / spt[8] >= 4.0, spt
 
 

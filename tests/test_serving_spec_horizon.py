@@ -562,7 +562,7 @@ def test_real_model_shadow_acceptance_rate_greedy(llama_runner):
             llama_runner, p, sp, max_model_len=64)
     m = eng.metrics
     assert m.spec_proposed_tokens.value > 0
-    assert m.spec_acceptance_rate() > 0.8, m.spec_acceptance_rate()
+    assert m.ratio("spec_acceptance_rate") > 0.8
     assert eng.pool.allocator.check_no_leaks()
 
 

@@ -127,7 +127,7 @@ def test_full_acceptance_and_step_collapse():
     m = eng.metrics
     assert m.spec_proposed_tokens.value > 0
     assert m.spec_accepted_tokens.value == m.spec_proposed_tokens.value
-    assert m.spec_acceptance_rate() == 1.0
+    assert m.ratio("spec_acceptance_rate") == 1.0
     # full acceptance: far fewer engine steps than generated tokens
     assert m.decode_steps.value < m.tokens_generated.value
     assert outs[rid].output_tokens == naive_generate(runner, prompt, sp,
