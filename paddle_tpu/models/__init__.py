@@ -10,6 +10,9 @@ from paddle_tpu.models.ernie import (  # noqa: F401
     ErnieForTokenClassification, ErnieModel, ernie_pretrain_loss_fn,
     mask_tokens,
 )
+from paddle_tpu.models.laguna import (  # noqa: F401
+    LagunaConfig, LagunaForCausalLM,
+)
 from paddle_tpu.models.olmo_hybrid import (  # noqa: F401
     OlmoHybridConfig, OlmoHybridForCausalLM,
 )

@@ -618,7 +618,8 @@ def moe_ffn(cfg, params, pre: str, h, valid=None, mm=plain_mm):
         y, pairs, touched = held_experts_ffn(
             h, idx, w, params[pre + "experts.gate_proj"],
             params[pre + "experts.up_proj"],
-            params[pre + "experts.down_proj"], cfg.first_expert, valid)
+            params[pre + "experts.down_proj"], cfg.first_expert, valid,
+            n_routed=cfg.n_routed_experts)
     with jax.named_scope("block/moe/shared"):
         y = y + dense_ffn(params, pre + "shared_experts.", h, mm
                           ).astype(jnp.float32)

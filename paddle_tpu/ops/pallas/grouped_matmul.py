@@ -52,6 +52,24 @@ def _tile(n: int, fits) -> int:
     return max(ok) if ok else n
 
 
+def _fits(bm: int, c: int, isz: int, wsz: int, osz: int):
+    """Whether a grid step of `_rows_call` with a weight tile of t columns
+    fits: its three blocks, twice each for the pipeline."""
+    return lambda t: 2 * (bm * c * isz + c * t * wsz
+                          + bm * t * osz) <= VMEM_BUDGET
+
+
+def one_tile(bm: int, c: int, n: int, dtype, out_itemsize: int = None
+             ) -> bool:
+    """Whether the forward's product of [bm, c] row blocks with [c, n]
+    matrices of `dtype` takes a group's matrix as ONE tile: the grid is then
+    the row blocks alone, a matrix is fetched once a run of its group's
+    blocks, and the pipeline fetches the next block's while this one
+    multiplies."""
+    isz = jnp.dtype(dtype).itemsize
+    return _fits(bm, c, isz, isz, out_itemsize or isz)(n)
+
+
 def _nn(a, b):
     return jnp.dot(a, b, preferred_element_type=jnp.float32)
 
@@ -86,8 +104,7 @@ def _rows_call(x, w, block_group, n_live, bm: int, transposed: bool,
     R, c = x.shape
     n = w.shape[1] if transposed else w.shape[2]
     isz, osz = x.dtype.itemsize, jnp.dtype(out_dtype).itemsize
-    bn = _tile(n, lambda t: 2 * (bm * c * isz + c * t * w.dtype.itemsize
-                                 + bm * t * osz) <= VMEM_BUDGET)
+    bn = _tile(n, _fits(bm, c, isz, w.dtype.itemsize, osz))
     w_block = (1, bn, c) if transposed else (1, c, bn)
 
     def w_index(j, i, group, n_live):

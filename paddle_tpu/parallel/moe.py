@@ -274,7 +274,8 @@ def sigmoid_topk_route(x, gate_w, bias, top_k: int, *,
                        norm_topk_prob: bool = True, scale: float = 1.0,
                        n_group: int = 1, topk_group: int = 1):
     """Sigmoid scores with a selection-only bias ("noaux_tc"): x [T, d],
-    gate_w [d, E], bias [E] -> (indices [T, top_k] of the top_k largest of
+    gate_w [d, E], bias [E] or None (a router without one selects by the
+    scores alone) -> (indices [T, top_k] of the top_k largest of
     score + bias, weights [T, top_k] float32). With `n_group` > 1 the
     selection is group-limited: the E experts are `n_group` groups of
     consecutive ids, a group's score is the sum of its two largest score +
@@ -287,7 +288,7 @@ def sigmoid_topk_route(x, gate_w, bias, top_k: int, *,
 
     s = jax.nn.sigmoid(jnp.matmul(x, gate_w,
                                   preferred_element_type=jnp.float32))
-    choice = s + bias.astype(jnp.float32)[None, :]
+    choice = s if bias is None else s + bias.astype(jnp.float32)[None, :]
     if n_group > 1:
         T, E = choice.shape
         grouped = choice.reshape(T, n_group, E // n_group)
@@ -352,48 +353,106 @@ def expert_layout(idx, first_expert: int, n_held: int, block_rows: int,
     return counts, row_pair, p_end, block_expert
 
 
+# fewest held experts at which the grouped product takes the serving form's
+# walk: below it a loop iteration an expert block is few iterations
+GROUPED_MIN_EXPERTS = 64
+
+
+def block_rows(n_pairs: int, n_routed: int) -> int:
+    """Rows of one block of `expert_layout` for the serving form: the power
+    of two at or under the pairs an expert can expect (`n_pairs / n_routed`:
+    the launch's token-expert pairs over ALL the experts the router chooses
+    among, held here or not), between 16 (a whole bfloat16 tile) and 256. A
+    decode step of 64 rows x 8 on 256 experts walks blocks of 16, a prefill
+    piece of 2048 rows on them blocks of 64, a prompt of 16384 rows on 384
+    experts blocks of 256: padding an expert's group to a block then costs
+    a fraction of its rows, where a block chosen from the pairs alone (256
+    past 4096 of them) multiplied four times the rows of an expert that
+    gets 64."""
+    per = n_pairs // max(n_routed, 1)
+    bm = 16
+    while bm * 2 <= min(per, 256):
+        bm *= 2
+    return bm
+
+
+def grouped_walk(w_gate, w_down, bm: int) -> bool:
+    """Whether the serving form walks its blocks inside
+    `ops.pallas.grouped_matmul` rather than in a loop of its own: MANY
+    experts held, each SMALL. Many: `GROUPED_MIN_EXPERTS` or more. Small:
+    an expert's matrix is one weight tile of the grouped product
+    (`grouped_matmul.one_tile`), so a launch's grid is its row blocks alone
+    and block i + 1's matrix is fetched while block i multiplies. A loop
+    iteration a block fetches nothing ahead: at 6 MB an expert it waits for
+    its matrices as long as it multiplies, at 88 MB (12 or 8 held experts of
+    7168 x 2048) the fetch IS the block and the loop reads at the memory's
+    rate (PERF.md, S8)."""
+    from paddle_tpu.ops.pallas.grouped_matmul import one_tile
+
+    G, d, f = w_gate.shape
+    return G >= GROUPED_MIN_EXPERTS and one_tile(
+        bm, d, f, w_gate.dtype) and one_tile(bm, f, d, w_down.dtype, 4)
+
+
 def held_experts_ffn(x, idx, weights, w_gate, w_up, w_down,
-                     first_expert: int, valid=None):
+                     first_expert: int, valid=None, walk: bool = False,
+                     n_routed: int = None):
     """The routed part of a top-k expert layer over the experts held here,
     dropless: y[t] = sum over the selected experts e of token t that lie in
     [first_expert, first_expert + G) of weights[t, e] * SwiGLU_e(x[t]).
 
-    The SERVING form: its loop has a dynamic trip count, which JAX cannot
-    differentiate in reverse mode; under `jax.grad` it raises by name. A
-    training step calls `held_experts_ffn_train`, the same layer over the
-    grouped product that has a gradient.
+    The SERVING form: it has no reverse mode and raises by name under
+    `jax.grad`. A training step calls `held_experts_ffn_train`, the same
+    layer with a gradient.
 
     x [T, d]; idx / weights [T, K] as `sigmoid_topk_route` gives them;
     w_gate / w_up [G, d, f], w_down [G, f, d]: the held experts, stacked;
-    valid [T] bool (rows that are padding route nowhere). Returns
+    valid [T] bool (rows that are padding route nowhere); `n_routed`: the
+    experts the router chooses among (left out: the G held). Returns
     (y [T, d] float32, pairs, touched): the token-expert pairs computed
-    here and the held experts that got at least one.
+    here and the held experts that got at least one; with `walk`, also
+    (blocks, rows): the row blocks the layer walked and the rows it
+    multiplied (whole blocks).
 
     No [tokens, experts, capacity] mask and no capacity: the local pairs
     are sorted by expert into a layout where every expert's group starts
-    at a multiple of a row block (`expert_layout`), and a loop with a
-    DYNAMIC trip count walks the blocks that exist: each block gathers its
-    tokens' rows, multiplies them by its one expert's matrices and adds the
-    weighted result onto its tokens. An expert no token chose costs nothing
-    (its matrices are not read); if every token chooses the same expert the
-    loop is longer, nothing is dropped. The block is 16 rows for a decode
-    step's few pairs and 256 for a prefill's: shapes decide."""
-    import jax
+    at a multiple of a row block (`expert_layout`; `block_rows` chooses the
+    block from the pairs an expert can expect), and only the blocks that
+    exist are multiplied: each gathers its tokens' rows, multiplies them by
+    its one expert's matrices and adds the weighted result onto its tokens.
+    An expert no token chose costs nothing (its matrices are not read); if
+    every token chooses the same expert the walk is longer, nothing is
+    dropped. Two walks of the same blocks, chosen from shapes
+    (`grouped_walk`): a loop with a DYNAMIC trip count, an expert block an
+    iteration, or the three products of the launch as
+    `ops.pallas.grouped_matmul`'s forward."""
+    G = w_gate.shape[0]
+    bm = block_rows(idx.size, n_routed or G)
+    y, counts, blocks = _walk(grouped_walk(w_gate, w_down, bm), bm, x, idx,
+                              weights, w_gate, w_up, w_down, first_expert,
+                              valid)
+    out = (y, jnp.sum(counts), jnp.sum((counts > 0).astype(jnp.int32)))
+    return out + (blocks, blocks * bm) if walk else out
 
-    T, d = x.shape
-    K, G = idx.shape[1], w_gate.shape[0]
-    N = T * K
-    bm = 16 if N <= 4096 else 256
+
+def _walk(grouped: bool, bm: int, x, idx, weights, w_gate, w_up, w_down,
+          first_expert: int, valid=None):
+    """One of the two walks over `expert_layout` in blocks of `bm` rows:
+    (y [T, d] float32, counts [G], the blocks that hold anything)."""
+    K, N = idx.shape[1], idx.size
     counts, row_pair, p_end, block_expert = expert_layout(
-        idx, first_expert, G, bm, valid)
+        idx, first_expert, w_gate.shape[0], bm, valid)
+    if grouped:
+        blocks = p_end[-1] // bm
+        return _walk_grouped(bm, blocks, x, weights, row_pair, block_expert,
+                             w_gate, w_up, w_down), counts, blocks
     real = row_pair < N
     row_tok = jnp.where(real, row_pair // K, 0)
     row_w = jnp.where(real, weights.reshape(N)[jnp.minimum(row_pair, N - 1)],
                       0.0).astype(jnp.float32)
-
-    y = _walk_blocks(bm, p_end[-1] // bm, x, row_tok, row_w, block_expert,
-                     w_gate, w_up, w_down)
-    return y, jnp.sum(counts), jnp.sum((counts > 0).astype(jnp.int32))
+    blocks = p_end[-1] // bm
+    return _walk_blocks(bm, blocks, x, row_tok, row_w, block_expert, w_gate,
+                        w_up, w_down), counts, blocks
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
@@ -413,16 +472,45 @@ def _walk_blocks(bm, n_blocks, x, row_tok, row_w, block_expert,
                              jnp.zeros(x.shape, jnp.float32))
 
 
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _walk_grouped(bm, n_blocks, x, weights, row_pair, block_expert,
+                  w_gate, w_up, w_down):
+    """The launch's three products over the layout's rows, then each pair's
+    row of the result weighted onto its token. Rows and pairs are two views
+    of one one-to-one map (`row_pair` [R]: the flat pair t * K + k a row
+    holds, T * K where it is padding), so both directions are GATHERS: a
+    scatter-add of the rows onto their tokens costs the chip several times
+    as much. A pair whose expert is absent, or whose token is padding, has
+    no row and adds nothing."""
+    from paddle_tpu.ops.pallas.grouped_matmul import grouped_matmul
+
+    (T, K), R = weights.shape, row_pair.shape[0]
+    product = functools.partial(
+        grouped_matmul, block_group=block_expert, n_live=n_blocks,
+        block_rows=bm)
+    pair_row = jnp.full((T * K + 1,), R, jnp.int32).at[row_pair].set(
+        jnp.arange(R, dtype=jnp.int32))[:T * K]
+    rows = x.at[row_pair // K].get(mode="fill", fill_value=0)
+    g = product(rows, w_gate).astype(jnp.float32)
+    u = product(rows, w_up).astype(jnp.float32)
+    out = product((jax.nn.silu(g) * u).astype(x.dtype), w_down,
+                  out_dtype=jnp.float32)
+    picked = out.at[pair_row].get(mode="fill", fill_value=0)
+    return jnp.sum(picked.reshape(T, K, -1)
+                   * weights.astype(jnp.float32)[..., None], axis=1)
+
+
 def _no_gradient(*_):
     raise TypeError(
         "parallel.moe.held_experts_ffn is the serving form of the held-"
-        "experts layer: its loop over the blocks that exist has a dynamic "
+        "experts layer: its walk over the blocks that exist has a dynamic "
         "trip count, which has no reverse mode. Differentiate "
         "parallel.moe.held_experts_ffn_train, the same layer over "
         "ops.pallas.grouped_matmul")
 
 
 _walk_blocks.defvjp(_no_gradient, _no_gradient)
+_walk_grouped.defvjp(_no_gradient, _no_gradient)
 
 
 # ------------------------------------------------------------------------

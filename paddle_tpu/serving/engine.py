@@ -498,7 +498,7 @@ class ServingEngine:
             model_axis=getattr(runner, "model_axis", "model"),
             state_slots=self.max_batch_size,
             window_span=max(1, self.decode_horizon))
-        if self.pool.state_layers:
+        if self.pool.state_layers or self.pool.window is not None:
             self._refuse_state_copies(kv_store)
         if self.enable_prefix_cache:
             self.pool.enable_prefix_cache()

@@ -1892,6 +1892,10 @@ class WindowGroup:
         self._held: Dict[int, Tuple[int, List[int]]] = {}
         self.pages_taken = 0          # ever handed out
         self.pages_returned = 0       # ever given back
+        # a slot's whole ring: taken by a slot that held nothing (a new
+        # request's first pages), released with the slot at its request's end
+        self.rings_taken = 0
+        self.rings_released = 0
         # summed over the rows of every launch `extend_tables` built: the
         # pages a layer held for the row, and what a cache of its whole
         # context would have held
@@ -1913,6 +1917,8 @@ class WindowGroup:
     def cover(self, slot: int, lo: int, hi: int) -> None:
         bs = self.block_size
         first, last = max(0, lo) // bs, (hi - 1) // bs
+        if slot not in self._held:
+            self.rings_taken += 1
         base, pages = self._held.get(slot, (first, []))
         if pages and not base <= first <= base + len(pages):
             # not a continuation (a slot's new holder, a restart): all go
@@ -1939,6 +1945,8 @@ class WindowGroup:
     def release(self, slot: int) -> None:
         """Everything the slot holds goes back (its request ended or was
         preempted)."""
+        if slot in self._held:
+            self.rings_released += 1
         self._give_back(self._held.pop(slot, (0, []))[1])
 
     def row(self, slot: int) -> np.ndarray:
@@ -1980,11 +1988,13 @@ class WindowGroup:
     def gauges(self) -> Dict[str, float]:
         """Sums over launches, so a window's delta divides: the pages a
         layer held for the rows of every decode launch, the pages a cache
-        of the whole context would have held for the same rows, and the
-        pages given back."""
+        of the whole context would have held for the same rows, the pages
+        given back, and the slots' whole rings taken and released."""
         return {"window_pages_held": self.held_page_rows,
                 "window_pages_whole_context": self.whole_context_page_rows,
-                "window_pages_returned": self.pages_returned}
+                "window_pages_returned": self.pages_returned,
+                "window_rings_taken": self.rings_taken,
+                "window_rings_released": self.rings_released}
 
     def check_no_leaks(self) -> bool:
         return not self._held and len(self._free) == self.num_blocks - 1
@@ -2036,7 +2046,8 @@ class KVCachePool:
         page GROUP (`WindowGroup`): that many layers keep only a
         sequence's last `window` positions, in pages of this pool's layout
         with their own table columns and free list, sized here for
-        `state_slots` sequences. `num_layers` and `num_blocks` are then
+        `state_slots` sequences (with or without state layers beside it).
+        `num_layers` and `num_blocks` are then
         the "full" group's, whose pages grow with the context under the
         allocator as ever; `pools` is the triple `(pages, states,
         window pages)`. `row_pages`: see `page_arrays`."""
@@ -2093,7 +2104,9 @@ class KVCachePool:
             self.state_layers = int(state_layout[0])
             self.state_arrays = [(tuple(int(n) for n in t), jnp.dtype(d))
                                  for t, d in state_layout[1]]
-        self.state_slots = int(state_slots) if self.state_layers else 0
+        # slots: a row of every state array, a ring of the window group
+        self.state_slots = int(state_slots) if (
+            self.state_layers or window is not None) else 0
         # the pages themselves: zero fills dispatched here, which finish
         # on the device behind whatever comes next
         with _prof.always_span("kv_pool.alloc", num_blocks=num_blocks):
@@ -2110,9 +2123,9 @@ class KVCachePool:
             self.window: Optional[WindowGroup] = None
             self.window_pools = []
             if window is not None:
-                if mesh is not None or not self.state_layers:
+                if mesh is not None:
                     raise ValueError("a window group is built for one "
-                                     "device, beside state slots")
+                                     "device")
                 layers, length, span = window
                 self.window = WindowGroup(layers, length, block_size,
                                           self.state_slots, span)

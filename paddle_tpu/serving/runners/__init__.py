@@ -27,6 +27,9 @@ RUNNERS = (
      "paddle_tpu.serving.runners.olmo_hybrid:OlmoHybridRunner", ""),
     ("paddle_tpu.models.phi4flash:Phi4FlashForCausalLM",
      "paddle_tpu.serving.runners.phi4flash:Phi4FlashRunner", ""),
+    ("paddle_tpu.models.laguna:LagunaForCausalLM",
+     "paddle_tpu.serving.runners.laguna:LagunaRunner",
+     "Laguna-XS.2: every expert held"),
 )
 
 

@@ -213,6 +213,43 @@ def _ragged_phi4(B, table, pages, bounded, dtype=jnp.bfloat16):
                 ((B, table), jnp.int32), vec, vec] + [vec] * bounded
 
 
+def _ragged_laguna(B, T, n_q, table, pages, bounded, dtype=jnp.bfloat16):
+    """laguna-xs.2.agent-8k's attention: 48 (a full layer) or 64 (a sliding
+    layer) query heads of 128 over (k, v) pages of 8 heads; a full layer's
+    table is 576 wide over 36928 pages, a sliding layer's ring 33 wide over
+    2177 pages with a lower bound a sequence; a decode step (64 rows, T 1)
+    and a prefill piece (one sequence, 2048 rows, groups of 6 or 8)."""
+    from paddle_tpu.ops.pallas.ragged_paged_attention import \
+        ragged_paged_attention
+
+    def fn(q, kp, vp, table, start, qlen, *lower):
+        return ragged_paged_attention(q, kp, vp, table, start, qlen,
+                                      interpret=False,
+                                      lower=lower[0] if lower else None)
+
+    pool = ((pages, PAGE, 8, 128), dtype)
+    vec = ((B,), jnp.int32)
+    return fn, [((B, T, n_q, 128), jnp.bfloat16), pool, pool,
+                ((B, table), jnp.int32), vec, vec] + [vec] * bounded
+
+
+def _grouped_laguna(rows, block, width_in, width_out, out_dtype=None):
+    """laguna-xs.2.agent-8k's expert products as the serving form's walk
+    makes them: 256 experts of 2048 x 512 (gate, up) and 512 x 2048 (down,
+    float32 out), the layout of a decode step (64 rows: 512 pairs in blocks
+    of 16) and of a prefill piece (2048 rows: 16384 pairs in blocks of
+    64)."""
+    from paddle_tpu.ops.pallas.grouped_matmul import grouped_matmul
+
+    def fwd(x, w, block_group, n_live):
+        return grouped_matmul(x, w, block_group, n_live, block_rows=block,
+                              out_dtype=out_dtype, interpret=False)
+
+    bf16 = jnp.bfloat16
+    return fwd, [((rows, width_in), bf16), ((256, width_in, width_out), bf16),
+                 ((rows // block,), jnp.int32), ((), jnp.int32)]
+
+
 def _scan_decode(B, n=16, c=5120):
     """The selective scan's decode update at the published widths: B
     sequences against a layer's pool of B + 1 float32 states of 16 x 5120,
@@ -366,6 +403,34 @@ CASES = {
         48, 1024, 49216, False, jnp.float8_e4m3fn),
     "ragged-phi4-window-fp8-b48": lambda mp: _ragged_phi4(
         48, 33, 1585, True, jnp.float8_e4m3fn),
+    # the Laguna cell: one kernel at two head counts over 8 K/V heads, with
+    # and without a lower bound, few-rows and prefill layouts (groups of 6
+    # and of 8), fp8 pages (the control), the oracle's batch of 1; the
+    # expert layer's grouped products at 256 held experts
+    "ragged-laguna-full-48-b64": lambda mp: _ragged_laguna(
+        64, 1, 48, 576, 36928, False),
+    "ragged-laguna-window-64-b64": lambda mp: _ragged_laguna(
+        64, 1, 64, 33, 2177, True),
+    "ragged-laguna-full-48-b1": lambda mp: _ragged_laguna(
+        1, 1, 48, 576, 36928, False),
+    "ragged-laguna-window-64-b1": lambda mp: _ragged_laguna(
+        1, 1, 64, 33, 2177, True),
+    "ragged-laguna-full-48-piece-2048": lambda mp: _ragged_laguna(
+        1, 2048, 48, 576, 36928, False),
+    "ragged-laguna-group-8-piece-2048": lambda mp: _ragged_laguna(
+        1, 2048, 64, 576, 36928, False),
+    "ragged-laguna-full-48-fp8-b64": lambda mp: _ragged_laguna(
+        64, 1, 48, 576, 36928, False, jnp.float8_e4m3fn),
+    "ragged-laguna-window-64-fp8-b64": lambda mp: _ragged_laguna(
+        64, 1, 64, 33, 2177, True, jnp.float8_e4m3fn),
+    "grouped-laguna-decode-gate": lambda mp: _grouped_laguna(
+        4608, 16, 2048, 512),
+    "grouped-laguna-decode-down": lambda mp: _grouped_laguna(
+        4608, 16, 512, 2048, jnp.float32),
+    "grouped-laguna-piece-gate": lambda mp: _grouped_laguna(
+        32768, 64, 2048, 512),
+    "grouped-laguna-piece-down": lambda mp: _grouped_laguna(
+        32768, 64, 512, 2048, jnp.float32),
     "scan-decode-b48": lambda mp: _scan_decode(48),
     "scan-decode-b1": lambda mp: _scan_decode(1),
     "flash-fp32-fwd": lambda mp: _flash(jnp.float32, False),

@@ -270,11 +270,12 @@ def test_the_cell_is_found_by_its_files():
     # walk's counters are the runner's own, sparse or not
     assert CELL not in listing["mla_attn_roofline"]["workloads"]
     assert listing["latent_run_copy_share"]["workloads"][-1] == CELL
-    for name in ("decode_weights_roofline", "moe_pairs_per_touched_expert",
-                 "decode_step_dev_ms", "setup_compile_s"):
-        assert listing[name]["workloads"][-1] == CELL
+    assert listing["decode_weights_roofline"]["workloads"][-1] == CELL
+    for name in ("moe_pairs_per_touched_expert", "decode_step_dev_ms",
+                 "setup_compile_s"):
+        assert CELL in listing[name]["workloads"]
     e2e = {m["name"]: m for m in manifest["end_to_end"]}
-    assert e2e["serve_tokens_per_s"]["workloads"][-1] == CELL
+    assert CELL in e2e["serve_tokens_per_s"]["workloads"]
 
 
 def test_every_published_number_is_in_the_configuration():

@@ -145,6 +145,17 @@ def _phi():
         max_seq_len=32))
 
 
+def _laguna():
+    from paddle_tpu.models.laguna import LagunaConfig, LagunaForCausalLM
+
+    return LagunaForCausalLM(LagunaConfig(
+        vocab_size=64, hidden_size=32, intermediate_size=48,
+        num_hidden_layers=5, num_attention_heads=6, num_key_value_heads=2,
+        head_dim=8, num_experts=8, num_experts_per_tok=2,
+        moe_intermediate_size=8, shared_expert_intermediate_size=8,
+        sliding_window=8, max_seq_len=32))
+
+
 # what every serving cell's readers under bench/layer_metrics/ read of the
 # window's counters (host_syncs_per_token; batch_occupancy_mean reads the
 # histogram), then what its own readers read besides
@@ -172,6 +183,14 @@ LAYERS = {
     "phi4flash": (_phi, "phi4flash", "Phi4FlashRunner",
                   {"window_pages_held", "window_pages_whole_context",
                    "ssm_decode_seq_steps"}),
+    # laguna-xs.2.agent-8k: pairs per touched expert and the held share of
+    # the two cells above, laguna_decode_block_fill and the gate of
+    # laguna_weights_roofline besides
+    "laguna": (_laguna, "laguna", "LagunaRunner",
+               {"moe_local_pairs", "moe_experts_touched",
+                "window_pages_held", "window_pages_whole_context",
+                "moe_decode_pairs", "moe_decode_rows_multiplied",
+                "moe_decode_experts_touched"}),
 }
 
 
